@@ -26,6 +26,12 @@ Compile/load counters are asserted PER ARM (hit arms must show
 rather than just slowing it down.  Dispatch counts are deliberately
 tiny — the numbers of interest are compile wall-clock, not throughput.
 
+The parent process never touches JAX — a chip belongs to one process
+at a time, so a parent that held it would starve its own cold children;
+training + export run in a child too.  The arms run on the CPU unless
+``JAX_PLATFORMS`` says otherwise, and the report names the platform
+the children saw.
+
 Usage::
 
     python benchmarks/coldstart_bench.py      # writes COLDSTART_BENCH.json
@@ -90,6 +96,7 @@ def child_serve(bundle: str) -> dict:
     x = np.random.RandomState(0).randn(4, 16).astype(np.float32)
     out = np.asarray(model(x))
     return {
+        "platform": model.device.jax_device.platform,
         "serve_ready_ms": round(1e3 * (t_ready - _T0), 1),
         "import_ms": round(1e3 * (t_import - _T0), 1),
         "warmup_ms": round(1e3 * (t_ready - t_import), 1),
@@ -184,20 +191,17 @@ def _run_arm(mode: str, cache_dir: str, bundle: str = "",
 
 
 def run() -> dict:
-    from benchmarks.serve_bench import train_and_export
-
     work = tempfile.mkdtemp(prefix="coldstart_")
     bundle = os.path.join(work, "model.npz")
-    train_and_export(bundle, epochs=1)
     serve_cache = os.path.join(work, "serve_cache")
     train_cache = os.path.join(work, "train_cache")
+    _run_arm("export", os.path.join(work, "export_cache"), bundle)
 
-    report: dict = {"platform": "cpu-subprocess",
+    miss = _run_arm("serve", serve_cache, bundle)
+    report: dict = {"platform": miss["platform"] + "-subprocess",
                     "note": ("each arm is a cold python process; "
                              "serve_ready_ms counts interpreter+jax "
                              "import+load+warmup")}
-
-    miss = _run_arm("serve", serve_cache, bundle)
     hit = _run_arm("serve", serve_cache, bundle)
     corrupt = _run_arm("serve", serve_cache, bundle, chaos=True)
     report["serve_miss"], report["serve_hit"] = miss, hit
@@ -245,13 +249,16 @@ def run() -> dict:
 def main() -> int:
     if len(sys.argv) > 1 and sys.argv[1].startswith("--child-"):
         mode = sys.argv[1][len("--child-"):]
-        if mode == "serve":
+        if mode == "export":
+            _ensure_platform()
+            from benchmarks.serve_bench import train_and_export
+            out = {"bundle": train_and_export(sys.argv[2], epochs=1)}
+        elif mode == "serve":
             out = child_serve(sys.argv[2])
         else:
             out = child_train()
         print(json.dumps(out))
         return 0
-    _ensure_platform()
     report = run()
     path = os.path.join(REPO, "COLDSTART_BENCH.json")
     with open(path, "w") as f:

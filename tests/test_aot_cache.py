@@ -331,3 +331,49 @@ def test_respecialize_guard_falls_back_on_sharding_change():
                     site=site) == before + 1
     # and the fallback keeps serving later fires
     np.testing.assert_array_equal(np.asarray(wrapped(sharded)), x * 2)
+
+
+def test_one_device_program_loads_with_eight_devices_visible(tmp_path):
+    """The jax 0.9 regression kept as a test: ``deserialize_and_load``
+    left to its default loads a program over EVERY visible device, and
+    a one-device program then refuses its one-shard operands
+    ("expected 8 shards, got [1]").  The store records the devices a
+    program was compiled for and loads it onto those — here the
+    fourth of the suite's eight CPU devices."""
+    import jax
+    import jax.numpy as jnp
+
+    devices = jax.devices()
+    assert len(devices) == 8
+    x = jax.device_put(jnp.arange(12.0).reshape(3, 4), devices[3])
+    compiled = jax.jit(lambda a: a * 2.0 + 1.0).lower(x).compile()
+    cache = aot_cache.AotCache(str(tmp_path / "store"))
+    assert cache.put("k" * 64, compiled, "test")
+    loaded = cache.get("k" * 64, "test")
+    assert loaded is not None and cache.corrupt == 0
+    out = loaded(x)
+    assert out.devices() == {devices[3]}
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(x) * 2 + 1)
+
+
+def test_program_from_jax_compilation_cache_is_not_stored(
+        tmp_path, monkeypatch):
+    """An executable JAX's own persistent cache handed back loads from
+    this store but fails at dispatch when serialized a second time, so
+    the store refuses any program whose compile may have been such a
+    hit: a hit of JAX's cache between the key's miss and its put."""
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.ones((2, 2))
+    compiled = jax.jit(lambda a: a + 1.0).lower(x).compile()
+    cache = aot_cache.AotCache(str(tmp_path / "store"))
+    assert cache.get("a" * 64, "test") is None            # miss
+    monkeypatch.setattr(aot_cache, "_jax_cache_hits",
+                        aot_cache._jax_cache_hits + 1)    # a JAX hit
+    assert not cache.put("a" * 64, compiled, "test")
+    assert cache.entries() == []
+    # a native compile (no JAX-cache hit since the miss) is stored
+    assert cache.get("b" * 64, "test") is None
+    assert cache.put("b" * 64, compiled, "test")
+    assert [key for key, _meta in cache.entries()] == ["b" * 64]

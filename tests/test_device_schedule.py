@@ -1,8 +1,8 @@
 """Device-resident minibatch schedule (FullBatchLoader.device_schedule):
 per-step indices come from an on-device cursor over the uploaded
 permutation, so a training step issues NO host→device transfers — the
-TPU-first replacement for per-step index uploads (decisive on
-remote/tunneled TPUs where each transfer is an RPC round trip)."""
+TPU-first replacement for per-step index uploads (each transfer
+costs a round trip)."""
 
 import numpy as np
 

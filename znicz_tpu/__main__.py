@@ -139,10 +139,11 @@ def make_parser() -> argparse.ArgumentParser:
                         "dispatch/RPC latency — see "
                         "StandardWorkflow.run_chunked)")
     p.add_argument("--n-model", type=int, default=1, metavar="M",
-                   help="model-axis size of the distributed device "
+                   help="model-axis size of the device "
                         "grid (tensor parallelism: layers with "
                         "model_parallel='column'/'row' shard over it; "
-                        "requires --listen/--master)")
+                        "standalone runs build the mesh over this "
+                        "host's devices)")
     p.add_argument("--dump-graph", metavar="FILE",
                    help="write the workflow's Graphviz DOT and exit")
     p.add_argument("--dry-run", action="store_true",
@@ -167,6 +168,8 @@ class Main(Logger):
         if not args.workflow:
             make_parser().print_usage()
             return 2
+        from znicz_tpu.backends import configure_compile_cache
+        configure_compile_cache()
         if args.config:
             _import_module(args.config, "config")
         _apply_root_overrides(args.root)

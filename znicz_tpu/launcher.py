@@ -10,7 +10,9 @@ TPU-first deltas (SURVEY.md §2.5, §5.8): the master–slave cluster
 runs the same program over a global device mesh and XLA inserts the
 gradient all-reduce over ICI/DCN.  So "mode" here means:
 
-- *standalone*: single process, all locally visible devices;
+- *standalone*: single process on one device, or — when ``n_model`` /
+  ``n_seq`` ask for a mesh — over every locally visible device (one
+  process drives all the chips of a host, no ``jax.distributed``);
 - *distributed*: ``jax.distributed.initialize`` (PJRT multi-host
   bootstrap over DCN) — the ``--listen`` host is process 0
   ("master" in reference terms: it owns snapshots and logging), every
@@ -160,30 +162,30 @@ class Launcher(Logger):
     # ------------------------------------------------------------------
     def make_device(self) -> Device:
         if self.device is None:
-            if self.coordinator and self.backend == "numpy":
+            on_mesh = bool(self.coordinator) or self.n_model > 1 \
+                or self.n_seq > 1
+            backend = self.backend or root.common.engine.backend
+            if on_mesh and backend == "numpy":
                 raise ValueError(
-                    "distributed mode requires an XLA backend — the "
-                    "host-only numpy oracle cannot join a device mesh "
-                    "(each process would silently train an independent "
-                    "replica)")
-            if not self.coordinator and (self.n_model > 1
-                                         or self.n_seq > 1):
-                raise ValueError(
-                    f"n_model={self.n_model}/n_seq={self.n_seq} "
-                    f"requires distributed mode (--listen/--master or "
-                    f"the ZNICZ_* env builds the global mesh); a "
-                    f"standalone run would silently ignore it")
-            if self.coordinator:
-                # Distributed mode: SPMD over the GLOBAL mesh (all
-                # hosts' devices, data × model[, seq]); XLA lays the
-                # gradient all-reduce over ICI/DCN.  This is the whole
-                # point of the bootstrap — a local-only device would
-                # silently train per-host replicas.
-                from znicz_tpu.backends import XLADevice
+                    "a device mesh requires an XLA backend — the "
+                    "host-only numpy oracle cannot join one (in "
+                    "distributed mode each process would silently "
+                    "train an independent replica)")
+            if on_mesh:
+                # SPMD over a mesh (data × model[, seq]); XLA lays the
+                # gradient all-reduce over ICI/DCN.  Distributed mode:
+                # the GLOBAL mesh of all hosts' devices — the whole
+                # point of the bootstrap, a local-only device would
+                # silently train per-host replicas.  Standalone: this
+                # host's devices.
+                import jax
+
+                from znicz_tpu.backends import TPUDevice, XLADevice
                 from znicz_tpu.parallel import make_mesh
-                self.device = XLADevice(
-                    mesh=make_mesh(n_model=self.n_model,
-                                   n_seq=self.n_seq))
+                cls = TPUDevice if backend == "tpu" else XLADevice
+                self.device = cls(mesh=make_mesh(
+                    n_model=self.n_model, n_seq=self.n_seq,
+                    devices=jax.devices(cls.platform)))
             else:
                 self.device = Device.create(self.backend)
         return self.device

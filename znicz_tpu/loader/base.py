@@ -277,7 +277,7 @@ class Loader(AcceleratedUnit):
         if self._on_device_schedule():
             # indices/valid are computed ON DEVICE from the resident
             # schedule (sched_* leaves) — no per-step host→device
-            # uploads, the big per-step cost on remote/tunneled TPUs
+            # uploads (each one a round trip the step would wait on)
             self._sync_device_schedule()
             return
         self.minibatch_indices.map_invalidate()

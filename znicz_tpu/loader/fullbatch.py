@@ -31,8 +31,8 @@ class FullBatchLoader(Loader):
       permutation and the minibatch schedule live ON DEVICE: per-step
       indices come from a device-resident cursor, so a training step
       issues NO host→device transfers (a permutation upload per epoch
-      replaces two uploads per step — decisive on tunneled/remote TPU
-      where every transfer is an RPC).
+      replaces two uploads per step, each of which costs a round
+      trip).
     """
 
     # the dataset itself: large, immutable, rebuilt by load_data on

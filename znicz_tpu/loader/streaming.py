@@ -574,7 +574,7 @@ class StreamingLoader(Loader):
     prefetch_depth:
         device batches uploaded ahead of the consumer (≥ 1; 2 =
         double-buffered h2d, 3 = triple).  Raise it when the transfer
-        is long-latency (tunneled TPU); host footprint grows by one
+        is long-latency; host footprint grows by one
         staged batch per unit.
     ring_slots:
         host staging buffers feeding the uploader (default

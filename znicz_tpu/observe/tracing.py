@@ -367,7 +367,9 @@ def profile_window(outdir: str, n_steps: int | None = None,
     recorded during the window — feed it to ``trace_top.py --spans``).
     ``n_steps`` is recorded on the window span so per-step math in the
     post-processors has its divisor.  ``device=False`` skips the jax
-    profiler (host spans only — cheap enough for always-on use).
+    profiler (host spans only — cheap enough for always-on use); with
+    ``device=True`` a profiler that will not start raises — a device
+    trace that was asked for and is missing must not read as success.
 
     Usage mid-training::
 
@@ -378,26 +380,18 @@ def profile_window(outdir: str, n_steps: int | None = None,
     if tracer is None:  # NOT `or`: an empty SpanTracer is falsy
         tracer = TRACER
     os.makedirs(outdir, exist_ok=True)
-    started = False
     global _DEVICE_TRACE_OPEN
     if device:
-        try:
-            import jax
-            jax.profiler.start_trace(outdir)
-            started = True
-            _DEVICE_TRACE_OPEN = True
-        except Exception as exc:  # noqa: BLE001 — an open trace must not kill the run
-            import logging
-            logging.getLogger("znicz_tpu.observe").warning(
-                "profile_window: device trace unavailable (%s) — "
-                "recording host spans only", exc)
+        import jax
+        jax.profiler.start_trace(outdir)
+        _DEVICE_TRACE_OPEN = True
     mark = tracer.mark()
     try:
         with tracer.span("profile_window", cat="profile",
                          n_steps=n_steps or 0):
             yield outdir
     finally:
-        if started:
+        if device:
             import jax
             _DEVICE_TRACE_OPEN = False
             try:

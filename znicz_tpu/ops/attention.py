@@ -179,9 +179,6 @@ class MultiHeadAttention(Forward):
         from znicz_tpu.parallel.mesh import kernel_shard_spec, \
             spec_divides
         from znicz_tpu.utils.config import root
-        flag = root.common.engine.get("flash_attention", "auto")
-        if flag == "auto":
-            flag = pallas_kernels.is_tpu_device(self.device)
         # interpret-mode lever: lets the virtual CPU mesh run the REAL
         # kernels (shard_map oracle tests / dryruns); never default
         interpret = bool(root.common.engine.get("pallas_interpret",
@@ -234,18 +231,22 @@ class MultiHeadAttention(Forward):
                 bq = bk = min(int(cblk), t)
         self._flash_pack = head_pack
         self._flash_block_q, self._flash_block_k = bq, bk
-        engaged = (
-            bool(flag)
-            and tpu_capable
-            and not self._ring_active
-            # T must tile evenly and the head dim must be lane-legal
-            # (dh % 8 — e.g. dh=1 via a to_sequence net would crash
-            # Mosaic at trace instead of falling back; ADVICE round 5)
-            and pallas_attention.kernel_legal(t, t, dh, bq, bk))
         self._flash_interpret = interpret
         self._flash_mesh = None
         self._flash_spec = None
-        if engaged and mesh is not None and mesh.size > 1:
+        #: why the kernel did not engage (None = it did, or the ring
+        #: owns the core)
+        refused = pallas_kernels.kernel_refusal(
+            self.device, "flash_attention", interpret)
+        local = refused is None and not self._ring_active
+        if local and not pallas_attention.kernel_legal(t, t, dh, bq,
+                                                       bk):
+            # T must tile evenly and the head dim must be lane-legal
+            # (dh % 8 — e.g. dh=1 via a to_sequence net would crash
+            # Mosaic at trace instead of falling back; ADVICE round 5)
+            refused = (f"T={t}, head dim {dh} do not tile by blocks "
+                       f"({bq}, {bk})")
+        elif local and mesh is not None and mesh.size > 1:
             # mesh-native path: the opaque pallas_call has no GSPMD
             # sharding rule — un-shard_mapped on a multi-device mesh
             # it would replicate-and-gather the batch-sharded operands
@@ -255,13 +256,30 @@ class MultiHeadAttention(Forward):
             # conservative single-device gate (kernel off on meshes —
             # the safe fallback, mirroring _pallas_ln's old guard).
             spec, _ = kernel_shard_spec(mesh, 4)
-            engaged = (
-                bool(root.common.engine.get("pallas_shard_map", True))
-                and getattr(self.input, "model_shard_dim", None) is None
-                and spec_divides(mesh, (b, t, self.n_heads, dh), spec))
-            if engaged:
+            if not root.common.engine.get("pallas_shard_map", True):
+                refused = "engine.pallas_shard_map is off"
+            elif getattr(self.input, "model_shard_dim", None) \
+                    is not None:
+                refused = "the input is model-sharded"
+            elif not spec_divides(mesh, (b, t, self.n_heads, dh),
+                                  spec):
+                refused = (f"batch {b} does not divide over mesh "
+                           f"{dict(mesh.shape)}")
+            else:
                 self._flash_mesh, self._flash_spec = mesh, spec
-        self._flash_pallas = engaged
+        self._flash_pallas = local and refused is None
+        if self._ring_active:
+            self.info("%s: ring attention over '%s', %s fold",
+                      self.name, self._ring_axis, self._ring_fold)
+        elif self._flash_pallas:
+            self.info("%s: flash kernel, blocks (%d, %d), head pack "
+                      "%d%s%s", self.name, bq, bk, head_pack,
+                      ", per shard under shard_map"
+                      if self._flash_mesh is not None else "",
+                      ", INTERPRETED" if interpret else "")
+        else:
+            self.info("%s: XLA attention core — %s", self.name,
+                      refused)
         self.init_vectors(self.input, self.output, self.weights,
                           self.bias, self.weights_out, self.bias_out)
 

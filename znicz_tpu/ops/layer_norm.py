@@ -51,22 +51,19 @@ class LayerNorm(Forward):
         from znicz_tpu.parallel.mesh import kernel_shard_spec, \
             spec_divides
         from znicz_tpu.utils.config import root
-        flag = root.common.engine.get("pallas_layer_norm", "auto")
-        if flag == "auto":
-            flag = pallas_kernels.is_tpu_device(self.device)
         interpret = bool(root.common.engine.get("pallas_interpret",
                                                 False))
         mesh = getattr(self.device, "mesh", None)
-        multi_device = mesh is not None and mesh.size > 1
-        engaged = bool(flag) and (
-            pallas_kernels.is_tpu_device(self.device) or interpret)
         self._ln_interpret = interpret
         self._ln_mesh = None
         self._ln_spec = None
         msd = getattr(self.input, "model_shard_dim", None)
         msd_axis = getattr(self.input, "model_shard_axis", None)
         ndim = len(self.input.shape)
-        if engaged and multi_device:
+        #: why the kernel did not engage (None = it did)
+        refused = pallas_kernels.kernel_refusal(
+            self.device, "pallas_layer_norm", interpret)
+        if refused is None and mesh is not None and mesh.size > 1:
             # mesh-native path: a pallas_call has no GSPMD sharding
             # rule — un-shard_mapped it would gather the sharded
             # operand onto every device.  Run per-shard under
@@ -78,17 +75,27 @@ class LayerNorm(Forward):
             spec, _ = kernel_shard_spec(
                 mesh, ndim, model_shard_dim=msd,
                 **({"model_axis": msd_axis} if msd_axis else {}))
-            engaged = (
-                bool(root.common.engine.get("pallas_shard_map", True))
-                and msd != ndim - 1  # feature axis must stay whole
-                and spec_divides(mesh, self.input.shape, spec))
-            if engaged:
+            if not root.common.engine.get("pallas_shard_map", True):
+                refused = "engine.pallas_shard_map is off"
+            elif msd == ndim - 1:
+                refused = "the feature axis is model-sharded"
+            elif not spec_divides(mesh, self.input.shape, spec):
+                refused = (f"shape {tuple(self.input.shape)} does not "
+                           f"divide over mesh {dict(mesh.shape)}")
+            else:
                 self._ln_mesh, self._ln_spec = mesh, spec
-        elif engaged:
-            # single device: plain kernel; a (trivially) model-sharded
-            # input keeps the XLA path as before
-            engaged = msd is None
-        self._pallas_ln = engaged
+        elif refused is None and msd is not None:
+            # single device: a (trivially) model-sharded input keeps
+            # the XLA path as before
+            refused = "the input is model-sharded"
+        self._pallas_ln = refused is None
+        if self._pallas_ln:
+            self.info("%s: layer-norm kernel%s%s", self.name,
+                      ", per shard under shard_map"
+                      if self._ln_mesh is not None else "",
+                      ", INTERPRETED" if interpret else "")
+        else:
+            self.info("%s: XLA layer norm — %s", self.name, refused)
         self.init_vectors(self.input, self.output, self.weights,
                           self.bias)
 

@@ -78,11 +78,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: jax renamed ``TPUCompilerParams`` → ``CompilerParams``; accept both
-#: so the kernels run on 0.4.x and current jax alike
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 _NEG_INF = -1e30
 #: default tile sizes — chip-swept (PERF.md round 5): 1024×1024 beats
 #: 512×512 by ~1.2× (fewer grid revisits of the VMEM stats; the f32
@@ -260,7 +255,7 @@ def _fwd_call(q, k, v, q_off, k_off, causal, bq, bk, interpret, pack):
         scratch_shapes=[pltpu.VMEM((bq, _STAT_LANES), jnp.float32),
                         pltpu.VMEM((bq, _STAT_LANES), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -392,7 +387,7 @@ def _bwd_call(q, k, v, lse, do, delta4, q_off, k_off, causal, bq, bk,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -414,7 +409,7 @@ def _bwd_call(q, k, v, lse, do, delta4, q_off, k_off, causal, bq, bk,
                    jax.ShapeDtypeStruct((b, h, tk, d), v.dtype)),
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -552,14 +547,16 @@ def flash_attention(q, k, v, causal: bool = False,
             raise ValueError(
                 "global offsets ride the ring path (per-shard hops), "
                 "not the batch-sharded shard_map path")
-        from znicz_tpu.parallel.mesh import shard_map_unchecked
         from jax.sharding import PartitionSpec as P
         hspec = P(spec[0], spec[2], None, None)  # boundary → head-major
-        fn = shard_map_unchecked(
+        # check_vma off: an opaque pallas_call (and the custom_vjp
+        # around it) has no replication rule for the checker
+        fn = jax.shard_map(
             lambda a, b_, c: _flash_hop(
                 a, b_, c, _off_arr(None), _off_arr(None), causal, bq,
                 bk, interpret, pack)[0],
-            mesh, in_specs=(hspec, hspec, hspec), out_specs=hspec)
+            mesh=mesh, in_specs=(hspec, hspec, hspec), out_specs=hspec,
+            check_vma=False)
         out = fn(qh, kh, vh)
     else:
         out = _flash_hop(qh, kh, vh, _off_arr(q_offset),

@@ -29,8 +29,6 @@ from znicz_tpu.parallel.mesh import (  # noqa: F401
     batch_sharding,
     kernel_shard_spec,
     replicated_sharding,
-    shard_map_fn,
-    shard_map_unchecked,
     spec_divides,
     zero1_choice,
     zero1_partition,

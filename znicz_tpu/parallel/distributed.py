@@ -86,13 +86,10 @@ def ensure_initialized(coordinator: str | None = None,
         spec["process_id"] = process_id
     if not spec.get("coordinator_address"):
         return False
-    try:
-        # CPU backends need a collectives implementation for
-        # cross-process computations (the default "none" fails every
-        # multi-process program); harmless no-op on TPU pods
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # pragma: no cover - old jax
-        pass
+    # CPU backends need a collectives implementation for
+    # cross-process computations (the default "none" fails every
+    # multi-process program); harmless no-op on TPU pods
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     engine = root.common.engine
     timeout = float(timeout_s if timeout_s is not None
                     else engine.get("dist_init_timeout_s", 300.0))
@@ -101,11 +98,8 @@ def ensure_initialized(coordinator: str | None = None,
     last_exc: Exception | None = None
     for attempt in range(retries + 1):
         try:
-            try:
-                jax.distributed.initialize(
-                    initialization_timeout=max(1, int(timeout)), **spec)
-            except TypeError:  # pragma: no cover - jax without the kwarg
-                jax.distributed.initialize(**spec)
+            jax.distributed.initialize(
+                initialization_timeout=max(1, int(timeout)), **spec)
             _initialized = True
             return True
         except (TypeError, ValueError):

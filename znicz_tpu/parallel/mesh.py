@@ -23,31 +23,6 @@ from znicz_tpu.parallel.axis import (DATA_AXIS, MODEL_AXIS, PIPE_AXIS,
                                      SEQ_AXIS)
 
 
-def shard_map_fn():
-    """The ``shard_map`` entry point across jax versions (moved out of
-    ``jax.experimental`` in 0.8)."""
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:  # pragma: no cover - version-dependent
-        from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
-def shard_map_unchecked(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` with the replication/varying-manual-axes check
-    OFF — an opaque ``pallas_call`` (and ``custom_vjp`` around one)
-    has no replication rule, so the checker would reject the body.
-    Handles the kwarg rename across jax versions (``check_rep`` →
-    ``check_vma``)."""
-    sm = shard_map_fn()
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, check_rep=False)
-    except TypeError:  # pragma: no cover - version-dependent
-        return sm(f, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, check_vma=False)
-
-
 def kernel_shard_spec(mesh: Mesh | None, ndim: int,
                       model_shard_dim: int | None = None,
                       model_axis: str = MODEL_AXIS,

@@ -412,8 +412,7 @@ def sequence_sharded_attention(mesh, q, k, v, causal: bool = False,
     ring collectives), so data parallelism composes with sequence
     parallelism instead of being silently all-gathered away at the
     shard_map boundary."""
-    from znicz_tpu.parallel.mesh import kernel_shard_spec, \
-        shard_map_fn, shard_map_unchecked
+    from znicz_tpu.parallel.mesh import kernel_shard_spec
 
     # one spec convention for the ring and the mesh-native Pallas
     # kernels: batch rides the data axis, time (dim 1) rides the
@@ -444,16 +443,15 @@ def sequence_sharded_attention(mesh, q, k, v, causal: bool = False,
         offs = (jnp.arange(n_seq, dtype=jnp.int32)
                 * t_local).reshape(n_seq, 1)
         # the opaque pallas_call (and its custom_vjp) has no
-        # replication rule — same unchecked wrapper as the
-        # batch-sharded flash path
-        fn = shard_map_unchecked(
-            body, mesh,
+        # replication rule — check_vma off, as on the batch-sharded
+        # flash path
+        fn = jax.shard_map(
+            body, mesh=mesh,
             in_specs=(spec, spec, spec, P(axis_name, None)),
-            out_specs=spec)
+            out_specs=spec, check_vma=False)
         return fn(q, k, v, offs)
-    fn = shard_map_fn()(body, mesh=mesh,
-                        in_specs=(spec, spec, spec),
-                        out_specs=spec)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec)
     return fn(q, k, v)
 
 

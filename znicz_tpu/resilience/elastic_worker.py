@@ -86,20 +86,11 @@ def main() -> None:
     devices_per_proc = int(os.environ.get("ZNICZ_ELASTIC_DEVICES", "2"))
     platform = os.environ.get("ZNICZ_ELASTIC_PLATFORM", "cpu")
 
-    if platform == "cpu":
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + f" --xla_force_host_platform_device_count="
-                        f"{devices_per_proc}").strip()
     import jax
     if platform:
         jax.config.update("jax_platforms", platform)
     if platform == "cpu":
-        try:
-            jax.config.update("jax_num_cpu_devices", devices_per_proc)
-        except AttributeError:  # older jax: XLA_FLAGS above covers it
-            pass
+        jax.config.update("jax_num_cpu_devices", devices_per_proc)
 
     from znicz_tpu.launcher import Launcher
     from znicz_tpu.observe import metrics as obs_metrics

@@ -78,6 +78,27 @@ DEFAULT_MAX_BYTES = 2 << 30
 _lock = threading.Lock()
 _build_digest: str | None = None
 _caches: dict[str, "AotCache"] = {}
+#: hits of JAX's own persistent compilation cache seen by this process
+_jax_cache_hits = 0
+_counting_jax_cache_hits = False
+
+
+def _count_jax_cache_hits() -> None:
+    """Listen (once per process) for hits of JAX's persistent
+    compilation cache: an executable it hands back must not enter this
+    store — see :meth:`AotCache.put`."""
+    global _counting_jax_cache_hits
+    if _counting_jax_cache_hits:     # (called under ``_lock`` too)
+        return
+    _counting_jax_cache_hits = True
+    import jax
+
+    def on_event(event: str, **_kwargs) -> None:
+        global _jax_cache_hits
+        if event == "/jax/compilation_cache/cache_hits":
+            _jax_cache_hits += 1
+
+    jax.monitoring.register_event_listener(on_event)
 
 
 # ----------------------------------------------------------------------
@@ -239,7 +260,8 @@ def jaxpr_key(fn, leaves, extra=()) -> str | None:
 # ----------------------------------------------------------------------
 class AotCache(Logger):
     """Content-addressed executable store: ``<key>.bin`` (pickled
-    ``serialize_executable`` triple) + ``<key>.sha256`` sidecar +
+    ``serialize_executable`` triple + the ids of the devices it was
+    compiled for) + ``<key>.sha256`` sidecar +
     ``<key>.json`` metadata per entry, plus an advisory
     ``manifest.json`` rollup.  Thread-safe; writes are atomic
     (tmp + rename) so concurrent processes sharing one directory never
@@ -258,6 +280,9 @@ class AotCache(Logger):
         self.misses = 0
         self.corrupt = 0
         self.puts = 0
+        #: key → JAX-cache hits seen when the key missed here
+        self._missed_at: dict[str, int] = {}
+        _count_jax_cache_hits()
 
     # -- paths ----------------------------------------------------------
     def _bin(self, key: str) -> str:
@@ -342,6 +367,7 @@ class AotCache(Logger):
             _metrics.aot_cache_events(site, "miss").inc()
             with self._lock:
                 self.misses += 1
+                self._missed_at[key] = _jax_cache_hits
             return None
         if _faults.fire("aotcache.corrupt", at_site=site) is not None:
             # rot the bytes AFTER the sidecar was written — exactly
@@ -353,9 +379,16 @@ class AotCache(Logger):
             self._quarantine(key, site, "sha256 mismatch")
             return None
         try:
+            import jax
             from jax.experimental import serialize_executable as _se
-            ser, in_tree, out_tree = pickle.loads(payload)
-            loaded = _se.deserialize_and_load(ser, in_tree, out_tree)
+            ser, in_tree, out_tree, device_ids = pickle.loads(payload)
+            # load onto the devices the program was compiled for: left
+            # to its default, jax loads over EVERY visible device and
+            # a one-chip program then refuses its one-shard operands
+            by_id = {d.id: d for d in jax.devices()}
+            loaded = _se.deserialize_and_load(
+                ser, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids])
         except Exception as exc:  # noqa: BLE001 — corrupt pickle/exe
             self._quarantine(key, site, f"deserialize failed: {exc}")
             return None
@@ -387,10 +420,26 @@ class AotCache(Logger):
             meta: dict | None = None) -> bool:
         """Serialize + store one compiled executable.  Best-effort: an
         executable this backend cannot serialize just stays uncached
-        (the compile already happened — nothing is lost)."""
+        (the compile already happened — nothing is lost).
+
+        An executable that JAX's own persistent compilation cache
+        handed back is never stored: serialized a second time it loads
+        but fails at dispatch ("Function … not found", CPU backend,
+        jax 0.9.0), which no digest can catch.  Any hit of JAX's cache
+        between this key's miss and its put counts as "this compile
+        may have been one" — a false alarm only costs the entry."""
+        with self._lock:
+            missed_at = self._missed_at.pop(key, _jax_cache_hits)
+        if missed_at != _jax_cache_hits:
+            self.debug("AOT cache: program for site %s came through "
+                       "JAX's compilation cache — not stored", site)
+            return False
         try:
             from jax.experimental import serialize_executable as _se
-            payload = pickle.dumps(_se.serialize(compiled))
+            device_ids = [d.id for d in
+                          compiled.runtime_executable().local_devices()]
+            payload = pickle.dumps(
+                _se.serialize(compiled) + (device_ids,))
         except Exception as exc:  # noqa: BLE001 — not serializable
             self.debug("AOT cache: executable for site %s not "
                        "serializable (%s)", site, exc)
@@ -513,21 +562,21 @@ class AotCache(Logger):
 def guard_donated(loaded, donate_argnums=()):
     """Make a DESERIALIZED executable safe to dispatch with donation.
 
-    Observed on the CPU PJRT backend (jax 0.4.37): a deserialized
-    executable that donates a multiply-referenced operand mishandles
-    the buffer's ownership — the output that aliases the donated
-    input gets freed while still live (non-finite garbage mid-train,
-    ``double free or corruption`` at teardown).  Natively-compiled
-    programs are immune; only the ``deserialize_and_load`` dispatch
-    path double-frees.  Until a chip run validates native aliasing
-    (CHIP_QUEUE ``COLDSTART_TPU=1``), donated operands of loaded
-    programs are re-owned first: each is passed as a fresh
-    single-owner device copy, which the probe matrix shows is
-    bitwise-identical to the un-guarded dispatch and stable across
-    thousands of steps.  A memcpy per donated leaf per dispatch —
-    orders of magnitude below the compile it replaces, but not free:
-    set ``engine.aot_cache_alias = "native"`` to dispatch unguarded
-    where the runtime is known good."""
+    Observed on the CPU PJRT backend when the store landed: a
+    deserialized executable that donates a multiply-referenced operand
+    mishandled the buffer's ownership — the output that aliases the
+    donated input got freed while still live (non-finite garbage
+    mid-train, ``double free or corruption`` at teardown), while
+    natively-compiled programs were immune.  A 2,000-step CPU probe on
+    the installed jax (a loaded region program dispatched unguarded)
+    no longer reproduces it, but no chip run has validated native
+    aliasing of loaded programs, so the default stays the safe one:
+    each donated operand is passed as a fresh single-owner device
+    copy, bitwise-identical to the un-guarded dispatch.  A memcpy per
+    donated leaf per dispatch — orders of magnitude below the compile
+    it replaces, but not free: set ``engine.aot_cache_alias =
+    "native"`` to dispatch unguarded where the runtime is known
+    good."""
     if not donate_argnums:
         return loaded
     if str(root.common.engine.get("aot_cache_alias",
