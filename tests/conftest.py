@@ -51,6 +51,17 @@ def fresh_state(tmp_path):
     graphics.reset_server()
 
 
+@pytest.fixture()
+def compile_cache_placed_outside(monkeypatch, tmp_path):
+    """For tests that run ``Main`` in this process: it places JAX's
+    compile cache in the checkout unless the environment already
+    placed it, and in-process that outlives the test — every later
+    compile of the worker would be written to ``<checkout>/.jax_cache``
+    (which ``test_chip_smoke`` watches from another worker)."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                       str(tmp_path / "jax_cache"))
+
+
 def make_blobs(n_per_class: int, n_classes: int, dim: int,
                spread: float = 0.35, seed: int = 7):
     """Synthetic gaussian-blob classification data (datasets are not

@@ -10,6 +10,8 @@ from znicz_tpu.__main__ import Main, _apply_root_overrides
 from znicz_tpu.launcher import Launcher
 from znicz_tpu.utils.config import root
 
+pytestmark = pytest.mark.usefixtures("compile_cache_placed_outside")
+
 
 def test_root_overrides():
     _apply_root_overrides(["wine.learning_rate=0.125",

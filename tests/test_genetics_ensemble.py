@@ -11,6 +11,8 @@ from znicz_tpu.genetics import (GeneticsOptimizer, Tune, apply_genome,
 from znicz_tpu.loader.base import VALID
 from znicz_tpu.utils.config import root
 
+pytestmark = pytest.mark.usefixtures("compile_cache_placed_outside")
+
 
 def test_tune_basics():
     t = Tune(0.1, 0.01, 1.0)

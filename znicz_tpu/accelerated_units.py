@@ -30,6 +30,7 @@ Unit contract for region membership:
 
 from __future__ import annotations
 
+import re
 from typing import Sequence
 
 import numpy as np
@@ -343,15 +344,33 @@ class JitRegion(Logger):
                         err.throw()
                     else:
                         out = fn(*leaves)
-        elif checks:
-            err, out = fn(*leaves)
-            err.throw()  # located NaN/inf/OOB report, e.g. "nan
-            #              generated by primitive: log" + traceback
         else:
-            out = fn(*leaves)
+            # the warmed program's call is a span of its own, inside
+            # the region unit's fire: past the few programs the
+            # runtime admits in flight the call BLOCKS until one
+            # finishes, and that wait is not the unit's work
+            with _tracing.TRACER.span(f"dispatch:{self.name}",
+                                      cat="region"):
+                out = fn(*leaves)
+            if checks:
+                err, out = out
+                err.throw()  # located NaN/inf/OOB report, e.g. "nan
+                #              generated by primitive: log" + traceback
         _metrics.region_steps(self.name).inc()
         for vec, leaf in zip(vectors, out):
             vec.devmem = leaf
+
+    def program_name(self, variant: str) -> str:
+        """``znicz_<variant>__<region>``: the name a jitted program's
+        function carries, so a profile's module line
+        (``jit_znicz_step__train_region``) and an HLO dump say which
+        program of which region they show."""
+        region = re.sub(r"\W", "_", self.name)
+        return f"znicz_{variant}__{region}"
+
+    def _named(self, fn, variant: str):
+        fn.__name__ = fn.__qualname__ = self.program_name(variant)
+        return fn
 
     def build_callable(self, skips: tuple[bool, ...],
                        accum_phase: "tuple[str, int] | None" = None):
@@ -367,16 +386,19 @@ class JitRegion(Logger):
         microbatch that commits one optimizer step from the buffered
         sum.  The phase is installed while the body traces (see
         :data:`_ACCUM_PHASE`), so phase-aware units branch statically
-        — each phase is its own compiled program variant."""
+        — each phase is its own compiled program variant.
+
+        The function is named for what it is (:meth:`program_name`:
+        ``step``, or ``accum_micro`` / ``apply_micro`` in a phase)."""
         if self._vectors is None:
             self._vectors = self._collect_vectors()
         vectors = self._vectors
         units = self.units
         precision = getattr(self.device, "matmul_precision", "default")
         # telemetry: trace each member under jax.named_scope so the
-        # compiled program's op names (and thus trace_top's fusion
-        # rows) carry unit attribution; resolved at trace time so a
-        # cached program keeps whatever naming it compiled with
+        # compiled program's op metadata carries unit attribution;
+        # resolved at trace time so a cached program keeps whatever
+        # naming it compiled with
         named = _metrics.enabled()
 
         def fn(*leaves):
@@ -408,7 +430,8 @@ class JitRegion(Logger):
                     if getattr(unit, "_traced_vjp", None) is not None:
                         unit._traced_vjp = None
 
-        return fn
+        return self._named(
+            fn, f"{accum_phase[0]}_micro" if accum_phase else "step")
 
     def run_chunk(self, n_steps: int) -> None:
         """Execute ``n_steps`` region steps in ONE dispatch:
@@ -453,6 +476,8 @@ class JitRegion(Logger):
                 scanned = self._scan_body(body, invariant, leaves,
                                           n_steps)
                 return tuple(scanned)
+
+            self._named(chunk_fn, f"chunk{n_steps}")
 
             fn = self._persisted_program(("chunk", n_steps) + key,
                                          chunk_fn, leaves, donate=True)
@@ -584,6 +609,8 @@ class JitRegion(Logger):
                 merged = self._scan_body(accum_body, invariant, leaves,
                                          n_micro - 1)
                 return apply_body(*merged)
+
+            self._named(accum_fn, f"accum{n_micro}")
 
             # the persisted key hashes the jaxpr of the FULL composed
             # accum+apply function — the accum body alone is blind to

@@ -26,6 +26,7 @@ from typing import TYPE_CHECKING
 import numpy as np
 
 from znicz_tpu.observe import metrics as _metrics
+from znicz_tpu.observe import tracing as _tracing
 
 if TYPE_CHECKING:  # pragma: no cover
     from znicz_tpu.backends import Device
@@ -179,9 +180,25 @@ class Vector:
             raise ValueError(f"Vector '{self.name}': map_read on empty buffer")
         if self._state == _State.DEVICE:
             assert self._device is not None
-            self._mem = self._device.get(self._devmem)
+            self._mem = self._read_back()
             _count_transfer("d2h", self._mem.nbytes)
             self._state = _State.SYNCED
+
+    def _read_back(self) -> np.ndarray:
+        """The blocking device→host read: the host waits here for
+        every step dispatched before it, so it is a span
+        (``host_read:<name>``), a count and a sum of waited seconds —
+        a 12-byte read costs a whole step of waiting and no bytes
+        worth counting."""
+        if not _metrics.enabled():
+            return self._device.get(self._devmem)
+        with _tracing.TRACER.span(
+                f"host_read:{self.name}", cat="transfer",
+                bytes=int(self._devmem.nbytes)) as span:
+            mem = self._device.get(self._devmem)
+        _metrics.host_reads().inc()
+        _metrics.host_read_wait_seconds().inc(span.dur_us / 1e6)
+        return mem
 
     def map_write(self) -> None:
         """Make the host copy current and mark it authoritative."""

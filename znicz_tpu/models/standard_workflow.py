@@ -26,6 +26,7 @@ The hot chain is backend-dependent — the TPU-first core of the design:
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Sequence
 
 import numpy as np
@@ -544,9 +545,10 @@ class StandardWorkflow(AcceleratedWorkflow):
         import time as _time
         self.run_started_at = _time.time()
         self.stopped.value = False
-        chunks = 0
-        while not decision.complete and not self.stopped:
-            loader.run()  # host bookkeeping (+ schedule upload if stale)
+
+        def advance() -> int:
+            """The chunk's host bookkeeping; returns its step count."""
+            loader.run()  # (+ schedule upload if stale)
             cls = loader.minibatch_class
             k = 1
             while (k < steps_per_dispatch and not loader.epoch_ended
@@ -554,23 +556,52 @@ class StandardWorkflow(AcceleratedWorkflow):
                    and loader._schedule[loader._cursor][0] == cls):
                 loader.run()
                 k += 1
-            region.run_chunk(k)
-            if self.lr_adjuster is not None and cls == TRAIN:
-                # chunk-granular application of the per-step policy
-                self.lr_adjuster._n_iterations += k - 1
-                self.lr_adjuster.run()
-            decision.run()
-            if decision.epoch_ended or decision.complete:
-                for unit in side_units:
-                    if unit is self.lr_adjuster:
-                        continue  # handled above
-                    if not unit.gate_block and not unit.gate_skip:
-                        unit._fire()
-            chunks += 1
-            if self._max_fires is not None and chunks > self._max_fires:
-                raise RuntimeError(
-                    f"workflow '{self.name}' exceeded max_fires="
-                    f"{self._max_fires} chunks (runaway loop?)")
+            return k
+
+        chunks = 0
+        # the spans wf.run() records: the root, ONE fire of the loader
+        # per dispatch (not k), the decision's; chunk:<region> is
+        # run_chunk's own
+        with self._run_span():
+            while not decision.complete and not self.stopped:
+                k = loader._record_fire(advance)
+                region.run_chunk(k)
+                if (self.lr_adjuster is not None
+                        and loader.minibatch_class == TRAIN):
+                    # chunk-granular application of the per-step policy
+                    self.lr_adjuster._n_iterations += k - 1
+                    self.lr_adjuster.run()
+                decision._fire()
+                self._fire_epoch_side_units(side_units)
+                chunks += 1
+                if (self._max_fires is not None
+                        and chunks > self._max_fires):
+                    raise RuntimeError(
+                        f"workflow '{self.name}' exceeded max_fires="
+                        f"{self._max_fires} chunks (runaway loop?)")
+
+    def _advance_microbatches(self, n_micro: int) -> int:
+        """One optimizer step's host bookkeeping (+ schedule upload if
+        stale): a TRAIN step advances the index stream over all its
+        ``n_micro`` microbatches.  Returns the minibatch class."""
+        loader = self.loader
+        loader.run()
+        if loader.minibatch_class == TRAIN:
+            for _ in range(n_micro - 1):
+                loader.run()
+        return loader.minibatch_class
+
+    def _fire_epoch_side_units(self, side_units) -> None:
+        """The decision's epoch side chain, for the drivers that walk
+        the hot loop themselves."""
+        decision = self.decision
+        if not (decision.epoch_ended or decision.complete):
+            return
+        for unit in side_units:
+            if unit is self.lr_adjuster:
+                continue  # the drivers apply it per optimizer step
+            if not unit.gate_block and not unit.gate_skip:
+                unit._fire()
 
     def run_accumulated(self, microbatches: int | None = None) -> None:
         """Gradient-accumulation training driver (round 20): every
@@ -624,34 +655,29 @@ class StandardWorkflow(AcceleratedWorkflow):
         import time as _time
         self.run_started_at = _time.time()
         self.stopped.value = False
+
+        advance = functools.partial(self._advance_microbatches, n_micro)
         steps = 0
-        while not decision.complete and not self.stopped:
-            loader.run()  # host bookkeeping (+ schedule upload if stale)
-            cls = loader.minibatch_class
-            if cls == TRAIN:
-                for _ in range(n_micro - 1):
-                    loader.run()  # advance the index stream M−1 more
-                if guard is not None:
-                    guard.host_run()  # arm fault/SDC injections
-                region.run_accum(n_micro)
-                if self.lr_adjuster is not None:
-                    # ONE optimizer step happened, whatever M is
-                    self.lr_adjuster.run()
-            else:
-                region.run()
-            decision.run()
-            if decision.epoch_ended or decision.complete:
-                for unit in side_units:
-                    if unit is self.lr_adjuster:
-                        continue  # handled above
-                    if not unit.gate_block and not unit.gate_skip:
-                        unit._fire()
-            steps += 1
-            if self._max_fires is not None and steps > self._max_fires:
-                raise RuntimeError(
-                    f"workflow '{self.name}' exceeded max_fires="
-                    f"{self._max_fires} accumulated steps "
-                    f"(runaway loop?)")
+        with self._run_span():  # the spans wf.run() records
+            while not decision.complete and not self.stopped:
+                if loader._record_fire(advance) == TRAIN:
+                    if guard is not None:
+                        guard.host_run()  # arm fault/SDC injections
+                    region.run_accum(n_micro)
+                    if self.lr_adjuster is not None:
+                        # ONE optimizer step happened, whatever M is
+                        self.lr_adjuster.run()
+                else:
+                    region.run()
+                decision._fire()
+                self._fire_epoch_side_units(side_units)
+                steps += 1
+                if (self._max_fires is not None
+                        and steps > self._max_fires):
+                    raise RuntimeError(
+                        f"workflow '{self.name}' exceeded max_fires="
+                        f"{self._max_fires} accumulated steps "
+                        f"(runaway loop?)")
 
     def run_pipelined(self, n_stages: int,
                       microbatches: int | None = None,
@@ -701,32 +727,28 @@ class StandardWorkflow(AcceleratedWorkflow):
         import time as _time
         self.run_started_at = _time.time()
         self.stopped.value = False
+
+        advance = functools.partial(self._advance_microbatches, n_micro)
         steps = 0
-        while not decision.complete and not self.stopped:
-            loader.run()
-            cls = loader.minibatch_class
-            if cls == TRAIN:
-                for _ in range(n_micro - 1):
-                    loader.run()
-                if guard is not None:
-                    guard.host_run()
-                executor.run_step()
-                if self.lr_adjuster is not None:
-                    self.lr_adjuster.run()
-            else:
-                region.run()
-            decision.run()
-            if decision.epoch_ended or decision.complete:
-                for unit in side_units:
-                    if unit is self.lr_adjuster:
-                        continue
-                    if not unit.gate_block and not unit.gate_skip:
-                        unit._fire()
-            steps += 1
-            if self._max_fires is not None and steps > self._max_fires:
-                raise RuntimeError(
-                    f"workflow '{self.name}' exceeded max_fires="
-                    f"{self._max_fires} pipelined steps (runaway loop?)")
+        with self._run_span():  # the spans wf.run() records
+            while not decision.complete and not self.stopped:
+                if loader._record_fire(advance) == TRAIN:
+                    if guard is not None:
+                        guard.host_run()
+                    executor.run_step()
+                    if self.lr_adjuster is not None:
+                        self.lr_adjuster.run()
+                else:
+                    region.run()
+                decision._fire()
+                self._fire_epoch_side_units(side_units)
+                steps += 1
+                if (self._max_fires is not None
+                        and steps > self._max_fires):
+                    raise RuntimeError(
+                        f"workflow '{self.name}' exceeded max_fires="
+                        f"{self._max_fires} pipelined steps "
+                        f"(runaway loop?)")
 
     def build_shadow(self) -> "StandardWorkflow":
         """A numpy-backend clone for the SDC sentinel's

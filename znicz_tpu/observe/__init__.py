@@ -8,11 +8,15 @@ introspection — plotters and ``veles/web_status.py``):
   registry of counters/gauges/histograms with JSON and Prometheus
   text exposition.  ``WebStatusServer`` serves it at ``/metrics``.
 - :mod:`znicz_tpu.observe.tracing` — a host-side span tracer (unit
-  fires, epochs, compiles, serving dispatches) exporting
-  Chrome-trace/Perfetto JSON, served live at ``/trace.json`` and
-  merged with device traces by ``trace_top.py --spans``.
-- :func:`profile_window` — capture a ``jax.profiler`` device trace +
-  the window's host spans around any region.
+  fires, epochs, region dispatches, compiles, blocking device→host
+  reads, SDC votes, serving dispatches), every span naming its parent,
+  exporting Chrome-trace/Perfetto JSON, served live at
+  ``/trace.json``.  Its clock is ``perf_counter``: a span rides the
+  profiler's own clock (a ``TraceAnnotation``) only inside a
+  :func:`profile_window`; ``znbench/run.py`` shifts the ring onto a
+  device trace and ``znbench/trace_reduce.py`` is the reduction.
+- :func:`profile_window` — capture a ``jax.profiler`` device trace
+  (Python tracer off) + the window's host spans around any region.
 - :mod:`znicz_tpu.observe.recorder` (round 24) — the ops flight
   recorder: a bounded crash-safe JSONL journal of consequential ops
   events (swaps, canary verdicts, restarts, quarantines, breaker

@@ -430,6 +430,28 @@ def transfer_bytes(direction: str) -> Counter:
         labels=("direction",)).labels(direction=direction)
 
 
+def host_reads() -> Counter:
+    """Blocking device→host reads (``Vector.map_read`` of a
+    device-authoritative buffer): each makes the host wait for every
+    step dispatched before it, whatever its size.  Over
+    ``znicz_region_steps_total`` it is blocking reads per step: at
+    ≥ 1 the driver waits for every step (raise
+    ``engine.anomaly_check_interval``)."""
+    return REGISTRY.counter(
+        "znicz_host_reads_total",
+        "Blocking device-to-host reads through Vector.map_read").labels()
+
+
+def host_read_wait_seconds() -> Counter:
+    """Seconds the host spent inside those reads — the sum of the
+    ``host_read:<vector>`` spans' durations, mostly time waiting for
+    the device to finish what was dispatched."""
+    return REGISTRY.counter(
+        "znicz_host_read_wait_seconds",
+        "Seconds blocked in device-to-host reads (Vector.map_read)"
+    ).labels()
+
+
 def input_wait_seconds(loader: str) -> Histogram:
     """Host time a training step spent BLOCKED on the input pipeline
     (prefetch miss, empty prefetch queue).  A fully hidden input plane

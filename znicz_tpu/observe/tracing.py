@@ -1,29 +1,40 @@
 """Host-side span tracer: Dapper-style spans over the control plane.
 
-The training engine's device time is already observable through
-``jax.profiler`` traces (summarized by ``benchmarks/trace_top.py``);
-what was missing is the HOST half — which unit, epoch, or serving
-request the device lanes were working for.  This module records
-host-side spans (unit fires, workflow runs, epochs, serving batch
-dispatches, compiles) into a bounded ring buffer and exports them as
-Chrome-trace/Perfetto JSON (``ph: "X"`` complete events), so
-``chrome://tracing`` / Perfetto can show them, ``WebStatusServer``
-serves them live at ``/trace.json``, and ``trace_top.py --spans``
-merges them with a device-trace summary.
+The device's time is observable through ``jax.profiler`` traces
+(reduced by ``znbench/trace_reduce.py``: busy time as a union of
+intervals, self time per operation, idle gaps); this module records the
+HOST half — which unit, epoch, blocking read or serving request the
+host was in while the device worked or waited.  Spans (unit fires,
+workflow runs, epochs, region dispatches, compiles, blocking
+device→host reads, SDC votes, serving phases) go into ONE bounded ring
+buffer on ``perf_counter`` and are exported as Chrome-trace/Perfetto
+JSON (``ph: "X"`` complete events): ``chrome://tracing`` / Perfetto
+show them, ``WebStatusServer`` serves them live at ``/trace.json``.
 
-Correlation with XLA device lanes: inside every span the tracer also
-enters ``jax.profiler.TraceAnnotation`` (a TraceMe), so when a
-``jax.profiler`` trace window is open the SAME span appears on the
-profiler's host thread lane, lined up against the device lanes — one
-timeline, two sources.  (``jax.named_scope`` is the tracing-time
-cousin: the jit-region builder enters it per member unit so device-op
-names carry unit attribution — see
+Every span names the span that caused it: ``args`` carries
+``span_id`` (unique in the process), ``parent_span_id`` (the span
+open on the same thread when this one began, 0 at the root) and
+``depth``.  A span's self time is its duration minus its children's.
+Request spans (``cat="request"``) keep ids of their own, scoped by
+``trace_id`` (see :class:`RequestTrace`).
+
+Lining spans up with the device: the ring's clock is not the
+profiler's.  Only while a :func:`profile_window` device trace is open
+does a span also enter a ``jax.profiler.TraceAnnotation`` of the same
+name, which puts a copy on the profiler's host lane; the benchmark
+instead shifts the ring's spans onto the profiler's clock by the
+offset of one annotation both clocks saw (``znbench/run.py``
+``host_spans_on_trace_clock``; ``tests/test_observe_spans.py`` pins
+the two copies to within 200 µs).  With no trace open a span costs
+two clock reads and one ring append.  (``jax.named_scope`` is the
+tracing-time cousin: the jit-region builder enters it per member unit
+so device-op metadata carries the unit — see
 ``JitRegion.build_callable``.)
 
 :func:`profile_window` is the capture helper: a context manager that
-opens a ``jax.profiler`` trace around any region (N training steps, a
-bench's timed loop) and drops the window's host spans beside it as
-``host_spans.trace.json`` — every committed BENCH row can carry both.
+opens a ``jax.profiler`` trace (Python tracer off) around any region
+and drops the window's host spans beside it as
+``host_spans.trace.json``.
 
 All recording is gated on :func:`znicz_tpu.observe.metrics.enabled`
 (``root.common.engine.telemetry``); a disabled tracer costs one dict
@@ -70,11 +81,17 @@ def _trace_annotation(name: str):
         return None
 
 
+#: process-unique span ids (0 is "no parent"); ``next`` on a count is
+#: atomic under the GIL
+_SPAN_SEQ = itertools.count(1)
+
+
 class _NullSpan:
     """The span handed out when telemetry is off — a shared, stateless
     no-op context manager."""
 
     __slots__ = ()
+    dur_us = 0.0
 
     def __enter__(self):
         return self
@@ -91,7 +108,7 @@ class _Span:
     ``@contextmanager`` is measurable at decode-step cadence)."""
 
     __slots__ = ("_tracer", "_name", "_cat", "_args", "_ann", "_t0",
-                 "_depth")
+                 "_depth", "_id", "_parent", "dur_us")
 
     def __init__(self, tracer, name, cat, args) -> None:
         self._tracer = tracer
@@ -102,7 +119,9 @@ class _Span:
     def __enter__(self):
         stack = self._tracer._stack()
         self._depth = len(stack)
-        stack.append(self._name)
+        self._parent = stack[-1] if stack else 0
+        self._id = next(_SPAN_SEQ)
+        stack.append(self._id)
         self._ann = (_trace_annotation(self._name)
                      if _DEVICE_TRACE_OPEN else None)
         if self._ann is not None:
@@ -115,12 +134,17 @@ class _Span:
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
         self._tracer._stack().pop()
+        #: readable after the with-body: the counter beside a span
+        #: sums the very duration the span recorded
+        self.dur_us = t1 - self._t0
         self._tracer._append({
             "ph": "X", "name": self._name, "cat": self._cat,
             "pid": self._tracer._pid,
             "tid": threading.get_native_id(),
-            "ts": self._t0, "dur": t1 - self._t0,
-            "args": {**self._args, "depth": self._depth}})
+            "ts": self._t0, "dur": self.dur_us,
+            "args": {**self._args, "depth": self._depth,
+                     "span_id": self._id,
+                     "parent_span_id": self._parent}})
         return False
 
 
@@ -164,7 +188,9 @@ class SpanTracer:
     # ------------------------------------------------------------------
     def span(self, name: str, cat: str = "host", **args):
         """Record a span around the with-body.  Nesting is tracked per
-        thread (the ``depth`` arg on the event); while a
+        thread: the event's ``args`` carry ``span_id``, the
+        ``parent_span_id`` of the span open on this thread when it
+        began (0 at the root) and ``depth``; while a
         :func:`profile_window` device trace is open a
         ``jax.profiler.TraceAnnotation`` rides the span so the
         captured device trace carries it on its host lane.  This is
@@ -179,9 +205,15 @@ class SpanTracer:
     def complete(self, name: str, t0_us: float, t1_us: float,
                  cat: str = "host", **args) -> None:
         """Record a retroactive span from explicit timestamps (epoch
-        boundaries are only known at the END of the epoch)."""
+        boundaries are only known at the END of the epoch).  It began
+        before whatever is open now, so it is a root (parent 0) unless
+        the caller passes ids of its own, as :class:`RequestTrace`
+        does."""
         if not _metrics.enabled():
             return
+        if "span_id" not in args:
+            args = {**args, "span_id": next(_SPAN_SEQ),
+                    "parent_span_id": 0}
         self._append({
             "ph": "X", "name": name, "cat": cat,
             "pid": self._pid, "tid": threading.get_native_id(),
@@ -361,10 +393,12 @@ def profile_window(outdir: str, n_steps: int | None = None,
     """Capture a ``jax.profiler`` device trace plus the window's host
     spans around the with-body.
 
-    ``outdir`` receives the profiler's trace directory (the usual
-    ``*.trace.json.gz`` tree ``trace_top.py`` reads) and
+    ``outdir`` receives the profiler's trace directory (the
+    ``.xplane.pb`` that ``znbench/trace_reduce.py`` reads) and
     ``host_spans.trace.json`` (Chrome-trace JSON of the host spans
-    recorded during the window — feed it to ``trace_top.py --spans``).
+    recorded during the window).  The profiler runs with its Python
+    tracer off: a per-call tracer on a host-bound loop measures
+    itself.
     ``n_steps`` is recorded on the window span so per-step math in the
     post-processors has its divisor.  ``device=False`` skips the jax
     profiler (host spans only — cheap enough for always-on use); with
@@ -383,7 +417,9 @@ def profile_window(outdir: str, n_steps: int | None = None,
     global _DEVICE_TRACE_OPEN
     if device:
         import jax
-        jax.profiler.start_trace(outdir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(outdir, profiler_options=options)
         _DEVICE_TRACE_OPEN = True
     mark = tracer.mark()
     try:

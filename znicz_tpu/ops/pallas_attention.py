@@ -259,6 +259,7 @@ def _fwd_call(q, k, v, q_off, k_off, causal, bq, bk, interpret, pack):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="znicz_flash_fwd",
     )(q_off, k_off, q, k, v)
 
 
@@ -391,6 +392,7 @@ def _bwd_call(q, k, v, lse, do, delta4, q_off, k_off, causal, bq, bk,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="znicz_flash_dq",
     )(q_off, k_off, q, k, v, do, lse, delta)
     # dk/dv: Q blocks innermost; the q-side specs index by the LAST
     # grid dim now, the k-side by dim 2
@@ -413,6 +415,7 @@ def _bwd_call(q, k, v, lse, do, delta4, q_off, k_off, causal, bq, bk,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="znicz_flash_dkv",
     )(q_off, k_off, q, k, v, do, lse, delta)
     return dq, dk, dv
 

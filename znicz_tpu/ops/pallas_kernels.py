@@ -124,9 +124,9 @@ def _lrn_bwd_kernel(x_ref, err_ref, o_ref, *, alpha, beta, k, n):
                 * _window_sum(t, n, n - 1 - n // 2))
 
 
-def _row_tiled_call(kernel, out_like, *inputs, interpret=False):
+def _row_tiled_call(kernel, out_like, *inputs, name, interpret=False):
     """Run an elementwise-rows kernel over (M, C) arrays on a 1-D row
-    grid."""
+    grid; ``name`` is what a profile calls the kernel."""
     m, c = out_like.shape
     tile = min(_TILE_ROWS, m)
     spec = pl.BlockSpec((tile, c), lambda i: (i, 0))
@@ -137,6 +137,7 @@ def _row_tiled_call(kernel, out_like, *inputs, interpret=False):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((m, c), out_like.dtype),
         interpret=interpret,
+        name=name,
     )(*inputs)
 
 
@@ -172,6 +173,7 @@ def dropout_apply(x, seed, drop_ratio: float, interpret: bool = False):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((m, c), x.dtype),
         interpret=interpret,
+        name="znicz_dropout",
     )(jnp.asarray(seed, jnp.int32).reshape(1), x2d)
     return out.reshape(shape)
 
@@ -303,6 +305,7 @@ def layer_norm_forward(x, gamma, beta, eps: float,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((m, d), x.dtype),
         interpret=interpret,
+        name="znicz_layer_norm_fwd",
     )(x2d, gamma.reshape(1, d).astype(jnp.float32),
       jnp.broadcast_to(beta, (1, d)).astype(jnp.float32))
     return out.reshape(shape)
@@ -377,6 +380,7 @@ def layer_norm_backward(x, err, gamma, eps: float,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="znicz_layer_norm_bwd",
     )(x2d, e2d, gamma.reshape(1, d).astype(jnp.float32))
     gb = out[2][0] if with_beta else None
     return out[0].reshape(shape), out[1][0], gb
@@ -411,6 +415,7 @@ def softmax_argmax(v, interpret: bool = False):
         out_shape=(jax.ShapeDtypeStruct((m, c), v.dtype),
                    jax.ShapeDtypeStruct((m, 1), jnp.int32)),
         interpret=interpret,
+        name="znicz_softmax_argmax",
     )(v)
     return probs, idx[:, 0]
 
@@ -422,7 +427,7 @@ def lrn_forward(x, alpha: float, beta: float, k: float, n: int,
     x2d = x.reshape(-1, shape[-1])
     kernel = functools.partial(_lrn_fwd_kernel, alpha=alpha, beta=beta,
                                k=k, n=n)
-    return _row_tiled_call(kernel, x2d, x2d,
+    return _row_tiled_call(kernel, x2d, x2d, name="znicz_lrn_fwd",
                            interpret=interpret).reshape(shape)
 
 
@@ -435,4 +440,5 @@ def lrn_backward(x, err_output, alpha: float, beta: float, k: float,
     kernel = functools.partial(_lrn_bwd_kernel, alpha=alpha, beta=beta,
                                k=k, n=n)
     return _row_tiled_call(kernel, x2d, x2d, err2d,
+                           name="znicz_lrn_bwd",
                            interpret=interpret).reshape(shape)

@@ -82,6 +82,7 @@ import os
 import numpy as np
 
 from znicz_tpu.observe import metrics as _metrics
+from znicz_tpu.observe import tracing as _tracing
 from znicz_tpu.resilience import faults as _faults
 from znicz_tpu.utils.config import root
 from znicz_tpu.utils.logger import Logger
@@ -268,13 +269,19 @@ class IntegritySentinel(Logger):
         self._tick += 1
         if self.audit_interval > 0:
             if self._pending_audit_state is not None:
-                self._run_audit()
+                with _tracing.TRACER.span("sdc_audit", cat="resilience",
+                                          tick=self._tick):
+                    self._run_audit()
             elif (self._tick + 1) % self.audit_interval == 0:
                 # the NEXT step is the audit target: capture its
                 # pre-state now (we are at the boundary before it)
                 self._capture_audit_state()
         if self.vote_interval > 0 and self._tick % self.vote_interval == 0:
-            self._vote()
+            # the vote reads every parameter back (a host_read span
+            # each) and fingerprints it on the host: the device idles
+            with _tracing.TRACER.span("sdc_vote", cat="resilience",
+                                      tick=self._tick):
+                self._vote()
 
     # ------------------------------------------------------------------
     # cross-replica vote

@@ -228,30 +228,48 @@ class Unit(Logger):
             else:
                 setattr(self, name, val)
 
+    #: a constant of the class, not a knob for unit authors:
+    #: ``TrivialUnit`` alone clears it, because its fire does nothing
+    #: (counted and timed still, but no span and no histogram sample)
+    TRACE_FIRES = True
+
     # engine hook — called by the workflow scheduler
     def _fire(self) -> None:
+        self._record_fire(self.run)
+
+    def _record_fire(self, body):
+        """One fire of this unit around ``body()``; returns what it
+        returns.  The scheduler's ``_fire`` and the drivers that call
+        units outside it (``run_chunked`` and its kin: one fire of the
+        loader around a whole chunk's bookkeeping) record the same
+        thing through here."""
         start = time.perf_counter()
-        if _metrics.enabled():
+        if self.TRACE_FIRES and _metrics.enabled():
             # telemetry on: the fire becomes a host span (lined up
             # with XLA device lanes when a profiler window is open)
             # and a sample in the per-unit run-time histogram
             with _tracing.TRACER.span(self.name, cat="unit",
                                       kind=type(self).__name__):
-                self.run()
+                result = body()
             elapsed = time.perf_counter() - start
             _metrics.unit_run_seconds(self.name).observe(elapsed)
         else:
-            self.run()
+            result = body()
             elapsed = time.perf_counter() - start
         self.run_time_total += elapsed
         self.run_count += 1
+        return result
 
     def __repr__(self) -> str:
         return f"<{type(self).__name__} '{self.name}'>"
 
 
 class TrivialUnit(Unit):
-    """A no-op unit (useful as a join/fan-out point)."""
+    """A no-op unit (useful as a join/fan-out point).  Its fires
+    record no span: a ``Repeater`` fires once per step and does
+    nothing."""
+
+    TRACE_FIRES = False
 
     def initialize(self, **kwargs) -> None:
         super().initialize(**kwargs)

@@ -111,8 +111,7 @@ class Workflow(Container):
         queue: deque[Unit] = deque([self.start_point])
         self.start_point.reset_links()
         fires = 0
-        with _tracing.TRACER.span(f"workflow:{self.name}",
-                                  cat="workflow"):
+        with self._run_span():
             while queue and not self._finished and not self.stopped:
                 unit = queue.popleft()
                 if unit.gate_block:
@@ -131,6 +130,13 @@ class Workflow(Container):
                         f"workflow '{self.name}' exceeded max_fires="
                         f"{self._max_fires} (runaway loop?)")
         self.on_workflow_finished()
+
+    def _run_span(self):
+        """The root span every driver of this workflow opens around
+        its loop (``run`` here; ``run_chunked`` and its kin in
+        ``StandardWorkflow``)."""
+        return _tracing.TRACER.span(f"workflow:{self.name}",
+                                    cat="workflow")
 
     def on_end_point(self) -> None:
         self._finished = True
