@@ -28,8 +28,10 @@ Sequence-parallel arm: ``SEQ_RING=<n>`` shards T over an (n_model=n)
 ring mesh; the ring hops fold through the flash kernel
 (``ring_fold="pallas"`` in the row) unless ``SEQ_RING_FOLD=0`` forces
 the scan fold — the committed A/B for the round-6 kernel-native ring.
-``SEQ_HEAD_PACK=1`` and ``SEQ_CBLOCK=<n|auto>`` are the head-packing
-and causal-block levers (PERF.md round 6 cont.).
+``SEQ_HEAD_PACK=1`` is the head-packing lever (PERF.md round 6 cont.).
+The causal tile schedule has no lever: the kernels derive it from the
+shapes (``pallas_attention.sub_tile_for``; PERF.md §6, PR 24) and the
+row records what they resolved (``sub_tile``, ``executed_share``).
 """
 
 from __future__ import annotations
@@ -81,9 +83,6 @@ RING_FOLD = os.environ.get("SEQ_RING_FOLD", "") != "0"
 #: SEQ_HEAD_PACK=1: pack head pairs into 128-lane kernel tiles
 #: (engine.flash_head_pack — the dh=64 half-MXU lever, PERF.md)
 HEAD_PACK = os.environ.get("SEQ_HEAD_PACK", "0") != "0"
-#: SEQ_CBLOCK=<n|auto>: causal block override/auto-pick
-#: (engine.flash_causal_block — the small-T causal grid-depth lever)
-CBLOCK = os.environ.get("SEQ_CBLOCK", "")
 #: SEQ_INTERPRET=1: run the Pallas kernels in interpret mode (CPU
 #: recording of the multi-device arm; meaningless on a real chip)
 INTERPRET = os.environ.get("SEQ_INTERPRET", "0") != "0"
@@ -169,9 +168,6 @@ def main() -> None:
         RING_FOLD and "auto" or False
     if HEAD_PACK:
         root.common.engine.flash_head_pack = True
-    if CBLOCK:
-        root.common.engine.flash_causal_block = \
-            CBLOCK if CBLOCK == "auto" else int(CBLOCK)
     if INTERPRET:
         root.common.engine.pallas_interpret = True
     prng.seed_all(11)
@@ -249,9 +245,11 @@ def main() -> None:
         "ring_fold": getattr(attn_unit, "_ring_fold", None),
         "head_pack": max(getattr(attn_unit, "_flash_pack", 1),
                          getattr(attn_unit, "_ring_pack", 1)),
-        "causal_block": (attn_unit._flash_block_k
-                         if attn_unit._flash_pallas and CAUSAL
-                         else None),
+        # the causal tile schedule the kernels derived: compute
+        # sub-tile inside the grid tile, share of T × T executed
+        "sub_tile": getattr(attn_unit, "_flash_sub_tile", None),
+        "executed_share": (getattr(attn_unit, "_flash_tiles", None)
+                           or {}).get("executed_share"),
         "pallas_ln": bool(getattr(ln_unit, "_pallas_ln", False)),
         "interpret": INTERPRET,
         "step_time_ms": round(dt * 1e3, 3),

@@ -92,6 +92,7 @@ def test_canonical_families_render_in_exposition():
         m.fleet_replicas("cov", "lm").set(2),
         m.fleet_tenant_tokens("cov", "tenant").set(8.0),
         m.fleet_traffic_weight("cov", "lm", "v2").set(0.25),
+        m.flash_tiles("cov_attention", "interior").set(28),
         m.loader_pipeline_restarts("cov").inc(),
         m.phase_p99_seconds("cov#0", "decode").set(0.002),
         m.prefix_tokens("cov#0", "hit").inc(4),

@@ -20,26 +20,33 @@ def _rand(shape, seed):
                        .normal(0, 1, shape).astype(np.float32))
 
 
+#: (grid tile, compute sub-tile): ``None`` lets the chooser decide
+#: (sub-tile = tile at these sizes); the explicit shapes put interior,
+#: crossing and skipped sub-tiles inside ONE 128-wide grid tile
+SCHEDULES = [((128, 128), None), ((256, 128), None), ((128, 256), None),
+             ((128, 128), (32, 32)), ((128, 128), (64, 32)),
+             ((256, 128), (32, 64))]
+
+
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("blocks", [(128, 128), (256, 128), (128, 256)])
-def test_flash_matches_oracle_fwd_and_grads(causal, blocks):
+@pytest.mark.parametrize("blocks,sub", SCHEDULES)
+def test_flash_matches_oracle_fwd_and_grads(causal, blocks, sub):
     b, t, h, d = 2, 256, 4, 64
     q, k, v = (_rand((b, t, h, d), s) for s in (0, 1, 2))
     dy = _rand((b, t, h, d), 3)
     bq, bk = blocks
+    kw = dict(causal=causal, block_q=bq, block_k=bk, interpret=True,
+              sub_tile=sub)
 
     ref = local_attention(q, k, v, causal=causal)
-    out = flash_attention(q, k, v, causal=causal, block_q=bq,
-                          block_k=bk, interpret=True)
+    out = flash_attention(q, k, v, **kw)
     np.testing.assert_allclose(out, ref, atol=2e-5)
 
     g_ref = jax.grad(
         lambda *a: jnp.vdot(local_attention(*a, causal=causal), dy),
         argnums=(0, 1, 2))(q, k, v)
     g_new = jax.grad(
-        lambda *a: jnp.vdot(flash_attention(
-            *a, causal=causal, block_q=bq, block_k=bk,
-            interpret=True), dy),
+        lambda *a: jnp.vdot(flash_attention(*a, **kw), dy),
         argnums=(0, 1, 2))(q, k, v)
     for name, a, b_ in zip("qkv", g_ref, g_new):
         np.testing.assert_allclose(b_, a, atol=5e-5,
@@ -96,7 +103,15 @@ def test_offsets_place_the_causal_diagonal_globally():
     np.testing.assert_allclose(out, ref, atol=2e-5)
 
 
-def test_offsets_diagonal_mid_tile_and_masked_rows_fwd_and_grads():
+#: (grid block, compute sub-tile) for the offset geometries: 16² tiles
+#: as before, and 32² tiles walked in 8² and 16 × 8 sub-tiles so the
+#: offset diagonal splits a tile into all three classes
+OFFSET_SCHEDULES = [(16, None), (32, (8, 8)), (32, (16, 8))]
+
+
+@pytest.mark.parametrize("block,sub", OFFSET_SCHEDULES)
+def test_offsets_diagonal_mid_tile_and_masked_rows_fwd_and_grads(block,
+                                                                 sub):
     """The hard offset geometry: q rows 8…71 vs k cols 40…103 — the
     diagonal crosses mid-tile AND rows 8…39 are FULLY masked (no
     visible key in this hop at all).  Masked rows must come out
@@ -105,11 +120,11 @@ def test_offsets_diagonal_mid_tile_and_masked_rows_fwd_and_grads():
     b, t, h, d = 2, 64, 2, 16
     q, k, v = (_rand((b, t, h, d), s) for s in (3, 4, 5))
     q_off, k_off = 8, 40
+    kw = dict(causal=True, block_q=block, block_k=block, interpret=True,
+              q_offset=q_off, k_offset=k_off, sub_tile=sub)
     vis = (q_off + np.arange(t)) >= k_off
     ref = _offset_oracle(q, k, v, q_off, k_off)
-    out = flash_attention(q, k, v, causal=True, block_q=16,
-                          block_k=16, interpret=True, q_offset=q_off,
-                          k_offset=k_off)
+    out = flash_attention(q, k, v, **kw)
     np.testing.assert_allclose(np.asarray(out)[:, vis],
                                np.asarray(ref)[:, vis], atol=2e-5)
     assert np.all(np.asarray(out)[:, ~vis] == 0.0)
@@ -122,13 +137,44 @@ def test_offsets_diagonal_mid_tile_and_masked_rows_fwd_and_grads():
         lambda *a: jnp.vdot(_offset_oracle(*a, q_off, k_off), dy),
         argnums=(0, 1, 2))(q, k, v)
     g_new = jax.grad(
-        lambda *a: jnp.vdot(flash_attention(
-            *a, causal=True, block_q=16, block_k=16, interpret=True,
-            q_offset=q_off, k_offset=k_off), dy),
+        lambda *a: jnp.vdot(flash_attention(*a, **kw), dy),
         argnums=(0, 1, 2))(q, k, v)
     for name, a, b_ in zip("qkv", g_ref, g_new):
         np.testing.assert_allclose(np.asarray(b_), np.asarray(a),
                                    atol=5e-5, err_msg=f"grad d{name}")
+
+
+@pytest.mark.parametrize("block,sub", OFFSET_SCHEDULES)
+def test_offsets_whole_hop_below_and_above_the_diagonal(block, sub):
+    """The two ring hops that hold no diagonal.  Wholly BELOW it
+    (q rows 64…127 vs k cols 0…63) every sub-tile is interior: the
+    unmasked body alone must reproduce plain non-causal attention,
+    forward and gradients.  Wholly ABOVE it (q rows 0…63 vs k cols
+    64…127) nothing is visited: output exactly 0, gradients exactly 0,
+    nothing NaN."""
+    b, t, h, d = 2, 64, 2, 16
+    q, k, v = (_rand((b, t, h, d), s) for s in (13, 14, 15))
+    dy = _rand((b, t, h, d), 16)
+    kw = dict(causal=True, block_q=block, block_k=block, interpret=True,
+              sub_tile=sub)
+    below = dict(kw, q_offset=64, k_offset=0)
+    np.testing.assert_allclose(flash_attention(q, k, v, **below),
+                               local_attention(q, k, v), atol=2e-5)
+    g_ref = jax.grad(lambda *a: jnp.vdot(local_attention(*a), dy),
+                     argnums=(0, 1, 2))(q, k, v)
+    g_new = jax.grad(
+        lambda *a: jnp.vdot(flash_attention(*a, **below), dy),
+        argnums=(0, 1, 2))(q, k, v)
+    for name, a, b_ in zip("qkv", g_ref, g_new):
+        np.testing.assert_allclose(np.asarray(b_), np.asarray(a),
+                                   atol=5e-5, err_msg=f"grad d{name}")
+    above = dict(kw, q_offset=0, k_offset=64)
+    assert np.all(np.asarray(flash_attention(q, k, v, **above)) == 0.0)
+    g_new = jax.grad(
+        lambda *a: jnp.vdot(flash_attention(*a, **above), dy),
+        argnums=(0, 1, 2))(q, k, v)
+    for g in g_new:
+        assert np.all(np.asarray(g) == 0.0)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -166,15 +212,129 @@ def test_resolve_head_pack_rules():
     assert resolve_head_pack(True, 8, 4) == 1       # lane-illegal dh
 
 
-def test_causal_block_autopick_deepens_small_t_grids():
-    from znicz_tpu.ops.pallas_attention import causal_block_for
-    # T=2048 at 1024² is a 2×2 grid (one skippable tile) → 512
-    assert causal_block_for(2048, 1024, 1024) == (512, 512)
-    assert causal_block_for(4096, 1024, 1024) == (1024, 1024)
-    # already deep grids keep the chip-swept default
-    assert causal_block_for(16384, 1024, 1024) == (1024, 1024)
-    # the floor: never below 256
-    assert causal_block_for(512, 1024, 1024) == (256, 256)
+def _brute_counts(t_q, t_k, sq, sk, q_off, k_off):
+    """Classify every sub-tile from the boolean (t_q, t_k) mask."""
+    mask = (q_off + np.arange(t_q)[:, None]) \
+        >= (k_off + np.arange(t_k)[None, :])
+    tiles = mask.reshape(t_q // sq, sq, t_k // sk, sk)
+    seen = tiles.sum(axis=(1, 3))
+    return {"interior": int((seen == sq * sk).sum()),
+            "crossing": int(((seen > 0) & (seen < sq * sk)).sum()),
+            "skipped": int((seen == 0).sum())}
+
+
+@pytest.mark.parametrize("t_q,t_k,bq,bk,sq,sk,q_off,k_off", [
+    (2048, 2048, 1024, 1024, 256, 256, 0, 0),      # the LM cell's shape
+    (2048, 2048, 1024, 1024, 512, 512, 0, 0),
+    (2048, 2048, 1024, 1024, 1024, 1024, 0, 0),    # what ran before
+    (512, 512, 512, 512, 128, 256, 0, 0),
+    (256, 256, 128, 128, 64, 32, 0, 0),
+    (1024, 1024, 512, 512, 256, 256, 2048, 1024),  # ring hop, below
+    (1024, 1024, 512, 512, 256, 256, 1024, 1024),  # ring hop, diagonal
+    (64, 64, 32, 32, 16, 8, 8, 40),                # mid-tile offsets
+    (1024, 1024, 1024, 1024, 256, 256, 0, 1024),   # ring hop, above
+])
+def test_causal_tile_counts_match_the_boolean_mask(t_q, t_k, bq, bk, sq,
+                                                   sk, q_off, k_off):
+    from znicz_tpu.ops.pallas_attention import causal_tile_counts
+    got = causal_tile_counts(t_q, t_k, bq, bk, sq, sk, q_off, k_off)
+    want = _brute_counts(t_q, t_k, sq, sk, q_off, k_off)
+    share = got.pop("executed_share")
+    assert got == want
+    total = (t_q // sq) * (t_k // sk)
+    assert share == (want["interior"] + want["crossing"]) / total
+
+
+def test_causal_tile_counts_of_the_lm_cell():
+    """T 2048 at 1024² grid tiles: sub-tiles of 256 give 28 interior,
+    8 crossing, 28 skipped of 64 (0.5625 of the square); of 512, 6 + 4
+    of 16 (0.625); the tile itself, 1 + 2 of 4 (0.75: what ran before
+    the walk)."""
+    from znicz_tpu.ops.pallas_attention import causal_tile_counts
+    assert causal_tile_counts(2048, 2048, 1024, 1024, 256, 256) == {
+        "interior": 28, "crossing": 8, "skipped": 28,
+        "executed_share": 0.5625}
+    assert causal_tile_counts(2048, 2048, 1024, 1024, 512, 512)[
+        "executed_share"] == 0.625
+    assert causal_tile_counts(2048, 2048, 1024, 1024, 1024, 1024)[
+        "executed_share"] == 0.75
+    with pytest.raises(ValueError):
+        causal_tile_counts(2048, 2048, 1024, 1024, 384, 256)
+
+
+@pytest.mark.parametrize("sq,sk", [(8, 8), (16, 8), (8, 16), (32, 32)])
+def test_walk_bounds_classify_like_the_definition(sq, sk):
+    """The loop bounds the kernels compute from scalars (first row −
+    first column of the grid tile) name exactly the sub-tiles the
+    definition does: interior = first row ≥ last column, skipped =
+    last row < first column, crossing = the rest — for every offset,
+    negative ones (hops above the diagonal) included."""
+    from znicz_tpu.ops.pallas_attention import (_col_walk_bounds,
+                                                _row_walk_bounds)
+    bq = bk = 32
+    for d in range(-80, 81):            # row0 + r - col0
+        n_int, n_vis = (int(x) for x in
+                        _row_walk_bounds(d, sq, sk, bk))
+        for j in range(bk // sk):
+            c = j * sk                  # this sub-tile: rows d…, cols c…
+            interior, skipped = d >= c + sk - 1, d + sq - 1 < c
+            assert (j < n_int) == interior, (d, j)
+            assert (j >= n_vis) == skipped, (d, j)
+        i_vis, i_int = (int(x) for x in
+                        _col_walk_bounds(d, sq, sk, bq))   # col0+c-row0
+        for i in range(bq // sq):
+            r = i * sq                  # rows r…, cols d…
+            interior, skipped = r >= d + sk - 1, r + sq - 1 < d
+            assert (i >= i_int) == interior, (d, i)
+            assert (i < i_vis) == skipped, (d, i)
+
+
+def test_tile_schedule_is_derived_from_the_shapes():
+    """The chooser that replaced ``causal_block_for`` and
+    ``engine.flash_causal_block``: grid tile and compute sub-tile come
+    from ``causal``, T and the caller's blocks alone.  Same rows as the
+    old auto-pick test (T 512, 2048, 4096, 16384); dh and the head pack
+    do not enter (the kernels compile for a described v5e at dh 64, at
+    dh 128 and at pack 2 under the same rule; PERF.md §6, PR 24)."""
+    from znicz_tpu.ops.pallas_attention import (causal_tile_counts,
+                                                grid_blocks,
+                                                sub_tile_for)
+
+    def schedule(causal, t, **blocks):
+        bq, bk = grid_blocks(causal, t, t, **blocks)
+        return (bq, bk), sub_tile_for(causal, bq, bk)
+
+    def share(t):
+        (bq, bk), (sq, sk) = schedule(True, t)
+        return causal_tile_counts(t, t, bq, bk, sq, sk)[
+            "executed_share"]
+
+    # T=2048, the LM cell: the K tile spans the sequence, 512² inside
+    assert schedule(True, 2048) == ((1024, 2048), (512, 512))
+    assert share(2048) == 0.625                 # 0.75 before the walk
+    # small T: the tile is the sequence; at 512 nothing is left to cut
+    assert schedule(True, 512) == ((512, 512), (512, 512))
+    assert share(512) == 1.0
+    assert schedule(True, 1024) == ((1024, 1024), (512, 512))
+    assert share(1024) == 0.75
+    # deep grids: 2048-long K tiles, the share falls towards a half
+    assert schedule(True, 4096) == ((1024, 2048), (512, 512))
+    assert share(4096) == 0.5625
+    assert schedule(True, 16384) == ((1024, 2048), (512, 512))
+    assert share(16384) == 0.515625
+    # a T that 2048 does not tile keeps the 1024 K tile
+    assert schedule(True, 3072) == ((1024, 1024), (512, 512))
+    # non-causal: one body per 1024² tile, nothing skipped
+    assert schedule(False, 2048) == ((1024, 1024), (1024, 1024))
+    # a caller's own blocks are kept; the sub-tile shrinks where one
+    # visit's score run would outgrow the scoped VMEM
+    assert schedule(True, 2048, block_k=512) == ((1024, 512), (512, 512))
+    assert schedule(True, 2048, block_q=2048) \
+        == ((2048, 2048), (512, 256))
+    # tiles too small to cut (the interpret-mode tests) stay whole
+    assert schedule(True, 256, block_q=128, block_k=128) \
+        == ((128, 128), (128, 128))
+    assert sub_tile_for(True, 16, 16) == (16, 16)
 
 
 def test_unit_engages_flash_only_on_tpu(monkeypatch):

@@ -69,11 +69,21 @@ def test_kernel_fold_equals_scan_fold_and_oracle(causal, n_shards):
 
 
 @pytest.mark.slow
-def test_kernel_fold_diagonal_mid_hop_tiles():
+@pytest.mark.parametrize("sub", [None, (4, 4), (8, 4)])
+def test_kernel_fold_diagonal_mid_hop_tiles(sub, monkeypatch):
     """Kernel tiles SMALLER than the per-device shard: the causal
     diagonal crosses inside the local hop's tile grid (partial tiles)
     while remote hops run at pure offset geometry — the q_offset /
-    k_offset case the scan fold gets for free."""
+    k_offset case the scan fold gets for free.  With ``sub`` the
+    kernels walk compute sub-tiles inside each 8² tile (the chooser is
+    steered here, in the test: the ring has no parameter for it), so
+    the local hop's diagonal tiles hold interior, crossing and skipped
+    sub-tiles, the hops below the diagonal only interior ones and the
+    hops above it none."""
+    from znicz_tpu.ops import pallas_attention
+    if sub is not None:
+        monkeypatch.setattr(pallas_attention, "sub_tile_for",
+                            lambda causal, *a: sub)
     mesh = make_seq_mesh(4)
     B, T, H, D = 1, 64, 2, 8           # t_local 16, tiles 8×8
     q, k, v = (_rand((B, T, H, D), s) for s in (4, 5, 6))

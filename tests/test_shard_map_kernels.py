@@ -326,26 +326,51 @@ def test_head_pack_gate(monkeypatch):
     assert unit._flash_pack == 1
 
 
-def test_causal_block_gate(monkeypatch):
-    """engine.flash_causal_block: "auto" deepens the causal grid via
-    causal_block_for, an int forces the block, default keeps the
-    chip-swept 1024 (the sweep's measurement hook)."""
+def test_causal_schedule_resolves_from_shapes_not_options(monkeypatch,
+                                                          caplog):
+    """The unit resolves its causal tile schedule from T and
+    ``causal`` alone (pallas_attention.sub_tile_for): the grid blocks
+    are the chooser's (1024 × 2048 at T 2048), the kernels walk compute
+    sub-tiles inside them, and no engine option changes either — the retired
+    ``engine.flash_causal_block`` included.  The split of the T × T
+    square is in the info line and in ``znicz_flash_tiles``."""
+    import logging
+
+    from znicz_tpu.observe import metrics as obs_metrics
+    from znicz_tpu.ops import pallas_attention as pa
     _fake_tpu(monkeypatch)
-    # T=2048: the row the sweep targets (initialize never dispatches
-    # the kernel, so the big T costs nothing here)
-    unit = _attention_unit(XLADevice(), t=2048, causal=True)
-    assert unit._flash_block_q == 1024       # chip-swept default
-    root.common.engine.flash_causal_block = "auto"
-    unit = _attention_unit(XLADevice(), t=2048, causal=True)
-    assert unit._flash_block_q == 512        # 2048//512 = 4-deep grid
-    assert unit._flash_pallas                # still kernel-legal
+    # T=2048: the LM cell's row (initialize never dispatches the
+    # kernel, so the big T costs nothing here)
+    with caplog.at_level(logging.INFO):
+        unit = _attention_unit(XLADevice(), t=2048, causal=True)
+    assert unit._flash_pallas
+    assert (unit._flash_block_q, unit._flash_block_k) == (1024, 2048)
+    sub = unit._flash_sub_tile
+    assert sub == pa.sub_tile_for(True, 1024, 2048) == (512, 512)
+    tiles = unit._flash_tiles
+    assert tiles == pa.causal_tile_counts(2048, 2048, 1024, 2048, *sub)
+    assert tiles["executed_share"] <= 0.625
+    assert f"{tiles['executed_share']:.4f} of T×T executed" in caplog.text
+    for cls in ("interior", "crossing", "skipped"):
+        assert obs_metrics.flash_tiles(unit.name, cls).value \
+            == tiles[cls]
+    assert 'znicz_flash_tiles{unit="%s",class="skipped"}' % unit.name \
+        in obs_metrics.REGISTRY.to_prometheus()
+    # an option of that name steers nothing any more
     root.common.engine.flash_causal_block = 256
-    unit = _attention_unit(XLADevice(), t=2048, causal=True)
-    assert unit._flash_block_q == 256
-    # non-causal units never touch the causal block lever
-    root.common.engine.flash_causal_block = "auto"
+    again = _attention_unit(XLADevice(), t=2048, causal=True)
+    assert (again._flash_block_q, again._flash_block_k) == (1024, 2048)
+    assert again._flash_sub_tile == sub
+    # non-causal units keep the single-body tile and the whole square
     unit = _attention_unit(XLADevice(), t=2048)
-    assert unit._flash_block_q == 1024
+    assert unit._flash_sub_tile == (1024, 1024)
+    assert unit._flash_tiles["executed_share"] == 1.0
+    assert unit._flash_tiles["skipped"] == 0
+    # off the kernel path there is no schedule to report
+    monkeypatch.setattr(pallas_kernels, "is_tpu_device",
+                        lambda device: False)
+    unit = _attention_unit(XLADevice(), t=2048, causal=True)
+    assert not unit._flash_pallas and unit._flash_tiles is None
 
 
 def _ln_unit(device, shape=(8, 16), model_shard_dim=None):

@@ -542,6 +542,20 @@ def grad_accum_microbatches(workflow: str) -> Gauge:
         labels=("workflow",)).labels(workflow=workflow)
 
 
+def flash_tiles(unit: str, cls: str) -> Gauge:
+    """How an attention unit's flash kernels split the T × T score
+    square into compute sub-tiles (``class`` = ``interior``: computed
+    without a mask, ``crossing``: computed under the causal mask,
+    ``skipped``: above the diagonal, never visited).  Static per
+    program, set once at ``initialize``: (interior + crossing) ÷ the
+    sum is the share of T × T this model's attention computes."""
+    return REGISTRY.gauge(
+        "znicz_flash_tiles",
+        "Flash-attention compute sub-tiles of the T x T square, by "
+        "class (interior, crossing, skipped)",
+        labels=("unit", "class")).labels(**{"unit": unit, "class": cls})
+
+
 def snapshot_seconds(op: str) -> Histogram:
     return REGISTRY.histogram(
         "znicz_snapshot_seconds",

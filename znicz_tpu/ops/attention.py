@@ -214,21 +214,18 @@ class MultiHeadAttention(Forward):
                     pallas_fold=bool(rflag), head_pack=head_pack)
             self._ring_pack = (head_pack
                                if self._ring_fold == "pallas" else 1)
-        bq = min(pallas_attention.BLOCK_Q, t)
-        bk = min(self.flash_block_k or pallas_attention.BLOCK_K, t)
-        if self.causal and not self._ring_active:
-            # causal block auto-pick (round 6, verdict item 3): at
-            # small T the default 1024² tiles leave a 2×2 grid with
-            # one skippable tile, so causal paid non-causal step time.
-            # ``engine.flash_causal_block``: "auto" = deepen the grid
-            # to ≥4 K-tiles (causal_block_for), int = force that
-            # block.  Default OFF until the chip A/B lands (no chip in
-            # this container — the SEQ_CBLOCK bench arm is the hook).
-            cblk = root.common.engine.get("flash_causal_block", None)
-            if cblk == "auto":
-                bq, bk = pallas_attention.causal_block_for(t, bq, bk)
-            elif cblk and t % int(cblk) == 0:
-                bq = bk = min(int(cblk), t)
+        # the kernels' tile schedule comes from the shapes and
+        # ``causal`` alone (pallas_attention.grid_blocks /
+        # sub_tile_for; PERF.md §6, PR 24): the grid tile is the unit
+        # of DMA — for a causal unit its K side spans up to 2048 keys,
+        # so at T ≤ 2048 a row block meets its whole key range in one
+        # grid step — and inside it the kernels walk compute sub-tiles,
+        # skip those above the diagonal and mask only the run the
+        # diagonal can cross.  No engine option steers it
+        # (``engine.flash_causal_block`` shrank the GRID tile instead:
+        # 1.39 × slower at 512, 2.8 × at 256 on the chip, and is gone).
+        bq, bk = pallas_attention.grid_blocks(
+            self.causal, t, t, None, self.flash_block_k)
         self._flash_pack = head_pack
         self._flash_block_q, self._flash_block_k = bq, bk
         self._flash_interpret = interpret
@@ -268,12 +265,32 @@ class MultiHeadAttention(Forward):
             else:
                 self._flash_mesh, self._flash_spec = mesh, spec
         self._flash_pallas = local and refused is None
+        #: the compute sub-tile the kernels walk inside a (bq, bk)
+        #: grid tile, and how the T × T square splits over it
+        self._flash_sub_tile = None
+        self._flash_tiles = None
+        if self._flash_pallas:
+            sq, sk = pallas_attention.sub_tile_for(self.causal, bq, bk)
+            self._flash_sub_tile = (sq, sk)
+            self._flash_tiles = pallas_attention.causal_tile_counts(
+                t, t, bq, bk, sq, sk, causal=self.causal)
+            from znicz_tpu.observe import metrics as obs_metrics
+            for cls in ("interior", "crossing", "skipped"):
+                obs_metrics.flash_tiles(self.name, cls).set(
+                    self._flash_tiles[cls])
         if self._ring_active:
             self.info("%s: ring attention over '%s', %s fold",
                       self.name, self._ring_axis, self._ring_fold)
         elif self._flash_pallas:
-            self.info("%s: flash kernel, blocks (%d, %d), head pack "
-                      "%d%s%s", self.name, bq, bk, head_pack,
+            tiles = self._flash_tiles
+            self.info("%s: flash kernel, blocks (%d, %d), sub-tiles "
+                      "(%d, %d): %d interior + %d crossing of %d = "
+                      "%.4f of T×T executed, head pack %d%s%s",
+                      self.name, bq, bk, *self._flash_sub_tile,
+                      tiles["interior"], tiles["crossing"],
+                      tiles["interior"] + tiles["crossing"]
+                      + tiles["skipped"], tiles["executed_share"],
+                      head_pack,
                       ", per shard under shard_map"
                       if self._flash_mesh is not None else "",
                       ", INTERPRETED" if interpret else "")
@@ -332,11 +349,9 @@ class MultiHeadAttention(Forward):
             # round 5.)
             o = pallas_attention.flash_attention(
                 q, k, v, causal=self.causal,
-                block_q=getattr(self, "_flash_block_q",
-                                pallas_attention.BLOCK_Q),
+                block_q=getattr(self, "_flash_block_q", None),
                 block_k=getattr(self, "_flash_block_k",
-                                self.flash_block_k
-                                or pallas_attention.BLOCK_K),
+                                self.flash_block_k),
                 dot_dtype=dot_dtype,
                 interpret=getattr(self, "_flash_interpret", False),
                 mesh=getattr(self, "_flash_mesh", None),

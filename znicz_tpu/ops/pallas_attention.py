@@ -29,15 +29,31 @@ Design (the standard flash decomposition, implemented TPU-first):
   ``preferred_element_type``; softmax statistics, lse, delta and all
   accumulators are f32 — the same bf16-inputs/f32-accumulation
   convention as the rest of the repo.
-- **causal**: global-position mask inside the tile (exact across
-  block boundaries — same rule as ``ring_attention._visibility``);
-  fully-masked tiles are skipped via ``pl.when``, so causal runs at
-  ~2× effective rate.
+- **causal**: a two-level tile schedule (PR 24).  The GRID tile
+  (bq, bk) is the unit of DMA, of the ``BlockSpec``s, of the VMEM
+  accumulators and of the whole-tile ``pl.when`` skip; inside it each
+  kernel walks COMPUTE sub-tiles (sq, sk) (`_walk`), classified from
+  scalars only: above the diagonal — in no visit, so they cost neither
+  MXU nor VPU work; interior (first row ≥ last column) — computed with
+  no mask code at all; and the short run the diagonal can cross —
+  masked by global position (exact across block boundaries, the rule
+  of ``ring_attention._visibility``).  A row block (fwd, dq) or column
+  block (dk/dv) is ONE visit over all its visible sub-tiles, so the
+  online-softmax statistics are read, rescaled and written once per
+  visit — on the chip that, not the skipped work, is most of what the
+  forward gains.  Shapes are static: the visit's extent is picked by
+  ``pl.when`` among the few a tile allows.  Share of the T × T square
+  executed at the chooser's tiles (`grid_blocks`, `sub_tile_for`,
+  `causal_tile_counts`): T 512 1.0, T 1024 0.75, T 2048 0.625, T 4096
+  0.5625, T 16384 0.516 — one body per 1024² tile executed 0.75 at
+  T 2048, not the half "causal at ~2× rate" promised.  Non-causal
+  calls keep one unmasked body per tile.
 - **global offsets** (round 6, the ring-fold composition): every
   kernel takes ``q_offset``/``k_offset`` scalars (SMEM) placing this
   call's q rows / k cols on the GLOBAL sequence axis, so one kernel
   invocation can be a single ring hop — the `pl.when` tile-skip then
-  skips whole hops that sit entirely above the causal diagonal.
+  skips whole hops that sit entirely above the causal diagonal, and
+  the sub-tile walk takes its bounds from the same scalars.
   Offsets are traced values (the ring derives them from
   ``axis_index``), which is why they ride SMEM instead of being
   Python constants.  With offsets, a hop can contain FULLY-MASKED
@@ -79,12 +95,32 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
-#: default tile sizes — chip-swept (PERF.md round 5): 1024×1024 beats
-#: 512×512 by ~1.2× (fewer grid revisits of the VMEM stats; the f32
-#: score tile is 4 MB); 2048-wide tiles overflow VMEM and fail to
-#: compile, so callers wanting other shapes pass block_q/block_k
+#: default GRID tile sizes — the unit of DMA, of the BlockSpecs and of
+#: the VMEM accumulators.  Chip-swept with one body per tile (PERF.md
+#: round 5): 1024×1024 beats 512×512 by 1.2–1.75× per kernel (PERF.md
+#: §6, PR 24: every grid step pays its prologue, its DMA waits and the
+#: statistics' read-modify-write), and a 2048² score tile overflows
+#: the 16 MB scoped VMEM.  Non-causal calls still run one body per
+#: tile, so they keep these.
 BLOCK_Q = 1024
 BLOCK_K = 1024
+#: causal calls walk compute sub-tiles inside the grid tile (`_walk`),
+#: so their score tile no longer grows with it: the K-side tile is as
+#: long as this where T allows — at T ≤ 2048 one grid step then holds
+#: a row block's whole key range and its softmax state is updated once
+#: (PERF.md §6, PR 24: fwd 3.99 → 3.23, dq 4.50 → 3.00, dk/dv 5.64 →
+#: 4.22 ms per call at T 2048, dh 64, on a v5e)
+CAUSAL_BLOCK_K = 2048
+#: compute sub-tile edge inside a causal grid tile (chip-swept, PR 24:
+#: 512² beats 256² in fwd and dk/dv, ties it in dq) …
+_SUB_TILE = 512
+#: … shrunk where one visit's f32 score run would outgrow what Mosaic
+#: fits beside its copies in the scoped VMEM: a row block visits up to
+#: sq × bk scores, a column block (dk/dv) up to bq × sk (compile-checked
+#: for a described v5e: 512 × 2048 and 1024 × 512 fit, 1024 × 2048 and
+#: 2048 × 512 do not)
+_ROW_VISIT_ELEMS = 512 * 2048
+_COL_VISIT_ELEMS = 1024 * 512
 #: lane width for the per-row statistics arrays (lse, delta): the
 #: minimum tile-legal last dim — the value is replicated across lanes
 #: (with head packing, each sub-head owns one _LANES-wide lane group)
@@ -94,14 +130,12 @@ _LANES = 8
 _STAT_LANES = 128
 
 
-def _causal_mask(iq, ik, bq: int, bk: int, q_off, k_off):
-    """(bq, bk) visibility tile from GLOBAL positions (rows
-    q_off + iq·bq…, cols k_off + ik·bk…).  Offsets are traced int32
-    scalars (0 outside the ring path)."""
-    rows = q_off + iq * bq \
-        + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    cols = k_off + ik * bk \
-        + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+def _causal_mask(row0, col0, sq: int, sk: int):
+    """(sq, sk) visibility of one sub-tile from GLOBAL positions: its
+    first row sits at ``row0``, its first column at ``col0`` (traced
+    int32 scalars: offset + grid tile + sub-tile)."""
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
     return rows >= cols
 
 
@@ -144,32 +178,194 @@ def resolve_head_pack(flag, n_heads: int, dh: int) -> int:
     return 1
 
 
-def causal_block_for(t: int, default_bq: int, default_bk: int,
-                     min_block: int = 256):
-    """Auto-pick causal blocks from grid depth (round-6 sweep,
-    verdict item 3): at T=2048 the default 1024² tiles give a 2×2
-    grid with ONE skippable tile, so causal ran at non-causal step
-    time (MFU 0.167 vs 0.253).  Shrink blocks until the K-grid is at
-    least 4 deep (≥ ~half the tiles skippable), floored at
-    ``min_block`` (smaller tiles trade MXU efficiency for skip
-    depth — the DMA/revisit floor the round-5 block sweep measured).
-    Returns (block_q, block_k)."""
-    bq, bk = min(default_bq, t), min(default_bk, t)
-    while bk > min_block and t // bk < 4 and t % (bk // 2) == 0:
-        bk //= 2
-    while bq > min_block and t // bq < 4 and t % (bq // 2) == 0:
-        bq //= 2
-    return bq, bk
+# ----------------------------------------------------------------------
+# the causal tile schedule: compute sub-tiles inside a grid tile
+# ----------------------------------------------------------------------
+def grid_blocks(causal: bool, t_q: int, t_k: int, block_q=None,
+                block_k=None) -> tuple:
+    """Grid tile (bq, bk) of a call: the caller's blocks where it names
+    them, else the defaults — for a causal call the K side as long as
+    ``CAUSAL_BLOCK_K`` where that still tiles T."""
+    bq = min(block_q or BLOCK_Q, t_q)
+    if block_k:
+        return bq, min(block_k, t_k)
+    if causal and t_k % CAUSAL_BLOCK_K == 0:
+        return bq, CAUSAL_BLOCK_K
+    return bq, min(BLOCK_K, t_k)
+
+
+def sub_tile_for(causal: bool, bq: int, bk: int) -> tuple:
+    """Compute sub-tile (sq, sk) the three kernels walk inside one
+    (bq, bk) grid tile — derived from what the call can see, never an
+    option.  Non-causal calls keep the single body (sub-tile = tile:
+    nothing to skip, nothing to leave unmasked).  Causal calls take the
+    largest lane-legal edge ≤ ``_SUB_TILE`` that divides the tile and
+    keeps one visit's score run inside the scoped VMEM; tiles too small
+    to cut (the interpret-mode tests) stay whole."""
+    if not causal:
+        return bq, bk
+    return (_sub_edge(bq, min(_SUB_TILE, _ROW_VISIT_ELEMS // bk)),
+            _sub_edge(bk, min(_SUB_TILE, _COL_VISIT_ELEMS // bq)))
+
+
+def _sub_edge(block: int, want: int) -> int:
+    """The largest of ``want``, ``want``/2, … (≥ 128, the lane width)
+    that divides ``block``; ``block`` itself where none does."""
+    size = 128
+    while size * 2 <= want:
+        size *= 2
+    while size >= 128:
+        if block % size == 0:
+            return size
+        size //= 2
+    return block
+
+
+def causal_tile_counts(t_q: int, t_k: int, bq: int, bk: int, sq: int,
+                       sk: int, q_off: int = 0, k_off: int = 0,
+                       causal: bool = True) -> dict:
+    """How a causal call's (t_q, t_k) rectangle splits into compute
+    sub-tiles: ``interior`` (wholly on or under the diagonal),
+    ``crossing`` (the diagonal passes through), ``skipped`` (wholly
+    above: never computed, whether inside a visited grid tile or in a
+    grid tile the ``pl.when`` drops), and ``executed_share`` =
+    (interior + crossing) ÷ all.  The classes are geometry: the kernels
+    mask the run of ≤ 2 sub-tiles per block the diagonal CAN cross, so
+    with aligned offsets one interior neighbour shares the masked run.
+    ``causal=False`` counts the whole rectangle as interior.  Static
+    per program: the attention unit reports it at ``initialize`` (info
+    line, ``znicz_flash_tiles``)."""
+    if t_q % bq or t_k % bk or bq % sq or bk % sk:
+        raise ValueError(f"({t_q}, {t_k}) / ({bq}, {bk}) / ({sq}, {sk})"
+                         f" do not tile")
+    counts = {"interior": 0, "crossing": 0, "skipped": 0}
+    for r0 in range(q_off, q_off + t_q, sq):
+        for c0 in range(k_off, k_off + t_k, sk):
+            if causal and r0 + sq - 1 < c0:
+                counts["skipped"] += 1
+            elif not causal or r0 >= c0 + sk - 1:
+                counts["interior"] += 1
+            else:
+                counts["crossing"] += 1
+    total = (t_q // sq) * (t_k // sk)
+    counts["executed_share"] = \
+        (counts["interior"] + counts["crossing"]) / total
+    return counts
+
+
+def _row_walk_bounds(d, sq: int, sk: int, bk: int):
+    """For the sub-tile row block whose first row lies ``d`` positions
+    after the grid tile's first column: column sub-tiles [0, n_int) are
+    interior, [n_int, n_vis) cross the diagonal, the rest lie above it.
+    Numerators are clipped to ≥ 0 first, so ``//`` never sees a
+    negative traced value."""
+    n_int = jnp.clip(d + 1, 0, bk) // sk
+    n_vis = jnp.clip(d + sq - 1 + sk, 0, bk) // sk
+    return n_int, n_vis
+
+
+def _col_walk_bounds(e, sq: int, sk: int, bq: int):
+    """For the sub-tile column block whose first column lies ``e``
+    positions after the grid tile's first row: row sub-tiles
+    [0, i_vis) lie above the diagonal, [i_vis, i_int) cross it,
+    [i_int, bq // sq) are interior."""
+    i_vis = jnp.clip(e, 0, bq) // sq
+    i_int = jnp.clip(e + sk - 1 + sq - 1, 0, bq) // sq
+    return i_vis, i_int
+
+
+def _ds(start, size: int):
+    """Slice of a ref's sublane dim; traced starts carry the alignment
+    hint Mosaic needs."""
+    if isinstance(start, int):
+        return pl.ds(start, size)
+    return pl.ds(pl.multiple_of(start, size), size)
+
+
+def _walk(body, causal: bool, row0, col0, bq: int, bk: int, sq: int,
+          sk: int, cols_outer: bool = False):
+    """Visit the compute sub-tiles of one grid tile whose first row /
+    column sit at global (row0, col0).
+
+    The tile is cut into row blocks of ``sq`` (or, ``cols_outer``,
+    column blocks of ``sk``: dk/dv accumulate per column block).  Each
+    block is ONE visit, ``body(start, parts)``: ``start`` is the
+    block's offset inside the tile (a traced scalar) and ``parts`` a
+    static list of ``(offset, size, masked)`` runs of sub-tiles along
+    the other axis — so whatever the body keeps per row (the online-
+    softmax state) is updated once per visit, not once per sub-tile.
+    Sub-tiles above the diagonal are in no part; interior ones form an
+    unmasked part (the body emits no mask code for it); the few the
+    diagonal can cross (``band``) form the masked part.  Which static
+    shape runs is picked from scalars alone, so traced ring-hop
+    offsets take the same code."""
+    n_r, n_c = bq // sq, bk // sk
+    if not causal:
+        inner = [(0, bq if cols_outer else bk, False)]
+        _loop(n_c if cols_outer else n_r,
+              lambda n: body(n * (sk if cols_outer else sq), inner))
+        return
+    # how many sub-tiles of one block the diagonal can cross
+    band = -(-(sq + sk - 1) // (sq if cols_outer else sk))
+
+    def col_block(j):
+        # the visible rows of a column block are a SUFFIX of the tile:
+        # its first ``band`` row blocks can cross, the rest are interior
+        c = j * sk
+        i_vis, i_int = _col_walk_bounds(col0 + c - row0, sq, sk, bq)
+        for h in range(1, n_r + 1):             # static heights
+            cut = min(h, band)
+            parts = _parts(n_r - h, cut, h - cut, sq, masked_first=True)
+            pl.when((i_vis == n_r - h) & (i_int > 0))(
+                functools.partial(body, c, parts))
+        pl.when(i_int == 0)(functools.partial(body, c, [(0, bq, False)]))
+
+    def row_block(i):
+        # the visible columns of a row block are a PREFIX of the tile:
+        # its last ``band`` sub-tiles can cross, the rest are interior
+        r = i * sq
+        n_int, n_vis = _row_walk_bounds(row0 + r - col0, sq, sk, bk)
+        for w in range(1, n_c + 1):             # static widths
+            cut = min(w, band)
+            parts = _parts(0, w - cut, cut, sk, masked_first=False)
+            pl.when((n_vis == w) & (n_int < n_c))(
+                functools.partial(body, r, parts))
+        pl.when(n_int == n_c)(functools.partial(body, r, [(0, bk, False)]))
+
+    if cols_outer:
+        _loop(n_c, col_block)
+    else:
+        _loop(n_r, row_block)
+
+
+def _parts(first: int, n_a: int, n_b: int, edge: int,
+           masked_first: bool) -> list:
+    """Two adjacent runs of ``n_a`` then ``n_b`` sub-tiles of ``edge``
+    starting at sub-tile ``first``, as ``(offset, size, masked)``; the
+    masked one is the first or the second, an empty run is left out."""
+    runs = [(first * edge, n_a * edge, masked_first),
+            ((first + n_a) * edge, n_b * edge, not masked_first)]
+    return [run for run in runs if run[1]]
+
+
+def _loop(n: int, visit):
+    """``visit(i)`` for i in [0, n): inline where there is one."""
+    if n == 1:
+        visit(0)
+    else:
+        jax.lax.fori_loop(0, n, lambda i, _: visit(i), None)
 
 
 # ----------------------------------------------------------------------
 # forward
 # ----------------------------------------------------------------------
 def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale, causal, bq, bk, pack):
+                m_scr, l_scr, acc_scr, *, scale, causal, bq, bk, sq, sk,
+                pack):
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
-    q_off, k_off = qoff_ref[0, 0], koff_ref[0, 0]
+    row0 = qoff_ref[0, 0] + iq * bq
+    col0 = koff_ref[0, 0] + ik * bk
 
     @pl.when(ik == 0)
     def _init():
@@ -177,42 +373,56 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    visible = True if not causal \
-        else q_off + iq * bq + bq - 1 >= k_off + ik * bk
-
-    @pl.when(visible)
-    def _fold():
-        mask = (_causal_mask(iq, ik, bq, bk, q_off, k_off)
-                if causal else None)
-        q_all, k_all, v_all = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]
+    def body(r, parts):
+        """ONE online-softmax update of rows [r, r+sq) by every column
+        run in ``parts``."""
+        rs = _ds(r, sq)
+        q_all = q_ref[0, 0, rs, :]
         d = q_all.shape[1]
         dh, sw = d // pack, _STAT_LANES // pack
-        m_all, l_all, acc_all = m_scr[...], l_scr[...], acc_scr[...]
+        masks = [_causal_mask(row0 + r, col0 + c, sq, n) if masked
+                 else None for c, n, masked in parts]
+        m_all, l_all, acc_all = m_scr[rs, :], l_scr[rs, :], acc_scr[rs, :]
         m_out, l_out, acc_out = [], [], []
         for p in range(pack):           # static: per-sub-head math
             fs = slice(p * dh, (p + 1) * dh)
-            s = _dot(q_all[:, fs], k_all[:, fs], trans_b=True) * scale
-            if causal:
-                s = jnp.where(mask, s, _NEG_INF)
-            m_prev = m_all[:, p * sw:p * sw + 1]        # (bq, 1)
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(s, axis=1, keepdims=True))
-            pt = jnp.exp(s - m_new)
-            if causal:
-                # offset hops can hold FULLY-masked rows (m stays
-                # -inf): exp(s - m) = exp(0) there without this guard
-                pt = jnp.where(mask, pt, 0.0)
-            corr = jnp.exp(m_prev - m_new)              # (bq, 1)
-            l_out.append(jnp.broadcast_to(
-                l_all[:, p * sw:p * sw + 1] * corr
-                + jnp.sum(pt, axis=1, keepdims=True), (bq, sw)))
-            acc_out.append(acc_all[:, fs] * corr
-                           + _dot(pt.astype(v_all.dtype),
-                                  v_all[:, fs]))
-            m_out.append(jnp.broadcast_to(m_new, (bq, sw)))
-        m_scr[...] = jnp.concatenate(m_out, axis=1)
-        l_scr[...] = jnp.concatenate(l_out, axis=1)
-        acc_scr[...] = jnp.concatenate(acc_out, axis=1)
+            q = q_all[:, fs]
+            m_prev = m_all[:, p * sw:p * sw + 1]        # (sq, 1)
+            scores, m_new = [], m_prev
+            for (c, n, _), mask in zip(parts, masks):
+                s = _dot(q, k_ref[0, 0, c:c + n, fs],
+                         trans_b=True) * scale
+                if mask is not None:
+                    s = jnp.where(mask, s, _NEG_INF)
+                scores.append(s)
+                m_new = jnp.maximum(
+                    m_new, jnp.max(s, axis=1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)              # (sq, 1)
+            l_new = l_all[:, p * sw:p * sw + 1] * corr
+            acc = acc_all[:, fs] * corr
+            for (c, n, _), mask, s in zip(parts, masks, scores):
+                pt = jnp.exp(s - m_new)
+                if mask is not None:
+                    # offset hops can hold FULLY-masked rows (m stays
+                    # -inf): exp(s - m) = exp(0) there without this
+                    # guard.  An interior run has no masked entry, so
+                    # its row maximum is finite: neither select
+                    pt = jnp.where(mask, pt, 0.0)
+                l_new = l_new + jnp.sum(pt, axis=1, keepdims=True)
+                v = v_ref[0, 0, c:c + n, fs]
+                acc = acc + _dot(pt.astype(v.dtype), v)
+            l_out.append(jnp.broadcast_to(l_new, (sq, sw)))
+            acc_out.append(acc)
+            m_out.append(jnp.broadcast_to(m_new, (sq, sw)))
+        m_scr[rs, :] = jnp.concatenate(m_out, axis=1)
+        l_scr[rs, :] = jnp.concatenate(l_out, axis=1)
+        acc_scr[rs, :] = jnp.concatenate(acc_out, axis=1)
+
+    visible = True if not causal else row0 + bq - 1 >= col0
+
+    @pl.when(visible)
+    def _fold():
+        _walk(body, causal, row0, col0, bq, bk, sq, sk)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -232,13 +442,22 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0, 0] = jnp.concatenate(lse_out, axis=1)
 
 
-def _fwd_call(q, k, v, q_off, k_off, causal, bq, bk, interpret, pack):
+# jitted: every layer of a model calls these with the same static
+# arguments and shapes, so the kernels are traced and lowered ONCE per
+# program, not once per layer (on the chip's host, lowering the LM
+# cell's 18 flash calls cost 7 s of every start, cached program or not;
+# PERF.md §6, PR 24)
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9, 10))
+def _fwd_call(q, k, v, q_off, k_off, causal, bq, bk, interpret, pack,
+              sub=None):
     b, h, t, d = q.shape
     tk = k.shape[2]
     nq, nk = t // bq, tk // bk
+    sq, sk = sub or sub_tile_for(causal, bq, bk)
     kernel = functools.partial(_fwd_kernel,
                                scale=1.0 / np.sqrt(d // pack),
-                               causal=causal, bq=bq, bk=bk, pack=pack)
+                               causal=causal, bq=bq, bk=bk, sq=sq,
+                               sk=sk, pack=pack)
     off_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     qspec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, iq, ik: (b_, h_, iq, 0))
     kspec = pl.BlockSpec((1, 1, bk, d), lambda b_, h_, iq, ik: (b_, h_, ik, 0))
@@ -267,9 +486,11 @@ def _fwd_call(q, k, v, q_off, k_off, causal, bq, bk, interpret, pack):
 # backward: dq kernel (K blocks innermost), dk/dv kernel (Q innermost)
 # ----------------------------------------------------------------------
 def _p_tile(q, k, lse_col, scale, mask):
-    """Recompute one sub-head's probability tile p = exp(s − lse) in
-    VMEM.  The mask select also guards fully-masked rows (offset
-    hops): there lse ≈ -1e30 and the unmasked exp overflows."""
+    """Recompute one sub-head's probability sub-tile p = exp(s − lse)
+    in VMEM.  Where the diagonal crosses (``mask`` given) the select
+    also guards fully-masked rows (offset hops): there lse ≈ -1e30 and
+    the unmasked exp overflows.  An interior sub-tile passes no mask:
+    every entry is visible and lse is finite."""
     s = _dot(q, k, trans_b=True) * scale
     if mask is not None:
         s = jnp.where(mask, s, _NEG_INF)
@@ -281,35 +502,44 @@ def _p_tile(q, k, lse_col, scale, mask):
 
 def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                lse_ref, delta_ref, dq_ref, dq_scr, *, scale, causal,
-               bq, bk, pack):
+               bq, bk, sq, sk, pack):
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
-    q_off, k_off = qoff_ref[0, 0], koff_ref[0, 0]
+    row0 = qoff_ref[0, 0] + iq * bq
+    col0 = koff_ref[0, 0] + ik * bk
 
     @pl.when(ik == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    visible = True if not causal \
-        else q_off + iq * bq + bq - 1 >= k_off + ik * bk
-
-    @pl.when(visible)
-    def _fold():
-        mask = (_causal_mask(iq, ik, bq, bk, q_off, k_off)
-                if causal else None)
-        q_all, k_all = q_ref[0, 0], k_ref[0, 0]
-        v_all, do_all = v_ref[0, 0], do_ref[0, 0]
+    def body(r, parts):
+        """dq of rows [r, r+sq) from every column run in ``parts``."""
+        rs = _ds(r, sq)
+        q_all, do_all = q_ref[0, 0, rs, :], do_ref[0, 0, rs, :]
+        lse, delta = lse_ref[0, 0, rs, :], delta_ref[0, 0, rs, :]
         dh = q_all.shape[1] // pack
-        parts = []
+        out = []
         for p in range(pack):
             fs = slice(p * dh, (p + 1) * dh)
             ls = slice(p * _LANES, p * _LANES + 1)
-            pt = _p_tile(q_all[:, fs], k_all[:, fs],
-                         lse_ref[0, 0][:, ls], scale, mask)
-            dp = _dot(do_all[:, fs], v_all[:, fs], trans_b=True)
-            ds = pt * (dp - delta_ref[0, 0][:, ls]) * scale
-            parts.append(_dot(ds.astype(k_all.dtype), k_all[:, fs]))
-        dq_scr[...] += jnp.concatenate(parts, axis=1)
+            acc = None
+            for c, n, masked in parts:
+                mask = (_causal_mask(row0 + r, col0 + c, sq, n)
+                        if masked else None)
+                k, v = k_ref[0, 0, c:c + n, fs], v_ref[0, 0, c:c + n, fs]
+                pt = _p_tile(q_all[:, fs], k, lse[:, ls], scale, mask)
+                dp = _dot(do_all[:, fs], v, trans_b=True)
+                ds = pt * (dp - delta[:, ls]) * scale
+                part = _dot(ds.astype(k.dtype), k)
+                acc = part if acc is None else acc + part
+            out.append(acc)
+        dq_scr[rs, :] += jnp.concatenate(out, axis=1)
+
+    visible = True if not causal else row0 + bq - 1 >= col0
+
+    @pl.when(visible)
+    def _fold():
+        _walk(body, causal, row0, col0, bq, bk, sq, sk)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -318,43 +548,52 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
 
 def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                 lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                scale, causal, bq, bk, pack):
+                scale, causal, bq, bk, sq, sk, pack):
     ik, iq = pl.program_id(2), pl.program_id(3)
     nq = pl.num_programs(3)
-    q_off, k_off = qoff_ref[0, 0], koff_ref[0, 0]
+    row0 = qoff_ref[0, 0] + iq * bq
+    col0 = koff_ref[0, 0] + ik * bk
 
     @pl.when(iq == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    visible = True if not causal \
-        else q_off + iq * bq + bq - 1 >= k_off + ik * bk
-
-    @pl.when(visible)
-    def _fold():
-        mask = (_causal_mask(iq, ik, bq, bk, q_off, k_off)
-                if causal else None)
-        q_all, k_all = q_ref[0, 0], k_ref[0, 0]
-        v_all, do_all = v_ref[0, 0], do_ref[0, 0]
-        dh = q_all.shape[1] // pack
-        dk_parts, dv_parts = [], []
+    def body(c, parts):
+        """dk, dv of columns [c, c+sk) from every row run in
+        ``parts``."""
+        cs = _ds(c, sk)
+        k_all, v_all = k_ref[0, 0, cs, :], v_ref[0, 0, cs, :]
+        dh = k_all.shape[1] // pack
+        dk_out, dv_out = [], []
         for p in range(pack):
             fs = slice(p * dh, (p + 1) * dh)
             ls = slice(p * _LANES, p * _LANES + 1)
-            pt = _p_tile(q_all[:, fs], k_all[:, fs],
-                         lse_ref[0, 0][:, ls], scale, mask)
-            do = do_all[:, fs]
-            # dv += pᵀ · do ; contract the q dim without
-            # materializing pᵀ
-            dv_parts.append(_dot(pt.astype(do.dtype), do,
-                                 trans_a=True))
-            dp = _dot(do, v_all[:, fs], trans_b=True)
-            ds = pt * (dp - delta_ref[0, 0][:, ls]) * scale
-            dk_parts.append(_dot(ds.astype(q_all.dtype),
-                                 q_all[:, fs], trans_a=True))
-        dk_scr[...] += jnp.concatenate(dk_parts, axis=1)
-        dv_scr[...] += jnp.concatenate(dv_parts, axis=1)
+            dk = dv = None
+            for r, n, masked in parts:
+                mask = (_causal_mask(row0 + r, col0 + c, n, sk)
+                        if masked else None)
+                q, do = q_ref[0, 0, r:r + n, fs], do_ref[0, 0, r:r + n, fs]
+                pt = _p_tile(q, k_all[:, fs],
+                             lse_ref[0, 0, r:r + n, ls], scale, mask)
+                # dv += pᵀ · do ; contract the q dim without
+                # materializing pᵀ
+                dv_part = _dot(pt.astype(do.dtype), do, trans_a=True)
+                dp = _dot(do, v_all[:, fs], trans_b=True)
+                ds = pt * (dp - delta_ref[0, 0, r:r + n, ls]) * scale
+                dk_part = _dot(ds.astype(q.dtype), q, trans_a=True)
+                dk = dk_part if dk is None else dk + dk_part
+                dv = dv_part if dv is None else dv + dv_part
+            dk_out.append(dk)
+            dv_out.append(dv)
+        dk_scr[cs, :] += jnp.concatenate(dk_out, axis=1)
+        dv_scr[cs, :] += jnp.concatenate(dv_out, axis=1)
+
+    visible = True if not causal else row0 + bq - 1 >= col0
+
+    @pl.when(visible)
+    def _fold():
+        _walk(body, causal, row0, col0, bq, bk, sq, sk, cols_outer=True)
 
     @pl.when(iq == nq - 1)
     def _finish():
@@ -362,14 +601,16 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11, 12, 13))
 def _bwd_call(q, k, v, lse, do, delta4, q_off, k_off, causal, bq, bk,
-              interpret, pack):
+              interpret, pack, sub=None):
     """``delta4``: (B, H, T, pack) f32 — rowsum(do·o) per SUB-head,
     already adjusted for any lse cotangent (the hop composition's
     extra term)."""
     b, h, t, d = q.shape
     tk = k.shape[2]
     nq, nk = t // bq, tk // bk
+    sq, sk = sub or sub_tile_for(causal, bq, bk)
     lanes = pack * _LANES
     # per-sub-head delta rides _LANES lanes each, like lse
     delta = jnp.repeat(delta4, _LANES, axis=-1)      # (B, H, T, lanes)
@@ -381,7 +622,7 @@ def _bwd_call(q, k, v, lse, do, delta4, q_off, k_off, causal, bq, bk,
                          lambda b_, h_, iq, ik: (b_, h_, iq, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, pack=pack),
+                          bq=bq, bk=bk, sq=sq, sk=sk, pack=pack),
         grid=(b, h, nq, nk),
         in_specs=[off_spec, off_spec, qspec, kspec, kspec, qspec,
                   rspec, rspec],
@@ -402,7 +643,7 @@ def _bwd_call(q, k, v, lse, do, delta4, q_off, k_off, causal, bq, bk,
                           lambda b_, h_, ik, iq: (b_, h_, iq, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, pack=pack),
+                          bq=bq, bk=bk, sq=sq, sk=sk, pack=pack),
         grid=(b, h, nk, nq),
         in_specs=[off_spec, off_spec, qspec2, kspec2, kspec2, qspec2,
                   rspec2, rspec2],
@@ -423,8 +664,10 @@ def _bwd_call(q, k, v, lse, do, delta4, q_off, k_off, causal, bq, bk,
 # ----------------------------------------------------------------------
 # custom_vjp hop (head-major) + the (B, T, H, D) public entry
 # ----------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash_hop(q, k, v, q_off, k_off, causal, bq, bk, interpret, pack):
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash_hop(q, k, v, q_off, k_off, causal, bq, bk, interpret, pack,
+               sub=None):
     """One flash pass over head-major (packed) operands at global
     positions (q_off, k_off) → (out, lse).  This is BOTH the plain
     single-call kernel (offsets 0, lse discarded) and the per-hop
@@ -432,16 +675,17 @@ def _flash_hop(q, k, v, q_off, k_off, causal, bq, bk, interpret, pack):
     the lse cotangent folds into delta in the backward, so one
     custom_vjp serves both."""
     return _fwd_call(q, k, v, q_off, k_off, causal, bq, bk, interpret,
-                     pack)
+                     pack, sub)
 
 
-def _hop_fwd(q, k, v, q_off, k_off, causal, bq, bk, interpret, pack):
+def _hop_fwd(q, k, v, q_off, k_off, causal, bq, bk, interpret, pack,
+             sub):
     out, lse = _fwd_call(q, k, v, q_off, k_off, causal, bq, bk,
-                         interpret, pack)
+                         interpret, pack, sub)
     return (out, lse), (q, k, v, out, lse, q_off, k_off)
 
 
-def _hop_bwd(causal, bq, bk, interpret, pack, res, cts):
+def _hop_bwd(causal, bq, bk, interpret, pack, sub, res, cts):
     q, k, v, out, lse, q_off, k_off = res
     do, dlse = cts
     do = do.astype(q.dtype)
@@ -456,7 +700,7 @@ def _hop_bwd(causal, bq, bk, interpret, pack, res, cts):
     delta4 = delta4 - dlse.astype(jnp.float32) \
         .reshape(b, h, t, pack, _LANES).sum(axis=-1)
     dq, dk, dv = _bwd_call(q, k, v, lse, do, delta4, q_off, k_off,
-                           causal, bq, bk, interpret, pack)
+                           causal, bq, bk, interpret, pack, sub)
     zero = np.zeros((1, 1), jax.dtypes.float0)
     return dq, dk, dv, zero, zero
 
@@ -466,14 +710,14 @@ _flash_hop.defvjp(_hop_fwd, _hop_bwd)
 
 def ring_hop(qh, kh, vh, q_offset, k_offset, causal: bool,
              block_q: int, block_k: int, interpret: bool = False,
-             pack: int = 1):
+             pack: int = 1, sub_tile=None):
     """One ring hop on head-major, already-packed operands
     (B, Hp, T, pack·dh): returns (out in qh.dtype, lse (B, Hp, T,
     pack) f32).  Offsets may be traced scalars (``axis_index``
     arithmetic under shard_map)."""
     out, lse = _flash_hop(qh, kh, vh, _off_arr(q_offset),
                           _off_arr(k_offset), causal, block_q,
-                          block_k, interpret, pack)
+                          block_k, interpret, pack, sub_tile)
     return out, lse[..., ::_LANES]
 
 
@@ -494,10 +738,11 @@ def unpack_heads(x, pack: int, n_heads: int):
 
 
 def flash_attention(q, k, v, causal: bool = False,
-                    block_q: int = BLOCK_Q, block_k: int = BLOCK_K,
+                    block_q: int | None = None,
+                    block_k: int | None = None,
                     dot_dtype=None, interpret: bool = False,
                     mesh=None, spec=None, q_offset=None, k_offset=None,
-                    head_pack: int = 1):
+                    head_pack: int = 1, sub_tile=None):
     """Fused flash attention: (B, T, H, D) → (B, T, H, D) f32.
 
     ``dot_dtype`` casts q/k/v (the tile-GEMM operand dtype — bf16 in
@@ -509,7 +754,12 @@ def flash_attention(q, k, v, causal: bool = False,
 
     ``q_offset``/``k_offset`` place this call on the GLOBAL sequence
     axis for causal masking (the ring-hop geometry; may be traced
-    scalars).  ``head_pack=2`` folds head pairs into 128-lane tiles
+    scalars).  ``block_q``/``block_k`` name the grid tile and
+    ``sub_tile`` the compute sub-tile inside it; left out, both come
+    from the shapes (:func:`grid_blocks`, :func:`sub_tile_for`) —
+    ``sub_tile`` is there for the tests, which put all three classes of
+    sub-tile into tiles small enough to interpret.  ``head_pack=2``
+    folds head pairs into 128-lane tiles
     (see the module docstring) — exact per-head math, resolved by the
     unit gate via :func:`resolve_head_pack`.
 
@@ -532,7 +782,7 @@ def flash_attention(q, k, v, causal: bool = False,
     if pack > 1 and h % pack:
         raise ValueError(f"head_pack {pack} does not divide "
                          f"{h} heads")
-    bq, bk = min(block_q, t), min(block_k, tk)
+    bq, bk = grid_blocks(causal, t, tk, block_q, block_k)
     if t % bq or tk % bk:
         raise ValueError(f"T {t}/{tk} not divisible by blocks "
                          f"({bq}, {bk})")
@@ -557,12 +807,12 @@ def flash_attention(q, k, v, causal: bool = False,
         fn = jax.shard_map(
             lambda a, b_, c: _flash_hop(
                 a, b_, c, _off_arr(None), _off_arr(None), causal, bq,
-                bk, interpret, pack)[0],
+                bk, interpret, pack, sub_tile)[0],
             mesh=mesh, in_specs=(hspec, hspec, hspec), out_specs=hspec,
             check_vma=False)
         out = fn(qh, kh, vh)
     else:
         out = _flash_hop(qh, kh, vh, _off_arr(q_offset),
                          _off_arr(k_offset), causal, bq, bk,
-                         interpret, pack)[0]
+                         interpret, pack, sub_tile)[0]
     return unpack_heads(out, pack, h).astype(jnp.float32)
