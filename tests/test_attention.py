@@ -390,3 +390,198 @@ def test_pe_attention_trains_on_positional_task():
     wf.initialize(device=XLADevice())
     wf.run()
     assert wf.decision.min_validation_n_err_pt <= 25.0
+
+
+# ----------------------------------------------------------------------
+# the pre-norm residual block: rope, qk_norm, pre_norm, residual (PR 25)
+# ----------------------------------------------------------------------
+BLOCK_OPTIONS = {
+    "rope": {"rope": {"theta": 10000}},
+    "qk_norm": {"qk_norm": "rms"},
+    "pre_norm": {"pre_norm": "rms"},
+    "residual": {"residual": True},
+    "all": {"rope": {"theta": 10000}, "qk_norm": "rms",
+            "pre_norm": "rms", "residual": True, "include_bias": False},
+}
+BLOCK_D, BLOCK_H = 16, 2          # an even head size for the rotation
+GAINS = ("gain_norm", "gain_q", "gain_k")
+
+
+def build_block(device, x, options, params=None):
+    """An attention unit with its GD pair; ``params`` (attr → array)
+    overrides the drawn parameters, gains included."""
+    prng.seed_all(5)
+    wf = DummyWorkflow()
+    src = DummyUnit(wf, output=Vector(np.asarray(x), name="x"))
+    fwd = attention.MultiHeadAttention(wf, n_heads=BLOCK_H, causal=True,
+                                       **options)
+    fwd.link_attrs(src, ("input", "output"))
+    fwd.initialize(device=device)
+    rng = np.random.default_rng(11)
+    for attr in GAINS:            # a gain of ones would hide its path
+        vec = getattr(fwd, attr)
+        if vec:
+            vec.reset(rng.uniform(0.5, 1.5, vec.shape).astype(
+                np.float32))
+            vec.initialize(device)
+    for attr, arr in (params or {}).items():
+        vec = getattr(fwd, attr)
+        vec.reset(np.array(arr, np.float32))
+        vec.initialize(device)
+    gd_u = attention.GDMultiHeadAttention(
+        wf, learning_rate=0.05, gradient_moment=0.9)
+    gd_u.forward_unit = fwd
+    gd_u.link_attrs(fwd, "input", "output", "weights", "bias")
+    gd_u.err_output = Vector(np.zeros(x.shape, np.float32), name="err")
+    gd_u.initialize(device=device)
+    return fwd, gd_u
+
+
+def block_params(fwd) -> dict:
+    out = {}
+    for attr in fwd.EXPORT_PARAMS:
+        vec = getattr(fwd, attr)
+        if vec:
+            vec.map_read()
+            out[attr] = np.array(vec.mem, np.float32)
+    return out
+
+
+@pytest.mark.parametrize("name", sorted(BLOCK_OPTIONS))
+def test_block_options_oracle_vs_vjp(name):
+    """Each option alone and all together: the numpy oracle (analytic
+    backward) and the XLA path (jax.vjp) agree on the output, on
+    err_input and on every parameter after two momentum steps."""
+    options = BLOCK_OPTIONS[name]
+    rng = np.random.default_rng(7)
+    x = rng.normal(0, 0.7, (B, T, BLOCK_D)).astype(np.float32)
+    err = rng.normal(0, 0.1, (B, T, BLOCK_D)).astype(np.float32)
+    np_f, np_g = build_block(NumpyDevice(), x, options)
+    xla_f, xla_g = build_block(XLADevice(), x, options,
+                               params=block_params(np_f))
+    results = []
+    for device, fwd, gd_u in ((np_f.device, np_f, np_g),
+                              (xla_f.device, xla_f, xla_g)):
+        for _ in range(2):
+            fwd.run()
+            gd_u.err_output.reset(err.copy())
+            gd_u.err_output.initialize(device)
+            gd_u.run()
+        fwd.output.map_read()
+        gd_u.err_input.map_read()
+        results.append({**block_params(fwd),
+                        "output": np.array(fwd.output.mem, np.float32),
+                        "err_input": np.array(gd_u.err_input.mem,
+                                              np.float32)})
+    expected_gains = {"rope": (), "residual": (),
+                      "qk_norm": ("gain_q", "gain_k"),
+                      "pre_norm": ("gain_norm",), "all": GAINS}[name]
+    assert {a for a in GAINS if a in results[0]} == set(expected_gains)
+    for key, want in results[0].items():
+        np.testing.assert_allclose(results[1][key], want, rtol=2e-3,
+                                   atol=2e-5, err_msg=key)
+
+
+def test_block_backward_matches_finite_differences():
+    """The analytic oracle of the whole block (all four options)
+    against central differences of Σ y·c: the input gradient and the
+    three gains."""
+    options = BLOCK_OPTIONS["all"]
+    rng = np.random.default_rng(8)
+    x = rng.normal(0, 0.7, (1, 4, BLOCK_D)).astype(np.float32)
+    c = rng.normal(0, 1, x.shape).astype(np.float32)
+    fwd, gd_u = build_block(NumpyDevice(), x, options)
+    gains0 = {a: getattr(fwd, a).mem.copy() for a in GAINS}
+
+    def loss(x_) -> float:
+        y, _ = fwd._forward_np(np.asarray(x_, np.float32))
+        return float((y.astype(np.float64) * c).sum())
+
+    fwd.run()
+    gd_u.err_output.reset(c.copy())
+    gd_u.learning_rate, gd_u.gradient_moment = 1.0, 0.0  # W −= grad
+    gd_u.run()
+    analytic_dx = gd_u.err_input.mem.copy()
+    grads = {a: gains0[a] - getattr(fwd, a).mem for a in GAINS}
+    # the step moved every parameter: rebuild for the differences
+    fwd, _ = build_block(NumpyDevice(), x, options)
+    eps = 2e-3
+    for idx in list(np.ndindex(*x.shape))[::5]:
+        hi, lo = x.copy(), x.copy()
+        hi[idx] += eps
+        lo[idx] -= eps
+        fd = (loss(hi) - loss(lo)) / (2 * eps)
+        np.testing.assert_allclose(analytic_dx[idx], fd, rtol=2e-2,
+                                   atol=2e-3)
+    for attr in GAINS:
+        vec = getattr(fwd, attr)
+        for i in (0, 5, BLOCK_D - 1):
+            keep = float(vec.mem[i])
+            vec.mem[i] = keep + eps
+            hi = loss(x)
+            vec.mem[i] = keep - eps
+            lo = loss(x)
+            vec.mem[i] = keep
+            np.testing.assert_allclose(grads[attr][i],
+                                       (hi - lo) / (2 * eps),
+                                       rtol=2e-2, atol=2e-3,
+                                       err_msg=f"{attr}[{i}]")
+
+
+def test_rope_is_a_rotation_by_position():
+    """Half-split convention: position 0 is left alone, norms are kept,
+    and the q·k score depends on the positions' difference only."""
+    rng = np.random.default_rng(9)
+    t, dh = 6, 8
+    cos, sin = attention.rope_tables(np, t, dh, 10000.0)
+    q = rng.normal(size=(1, t, 1, dh)).astype(np.float32)
+    rot = attention.apply_rope(np, q, cos, sin)
+    np.testing.assert_allclose(rot[:, 0], q[:, 0], atol=1e-7)
+    np.testing.assert_allclose(np.linalg.norm(rot, axis=-1),
+                               np.linalg.norm(q, axis=-1), rtol=1e-5)
+    back = attention.apply_rope(np, rot, cos, sin, inverse=True)
+    np.testing.assert_allclose(back, q, atol=1e-6)
+    # the same two vectors at positions (1, 3) and (2, 4): equal score
+    a, b_ = rng.normal(size=dh), rng.normal(size=dh)
+    same = np.broadcast_to(a, (1, t, 1, dh)).astype(np.float32)
+    other = np.broadcast_to(b_, (1, t, 1, dh)).astype(np.float32)
+    ra = attention.apply_rope(np, same, cos, sin)[0, :, 0]
+    rb = attention.apply_rope(np, other, cos, sin)[0, :, 0]
+    np.testing.assert_allclose(ra[3] @ rb[1], ra[4] @ rb[2], rtol=1e-4)
+    # x1' = x1 cos − x2 sin with x1, x2 the two HALVES of the head
+    half = dh // 2
+    want = q[0, 2, 0, :half] * cos[2] - q[0, 2, 0, half:] * sin[2]
+    np.testing.assert_allclose(rot[0, 2, 0, :half], want, rtol=1e-5)
+
+
+@pytest.mark.parametrize("precision", ["float32", "bfloat16"])
+def test_default_options_leave_the_bare_layer_bit_identical(precision):
+    """With every block option off (``attn_lm_base``'s layer) the XLA
+    forward is, bit for bit, the bare layer's formula as it stood
+    before the options came: projection, cast, split, core, output
+    projection — and the unit allocates no gain."""
+    import jax.numpy as jnp
+    from znicz_tpu.parallel.ring_attention import local_attention
+    from znicz_tpu.utils.config import root
+    root.common.precision_type = precision
+    x = _rand(12)
+    fwd = build(XLADevice(), x, causal=True)
+    assert not (fwd.gain_norm or fwd.gain_q or fwd.gain_k)
+    assert not (fwd.pre_norm or fwd.qk_norm or fwd.residual) \
+        and fwd.rope_theta is None
+    fwd.run()
+    fwd.output.map_read()
+    dt = fwd.mxu_dtype
+    assert (dt is not None) == (precision == "bfloat16")
+    qkv = fwd.mxu_dot(jnp, jnp.asarray(x).reshape(B * T, D),
+                      fwd.weights.devmem) + fwd.bias.devmem
+    if dt is not None:
+        qkv = qkv.astype(dt)
+    q, k, v = attention._split_heads(qkv.reshape(B, T, 3 * D), H)
+    o = local_attention(q, k, v, causal=True, dot_dtype=dt)
+    y = fwd.mxu_dot(jnp, o.reshape(B * T, D), fwd.weights_out.devmem) \
+        + fwd.bias_out.devmem
+    stored = y.reshape(B, T, D).astype(fwd.output.dtype)  # bf16 mode
+    np.testing.assert_array_equal(                        # stores bf16
+        np.asarray(fwd.output.mem, np.float32),
+        np.asarray(stored, np.float32))

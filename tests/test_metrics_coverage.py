@@ -94,6 +94,8 @@ def test_canonical_families_render_in_exposition():
         m.fleet_traffic_weight("cov", "lm", "v2").set(0.25),
         m.flash_tiles("cov_attention", "interior").set(28),
         m.loader_pipeline_restarts("cov").inc(),
+        m.moe_aux_loss("cov_moe", "z").set(17.3),
+        m.moe_expert_tokens("cov_moe", "max").set(1124),
         m.phase_p99_seconds("cov#0", "decode").set(0.002),
         m.prefix_tokens("cov#0", "hit").inc(4),
         m.serving_bucket_batches("cov#0", 128).inc(),

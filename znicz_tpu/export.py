@@ -209,9 +209,42 @@ def attach_decode_meta(path: str, *, page_tokens: int | None = None,
     return meta
 
 
+#: the attention options of the pre-norm residual block (ROADMAP R0)
+_BLOCK_OPTIONS = ("pre_norm", "qk_norm", "rope_theta", "residual")
+
+
+def refuse_unserved(forwards, what: str) -> None:
+    """Serving knows the bare attention layer only: a bundle's manifest
+    carries no block option and no expert layer, the decode plan has no
+    incremental step for rotary positions, the q/k norms, the pre-norm
+    residual or the experts.  Refuse such a chain by name rather than
+    serve another model than was trained (ROADMAP R1, serving half)."""
+    for i, unit in enumerate(forwards):
+        kind = type(unit).__name__
+        if kind == "MoE":
+            raise NotImplementedError(
+                f"{what}: layer {i} is a sparse-expert layer (moe); "
+                f"serving has no expert dispatch yet — router, top-k "
+                f"and grouped matmul exist on the training path only "
+                f"(ROADMAP R1, serving half)")
+        if kind != "MultiHeadAttention":
+            continue
+        used = [name for name in _BLOCK_OPTIONS
+                if getattr(unit, name, None)]
+        if used:
+            raise NotImplementedError(
+                f"{what}: attention layer {i} sets "
+                f"{', '.join(n.replace('_theta', '') for n in used)}; "
+                f"serving runs the bare attention layer only — the "
+                f"manifest and the prefill / decode steps lack the "
+                f"norm gains, the rotation by cached position and the "
+                f"residual (ROADMAP R1, serving half)")
+
+
 def export_forward(workflow, path: str) -> str:
     """Write the trained forward chain of a ``StandardWorkflow`` to
     ``path`` (``.npz`` bundle).  Returns the path written."""
+    refuse_unserved(workflow.forwards, "export_forward")
     manifest = _manifest_for(workflow)
     arrays: dict[str, np.ndarray] = {}
     for i, unit in enumerate(workflow.forwards):

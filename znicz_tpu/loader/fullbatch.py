@@ -107,8 +107,12 @@ class FullBatchLoader(Loader):
             (self.max_minibatch_size,) + tuple(sample_shape),
             dtype=self.act_store_dtype))
         if self.has_labels:
+            # one label per sample, or (T,) labels per sample (a
+            # next-token label at every position)
             self.minibatch_labels.reset(np.zeros(
-                self.max_minibatch_size, dtype=np.int32))
+                (self.max_minibatch_size,)
+                + tuple(self.original_labels.shape[1:]),
+                dtype=np.int32))
 
     # -- the gather -----------------------------------------------------
     def _normalize_np(self, batch: np.ndarray) -> np.ndarray:

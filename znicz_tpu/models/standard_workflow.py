@@ -37,7 +37,8 @@ from znicz_tpu.loader.base import TRAIN, Loader
 from znicz_tpu.mutable import Bool
 from znicz_tpu.ops import activation, all2all, conv, cutter, dropout, pooling
 from znicz_tpu.ops import attention, deconv, depooling, lstm, normalization
-from znicz_tpu.ops import embedding, layer_norm, pos_encoding
+from znicz_tpu.ops import embedding, layer_norm, moe, pos_encoding
+from znicz_tpu.ops import rms_norm
 from znicz_tpu.ops import seq_reshape
 from znicz_tpu.ops import gd, gd_conv, gd_pooling  # noqa: F401 (pairs)
 from znicz_tpu.ops.decision import DecisionGD, DecisionMSE
@@ -101,6 +102,8 @@ for _name, _cls in {
     "pos_encoding": pos_encoding.PositionalEncoding,
     "layer_norm": layer_norm.LayerNorm,
     "embedding": embedding.Embedding,
+    "rms_norm": rms_norm.RMSNorm,
+    "moe": moe.MoE,
 }.items():
     register_layer_type(_name, _cls)
 

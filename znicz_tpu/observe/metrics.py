@@ -556,6 +556,31 @@ def flash_tiles(unit: str, cls: str) -> Gauge:
         labels=("unit", "class")).labels(**{"unit": unit, "class": cls})
 
 
+def moe_expert_tokens(unit: str, stat: str) -> Gauge:
+    """Rows (token, expert) pairs an expert of a ``MoE`` unit computed
+    per step, over the last epoch: ``stat`` = ``max`` / ``min`` (the
+    fullest / emptiest expert of a step, averaged over the steps) or
+    ``mean`` (N·k ÷ E).  max ÷ mean is the load imbalance a dropless
+    layer pays in its grouped matmul's longest group.  Fed from
+    totals the unit keeps on the device, read once per epoch."""
+    return REGISTRY.gauge(
+        "znicz_moe_expert_tokens",
+        "Rows per expert and step of a dropless MoE layer over the "
+        "last epoch (max, mean, min)",
+        labels=("unit", "stat")).labels(unit=unit, stat=stat)
+
+
+def moe_aux_loss(unit: str, kind: str) -> Gauge:
+    """A ``MoE`` unit's auxiliary router losses, mean per step over
+    the last epoch, unweighted (``kind`` = ``load_balance``: top_k
+    under uniform routing; ``z``: mean squared log-partition)."""
+    return REGISTRY.gauge(
+        "znicz_moe_aux_loss",
+        "Auxiliary router losses of a MoE layer, mean per step over "
+        "the last epoch (load_balance, z)",
+        labels=("unit", "kind")).labels(unit=unit, kind=kind)
+
+
 def snapshot_seconds(op: str) -> Histogram:
     return REGISTRY.histogram(
         "znicz_snapshot_seconds",

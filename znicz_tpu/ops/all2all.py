@@ -38,6 +38,11 @@ class All2All(Forward):
       replicated-over-model output — GSPMD inserts the psum.
     - ``None`` (default): replicated weights, pure data parallelism.
 
+    ``per_position=True`` applies the layer to every position of a
+    (batch, time, features) input — B·T rows through one (features,
+    n_out) matrix, output (batch, time, n_out) — instead of flattening
+    time into the features: the language-model head.
+
     Annotation-only: the GEMMs are unchanged, ``sharding_for`` places
     the buffers, and XLA's partitioner derives the collectives
     (all-gather/reduce-scatter over ICI).  On a mesh with model=1 or
@@ -47,8 +52,10 @@ class All2All(Forward):
     ACTIVATION = "linear"
 
     def __init__(self, workflow, output_sample_shape, name=None,
-                 model_parallel: str | None = None, **kwargs):
+                 model_parallel: str | None = None,
+                 per_position: bool = False, **kwargs):
         super().__init__(workflow, name=name, **kwargs)
+        self.per_position = bool(per_position)
         if isinstance(output_sample_shape, (int, np.integer)):
             output_sample_shape = (int(output_sample_shape),)
         self.output_sample_shape = tuple(output_sample_shape)
@@ -68,6 +75,11 @@ class All2All(Forward):
     @property
     def neurons(self) -> int:
         return int(np.prod(self.output_sample_shape))
+
+    def lead_shape(self, x_shape) -> tuple:
+        """The leading dims of an input that index rows of the GEMM:
+        the batch, or (batch, time) when ``per_position``."""
+        return tuple(x_shape[:-1] if self.per_position else x_shape[:1])
 
     def _apply_model_parallel(self, n_in: int, n_out: int) -> None:
         """Set model-axis sharding dims on weights/bias/output before
@@ -116,7 +128,8 @@ class All2All(Forward):
         super().initialize(device=device, **kwargs)
         if self.input is None or not self.input:
             raise AttributeError(f"{self}: input not linked/allocated yet")
-        n_in = self.input.sample_size
+        lead = self.lead_shape(self.input.shape)
+        n_in = int(np.prod(self.input.shape[len(lead):]))
         n_out = self.neurons
         if not self.weights:
             self.weights.reset(self.fill_array(
@@ -125,20 +138,19 @@ class All2All(Forward):
         if self.include_bias and not self.bias:
             self.bias.reset(self.fill_array(
                 (n_out,), self.bias_filling, self.bias_stddev, fan_in=n_in))
-        batch = self.input.shape[0]
-        self.output.reset(np.zeros((batch,) + self.output_sample_shape,
+        self.output.reset(np.zeros(lead + self.output_sample_shape,
                                    dtype=self.output_store_dtype))
         self._apply_model_parallel(n_in, n_out)
         self.init_vectors(self.input, self.output, self.weights, self.bias)
 
     # -- math (shared shape logic; xp-generic) --------------------------
     def _forward(self, xp, x, w, b):
-        batch = x.shape[0]
-        y = self.mxu_dot(xp, x.reshape(batch, -1), w)
+        lead = self.lead_shape(x.shape)
+        y = self.mxu_dot(xp, x.reshape(int(np.prod(lead)), -1), w)
         if b is not None:
             y = y + b
         y = self.activation.fwd(xp, y)
-        return y.reshape((batch,) + self.output_sample_shape)
+        return y.reshape(lead + self.output_sample_shape)
 
     def numpy_run(self) -> None:
         self.input.map_read()
@@ -194,7 +206,8 @@ class All2AllSoftmax(All2All):
 
     def initialize(self, device=None, **kwargs) -> None:
         super().initialize(device=device, **kwargs)
-        self.max_idx.reset(np.zeros(self.output.shape[0], dtype=np.int32))
+        self.max_idx.reset(np.zeros(
+            self.lead_shape(self.input.shape), dtype=np.int32))
         self.init_vectors(self.max_idx)
 
     def _softmax(self, xp, logits):
@@ -203,7 +216,9 @@ class All2AllSoftmax(All2All):
         return e / e.sum(axis=1, keepdims=True)
 
     def _logits(self, xp, x, w, b):
-        y = self.mxu_dot(xp, x.reshape(x.shape[0], -1), w)
+        """(rows, classes): a row per sample, or per position."""
+        rows = int(np.prod(self.lead_shape(x.shape)))
+        y = self.mxu_dot(xp, x.reshape(rows, -1), w)
         return y if b is None else y + b
 
     def numpy_run(self) -> None:
@@ -217,11 +232,15 @@ class All2AllSoftmax(All2All):
         logits = self._logits(np, x, self.weights.mem, b)
         self.output.map_invalidate()
         self.max_idx.map_invalidate()
-        self.output.mem[...] = self._softmax(np, logits)
-        self.max_idx.mem[...] = np.argmax(logits, axis=1).astype(np.int32)
+        self.output.mem[...] = self._softmax(np, logits).reshape(
+            self.output.shape)
+        self.max_idx.mem[...] = np.argmax(logits, axis=1).astype(
+            np.int32).reshape(self.max_idx.shape)
 
     def xla_run(self) -> None:
         b = self.bias.devmem if self.include_bias else None
         logits = self._logits(jnp, self.input.devmem, self.weights.devmem, b)
-        self.output.devmem = self._softmax(jnp, logits)
-        self.max_idx.devmem = jnp.argmax(logits, axis=1).astype(jnp.int32)
+        self.output.devmem = self._softmax(jnp, logits).reshape(
+            self.output.shape)
+        self.max_idx.devmem = jnp.argmax(logits, axis=1).astype(
+            jnp.int32).reshape(self.max_idx.shape)

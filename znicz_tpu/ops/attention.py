@@ -22,6 +22,17 @@ over the mesh exactly like the scaling-book recipe.  The unit's
 output Vector carries ``model_shard_dim=1`` (the time axis) so the
 sharding annotation flows through the graph.
 
+The pre-norm residual block (ROADMAP R0; OLMoE, PR 25) is options of
+this unit, all off by default so that a bare attention layer's program
+does not change: ``pre_norm="rms"`` attends over ``RMSNorm(x)``,
+``residual=True`` adds the input back (``y = x + f(norm(x))`` is ONE
+unit with ONE GD pair, so the workflow's graph stays a chain),
+``qk_norm="rms"`` normalizes the whole D-wide q and k projections (not
+per head) with a gain each, ``rope={"theta": …}`` rotates q and k by
+position over the full head in the half-split ("rotate_half")
+convention.  Norms and rotation run in f32 between the QKV projection
+and the attention core; the kernels are untouched.
+
 Backward (``GDMultiHeadAttention``): ``jax.vjp`` of the forward on
 the XLA path — this differentiates THROUGH the shard_map/ppermute
 ring, so sequence-parallel training needs no hand-written collective
@@ -37,6 +48,7 @@ import jax.numpy as jnp
 
 from znicz_tpu.memory import Vector
 from znicz_tpu.ops.nn_units import Forward, GradientDescentBase
+from znicz_tpu.ops.rms_norm import rms_norm, rms_norm_backward
 from znicz_tpu.parallel.axis import DATA_AXIS, MODEL_AXIS, SEQ_AXIS
 
 
@@ -49,6 +61,28 @@ def _split_heads(qkv, n_heads: int):
     q, k, v = qkv[..., :d], qkv[..., d:2 * d], qkv[..., 2 * d:]
     reshape = (b, t, n_heads, dh)
     return q.reshape(reshape), k.reshape(reshape), v.reshape(reshape)
+
+
+def rope_tables(xp, t: int, dh: int, theta: float):
+    """(T, dh/2) cosines and sines of ``pos · theta^(−2i/dh)``, f32."""
+    inv_freq = 1.0 / np.power(
+        float(theta), np.arange(0, dh, 2, dtype=np.float64) / dh)
+    angle = np.arange(t, dtype=np.float64)[:, None] * inv_freq[None, :]
+    return (xp.asarray(np.cos(angle), dtype=xp.float32),
+            xp.asarray(np.sin(angle), dtype=xp.float32))
+
+
+def apply_rope(xp, x, cos, sin, inverse: bool = False):
+    """Rotate (B, T, H, dh) by position, half-split convention:
+    ``(x₁, x₂) → (x₁·cos − x₂·sin, x₂·cos + x₁·sin)`` with x₁ / x₂ the
+    two halves of the head.  ``inverse`` rotates back — the adjoint,
+    which is what the backward applies to the cotangent."""
+    half = x.shape[-1] // 2
+    x1, x2 = x[..., :half], x[..., half:]
+    c, s = cos[None, :, None, :], sin[None, :, None, :]
+    if inverse:
+        s = -s
+    return xp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
 
 
 def _local_attention_np(q, k, v, causal: bool):
@@ -70,11 +104,15 @@ def _local_attention_np(q, k, v, causal: bool):
 class MultiHeadAttention(Forward):
     """Weighted multi-head self-attention layer."""
 
-    EXPORT_PARAMS = ("weights", "bias", "weights_out", "bias_out")
+    EXPORT_PARAMS = ("weights", "bias", "weights_out", "bias_out",
+                     "gain_norm", "gain_q", "gain_k")
 
     def __init__(self, workflow, n_heads: int, causal: bool = False,
                  seq_parallel: bool = False,
                  flash_block_k: int | None = None,
+                 pre_norm: str | None = None, residual: bool = False,
+                 qk_norm: str | None = None, rope: dict | None = None,
+                 norm_eps: float = 1e-5,
                  name=None, **kwargs) -> None:
         # attention defaults to fan-scaled init (the reference's
         # fixed-stddev fillings predate attention entirely)
@@ -96,6 +134,21 @@ class MultiHeadAttention(Forward):
         #: re-initializing on a capable mesh re-engages the ring).
         self.seq_parallel = bool(seq_parallel)
         self._ring_active = False
+        for option, value in (("pre_norm", pre_norm),
+                              ("qk_norm", qk_norm)):
+            if value not in (None, "rms"):
+                raise ValueError(f"{option} must be None or 'rms', got "
+                                 f"{value!r}")
+        #: the pre-norm residual block (module docstring); all off =
+        #: the bare layer, whose program these options leave untouched
+        self.pre_norm = pre_norm
+        self.residual = bool(residual)
+        self.qk_norm = qk_norm
+        self.rope_theta = None if rope is None else float(rope["theta"])
+        self.norm_eps = float(norm_eps)
+        self.gain_norm = Vector(name=f"{self.name}.gain_norm")
+        self.gain_q = Vector(name=f"{self.name}.gain_q")
+        self.gain_k = Vector(name=f"{self.name}.gain_k")
         #: pullback stashed by xla_run for the GD pair (same trace;
         #: transient — never pickled, cleared by the consumer)
         self._traced_vjp = None
@@ -130,6 +183,14 @@ class MultiHeadAttention(Forward):
                 self.bias.reset(np.zeros(3 * d, np.float32))
             if not self.bias_out:
                 self.bias_out.reset(np.zeros(d, np.float32))
+        gains = ([self.gain_norm] if self.pre_norm else []) \
+            + ([self.gain_q, self.gain_k] if self.qk_norm else [])
+        for gain in gains:
+            if not gain:
+                gain.reset(np.ones(d, np.float32))
+        if self.rope_theta is not None and (d // self.n_heads) % 2:
+            raise ValueError(f"{self}: rope needs an even head size, "
+                             f"got {d // self.n_heads}")
         self.output.reset(np.zeros((b, t, d),
                                    dtype=self.output_store_dtype))
         from jax.sharding import PartitionSpec as P
@@ -298,7 +359,8 @@ class MultiHeadAttention(Forward):
             self.info("%s: XLA attention core — %s", self.name,
                       refused)
         self.init_vectors(self.input, self.output, self.weights,
-                          self.bias, self.weights_out, self.bias_out)
+                          self.bias, self.weights_out, self.bias_out,
+                          self.gain_norm, self.gain_q, self.gain_k)
 
     @property
     def ring_active(self) -> bool:
@@ -307,10 +369,41 @@ class MultiHeadAttention(Forward):
         return self._ring_active
 
     # -- pure forward (jnp; the backward vjp's this) --------------------
-    def xla_forward(self, x, w_qkv, b_qkv, w_out, b_out):
+    def forward_args(self) -> tuple:
+        """The arguments of :meth:`xla_forward` from this unit's
+        Vectors (``None`` for what an option leaves out)."""
+        def dev(vec):
+            return vec.devmem if vec else None
+        return (self.input.devmem, self.weights.devmem,
+                self.bias.devmem if self.include_bias else None,
+                self.weights_out.devmem,
+                self.bias_out.devmem if self.include_bias else None,
+                dev(self.gain_norm), dev(self.gain_q), dev(self.gain_k))
+
+    def _normed_rotated(self, xp, qkv, g_q, g_k):
+        """(B, T, 3D) f32 projections → q, k, v (B, T, H, dh) with the
+        whole-projection q/k norms and the rotation applied."""
+        b, t, d3 = qkv.shape
+        d = d3 // 3
+        q, k, v = qkv[..., :d], qkv[..., d:2 * d], qkv[..., 2 * d:]
+        if self.qk_norm:
+            q = rms_norm(xp, q, g_q, self.norm_eps)
+            k = rms_norm(xp, k, g_k, self.norm_eps)
+        shape = (b, t, self.n_heads, d // self.n_heads)
+        q, k, v = q.reshape(shape), k.reshape(shape), v.reshape(shape)
+        if self.rope_theta is not None:
+            cos, sin = rope_tables(xp, t, shape[-1], self.rope_theta)
+            q = apply_rope(xp, q, cos, sin)
+            k = apply_rope(xp, k, cos, sin)
+        return q, k, v
+
+    def xla_forward(self, x, w_qkv, b_qkv, w_out, b_out,
+                    g_norm=None, g_q=None, g_k=None):
         b, t, d = x.shape
         x32 = x.astype(jnp.float32)
-        qkv = self.mxu_dot(jnp, x32.reshape(b * t, d), w_qkv)
+        h = x32 if g_norm is None \
+            else rms_norm(jnp, x32, g_norm, self.norm_eps)
+        qkv = self.mxu_dot(jnp, h.reshape(b * t, d), w_qkv)
         if b_qkv is not None:
             qkv = qkv + b_qkv
         # attention-core GEMM/storage dtype: the repo-wide bf16-inputs/
@@ -319,9 +412,17 @@ class MultiHeadAttention(Forward):
         # Cast ONCE here so q/k/v reach the core (and the flash
         # kernel's layout transposes) at half width.
         dot_dtype = self.mxu_dtype
-        if dot_dtype is not None:
-            qkv = qkv.astype(dot_dtype)
-        q, k, v = _split_heads(qkv.reshape(b, t, 3 * d), self.n_heads)
+        if self.qk_norm or self.rope_theta is not None:
+            # norms and rotation in f32, THEN the cast
+            q, k, v = self._normed_rotated(
+                jnp, qkv.reshape(b, t, 3 * d), g_q, g_k)
+            if dot_dtype is not None:
+                q, k, v = (a.astype(dot_dtype) for a in (q, k, v))
+        else:
+            if dot_dtype is not None:
+                qkv = qkv.astype(dot_dtype)
+            q, k, v = _split_heads(qkv.reshape(b, t, 3 * d),
+                                   self.n_heads)
         if self.ring_active:
             from znicz_tpu.parallel.ring_attention import \
                 sequence_sharded_attention
@@ -370,13 +471,11 @@ class MultiHeadAttention(Forward):
         y = self.mxu_dot(jnp, o.reshape(b * t, d), w_out)
         if b_out is not None:
             y = y + b_out
-        return y.reshape(b, t, d)
+        y = y.reshape(b, t, d)
+        return x32 + y if self.residual else y
 
     def xla_run(self) -> None:
-        args = (self.input.devmem, self.weights.devmem,
-                self.bias.devmem if self.include_bias else None,
-                self.weights_out.devmem,
-                self.bias_out.devmem if self.include_bias else None)
+        args = self.forward_args()
         if not self.output._tracing:
             # eager (non-region) execution: plain forward.  Stashing a
             # pullback here would pin the forward residuals — for the
@@ -393,9 +492,7 @@ class MultiHeadAttention(Forward):
         # so the kernel executed twice per step (measured +3.4 ms at
         # T=2048 — PERF.md round 5).  In eval-mode region variants the
         # unused pullback is dead code and XLA drops it.
-        out, self._traced_vjp = jax.vjp(
-            lambda x, wq, bq, wo, bo: self.xla_forward(
-                x, wq, bq, wo, bo), *args)
+        out, self._traced_vjp = jax.vjp(self.xla_forward, *args)
         self.output.devmem = out
 
     # -- autoregressive decode (round 12, serving.decode) ---------------
@@ -669,16 +766,27 @@ class MultiHeadAttention(Forward):
 
     # -- numpy oracle ---------------------------------------------------
     def _forward_np(self, x):
+        """``(y, (h, qkv, q, k, v, o, p))``: ``h`` is what the QKV
+        projection saw (the input, or its pre-norm), ``qkv`` the raw
+        projections, ``q``/``k`` what the core saw (normed, rotated)."""
         b, t, d = x.shape
-        qkv = x.reshape(b * t, d) @ self.weights.mem
+        h = rms_norm(np, x, self.gain_norm.mem, self.norm_eps) \
+            if self.pre_norm else x
+        qkv = h.reshape(b * t, d) @ self.weights.mem
         if self.include_bias:
             qkv = qkv + self.bias.mem
-        q, k, v = _split_heads(qkv.reshape(b, t, 3 * d), self.n_heads)
+        q, k, v = self._normed_rotated(
+            np, qkv.reshape(b, t, 3 * d),
+            self.gain_q.mem if self.qk_norm else None,
+            self.gain_k.mem if self.qk_norm else None)
         o, p = _local_attention_np(q, k, v, self.causal)
         y = o.reshape(b * t, d) @ self.weights_out.mem
         if self.include_bias:
             y = y + self.bias_out.mem
-        return y.reshape(b, t, d), (qkv, q, k, v, o, p)
+        y = y.reshape(b, t, d)
+        if self.residual:
+            y = x + y
+        return y, (h, qkv, q, k, v, o, p)
 
     def numpy_run(self) -> None:
         self.input.map_read()
@@ -687,6 +795,9 @@ class MultiHeadAttention(Forward):
         if self.include_bias:
             self.bias.map_read()
             self.bias_out.map_read()
+        for gain in (self.gain_norm, self.gain_q, self.gain_k):
+            if gain:
+                gain.map_read()
         y, _ = self._forward_np(self.input.mem.astype(np.float32))
         self.output.map_invalidate()
         self.output.mem[...] = y
@@ -708,6 +819,23 @@ class GDMultiHeadAttention(GradientDescentBase):
             name=f"{self.name}.acc_gw_out")
         self.accumulated_gradient_bias_out = Vector(
             name=f"{self.name}.acc_gb_out")
+        # the block's three gains (pre-norm, q norm, k norm)
+        self.accumulated_gradient_gain_norm = Vector(
+            name=f"{self.name}.acc_gain_norm")
+        self.accumulated_gradient_gain_q = Vector(
+            name=f"{self.name}.acc_gain_q")
+        self.accumulated_gradient_gain_k = Vector(
+            name=f"{self.name}.acc_gain_k")
+
+    def _gain_pairs(self) -> list:
+        """``(suffix, gain Vector, its accumulator)`` for the gains the
+        forward's options allocated."""
+        fwd = self.forward_unit
+        return [(name, gain, getattr(
+                    self, f"accumulated_gradient_gain_{name}"))
+                for name, gain in (("norm", fwd.gain_norm),
+                                   ("q", fwd.gain_q), ("k", fwd.gain_k))
+                if gain]
 
     def initialize(self, device=None, **kwargs) -> None:
         super().initialize(device=device, **kwargs)
@@ -721,11 +849,17 @@ class GDMultiHeadAttention(GradientDescentBase):
         if self.gradient_moment_bias and fwd.include_bias:
             self._alloc_accumulator(self.accumulated_gradient_bias_out,
                                     fwd.bias_out)
+        gains = self._gain_pairs()
+        if self.gradient_moment:
+            for _, gain, acc in gains:
+                self._alloc_accumulator(acc, gain)
         self.init_vectors(self.err_input, self.err_output, self.input,
                           self.output, self.weights, self.bias,
                           fwd.weights_out, fwd.bias_out,
                           self.accumulated_gradient_weights_out,
-                          self.accumulated_gradient_bias_out)
+                          self.accumulated_gradient_bias_out,
+                          *(v for _, gain, acc in gains
+                            for v in (gain, acc)))
 
     def _micro_accum_params(self):
         # round 20: the output projection pair accumulates too — the
@@ -734,6 +868,8 @@ class GDMultiHeadAttention(GradientDescentBase):
         fwd = self.forward_unit
         if fwd is not None:
             pairs.extend([("wo", fwd.weights_out), ("bo", fwd.bias_out)])
+            pairs.extend((f"g{name}", gain)
+                         for name, gain, _ in self._gain_pairs())
         return pairs
 
     def region_vectors(self):
@@ -742,7 +878,9 @@ class GDMultiHeadAttention(GradientDescentBase):
         fwd = self.forward_unit
         for vec in (fwd.weights_out, fwd.bias_out,
                     self.accumulated_gradient_weights_out,
-                    self.accumulated_gradient_bias_out):
+                    self.accumulated_gradient_bias_out,
+                    *(v for _, gain, acc in self._gain_pairs()
+                      for v in (gain, acc))):
             if vec and id(vec) not in seen:
                 vecs.append(vec)
         return vecs
@@ -760,15 +898,8 @@ class GDMultiHeadAttention(GradientDescentBase):
         vjp = fwd._traced_vjp if self.err_output._tracing else None
         fwd._traced_vjp = None   # single-use: never reuse stale state
         if vjp is None:          # forward ran outside this trace
-            args = (self.input.devmem, self.weights.devmem,
-                    self.bias.devmem if has_bias else None,
-                    fwd.weights_out.devmem,
-                    fwd.bias_out.devmem if has_bias else None)
-            _, vjp = jax.vjp(
-                lambda x, wq, bq, wo, bo: fwd.xla_forward(
-                    x, wq, bq, wo, bo),
-                *args)
-        gx, gwq, gbq, gwo, gbo = vjp(
+            _, vjp = jax.vjp(fwd.xla_forward, *fwd.forward_args())
+        gx, gwq, gbq, gwo, gbo, *ggains = vjp(
             self.err_output.devmem.astype(jnp.float32))
         if self.need_err_input:
             self.err_input.devmem = gx
@@ -783,6 +914,9 @@ class GDMultiHeadAttention(GradientDescentBase):
             self._apply_bias_xla(
                 gbo, vec=fwd.bias_out,
                 acc_vec=self.accumulated_gradient_bias_out)
+        grads = dict(zip(("norm", "q", "k"), ggains))
+        for name, gain, acc in self._gain_pairs():
+            self._apply_weights_xla(grads[name], vec=gain, acc_vec=acc)
 
     def numpy_run(self) -> None:
         """Analytic attention backward (the oracle/spec)."""
@@ -794,11 +928,13 @@ class GDMultiHeadAttention(GradientDescentBase):
         if fwd.include_bias:
             self.bias.map_write()
             fwd.bias_out.map_write()
+        for _, gain, _ in self._gain_pairs():
+            gain.map_write()
         x = self.input.mem.astype(np.float32)
         b, t, d = x.shape
         h = fwd.n_heads
         dh = d // h
-        _, (qkv, q, k, v, o, p) = fwd._forward_np(x)
+        _, (hidden, qkv, q, k, v, o, p) = fwd._forward_np(x)
         dy = self.err_output.mem.astype(np.float32).reshape(b * t, d)
         # output projection
         grad_wo = o.reshape(b * t, d).T @ dy
@@ -811,16 +947,34 @@ class GDMultiHeadAttention(GradientDescentBase):
         ds = ds / np.sqrt(dh)
         dq = np.einsum("bhqk,bkhd->bqhd", ds, k)
         dk = np.einsum("bhqk,bqhd->bkhd", ds, q)
+        grad_gains = {}
+        if fwd.rope_theta is not None:    # the rotation's adjoint
+            cos, sin = rope_tables(np, t, dh, fwd.rope_theta)
+            dq = apply_rope(np, dq, cos, sin, inverse=True)
+            dk = apply_rope(np, dk, cos, sin, inverse=True)
+        if fwd.qk_norm:                   # back through the q/k norms
+            raw = qkv.reshape(b, t, 3 * d)
+            dq, grad_gains["q"] = rms_norm_backward(
+                np, raw[..., :d], fwd.gain_q.mem, fwd.norm_eps,
+                dq.reshape(b, t, d))
+            dk, grad_gains["k"] = rms_norm_backward(
+                np, raw[..., d:2 * d], fwd.gain_k.mem, fwd.norm_eps,
+                dk.reshape(b, t, d))
         dqkv = np.concatenate(
             [a.reshape(b, t, d) for a in (dq, dk, dv)],
             axis=-1).reshape(b * t, 3 * d)
         # input projection
-        grad_wq = x.reshape(b * t, d).T @ dqkv
+        grad_wq = hidden.reshape(b * t, d).T @ dqkv
         grad_bq = dqkv.sum(axis=0)
+        dx = (dqkv @ self.weights.mem.T).reshape(b, t, d)
+        if fwd.pre_norm:
+            dx, grad_gains["norm"] = rms_norm_backward(
+                np, x, fwd.gain_norm.mem, fwd.norm_eps, dx)
+        if fwd.residual:
+            dx = dx + dy.reshape(b, t, d)
         if self.need_err_input:
             self.err_input.map_invalidate()
-            self.err_input.mem[...] = (
-                dqkv @ self.weights.mem.T).reshape(b, t, d)
+            self.err_input.mem[...] = dx
         self._apply_weights_np(grad_wq)
         if fwd.include_bias:
             self._apply_bias_np(grad_bq)
@@ -831,3 +985,6 @@ class GDMultiHeadAttention(GradientDescentBase):
             self._apply_bias_np(
                 grad_bo, vec=fwd.bias_out,
                 acc_vec=self.accumulated_gradient_bias_out)
+        for name, gain, acc in self._gain_pairs():
+            self._apply_weights_np(grad_gains[name], vec=gain,
+                                   acc_vec=acc)

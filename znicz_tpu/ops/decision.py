@@ -57,6 +57,12 @@ class DecisionBase(Unit):
         self.accumulate_minibatch()
         if loader.epoch_ended:
             self.on_epoch_ended()
+            for unit in getattr(self.workflow, "forwards", ()):
+                # forwards that keep epoch totals on the device (the
+                # expert layer's routing) read them here, once
+                hook = getattr(unit, "on_epoch_ended", None)
+                if hook is not None:
+                    hook()
             self.epoch_ended.value = True
             if _metrics.enabled():
                 # epoch boundaries are only known here, so the epoch
@@ -175,12 +181,15 @@ class DecisionGD(DecisionBase):
         self.epoch_n_err = [int(x) for x in acc.mem]
         acc.map_invalidate()
         acc.mem[...] = 0  # uploaded on the next region fire
+        # labelled rows per sample: 1, or T under per-position labels
+        per = int(getattr(self.evaluator, "labels_per_sample", 1))
         loss_acc: Vector = getattr(self.evaluator, "epoch_loss", None)
         if isinstance(loss_acc, Vector) and loss_acc:
             loss_acc.map_read()
-            # summed −log p(true) → mean per sample (the loss curve)
+            # summed −log p(true) → mean per labelled row (the loss
+            # curve)
             self.epoch_loss = [
-                float(loss_acc.mem[c]) / loader.class_lengths[c]
+                float(loss_acc.mem[c]) / (loader.class_lengths[c] * per)
                 if loader.class_lengths[c] else None for c in range(3)]
             loss_acc.map_invalidate()
             loss_acc.mem[...] = 0.0
@@ -195,7 +204,7 @@ class DecisionGD(DecisionBase):
             length = loader.class_lengths[cls]
             if length:
                 self.epoch_n_err_pt[cls] = \
-                    100.0 * self.epoch_n_err[cls] / length
+                    100.0 * self.epoch_n_err[cls] / (length * per)
         has_valid = loader.class_lengths[VALID] > 0
         n_err = self.epoch_n_err[VALID if has_valid else TRAIN]
         best = (self.min_validation_n_err if has_valid

@@ -4,6 +4,8 @@ chain's seed error and host-readable quality metrics
 
 ``EvaluatorSoftmax`` consumes the softmax output and emits
 
+- labels may be (B, T) — a next-token label at every position — with
+  a (B, T, C) output: the B·T rows are then what samples are below;
 - ``err_output = (p − onehot(t)) / n_valid`` — the combined
   softmax+cross-entropy derivative w.r.t. the logits, masked over
   padded tail samples (static-shape minibatches, see loader);
@@ -49,9 +51,12 @@ class EvaluatorBase(AcceleratedUnit):
         # for the sentinel's vote to read
         self.sdc_fingerprint: Vector | None = None
 
-    def _valid_mask(self, xp, n_rows):
+    def _valid_mask(self, xp, n_rows, per_sample: int = 1):
+        """Mask of the valid rows and their number; a sample is
+        ``per_sample`` rows (positions)."""
         valid = self.minibatch_valid.devmem if xp is jnp \
             else self.minibatch_valid.mem
+        valid = valid * per_sample
         return (xp.arange(n_rows) < valid), valid
 
     def _inject(self, xp, idx: int):
@@ -159,15 +164,27 @@ class EvaluatorSoftmax(EvaluatorBase):
 
     @property
     def n_classes(self) -> int:
-        return self.output.shape[1]
+        return self.output.shape[-1]
+
+    @property
+    def labels_per_sample(self) -> int:
+        """1, or T where every position of a sample has a label."""
+        return int(np.prod(self.labels.shape[1:]))
+
+    def _rows(self, p, t, max_idx):
+        """(rows, classes) probabilities, (rows,) labels and
+        arg-maxes: a row per sample, or per position."""
+        return (p.reshape(-1, p.shape[-1]), t.reshape(-1),
+                max_idx.reshape(-1))
 
     def numpy_run(self) -> None:
         for vec in (self.output, self.labels, self.max_idx,
                     self.minibatch_valid):
             vec.map_read()
-        p = self.output.mem
-        t = self.labels.mem
-        mask, valid = self._valid_mask(np, p.shape[0])
+        p, t, max_idx = self._rows(self.output.mem, self.labels.mem,
+                                   self.max_idx.mem)
+        mask, valid = self._valid_mask(np, p.shape[0],
+                                       self.labels_per_sample)
         onehot = np.zeros_like(p)
         onehot[np.arange(p.shape[0]), t] = 1.0
         err = mask[:, None] * (p - onehot) / max(int(valid), 1)
@@ -175,9 +192,9 @@ class EvaluatorSoftmax(EvaluatorBase):
         if grad_inj is not None:
             err = err + grad_inj
         self.err_output.map_invalidate()
-        self.err_output.mem[...] = err
+        self.err_output.mem[...] = err.reshape(self.err_output.shape)
         self.n_err.map_invalidate()
-        n_err = int(np.sum((self.max_idx.mem != t) & mask))
+        n_err = int(np.sum((max_idx != t) & mask))
         self.n_err.mem[...] = n_err
         self.epoch_n_err.map_write()
         self.epoch_n_err.mem[int(self.minibatch_class)] += n_err
@@ -196,21 +213,24 @@ class EvaluatorSoftmax(EvaluatorBase):
         if self.compute_confusion:
             self.confusion_matrix.map_write()
             cm = self.confusion_matrix.mem[int(self.minibatch_class)]
-            pred = self.max_idx.mem
-            np.add.at(cm, (t[mask], pred[mask]), 1)
+            np.add.at(cm, (t[mask], max_idx[mask]), 1)
 
     def xla_run(self) -> None:
-        p = self.output.devmem
-        t = self.labels.devmem
-        mask, valid = self._valid_mask(jnp, p.shape[0])
-        onehot = jax_onehot(t, p.shape[1], p.dtype)
+        p, t, max_idx = self._rows(self.output.devmem,
+                                   self.labels.devmem,
+                                   self.max_idx.devmem)
+        mask, valid = self._valid_mask(jnp, p.shape[0],
+                                       self.labels_per_sample)
+        # p − onehot(t) as a select: no rows × classes one-hot exists,
+        # not even in the trace (at 8,192 × 50,304 it would be 1.65 GB)
+        hit = t[:, None] == jnp.arange(p.shape[1])[None, :]
         denom = jnp.maximum(valid, 1).astype(p.dtype)
-        err = mask[:, None] * (p - onehot) / denom
+        err = mask[:, None] * jnp.where(hit, p - 1.0, p) / denom
         grad_inj = self._inject(jnp, 1)
         if grad_inj is not None:
             err = err + grad_inj.astype(err.dtype)
-        self.err_output.devmem = err
-        n_err = jnp.sum((self.max_idx.devmem != t) & mask).astype(jnp.int32)
+        self.err_output.devmem = err.reshape(self.err_output.shape)
+        n_err = jnp.sum((max_idx != t) & mask).astype(jnp.int32)
         self.n_err.devmem = n_err
         self.epoch_n_err.devmem = self.epoch_n_err.devmem.at[
             int(self.minibatch_class)].add(n_err)
@@ -231,7 +251,7 @@ class EvaluatorSoftmax(EvaluatorBase):
             # accumulate via scatter-add
             cls = int(self.minibatch_class)
             self.confusion_matrix.devmem = self.confusion_matrix.devmem.at[
-                cls, t, self.max_idx.devmem].add(mask.astype(jnp.int32))
+                cls, t, max_idx].add(mask.astype(jnp.int32))
 
 
 class EvaluatorMSE(EvaluatorBase):
@@ -313,7 +333,3 @@ class EvaluatorMSE(EvaluatorBase):
             int(self.minibatch_class)].add(jnp.where(loss_ok, sse, 0.0))
         self._seed_step_flags(jnp, loss_ok)
 
-
-def jax_onehot(labels, n_classes: int, dtype):
-    return (labels[:, None] ==
-            jnp.arange(n_classes)[None, :]).astype(dtype)

@@ -60,9 +60,17 @@ class GradientDescent(GradientDescentBase):
                           self.output, self.weights, self.bias)
 
     # -- shared math ----------------------------------------------------
+    def _rows(self, x) -> int:
+        """Rows of the GEMM: the batch, or batch × time under the
+        forward's ``per_position``."""
+        fwd = getattr(self, "forward_unit", None)
+        if getattr(fwd, "per_position", False):
+            return int(np.prod(x.shape[:-1]))
+        return x.shape[0]
+
     def _delta(self, xp, err_output, output, x2d):
         """Activation-derivative folding: δ_act over flat (N, out)."""
-        batch = err_output.shape[0]
+        batch = x2d.shape[0]
         d = err_output.reshape(batch, -1)
         y = output.reshape(batch, -1)
         deriv = self.activation.derivative(
@@ -74,8 +82,7 @@ class GradientDescent(GradientDescentBase):
             vec.map_read()
         self.weights.map_write()
         x = self.input.mem.astype(np.float32)
-        batch = x.shape[0]
-        x2d = x.reshape(batch, -1)
+        x2d = x.reshape(self._rows(x), -1)
         delta = self._delta(np, self.err_output.mem, self.output.mem, x2d)
         if self.need_err_input:
             self.err_input.map_invalidate()
@@ -89,8 +96,7 @@ class GradientDescent(GradientDescentBase):
 
     def xla_run(self) -> None:
         x = self.input.devmem
-        batch = x.shape[0]
-        x2d = x.reshape(batch, -1)
+        x2d = x.reshape(self._rows(x), -1)
         w = self.weights.devmem
         delta = self._delta(jnp, self.err_output.devmem, self.output.devmem,
                             x2d)

@@ -219,7 +219,7 @@ _BATCH_SLOTS = (
     r"output", r"out\d+", r"err_input\d*", r"err_output",
     r"minibatch_data", r"minibatch_labels", r"minibatch_indices",
     r"minibatch_raw", r"mask", r"max_idx", r"winners", r"input",
-    r"reconstruction", r"targets", r"last_choice",
+    r"reconstruction", r"targets", r"last_choice", r"router_logits",
 )
 #: replicated persistent / host-bookkeeping state: parameters,
 #: momentum (non-ZeRO-1 — the ZeRO-1 allocator declares overrides),
@@ -232,6 +232,9 @@ _REPLICATED_SLOTS = (
     r"fault_inject", r"sdc_\w+", r"zero_mask", r"original_data",
     r"original_labels", r"minibatch_valid",
     r"pos_table", r"hits", r"metrics", r"time", r"histogram",
+    # the pre-norm block's gains, the expert layer's weight slabs and
+    # its routing totals (PR 25)
+    r"gain_\w+", r"weights_\w+", r"moe_stats",
 )
 
 

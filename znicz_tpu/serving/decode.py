@@ -789,6 +789,8 @@ class DecodeModel(Logger):
         per-sample FC layers ending in the vocabulary softmax."""
         units = self.model.forwards
         layers = self.model.manifest["layers"]
+        from znicz_tpu.export import refuse_unserved
+        refuse_unserved(units, "DecodeModel")
         plan: list[_Op] = []
         cache_specs: list[tuple[str, tuple]] = []
         phase = "seq"
