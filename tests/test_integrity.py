@@ -9,7 +9,9 @@ every layer the drill composes, fast and in-process.
 
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import numpy as np
 import pytest
@@ -100,6 +102,124 @@ def test_tensor_fingerprint_position_sensitive():
     b[0], b[2] = 2.0, 1.0   # swapped values must not cancel
     assert float(integrity.tensor_fingerprint(np, a)) \
         != float(integrity.tensor_fingerprint(np, b))
+
+
+def _flat_view_fingerprint(xp, arr):
+    """The fold as it was defined before PR 26 — samples through a
+    flat view of the whole tensor: the reference the sampler that
+    reads them where they lie is held to."""
+    flat = xp.ravel(arr).astype(xp.float32)
+    sample = flat[::max(1, int(flat.shape[0]) // 64)]
+    weights = 1.0 + (xp.arange(sample.shape[0], dtype=xp.float32)
+                     % 31.0)
+    return xp.sum(sample * weights)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", [
+    (5,), (1000,), (16, 16), (7, 13), (3, 4, 5),
+    (4, 64, 32),     # slab-like: the stride is a whole expert
+    (3, 3, 8, 16),
+    (2, 3, 7),       # fewer than 64 elements: every one is sampled
+])
+def test_tensor_fingerprint_reads_the_flat_views_samples(shape, dtype):
+    """Same elements, same order, same weights as
+    ``ravel(arr).astype(f32)[::max(1, n // 64)]``: bit for bit on
+    numpy, to the numpy-vs-jnp tolerance on jnp."""
+    import jax.numpy as jnp
+    arr = np.random.default_rng(5).normal(size=shape).astype(
+        jnp.dtype(dtype))
+    want = _flat_view_fingerprint(np, arr)
+    got = integrity.tensor_fingerprint(np, arr)
+    assert got.dtype == np.float32 and got == want, (got, want)
+    on_jnp = float(integrity.tensor_fingerprint(jnp, jnp.asarray(arr)))
+    assert abs(on_jnp - float(want)) <= 1e-4 * max(abs(float(want)), 1.0)
+    n = math.prod(shape)
+    assert np.array_equal(
+        np.ravel_multi_index(integrity.sample_positions(shape), shape),
+        np.arange(0, n, max(1, n // 64)))
+
+
+def _all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                yield from _all_eqns(inner)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_jnp_fold_has_no_tensor_sized_equation(dtype):
+    """No reshape of the operand, no whole-tensor convert: every
+    equation of the traced fold is sample-sized."""
+    import jax
+    import jax.numpy as jnp
+    operand = jnp.zeros((4, 64, 32), jnp.dtype(dtype))
+    closed = jax.make_jaxpr(
+        lambda a: integrity.tensor_fingerprint(jnp, a))(operand)
+    sized = [(eqn.primitive.name, var.aval.shape)
+             for eqn in _all_eqns(closed.jaxpr) for var in eqn.outvars
+             if var.aval.size >= operand.size]
+    assert not sized, sized
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a DESCRIBED v5e (compile only, nothing runs).  The
+    call loads libtpu, so it is made here, never at import."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no libtpu here, or another process has it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+_RELAYOUT = re.compile(
+    r"= \w+\[([\d,]*)\]\{\S*\} (copy|reshape|transpose)\(")
+
+
+@pytest.mark.parametrize("grad_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", [(4, 64, 32), (16, 128, 256),
+                                   (512, 384)])
+def test_fold_compiled_for_v5e_rewrites_no_tensor(v5e_chip, shape,
+                                                  grad_dtype):
+    """The three folds of ``_apply_param_xla`` around a momentum
+    update, compiled for the chip: the tiled layout makes a flat view
+    a whole-tensor ``copy`` (or a physical ``reshape``) there, three
+    per parameter per step — the optimised program must hold none."""
+    import jax
+    import jax.numpy as jnp
+
+    def step(w, acc, grad, fp):
+        fp = fp.at[2].add(integrity.tensor_fingerprint(jnp, w))
+        fp = fp.at[1].add(integrity.tensor_fingerprint(jnp, grad))
+        acc = 0.9 * acc - 1e-4 * grad.astype(jnp.float32)
+        w = w + acc
+        fp = fp.at[0].add(integrity.tensor_fingerprint(jnp, w))
+        return w, acc, fp
+
+    def struct(shp, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shp, dtype, sharding=v5e_chip)
+
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:   # a described chip's executable cannot be read back here
+        text = jax.jit(step, donate_argnums=(0, 1, 3)).lower(
+            struct(shape), struct(shape),
+            struct(shape, jnp.dtype(grad_dtype)),
+            struct((5,))).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    assert "T(8,128)" in text or "T(4,128)" in text   # a TPU program
+    rewrites = [m.group(0) for m in _RELAYOUT.finditer(text)
+                if math.prod(int(d) for d in m.group(1).split(",")
+                             if d) >= math.prod(shape)]
+    assert not rewrites, rewrites
 
 
 def test_vote_verdict_clean_selfbad_majority_tie():

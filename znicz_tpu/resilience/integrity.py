@@ -18,7 +18,18 @@ such chips at ~1/1000 machines.  This module is the detection layer:
   The fold rides the existing ``_apply_param_xla`` path inside the
   SAME jit region — zero extra compiles, zero extra per-step d2h
   (the fingerprint is read at the sentinel's vote cadence, like the
-  guard's anomaly state).
+  guard's anomaly state).  The samples are taken WHERE THEY LIE: the
+  positions come from the static shape on the host
+  (:func:`sample_positions`) and one gather at those constant
+  multi-dimensional indices reads them; only the ~64 samples are cast
+  to f32.  Not through a flat view: a TPU keeps a tensor tiled
+  (``T(8,128)``), so ``ravel`` is no bitcast there and XLA rewrote
+  the WHOLE tensor in linear order to pick 64 elements of it — three
+  folds per parameter per step, each a ``copy`` of
+  ``f32[16384,8,8,128]`` (1.63 ms) for an OLMoE expert slab: 18
+  copies, 29.4 ms of a 165 ms step, 1.19 x the momentum update they
+  guard (PERF.md §5/§6, PR 25/26).  On the host the cast-first form
+  copied every tensor a vote read back.
 
 - **Cross-replica vote** — post-update parameters are definitionally
   identical across data replicas, so per-replica fingerprints must
@@ -77,6 +88,7 @@ anomaly guard is on).  Knobs: ``sdc_vote_interval`` (50),
 
 from __future__ import annotations
 
+import math
 import os
 
 import numpy as np
@@ -102,6 +114,17 @@ def enabled() -> bool:
     return bool(root.common.engine.get("sdc_fingerprints", True))
 
 
+def sample_positions(shape) -> tuple[np.ndarray, ...]:
+    """THE definition of which elements a fingerprint reads: flat
+    (row-major) positions ``0, stride, 2·stride, …`` with ``stride =
+    max(1, n // FP_SAMPLES)``, returned as one index array per
+    dimension of ``shape``.  Computed on the host from the static
+    shape, so inside a jit region they are constants."""
+    n = math.prod(shape)
+    stride = max(1, n // FP_SAMPLES)
+    return np.unravel_index(np.arange(0, n, stride), shape)
+
+
 def tensor_fingerprint(xp, arr):
     """Position-weighted sub-sampled checksum of one tensor.
 
@@ -112,12 +135,17 @@ def tensor_fingerprint(xp, arr):
     recompute) and jax.numpy (the in-region fold); all math in f32 so
     a healthy device fold and the same fold re-traced later are
     bitwise-stable.
+
+    The samples are read WHERE THEY LIE — one gather at the constant
+    multi-dimensional indices of :func:`sample_positions` — and cast
+    to f32 after sampling.  Never through ``ravel(arr)[::stride]``:
+    that reads the same elements, but on a TPU a flat view of a tiled
+    tensor is a whole-tensor relayout, and a cast before sampling is a
+    whole-tensor pass on either backend (module docstring).
     """
-    flat = xp.ravel(arr).astype(xp.float32)
-    n = int(flat.shape[0])
-    stride = max(1, n // FP_SAMPLES)
-    sample = flat[::stride]
-    weights = 1.0 + (xp.arange(sample.shape[0], dtype=xp.float32)
+    positions = sample_positions(arr.shape)
+    sample = arr[positions].astype(xp.float32)
+    weights = 1.0 + (xp.arange(len(positions[0]), dtype=xp.float32)
                      % 31.0)
     return xp.sum(sample * weights)
 
