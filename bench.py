@@ -56,29 +56,6 @@ IMAGE_SIZE = int(os.environ.get("BENCH_IMAGE_SIZE", "227"))
 #: bf16 matmul/conv inputs with f32 params+accumulation — the
 #: MXU-native training mode (override: BENCH_PRECISION=float32)
 PRECISION = os.environ.get("BENCH_PRECISION", "bfloat16")
-#: BENCH_PALLAS=1 opts into every Pallas variant; a comma list of op
-#: names (BENCH_PALLAS=dropout) opts in per-op — the in-graph A/B
-#: lever (plain XLA is the measured winner — see PALLAS_BENCH.md).
-#: Unknown op names are rejected loudly: a typo silently matching no
-#: op would measure the XLA path while labelled as the Pallas arm.
-_PALLAS_OPS = ("lrn", "dropout")
-_pallas_env = os.environ.get("BENCH_PALLAS", "0")
-_pallas_toks = [t for t in _pallas_env.replace(" ", "").split(",") if t]
-if _pallas_toks and all(t in _PALLAS_OPS for t in _pallas_toks):
-    PALLAS = _pallas_toks
-elif any(c.isalpha() for c in _pallas_env):
-    raise SystemExit(f"BENCH_PALLAS={_pallas_env!r}: expected 0/1 or "
-                     f"a comma list of {_PALLAS_OPS}")
-else:
-    PALLAS = _pallas_env != "0"
-#: BENCH_S2D=1 opts into the space-to-depth conv rewrite (A/B lever)
-S2D = os.environ.get("BENCH_S2D", "0") != "0"
-#: BENCH_WGRAD_IM2COL=1: conv1 weight grad as a patches GEMM (A/B
-#: lever for the geometry-starved first-layer wgrad, PERF.md round 4)
-WGRAD_IM2COL = os.environ.get("BENCH_WGRAD_IM2COL", "0") != "0"
-#: BENCH_LRN_BAND_BF16=1: bf16 operands into the LRN band GEMMs (A/B
-#: lever for the bandwidth-bound band adjoints, PERF.md round 4)
-LRN_BAND_BF16 = os.environ.get("BENCH_LRN_BAND_BF16", "0") != "0"
 #: BENCH_LRN_D_BF16: bf16 STORAGE for the shared LRN denominator
 #: tensors (~1.5 GB/step of f32 traffic at b384 — PERF.md round 5,
 #: measured +5.4%).  Unset = the engine's auto default (on in bf16
@@ -222,10 +199,6 @@ def main() -> None:
     from znicz_tpu.utils.config import root
 
     root.common.precision_type = PRECISION
-    root.common.engine.use_pallas = PALLAS
-    root.common.engine.space_to_depth = S2D
-    root.common.engine.conv_wgrad_im2col = WGRAD_IM2COL
-    root.common.engine.lrn_band_bf16 = LRN_BAND_BF16
     if LRN_D_BF16:
         root.common.engine.lrn_d_bf16 = LRN_D_BF16 != "0"
 

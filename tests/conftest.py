@@ -77,6 +77,30 @@ def make_blobs(n_per_class: int, n_classes: int, dim: int,
     return data[order], labels[order]
 
 
+def blob_classifier(name: str, n_per_class: int, epochs: int,
+                    minibatch: int = 12, **kwargs):
+    """An UNinitialized two-layer classifier over :func:`make_blobs`
+    (TRAIN only, ``3 · n_per_class ÷ minibatch`` steps an epoch) — the
+    toy the dispatch and driver tests drive by hand."""
+    from znicz_tpu.loader.fullbatch import ArrayLoader
+    from znicz_tpu.models.standard_workflow import StandardWorkflow
+
+    data, labels = make_blobs(n_per_class, 3, 10)
+    wf = StandardWorkflow(
+        name=name,
+        loader_factory=lambda w: ArrayLoader(
+            w, train_data=data, train_labels=labels,
+            minibatch_size=minibatch),
+        layers=[{"type": "all2all_tanh",
+                 "->": {"output_sample_shape": 16},
+                 "<-": {"learning_rate": 0.05}},
+                {"type": "softmax", "->": {"output_sample_shape": 3},
+                 "<-": {"learning_rate": 0.05}}],
+        decision_config={"max_epochs": epochs}, **kwargs)
+    wf._max_fires = 100_000
+    return wf
+
+
 def positional_task_workflow(layers, data_seed=9, prng_seed=11,
                              t=9, d=8, n_classes=3, max_epochs=30):
     """Shared builder for 'which third of the sequence carries the

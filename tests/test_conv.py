@@ -84,57 +84,3 @@ def test_non_nhwc_input_rejected():
     unit.link_attrs(src, ("input", "output"))
     with pytest.raises(ValueError, match="NHWC"):
         unit.initialize(device=NumpyDevice())
-
-
-def test_space_to_depth_exact_alexnet_conv1():
-    """The stride-4 11x11 RGB conv (AlexNet conv1 geometry, small) must
-    take the space-to-depth path and match the im2col oracle exactly
-    (the rewrite is a re-indexing, not an approximation)."""
-    import jax
-
-    from znicz_tpu.utils.config import root
-
-    root.common.engine.space_to_depth = True  # opt-in feature
-    try:
-        rng = np.random.default_rng(8)
-        x = rng.normal(size=(2, 51, 51, 3)).astype(np.float32)
-        np_u, xla_u = run_both(conv.Conv, x, n_kernels=8, kx=11, ky=11,
-                               sliding=(4, 4))
-    finally:
-        root.common.engine.space_to_depth = False
-    assert xla_u._s2d, "space-to-depth should engage for stride-4 RGB"
-    np.testing.assert_allclose(np_u.output.mem, xla_u.output.mem,
-                               rtol=1e-4, atol=1e-5)
-    # gradient path: linear_transpose of the s2d conv vs the plain conv
-    unit = xla_u
-    w = unit.weights.devmem
-    cot = rng.normal(
-        size=unit.output.shape).astype(np.float32)
-    t_x = jax.linear_transpose(lambda xx: unit.conv_raw(xx, w),
-                               unit.input.devmem)
-    (gx,) = t_x(cot)
-    unit._s2d = False
-    t_x_ref = jax.linear_transpose(lambda xx: unit.conv_raw(xx, w),
-                                   unit.input.devmem)
-    (gx_ref,) = t_x_ref(cot)
-    np.testing.assert_allclose(np.asarray(gx), np.asarray(gx_ref),
-                               rtol=1e-4, atol=1e-5)
-
-
-def test_space_to_depth_guard_declines_inexact_geometry():
-    """Geometries where the block count formula would over-produce
-    outputs must fall back to the plain conv: hp=53 gives
-    ceil(53/4)-ceil(11/4)+1 = 12 != (53-11)//4+1 = 11."""
-    from znicz_tpu.utils.config import root
-
-    root.common.engine.space_to_depth = True  # opt-in feature
-    try:
-        rng = np.random.default_rng(8)
-        x = rng.normal(size=(2, 53, 53, 3)).astype(np.float32)
-        np_u, xla_u = run_both(conv.Conv, x, n_kernels=8, kx=11, ky=11,
-                               sliding=(4, 4))
-    finally:
-        root.common.engine.space_to_depth = False
-    assert not xla_u._s2d
-    np.testing.assert_allclose(np_u.output.mem, xla_u.output.mem,
-                               rtol=1e-4, atol=1e-5)

@@ -118,38 +118,3 @@ def test_numeric_gradient_linear_conv():
                    - loss(w0, b0, xm_.reshape(X.shape))) / (2 * eps)
         np.testing.assert_allclose(err_input.reshape(-1)[k], numeric,
                                    rtol=2e-2, atol=1e-2)
-
-
-def test_wgrad_im2col_matches_transpose_conv():
-    """The opt-in patches-GEMM weight grad (engine.conv_wgrad_im2col,
-    for MXU-starved first layers) must equal the transposed gradient
-    conv — same sums, reassociated."""
-    from znicz_tpu.utils.config import root
-
-    probe = build_pair(conv.Conv, gd_conv.GradientDescentConv,
-                       NumpyDevice(), np.zeros(1))[0]
-    err = make_err(probe)
-    results = {}
-    for mode in ("transpose", "im2col"):
-        root.common.engine.conv_wgrad_im2col = mode == "im2col"
-        try:
-            fwd, bwd = build_pair(conv.Conv,
-                                  gd_conv.GradientDescentConv,
-                                  XLADevice(), err)
-            assert bwd._wgrad_im2col == (mode == "im2col")
-            if "w0" in results:
-                fwd.weights.reset(results["w0"])
-                fwd.weights.initialize(bwd.device)
-                fwd.bias.reset(results["b0"])
-                fwd.bias.initialize(bwd.device)
-            else:
-                results["w0"] = fwd.weights.mem.copy()
-                results["b0"] = fwd.bias.mem.copy()
-            fwd.run()
-            bwd.run()
-            bwd.weights.map_read()
-            results[mode] = bwd.weights.mem.copy()
-        finally:
-            root.common.engine.conv_wgrad_im2col = False
-    np.testing.assert_allclose(results["transpose"], results["im2col"],
-                               rtol=1e-4, atol=1e-5)

@@ -82,27 +82,3 @@ def test_normalization_shrinks_large_activations():
     fwd, _ = build_pair(NumpyDevice(), alpha=1.0, beta=0.75, k=1.0, n=5)
     fwd.run()
     assert np.all(np.abs(fwd.output.mem) <= np.abs(X) + 1e-6)
-
-
-def test_lrn_band_bf16_lever_close_to_f32():
-    """engine.lrn_band_bf16 feeds the band GEMMs bf16 operands; the
-    result must stay close to the f32 path (the band term is α-damped
-    in d, so bf16 operand rounding is far below the k=2 offset)."""
-    import jax.numpy as jnp
-
-    from znicz_tpu.ops.normalization import _window_sum
-    from znicz_tpu.utils.config import root
-
-    rng = np.random.default_rng(5)
-    # x² like the forward's window operand: positive summands, so
-    # bf16 rounding stays a RELATIVE error (zero-crossing sums would
-    # make 'relative' meaningless)
-    x = (rng.normal(0, 2, size=(64, 96)).astype(np.float32)) ** 2
-    ref = np.asarray(_window_sum(jnp, x, 5))
-    root.common.engine.lrn_band_bf16 = True
-    try:
-        got = np.asarray(_window_sum(jnp, x, 5))
-    finally:
-        root.common.engine.lrn_band_bf16 = False
-    np.testing.assert_allclose(got, ref, rtol=2e-2, atol=2e-2)
-    assert not np.array_equal(got, ref)  # the lever actually engaged

@@ -3,113 +3,9 @@
 reference test strategy, SURVEY.md §4)."""
 
 import numpy as np
-import pytest
 
 import jax
 import jax.numpy as jnp
-
-from znicz_tpu.ops import pallas_kernels
-from znicz_tpu.ops.normalization import _window_sum
-
-RNG = np.random.default_rng(21)
-PARAMS = dict(alpha=1e-4, beta=0.75, k=2.0)
-
-
-def lrn_fwd_oracle(x, n, **p):
-    d = p["k"] + p["alpha"] * _window_sum(np, x * x, n)
-    return x * d ** (-p["beta"])
-
-
-def lrn_bwd_oracle(x, err, n, **p):
-    d = p["k"] + p["alpha"] * _window_sum(np, x * x, n)
-    t = err * x * d ** (-p["beta"] - 1.0)
-    return (err * d ** (-p["beta"])
-            - 2.0 * p["alpha"] * p["beta"] * x
-            * _window_sum(np, t, n, half_low=n - 1 - n // 2))
-
-
-@pytest.mark.parametrize("n", [5, 4, 3])
-@pytest.mark.parametrize("shape", [(2, 7, 7, 96), (64, 33)])
-def test_lrn_forward_matches_oracle(n, shape):
-    x = RNG.normal(0, 2, size=shape).astype(np.float32)
-    got = np.asarray(pallas_kernels.lrn_forward(
-        x, n=n, interpret=True, **PARAMS))
-    np.testing.assert_allclose(got, lrn_fwd_oracle(x, n, **PARAMS),
-                               rtol=1e-5, atol=1e-6)
-
-
-@pytest.mark.parametrize("n", [5, 4])
-@pytest.mark.parametrize("shape", [(2, 5, 5, 40), (700, 96)])
-def test_lrn_backward_matches_oracle(n, shape):
-    """Covers the adjoint window (asymmetric for even n) and the
-    multi-tile grid path (700 rows > one 512-row tile)."""
-    x = RNG.normal(0, 2, size=shape).astype(np.float32)
-    err = RNG.normal(size=shape).astype(np.float32)
-    got = np.asarray(pallas_kernels.lrn_backward(
-        x, err, n=n, interpret=True, **PARAMS))
-    np.testing.assert_allclose(got, lrn_bwd_oracle(x, err, n, **PARAMS),
-                               rtol=1e-4, atol=1e-6)
-
-
-def test_lrn_backward_is_vjp_of_jnp_forward():
-    """The fused analytic backward must equal jax.vjp of the plain
-    jnp forward composition (the non-pallas XLA path) — the two code
-    paths a workflow can take stay consistent."""
-    import jax
-    import jax.numpy as jnp
-
-    x = RNG.normal(0, 1, size=(3, 4, 4, 24)).astype(np.float32)
-    err = RNG.normal(size=x.shape).astype(np.float32)
-
-    def jnp_fwd(xx):
-        d = PARAMS["k"] + PARAMS["alpha"] * _window_sum(jnp, xx * xx, 5)
-        return xx * d ** (-PARAMS["beta"])
-
-    _, vjp = jax.vjp(jnp_fwd, jnp.asarray(x))
-    (want,) = vjp(jnp.asarray(err))
-    got = pallas_kernels.lrn_backward(x, err, n=5, interpret=True,
-                                      **PARAMS)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-6)
-
-
-def test_use_pallas_gate():
-    from znicz_tpu.backends import NumpyDevice, XLADevice
-    from znicz_tpu.utils.config import root
-
-    assert not pallas_kernels.use_pallas(NumpyDevice())
-    dev = XLADevice()  # cpu platform under tests
-    assert not pallas_kernels.use_pallas(dev)
-
-    class FakeTPU:  # platform check + the opt-in config switch
-        class jax_device:
-            platform = "tpu"
-
-    # default is OFF even on TPU (in-graph layout copies lose to
-    # fused XLA — see PALLAS_BENCH.md); config opts in
-    assert not pallas_kernels.use_pallas(FakeTPU())
-    root.common.engine.use_pallas = True
-    assert pallas_kernels.use_pallas(FakeTPU())
-    root.common.engine.use_pallas = False
-    assert not pallas_kernels.use_pallas(FakeTPU())
-
-
-def test_softmax_argmax_matches_xla():
-    """Fused softmax+argmax kernel (interpret mode) vs the XLA
-    composition."""
-    import jax
-    import jax.numpy as jnp
-
-    from znicz_tpu.ops.pallas_kernels import softmax_argmax
-
-    rng = np.random.default_rng(5)
-    v = jnp.asarray(rng.normal(size=(96, 13)).astype(np.float32))
-    probs, idx = softmax_argmax(v, interpret=True)
-    np.testing.assert_allclose(np.asarray(probs),
-                               np.asarray(jax.nn.softmax(v, axis=1)),
-                               rtol=1e-5, atol=1e-6)
-    np.testing.assert_array_equal(np.asarray(idx),
-                                  np.asarray(jnp.argmax(v, axis=1)))
 
 
 def test_layer_norm_forward_matches_reference():

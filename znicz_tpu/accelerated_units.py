@@ -306,7 +306,48 @@ class JitRegion(Logger):
         from znicz_tpu.utils.config import root
         return bool(root.common.engine.get("debug_checks", False))
 
-    def run(self) -> None:
+    def _dispatch(self, variant: tuple, build, span: str,
+                  count: int = 1, donate: bool = True,
+                  **span_args) -> None:
+        """The ONE dispatch protocol of a region; :meth:`run`,
+        :meth:`run_chunk`, :meth:`run_accum` and :meth:`run_undonated`
+        pass what differs between their programs and nothing else:
+
+        - ``variant``: the program's tag — ``("step",)``,
+          ``("chunk", n)``, ``("accum", n)``, ``("nodonate", phase)``
+          — part of the in-memory key and of the persisted one;
+        - ``build(skips, leaves)``: the un-jitted, named function;
+        - ``span`` / ``span_args``: the warmed call's span is
+          ``<span>:<region>`` (cat ``region``) with these arguments,
+          and the ``compile:<region>`` span carries the same ones;
+        - ``count``: the steps one dispatch adds to
+          ``znicz_region_steps_total``;
+        - ``donate``: whether the program may reuse its input buffers.
+
+        The protocol: vectors collected once and unmapped, the gate
+        skips and the units' static keys make the key, the program is
+        looked up; on a miss it comes from the persisted store
+        (:meth:`_persisted_program`, which counts and spans its own
+        eager compile) or from a lazy ``jax.jit`` whose first call is
+        the compile — counted on ``znicz_xla_compiles_total`` and
+        spanned ``compile:<region>``; a hit is the cat-``region``
+        span alone.  Then the step count, then the leaves go back
+        into their Vectors.
+
+        ``engine.debug_checks`` is decided here, once:
+
+        ========  ====================================================
+        step      compiled through ``checkify``: no donation (the
+                  error pytree breaks input→output aliasing), never
+                  persisted, the located error raised after each call
+        chunk     ``n`` per-step dispatches of the checked step
+                  program (checkify's error pytree does not thread
+                  through the scan harness)
+        accum     refused: the accumulation scan cannot carry it
+        nodonate  refused likewise: the phase programs of the
+                  pipeline executor are built bare
+        ========  ====================================================
+        """
         if self._vectors is None:
             self._vectors = self._collect_vectors()
         vectors = self._vectors
@@ -314,22 +355,41 @@ class JitRegion(Logger):
             vec.unmap()
         skips = tuple(bool(unit.gate_skip) for unit in self.units)
         checks = self.debug_checks
+        kind = variant[0]
+        if checks and kind == "chunk":
+            for _ in range(count):
+                self.run()
+            return
+        if checks and kind != "step":
+            raise NotImplementedError(
+                f"engine.debug_checks does not compose with the "
+                f"'{kind}' program of region '{self.name}' "
+                f"(checkify's error state threads through neither the "
+                f"accumulation scan nor the pipeline's phase "
+                f"programs); disable one of them")
+        # the step program's key carries the checkify flag where the
+        # others carry their tag: only it is ever built under checkify
         key = tuple(unit.region_key() for unit in self.units) \
-            + (skips, checks)
+            + (skips,) + ((checks,) if kind == "step" else variant)
         fn = self._cache.get(key)
         leaves = [vec._devmem for vec in vectors]
         if fn is None:
-            self.debug("region '%s': compiling for key %s "
-                       "(%d units, %d leaves)", self.name, key,
+            self.debug("region '%s': compiling %s for key %s "
+                       "(%d units, %d leaves)", self.name, variant, key,
                        len(self.units), len(vectors))
+            body = build(skips, leaves)
             if not checks:  # checkify programs are never persisted
-                fn = self._persisted_program(
-                    ("step",) + key, self.build_callable(skips),
-                    leaves, donate=True)
+                fn = self._persisted_program(variant + key, body,
+                                             leaves, donate=donate)
             if fn is not None:
                 self._cache[key] = fn
                 out = fn(*leaves)
             else:
+                if checks:
+                    from jax.experimental import checkify
+                    body = checkify.checkify(
+                        body, errors=checkify.all_checks)
+                    donate = False
                 # compile/retrace counter: the steady-state retrace
                 # guard asserts this stays flat once every variant is
                 # warmed.  jit compiles lazily, so the first dispatch
@@ -337,28 +397,33 @@ class JitRegion(Logger):
                 # trace+compile cost actually lands.
                 _metrics.xla_compiles(f"region:{self.name}").inc()
                 with _tracing.TRACER.span(f"compile:{self.name}",
-                                          cat="compile"):
-                    fn = self._cache[key] = self._build(skips, checks)
-                    if checks:
-                        err, out = fn(*leaves)
-                        err.throw()
-                    else:
-                        out = fn(*leaves)
+                                          cat="compile", **span_args):
+                    fn = self._cache[key] = jax.jit(
+                        body, donate_argnums=(
+                            tuple(range(len(leaves))) if donate else ()))
+                    out = fn(*leaves)
         else:
-            # the warmed program's call is a span of its own, inside
-            # the region unit's fire: past the few programs the
-            # runtime admits in flight the call BLOCKS until one
-            # finishes, and that wait is not the unit's work
-            with _tracing.TRACER.span(f"dispatch:{self.name}",
-                                      cat="region"):
+            # the warmed program's call is a span of its own (under
+            # wf.run() a child of the region unit's fire): past the few
+            # programs the runtime admits in flight the call BLOCKS
+            # until one finishes, and that wait is not the unit's work
+            with _tracing.TRACER.span(f"{span}:{self.name}",
+                                      cat="region", **span_args):
                 out = fn(*leaves)
-            if checks:
-                err, out = out
-                err.throw()  # located NaN/inf/OOB report, e.g. "nan
-                #              generated by primitive: log" + traceback
-        _metrics.region_steps(self.name).inc()
+        if checks:
+            err, out = out
+            err.throw()  # located NaN/inf/OOB report, e.g. "nan
+            #              generated by primitive: log" + traceback
+        _metrics.region_steps(self.name).inc(count)
         for vec, leaf in zip(vectors, out):
             vec.devmem = leaf
+
+    def run(self) -> None:
+        """One region step: the donated ``znicz_step__<region>``
+        program."""
+        self._dispatch(("step",),
+                       lambda skips, leaves: self.build_callable(skips),
+                       "dispatch")
 
     def program_name(self, variant: str) -> str:
         """``znicz_<variant>__<region>``: the name a jitted program's
@@ -450,25 +515,8 @@ class JitRegion(Logger):
         """
         if n_steps == 1:
             return self.run()
-        if self._vectors is None:
-            self._vectors = self._collect_vectors()
-        vectors = self._vectors
-        for vec in vectors:
-            vec.unmap()
-        skips = tuple(bool(unit.gate_skip) for unit in self.units)
-        if self.debug_checks:
-            # checkify's error pytree doesn't thread through this scan
-            # harness; debug runs take the per-step path
-            for _ in range(n_steps):
-                self.run()
-            return
-        key = tuple(unit.region_key() for unit in self.units) \
-            + (skips, "chunk", n_steps)
-        fn = self._cache.get(key)
-        leaves = [vec._devmem for vec in vectors]
-        if fn is None:
-            self.debug("region '%s': compiling %d-step scan chunk",
-                       self.name, n_steps)
+
+        def build(skips, leaves):
             body, invariant = self._analyzed_body(
                 self.build_callable(skips), leaves)
 
@@ -477,32 +525,11 @@ class JitRegion(Logger):
                                           n_steps)
                 return tuple(scanned)
 
-            self._named(chunk_fn, f"chunk{n_steps}")
+            return self._named(chunk_fn, f"chunk{n_steps}")
 
-            fn = self._persisted_program(("chunk", n_steps) + key,
-                                         chunk_fn, leaves, donate=True)
-            if fn is not None:
-                self._cache[key] = fn
-                out = fn(*leaves)
-            else:
-                _metrics.xla_compiles(f"region:{self.name}").inc()
-                fn = self._cache[key] = jax.jit(
-                    chunk_fn,
-                    donate_argnums=tuple(range(len(vectors))))
-                with _tracing.TRACER.span(f"compile:{self.name}",
-                                          cat="compile",
-                                          chunk=n_steps):
-                    out = fn(*leaves)  # first dispatch = trace+compile
-        else:
-            # chunked dispatches bypass RegionUnit._fire (bench /
-            # run_chunked drive this directly), so the dispatch gets
-            # its own span — one per chunk, not per step
-            with _tracing.TRACER.span(f"chunk:{self.name}",
-                                      cat="region", steps=n_steps):
-                out = fn(*leaves)
-        _metrics.region_steps(self.name).inc(n_steps)
-        for vec, leaf in zip(vectors, out):
-            vec.devmem = leaf
+        # one span per chunk, not per step
+        self._dispatch(("chunk", n_steps), build, "chunk",
+                       count=n_steps, steps=n_steps)
 
     # -- shared scan machinery (run_chunk / run_accum) ------------------
     def _analyzed_body(self, body, leaves):
@@ -580,24 +607,8 @@ class JitRegion(Logger):
         """
         if n_micro == 1:
             return self.run()
-        if self._vectors is None:
-            self._vectors = self._collect_vectors()
-        vectors = self._vectors
-        for vec in vectors:
-            vec.unmap()
-        skips = tuple(bool(unit.gate_skip) for unit in self.units)
-        if self.debug_checks:
-            raise NotImplementedError(
-                "engine.debug_checks does not compose with "
-                "run_accum (checkify cannot thread the accumulation "
-                "scan); disable one of them")
-        key = tuple(unit.region_key() for unit in self.units) \
-            + (skips, "accum", n_micro)
-        fn = self._cache.get(key)
-        leaves = [vec._devmem for vec in vectors]
-        if fn is None:
-            self.debug("region '%s': compiling %d-microbatch "
-                       "accumulate-then-apply step", self.name, n_micro)
+
+        def build(skips, leaves):
             accum_body, invariant = self._analyzed_body(
                 self.build_callable(skips,
                                     accum_phase=("accum", n_micro)),
@@ -610,32 +621,14 @@ class JitRegion(Logger):
                                          n_micro - 1)
                 return apply_body(*merged)
 
-            self._named(accum_fn, f"accum{n_micro}")
+            # what the persisted key hashes is the jaxpr of this FULL
+            # composed accum+apply function — the accum body alone is
+            # blind to apply-only constants (lr, momentum), which
+            # would let a wrong optimizer step load
+            return self._named(accum_fn, f"accum{n_micro}")
 
-            # the persisted key hashes the jaxpr of the FULL composed
-            # accum+apply function — the accum body alone is blind to
-            # apply-only constants (lr, momentum), which would let a
-            # wrong optimizer step load
-            fn = self._persisted_program(("accum", n_micro) + key,
-                                         accum_fn, leaves, donate=True)
-            if fn is not None:
-                self._cache[key] = fn
-                out = fn(*leaves)
-            else:
-                _metrics.xla_compiles(f"region:{self.name}").inc()
-                fn = self._cache[key] = jax.jit(
-                    accum_fn,
-                    donate_argnums=tuple(range(len(vectors))))
-                with _tracing.TRACER.span(f"compile:{self.name}",
-                                          cat="compile", accum=n_micro):
-                    out = fn(*leaves)  # first dispatch = trace+compile
-        else:
-            with _tracing.TRACER.span(f"accum:{self.name}",
-                                      cat="region", micro=n_micro):
-                out = fn(*leaves)
-        _metrics.region_steps(self.name).inc(n_micro)
-        for vec, leaf in zip(vectors, out):
-            vec.devmem = leaf
+        self._dispatch(("accum", n_micro), build, "accum",
+                       count=n_micro, micro=n_micro)
 
     def run_undonated(self,
                       accum_phase: "tuple[str, int] | None" = None,
@@ -646,39 +639,11 @@ class JitRegion(Logger):
         activation store holds references to leaf buffers across
         dispatches, which donation would invalidate.  Programs cache
         alongside the donated variants under a distinct key."""
-        if self._vectors is None:
-            self._vectors = self._collect_vectors()
-        vectors = self._vectors
-        for vec in vectors:
-            vec.unmap()
-        skips = tuple(bool(unit.gate_skip) for unit in self.units)
-        key = tuple(unit.region_key() for unit in self.units) \
-            + (skips, "nodonate", accum_phase)
-        fn = self._cache.get(key)
-        leaves = [vec._devmem for vec in vectors]
-        if fn is None:
-            self.debug("region '%s': compiling undonated variant "
-                       "(phase=%s)", self.name, accum_phase)
-            fn = self._persisted_program(
-                ("nodonate", accum_phase) + key,
-                self.build_callable(skips, accum_phase=accum_phase),
-                leaves, donate=False)
-            if fn is not None:
-                self._cache[key] = fn
-                out = fn(*leaves)
-            else:
-                _metrics.xla_compiles(f"region:{self.name}").inc()
-                with _tracing.TRACER.span(f"compile:{self.name}",
-                                          cat="compile"):
-                    fn = self._cache[key] = jax.jit(
-                        self.build_callable(skips,
-                                            accum_phase=accum_phase))
-                    out = fn(*leaves)
-        else:
-            out = fn(*leaves)
-        _metrics.region_steps(self.name).inc()
-        for vec, leaf in zip(vectors, out):
-            vec.devmem = leaf
+        self._dispatch(
+            ("nodonate", accum_phase),
+            lambda skips, leaves: self.build_callable(
+                skips, accum_phase=accum_phase),
+            "dispatch", donate=False)
 
     def _persisted_program(self, variant: tuple, fn, leaves,
                            donate: bool):
@@ -744,18 +709,6 @@ class JitRegion(Logger):
             return fallback(*leaves)
 
         return call
-
-    def _build(self, skips: tuple[bool, ...], checks: bool = False):
-        assert self._vectors is not None
-        fn = self.build_callable(skips)
-        if checks:
-            from jax.experimental import checkify
-            # no donation: checkify threads an error-state pytree
-            # through the program, which breaks input→output aliasing
-            return jax.jit(checkify.checkify(
-                fn, errors=checkify.all_checks))
-        return jax.jit(fn,
-                       donate_argnums=tuple(range(len(self._vectors))))
 
 
 class RegionUnit(AcceleratedUnit):

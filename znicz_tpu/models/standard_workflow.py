@@ -26,7 +26,7 @@ The hot chain is backend-dependent — the TPU-first core of the design:
 
 from __future__ import annotations
 
-import functools
+import time
 from typing import Any, Callable, Sequence
 
 import numpy as np
@@ -522,8 +522,11 @@ class StandardWorkflow(AcceleratedWorkflow):
         the epoch side chain (snapshotter, plotters, LR adjuster) fire
         at the same points; an active LR-adjust policy is applied at
         chunk granularity (piecewise-constant within a chunk) rather
-        than per step.  Requires the XLA backend + a device-schedule
-        loader; falls back to :meth:`run` otherwise.
+        than per step, and the anomaly guard's host hook arms its
+        fault / SDC injection leaves once per dispatch, so under
+        ``run_chunked(k)`` an armed fault holds for the dispatch's k
+        steps.  Requires the XLA backend + a device-schedule loader;
+        falls back to :meth:`run` otherwise.
         """
         region_unit = self._region_unit
         loader = self.loader
@@ -542,12 +545,6 @@ class StandardWorkflow(AcceleratedWorkflow):
             return self.run()
         region = region_unit.region
         assert region is not None
-        decision = self.decision
-        side_units = [u for u in decision.links_to
-                      if u is not self.repeater and u is not self.end_point]
-        import time as _time
-        self.run_started_at = _time.time()
-        self.stopped.value = False
 
         def advance() -> int:
             """The chunk's host bookkeeping; returns its step count."""
@@ -561,42 +558,56 @@ class StandardWorkflow(AcceleratedWorkflow):
                 k += 1
             return k
 
-        chunks = 0
-        # the spans wf.run() records: the root, ONE fire of the loader
-        # per dispatch (not k), the decision's; chunk:<region> is
-        # run_chunk's own
+        def dispatch(k: int) -> None:
+            region.run_chunk(k)
+            if (self.lr_adjuster is not None
+                    and loader.minibatch_class == TRAIN):
+                # chunk-granular application of the per-step policy
+                self.lr_adjuster._n_iterations += k - 1
+                self.lr_adjuster.run()
+
+        self._drive(advance, dispatch, "chunks")
+
+    def _drive(self, advance, dispatch, noun: str) -> None:
+        """The ONE step loop of the drivers that walk the hot loop
+        themselves (``run_chunked``, ``run_accumulated``,
+        ``run_pipelined``), recording the spans ``wf.run()`` records:
+        the root, ONE fire of the loader per dispatch around
+        ``advance()`` (a whole chunk's or optimizer step's
+        bookkeeping), the decision's; the region's dispatch span is
+        its own.  Per dispatch, in the graph's order: the loader, the
+        anomaly guard's host hook (it arms the fault / SDC injection
+        leaves — a no-op where no fault site is configured; an armed
+        fault holds for every step of the dispatch, so for the k steps
+        of a chunk), ``dispatch(what advance returned)`` — the
+        driver's device call and its LR-adjuster application — then
+        the decision and, at epoch end, its side chain.  ``noun``
+        names the driver's unit of work in the ``max_fires`` error."""
+        loader = self.loader
+        decision = self.decision
+        guard = self.anomaly_guard
+        side_units = [u for u in decision.links_to
+                      if u is not self.repeater and u is not self.end_point]
+        self.run_started_at = time.time()
+        self.stopped.value = False
+        fires = 0
         with self._run_span():
             while not decision.complete and not self.stopped:
-                k = loader._record_fire(advance)
-                region.run_chunk(k)
-                if (self.lr_adjuster is not None
-                        and loader.minibatch_class == TRAIN):
-                    # chunk-granular application of the per-step policy
-                    self.lr_adjuster._n_iterations += k - 1
-                    self.lr_adjuster.run()
+                advanced = loader._record_fire(advance)
+                if guard is not None:
+                    guard.host_run()
+                dispatch(advanced)
                 decision._fire()
                 self._fire_epoch_side_units(side_units)
-                chunks += 1
+                fires += 1
                 if (self._max_fires is not None
-                        and chunks > self._max_fires):
+                        and fires > self._max_fires):
                     raise RuntimeError(
                         f"workflow '{self.name}' exceeded max_fires="
-                        f"{self._max_fires} chunks (runaway loop?)")
-
-    def _advance_microbatches(self, n_micro: int) -> int:
-        """One optimizer step's host bookkeeping (+ schedule upload if
-        stale): a TRAIN step advances the index stream over all its
-        ``n_micro`` microbatches.  Returns the minibatch class."""
-        loader = self.loader
-        loader.run()
-        if loader.minibatch_class == TRAIN:
-            for _ in range(n_micro - 1):
-                loader.run()
-        return loader.minibatch_class
+                        f"{self._max_fires} {noun} (runaway loop?)")
 
     def _fire_epoch_side_units(self, side_units) -> None:
-        """The decision's epoch side chain, for the drivers that walk
-        the hot loop themselves."""
+        """The decision's epoch side chain, for :meth:`_drive`."""
         decision = self.decision
         if not (decision.epoch_ended or decision.complete):
             return
@@ -605,6 +616,66 @@ class StandardWorkflow(AcceleratedWorkflow):
                 continue  # the drivers apply it per optimizer step
             if not unit.gate_block and not unit.gate_skip:
                 unit._fire()
+
+    def _microbatches(self, microbatches: int | None) -> int:
+        """M of an optimizer step split into microbatches: the
+        argument, else ``engine.grad_accum``."""
+        if microbatches is None:
+            from znicz_tpu.utils.config import root
+            microbatches = root.common.engine.get("grad_accum", 1) or 1
+        return int(microbatches)
+
+    def _require_microbatchable(self, n_micro: int, noun: str) -> None:
+        """What ``run_accumulated`` and ``run_pipelined`` (``noun``:
+        "accumulated" / "pipelined") both need before they build
+        anything: an XLA region, a device-schedule loader, and a TRAIN
+        set that divides into optimizer steps of ``n_micro`` FULL
+        microbatches."""
+        loader = self.loader
+        if self._region_unit is None or not loader._on_device_schedule():
+            raise RuntimeError(
+                f"workflow '{self.name}': run_{noun} requires the XLA "
+                f"region + a device-schedule loader (a {noun} step is "
+                f"made of on-device programs; there is no meaningful "
+                f"host fallback)")
+        n_train = int(loader.class_lengths[TRAIN])
+        if n_train % (loader.max_minibatch_size * n_micro) != 0:
+            raise RuntimeError(
+                f"workflow '{self.name}': TRAIN set of {n_train} does "
+                f"not divide into {noun} steps of "
+                f"{loader.max_minibatch_size} × {n_micro} microbatches — "
+                f"a ragged tail microbatch would break the fixed "
+                f"{noun} program")
+
+    def _drive_microbatched(self, n_micro: int, train_step,
+                            noun: str) -> None:
+        """The step ``run_accumulated`` and ``run_pipelined`` share: a
+        TRAIN step advances the index stream over all its ``n_micro``
+        microbatches, calls ``train_step()`` and applies the LR
+        adjuster once (ONE optimizer step happened, whatever M is);
+        eval/validation minibatches run unaccumulated through the
+        regular region program."""
+        loader = self.loader
+        region = self._region_unit.region
+        assert region is not None
+
+        def advance() -> int:
+            """One optimizer step's host bookkeeping (+ schedule
+            upload if stale); returns the minibatch class."""
+            loader.run()
+            if loader.minibatch_class == TRAIN:
+                for _ in range(n_micro - 1):
+                    loader.run()
+            return loader.minibatch_class
+
+        def dispatch(cls: int) -> None:
+            if cls != TRAIN:
+                return region.run()
+            train_step()
+            if self.lr_adjuster is not None:
+                self.lr_adjuster.run()
+
+        self._drive(advance, dispatch, f"{noun} steps")
 
     def run_accumulated(self, microbatches: int | None = None) -> None:
         """Gradient-accumulation training driver (round 20): every
@@ -624,63 +695,15 @@ class StandardWorkflow(AcceleratedWorkflow):
         fingerprints fold once, at apply.  Eval/validation minibatches
         run unaccumulated through the regular region program.
         """
-        region_unit = self._region_unit
-        loader = self.loader
-        if microbatches is None:
-            from znicz_tpu.utils.config import root
-            microbatches = int(root.common.engine.get("grad_accum", 1) or 1)
-        n_micro = int(microbatches)
+        n_micro = self._microbatches(microbatches)
         if n_micro <= 1:
             return self.run()
-        if region_unit is None or not loader._on_device_schedule():
-            raise RuntimeError(
-                f"workflow '{self.name}': run_accumulated requires the "
-                f"XLA region + a device-schedule loader (accumulation "
-                f"is an on-device scan; there is no meaningful host "
-                f"fallback)")
-        span = loader.max_minibatch_size * n_micro
-        n_train = int(loader.class_lengths[TRAIN])
-        if n_train % span != 0:
-            raise RuntimeError(
-                f"workflow '{self.name}': TRAIN set of {n_train} does "
-                f"not divide into accumulated steps of "
-                f"{loader.max_minibatch_size} × {n_micro} microbatches — "
-                f"a ragged tail microbatch would break the fixed "
-                f"accumulation program")
-        region = region_unit.region
-        assert region is not None
-        decision = self.decision
-        side_units = [u for u in decision.links_to
-                      if u is not self.repeater and u is not self.end_point]
-        guard = getattr(self, "anomaly_guard", None)
+        self._require_microbatchable(n_micro, "accumulated")
         from znicz_tpu.observe import metrics as _metrics
         _metrics.grad_accum_microbatches(self.name).set(n_micro)
-        import time as _time
-        self.run_started_at = _time.time()
-        self.stopped.value = False
-
-        advance = functools.partial(self._advance_microbatches, n_micro)
-        steps = 0
-        with self._run_span():  # the spans wf.run() records
-            while not decision.complete and not self.stopped:
-                if loader._record_fire(advance) == TRAIN:
-                    if guard is not None:
-                        guard.host_run()  # arm fault/SDC injections
-                    region.run_accum(n_micro)
-                    if self.lr_adjuster is not None:
-                        # ONE optimizer step happened, whatever M is
-                        self.lr_adjuster.run()
-                else:
-                    region.run()
-                decision._fire()
-                self._fire_epoch_side_units(side_units)
-                steps += 1
-                if (self._max_fires is not None
-                        and steps > self._max_fires):
-                    raise RuntimeError(
-                        f"workflow '{self.name}' exceeded max_fires="
-                        f"{self._max_fires} accumulated steps "
-                        f"(runaway loop?)")
+        region = self._region_unit.region
+        self._drive_microbatched(
+            n_micro, lambda: region.run_accum(n_micro), "accumulated")
 
     def run_pipelined(self, n_stages: int,
                       microbatches: int | None = None,
@@ -698,60 +721,15 @@ class StandardWorkflow(AcceleratedWorkflow):
         program unchanged.
         """
         from znicz_tpu.parallel.pipeline import PipelineExecutor
-        region_unit = self._region_unit
-        loader = self.loader
-        if microbatches is None:
-            from znicz_tpu.utils.config import root
-            microbatches = int(root.common.engine.get("grad_accum", 1) or 1)
-        n_micro = int(microbatches)
-        if region_unit is None or not loader._on_device_schedule():
-            raise RuntimeError(
-                f"workflow '{self.name}': run_pipelined requires the "
-                f"XLA region + a device-schedule loader")
-        span = loader.max_minibatch_size * n_micro
-        n_train = int(loader.class_lengths[TRAIN])
-        if n_train % span != 0:
-            raise RuntimeError(
-                f"workflow '{self.name}': TRAIN set of {n_train} does "
-                f"not divide into pipelined steps of "
-                f"{loader.max_minibatch_size} × {n_micro} microbatches")
+        n_micro = self._microbatches(microbatches)
+        self._require_microbatchable(n_micro, "pipelined")
         executor = self._pipeline
         if (executor is None or executor.n_stages != int(n_stages)
                 or executor.n_micro != n_micro
                 or executor.schedule_kind != schedule):
             executor = self._pipeline = PipelineExecutor(
                 self, n_stages, n_micro, schedule=schedule)
-        region = region_unit.region
-        assert region is not None
-        decision = self.decision
-        side_units = [u for u in decision.links_to
-                      if u is not self.repeater and u is not self.end_point]
-        guard = getattr(self, "anomaly_guard", None)
-        import time as _time
-        self.run_started_at = _time.time()
-        self.stopped.value = False
-
-        advance = functools.partial(self._advance_microbatches, n_micro)
-        steps = 0
-        with self._run_span():  # the spans wf.run() records
-            while not decision.complete and not self.stopped:
-                if loader._record_fire(advance) == TRAIN:
-                    if guard is not None:
-                        guard.host_run()
-                    executor.run_step()
-                    if self.lr_adjuster is not None:
-                        self.lr_adjuster.run()
-                else:
-                    region.run()
-                decision._fire()
-                self._fire_epoch_side_units(side_units)
-                steps += 1
-                if (self._max_fires is not None
-                        and steps > self._max_fires):
-                    raise RuntimeError(
-                        f"workflow '{self.name}' exceeded max_fires="
-                        f"{self._max_fires} pipelined steps "
-                        f"(runaway loop?)")
+        self._drive_microbatched(n_micro, executor.run_step, "pipelined")
 
     def build_shadow(self) -> "StandardWorkflow":
         """A numpy-backend clone for the SDC sentinel's

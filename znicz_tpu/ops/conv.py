@@ -116,90 +116,21 @@ class Conv(Forward):
         oh, ow = self.output_spatial(h, w)
         self.output.reset(np.zeros((n, oh, ow, self.n_kernels),
                                    dtype=self.output_store_dtype))
-        self._s2d = self._space_to_depth_applicable(h, w, c)
         self.init_vectors(self.input, self.output, self.weights, self.bias)
-
-    def _space_to_depth_applicable(self, h: int, w: int, c: int) -> bool:
-        """Large-stride few-channel convs (AlexNet conv1: 11×11 s4 on
-        RGB) starve the MXU — the contracting dim is only ky·kx·c and
-        the stride makes XLA's windowing inefficient (profiled at
-        ~55 TF/s vs ~170 for the 3×3 convs, profiles/r03_b384).  When
-        the geometry allows an EXACT rewrite, conv_raw re-indexes the
-        input into stride-sized blocks (space-to-depth) and runs a
-        stride-1 conv with s²·c input channels instead.
-
-        **Opt-in** (``root.common.engine.space_to_depth = True``): the
-        chip A/B measured it NEUTRAL on AlexNet conv1 (9428 vs the
-        9396–9568 img/s baseline band) — XLA's TPU backend evidently
-        performs an equivalent transform internally for strided convs,
-        so the manual rewrite stays available for geometries where it
-        might matter but is off by default."""
-        from znicz_tpu.utils.config import root
-        if not bool(root.common.engine.get("space_to_depth", False)):
-            return False
-        sy, sx = self.sliding
-        if sy != sx or sy < 2 or c > 8:
-            return False
-        b = sy
-        pt, pb, pl, pr = self.padding
-        hp, wp = h + pt + pb, w + pl + pr
-        # the block conv yields ceil(hp/b) − ceil(k/b) + 1 outputs;
-        # only exact when that matches the true floor-form count
-        for size, k in ((hp, self.ky), (wp, self.kx)):
-            if -(-size // b) - (-(-k // b)) + 1 != (size - k) // b + 1:
-                return False
-        return True
 
     # -- pure forward (jnp; the backward unit transposes conv_raw) ------
     def conv_raw(self, x, w):
         """The bare conv at MXU precision: bf16 in → bf16 out in bf16
         mode (single-dtype, so ``jax.linear_transpose``'d gradient
         convs stay single-dtype — the casts' own transposes move the
-        cotangent between f32 and bf16).
-
-        With ``_s2d`` (see ``_space_to_depth_applicable``) the conv is
-        EXACTLY rewritten as stride-1 over stride-sized pixel blocks;
-        everything here is linear, so the backward's
-        ``jax.linear_transpose`` of this function automatically yields
-        the transformed gradient convolutions too."""
+        cotangent between f32 and bf16)."""
         pt, pb, pl, pr = self.padding
         dt = self.mxu_dtype
         if dt is not None:
             x, w = x.astype(dt), w.astype(dt)
-        if getattr(self, "_s2d", False):
-            return self._conv_s2d(x, w)
         return jax.lax.conv_general_dilated(
             x, w, window_strides=self.sliding,
             padding=((pt, pb), (pl, pr)),
-            dimension_numbers=DIMNUMS)
-
-    def _conv_s2d(self, x, w):
-        """Stride-b conv as a stride-1 conv over b×b pixel blocks:
-        block (i,j) holds the b² pixels as extra channels, the kernel
-        is zero-padded to a multiple of b and re-indexed the same way.
-        Output position i then reads block window i..i+ceil(k/b)−1 —
-        identical taps, contracted over b²·c channels on the MXU."""
-        b = self.sliding[0]
-        pt, pb_, pl, pr = self.padding
-        n, h, wd, c = x.shape
-        kyb, kxb = -(-self.ky // b), -(-self.kx // b)
-        # kernel: pad to (kyb·b, kxb·b), split rows/cols into
-        # (block, offset), move offsets into the channel dim
-        w2 = jnp.pad(w, ((0, kyb * b - self.ky),
-                         (0, kxb * b - self.kx), (0, 0), (0, 0)))
-        w2 = w2.reshape(kyb, b, kxb, b, c, self.n_kernels)
-        w2 = w2.transpose(0, 2, 1, 3, 4, 5).reshape(
-            kyb, kxb, b * b * c, self.n_kernels)
-        # input: conv padding + trailing pad to whole blocks, then the
-        # same (block, offset) split
-        hp, wp = h + pt + pb_, wd + pl + pr
-        hb, wb = -(-hp // b), -(-wp // b)
-        x2 = jnp.pad(x, ((0, 0), (pt, hb * b - hp + pb_),
-                         (pl, wb * b - wp + pr), (0, 0)))
-        x2 = x2.reshape(n, hb, b, wb, b, c)
-        x2 = x2.transpose(0, 1, 3, 2, 4, 5).reshape(n, hb, wb, b * b * c)
-        return jax.lax.conv_general_dilated(
-            x2, w2, window_strides=(1, 1), padding="VALID",
             dimension_numbers=DIMNUMS)
 
     def xla_forward(self, x, w, b):

@@ -11,13 +11,6 @@ reused by the backward unit, exactly like the reference.
 region compiles a masked and an identity variant — this is the
 per-minibatch-gate case SURVEY.md §7 calls out.  Device randomness
 comes from the unit's own PRNG key chain (a region leaf).
-
-Pallas variant (``root.common.engine.use_pallas`` incl. ``"dropout"``,
-resolved once at initialize): mask generation + apply fuse into one
-VMEM pass over TPU-core PRNG bits (``pallas_kernels.dropout_apply``);
-no mask array materializes — the backward regenerates the identical
-mask from the same per-step seed.  The default follows the in-graph
-chip A/B in PALLAS_BENCH.md.
 """
 
 from __future__ import annotations
@@ -25,7 +18,6 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
 from znicz_tpu.memory import Vector
 from znicz_tpu.ops.nn_units import Forward, WeightlessGradientUnit
@@ -48,23 +40,12 @@ class DropoutForward(Forward):
         super().initialize(device=device, **kwargs)
         if self.input is None or not self.input:
             raise AttributeError(f"{self}: input not linked yet")
-        from znicz_tpu.ops import pallas_kernels
-        self._use_pallas = pallas_kernels.use_pallas(self.device,
-                                                     "dropout")
-        self._pallas_seed = None  # per-step traced seed (fwd → bwd)
         self.output.reset(np.zeros(self.input.shape,
                                    dtype=self.output_store_dtype))
-        if self._use_pallas:
-            # no mask array at all: the backward regenerates it in
-            # VMEM from the seed — allocating/uploading the Vector
-            # would negate the kernel's HBM saving
-            self.inherit_model_shard(self.output)
-            self.init_vectors(self.input, self.output)
-        else:
-            self.mask.reset(np.ones(self.input.shape,
-                                    dtype=self.act_store_dtype))
-            self.inherit_model_shard(self.output, self.mask)
-            self.init_vectors(self.input, self.output, self.mask)
+        self.mask.reset(np.ones(self.input.shape,
+                                dtype=self.act_store_dtype))
+        self.inherit_model_shard(self.output, self.mask)
+        self.init_vectors(self.input, self.output, self.mask)
         self.init_rng()
 
     def numpy_run(self) -> None:
@@ -87,17 +68,6 @@ class DropoutForward(Forward):
             self.output.devmem = x
             return
         key = self.take_key()
-        if self._use_pallas:
-            from znicz_tpu.ops import pallas_kernels
-            # one int32 seed per step drives the TPU-core PRNG; the
-            # backward regenerates the identical mask from it (no
-            # mask array materializes in HBM)
-            seed = jax.random.bits(key, (1,), jnp.uint32) \
-                .astype(jnp.int32)
-            self._pallas_seed = seed
-            self.output.devmem = pallas_kernels.dropout_apply(
-                x, seed, self.dropout_ratio)
-            return
         keep = 1.0 - self.dropout_ratio
         mask = jax.random.bernoulli(key, keep, x.shape).astype(
             x.dtype) / keep
@@ -127,13 +97,6 @@ class DropoutBackward(WeightlessGradientUnit):
         err = self.err_output.devmem
         if fwd.forward_mode != "train":
             self.err_input.devmem = err
-            return
-        if getattr(fwd, "_use_pallas", False):
-            from znicz_tpu.ops import pallas_kernels
-            # same seed, same shape → bit-identical mask regenerated
-            # in VMEM (err · mask ≡ dropout_apply(err, seed))
-            self.err_input.devmem = pallas_kernels.dropout_apply(
-                err, fwd._pallas_seed, fwd.dropout_ratio)
             return
         self.err_input.devmem = err * fwd.mask.devmem
 

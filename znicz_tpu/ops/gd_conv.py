@@ -50,16 +50,6 @@ class GradientDescentConv(GradientDescentBase):
         super().initialize(device=device, **kwargs)
         self.init_vectors(self.err_input, self.err_output, self.input,
                           self.output, self.weights, self.bias)
-        # opt-in A/B lever (root.common.engine.conv_wgrad_im2col):
-        # express the weight grad as patchesᵀ@delta — one tall GEMM
-        # with a huge contraction dim — instead of the transposed
-        # gradient conv, for geometry-starved first layers (c_in ≤ 4:
-        # the conv-form wgrad contracts over only 3 input channels and
-        # measured 51 TF/s in the round-3 profile, PERF.md)
-        from znicz_tpu.utils.config import root
-        self._wgrad_im2col = (
-            bool(root.common.engine.get("conv_wgrad_im2col", False))
-            and self.input.shape[-1] <= 4)
 
     # -- numpy oracle: explicit col2im/GEMM -----------------------------
     def numpy_run(self) -> None:
@@ -112,42 +102,14 @@ class GradientDescentConv(GradientDescentBase):
                 jax.ShapeDtypeStruct(x.shape, x.dtype))
             (grad_x,) = t_x(cotangent)
             self.err_input.devmem = grad_x.astype(jnp.float32)
-        if self._wgrad_im2col:
-            grad_w = self._wgrad_via_patches(fwd, x, cotangent, w.shape)
-        else:
-            t_w = jax.linear_transpose(
-                lambda ww: fwd.conv_raw(x, ww),
-                jax.ShapeDtypeStruct(w.shape, w.dtype))
-            (grad_w,) = t_w(cotangent)
+        t_w = jax.linear_transpose(
+            lambda ww: fwd.conv_raw(x, ww),
+            jax.ShapeDtypeStruct(w.shape, w.dtype))
+        (grad_w,) = t_w(cotangent)
         self._apply_weights_xla(grad_w.astype(jnp.float32))
         if self.bias is not None and self.bias:
             self._apply_bias_xla(
                 delta.astype(jnp.float32).sum(axis=(0, 1, 2)))
-
-    @staticmethod
-    def _wgrad_via_patches(fwd, x, cotangent, w_shape):
-        """Weight grad as one MXU GEMM: extract the forward's im2col
-        patches (B·OH·OW, C·ky·kx) and contract with the cotangent
-        (B·OH·OW, K) — mathematically identical to the transposed
-        gradient conv (same sums, reassociated), tested against it in
-        ``tests/test_gd_conv.py``."""
-        pt, pb, pl, pr = fwd.padding
-        dt = fwd.mxu_dtype
-        if dt is not None:
-            x = x.astype(dt)
-        patches = jax.lax.conv_general_dilated_patches(
-            x, filter_shape=(fwd.ky, fwd.kx),
-            window_strides=fwd.sliding,
-            padding=((pt, pb), (pl, pr)),
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        c = x.shape[-1]
-        ky, kx, _, k = w_shape
-        p2d = patches.reshape(-1, patches.shape[-1])
-        d2d = cotangent.reshape(-1, k)
-        grad = jnp.matmul(p2d.T, d2d,
-                          preferred_element_type=jnp.float32)
-        # patches features are (C, ky, kx)-ordered → HWIO weights
-        return grad.reshape(c, ky, kx, k).transpose(1, 2, 0, 3)
 
 
 class GDTanhConv(GradientDescentConv):

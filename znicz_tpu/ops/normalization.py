@@ -13,8 +13,7 @@ The backward unit uses the exact analytic gradient on both paths
 (numpy oracle and XLA) — XLA fuses the elementwise/window-sum chain
 into the jit region, which benchmarking in the reference survey flags
 as the right first choice before reaching for a Pallas kernel
-(SURVEY.md §2.3; PALLAS_BENCH.md records the in-graph measurement
-that made plain XLA the default here).
+(SURVEY.md §2.3).
 """
 
 from __future__ import annotations
@@ -37,8 +36,7 @@ def _band_matrix(c: int, n: int, half_low: int) -> np.ndarray:
     return ((j >= lo[None, :]) & (j <= hi[None, :])).astype(np.float32)
 
 
-def _window_sum(xp, arr, n: int, half_low: int | None = None,
-                via_matmul: bool = True):
+def _window_sum(xp, arr, n: int, half_low: int | None = None):
     """Sliding sum over the LAST (channel) axis:
     ``out_i = Σ_{k=i−half_low}^{i+(n−1−half_low)} arr_k`` (zero-padded).
 
@@ -57,21 +55,8 @@ def _window_sum(xp, arr, n: int, half_low: int | None = None,
     c = arr.shape[-1]
     if half_low is None:
         half_low = n // 2
-    if xp is jnp and via_matmul:
-        # (Pallas kernels pass via_matmul=False: inside pallas_call
-        # the traced jnp is not plain XLA and keeps the shift form.)
-        # engine.lrn_band_bf16 feeds the GEMM bf16 operands (f32
-        # accumulate) — the band sum is bandwidth-bound (2·C FLOP per
-        # element read), so halving the read traffic is the lever;
-        # the contribution is α-damped (~1e-4) in d and 2αβ-damped in
-        # the backward term, far inside the convergence band.  A/B
-        # lever, default follows PERF.md round-4 measurements.
-        from znicz_tpu.utils.config import root
-        dt = jnp.bfloat16 if root.common.engine.get(
-            "lrn_band_bf16", False) else None
+    if xp is jnp:
         band = jnp.asarray(_band_matrix(c, n, half_low))
-        if dt is not None:
-            arr, band = arr.astype(dt), band.astype(dt)
         return jnp.matmul(arr, band,
                           preferred_element_type=jnp.float32)
     half_high = n - 1 - half_low
@@ -148,8 +133,6 @@ class LRNormalizerForward(Forward):
                                    dtype=self.output_store_dtype))
         self.inherit_model_shard(self.output)
         self.init_vectors(self.input, self.output)
-        from znicz_tpu.ops import pallas_kernels
-        self._use_pallas = pallas_kernels.use_pallas(self.device, "lrn")
 
     def _forward(self, xp, x):
         d = self.k + self.alpha * _window_sum(xp, x * x, self.n)
@@ -167,11 +150,6 @@ class LRNormalizerForward(Forward):
         # upcast fuses (in-register), costs no HBM traffic; the
         # devmem setter casts the result back to the storage dtype.
         x = self.input.devmem.astype(jnp.float32)
-        if self._use_pallas:  # resolved once at initialize
-            from znicz_tpu.ops import pallas_kernels
-            self.output.devmem = pallas_kernels.lrn_forward(
-                x, self.alpha, self.beta, self.k, self.n)
-            return
         self.output.devmem = self._forward(jnp, x)
 
 
@@ -189,8 +167,6 @@ class LRNormalizerBackward(GradientDescentBase):
         super().initialize(device=device, **kwargs)
         self.init_vectors(self.err_input, self.err_output, self.input,
                           self.output)
-        from znicz_tpu.ops import pallas_kernels
-        self._use_pallas = pallas_kernels.use_pallas(self.device, "lrn")
 
     def numpy_run(self) -> None:
         """Analytic gradient (the oracle/spec):
@@ -218,11 +194,6 @@ class LRNormalizerBackward(GradientDescentBase):
         # f32 math on bf16-stored operands — see the forward's note
         x = self.input.devmem.astype(jnp.float32)
         err = self.err_output.devmem.astype(jnp.float32)
-        if self._use_pallas:  # resolved once at initialize
-            from znicz_tpu.ops import pallas_kernels
-            self.err_input.devmem = pallas_kernels.lrn_backward(
-                x, err, fwd.alpha, fwd.beta, fwd.k, fwd.n)
-            return
         d = fwd.k + fwd.alpha * _window_sum(jnp, x * x, fwd.n)
         d = _store_d(jnp, d)  # identical expression to the forward's
         # — XLA CSE shares ONE materialized d between fwd and bwd

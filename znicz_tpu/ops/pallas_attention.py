@@ -79,9 +79,9 @@ transposes, which costs two cheap bandwidth passes versus the many
 (T, T) passes saved.
 
 Adoption is measured, not assumed: SEQ_BENCH.json / PERF.md round 5
-carry the chip A/B against the plain and scan-blocked XLA forms (the
-PALLAS_BENCH.md decision rule).  ``interpret=True`` runs the same
-kernels on CPU for the oracle equality tests.
+carry the chip A/B against the plain and scan-blocked XLA forms.
+``interpret=True`` runs the same kernels on CPU for the oracle
+equality tests.
 """
 
 from __future__ import annotations

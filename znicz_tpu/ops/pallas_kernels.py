@@ -1,25 +1,15 @@
-"""Pallas TPU kernels for irregular hot ops.
+"""Pallas TPU kernels for row-wise hot ops.
 
 SURVEY.md §2.3 maps the reference's hand-written OpenCL/CUDA kernel
 corpus onto XLA ops, with Pallas reserved for the fused/irregular
-cases.  This module holds those kernels; the first resident is the
-**cross-channel LRN** (AlexNet's normalization layer, reference:
-``znicz/ocl|cuda`` normalization kernels):
+cases.  This module holds the **layer-norm** pair (the flash-attention
+kernels live in ``ops/pallas_attention.py``) and the two gates every
+kernel-carrying unit asks: :func:`is_tpu_device` and
+:func:`kernel_refusal`.
 
-- the forward fuses square → sliding channel-window sum → pow →
-  multiply into one VMEM pass over the activations (the plain-XLA
-  path now rides the MXU via a constant band-matrix matmul — see
-  ``normalization._window_sum`` — which is why Pallas stays opt-in);
-- the backward fuses the analytic gradient the same way (one pass,
-  two window sums) instead of re-running the forward under ``jax.vjp``.
-
-Both run on a 1-D grid over row tiles with the channel axis resident
-in lanes; ``interpret=True`` runs them on CPU for the test oracle
-comparison (tests force the cpu platform).
-
-Gating: units call :func:`use_pallas` — True only on real TPU devices
-and when ``root.common.engine.use_pallas`` is not disabled, so every
-other platform keeps the plain-XLA path.
+The kernels run on a 1-D grid over row tiles with the feature axis
+resident in lanes; ``interpret=True`` runs them on CPU for the test
+oracle comparison (tests force the cpu platform).
 """
 
 from __future__ import annotations
@@ -30,10 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# THE window-sum definition (shared with the numpy oracle and the jnp
-# forward — one source of truth for the window/adjoint convention)
-from znicz_tpu.ops.normalization import _window_sum as _window_sum_xp
 
 #: rows per grid step (sublane-aligned; channels ride the lane axis)
 _TILE_ROWS = 512
@@ -62,120 +48,6 @@ def kernel_refusal(device, option: str, interpret: bool) -> str | None:
     platform = getattr(getattr(device, "jax_device", None), "platform",
                        getattr(device, "backend", None))
     return f"device platform {platform} is not a TPU"
-
-
-def use_pallas(device, op: str | None = None) -> bool:
-    """Pallas path gate: TPU platform + config switch.
-
-    **Default OFF** (``root.common.engine.use_pallas`` opts in —
-    ``True`` enables every Pallas variant; a list/tuple/set of op
-    names (``["dropout"]``) enables per-op, which is how the in-graph
-    A/Bs isolate one kernel).  The standalone microbenchmark
-    (PALLAS_BENCH.md) has the Pallas LRN ahead of the jnp composition,
-    but IN-GRAPH the picture inverts: `pallas_call` pins its operand
-    to a 2-D row-major layout, so XLA brackets every call with layout
-    copies + reshapes of the (n,55,55,96) activations — profiled at
-    ~40% of the AlexNet step (profiles/r03_b256), and the chip A/B
-    measured plain XLA 24% faster end-to-end (7795 vs 6263 img/s,
-    batch 256).  The fused-XLA LRN fuses into its conv/pool neighbors
-    with no layout constraint.
-
-    **Compile-time flag**: units resolve this ONCE at ``initialize``
-    and bake the result into their traced program — flipping
-    ``root.common.engine.use_pallas`` after a region compiled has no
-    effect for that workflow's lifetime (re-initialize to re-decide).
-    """
-    from znicz_tpu.utils.config import root
-    if not is_tpu_device(device):
-        return False
-    val = root.common.engine.get("use_pallas", False)
-    if isinstance(val, (list, tuple, set, frozenset)):
-        return op is not None and op in val
-    return bool(val)
-
-
-# ----------------------------------------------------------------------
-# LRN: d_i = k + α·Σ_{j∈win(i)} x_j² ;  y_i = x_i · d_i^{−β}
-# ----------------------------------------------------------------------
-def _window_sum(arr, n: int, half_low: int):
-    """Sliding sum over the last (lane) axis — the shared xp-generic
-    definition traced with jnp inside the kernel."""
-    return _window_sum_xp(jnp, arr, n, half_low=half_low,
-                          via_matmul=False)
-
-
-def _lrn_fwd_kernel(x_ref, o_ref, *, alpha, beta, k, n):
-    x = x_ref[:]
-    d = k + alpha * _window_sum(x * x, n, n // 2)
-    o_ref[:] = x * d ** (-beta)
-
-
-def _lrn_bwd_kernel(x_ref, err_ref, o_ref, *, alpha, beta, k, n):
-    # dy_i/dx_j = δ_ij·d_i^{−β} − 2αβ·x_i·x_j·d_i^{−β−1}·[j∈win(i)];
-    # err_input_j = err_j·d_j^{−β} − 2αβ·x_j·Σ_{i: j∈win(i)} t_i with
-    # t_i = err_i·x_i·d_i^{−β−1} — the second sum is the window
-    # operator's ADJOINT (half_low mirrored; differs for even n)
-    x = x_ref[:]
-    err = err_ref[:]
-    d = k + alpha * _window_sum(x * x, n, n // 2)
-    t = err * x * d ** (-beta - 1.0)
-    o_ref[:] = (err * d ** (-beta)
-                - 2.0 * alpha * beta * x
-                * _window_sum(t, n, n - 1 - n // 2))
-
-
-def _row_tiled_call(kernel, out_like, *inputs, name, interpret=False):
-    """Run an elementwise-rows kernel over (M, C) arrays on a 1-D row
-    grid; ``name`` is what a profile calls the kernel."""
-    m, c = out_like.shape
-    tile = min(_TILE_ROWS, m)
-    spec = pl.BlockSpec((tile, c), lambda i: (i, 0))
-    return pl.pallas_call(
-        kernel,
-        grid=(pl.cdiv(m, tile),),
-        in_specs=[spec] * len(inputs),
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((m, c), out_like.dtype),
-        interpret=interpret,
-        name=name,
-    )(*inputs)
-
-
-# ----------------------------------------------------------------------
-# Dropout: PRNG mask + apply in one VMEM pass (candidate; measured
-# against the jax.random path by benchmarks/pallas_microbench.py)
-# ----------------------------------------------------------------------
-def _dropout_kernel(seed_ref, x_ref, o_ref, *, drop_ratio):
-    pltpu.prng_seed(seed_ref[0] + pl.program_id(0))
-    bits = pltpu.prng_random_bits(x_ref.shape)
-    threshold = jnp.uint32(int(drop_ratio * (2 ** 32 - 1)))
-    keep = bits.astype(jnp.uint32) > threshold
-    scale = 1.0 / (1.0 - drop_ratio)
-    o_ref[:] = jnp.where(keep, x_ref[:] * scale, 0.0)
-
-
-def dropout_apply(x, seed, drop_ratio: float, interpret: bool = False):
-    """Fused mask-generate + apply: TPU-core PRNG bits in VMEM instead
-    of a materialized threefry mask array from ``jax.random``.
-
-    ``seed``: int32 scalar array.  Inverted-dropout scaling matches
-    ``ops/dropout.py`` (keep → ×1/(1−ratio))."""
-    shape = x.shape
-    x2d = x.reshape(-1, shape[-1])
-    m, c = x2d.shape
-    tile = min(_TILE_ROWS, m)
-    spec = pl.BlockSpec((tile, c), lambda i: (i, 0))
-    kernel = functools.partial(_dropout_kernel, drop_ratio=drop_ratio)
-    out = pl.pallas_call(
-        kernel,
-        grid=(pl.cdiv(m, tile),),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((m, c), x.dtype),
-        interpret=interpret,
-        name="znicz_dropout",
-    )(jnp.asarray(seed, jnp.int32).reshape(1), x2d)
-    return out.reshape(shape)
 
 
 # ----------------------------------------------------------------------
@@ -384,61 +256,3 @@ def layer_norm_backward(x, err, gamma, eps: float,
     )(x2d, e2d, gamma.reshape(1, d).astype(jnp.float32))
     gb = out[2][0] if with_beta else None
     return out[0].reshape(shape), out[1][0], gb
-
-
-# ----------------------------------------------------------------------
-# Softmax (+ argmax): one row pass — max, exp, sum, divide, argmax
-# fused in VMEM (candidate; the XLA composition is 3-4 HBM passes)
-# ----------------------------------------------------------------------
-def _softmax_argmax_kernel(v_ref, y_ref, idx_ref):
-    v = v_ref[:]
-    m = jnp.max(v, axis=1, keepdims=True)
-    e = jnp.exp(v - m)
-    y_ref[:] = e / jnp.sum(e, axis=1, keepdims=True)
-    idx_ref[:] = jnp.argmax(v, axis=1, keepdims=True).astype(jnp.int32)
-
-
-def softmax_argmax(v, interpret: bool = False):
-    """Row softmax + winner index in one pass over (batch, n_classes).
-
-    Returns ``(probs, max_idx)`` matching ``All2AllSoftmax``'s
-    stabilized softmax + ``max_idx`` contract."""
-    m, c = v.shape
-    tile = min(_TILE_ROWS, m)
-    spec = pl.BlockSpec((tile, c), lambda i: (i, 0))
-    idx_spec = pl.BlockSpec((tile, 1), lambda i: (i, 0))
-    probs, idx = pl.pallas_call(
-        _softmax_argmax_kernel,
-        grid=(pl.cdiv(m, tile),),
-        in_specs=[spec],
-        out_specs=(spec, idx_spec),
-        out_shape=(jax.ShapeDtypeStruct((m, c), v.dtype),
-                   jax.ShapeDtypeStruct((m, 1), jnp.int32)),
-        interpret=interpret,
-        name="znicz_softmax_argmax",
-    )(v)
-    return probs, idx[:, 0]
-
-
-def lrn_forward(x, alpha: float, beta: float, k: float, n: int,
-                interpret: bool = False):
-    """Fused LRN forward over an ND array whose LAST axis is channels."""
-    shape = x.shape
-    x2d = x.reshape(-1, shape[-1])
-    kernel = functools.partial(_lrn_fwd_kernel, alpha=alpha, beta=beta,
-                               k=k, n=n)
-    return _row_tiled_call(kernel, x2d, x2d, name="znicz_lrn_fwd",
-                           interpret=interpret).reshape(shape)
-
-
-def lrn_backward(x, err_output, alpha: float, beta: float, k: float,
-                 n: int, interpret: bool = False):
-    """Fused LRN analytic gradient (one pass, two window sums)."""
-    shape = x.shape
-    x2d = x.reshape(-1, shape[-1])
-    err2d = err_output.reshape(-1, shape[-1])
-    kernel = functools.partial(_lrn_bwd_kernel, alpha=alpha, beta=beta,
-                               k=k, n=n)
-    return _row_tiled_call(kernel, x2d, x2d, err2d,
-                           name="znicz_lrn_bwd",
-                           interpret=interpret).reshape(shape)

@@ -18,7 +18,6 @@ from __future__ import annotations
 from collections import deque
 
 from znicz_tpu.mutable import Bool
-from znicz_tpu.observe import metrics as _metrics
 from znicz_tpu.observe import tracing as _tracing
 from znicz_tpu.units import Container, EndPoint, StartPoint, Unit
 
@@ -102,10 +101,6 @@ class Workflow(Container):
         import time as _time
         self.run_started_at = _time.time()  # consumers (Publisher)
         #                       use it to tell this run's artifacts apart
-        if _metrics.enabled():
-            _metrics.REGISTRY.counter(
-                "znicz_workflow_runs_total", "Workflow.run invocations",
-                labels=("workflow",)).labels(workflow=self.name).inc()
         self._finished = False
         self.stopped.value = False
         queue: deque[Unit] = deque([self.start_point])
