@@ -28,10 +28,11 @@ Sequence-parallel arm: ``SEQ_RING=<n>`` shards T over an (n_model=n)
 ring mesh; the ring hops fold through the flash kernel
 (``ring_fold="pallas"`` in the row) unless ``SEQ_RING_FOLD=0`` forces
 the scan fold — the committed A/B for the round-6 kernel-native ring.
-``SEQ_HEAD_PACK=1`` is the head-packing lever (PERF.md round 6 cont.).
-The causal tile schedule has no lever: the kernels derive it from the
-shapes (``pallas_attention.sub_tile_for``; PERF.md §6, PR 24) and the
-row records what they resolved (``sub_tile``, ``executed_share``).
+Neither the causal tile schedule nor the kernels' address and head
+pack has a lever: the kernels derive them from the shapes
+(``pallas_attention.sub_tile_for``, ``head_layout``; PERF.md §6, PR 24
+and PR 28) and the row records what they resolved (``sub_tile``,
+``executed_share``, ``flash_layout``).
 """
 
 from __future__ import annotations
@@ -80,9 +81,6 @@ SHARD_MAP = os.environ.get("SEQ_SHARD_MAP", "") != "0"
 #: as SEQ_SHARD_MAP).
 RING = int(os.environ.get("SEQ_RING", "0"))
 RING_FOLD = os.environ.get("SEQ_RING_FOLD", "") != "0"
-#: SEQ_HEAD_PACK=1: pack head pairs into 128-lane kernel tiles
-#: (engine.flash_head_pack — the dh=64 half-MXU lever, PERF.md)
-HEAD_PACK = os.environ.get("SEQ_HEAD_PACK", "0") != "0"
 #: SEQ_INTERPRET=1: run the Pallas kernels in interpret mode (CPU
 #: recording of the multi-device arm; meaningless on a real chip)
 INTERPRET = os.environ.get("SEQ_INTERPRET", "0") != "0"
@@ -166,8 +164,6 @@ def main() -> None:
     root.common.engine.pallas_shard_map = SHARD_MAP
     root.common.engine.ring_pallas_fold = \
         RING_FOLD and "auto" or False
-    if HEAD_PACK:
-        root.common.engine.flash_head_pack = True
     if INTERPRET:
         root.common.engine.pallas_interpret = True
     prng.seed_all(11)
@@ -243,8 +239,10 @@ def main() -> None:
         # "scan" = the XLA fallback; null = no ring)
         "ring": RING or None,
         "ring_fold": getattr(attn_unit, "_ring_fold", None),
-        "head_pack": max(getattr(attn_unit, "_flash_pack", 1),
-                         getattr(attn_unit, "_ring_pack", 1)),
+        # where the kernels find a head's tiles and how many heads
+        # share a program: from the shapes (pallas_attention.
+        # head_layout); null off the one-chip kernel path
+        "flash_layout": getattr(attn_unit, "_flash_layout", None),
         # the causal tile schedule the kernels derived: compute
         # sub-tile inside the grid tile, share of T × T executed
         "sub_tile": getattr(attn_unit, "_flash_sub_tile", None),

@@ -554,6 +554,65 @@ def test_rope_is_a_rotation_by_position():
     np.testing.assert_allclose(rot[0, 2, 0, :half], want, rtol=1e-5)
 
 
+@pytest.mark.parametrize("t", [16, 12])
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_rope_over_rows_is_the_same_rotation(backend, t):
+    """``apply_rope_rows`` — the rotation on the (B, T/8, H, 8, dh) view
+    of (B, T, D) rows, the one the flash path runs — equals
+    ``apply_rope`` on (B, T, H, dh), whether or not 8 rows divide T."""
+    import jax.numpy as jnp
+    xp = np if backend == "numpy" else jnp
+    b, h, dh = 2, 3, 8
+    x = np.random.default_rng(4).normal(size=(b, t, h, dh)) \
+        .astype(np.float32)
+    want = attention.apply_rope(
+        np, x, *attention.rope_tables(np, t, dh, 10000.0))
+    got = attention.apply_rope_rows(
+        xp, xp.asarray(x.reshape(b, t, h * dh)),
+        *attention.rope_tables(xp, t, dh, 10000.0), h)
+    np.testing.assert_allclose(np.asarray(got).reshape(x.shape), want,
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("name,d", [("bare", 128), ("all", 256)])
+def test_block_through_the_flash_kernels_matches_the_oracle(name, d):
+    """The unit's call site of the boundary-layout kernels (interpret
+    mode): the bare layer hands them ONE (B, T, 3D) projection result
+    (dh 64: pairs of heads), the pre-norm block three (B, T, D) tensors
+    normed and rotated in the rows' layout (dh 128) — output, err_input
+    and every parameter after two momentum steps agree with the numpy
+    oracle's analytic backward."""
+    from znicz_tpu.utils.config import root
+    root.common.engine.flash_attention = True
+    root.common.engine.pallas_interpret = True
+    options = {} if name == "bare" else BLOCK_OPTIONS["all"]
+    rng = np.random.default_rng(7)
+    x = rng.normal(0, 0.7, (B, 16, d)).astype(np.float32)
+    err = rng.normal(0, 0.1, x.shape).astype(np.float32)
+    np_f, np_g = build_block(NumpyDevice(), x, options)
+    xla_f, xla_g = build_block(XLADevice(), x, options,
+                               params=block_params(np_f))
+    assert xla_f._flash_pallas
+    assert xla_f._flash_layout == ("boundary", 128 // (d // BLOCK_H))
+    results = []
+    for device, fwd, gd_u in ((np_f.device, np_f, np_g),
+                              (xla_f.device, xla_f, xla_g)):
+        for _ in range(2):
+            fwd.run()
+            gd_u.err_output.reset(err.copy())
+            gd_u.err_output.initialize(device)
+            gd_u.run()
+        fwd.output.map_read()
+        gd_u.err_input.map_read()
+        results.append({**block_params(fwd),
+                        "output": np.array(fwd.output.mem, np.float32),
+                        "err_input": np.array(gd_u.err_input.mem,
+                                              np.float32)})
+    for key, want in results[0].items():
+        np.testing.assert_allclose(results[1][key], want, rtol=2e-3,
+                                   atol=2e-5, err_msg=key)
+
+
 @pytest.mark.parametrize("precision", ["float32", "bfloat16"])
 def test_default_options_leave_the_bare_layer_bit_identical(precision):
     """With every block option off (``attn_lm_base``'s layer) the XLA

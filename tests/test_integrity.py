@@ -222,6 +222,92 @@ def test_fold_compiled_for_v5e_rewrites_no_tensor(v5e_chip, shape,
     assert not rewrites, rewrites
 
 
+_MOVE = re.compile(
+    r"^(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\{[^}]*\} "
+    r"(copy|transpose|slice|concatenate|reshape)\(%([\w.\-]+)")
+
+#: one attention layer of each LM cell: (batch, T, D, heads, options)
+_ATTENTION_CELLS = {
+    "attn_lm_t2048": (32, 2048, 512, 8, {}),
+    "olmoe_t4096": (1, 4096, 2048, 16, dict(
+        pre_norm="rms", residual=True, qk_norm="rms",
+        rope={"theta": 10000.0}, include_bias=False)),
+}
+
+
+@pytest.mark.parametrize("cell", list(_ATTENTION_CELLS))
+def test_attention_layer_compiled_for_v5e_moves_no_activation(
+        v5e_chip, monkeypatch, cell):
+    """One attention layer's forward + backward at a cell's shape
+    (causal, bf16 operands), compiled for the chip: the flash kernels
+    read q, k, v and write o and the three gradients in the
+    projections' own layout, so between a projection and a
+    ``znicz_flash_*`` call the program holds no top-level ``copy`` /
+    ``transpose`` / ``slice`` / ``concatenate`` / physical ``reshape``
+    of B·T·D elements or more (the parent held four head-major
+    transposes per layer: 24 ``copy`` of 0.6 ms a step in the LM
+    cell).  A ``slice`` that changes the element type is the bf16 cast
+    of v beside OLMoE's f32 norms, not a move, and XLA's asynchronous
+    ``copy-start`` / ``slice-start`` are its own prefetches into the
+    fast memory space."""
+    import jax
+    import jax.numpy as jnp
+
+    from znicz_tpu.dummy import DummyUnit, DummyWorkflow
+    from znicz_tpu.memory import Vector
+    from znicz_tpu.ops import attention, pallas_kernels
+    b, t, d, heads, options = _ATTENTION_CELLS[cell]
+    monkeypatch.setattr(pallas_kernels, "is_tpu_device",
+                        lambda device: True)
+    root.common.precision_type = "bfloat16"
+    wf = DummyWorkflow()
+    src = DummyUnit(wf, output=Vector(np.zeros((b, t, d), np.float32),
+                                      name="x"))
+    unit = attention.MultiHeadAttention(wf, n_heads=heads, causal=True,
+                                        **options)
+    unit.link_attrs(src, ("input", "output"))
+    unit.initialize(device=XLADevice())
+    assert unit._flash_pallas
+    assert unit._flash_layout == ("boundary", 128 // (d // heads))
+
+    def struct(a):
+        return None if a is None else jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e_chip)
+
+    def step(dy, *args):
+        out, pullback = jax.vjp(unit.xla_forward, *args)
+        return out, pullback(dy)
+
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:   # a described chip's executable cannot be read back here
+        text = jax.jit(step).lower(
+            jax.ShapeDtypeStruct((b, t, d), jnp.float32,
+                                 sharding=v5e_chip),
+            *(struct(a) for a in unit.forward_args())) \
+            .compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    for kernel in ("znicz_flash_fwd", "znicz_flash_dq",
+                   "znicz_flash_dkv"):
+        assert f"%{kernel}" in text, kernel
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    dtype_of = {m.group(1): m.group(2) for m in re.finditer(
+        r"%([\w.\-]+) = \(?(\w+)\[", text)}
+    moves = []
+    for line in entry.splitlines():
+        m = _MOVE.match(line.strip())
+        if m is None:
+            continue
+        dtype, dims, op, operand = m.groups()
+        size = math.prod(int(n) for n in dims.split(",") if n)
+        if size >= b * t * d and not (
+                op == "slice" and dtype_of.get(operand) != dtype):
+            moves.append(line.strip()[:160])
+    assert not moves, moves
+
+
 def test_vote_verdict_clean_selfbad_majority_tie():
     v = integrity.vote_verdict([1.0, 1.0, 1.0], [1.0, 1.0, 1.0], 1e-3)
     assert v == {"divergent": False, "culprits": [], "self_bad": []}

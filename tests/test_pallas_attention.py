@@ -177,39 +177,93 @@ def test_offsets_whole_hop_below_and_above_the_diagonal(block, sub):
         assert np.all(np.asarray(g) == 0.0)
 
 
+def _np_attention(q, k, v, causal):
+    """The numpy oracle over (B, T, H, dh) float64: out and the three
+    gradients of ``vdot(out, dy)`` written out by hand."""
+    def run(dy):
+        d = q.shape[-1]
+        s = np.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+        if causal:
+            t = q.shape[1]
+            s = np.where(np.tril(np.ones((t, t), bool)), s, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        out = np.einsum("bhqk,bkhd->bqhd", p, v)
+        dv = np.einsum("bhqk,bqhd->bkhd", p, dy)
+        dp = np.einsum("bqhd,bkhd->bhqk", dy, v)
+        ds = p * (dp - (dp * p).sum(-1, keepdims=True)) / np.sqrt(d)
+        return (out, np.einsum("bhqk,bkhd->bqhd", ds, k),
+                np.einsum("bhqk,bqhd->bkhd", ds, q), dv)
+    return run
+
+
+#: (heads, head width) → the address and pack the shapes give: the
+#: pair body at dh 64, one head a block at dh 128, and three shapes
+#: with no lane-legal column block
+LAYOUTS = {(4, 64): ("boundary", 2), (2, 128): ("boundary", 1),
+           (4, 32): ("head_major", 1), (2, 96): ("head_major", 1),
+           (3, 64): ("head_major", 1)}
+
+
+@pytest.mark.parametrize("fused", [True, False],
+                         ids=["one_array", "three_arrays"])
 @pytest.mark.parametrize("causal", [False, True])
-def test_head_pack_matches_unpacked_fwd_and_grads(causal):
-    """head_pack=2 (pairs of heads in one 128-lane program) is exact
-    per-head math: must equal the unpacked kernel AND the oracle,
-    forward and every gradient."""
-    b, t, h, d = 2, 128, 4, 16
-    q, k, v = (_rand((b, t, h, d), s) for s in (7, 8, 9))
-    dy = _rand((b, t, h, d), 10)
-    kw = dict(causal=causal, block_q=32, block_k=32, interpret=True)
-    ref = local_attention(q, k, v, causal=causal)
-    out = flash_attention(q, k, v, head_pack=2, **kw)
-    np.testing.assert_allclose(out, ref, atol=2e-5)
-    np.testing.assert_allclose(out, flash_attention(q, k, v, **kw),
-                               atol=2e-5)
-    g_ref = jax.grad(
-        lambda *a: jnp.vdot(local_attention(*a, causal=causal), dy),
-        argnums=(0, 1, 2))(q, k, v)
-    g_new = jax.grad(
-        lambda *a: jnp.vdot(flash_attention(*a, head_pack=2, **kw),
-                            dy),
-        argnums=(0, 1, 2))(q, k, v)
-    for name, a, b_ in zip("qkv", g_ref, g_new):
-        np.testing.assert_allclose(np.asarray(b_), np.asarray(a),
-                                   atol=5e-5, err_msg=f"grad d{name}")
+@pytest.mark.parametrize("h,dh", list(LAYOUTS))
+def test_head_pack_matches_unpacked_fwd_and_grads(h, dh, causal, fused):
+    """The boundary-layout entry against the numpy oracle, forward and
+    every gradient: heads as column blocks of the projection (the pair
+    body at dh 64 — exact per-head math), q / k / v as column offsets
+    of ONE (B, T, 3D) array (whose cotangent is one array too) and as
+    three arrays, and widths that fall to the head-major address."""
+    from znicz_tpu.ops.pallas_attention import (flash_attention_rows,
+                                                head_layout)
+    assert head_layout(h, dh) == LAYOUTS[h, dh]
+    b, t, d = 2, 128, h * dh
+    qkv = np.random.default_rng(7).normal(0, 1, (b, t, 3 * d))
+    dy = np.random.default_rng(8).normal(0, 1, (b, t, d))
+    want = _np_attention(*(qkv[..., i * d:(i + 1) * d]
+                           .reshape(b, t, h, dh) for i in range(3)),
+                         causal)(dy.reshape(b, t, h, dh))
+    kw = dict(causal=causal, block_q=64, block_k=32, interpret=True,
+              sub_tile=(32, 32) if causal else None)
+    dy32 = jnp.asarray(dy, jnp.float32)
+
+    def loss(*arrays):
+        return jnp.vdot(flash_attention_rows(arrays, h, **kw), dy32)
+
+    x = jnp.asarray(qkv, jnp.float32)
+    if fused:
+        out = flash_attention_rows((x,), h, **kw)
+        g = jax.grad(loss)(x)
+        grads = [g[..., i * d:(i + 1) * d] for i in range(3)]
+    else:
+        parts = [x[..., i * d:(i + 1) * d] for i in range(3)]
+        out = flash_attention_rows(tuple(parts), h, **kw)
+        grads = jax.grad(loss, argnums=(0, 1, 2))(*parts)
+    np.testing.assert_allclose(out, want[0].reshape(b, t, d), atol=2e-5)
+    for name, got, ref in zip("qkv", grads, want[1:]):
+        np.testing.assert_allclose(got, ref.reshape(b, t, d), atol=5e-5,
+                                   err_msg=f"grad d{name}")
 
 
-def test_resolve_head_pack_rules():
-    from znicz_tpu.ops.pallas_attention import resolve_head_pack
-    assert resolve_head_pack(False, 8, 64) == 1     # gated off
-    assert resolve_head_pack(True, 8, 64) == 2      # the dh=64 case
-    assert resolve_head_pack(True, 7, 64) == 1      # odd head count
-    assert resolve_head_pack(True, 8, 128) == 1     # already full-lane
-    assert resolve_head_pack(True, 8, 4) == 1       # lane-illegal dh
+@pytest.mark.parametrize("n_heads,dh,want", [
+    (8, 64, ("boundary", 2)),       # the LM cell: a pair per block
+    (16, 128, ("boundary", 1)),     # OLMoE: a head is a block
+    (4, 256, ("boundary", 1)),      # a multiple of the lanes
+    (8, 32, ("head_major", 1)),     # four to a block outgrow VMEM
+    (7, 64, ("head_major", 1)),     # odd head count: no pair
+    (8, 96, ("head_major", 1)),     # neither multiple nor divisor
+    (4, 192, ("head_major", 1)),
+    (8, 4, ("head_major", 1)),      # sublane-illegal dh
+])
+def test_resolve_head_pack_rules(n_heads, dh, want):
+    """The pack factor is what the shape says — a pair where two heads
+    fill the 128 lanes and the head count is even, 1 at multiples of
+    128 — and with it the address: no option enters."""
+    from znicz_tpu.ops.pallas_attention import (head_layout,
+                                                head_pack_for)
+    assert head_layout(n_heads, dh) == want
+    assert head_pack_for(n_heads, dh) == want[1]
 
 
 def _brute_counts(t_q, t_k, sq, sk, q_off, k_off):

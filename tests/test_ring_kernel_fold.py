@@ -111,16 +111,20 @@ def test_kernel_fold_diagonal_mid_hop_tiles(sub, monkeypatch):
 @pytest.mark.slow
 @pytest.mark.parametrize("causal", [False, True])
 def test_kernel_fold_head_packed(causal):
-    """Head packing through the ring: pairs of heads in one 128-lane
-    kernel program per hop, exact per-head math — fwd + grads."""
+    """Head packing through the ring, as the shapes give it (pairs of
+    dh-64 heads, ``pallas_attention.head_pack_for``): two heads in one
+    128-lane kernel program per hop, head-major around the ring, exact
+    per-head math — fwd + grads."""
+    from znicz_tpu.ops.pallas_attention import head_pack_for
     mesh = make_seq_mesh(4)
-    B, T, H, D = 2, 64, 4, 8
+    B, T, H, D = 2, 64, 4, 64
+    assert head_pack_for(H, D) == 2
     q, k, v = (_rand((B, T, H, D), s) for s in (7, 8, 9))
     with jax.default_matmul_precision("highest"):
         ref = local_attention(q, k, v, causal=causal)
         got = sequence_sharded_attention(
             mesh, q, k, v, causal=causal, pallas_fold=True,
-            pallas_interpret=True, head_pack=2)
+            pallas_interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-4, atol=2e-5)
         ct = _rand(ref.shape, 10)
@@ -129,7 +133,7 @@ def test_kernel_fold_head_packed(causal):
         _, vjp_got = jax.vjp(
             lambda *a: sequence_sharded_attention(
                 mesh, *a, causal=causal, pallas_fold=True,
-                pallas_interpret=True, head_pack=2), q, k, v)
+                pallas_interpret=True), q, k, v)
         for name, gr, gg in zip("qkv", vjp_ref(ct), vjp_got(ct)):
             np.testing.assert_allclose(np.asarray(gg), np.asarray(gr),
                                        rtol=3e-4, atol=3e-4,
@@ -162,8 +166,9 @@ def test_illegal_shapes_fall_back_to_scan_fold():
     _assert_fold(mesh, (2, 32, 2, 4), "scan")      # dh = 4
     _assert_fold(mesh, (2, 12, 2, 8), "scan")      # t_local = 6
     _assert_fold(mesh, (2, 32, 2, 8), "pallas")
-    # head_pack on an odd head count degrades to pack=1 legality
-    _assert_fold(mesh, (2, 32, 3, 4), "scan", head_pack=2)
+    # an odd head count at dh 64 keeps one head per program: legal
+    _assert_fold(mesh, (2, 32, 3, 64), "pallas")
+    _assert_fold(mesh, (2, 32, 3, 4), "scan")
     q = _rand((2, 32, 2, 4), 1)
     ref = local_attention(q, q, q, causal=True)
     got = sequence_sharded_attention(mesh, q, q, q, causal=True,

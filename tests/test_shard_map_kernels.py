@@ -307,23 +307,40 @@ def test_ring_fold_gate_non_tpu_keeps_scan(monkeypatch):
     assert unit.ring_active and unit._ring_fold == "scan"
 
 
-def test_head_pack_gate(monkeypatch):
-    """engine.flash_head_pack resolves pack=2 only on pack-legal
-    geometry, for the local flash path and the ring fold alike —
-    default OFF (the chip A/B decides adoption)."""
+def test_head_pack_gate(monkeypatch, caplog):
+    """Where the kernels find a head's tiles, and how many heads share
+    a program, is resolved from the shapes alone — the address in the
+    projections' own layout at dh 64 (pairs) and dh 128, the head-major
+    one where no lane-legal column block exists — and reported in the
+    info line and in ``znicz_flash_layout``.  The retired
+    ``engine.flash_head_pack`` steers nothing."""
+    import logging
+
+    from znicz_tpu.observe import metrics as obs_metrics
     _fake_tpu(monkeypatch)
     unit = _attention_unit(XLADevice(), d=32, heads=2)   # dh = 16
-    assert unit._flash_pallas and unit._flash_pack == 1  # default off
+    assert unit._flash_pallas
+    assert unit._flash_layout == ("head_major", 1)
     root.common.engine.flash_head_pack = True
     unit = _attention_unit(XLADevice(), d=32, heads=2)
-    assert unit._flash_pallas and unit._flash_pack == 2
+    assert unit._flash_layout == ("head_major", 1)
+    with caplog.at_level(logging.INFO):
+        unit = _attention_unit(XLADevice(), d=128, heads=2)  # dh = 64
+    assert unit._flash_layout == ("boundary", 2)
+    assert "layout=boundary, head pack 2" in caplog.text
+    assert obs_metrics.flash_layout(unit.name, "boundary", 2).value == 1
+    assert ('znicz_flash_layout{unit="%s",layout="boundary",pack="2"} 1'
+            % unit.name) in obs_metrics.REGISTRY.to_prometheus()
+    unit = _attention_unit(XLADevice(), d=256, heads=2)  # dh = 128
+    assert unit._flash_layout == ("boundary", 1)
+    # an odd head count keeps one head per program, never raises
+    unit = _attention_unit(XLADevice(), d=192, heads=3)
+    assert unit._flash_layout == ("head_major", 1)
+    # the ring keeps the head-major address and takes the same pack
     unit = _attention_unit(
         XLADevice(mesh=make_mesh(n_data=2, n_model=2)),
-        seq_parallel=True, d=32, heads=2)
-    assert unit._ring_fold == "pallas" and unit._ring_pack == 2
-    # odd head count degrades to 1, never raises
-    unit = _attention_unit(XLADevice(), d=48, heads=3)
-    assert unit._flash_pack == 1
+        seq_parallel=True, d=128, heads=2)
+    assert unit._ring_fold == "pallas" and unit._flash_layout is None
 
 
 def test_causal_schedule_resolves_from_shapes_not_options(monkeypatch,

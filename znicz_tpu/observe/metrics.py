@@ -556,6 +556,24 @@ def flash_tiles(unit: str, cls: str) -> Gauge:
         labels=("unit", "class")).labels(**{"unit": unit, "class": cls})
 
 
+def flash_layout(unit: str, layout: str, pack: int) -> Gauge:
+    """Where an attention unit's flash kernels find a head's tiles:
+    ``layout`` = ``boundary`` (column blocks of the projections' own
+    (B, T, ·) arrays, addressed in place: no transpose, slice or
+    concatenate around a kernel) or ``head_major`` (a head width with
+    no lane-legal column block: the tiles are moved there first);
+    ``pack`` = heads per kernel program (2: pairs of dh-64 heads fill
+    the 128 lanes).  Static per program, 1 for the combination in
+    force, set once at ``initialize``."""
+    return REGISTRY.gauge(
+        "znicz_flash_layout",
+        "Address of the flash-attention kernels' tiles (boundary, "
+        "head_major) and heads per kernel program; 1 for the "
+        "combination in force",
+        labels=("unit", "layout", "pack")).labels(
+            unit=unit, layout=layout, pack=str(pack))
+
+
 def moe_expert_tokens(unit: str, stat: str) -> Gauge:
     """Rows (token, expert) pairs an expert of a ``MoE`` unit computed
     per step, over the last epoch: ``stat`` = ``max`` / ``min`` (the

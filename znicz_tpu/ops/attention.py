@@ -77,12 +77,37 @@ def apply_rope(xp, x, cos, sin, inverse: bool = False):
     ``(x₁, x₂) → (x₁·cos − x₂·sin, x₂·cos + x₁·sin)`` with x₁ / x₂ the
     two halves of the head.  ``inverse`` rotates back — the adjoint,
     which is what the backward applies to the cotangent."""
+    return _rotate(xp, x, cos[None, :, None, :], sin[None, :, None, :],
+                   inverse)
+
+
+def _rotate(xp, x, c, s, inverse: bool = False):
+    """The half-split rotation of (…, dh) by tables that broadcast
+    against (…, dh/2)."""
     half = x.shape[-1] // 2
     x1, x2 = x[..., :half], x[..., half:]
-    c, s = cos[None, :, None, :], sin[None, :, None, :]
     if inverse:
         s = -s
     return xp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+
+
+def apply_rope_rows(xp, x, cos, sin, n_heads: int):
+    """:func:`apply_rope` over (B, T, H·dh) rows without leaving their
+    layout.  On a TPU a (B, T, D) array lies in tiles of 8 rows × 128
+    columns, and its (B, T, H, dh) view in tiles of 8 HEADS × 128: no
+    bitcast, so slicing heads there costs a relayout each way (compiled
+    for a described v5e: eight of B·T·D elements per layer and step;
+    PR 28).  The view (B, T/8, H, 8, dh) keeps 8 rows of one head
+    together — the tiles the rows already lie in — and there the
+    rotation is fusions over them and nothing else."""
+    b, t, d = x.shape
+    dh = d // n_heads
+    sub = 8 if t % 8 == 0 else 1
+    tiles = x.reshape(b, t // sub, sub, n_heads, dh) \
+        .transpose(0, 1, 3, 2, 4)
+    table = (t // sub, 1, sub, dh // 2)
+    out = _rotate(xp, tiles, cos.reshape(table), sin.reshape(table))
+    return out.transpose(0, 1, 3, 2, 4).reshape(b, t, d)
 
 
 def _local_attention_np(q, k, v, causal: bool):
@@ -247,20 +272,21 @@ class MultiHeadAttention(Forward):
         dh = d // self.n_heads
         tpu_capable = (pallas_kernels.is_tpu_device(self.device)
                        or interpret)
-        # head packing (round 6, ``engine.flash_head_pack``): pairs of
-        # dh≤64 heads ride one 128-lane kernel program — exact
-        # per-head math, kernel-boundary reshape only.  OPT-IN pending
-        # the chip A/B (the decision rule: kept only if it moves
-        # toward the head_dim-128 MFU-0.405 ceiling — PERF.md).
-        head_pack = pallas_attention.resolve_head_pack(
-            root.common.engine.get("flash_head_pack", False),
-            self.n_heads, dh)
+        # where a head's tiles lie and how many heads share a kernel
+        # program come from the shapes (pallas_attention.head_layout):
+        # at dh 128 a head, at dh 64 a pair of heads is a 128-lane
+        # column block of the projection, which the kernels address in
+        # place; no option steers it (``engine.flash_head_pack`` was
+        # the pair body's opt-in: measured on the LM cell, −1.4 ms of
+        # kernels per step with the copies still there, and gone with
+        # them; PERF.md §6, PR 28)
+        layout, head_pack = pallas_attention.head_layout(self.n_heads,
+                                                         dh)
         #: which fold the ring runs ("pallas"/"scan"; None = no ring)
         #: — the multichip dryrun attests this
         self._ring_fold = None
         self._ring_block_q = None
         self._ring_block_k = self.flash_block_k
-        self._ring_pack = 1
         if self._ring_active:
             from znicz_tpu.parallel.ring_attention import \
                 ring_fold_choice
@@ -272,9 +298,7 @@ class MultiHeadAttention(Forward):
                     mesh, (b, t, self.n_heads, dh),
                     axis_name=self._ring_axis,
                     block_k=self.flash_block_k,
-                    pallas_fold=bool(rflag), head_pack=head_pack)
-            self._ring_pack = (head_pack
-                               if self._ring_fold == "pallas" else 1)
+                    pallas_fold=bool(rflag))
         # the kernels' tile schedule comes from the shapes and
         # ``causal`` alone (pallas_attention.grid_blocks /
         # sub_tile_for; PERF.md §6, PR 24): the grid tile is the unit
@@ -287,7 +311,6 @@ class MultiHeadAttention(Forward):
         # 1.39 × slower at 512, 2.8 × at 256 on the chip, and is gone).
         bq, bk = pallas_attention.grid_blocks(
             self.causal, t, t, None, self.flash_block_k)
-        self._flash_pack = head_pack
         self._flash_block_q, self._flash_block_k = bq, bk
         self._flash_interpret = interpret
         self._flash_mesh = None
@@ -327,10 +350,14 @@ class MultiHeadAttention(Forward):
                 self._flash_mesh, self._flash_spec = mesh, spec
         self._flash_pallas = local and refused is None
         #: the compute sub-tile the kernels walk inside a (bq, bk)
-        #: grid tile, and how the T × T square splits over it
+        #: grid tile, how the T × T square splits over it, and where
+        #: the kernels find a head's tiles ("boundary": in the
+        #: projections' own layout; "head_major": moved there first)
         self._flash_sub_tile = None
         self._flash_tiles = None
+        self._flash_layout = None
         if self._flash_pallas:
+            self._flash_layout = (layout, head_pack)
             sq, sk = pallas_attention.sub_tile_for(self.causal, bq, bk)
             self._flash_sub_tile = (sq, sk)
             self._flash_tiles = pallas_attention.causal_tile_counts(
@@ -339,6 +366,7 @@ class MultiHeadAttention(Forward):
             for cls in ("interior", "crossing", "skipped"):
                 obs_metrics.flash_tiles(self.name, cls).set(
                     self._flash_tiles[cls])
+            obs_metrics.flash_layout(self.name, layout, head_pack).set(1)
         if self._ring_active:
             self.info("%s: ring attention over '%s', %s fold",
                       self.name, self._ring_axis, self._ring_fold)
@@ -346,12 +374,13 @@ class MultiHeadAttention(Forward):
             tiles = self._flash_tiles
             self.info("%s: flash kernel, blocks (%d, %d), sub-tiles "
                       "(%d, %d): %d interior + %d crossing of %d = "
-                      "%.4f of T×T executed, head pack %d%s%s",
+                      "%.4f of T×T executed, layout=%s, head pack %d"
+                      "%s%s",
                       self.name, bq, bk, *self._flash_sub_tile,
                       tiles["interior"], tiles["crossing"],
                       tiles["interior"] + tiles["crossing"]
                       + tiles["skipped"], tiles["executed_share"],
-                      head_pack,
+                      layout, head_pack,
                       ", per shard under shard_map"
                       if self._flash_mesh is not None else "",
                       ", INTERPRETED" if interpret else "")
@@ -380,9 +409,10 @@ class MultiHeadAttention(Forward):
                 self.bias_out.devmem if self.include_bias else None,
                 dev(self.gain_norm), dev(self.gain_q), dev(self.gain_k))
 
-    def _normed_rotated(self, xp, qkv, g_q, g_k):
+    def _normed_rotated(self, xp, qkv, g_q, g_k, rows: bool = False):
         """(B, T, 3D) f32 projections → q, k, v (B, T, H, dh) with the
-        whole-projection q/k norms and the rotation applied."""
+        whole-projection q/k norms and the rotation applied; ``rows``
+        keeps them (B, T, D), where the flash kernels read them."""
         b, t, d3 = qkv.shape
         d = d3 // 3
         q, k, v = qkv[..., :d], qkv[..., d:2 * d], qkv[..., 2 * d:]
@@ -390,11 +420,16 @@ class MultiHeadAttention(Forward):
             q = rms_norm(xp, q, g_q, self.norm_eps)
             k = rms_norm(xp, k, g_k, self.norm_eps)
         shape = (b, t, self.n_heads, d // self.n_heads)
-        q, k, v = q.reshape(shape), k.reshape(shape), v.reshape(shape)
+        if not rows:
+            q, k, v = q.reshape(shape), k.reshape(shape), v.reshape(shape)
         if self.rope_theta is not None:
             cos, sin = rope_tables(xp, t, shape[-1], self.rope_theta)
-            q = apply_rope(xp, q, cos, sin)
-            k = apply_rope(xp, k, cos, sin)
+            if rows:
+                q = apply_rope_rows(xp, q, cos, sin, self.n_heads)
+                k = apply_rope_rows(xp, k, cos, sin, self.n_heads)
+            else:
+                q = apply_rope(xp, q, cos, sin)
+                k = apply_rope(xp, k, cos, sin)
         return q, k, v
 
     def xla_forward(self, x, w_qkv, b_qkv, w_out, b_out,
@@ -409,20 +444,41 @@ class MultiHeadAttention(Forward):
         # attention-core GEMM/storage dtype: the repo-wide bf16-inputs/
         # f32-accumulation convention (profiled: the core's (T, T)
         # tensors are the step's HBM-bandwidth sink — PERF.md round 5).
-        # Cast ONCE here so q/k/v reach the core (and the flash
-        # kernel's layout transposes) at half width.
+        # Cast ONCE here so q/k/v reach the core at half width.
         dot_dtype = self.mxu_dtype
-        if self.qk_norm or self.rope_theta is not None:
-            # norms and rotation in f32, THEN the cast
-            q, k, v = self._normed_rotated(
-                jnp, qkv.reshape(b, t, 3 * d), g_q, g_k)
-            if dot_dtype is not None:
-                q, k, v = (a.astype(dot_dtype) for a in (q, k, v))
-        else:
+        fused = not self.qk_norm and self.rope_theta is None
+        flash = getattr(self, "_flash_pallas", False)   # never the ring
+        if fused:
+            # q, k, v are column ranges of ONE projection result
             if dot_dtype is not None:
                 qkv = qkv.astype(dot_dtype)
-            q, k, v = _split_heads(qkv.reshape(b, t, 3 * d),
-                                   self.n_heads)
+            arrays = (qkv.reshape(b, t, 3 * d),)
+        else:
+            # norms and rotation in f32, THEN the cast
+            arrays = self._normed_rotated(
+                jnp, qkv.reshape(b, t, 3 * d), g_q, g_k, rows=flash)
+            if dot_dtype is not None:
+                arrays = tuple(a.astype(dot_dtype) for a in arrays)
+        if flash:
+            from znicz_tpu.ops import pallas_attention
+            # the kernels read q, k, v and write o where the
+            # projections have them: a head (a pair at dh 64) is a
+            # column block of (B, T, ·), so neither a transpose nor a
+            # slice stands between a projection and a kernel, forward
+            # or backward (24 copies of 0.6 ms a step in the LM cell
+            # before; PERF.md §6, PR 28)
+            o = pallas_attention.flash_attention_rows(
+                arrays, self.n_heads, causal=self.causal,
+                block_q=getattr(self, "_flash_block_q", None),
+                block_k=getattr(self, "_flash_block_k",
+                                self.flash_block_k),
+                dot_dtype=dot_dtype,
+                interpret=getattr(self, "_flash_interpret", False),
+                mesh=getattr(self, "_flash_mesh", None),
+                spec=getattr(self, "_flash_spec", None))
+            return self._project_out(x32, o, w_out, b_out)
+        q, k, v = _split_heads(arrays[0], self.n_heads) if fused \
+            else arrays
         if self.ring_active:
             from znicz_tpu.parallel.ring_attention import \
                 sequence_sharded_attention
@@ -438,26 +494,7 @@ class MultiHeadAttention(Forward):
                              == "pallas"),
                 pallas_interpret=getattr(self, "_flash_interpret",
                                          False),
-                pallas_block_q=getattr(self, "_ring_block_q", None),
-                head_pack=getattr(self, "_ring_pack", 1))
-        elif getattr(self, "_flash_pallas", False):
-            from znicz_tpu.ops import pallas_attention
-            # (a head-major fast path — contracting the kernel's
-            # native (B, H, T, Dh) output directly with a reshaped
-            # W_out to skip the boundary transposes — was measured
-            # NEUTRAL within the ±2–4% run band and reverted per the
-            # decision rule: neutral keeps the simpler path.  PERF.md
-            # round 5.)
-            o = pallas_attention.flash_attention(
-                q, k, v, causal=self.causal,
-                block_q=getattr(self, "_flash_block_q", None),
-                block_k=getattr(self, "_flash_block_k",
-                                self.flash_block_k),
-                dot_dtype=dot_dtype,
-                interpret=getattr(self, "_flash_interpret", False),
-                mesh=getattr(self, "_flash_mesh", None),
-                spec=getattr(self, "_flash_spec", None),
-                head_pack=getattr(self, "_flash_pack", 1))
+                pallas_block_q=getattr(self, "_ring_block_q", None))
         elif self.flash_block_k:
             from znicz_tpu.parallel.ring_attention import \
                 local_attention_blocked
@@ -468,6 +505,13 @@ class MultiHeadAttention(Forward):
             from znicz_tpu.parallel.ring_attention import local_attention
             o = local_attention(q, k, v, causal=self.causal,
                                 dot_dtype=dot_dtype)
+        return self._project_out(x32, o, w_out, b_out)
+
+    def _project_out(self, x32, o, w_out, b_out):
+        """The out-projection over the core's result — (B, T, H, dh)
+        or (B, T, D), (B·T, D) by a free reshape either way — and the
+        residual."""
+        b, t, d = x32.shape
         y = self.mxu_dot(jnp, o.reshape(b * t, d), w_out)
         if b_out is not None:
             y = y + b_out

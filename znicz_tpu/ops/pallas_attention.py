@@ -62,21 +62,44 @@ Design (the standard flash decomposition, implemented TPU-first):
   recompute both) so the statistics degrade to (m=-inf, l=0) instead
   of exploding; such a hop contributes lse ≈ -1e30 and weight 0 to
   the cross-hop combination.
-- **head packing** (round 6, ``pack=2``): pairs of dh=64 heads ride
-  one kernel program as a (…, 128)-lane layout — q/k/v/o tiles carry
-  both sub-heads side by side in the lane dim (full 128-lane VMEM
-  loads/stores and element ops instead of half-width dh=64 tiles, the
-  measured half-MXU bottleneck: MFU 0.25 at head_dim 64 vs 0.405 at
-  128 — PERF.md round 5), while every GEMM and every softmax
+- **head packing** (``pack=2``, from the shapes: :func:`head_pack_for`):
+  pairs of dh=64 heads ride one kernel program as a (…, 128)-lane
+  tile — q/k/v/o tiles carry both sub-heads side by side in the lane
+  dim (full 128-lane VMEM loads/stores and element ops instead of
+  half-width dh=64 tiles), while every GEMM and every softmax
   statistic stays per-sub-head (static lane slices), so the math is
-  exactly per-head attention.  The pack happens as a free reshape at
-  the (B, T, H, Dh) boundary (heads are adjacent to Dh there), never
-  a model change.
+  exactly per-head attention.  The pair is adjacent in the
+  projection's columns, so packing moves nothing.  Measured on the LM
+  cell with the layout copies still in place (PR 28, PERF.md §6): the
+  pair body takes 61.26 ms of kernels a step where one head per
+  program takes 62.70 (forward +0.39, dq −0.62, dk/dv −1.21); it was
+  ``engine.flash_head_pack``, opt-in, and is now simply what dh 64
+  with an even head count gets.  Four, eight or sixteen narrower heads
+  to a program outgrow the scoped VMEM and are not packed.
 
-Layout contract: (B, T, H, D) at the boundary (the unit-graph
-convention); kernels run head-major (B, H, T, D) — the wrapper
-transposes, which costs two cheap bandwidth passes versus the many
-(T, T) passes saved.
+Layout contract: the kernels read and write the projections' OWN
+layout.  At the boundary q, k, v are (B, T, D) rows — column ranges of
+one (B, T, 3·D) projection result, or three tensors — and a head (a
+pair at dh 64) is a 128-lane COLUMN BLOCK of them: the ``BlockSpec``
+index map picks block ``col0 + head`` (:func:`_tile`, :func:`_operands`),
+so no transpose, slice or concatenate stands between a projection and
+a kernel, forward or backward.  ``o`` is written at its column block
+of a (B, T, D) result (the out-projection's (B·T, D) by a free
+reshape), ``do`` is read in place, and dq, dk, dv land in column
+blocks of the cotangent: for one fused array the dq call begins a
+(B, T, 3·D) result and the dk/dv call takes it aliased and writes the
+rest (its grid's last axis has one step more, at which the output
+block moves from dk's to dv's).  ``lse`` and ``delta`` stay head-major
+(B, Hp, T, lanes): they are the kernels' own.  Head widths with no
+lane-legal column block (dh 32, 80, 96, 192, an odd head count at
+dh 64: :func:`head_layout`) keep the head-major address (B, Hp, T,
+pack·dh) — ``pack_heads`` / ``unpack_heads`` transpose around the
+call — as does the ring, whose K and V travel between chips in it.
+The bodies are shared: every ``BlockSpec`` squeezes its leading dims,
+so a kernel sees (rows, width) tiles wherever they lay.  Before PR 28
+the wrapper transposed every operand ("two cheap bandwidth passes"):
+eleven activation-sized moves per layer and step in the compiled LM
+program, 20.8 ms of ``copy`` a step on the chip (PERF.md §6).
 
 Adoption is measured, not assumed: SEQ_BENCH.json / PERF.md round 5
 carry the chip A/B against the plain and scan-blocked XLA forms.
@@ -165,17 +188,33 @@ def kernel_legal(t_q: int, t_k: int, dh: int, bq: int, bk: int) -> bool:
             and t_q % 8 == 0 and t_k % 8 == 0 and dh % 8 == 0)
 
 
-def resolve_head_pack(flag, n_heads: int, dh: int) -> int:
-    """Head-pack factor for the kernel call path: 2 when the
-    ``engine.flash_head_pack`` gate is on and pairs of heads fit the
-    128-lane tile (dh·2 ≤ 128, lane-legal, head count even) — else 1.
-    A model change is never implied; packing is a kernel-boundary
-    reshape."""
-    if not flag:
-        return 1
-    if n_heads % 2 == 0 and dh % 8 == 0 and dh * 2 <= 128:
+def head_pack_for(n_heads: int, dh: int) -> int:
+    """How many heads ride one kernel program, from the shapes alone:
+    2 where a pair fills the 128 lanes exactly (dh 64) and the head
+    count is even, else 1.  Never a model change: the pair lies side by
+    side in the projection's columns, and the kernels' matmuls and
+    statistics stay per head.  Narrower heads would fill the lanes
+    four, eight or sixteen at a time, but with every sub-head's score
+    run live the kernels outgrow the scoped VMEM at the chooser's tiles
+    (compiled for a described v5e at dh 32, 16 and 8: PR 28), so they
+    keep one head per program, as before."""
+    if 2 * dh == _STAT_LANES and n_heads % 2 == 0:
         return 2
     return 1
+
+
+def head_layout(n_heads: int, dh: int) -> tuple:
+    """``(layout, pack)`` of a call over ``n_heads`` heads of ``dh``:
+    ``"boundary"`` where a program's tile is a lane-legal column block
+    of the (B, T, H·dh) projection (``pack·dh`` a multiple of 128:
+    dh 128, 256, …, or pairs of dh 64), so the kernels address it where
+    it lies; else ``"head_major"`` (dh 32, 80, 96, 192, an odd head
+    count at dh 64: no such block exists, the wrapper transposes).  Static per program:
+    the attention unit reports it (info line, ``znicz_flash_layout``)."""
+    pack = head_pack_for(n_heads, dh)
+    if (pack * dh) % _STAT_LANES == 0:
+        return "boundary", pack
+    return "head_major", pack
 
 
 # ----------------------------------------------------------------------
@@ -377,7 +416,7 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         """ONE online-softmax update of rows [r, r+sq) by every column
         run in ``parts``."""
         rs = _ds(r, sq)
-        q_all = q_ref[0, 0, rs, :]
+        q_all = q_ref[rs, :]
         d = q_all.shape[1]
         dh, sw = d // pack, _STAT_LANES // pack
         masks = [_causal_mask(row0 + r, col0 + c, sq, n) if masked
@@ -390,7 +429,7 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             m_prev = m_all[:, p * sw:p * sw + 1]        # (sq, 1)
             scores, m_new = [], m_prev
             for (c, n, _), mask in zip(parts, masks):
-                s = _dot(q, k_ref[0, 0, c:c + n, fs],
+                s = _dot(q, k_ref[c:c + n, fs],
                          trans_b=True) * scale
                 if mask is not None:
                     s = jnp.where(mask, s, _NEG_INF)
@@ -409,7 +448,7 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                     # its row maximum is finite: neither select
                     pt = jnp.where(mask, pt, 0.0)
                 l_new = l_new + jnp.sum(pt, axis=1, keepdims=True)
-                v = v_ref[0, 0, c:c + n, fs]
+                v = v_ref[c:c + n, fs]
                 acc = acc + _dot(pt.astype(v.dtype), v)
             l_out.append(jnp.broadcast_to(l_new, (sq, sw)))
             acc_out.append(acc)
@@ -426,7 +465,7 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(ik == nk - 1)
     def _finish():
-        d = o_ref.shape[3]
+        d = o_ref.shape[1]
         dh, sw = d // pack, _STAT_LANES // pack
         o_out, lse_out = [], []
         for p in range(pack):
@@ -438,48 +477,9 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             lse_out.append(jnp.broadcast_to(
                 m_scr[:, p * sw:p * sw + 1] + jnp.log(l),
                 (bq, _LANES)))
-        o_ref[0, 0] = jnp.concatenate(o_out, axis=1)
-        lse_ref[0, 0] = jnp.concatenate(lse_out, axis=1)
+        o_ref[...] = jnp.concatenate(o_out, axis=1)
+        lse_ref[...] = jnp.concatenate(lse_out, axis=1)
 
-
-# jitted: every layer of a model calls these with the same static
-# arguments and shapes, so the kernels are traced and lowered ONCE per
-# program, not once per layer (on the chip's host, lowering the LM
-# cell's 18 flash calls cost 7 s of every start, cached program or not;
-# PERF.md §6, PR 24)
-@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9, 10))
-def _fwd_call(q, k, v, q_off, k_off, causal, bq, bk, interpret, pack,
-              sub=None):
-    b, h, t, d = q.shape
-    tk = k.shape[2]
-    nq, nk = t // bq, tk // bk
-    sq, sk = sub or sub_tile_for(causal, bq, bk)
-    kernel = functools.partial(_fwd_kernel,
-                               scale=1.0 / np.sqrt(d // pack),
-                               causal=causal, bq=bq, bk=bk, sq=sq,
-                               sk=sk, pack=pack)
-    off_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    qspec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, iq, ik: (b_, h_, iq, 0))
-    kspec = pl.BlockSpec((1, 1, bk, d), lambda b_, h_, iq, ik: (b_, h_, ik, 0))
-    lanes = pack * _LANES
-    return pl.pallas_call(
-        kernel,
-        grid=(b, h, nq, nk),
-        in_specs=[off_spec, off_spec, qspec, kspec, kspec],
-        out_specs=(qspec,
-                   pl.BlockSpec((1, 1, bq, lanes),
-                                lambda b_, h_, iq, ik: (b_, h_, iq, 0))),
-        out_shape=(jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
-                   jax.ShapeDtypeStruct((b, h, t, lanes), jnp.float32)),
-        scratch_shapes=[pltpu.VMEM((bq, _STAT_LANES), jnp.float32),
-                        pltpu.VMEM((bq, _STAT_LANES), jnp.float32),
-                        pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-        name="znicz_flash_fwd",
-    )(q_off, k_off, q, k, v)
 
 
 # ----------------------------------------------------------------------
@@ -515,8 +515,8 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
     def body(r, parts):
         """dq of rows [r, r+sq) from every column run in ``parts``."""
         rs = _ds(r, sq)
-        q_all, do_all = q_ref[0, 0, rs, :], do_ref[0, 0, rs, :]
-        lse, delta = lse_ref[0, 0, rs, :], delta_ref[0, 0, rs, :]
+        q_all, do_all = q_ref[rs, :], do_ref[rs, :]
+        lse, delta = lse_ref[rs, :], delta_ref[rs, :]
         dh = q_all.shape[1] // pack
         out = []
         for p in range(pack):
@@ -526,7 +526,7 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
             for c, n, masked in parts:
                 mask = (_causal_mask(row0 + r, col0 + c, sq, n)
                         if masked else None)
-                k, v = k_ref[0, 0, c:c + n, fs], v_ref[0, 0, c:c + n, fs]
+                k, v = k_ref[c:c + n, fs], v_ref[c:c + n, fs]
                 pt = _p_tile(q_all[:, fs], k, lse[:, ls], scale, mask)
                 dp = _dot(do_all[:, fs], v, trans_b=True)
                 ds = pt * (dp - delta[:, ls]) * scale
@@ -543,12 +543,22 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
 
     @pl.when(ik == nk - 1)
     def _finish():
-        dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
+        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
-                lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                scale, causal, bq, bk, sq, sk, pack):
+                lse_ref, delta_ref, *rest, scale, causal, bq, bk, sq,
+                sk, pack, shared):
+    """``shared`` (None, or the first column blocks (k, v) of dk and
+    dv): both are column blocks of ONE result — the cotangent of a
+    fused projection, which the dq call has begun and hands in aliased.
+    A kernel's blocked output is one block per grid step, so the two
+    finished tiles go there by DMA from VMEM."""
+    if shared is None:
+        dk_ref, dv_ref, dk_scr, dv_scr = rest
+    else:               # the aliased operand itself is never read
+        _, out_ref, dk_scr, dv_scr, dk_tile, dv_tile, sems = rest
+    batch, head = pl.program_id(0), pl.program_id(1)
     ik, iq = pl.program_id(2), pl.program_id(3)
     nq = pl.num_programs(3)
     row0 = qoff_ref[0, 0] + iq * bq
@@ -563,7 +573,7 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         """dk, dv of columns [c, c+sk) from every row run in
         ``parts``."""
         cs = _ds(c, sk)
-        k_all, v_all = k_ref[0, 0, cs, :], v_ref[0, 0, cs, :]
+        k_all, v_all = k_ref[cs, :], v_ref[cs, :]
         dh = k_all.shape[1] // pack
         dk_out, dv_out = [], []
         for p in range(pack):
@@ -573,14 +583,14 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
             for r, n, masked in parts:
                 mask = (_causal_mask(row0 + r, col0 + c, n, sk)
                         if masked else None)
-                q, do = q_ref[0, 0, r:r + n, fs], do_ref[0, 0, r:r + n, fs]
+                q, do = q_ref[r:r + n, fs], do_ref[r:r + n, fs]
                 pt = _p_tile(q, k_all[:, fs],
-                             lse_ref[0, 0, r:r + n, ls], scale, mask)
+                             lse_ref[r:r + n, ls], scale, mask)
                 # dv += pᵀ · do ; contract the q dim without
                 # materializing pᵀ
                 dv_part = _dot(pt.astype(do.dtype), do, trans_a=True)
                 dp = _dot(do, v_all[:, fs], trans_b=True)
-                ds = pt * (dp - delta_ref[0, 0, r:r + n, ls]) * scale
+                ds = pt * (dp - delta_ref[r:r + n, ls]) * scale
                 dk_part = _dot(ds.astype(q.dtype), q, trans_a=True)
                 dk = dk_part if dk is None else dk + dk_part
                 dv = dv_part if dv is None else dv + dv_part
@@ -597,115 +607,261 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
 
     @pl.when(iq == nq - 1)
     def _finish():
-        dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
+        if shared is None:
+            dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+            dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+            return
+        d = dk_scr.shape[1]
+        rows = pl.ds(pl.multiple_of(ik * bk, bk), bk)
+        copies = []
+        for acc, tile, first, sem in ((dk_scr, dk_tile, shared[0], 0),
+                                      (dv_scr, dv_tile, shared[1], 1)):
+            tile[...] = acc[...].astype(tile.dtype)
+            lanes = pl.ds(pl.multiple_of((first + head) * d, d), d)
+            copies.append(pltpu.make_async_copy(
+                tile, out_ref.at[batch, rows, lanes], sems.at[sem]))
+            copies[-1].start()
+        for copy in copies:
+            copy.wait()
 
 
-@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11, 12, 13))
-def _bwd_call(q, k, v, lse, do, delta4, q_off, k_off, causal, bq, bk,
-              interpret, pack, sub=None):
-    """``delta4``: (B, H, T, pack) f32 — rowsum(do·o) per SUB-head,
+def _tile(rows: int, width: int, col0, rows_of):
+    """``BlockSpec`` of one head's (rows, width) tile at grid position
+    (batch, head, i, j); ``rows_of(i, j)`` is its row block.  ``col0``
+    None: the operand is head-major (B, H, T, width).  Else it is in
+    the boundary layout (B, T, C) and the tile is column block
+    ``col0 + head`` — an address, so nothing is moved to where the
+    kernel could have fetched it.  Leading dims are squeezed: the
+    kernels see (rows, width) either way."""
+    if col0 is None:
+        return pl.BlockSpec((None, None, rows, width),
+                            lambda b, h, i, j: (b, h, rows_of(i, j), 0))
+    return pl.BlockSpec((None, rows, width),
+                        lambda b, h, i, j: (b, rows_of(i, j), col0 + h))
+
+
+def _first(i, j):
+    """The row block of a tile that follows the grid's third axis."""
+    return i
+
+
+def _second(i, j):
+    """… or its last."""
+    return j
+
+
+def _operands(arrays, cols):
+    """(q, k, v), (batch, head programs, T_q, T_k, tile width) and the
+    three first column blocks of a call's ``arrays``.  ``cols`` None:
+    three head-major (B, Hp, T, width) arrays, no column blocks.  Else
+    ``cols`` = (head programs, width) of boundary-layout (B, T, C)
+    operands: three arrays, each from its block 0 on, or ONE projection
+    result whose column ranges are q, k, v in turn."""
+    q, k, v = arrays if len(arrays) == 3 else arrays * 3
+    if cols is None:
+        b, h, t, d = q.shape
+        return (q, k, v), (b, h, t, k.shape[2], d), (None,) * 3
+    h, d = cols
+    first = (0, 0, 0) if len(arrays) == 3 else (0, h, 2 * h)
+    return (q, k, v), (q.shape[0], h, q.shape[1], k.shape[1], d), first
+
+
+# jitted: every layer of a model calls these with the same static
+# arguments and shapes, so the kernels are traced and lowered ONCE per
+# program, not once per layer (on the chip's host, lowering the LM
+# cell's 18 flash calls cost 7 s of every start, cached program or not;
+# PERF.md §6, PR 24)
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _fwd_call(arrays, q_off, k_off, causal, bq, bk, interpret, pack,
+              sub=None, cols=None):
+    """``arrays``: (q, k, v) head-major (B, Hp, T, pack·dh) where
+    ``cols`` is None; else boundary-layout (B, T, C) operands, ``cols``
+    = (head programs, tile width) — three arrays, or ONE that holds all
+    three (:func:`_operands`).  Returns
+    (out, lse): out in the operands' layout ((B, T, heads·width) for
+    the boundary layout), lse head-major (B, Hp, T, pack·_LANES)."""
+    (q, k, v), (b, h, t, tk, d), (cq, ck, cv) = _operands(arrays, cols)
+    nq, nk = t // bq, tk // bk
+    sq, sk = sub or sub_tile_for(causal, bq, bk)
+    kernel = functools.partial(_fwd_kernel,
+                               scale=1.0 / np.sqrt(d // pack),
+                               causal=causal, bq=bq, bk=bk, sq=sq,
+                               sk=sk, pack=pack)
+    off_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
+    lanes = pack * _LANES
+    out_shape = (b, h, t, d) if cols is None else (b, t, h * d)
+    return pl.pallas_call(
+        kernel,
+        grid=(b, h, nq, nk),        # q rows follow axis 2, k rows axis 3
+        in_specs=[off_spec, off_spec, _tile(bq, d, cq, _first),
+                  _tile(bk, d, ck, _second), _tile(bk, d, cv, _second)],
+        out_specs=(_tile(bq, d, None if cols is None else 0, _first),
+                   _tile(bq, lanes, None, _first)),
+        out_shape=(jax.ShapeDtypeStruct(out_shape, q.dtype),
+                   jax.ShapeDtypeStruct((b, h, t, lanes), jnp.float32)),
+        scratch_shapes=[pltpu.VMEM((bq, _STAT_LANES), jnp.float32),
+                        pltpu.VMEM((bq, _STAT_LANES), jnp.float32),
+                        pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=interpret,
+        name="znicz_flash_fwd",
+    )(q_off, k_off, q, k, v)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12))
+def _bwd_call(arrays, lse, do, delta4, q_off, k_off, causal, bq, bk,
+              interpret, pack, sub=None, cols=None):
+    """Cotangents of ``arrays`` (see :func:`_fwd_call`), in their
+    layout: (dq, dk, dv), or for ONE fused array its one cotangent —
+    the dq call writes q's column blocks of a (B, T, C) result, the
+    dk/dv call takes that result aliased and puts its tiles beside
+    them, so no concatenate of activation size stands before the
+    projection's backward.  ``do`` is in the layout of the forward's out;
+    ``delta4``: (B, Hp, T, pack) f32 — rowsum(do·o) per SUB-head,
     already adjusted for any lse cotangent (the hop composition's
     extra term)."""
-    b, h, t, d = q.shape
-    tk = k.shape[2]
+    (q, k, v), (b, h, t, tk, d), (cq, ck, cv) = _operands(arrays, cols)
+    shared = len(arrays) == 1
     nq, nk = t // bq, tk // bk
     sq, sk = sub or sub_tile_for(causal, bq, bk)
     lanes = pack * _LANES
     # per-sub-head delta rides _LANES lanes each, like lse
     delta = jnp.repeat(delta4, _LANES, axis=-1)      # (B, H, T, lanes)
-    scale = 1.0 / np.sqrt(d // pack)
+    static = dict(scale=1.0 / np.sqrt(d // pack), causal=causal, bq=bq,
+                  bk=bk, sq=sq, sk=sk, pack=pack)
     off_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    qspec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, iq, ik: (b_, h_, iq, 0))
-    kspec = pl.BlockSpec((1, 1, bk, d), lambda b_, h_, iq, ik: (b_, h_, ik, 0))
-    rspec = pl.BlockSpec((1, 1, bq, lanes),
-                         lambda b_, h_, iq, ik: (b_, h_, iq, 0))
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel",
+                             "arbitrary"))
+    c_do = None if cols is None else 0
+
+    def like(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+    def specs(q_rows, k_rows):
+        return [off_spec, off_spec, _tile(bq, d, cq, q_rows),
+                _tile(bk, d, ck, k_rows), _tile(bk, d, cv, k_rows),
+                _tile(bq, d, c_do, q_rows),
+                _tile(bq, lanes, None, q_rows),
+                _tile(bq, lanes, None, q_rows)]
+
+    operands = [q_off, k_off, q, k, v, do, lse, delta]
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, sq=sq, sk=sk, pack=pack),
+        functools.partial(_dq_kernel, **static),
         grid=(b, h, nq, nk),
-        in_specs=[off_spec, off_spec, qspec, kspec, kspec, qspec,
-                  rspec, rspec],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
+        in_specs=specs(_first, _second),
+        out_specs=_tile(bq, d, cq, _first),
+        out_shape=like(q),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=params,
         interpret=interpret,
         name="znicz_flash_dq",
-    )(q_off, k_off, q, k, v, do, lse, delta)
+    )(*operands)
     # dk/dv: Q blocks innermost; the q-side specs index by the LAST
     # grid dim now, the k-side by dim 2
-    qspec2 = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, ik, iq: (b_, h_, iq, 0))
-    kspec2 = pl.BlockSpec((1, 1, bk, d), lambda b_, h_, ik, iq: (b_, h_, ik, 0))
-    rspec2 = pl.BlockSpec((1, 1, bq, lanes),
-                          lambda b_, h_, ik, iq: (b_, h_, iq, 0))
+    in_specs = specs(_second, _first)
+    accumulators = [pltpu.VMEM((bk, d), jnp.float32),
+                    pltpu.VMEM((bk, d), jnp.float32)]
+    if shared:
+        anywhere = pl.BlockSpec(memory_space=pl.ANY)
+        out = pl.pallas_call(
+            functools.partial(_dkv_kernel, shared=(ck, cv), **static),
+            grid=(b, h, nk, nq),
+            in_specs=in_specs + [anywhere],
+            out_specs=anywhere,
+            out_shape=like(dq),
+            input_output_aliases={len(operands): 0},
+            scratch_shapes=accumulators + [
+                pltpu.VMEM((bk, d), k.dtype), pltpu.VMEM((bk, d), v.dtype),
+                pltpu.SemaphoreType.DMA((2,))],
+            compiler_params=params,
+            interpret=interpret,
+            name="znicz_flash_dkv",
+        )(*operands, dq)
+        return (out,)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, sq=sq, sk=sk, pack=pack),
+        functools.partial(_dkv_kernel, shared=None, **static),
         grid=(b, h, nk, nq),
-        in_specs=[off_spec, off_spec, qspec2, kspec2, kspec2, qspec2,
-                  rspec2, rspec2],
-        out_specs=(kspec2, kspec2),
-        out_shape=(jax.ShapeDtypeStruct((b, h, tk, d), k.dtype),
-                   jax.ShapeDtypeStruct((b, h, tk, d), v.dtype)),
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        in_specs=in_specs,
+        out_specs=(_tile(bk, d, ck, _first), _tile(bk, d, cv, _first)),
+        out_shape=(like(k), like(v)),
+        scratch_shapes=accumulators,
+        compiler_params=params,
         interpret=interpret,
         name="znicz_flash_dkv",
-    )(q_off, k_off, q, k, v, do, lse, delta)
+    )(*operands)
     return dq, dk, dv
 
 
 # ----------------------------------------------------------------------
-# custom_vjp hop (head-major) + the (B, T, H, D) public entry
+# custom_vjp: the head-major hop (the ring's) and the boundary-layout
+# pass (the attention unit's), over the same calls
 # ----------------------------------------------------------------------
+def _delta(do, out, dlse, pack: int, boundary: bool):
+    """delta = rowsum(do·o) per sub-head, (B, Hp, T, pack) f32, from
+    ``do`` / ``out`` head-major (B, Hp, T, pack·dh) or in the boundary
+    layout (B, T, Hp·pack·dh).  There a head's sum runs over a PART of
+    the minor dim, which as a reduce makes XLA relayout the whole f32
+    product twice (compiled for a described v5e, PR 28); contracted
+    with a 0/1 (D, heads) selector at full f32 precision it is one
+    fusion over do and o where they lie, and what is transposed to the
+    kernels' head-major statistics is a number per row and head.  An
+    lse cotangent (the hop composition) enters the score gradient as
+    ds += p·dlse, i.e. delta -= dlse (lanes are value copies →
+    group-sum them)."""
+    prod = do.astype(jnp.float32) * out.astype(jnp.float32)
+    hp = dlse.shape[1]
+    if boundary:
+        b, t, d = prod.shape
+        heads = hp * pack
+        select = np.repeat(np.eye(heads, dtype=np.float32), d // heads,
+                           axis=0)
+        delta4 = jnp.dot(prod.reshape(b * t, d), select,
+                         precision=jax.lax.Precision.HIGHEST) \
+            .reshape(b, t, hp, pack).transpose(0, 2, 1, 3)
+    else:
+        b, _, t, _ = prod.shape
+        delta4 = prod.reshape(b, hp, t, pack, -1).sum(axis=-1)
+    return delta4 - dlse.astype(jnp.float32) \
+        .reshape(b, hp, t, pack, _LANES).sum(axis=-1)
+
+
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(5, 6, 7, 8, 9, 10))
-def _flash_hop(q, k, v, q_off, k_off, causal, bq, bk, interpret, pack,
-               sub=None):
-    """One flash pass over head-major (packed) operands at global
-    positions (q_off, k_off) → (out, lse).  This is BOTH the plain
-    single-call kernel (offsets 0, lse discarded) and the per-hop
-    ring fold (lse feeds the cross-hop online-softmax combination);
-    the lse cotangent folds into delta in the backward, so one
-    custom_vjp serves both."""
-    return _fwd_call(q, k, v, q_off, k_off, causal, bq, bk, interpret,
-                     pack, sub)
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash_pass(arrays, q_off, k_off, causal, bq, bk, interpret, pack,
+                sub=None, cols=None):
+    """One flash pass at global positions (q_off, k_off) → (out, lse)
+    over ``arrays`` as :func:`_fwd_call` takes them: head-major
+    (``cols`` None) or addressed in the boundary layout.  This is BOTH
+    the plain single-call kernel (offsets 0, lse discarded) and the
+    per-hop ring fold (lse feeds the cross-hop online-softmax
+    combination); the lse cotangent folds into delta in the backward,
+    so one custom_vjp serves both."""
+    return _fwd_call(arrays, q_off, k_off, causal, bq, bk, interpret,
+                     pack, sub, cols)
 
 
-def _hop_fwd(q, k, v, q_off, k_off, causal, bq, bk, interpret, pack,
-             sub):
-    out, lse = _fwd_call(q, k, v, q_off, k_off, causal, bq, bk,
-                         interpret, pack, sub)
-    return (out, lse), (q, k, v, out, lse, q_off, k_off)
+def _pass_fwd(arrays, q_off, k_off, causal, bq, bk, interpret, pack,
+              sub, cols):
+    out, lse = _fwd_call(arrays, q_off, k_off, causal, bq, bk,
+                         interpret, pack, sub, cols)
+    return (out, lse), (arrays, out, lse, q_off, k_off)
 
 
-def _hop_bwd(causal, bq, bk, interpret, pack, sub, res, cts):
-    q, k, v, out, lse, q_off, k_off = res
+def _pass_bwd(causal, bq, bk, interpret, pack, sub, cols, res, cts):
+    arrays, out, lse, q_off, k_off = res
     do, dlse = cts
-    do = do.astype(q.dtype)
-    b, h, t, d = q.shape
-    dh = d // pack
-    # delta = rowsum(do·o) per sub-head; the lse cotangent (hop
-    # composition) enters the score gradient as ds += p·dlse, i.e.
-    # delta -= dlse (lanes are value copies → group-sum them)
-    delta4 = jnp.sum(
-        (do.astype(jnp.float32) * out.astype(jnp.float32))
-        .reshape(b, h, t, pack, dh), axis=-1)
-    delta4 = delta4 - dlse.astype(jnp.float32) \
-        .reshape(b, h, t, pack, _LANES).sum(axis=-1)
-    dq, dk, dv = _bwd_call(q, k, v, lse, do, delta4, q_off, k_off,
-                           causal, bq, bk, interpret, pack, sub)
+    do = do.astype(out.dtype)
+    delta4 = _delta(do, out, dlse, pack, cols is not None)
+    grads = _bwd_call(arrays, lse, do, delta4, q_off, k_off, causal,
+                      bq, bk, interpret, pack, sub, cols)
     zero = np.zeros((1, 1), jax.dtypes.float0)
-    return dq, dk, dv, zero, zero
+    return tuple(grads), zero, zero
 
 
-_flash_hop.defvjp(_hop_fwd, _hop_bwd)
+_flash_pass.defvjp(_pass_fwd, _pass_bwd)
 
 
 def ring_hop(qh, kh, vh, q_offset, k_offset, causal: bool,
@@ -714,18 +870,20 @@ def ring_hop(qh, kh, vh, q_offset, k_offset, causal: bool,
     """One ring hop on head-major, already-packed operands
     (B, Hp, T, pack·dh): returns (out in qh.dtype, lse (B, Hp, T,
     pack) f32).  Offsets may be traced scalars (``axis_index``
-    arithmetic under shard_map)."""
-    out, lse = _flash_hop(qh, kh, vh, _off_arr(q_offset),
-                          _off_arr(k_offset), causal, block_q,
-                          block_k, interpret, pack, sub_tile)
+    arithmetic under shard_map).  K and V travel between chips in this
+    layout, so the ring keeps the head-major address (no cell and no
+    chip run covers it: PERF.md §7)."""
+    out, lse = _flash_pass((qh, kh, vh), _off_arr(q_offset),
+                           _off_arr(k_offset), causal, block_q,
+                           block_k, interpret, pack, sub_tile)
     return out, lse[..., ::_LANES]
 
 
 def pack_heads(x, pack: int):
     """(B, T, H, dh) boundary layout → head-major packed
     (B, H//pack, T, pack·dh).  Heads are adjacent to dh at the
-    boundary, so the pack itself is a free reshape; the transpose is
-    the same bandwidth pass the unpacked path already pays."""
+    boundary, so the pack itself is a free reshape; the transpose is a
+    bandwidth pass (the ring's, and a lane-illegal head width's)."""
     b, t, h, dh = x.shape
     return x.reshape(b, t, h // pack, pack * dh).transpose(0, 2, 1, 3)
 
@@ -737,20 +895,52 @@ def unpack_heads(x, pack: int, n_heads: int):
     return x.transpose(0, 2, 1, 3).reshape(b, t, n_heads, d // pack)
 
 
-def flash_attention(q, k, v, causal: bool = False,
-                    block_q: int | None = None,
-                    block_k: int | None = None,
-                    dot_dtype=None, interpret: bool = False,
-                    mesh=None, spec=None, q_offset=None, k_offset=None,
-                    head_pack: int = 1, sub_tile=None):
-    """Fused flash attention: (B, T, H, D) → (B, T, H, D) f32.
+def _rows_pass(arrays, n_heads: int, q_off, k_off, causal, bq, bk,
+               interpret, sub):
+    """(B, T, ·) operands → (B, T, D) out, by the address their head
+    width allows (:func:`head_layout`)."""
+    fused = len(arrays) == 1
+    b, t, c = arrays[0].shape
+    d = c // 3 if fused else c
+    dh = d // n_heads
+    layout, pack = head_layout(n_heads, dh)
+    if layout == "boundary":
+        return _flash_pass(arrays, q_off, k_off, causal, bq, bk,
+                           interpret, pack, sub,
+                           (n_heads // pack, pack * dh))[0]
+    # a head width that is neither a multiple nor a divisor of the 128
+    # lanes: no lane-legal column block, so the tiles are moved
+    if fused:
+        arrays = tuple(arrays[0][..., i * d:(i + 1) * d]
+                       for i in range(3))
+    heads = tuple(pack_heads(a.reshape(b, a.shape[1], n_heads, dh), pack)
+                  for a in arrays)
+    out = _flash_pass(heads, q_off, k_off, causal, bq, bk, interpret,
+                      pack, sub)[0]
+    return unpack_heads(out, pack, n_heads).reshape(b, t, d)
 
-    ``dot_dtype`` casts q/k/v (the tile-GEMM operand dtype — bf16 in
-    the framework's mixed-precision mode); accumulation and softmax
-    statistics are always f32.  Blocks must divide T (same contract as
-    ``local_attention_blocked``).  Differentiable via the fused
-    recompute backward — no (T, T) tensor ever reaches HBM in either
-    direction.
+
+def flash_attention_rows(arrays, n_heads: int, causal: bool = False,
+                         block_q: int | None = None,
+                         block_k: int | None = None,
+                         dot_dtype=None, interpret: bool = False,
+                         mesh=None, spec=None, q_offset=None,
+                         k_offset=None, sub_tile=None):
+    """Fused flash attention in the projections' own layout:
+    ``arrays`` is ``(qkv,)`` — ONE (B, T, 3·D) projection result whose
+    column ranges are q, k, v — or ``(q, k, v)``, each (B, T, D); the
+    result is (B, T, D) in their dtype, ready for the out-projection as
+    (B·T, D).  Heads are column blocks (module docstring, "Layout
+    contract"): nothing is transposed, sliced or concatenated on the
+    way in or out, forward or backward; a fused array's cotangent is
+    ONE (B, T, 3·D) array.
+
+    ``dot_dtype`` casts the operands (the tile-GEMM operand dtype —
+    bf16 in the framework's mixed-precision mode); accumulation and
+    softmax statistics are always f32.  Blocks must divide T (same
+    contract as ``local_attention_blocked``).  Differentiable via the
+    fused recompute backward — no (T, T) tensor ever reaches HBM in
+    either direction.
 
     ``q_offset``/``k_offset`` place this call on the GLOBAL sequence
     axis for causal masking (the ring-hop geometry; may be traced
@@ -758,37 +948,28 @@ def flash_attention(q, k, v, causal: bool = False,
     ``sub_tile`` the compute sub-tile inside it; left out, both come
     from the shapes (:func:`grid_blocks`, :func:`sub_tile_for`) —
     ``sub_tile`` is there for the tests, which put all three classes of
-    sub-tile into tiles small enough to interpret.  ``head_pack=2``
-    folds head pairs into 128-lane tiles
-    (see the module docstring) — exact per-head math, resolved by the
-    unit gate via :func:`resolve_head_pack`.
+    sub-tile into tiles small enough to interpret.
 
-    ``mesh``/``spec`` is the mesh-native path: ``spec`` is a boundary-
-    layout (B, T, H, D) PartitionSpec (derive it with
+    ``mesh``/``spec`` is the mesh-native path: ``spec`` is a
+    (B, T, H, D) PartitionSpec (derive it with
     :func:`znicz_tpu.parallel.mesh.kernel_shard_spec`) and the kernel
     runs per-shard under ``shard_map`` — without it an opaque
     ``pallas_call`` has no GSPMD sharding rule, so a multi-device mesh
     would replicate-and-gather the operands onto every device.  Only
-    batch-like dims may shard (batch over ``data``; heads compose with
-    TP the same way); sharding T is the ring's job and is rejected
-    here, as is sharding the head dim.  Gradients flow through the
-    shard_map (the custom_vjp backward runs per-shard — attention is
-    independent per batch element and head, so no cross-shard
-    reduction exists).
+    batch-like dims may shard (batch over ``data``; the heads of
+    separate q, k, v compose with TP the same way, as their column
+    dim); sharding T is the ring's job and is rejected here, as is
+    sharding the head dim.  Gradients flow through the shard_map (the
+    custom_vjp backward runs per-shard — attention is independent per
+    batch element and head, so no cross-shard reduction exists).
     """
-    b, t, h, d = q.shape
-    tk = k.shape[1]
-    pack = int(head_pack) if head_pack else 1
-    if pack > 1 and h % pack:
-        raise ValueError(f"head_pack {pack} does not divide "
-                         f"{h} heads")
+    t, tk = arrays[0].shape[1], arrays[-1].shape[1]
     bq, bk = grid_blocks(causal, t, tk, block_q, block_k)
     if t % bq or tk % bk:
         raise ValueError(f"T {t}/{tk} not divisible by blocks "
                          f"({bq}, {bk})")
     if dot_dtype is not None:
-        q, k, v = (a.astype(dot_dtype) for a in (q, k, v))
-    qh, kh, vh = (pack_heads(a, pack) for a in (q, k, v))
+        arrays = tuple(a.astype(dot_dtype) for a in arrays)
     if mesh is not None and spec is not None \
             and any(a is not None for a in spec):
         if spec[1] is not None or spec[3] is not None:
@@ -796,23 +977,38 @@ def flash_attention(q, k, v, causal: bool = False,
                 f"flash_attention shard spec {spec} shards T or the "
                 f"head dim — only batch-like dims (batch, heads) may "
                 f"shard; time sharding rides the ring path")
+        if spec[2] is not None and len(arrays) == 1:
+            raise ValueError(
+                f"flash_attention shard spec {spec} shards the heads "
+                f"of a fused projection, whose columns are q, k and v "
+                f"in turn; pass q, k, v apart")
         if q_offset is not None or k_offset is not None:
             raise ValueError(
                 "global offsets ride the ring path (per-shard hops), "
                 "not the batch-sharded shard_map path")
         from jax.sharding import PartitionSpec as P
-        hspec = P(spec[0], spec[2], None, None)  # boundary → head-major
+        rspec = P(spec[0], None, spec[2])   # (B, T, H, D) → (B, T, H·D)
+        shards = 1 if spec[2] is None else mesh.shape[spec[2]]
         # check_vma off: an opaque pallas_call (and the custom_vjp
         # around it) has no replication rule for the checker
         fn = jax.shard_map(
-            lambda a, b_, c: _flash_hop(
-                a, b_, c, _off_arr(None), _off_arr(None), causal, bq,
-                bk, interpret, pack, sub_tile)[0],
-            mesh=mesh, in_specs=(hspec, hspec, hspec), out_specs=hspec,
-            check_vma=False)
-        out = fn(qh, kh, vh)
-    else:
-        out = _flash_hop(qh, kh, vh, _off_arr(q_offset),
-                         _off_arr(k_offset), causal, bq, bk,
-                         interpret, pack, sub_tile)[0]
-    return unpack_heads(out, pack, h).astype(jnp.float32)
+            lambda *shard: _rows_pass(
+                shard, n_heads // shards, _off_arr(None),
+                _off_arr(None), causal, bq, bk, interpret, sub_tile),
+            mesh=mesh, in_specs=(rspec,) * len(arrays),
+            out_specs=rspec, check_vma=False)
+        return fn(*arrays)
+    return _rows_pass(arrays, n_heads, _off_arr(q_offset),
+                      _off_arr(k_offset), causal, bq, bk, interpret,
+                      sub_tile)
+
+
+def flash_attention(q, k, v, **kwargs):
+    """:func:`flash_attention_rows` over (B, T, H, D) tensors →
+    (B, T, H, D) f32: at the boundary the heads are adjacent to D, so
+    both reshapes are free."""
+    b, t, h, d = q.shape
+    out = flash_attention_rows(
+        tuple(a.reshape(a.shape[0], a.shape[1], h * d)
+              for a in (q, k, v)), h, **kwargs)
+    return out.reshape(b, t, h, d).astype(jnp.float32)

@@ -173,8 +173,7 @@ def _fold_block(carry, q, k_blk, v_blk, s_mask, dot_dtype=None):
 
 def _ring_kernel_fold(q, k, v, offs, axis_name: str, causal: bool,
                       dot_dtype, block_q: int | None,
-                      block_k: int | None, interpret: bool,
-                      head_pack: int):
+                      block_k: int | None, interpret: bool):
     """The round-6 ring fold: each hop IS one fused flash-kernel pass
     (:func:`znicz_tpu.ops.pallas_attention.ring_hop`) over the
     arriving K/V shard at its GLOBAL offset, and the hops compose
@@ -189,9 +188,12 @@ def _ring_kernel_fold(q, k, v, offs, axis_name: str, causal: bool,
     kernel's offset-aware ``pl.when`` (they contribute lse ≈ −1e30 and
     weight 0 here).
 
-    Operands stay head-major (and head-packed) around the whole ring —
+    Operands stay head-major (and head-packed: pairs of heads at
+    dh 64, ``pallas_attention.head_pack_for``) around the whole ring —
     K/V rotate in kernel layout, so the per-hop cost is exactly one
-    kernel dispatch, no re-transposes.
+    kernel dispatch, no re-transposes.  The one-chip path addresses
+    the projections' layout instead (PR 28); the ring keeps this one
+    until a four-chip cell can measure the move.
 
     ``offs`` is this device's (1, 1) int32 global row offset, handed
     in as a SEQUENCE-SHARDED OPERAND (not ``axis_index``), and the
@@ -209,7 +211,7 @@ def _ring_kernel_fold(q, k, v, offs, axis_name: str, causal: bool,
     tk = k.shape[1]
     if dot_dtype is not None:
         q, k, v = (a.astype(dot_dtype) for a in (q, k, v))
-    pack = head_pack or 1
+    pack = pa.head_pack_for(h, dh)
     qh, kh, vh = (pa.pack_heads(a, pack) for a in (q, k, v))
     bq = min(block_q or pa.BLOCK_Q, tq)
     bk = min(block_k or pa.BLOCK_K, tk)
@@ -257,17 +259,15 @@ def ring_attention_block(q, k, v, seq_offsets=None,
                          block_k: int | None = None,
                          pallas_fold: bool = False,
                          pallas_interpret: bool = False,
-                         pallas_block_q: int | None = None,
-                         head_pack: int = 1):
+                         pallas_block_q: int | None = None):
     """The per-device body (call under ``shard_map``): q/k/v are THIS
     device's sequence shards; K/V rotate the full ring.
 
     ``pallas_fold`` makes each hop a fused flash-kernel pass (the
     round-6 production TPU path — see :func:`_ring_kernel_fold`);
     ``pallas_interpret`` runs those kernels in interpret mode (the
-    virtual-CPU-mesh testing lever), ``pallas_block_q`` overrides the
-    kernel's q tile and ``head_pack`` is the lane-packing factor
-    resolved by the unit gate.  Legality (tiling/dh) is the CALLER's
+    virtual-CPU-mesh testing lever) and ``pallas_block_q`` overrides
+    the kernel's q tile.  Legality (tiling/dh) is the CALLER's
     job — :func:`sequence_sharded_attention` gates on the per-shard
     shapes and falls back to the scan fold.
 
@@ -292,7 +292,7 @@ def ring_attention_block(q, k, v, seq_offsets=None,
                              "sequence_sharded_attention)")
         return _ring_kernel_fold(q, k, v, seq_offsets, axis_name,
                                  causal, dot_dtype, pallas_block_q,
-                                 block_k, pallas_interpret, head_pack)
+                                 block_k, pallas_interpret)
     axis_size = jax.lax.psum(1, axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     b, tq, h, dim = q.shape
@@ -365,8 +365,7 @@ def ring_attention_block(q, k, v, seq_offsets=None,
 def ring_fold_choice(mesh, shape, axis_name: str = SEQ_AXIS,
                      block_k: int | None = None,
                      pallas_fold: bool = False,
-                     pallas_block_q: int | None = None,
-                     head_pack: int = 1):
+                     pallas_block_q: int | None = None):
     """Resolve which fold the ring will actually run for a GLOBAL
     (B, T, H, Dh) shape: ``("pallas", bq, bk)`` when the kernel fold
     is requested AND the per-shard geometry is kernel-legal, else
@@ -383,9 +382,8 @@ def ring_fold_choice(mesh, shape, axis_name: str = SEQ_AXIS,
     _, t_local, h, dh = shard_shape(mesh, shape, spec)
     bq = min(pallas_block_q or pa.BLOCK_Q, t_local)
     bk = min(block_k or pa.BLOCK_K, t_local)
-    pack = head_pack or 1
-    if h % pack or not pa.kernel_legal(t_local, t_local, dh * pack,
-                                       bq, bk):
+    pack = pa.head_pack_for(h, dh)
+    if not pa.kernel_legal(t_local, t_local, dh * pack, bq, bk):
         return "scan", None, block_k
     return "pallas", bq, bk
 
@@ -396,8 +394,7 @@ def sequence_sharded_attention(mesh, q, k, v, causal: bool = False,
                                block_k: int | None = None,
                                pallas_fold: bool = False,
                                pallas_interpret: bool = False,
-                               pallas_block_q: int | None = None,
-                               head_pack: int = 1):
+                               pallas_block_q: int | None = None):
     """Shard the time axis of q/k/v over ``mesh[axis_name]`` and run
     ring attention; returns output with the same sharding as q.
 
@@ -421,16 +418,13 @@ def sequence_sharded_attention(mesh, q, k, v, causal: bool = False,
                                 model_axis=axis_name)
     fold, bq, bk = ring_fold_choice(
         mesh, q.shape, axis_name=axis_name, block_k=block_k,
-        pallas_fold=pallas_fold, pallas_block_q=pallas_block_q,
-        head_pack=head_pack)
+        pallas_fold=pallas_fold, pallas_block_q=pallas_block_q)
     body = functools.partial(ring_attention_block,
                              axis_name=axis_name, causal=causal,
                              dot_dtype=dot_dtype, block_k=bk,
                              pallas_fold=(fold == "pallas"),
                              pallas_interpret=pallas_interpret,
-                             pallas_block_q=bq,
-                             head_pack=head_pack if fold == "pallas"
-                             else 1)
+                             pallas_block_q=bq)
     if fold == "pallas":
         from jax.sharding import PartitionSpec as P
 
