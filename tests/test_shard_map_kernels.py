@@ -329,8 +329,9 @@ def test_head_pack_gate(monkeypatch, caplog):
     assert unit._flash_layout == ("boundary", 2)
     assert "layout=boundary, head pack 2" in caplog.text
     assert obs_metrics.flash_layout(unit.name, "boundary", 2).value == 1
-    assert ('znicz_flash_layout{unit="%s",layout="boundary",pack="2"} 1'
-            % unit.name) in obs_metrics.REGISTRY.to_prometheus()
+    assert ('znicz_flash_layout{unit="%s",layout="boundary",pack="2",'
+            'kv_group="1"} 1' % unit.name) \
+        in obs_metrics.REGISTRY.to_prometheus()
     unit = _attention_unit(XLADevice(), d=256, heads=2)  # dh = 128
     assert unit._flash_layout == ("boundary", 1)
     # an odd head count keeps one head per program, never raises

@@ -210,7 +210,8 @@ def attach_decode_meta(path: str, *, page_tokens: int | None = None,
 
 
 #: the attention options of the pre-norm residual block (ROADMAP R0)
-_BLOCK_OPTIONS = ("pre_norm", "qk_norm", "rope_theta", "residual")
+_BLOCK_OPTIONS = ("pre_norm", "qk_norm", "rope_theta", "residual",
+                  "head_dim", "window", "head_gate")
 
 
 def refuse_unserved(forwards, what: str) -> None:
@@ -221,6 +222,11 @@ def refuse_unserved(forwards, what: str) -> None:
     serve another model than was trained (ROADMAP R1, serving half)."""
     for i, unit in enumerate(forwards):
         kind = type(unit).__name__
+        if kind == "GatedMLP":
+            raise NotImplementedError(
+                f"{what}: layer {i} is a gated MLP block (gated_mlp); "
+                f"serving runs no feed-forward sublayer yet (ROADMAP R1, "
+                f"serving half)")
         if kind == "MoE":
             raise NotImplementedError(
                 f"{what}: layer {i} is a sparse-expert layer (moe); "
@@ -231,6 +237,8 @@ def refuse_unserved(forwards, what: str) -> None:
             continue
         used = [name for name in _BLOCK_OPTIONS
                 if getattr(unit, name, None)]
+        if getattr(unit, "n_kv_heads", unit.n_heads) != unit.n_heads:
+            used.append("n_kv_heads")
         if used:
             raise NotImplementedError(
                 f"{what}: attention layer {i} sets "

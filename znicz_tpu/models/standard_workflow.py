@@ -104,6 +104,7 @@ for _name, _cls in {
     "embedding": embedding.Embedding,
     "rms_norm": rms_norm.RMSNorm,
     "moe": moe.MoE,
+    "gated_mlp": moe.GatedMLP,
 }.items():
     register_layer_type(_name, _cls)
 

@@ -546,32 +546,52 @@ def flash_tiles(unit: str, cls: str) -> Gauge:
     """How an attention unit's flash kernels split the T × T score
     square into compute sub-tiles (``class`` = ``interior``: computed
     without a mask, ``crossing``: computed under the causal mask,
-    ``skipped``: above the diagonal, never visited).  Static per
-    program, set once at ``initialize``: (interior + crossing) ÷ the
-    sum is the share of T × T this model's attention computes."""
+    ``skipped``: above the diagonal — or, for a windowed layer, below
+    its band — never visited; ``band_edge``, windowed layers only:
+    computed under the mask because the band's lower edge passes
+    through).  Static per program, set once at ``initialize``:
+    1 − skipped ÷ the sum is the share of T × T this model's
+    attention computes."""
     return REGISTRY.gauge(
         "znicz_flash_tiles",
         "Flash-attention compute sub-tiles of the T x T square, by "
-        "class (interior, crossing, skipped)",
+        "class (interior, crossing, band_edge, skipped)",
         labels=("unit", "class")).labels(**{"unit": unit, "class": cls})
 
 
-def flash_layout(unit: str, layout: str, pack: int) -> Gauge:
+def flash_band(unit: str, stat: str) -> Gauge:
+    """A windowed attention unit's band (``stat`` = ``window``: the
+    positions a row sees; ``band_share``: Σ_r min(r + 1, window) ÷ T²,
+    what the mask leaves of the square; ``executed_share``: sub-tiles
+    the kernels run ÷ all — its excess over ``band_share`` is what the
+    tiling computes and masks away).  Static per program, set once at
+    ``initialize``; a layer without a window has no series."""
+    return REGISTRY.gauge(
+        "znicz_flash_band",
+        "Window of a flash-attention layer, the share of T x T inside "
+        "its band and the share its tiles execute",
+        labels=("unit", "stat")).labels(unit=unit, stat=stat)
+
+
+def flash_layout(unit: str, layout: str, pack: int,
+                 kv_group: int = 1) -> Gauge:
     """Where an attention unit's flash kernels find a head's tiles:
     ``layout`` = ``boundary`` (column blocks of the projections' own
     (B, T, ·) arrays, addressed in place: no transpose, slice or
     concatenate around a kernel) or ``head_major`` (a head width with
     no lane-legal column block: the tiles are moved there first);
     ``pack`` = heads per kernel program (2: pairs of dh-64 heads fill
-    the 128 lanes).  Static per program, 1 for the combination in
-    force, set once at ``initialize``."""
+    the 128 lanes); ``kv_group`` = query heads that read one K/V head
+    (1: multi-head attention).  Static per program, 1 for the
+    combination in force, set once at ``initialize``."""
     return REGISTRY.gauge(
         "znicz_flash_layout",
         "Address of the flash-attention kernels' tiles (boundary, "
-        "head_major) and heads per kernel program; 1 for the "
-        "combination in force",
-        labels=("unit", "layout", "pack")).labels(
-            unit=unit, layout=layout, pack=str(pack))
+        "head_major), heads per kernel program and query heads per K/V "
+        "head; 1 for the combination in force",
+        labels=("unit", "layout", "pack", "kv_group")).labels(
+            unit=unit, layout=layout, pack=str(pack),
+            kv_group=str(kv_group))
 
 
 def moe_expert_tokens(unit: str, stat: str) -> Gauge:
@@ -585,6 +605,23 @@ def moe_expert_tokens(unit: str, stat: str) -> Gauge:
         "znicz_moe_expert_tokens",
         "Rows per expert and step of a dropless MoE layer over the "
         "last epoch (max, mean, min)",
+        labels=("unit", "stat")).labels(unit=unit, stat=stat)
+
+
+def moe_held(unit: str, stat: str) -> Gauge:
+    """One chip's share of an expert-parallel ``MoE`` layer, per step
+    over the last epoch (``stat`` = ``held``: experts whose weights
+    live here; ``of``: experts the router chooses among; ``rows_here``:
+    (token, expert) pairs routed to the held experts, which this chip
+    computes; ``rows_routed``: all N·k pairs; ``capacity``: rows the
+    step's buffers hold; ``rows_over``: pairs beyond it, which poison
+    the step so that the guard refuses it).  A layer that holds every
+    expert has no series.  Fed from totals the unit keeps on the
+    device, read once per epoch."""
+    return REGISTRY.gauge(
+        "znicz_moe_held",
+        "Experts held on this chip, experts routed over, and rows "
+        "routed here and in all per step, of a MoE layer",
         labels=("unit", "stat")).labels(unit=unit, stat=stat)
 
 

@@ -33,6 +33,23 @@ position over the full head in the half-split ("rotate_half")
 convention.  Norms and rotation run in f32 between the QKV projection
 and the attention core; the kernels are untouched.
 
+Grouped queries, a window and a per-head gate (Laguna-S-2.1, PR 29)
+are options of the same kind, per unit, so that one model mixes layers
+of different head counts, windows and rotary rules: ``n_kv_heads`` (K
+and V have fewer heads than q; query head h reads K/V head
+h // (n_heads / n_kv_heads)), ``head_dim`` (the head size where
+``n_heads · head_dim`` is not the model's width: the fused projection
+is (D, (H + 2·H_kv)·dh) and the out-projection (H·dh, D)), ``window``
+(causal only: position r attends to (r − window, r]), ``head_gate``
+(``o_h`` is multiplied by ``σ(n · W_g)_h``, W_g (D, H), n the
+sublayer's normed input — the head-wise sigmoid output gate of
+arXiv:2505.06708) and, inside ``rope``, ``rotary_dim`` (only the first
+``rotary_dim`` of a head rotate) and ``yarn`` (arXiv:2309.00071: blended
+inverse frequencies, cos and sin scaled by ``attention_factor``).  The
+flash kernels take the group and the window (``pallas_attention``); a
+shape they cannot tile takes the plain core WITH the band mask; the
+ring and the scan-blocked core refuse them at ``initialize``.
+
 Backward (``GDMultiHeadAttention``): ``jax.vjp`` of the forward on
 the XLA path — this differentiates THROUGH the shard_map/ppermute
 ring, so sequence-parallel training needs no hand-written collective
@@ -63,13 +80,53 @@ def _split_heads(qkv, n_heads: int):
     return q.reshape(reshape), k.reshape(reshape), v.reshape(reshape)
 
 
-def rope_tables(xp, t: int, dh: int, theta: float):
-    """(T, dh/2) cosines and sines of ``pos · theta^(−2i/dh)``, f32."""
-    inv_freq = 1.0 / np.power(
-        float(theta), np.arange(0, dh, 2, dtype=np.float64) / dh)
+def yarn_inv_freq(dim: int, theta: float, yarn: dict) -> np.ndarray:
+    """YaRN's (arXiv:2309.00071) ``dim/2`` inverse frequencies, float64,
+    as ``transformers``' ``_compute_yarn_parameters``: the plain
+    ``theta^(−2i/dim)`` and the same ÷ ``factor``, blended over the
+    linear ramp between the two correction dims (where a frequency
+    turns ``beta_fast`` and ``beta_slow`` times over the original
+    context)."""
+    factor = float(yarn["factor"])
+    original = float(yarn["original_max_position_embeddings"])
+
+    def correction_dim(rotations: float) -> float:
+        return dim * np.log(original / (rotations * 2 * np.pi)) \
+            / (2 * np.log(theta))
+
+    low = max(np.floor(correction_dim(float(yarn.get("beta_fast", 32)))),
+              0)
+    high = min(np.ceil(correction_dim(float(yarn.get("beta_slow", 1)))),
+               dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    plain = 1.0 / np.power(float(theta),
+                           np.arange(0, dim, 2, dtype=np.float64) / dim)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def yarn_attention_factor(yarn: dict) -> float:
+    given = yarn.get("attention_factor")
+    return float(given) if given is not None \
+        else 0.1 * float(np.log(float(yarn["factor"]))) + 1.0
+
+
+def rope_tables(xp, t: int, dh: int, theta: float, yarn=None):
+    """(T, dh/2) cosines and sines of ``pos · theta^(−2i/dh)``, f32;
+    with ``yarn`` the blended frequencies, both tables scaled by its
+    ``attention_factor``.  ``dh`` is the ROTATED width."""
+    if yarn:
+        inv_freq = yarn_inv_freq(dh, float(theta), yarn)
+        scale = yarn_attention_factor(yarn)
+    else:
+        inv_freq = 1.0 / np.power(
+            float(theta), np.arange(0, dh, 2, dtype=np.float64) / dh)
+        scale = 1.0
     angle = np.arange(t, dtype=np.float64)[:, None] * inv_freq[None, :]
-    return (xp.asarray(np.cos(angle), dtype=xp.float32),
-            xp.asarray(np.sin(angle), dtype=xp.float32))
+    return (xp.asarray(np.cos(angle) * scale, dtype=xp.float32),
+            xp.asarray(np.sin(angle) * scale, dtype=xp.float32))
 
 
 def apply_rope(xp, x, cos, sin, inverse: bool = False):
@@ -83,12 +140,16 @@ def apply_rope(xp, x, cos, sin, inverse: bool = False):
 
 def _rotate(xp, x, c, s, inverse: bool = False):
     """The half-split rotation of (…, dh) by tables that broadcast
-    against (…, dh/2)."""
-    half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
+    against (…, rot/2): the first ``rot`` of a head rotate (its two
+    halves being x₁, x₂), the rest pass (``rot`` = dh: the whole
+    head)."""
+    half = c.shape[-1]
+    x1, x2 = x[..., :half], x[..., half:2 * half]
     if inverse:
         s = -s
-    return xp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+    return xp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s]
+                          + ([x[..., 2 * half:]]
+                             if 2 * half < x.shape[-1] else []), axis=-1)
 
 
 def apply_rope_rows(xp, x, cos, sin, n_heads: int):
@@ -105,19 +166,29 @@ def apply_rope_rows(xp, x, cos, sin, n_heads: int):
     sub = 8 if t % 8 == 0 else 1
     tiles = x.reshape(b, t // sub, sub, n_heads, dh) \
         .transpose(0, 1, 3, 2, 4)
-    table = (t // sub, 1, sub, dh // 2)
+    table = (t // sub, 1, sub, cos.shape[-1])
     out = _rotate(xp, tiles, cos.reshape(table), sin.reshape(table))
     return out.transpose(0, 1, 3, 2, 4).reshape(b, t, d)
 
 
-def _local_attention_np(q, k, v, causal: bool):
+def band_mask(xp, tq: int, tk: int, window=None):
+    """(tq, tk) causal visibility; with a ``window`` row r also stops
+    seeing columns ≤ r − window."""
+    rows, cols = xp.arange(tq)[:, None], xp.arange(tk)[None, :]
+    mask = rows >= cols
+    return mask if window is None else mask & (cols > rows - window)
+
+
+def _local_attention_np(q, k, v, causal: bool, window=None):
     """Numpy oracle core (mirrors parallel.ring_attention's
-    local_attention)."""
+    local_attention); K/V heads are repeated over their group."""
     d = q.shape[-1]
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = np.repeat(k, group, axis=2), np.repeat(v, group, axis=2)
     s = np.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
     if causal:
-        tq, tk = q.shape[1], k.shape[1]
-        mask = np.arange(tq)[:, None] >= np.arange(tk)[None, :]
+        mask = band_mask(np, q.shape[1], k.shape[1], window)
         s = np.where(mask[None, None], s, -1e30)
     s = s - s.max(axis=-1, keepdims=True)
     p = np.exp(s)
@@ -130,7 +201,7 @@ class MultiHeadAttention(Forward):
     """Weighted multi-head self-attention layer."""
 
     EXPORT_PARAMS = ("weights", "bias", "weights_out", "bias_out",
-                     "gain_norm", "gain_q", "gain_k")
+                     "gain_norm", "gain_q", "gain_k", "weights_head_gate")
 
     def __init__(self, workflow, n_heads: int, causal: bool = False,
                  seq_parallel: bool = False,
@@ -138,6 +209,9 @@ class MultiHeadAttention(Forward):
                  pre_norm: str | None = None, residual: bool = False,
                  qk_norm: str | None = None, rope: dict | None = None,
                  norm_eps: float = 1e-5,
+                 n_kv_heads: int | None = None,
+                 head_dim: int | None = None,
+                 window: int | None = None, head_gate: bool = False,
                  name=None, **kwargs) -> None:
         # attention defaults to fan-scaled init (the reference's
         # fixed-stddev fillings predate attention entirely)
@@ -170,7 +244,29 @@ class MultiHeadAttention(Forward):
         self.residual = bool(residual)
         self.qk_norm = qk_norm
         self.rope_theta = None if rope is None else float(rope["theta"])
+        #: how many dims of a head rotate (None: all) and the YaRN
+        #: scaling of the frequencies (None: none)
+        self.rotary_dim = None if not rope or not rope.get("rotary_dim") \
+            else int(rope["rotary_dim"])
+        self.rope_yarn = dict(rope["yarn"]) \
+            if rope and rope.get("yarn") else None
         self.norm_eps = float(norm_eps)
+        #: grouped queries, a head size of its own, the window and the
+        #: per-head gate (module docstring); all unset = the layer as
+        #: it was
+        self.n_kv_heads = self.n_heads if n_kv_heads is None \
+            else int(n_kv_heads)
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(f"{n_heads} query heads do not divide over "
+                             f"{n_kv_heads} K/V heads")
+        self.head_dim = None if head_dim is None else int(head_dim)
+        self.window = None if window is None else int(window)
+        if self.window is not None and (self.window < 1 or not causal):
+            raise ValueError(f"window {window} needs causal=True and "
+                             f"≥ 1 position")
+        self.head_gate = bool(head_gate)
+        self.weights_head_gate = Vector(
+            name=f"{self.name}.weights_head_gate")
         self.gain_norm = Vector(name=f"{self.name}.gain_norm")
         self.gain_q = Vector(name=f"{self.name}.gain_q")
         self.gain_k = Vector(name=f"{self.name}.gain_k")
@@ -188,34 +284,52 @@ class MultiHeadAttention(Forward):
             raise ValueError(f"{self}: expected (batch, time, features) "
                              f"input, got {self.input.shape}")
         b, t, d = self.input.shape
-        if d % self.n_heads:
+        if self.head_dim is None and d % self.n_heads:
             raise ValueError(f"{self}: features {d} not divisible by "
                              f"{self.n_heads} heads")
+        #: widths of q (= the core's output) and of k, v
+        q_width, kv_width, dh = self._widths(d)
+        wide = q_width + 2 * kv_width
         if self.flash_block_k and t % self.flash_block_k:
             raise ValueError(
                 f"{self}: time axis {t} not divisible by "
                 f"flash_block_k {self.flash_block_k}")
         if not self.weights:
             self.weights.reset(self.fill_array(
-                (d, 3 * d), self.weights_filling,
+                (d, wide), self.weights_filling,
                 self.weights_stddev, fan_in=d))
         if not self.weights_out:
             self.weights_out.reset(self.fill_array(
-                (d, d), self.weights_filling,
+                (q_width, d), self.weights_filling,
+                self.weights_stddev, fan_in=q_width))
+        if self.head_gate and not self.weights_head_gate:
+            self.weights_head_gate.reset(self.fill_array(
+                (d, self.n_heads), self.weights_filling,
                 self.weights_stddev, fan_in=d))
         if self.include_bias:
             if not self.bias:
-                self.bias.reset(np.zeros(3 * d, np.float32))
+                self.bias.reset(np.zeros(wide, np.float32))
             if not self.bias_out:
                 self.bias_out.reset(np.zeros(d, np.float32))
-        gains = ([self.gain_norm] if self.pre_norm else []) \
-            + ([self.gain_q, self.gain_k] if self.qk_norm else [])
-        for gain in gains:
-            if not gain:
-                gain.reset(np.ones(d, np.float32))
-        if self.rope_theta is not None and (d // self.n_heads) % 2:
-            raise ValueError(f"{self}: rope needs an even head size, "
-                             f"got {d // self.n_heads}")
+        for gain, width in ((self.gain_norm, d if self.pre_norm else 0),
+                            (self.gain_q,
+                             q_width if self.qk_norm else 0),
+                            (self.gain_k,
+                             kv_width if self.qk_norm else 0)):
+            if width and not gain:
+                gain.reset(np.ones(width, np.float32))
+        rotated = self.rotary_dim or dh
+        if self.rope_theta is not None and (rotated % 2 or rotated > dh):
+            raise ValueError(f"{self}: rope needs an even rotated width "
+                             f"within the head, got {rotated} of {dh}")
+        #: the options the ring and the scan-blocked core do not know
+        grouped = (self.n_kv_heads != self.n_heads
+                   or self.window is not None)
+        if grouped and (self.seq_parallel or self.flash_block_k):
+            raise ValueError(
+                f"{self}: grouped queries and a window run on the flash "
+                f"kernels or the plain core, not on the ring or the "
+                f"scan-blocked core (seq_parallel, flash_block_k)")
         self.output.reset(np.zeros((b, t, d),
                                    dtype=self.output_store_dtype))
         from jax.sharding import PartitionSpec as P
@@ -269,7 +383,6 @@ class MultiHeadAttention(Forward):
         # kernels (shard_map oracle tests / dryruns); never default
         interpret = bool(root.common.engine.get("pallas_interpret",
                                                 False))
-        dh = d // self.n_heads
         tpu_capable = (pallas_kernels.is_tpu_device(self.device)
                        or interpret)
         # where a head's tiles lie and how many heads share a kernel
@@ -280,8 +393,13 @@ class MultiHeadAttention(Forward):
         # the pair body's opt-in: measured on the LM cell, −1.4 ms of
         # kernels per step with the copies still there, and gone with
         # them; PERF.md §6, PR 28)
+        group = self.n_heads // self.n_kv_heads
         layout, head_pack = pallas_attention.head_layout(self.n_heads,
-                                                         dh)
+                                                         dh, group)
+        # a window that covers the sequence is the causal call
+        window = self.window if self.window is not None \
+            and self.window < t else None
+        self._flash_window = window
         #: which fold the ring runs ("pallas"/"scan"; None = no ring)
         #: — the multichip dryrun attests this
         self._ring_fold = None
@@ -310,7 +428,8 @@ class MultiHeadAttention(Forward):
         # (``engine.flash_causal_block`` shrank the GRID tile instead:
         # 1.39 × slower at 512, 2.8 × at 256 on the chip, and is gone).
         bq, bk = pallas_attention.grid_blocks(
-            self.causal, t, t, None, self.flash_block_k)
+            self.causal, t, t, None, self.flash_block_k) \
+            if window is None else pallas_attention.band_blocks(t)
         self._flash_block_q, self._flash_block_k = bq, bk
         self._flash_interpret = interpret
         self._flash_mesh = None
@@ -358,15 +477,25 @@ class MultiHeadAttention(Forward):
         self._flash_layout = None
         if self._flash_pallas:
             self._flash_layout = (layout, head_pack)
-            sq, sk = pallas_attention.sub_tile_for(self.causal, bq, bk)
+            sq, sk = pallas_attention.sub_tile_for(self.causal, bq, bk) \
+                if window is None else (bq, bk)
             self._flash_sub_tile = (sq, sk)
             self._flash_tiles = pallas_attention.causal_tile_counts(
-                t, t, bq, bk, sq, sk, causal=self.causal)
+                t, t, bq, bk, sq, sk, causal=self.causal, window=window)
             from znicz_tpu.observe import metrics as obs_metrics
-            for cls in ("interior", "crossing", "skipped"):
-                obs_metrics.flash_tiles(self.name, cls).set(
-                    self._flash_tiles[cls])
-            obs_metrics.flash_layout(self.name, layout, head_pack).set(1)
+            for cls in ("interior", "crossing", "skipped", "band_edge"):
+                if cls in self._flash_tiles:
+                    obs_metrics.flash_tiles(self.name, cls).set(
+                        self._flash_tiles[cls])
+            obs_metrics.flash_layout(self.name, layout, head_pack,
+                                     group).set(1)
+            if window is not None:
+                share = pallas_attention.band_share(t, window)
+                for stat, value in (
+                        ("window", window), ("band_share", share),
+                        ("executed_share",
+                         self._flash_tiles["executed_share"])):
+                    obs_metrics.flash_band(self.name, stat).set(value)
         if self._ring_active:
             self.info("%s: ring attention over '%s', %s fold",
                       self.name, self._ring_axis, self._ring_fold)
@@ -375,12 +504,19 @@ class MultiHeadAttention(Forward):
             self.info("%s: flash kernel, blocks (%d, %d), sub-tiles "
                       "(%d, %d): %d interior + %d crossing of %d = "
                       "%.4f of T×T executed, layout=%s, head pack %d"
-                      "%s%s",
+                      "%s%s%s",
                       self.name, bq, bk, *self._flash_sub_tile,
-                      tiles["interior"], tiles["crossing"],
-                      tiles["interior"] + tiles["crossing"]
-                      + tiles["skipped"], tiles["executed_share"],
+                      tiles["interior"], tiles["crossing"]
+                      + tiles.get("band_edge", 0),
+                      sum(n for cls, n in tiles.items()
+                          if cls != "executed_share"),
+                      tiles["executed_share"],
                       layout, head_pack,
+                      "" if group == 1 and window is None else
+                      ", %d query heads to a K/V head, window %s (band "
+                      "%.4f of T×T)" % (
+                          group, window,
+                          pallas_attention.band_share(t, window)),
                       ", per shard under shard_map"
                       if self._flash_mesh is not None else "",
                       ", INTERPRETED" if interpret else "")
@@ -389,7 +525,8 @@ class MultiHeadAttention(Forward):
                       refused)
         self.init_vectors(self.input, self.output, self.weights,
                           self.bias, self.weights_out, self.bias_out,
-                          self.gain_norm, self.gain_q, self.gain_k)
+                          self.gain_norm, self.gain_q, self.gain_k,
+                          self.weights_head_gate)
 
     @property
     def ring_active(self) -> bool:
@@ -407,34 +544,49 @@ class MultiHeadAttention(Forward):
                 self.bias.devmem if self.include_bias else None,
                 self.weights_out.devmem,
                 self.bias_out.devmem if self.include_bias else None,
-                dev(self.gain_norm), dev(self.gain_q), dev(self.gain_k))
+                dev(self.gain_norm), dev(self.gain_q), dev(self.gain_k)) \
+            + ((self.weights_head_gate.devmem,) if self.head_gate else ())
+
+    def _widths(self, d: int) -> tuple:
+        """(q width, k/v width, head size) for a model width ``d``."""
+        dh = self.head_dim or d // self.n_heads
+        return self.n_heads * dh, self.n_kv_heads * dh, dh
+
+    def _rope_tables(self, xp, t: int, dh: int):
+        return rope_tables(xp, t, self.rotary_dim or dh, self.rope_theta,
+                           self.rope_yarn)
 
     def _normed_rotated(self, xp, qkv, g_q, g_k, rows: bool = False):
-        """(B, T, 3D) f32 projections → q, k, v (B, T, H, dh) with the
-        whole-projection q/k norms and the rotation applied; ``rows``
-        keeps them (B, T, D), where the flash kernels read them."""
-        b, t, d3 = qkv.shape
-        d = d3 // 3
-        q, k, v = qkv[..., :d], qkv[..., d:2 * d], qkv[..., 2 * d:]
+        """(B, T, q + 2·kv widths) f32 projections → q (B, T, H, dh),
+        k, v (B, T, H_kv, dh) with the whole-projection q/k norms and
+        the rotation applied; ``rows`` keeps them (B, T, width), where
+        the flash kernels read them."""
+        b, t, wide = qkv.shape
+        h, h_kv = self.n_heads, self.n_kv_heads
+        dh = wide // (h + 2 * h_kv)
+        qw, kw = h * dh, h_kv * dh
+        q, k, v = qkv[..., :qw], qkv[..., qw:qw + kw], qkv[..., qw + kw:]
         if self.qk_norm:
             q = rms_norm(xp, q, g_q, self.norm_eps)
             k = rms_norm(xp, k, g_k, self.norm_eps)
-        shape = (b, t, self.n_heads, d // self.n_heads)
         if not rows:
-            q, k, v = q.reshape(shape), k.reshape(shape), v.reshape(shape)
+            q = q.reshape(b, t, h, dh)
+            k, v = k.reshape(b, t, h_kv, dh), v.reshape(b, t, h_kv, dh)
         if self.rope_theta is not None:
-            cos, sin = rope_tables(xp, t, shape[-1], self.rope_theta)
+            cos, sin = self._rope_tables(xp, t, dh)
             if rows:
-                q = apply_rope_rows(xp, q, cos, sin, self.n_heads)
-                k = apply_rope_rows(xp, k, cos, sin, self.n_heads)
+                q = apply_rope_rows(xp, q, cos, sin, h)
+                k = apply_rope_rows(xp, k, cos, sin, h_kv)
             else:
                 q = apply_rope(xp, q, cos, sin)
                 k = apply_rope(xp, k, cos, sin)
         return q, k, v
 
     def xla_forward(self, x, w_qkv, b_qkv, w_out, b_out,
-                    g_norm=None, g_q=None, g_k=None):
+                    g_norm=None, g_q=None, g_k=None, w_gate=None):
         b, t, d = x.shape
+        wide = w_qkv.shape[1]
+        grouped = self.n_kv_heads != self.n_heads
         x32 = x.astype(jnp.float32)
         h = x32 if g_norm is None \
             else rms_norm(jnp, x32, g_norm, self.norm_eps)
@@ -452,11 +604,11 @@ class MultiHeadAttention(Forward):
             # q, k, v are column ranges of ONE projection result
             if dot_dtype is not None:
                 qkv = qkv.astype(dot_dtype)
-            arrays = (qkv.reshape(b, t, 3 * d),)
+            arrays = (qkv.reshape(b, t, wide),)
         else:
             # norms and rotation in f32, THEN the cast
             arrays = self._normed_rotated(
-                jnp, qkv.reshape(b, t, 3 * d), g_q, g_k, rows=flash)
+                jnp, qkv.reshape(b, t, wide), g_q, g_k, rows=flash)
             if dot_dtype is not None:
                 arrays = tuple(a.astype(dot_dtype) for a in arrays)
         if flash:
@@ -467,6 +619,13 @@ class MultiHeadAttention(Forward):
             # slice stands between a projection and a kernel, forward
             # or backward (24 copies of 0.6 ms a step in the LM cell
             # before; PERF.md §6, PR 28)
+            # the group and the window only where a layer has them: a
+            # layer without keeps the call (and the program) it had
+            more = {}
+            if grouped:
+                more["n_kv_heads"] = self.n_kv_heads
+            if getattr(self, "_flash_window", None) is not None:
+                more["window"] = self._flash_window
             o = pallas_attention.flash_attention_rows(
                 arrays, self.n_heads, causal=self.causal,
                 block_q=getattr(self, "_flash_block_q", None),
@@ -475,10 +634,10 @@ class MultiHeadAttention(Forward):
                 dot_dtype=dot_dtype,
                 interpret=getattr(self, "_flash_interpret", False),
                 mesh=getattr(self, "_flash_mesh", None),
-                spec=getattr(self, "_flash_spec", None))
-            return self._project_out(x32, o, w_out, b_out)
-        q, k, v = _split_heads(arrays[0], self.n_heads) if fused \
-            else arrays
+                spec=getattr(self, "_flash_spec", None), **more)
+            return self._project_out(x32, h, o, w_out, b_out, w_gate)
+        q, k, v = self._normed_rotated(jnp, arrays[0], None, None) \
+            if fused else arrays      # fused: neither norm nor rotation
         if self.ring_active:
             from znicz_tpu.parallel.ring_attention import \
                 sequence_sharded_attention
@@ -502,17 +661,28 @@ class MultiHeadAttention(Forward):
                                         block_k=self.flash_block_k,
                                         dot_dtype=dot_dtype)
         else:
+            # also the core of a grouped or windowed layer whose shape
+            # the kernels cannot tile: it never attends outside its
+            # window (ring and scan refuse both at initialize)
             from znicz_tpu.parallel.ring_attention import local_attention
             o = local_attention(q, k, v, causal=self.causal,
-                                dot_dtype=dot_dtype)
-        return self._project_out(x32, o, w_out, b_out)
+                                dot_dtype=dot_dtype, window=self.window)
+        return self._project_out(x32, h, o, w_out, b_out, w_gate)
 
-    def _project_out(self, x32, o, w_out, b_out):
+    def _project_out(self, x32, h, o, w_out, b_out, w_gate=None):
         """The out-projection over the core's result — (B, T, H, dh)
-        or (B, T, D), (B·T, D) by a free reshape either way — and the
-        residual."""
+        or (B, T, H·dh), (B·T, H·dh) by a free reshape either way —
+        and the residual; with ``w_gate`` every head's output first
+        multiplied by its sigmoid gate, computed from the sublayer's
+        (normed) input ``h``."""
         b, t, d = x32.shape
-        y = self.mxu_dot(jnp, o.reshape(b * t, d), w_out)
+        o = o.reshape(b * t, w_out.shape[0])
+        if w_gate is not None:
+            gate = jax.nn.sigmoid(
+                self.mxu_dot(jnp, h.reshape(b * t, d), w_gate))
+            o = o.astype(jnp.float32) * jnp.repeat(
+                gate, w_out.shape[0] // self.n_heads, axis=-1)
+        y = self.mxu_dot(jnp, o, w_out)
         if b_out is not None:
             y = y + b_out
         y = y.reshape(b, t, d)
@@ -810,9 +980,11 @@ class MultiHeadAttention(Forward):
 
     # -- numpy oracle ---------------------------------------------------
     def _forward_np(self, x):
-        """``(y, (h, qkv, q, k, v, o, p))``: ``h`` is what the QKV
-        projection saw (the input, or its pre-norm), ``qkv`` the raw
-        projections, ``q``/``k`` what the core saw (normed, rotated)."""
+        """``(y, (h, qkv, q, k, v, o, p, gate))``: ``h`` is what the
+        QKV projection saw (the input, or its pre-norm), ``qkv`` the raw
+        projections, ``q``/``k`` what the core saw (normed, rotated),
+        ``o`` the core's output BEFORE the per-head ``gate`` (None
+        without one)."""
         b, t, d = x.shape
         h = rms_norm(np, x, self.gain_norm.mem, self.norm_eps) \
             if self.pre_norm else x
@@ -820,17 +992,22 @@ class MultiHeadAttention(Forward):
         if self.include_bias:
             qkv = qkv + self.bias.mem
         q, k, v = self._normed_rotated(
-            np, qkv.reshape(b, t, 3 * d),
+            np, qkv.reshape(b, t, -1),
             self.gain_q.mem if self.qk_norm else None,
             self.gain_k.mem if self.qk_norm else None)
-        o, p = _local_attention_np(q, k, v, self.causal)
-        y = o.reshape(b * t, d) @ self.weights_out.mem
+        o, p = _local_attention_np(q, k, v, self.causal, self.window)
+        gate, out = None, o
+        if self.head_gate:
+            gate = 1.0 / (1.0 + np.exp(
+                -(h.reshape(b * t, d) @ self.weights_head_gate.mem)))
+            out = o * gate.reshape(b, t, self.n_heads, 1)
+        y = out.reshape(b * t, -1) @ self.weights_out.mem
         if self.include_bias:
             y = y + self.bias_out.mem
         y = y.reshape(b, t, d)
         if self.residual:
             y = x + y
-        return y, (h, qkv, q, k, v, o, p)
+        return y, (h, qkv, q, k, v, o, p, gate)
 
     def numpy_run(self) -> None:
         self.input.map_read()
@@ -839,7 +1016,8 @@ class MultiHeadAttention(Forward):
         if self.include_bias:
             self.bias.map_read()
             self.bias_out.map_read()
-        for gain in (self.gain_norm, self.gain_q, self.gain_k):
+        for gain in (self.gain_norm, self.gain_q, self.gain_k,
+                     self.weights_head_gate):
             if gain:
                 gain.map_read()
         y, _ = self._forward_np(self.input.mem.astype(np.float32))
@@ -870,16 +1048,23 @@ class GDMultiHeadAttention(GradientDescentBase):
             name=f"{self.name}.acc_gain_q")
         self.accumulated_gradient_gain_k = Vector(
             name=f"{self.name}.acc_gain_k")
+        # … and the per-head gate's projection
+        self.accumulated_gradient_weights_head_gate = Vector(
+            name=f"{self.name}.acc_gw_head_gate")
 
     def _gain_pairs(self) -> list:
-        """``(suffix, gain Vector, its accumulator)`` for the gains the
-        forward's options allocated."""
+        """``(suffix, parameter Vector, its accumulator)`` for the
+        gains and the gate the forward's options allocated."""
         fwd = self.forward_unit
-        return [(name, gain, getattr(
+        pairs = [(name, gain, getattr(
                     self, f"accumulated_gradient_gain_{name}"))
-                for name, gain in (("norm", fwd.gain_norm),
-                                   ("q", fwd.gain_q), ("k", fwd.gain_k))
-                if gain]
+                 for name, gain in (("norm", fwd.gain_norm),
+                                    ("q", fwd.gain_q), ("k", fwd.gain_k))
+                 if gain]
+        if fwd.weights_head_gate:
+            pairs.append(("head_gate", fwd.weights_head_gate,
+                          self.accumulated_gradient_weights_head_gate))
+        return pairs
 
     def initialize(self, device=None, **kwargs) -> None:
         super().initialize(device=device, **kwargs)
@@ -958,7 +1143,7 @@ class GDMultiHeadAttention(GradientDescentBase):
             self._apply_bias_xla(
                 gbo, vec=fwd.bias_out,
                 acc_vec=self.accumulated_gradient_bias_out)
-        grads = dict(zip(("norm", "q", "k"), ggains))
+        grads = dict(zip(("norm", "q", "k", "head_gate"), ggains))
         for name, gain, acc in self._gain_pairs():
             self._apply_weights_xla(grads[name], vec=gain, acc_vec=acc)
 
@@ -976,41 +1161,58 @@ class GDMultiHeadAttention(GradientDescentBase):
             gain.map_write()
         x = self.input.mem.astype(np.float32)
         b, t, d = x.shape
-        h = fwd.n_heads
-        dh = d // h
-        _, (hidden, qkv, q, k, v, o, p) = fwd._forward_np(x)
+        h, h_kv = fwd.n_heads, fwd.n_kv_heads
+        qw, kw, dh = fwd._widths(d)
+        _, (hidden, qkv, q, k, v, o, p, gate) = fwd._forward_np(x)
         dy = self.err_output.mem.astype(np.float32).reshape(b * t, d)
-        # output projection
-        grad_wo = o.reshape(b * t, d).T @ dy
-        grad_bo = dy.sum(axis=0)
+        grad_gains = {}
+        # output projection (over the gated heads, where there is a gate)
         do = (dy @ fwd.weights_out.mem.T).reshape(b, t, h, dh)
-        # attention core: dv, softmax jacobian, dq/dk
+        d_hidden = 0.0
+        if gate is None:
+            grad_wo = o.reshape(b * t, qw).T @ dy
+        else:
+            g4 = gate.reshape(b, t, h, 1)
+            grad_wo = (o * g4).reshape(b * t, qw).T @ dy
+            d_logit = ((do * o).sum(axis=-1) * gate.reshape(b, t, h)
+                       * (1.0 - gate.reshape(b, t, h))).reshape(b * t, h)
+            grad_gains["head_gate"] = hidden.reshape(b * t, d).T @ d_logit
+            d_hidden = d_logit @ fwd.weights_head_gate.mem.T
+            do = do * g4
+        grad_bo = dy.sum(axis=0)
+        # attention core: dv, softmax jacobian, dq/dk — K/V heads
+        # repeated over their group, their gradients summed over it
+        group = h // h_kv
+        k_all, v_all = np.repeat(k, group, axis=2), \
+            np.repeat(v, group, axis=2)
         dv = np.einsum("bhqk,bqhd->bkhd", p, do)
-        dp = np.einsum("bqhd,bkhd->bhqk", do, v)
+        dp = np.einsum("bqhd,bkhd->bhqk", do, v_all)
         ds = p * (dp - (dp * p).sum(axis=-1, keepdims=True))
         ds = ds / np.sqrt(dh)
-        dq = np.einsum("bhqk,bkhd->bqhd", ds, k)
+        dq = np.einsum("bhqk,bkhd->bqhd", ds, k_all)
         dk = np.einsum("bhqk,bqhd->bkhd", ds, q)
-        grad_gains = {}
+        dk, dv = (a.reshape(b, t, h_kv, group, dh).sum(axis=3)
+                  for a in (dk, dv))
         if fwd.rope_theta is not None:    # the rotation's adjoint
-            cos, sin = rope_tables(np, t, dh, fwd.rope_theta)
+            # (with YaRN's scaled tables too: a·Rᵀ, not the inverse)
+            cos, sin = fwd._rope_tables(np, t, dh)
             dq = apply_rope(np, dq, cos, sin, inverse=True)
             dk = apply_rope(np, dk, cos, sin, inverse=True)
         if fwd.qk_norm:                   # back through the q/k norms
-            raw = qkv.reshape(b, t, 3 * d)
+            raw = qkv.reshape(b, t, -1)
             dq, grad_gains["q"] = rms_norm_backward(
-                np, raw[..., :d], fwd.gain_q.mem, fwd.norm_eps,
-                dq.reshape(b, t, d))
+                np, raw[..., :qw], fwd.gain_q.mem, fwd.norm_eps,
+                dq.reshape(b, t, qw))
             dk, grad_gains["k"] = rms_norm_backward(
-                np, raw[..., d:2 * d], fwd.gain_k.mem, fwd.norm_eps,
-                dk.reshape(b, t, d))
+                np, raw[..., qw:qw + kw], fwd.gain_k.mem, fwd.norm_eps,
+                dk.reshape(b, t, kw))
         dqkv = np.concatenate(
-            [a.reshape(b, t, d) for a in (dq, dk, dv)],
-            axis=-1).reshape(b * t, 3 * d)
+            [a.reshape(b, t, -1) for a in (dq, dk, dv)],
+            axis=-1).reshape(b * t, qw + 2 * kw)
         # input projection
         grad_wq = hidden.reshape(b * t, d).T @ dqkv
         grad_bq = dqkv.sum(axis=0)
-        dx = (dqkv @ self.weights.mem.T).reshape(b, t, d)
+        dx = (dqkv @ self.weights.mem.T + d_hidden).reshape(b, t, d)
         if fwd.pre_norm:
             dx, grad_gains["norm"] = rms_norm_backward(
                 np, x, fwd.gain_norm.mem, fwd.norm_eps, dx)

@@ -46,6 +46,38 @@ per step.
 Backward (``GDMoE``): the stashed ``jax.vjp`` of the forward on the XLA
 path (as the attention unit does), validated against the analytic
 numpy oracle, which loops over the experts.
+
+Options of the same layer (Laguna-S-2.1, PR 29; all unset = the layer
+above, whose program they leave untouched): ``score="sigmoid"`` scores
+an expert by ``σ(logit)`` instead of the softmax (the load-balancing
+loss then runs over the scores normalised to sum 1); ``routed_scale``
+multiplies the routed sum; ``shared_width`` adds an always-on gated MLP
+of that width, ``Shared(m)``, beside it; and **``held``** names the
+experts whose weights live on THIS chip — one chip's share of an
+expert-parallel deployment.  The router keeps all its outputs and its
+top k; slabs, momentum and gradients exist for the held experts only;
+the layer computes the (token, expert) pairs routed to them and nothing
+stands in for the others: ``y = x + Shared(m) + Σ_{e ∈ top_k ∩ held}
+w_e · Expert_e(m)``.  The pairs here are a traced number under a static
+shape: they are sorted to the front and the first ``capacity`` rows go
+through the grouped matmuls, whose work follows the rows that are real,
+and come back by a scatter-add.  ONE rule sizes that buffer:
+``HELD_SLACK`` (4) times the share uniform routing sends here,
+N · k · |held| / E, and never more than the N · min(k, |held|) pairs
+that CAN arrive — as an expert-parallel exchange buffer is sized, and
+what keeps the dead rows cheap (the worst case is 25.6 times the
+uniform share at 8 of 256 experts, top 10, and the gathers, the
+weighting and the scatter-add run over every row of the buffer, real or
+not: PERF.md §6, PR 29).  A step that routes more pairs here is
+not computed short: its output is NaN, so the anomaly guard refuses the
+whole step.  What a user then sees: ``guard_skipped_steps`` rises WITH
+``znicz_moe_held{stat="rows_over"}`` above 0 and ``rows_here`` near
+``capacity`` — the router has collapsed onto this chip's experts
+(raise ``aux_loss_weight``, or hold fewer tokens a step); skipped steps
+with ``rows_over`` 0 have another cause.
+
+``GatedMLP`` is the same gated MLP with no router — a model's dense
+feed-forward block (``y = x + W_down (silu(W_gate m) ⊙ W_up m)``).
 """
 
 from __future__ import annotations
@@ -74,6 +106,11 @@ _LB, _Z, _STEPS, _MAX, _MIN = range(5)
 GMM_TILING = (256, 1024, 1024)
 
 
+#: a held share's row buffer, as a multiple of what uniform routing
+#: sends to the experts held (module docstring)
+HELD_SLACK = 4
+
+
 def gmm_tiling(rows: int, k: int, n: int) -> tuple:
     """``GMM_TILING`` cut to a small problem (the tests'): the kernels
     want the row tile to divide the rows."""
@@ -90,27 +127,41 @@ def _megablox():
         "jax.experimental.pallas.ops.tpu.megablox.gmm")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _gmm_pallas(lhs, rhs, group_sizes, interpret):
-    return _megablox().gmm(lhs, rhs.astype(lhs.dtype), group_sizes,
-                           jnp.float32, gmm_tiling(*lhs.shape,
-                                                   rhs.shape[2]),
-                           interpret=interpret)
+def _zero_tail(out, group_sizes):
+    """Rows past the last group: the kernel never writes them."""
+    live = jnp.arange(out.shape[0])[:, None] < group_sizes.sum()
+    return jnp.where(live, out, 0.0)
 
 
-def _gmm_pallas_fwd(lhs, rhs, group_sizes, interpret):
-    return (_gmm_pallas(lhs, rhs, group_sizes, interpret),
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gmm_pallas(lhs, rhs, group_sizes, interpret, tail=False):
+    """``tail``: the groups may end before the rows do (one chip's
+    share of the pairs, under a static capacity) — the kernel's work
+    follows the groups, and the rows it leaves unwritten are zeroed."""
+    out = _megablox().gmm(lhs, rhs.astype(lhs.dtype), group_sizes,
+                          jnp.float32, gmm_tiling(*lhs.shape,
+                                                  rhs.shape[2]),
+                          interpret=interpret)
+    return _zero_tail(out, group_sizes) if tail else out
+
+
+def _gmm_pallas_fwd(lhs, rhs, group_sizes, interpret, tail):
+    return (_gmm_pallas(lhs, rhs, group_sizes, interpret, tail),
             (lhs, rhs, group_sizes))
 
 
-def _gmm_pallas_bwd(interpret, residual, grad):
+def _gmm_pallas_bwd(interpret, tail, residual, grad):
     lhs, rhs, group_sizes = residual
     kernels = _megablox()
     grad = grad.astype(lhs.dtype)
+    if tail:
+        grad = _zero_tail(grad, group_sizes).astype(lhs.dtype)
     tiling = gmm_tiling(*lhs.shape, rhs.shape[2])
     d_lhs = kernels.gmm(grad, rhs.astype(lhs.dtype), group_sizes,
                         jnp.float32, tiling, transpose_rhs=True,
                         interpret=interpret)
+    if tail:
+        d_lhs = _zero_tail(d_lhs, group_sizes)
     d_rhs = kernels.tgmm(lhs.swapaxes(0, 1), grad, group_sizes,
                          jnp.float32, tiling,
                          num_actual_groups=rhs.shape[0],
@@ -121,9 +172,9 @@ def _gmm_pallas_bwd(interpret, residual, grad):
 _gmm_pallas.defvjp(_gmm_pallas_fwd, _gmm_pallas_bwd)
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4))
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
 def grouped_matmul(lhs, rhs, group_sizes, kernel: bool = False,
-                   interpret: bool = False):
+                   interpret: bool = False, tail: bool = False):
     """(M, K) rows in E contiguous groups × (E, K, N) f32 slabs →
     (M, N) f32: row r of group e is multiplied by ``rhs[e]``, the slabs
     cast to the rows' dtype on the way in.  ``kernel`` runs JAX's
@@ -131,7 +182,10 @@ def grouped_matmul(lhs, rhs, group_sizes, kernel: bool = False,
     in f32), else ``jax.lax.ragged_dot``, the XLA core.  Jitted so that
     the three call sites of a layer, and every layer, lower it once
     (PERF.md §6, PR 24: lowering is a set-up cost the compile cache
-    does not hide)."""
+    does not hide).  ``tail``: rows may follow the last group; they
+    come back zero (``ragged_dot`` zeroes them itself)."""
+    if kernel and tail:
+        return _gmm_pallas(lhs, rhs, group_sizes, interpret, True)
     if kernel:
         return _gmm_pallas(lhs, rhs, group_sizes, interpret)
     return jax.lax.ragged_dot(lhs, rhs.astype(lhs.dtype), group_sizes,
@@ -186,16 +240,37 @@ def _silu(xp, x):
     return x / (1.0 + xp.exp(-x))
 
 
+def _sigmoid(xp, x):
+    return 1.0 / (1.0 + xp.exp(-x))
+
+
+def gated_mlp(xp, dot, m, w_gate, w_up, w_down):
+    """``W_down (silu(W_gate m) ⊙ W_up m)`` of (N, D) rows; ``dot`` is
+    the unit's matmul (bf16 inputs on the device, plain in numpy)."""
+    return dot(xp, _silu(xp, dot(xp, m, w_gate)) * dot(xp, m, w_up),
+               w_down)
+
+
+def _np_dot(xp, a, b):
+    return a @ b
+
+
 class MoE(Forward):
     """Dropless top-k mixture of SwiGLU experts (module docstring)."""
 
     EXPORT_PARAMS = ("weights", "weights_gate", "weights_up",
-                     "weights_down", "gain_norm")
+                     "weights_down", "gain_norm", "weights_shared_gate",
+                     "weights_shared_up", "weights_shared_down")
+    #: the always-on shared expert's three matrices
+    SHARED = ("weights_shared_gate", "weights_shared_up",
+              "weights_shared_down")
 
     def __init__(self, workflow, n_experts: int, top_k: int, width: int,
                  norm_topk: bool = False, pre_norm: str | None = None,
                  residual: bool = False, aux_loss_weight: float = 0.0,
                  z_loss_weight: float = 0.0, norm_eps: float = 1e-5,
+                 score: str = "softmax", routed_scale: float = 1.0,
+                 shared_width: int = 0, held=None,
                  name=None, **kwargs) -> None:
         kwargs.setdefault("weights_filling", "xavier")
         kwargs["include_bias"] = False
@@ -208,18 +283,36 @@ class MoE(Forward):
         if pre_norm not in (None, "rms"):
             raise ValueError(f"pre_norm must be None or 'rms', got "
                              f"{pre_norm!r}")
+        if score not in ("softmax", "sigmoid"):
+            raise ValueError(f"score must be 'softmax' or 'sigmoid', "
+                             f"got {score!r}")
         self.norm_topk = bool(norm_topk)
         self.pre_norm = pre_norm
         self.residual = bool(residual)
         self.aux_loss_weight = float(aux_loss_weight)
         self.z_loss_weight = float(z_loss_weight)
         self.norm_eps = float(norm_eps)
+        #: the options of the module docstring's last part
+        self.score = score
+        self.routed_scale = float(routed_scale)
+        self.shared_width = int(shared_width)
+        self.held = None if held is None \
+            else tuple(sorted({int(e) for e in held}))
+        if self.held is not None and not (
+                self.held and 0 <= self.held[0]
+                and self.held[-1] < self.n_experts):
+            raise ValueError(f"{self}: held {held} is not a set of "
+                             f"experts below {n_experts}")
         self.weights_gate = Vector(name=f"{self.name}.weights_gate")
         self.weights_up = Vector(name=f"{self.name}.weights_up")
         self.weights_down = Vector(name=f"{self.name}.weights_down")
         self.gain_norm = Vector(name=f"{self.name}.gain_norm")
-        #: [rows per expert (E) | lb loss, z loss, steps, per-step max
-        #: and min rows of an expert], summed on the device
+        for attr in self.SHARED:
+            setattr(self, attr, Vector(name=f"{self.name}.{attr}"))
+        #: [rows per expert held here (all E without ``held``) | lb
+        #: loss, z loss, steps, per-step max and min rows of an expert;
+        #: with ``held`` also: rows here, rows routed, rows over the
+        #: capacity], summed on the device
         self.moe_stats = Vector(name=f"{self.name}.moe_stats")
         #: what the router did in the last step: its (N, E) logits and
         #: the (N, top_k) experts chosen — what a check against a plain
@@ -231,6 +324,11 @@ class MoE(Forward):
         #: pullback stashed by xla_run for the GD pair (as attention)
         self._traced_vjp = None
 
+    @property
+    def n_local(self) -> int:
+        """Experts whose weights live here."""
+        return self.n_experts if self.held is None else len(self.held)
+
     def initialize(self, device=None, **kwargs) -> None:
         super().initialize(device=device, **kwargs)
         if self.input is None or not self.input:
@@ -240,22 +338,28 @@ class MoE(Forward):
                              f"input, got {self.input.shape}")
         b, t, d = self.input.shape
         e, f = self.n_experts, self.width
+        local = self.n_local
         if not self.weights:                       # the router
             self.weights.reset(self.fill_array(
                 (d, e), self.weights_filling, self.weights_stddev,
                 fan_in=d))
+        shared = self.shared_width
         for vec, shape, fan_in in (
-                (self.weights_gate, (e, d, f), d),
-                (self.weights_up, (e, d, f), d),
-                (self.weights_down, (e, f, d), f)):
-            if not vec:
+                (self.weights_gate, (local, d, f), d),
+                (self.weights_up, (local, d, f), d),
+                (self.weights_down, (local, f, d), f),
+                (self.weights_shared_gate, (d, shared), d),
+                (self.weights_shared_up, (d, shared), d),
+                (self.weights_shared_down, (shared, d), shared)):
+            if all(shape) and not vec:
                 vec.reset(self.fill_array(shape, self.weights_filling,
                                           self.weights_stddev,
                                           fan_in=fan_in))
         if self.pre_norm and not self.gain_norm:
             self.gain_norm.reset(np.ones(d, np.float32))
         if not self.moe_stats:
-            self.moe_stats.reset(np.zeros(e + 5, np.float32))
+            self.moe_stats.reset(np.zeros(
+                local + 5 + (3 if self.held else 0), np.float32))
         self.output.reset(np.zeros((b, t, d),
                                    dtype=self.output_store_dtype))
         self.router_logits.reset(np.zeros((b, t, e), np.float32))
@@ -271,60 +375,138 @@ class MoE(Forward):
         mesh = getattr(self.device, "mesh", None)
         if refused is None and mesh is not None and mesh.size > 1:
             refused = "expert parallelism over a mesh is not built"
-        rows = b * t * self.top_k
+        pairs = rows = b * t * self.top_k
+        if self.held is not None:
+            # the rows the step's buffers hold (module docstring), a
+            # whole number of the kernel's row tiles where they fit
+            rows = min(-(-HELD_SLACK * pairs * local // e),
+                       b * t * min(self.top_k, local))
+            tile = min(GMM_TILING[0], rows)
+            rows = min(-(-rows // tile) * tile, pairs)
+            self._capacity = rows
         if refused is None and rows % min(GMM_TILING[0], rows):
             refused = (f"{rows} rows do not divide by the kernel's "
                        f"row tile {GMM_TILING[0]}")
         self._gmm_kernel = refused is None
         self._gmm_interpret = interpret
-        self.info("%s: %d experts top %d, dropless; grouped matmul %s "
+        self.info("%s: %d experts top %d%s, dropless; grouped matmul %s "
                   "over %d rows in %d groups, %d x %d (gate, up) and "
-                  "%d x %d (down)", self.name, e, self.top_k,
+                  "%d x %d (down)%s", self.name, e, self.top_k,
+                  "" if self.held is None else
+                  f", {local} held here (capacity {rows} of "
+                  f"{pairs} pairs)",
                   f"megablox kernel, tiles {GMM_TILING}"
                   + (", INTERPRETED" if interpret else "")
                   if self._gmm_kernel
                   else f"jax.lax.ragged_dot ({refused})",
-                  rows, e, d, f, f, d)
+                  rows, local, d, f, f, d,
+                  f"; {self.score} scores x {self.routed_scale:g}, "
+                  f"shared expert of {shared}"
+                  if (self.score, self.routed_scale, shared)
+                  != ("softmax", 1.0, 0) else "")
         self.init_vectors(self.input, self.output, self.weights,
                           self.weights_gate, self.weights_up,
                           self.weights_down, self.gain_norm,
                           self.moe_stats, self.router_logits,
-                          self.last_choice)
+                          self.last_choice,
+                          *(getattr(self, attr) for attr in self.SHARED))
 
     # -- pure forward (jnp; the backward vjp's this) --------------------
     def forward_args(self) -> tuple:
-        return (self.input.devmem, self.weights.devmem,
+        args = (self.input.devmem, self.weights.devmem,
                 self.weights_gate.devmem, self.weights_up.devmem,
                 self.weights_down.devmem,
                 self.gain_norm.devmem if self.gain_norm else None)
+        if self.shared_width:
+            args += tuple(getattr(self, attr).devmem
+                          for attr in self.SHARED)
+        return args
 
     def route(self, xp, m, w_r):
         """``(logits, p, top_p, top_e)`` for (N, D) rows — float32
         throughout, on the device at the highest matmul precision, so
-        that the choice of experts does not ride bf16 rounding."""
+        that the choice of experts does not ride bf16 rounding.  ``p``
+        is the score the top k are taken by: the softmax over the
+        experts, or each expert's own sigmoid."""
         if xp is jnp:
             logits = jnp.dot(m, w_r, precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
-            p = jax.nn.softmax(logits, axis=-1)
+            p = jax.nn.softmax(logits, axis=-1) \
+                if self.score == "softmax" else jax.nn.sigmoid(logits)
             top_p, top_e = jax.lax.top_k(p, self.top_k)
         else:
             logits = m @ w_r
-            z = np.exp(logits - logits.max(axis=-1, keepdims=True))
-            p = z / z.sum(axis=-1, keepdims=True)
+            if self.score == "softmax":
+                z = np.exp(logits - logits.max(axis=-1, keepdims=True))
+                p = z / z.sum(axis=-1, keepdims=True)
+            else:
+                p = _sigmoid(np, logits)
             # ties go to the lower index, as lax.top_k
             top_e = np.argsort(-p, axis=-1, kind="stable")[:, :self.top_k]
             top_p = np.take_along_axis(p, top_e, axis=-1)
         return logits, p, top_p, top_e
 
     def aux_losses(self, xp, logits, p, counts):
-        """(load-balancing loss, z-loss); ``counts`` rows per expert."""
+        """(load-balancing loss, z-loss); ``counts`` rows per expert,
+        over ALL the experts the router chooses among.  Sigmoid scores
+        enter the load-balancing loss normalised to sum 1."""
         n = logits.shape[0]
+        if self.score == "sigmoid":
+            p = p / p.sum(axis=-1, keepdims=True)
         lb = self.n_experts * ((counts / n) * p.mean(axis=0)).sum()
         top = logits.max(axis=-1)
         lse = top + xp.log(xp.exp(logits - top[:, None]).sum(axis=-1))
         return lb, (lse * lse).mean()
 
-    def xla_forward(self, x, w_r, w_g, w_u, w_d, g_norm=None):
+    def _weights_of(self, top_p):
+        """The chosen experts' weights in the routed sum."""
+        if self.norm_topk:
+            top_p = top_p / top_p.sum(axis=-1, keepdims=True)
+        if self.routed_scale != 1.0:
+            top_p = top_p * self.routed_scale
+        return top_p
+
+    def _held_experts(self, m, top_p, top_e, w_g, w_u, w_d):
+        """``(f, local counts, (rows here, rows over))``: the routed
+        sum of the pairs whose expert lives here (module docstring)."""
+        n, d = m.shape
+        k, local, cap = self.top_k, self.n_local, self._capacity
+        table = np.full(self.n_experts, local, np.int32)
+        table[list(self.held)] = np.arange(local, dtype=np.int32)
+        slot = jnp.asarray(table)[top_e.reshape(n * k)]
+        # the pairs here first, by expert and inside an expert by
+        # token; the pairs of absent experts last
+        order = jnp.argsort(slot, stable=True).astype(jnp.int32)
+        sizes = (slot[:, None] == jnp.arange(local)[None, :]).sum(
+            axis=0, dtype=jnp.int32)
+        here = sizes.sum()
+        pair = order[:cap]
+        live = jnp.arange(cap) < here
+        token = pair // k
+        # the groups cut to the buffer (all of them fit, unless the
+        # step is over: then it is poisoned below)
+        sizes = jnp.minimum(sizes, jnp.maximum(
+            cap - (jnp.cumsum(sizes) - sizes), 0))
+        dt = self.mxu_dtype or jnp.float32
+        path = (getattr(self, "_gmm_kernel", False),
+                getattr(self, "_gmm_interpret", False), True)
+        rows = jnp.where(live[:, None], jnp.take(m, token, axis=0),
+                         0.0).astype(dt)
+        gate = grouped_matmul(rows, w_g, sizes, *path)
+        up = grouped_matmul(rows, w_u, sizes, *path)
+        hidden = (_silu(jnp, gate) * up).astype(dt)
+        out = grouped_matmul(hidden, w_d, sizes, *path)
+        weight = jnp.where(live, jnp.take(top_p.reshape(n * k), pair),
+                           0.0)
+        f = jnp.zeros((n, d), jnp.float32).at[token].add(
+            out * weight[:, None])
+        over = jnp.maximum(here - cap, 0)
+        # never short: the guard refuses a step that is over
+        f = f + jnp.where(over > 0, jnp.float32(jnp.nan), 0.0)
+        return f, sizes, (here, over)
+
+    def xla_forward(self, x, w_r, w_g, w_u, w_d, g_norm=None,
+                    ws_g=None, ws_u=None, ws_d=None):
         """``((y, (lb, z)), (counts, logits, top_e))``: the output and
         the two auxiliary losses (differentiable); rows per expert, the
         router's logits and its choice (not)."""
@@ -334,40 +516,60 @@ class MoE(Forward):
         m = (x32 if g_norm is None
              else rms_norm(jnp, x32, g_norm, self.norm_eps)).reshape(n, d)
         logits, p, top_p, top_e = self.route(jnp, m, w_r)
-        if self.norm_topk:
-            top_p = top_p / top_p.sum(axis=-1, keepdims=True)
+        top_p = self._weights_of(top_p)
         flat_e = top_e.reshape(n * k)
-        order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
-        inverse = jnp.argsort(order).astype(jnp.int32)
-        # rows per expert by comparison, not by a scatter-add (which a
-        # TPU serialises)
-        sizes = (flat_e[:, None] == jnp.arange(e)[None, :]).sum(
-            axis=0, dtype=jnp.int32)
-        dt = self.mxu_dtype or jnp.float32
-        path = (getattr(self, "_gmm_kernel", False),
-                getattr(self, "_gmm_interpret", False))
-        rows = _dispatch(m, order, inverse, dt)
-        gate = grouped_matmul(rows, w_g, sizes, *path)
-        up = grouped_matmul(rows, w_u, sizes, *path)
-        hidden = (_silu(jnp, gate) * up).astype(dt)
-        out = grouped_matmul(hidden, w_d, sizes, *path)
-        out = _unpermute(out, inverse, order).reshape(n, k, d)
-        y = (out * top_p[..., None]).sum(axis=1).reshape(b, t, d)
+
+        def rows_per_expert():
+            # by comparison, not by a scatter-add (which a TPU
+            # serialises)
+            return (flat_e[:, None] == jnp.arange(e)[None, :]).sum(
+                axis=0, dtype=jnp.int32)
+
+        if self.held is not None:
+            sizes = rows_per_expert()
+            f, local, here = self._held_experts(m, top_p, top_e, w_g,
+                                                w_u, w_d)
+            y = f.reshape(b, t, d)
+            extra = jnp.stack([here[0], jnp.int32(n * k), here[1]])
+            counts = jax.lax.stop_gradient(
+                jnp.concatenate([local, extra]).astype(jnp.float32))
+        else:
+            order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
+            inverse = jnp.argsort(order).astype(jnp.int32)
+            sizes = rows_per_expert()
+            dt = self.mxu_dtype or jnp.float32
+            path = (getattr(self, "_gmm_kernel", False),
+                    getattr(self, "_gmm_interpret", False))
+            rows = _dispatch(m, order, inverse, dt)
+            gate = grouped_matmul(rows, w_g, sizes, *path)
+            up = grouped_matmul(rows, w_u, sizes, *path)
+            hidden = (_silu(jnp, gate) * up).astype(dt)
+            out = grouped_matmul(hidden, w_d, sizes, *path)
+            out = _unpermute(out, inverse, order).reshape(n, k, d)
+            y = (out * top_p[..., None]).sum(axis=1).reshape(b, t, d)
+            counts = None
+        if ws_g is not None:
+            y = y + gated_mlp(jnp, self.mxu_dot, m, ws_g, ws_u,
+                              ws_d).reshape(b, t, d)
         if self.residual:
             y = x32 + y
-        counts = jax.lax.stop_gradient(sizes.astype(jnp.float32))
-        return ((y, self.aux_losses(jnp, logits, p, counts)),
-                (counts, jax.lax.stop_gradient(logits), top_e))
+        routed = jax.lax.stop_gradient(sizes.astype(jnp.float32))
+        return ((y, self.aux_losses(jnp, logits, p, routed)),
+                (routed if counts is None else counts,
+                 jax.lax.stop_gradient(logits), top_e))
 
     def _record(self, lb, z, counts, logits, top_e) -> None:
         self.router_logits.devmem = logits.reshape(
             self.router_logits.shape)
         self.last_choice.devmem = top_e.astype(jnp.int32).reshape(
             self.last_choice.shape)
+        held = []
+        if self.held is not None:      # [local rows | here, all, over]
+            counts, held = counts[:self.n_local], [counts[self.n_local:]]
         tail = jnp.stack([lb, z, jnp.float32(1.0), counts.max(),
                           counts.min()])
         self.moe_stats.devmem = self.moe_stats.devmem \
-            + jnp.concatenate([counts, tail]).astype(jnp.float32)
+            + jnp.concatenate([counts, tail] + held).astype(jnp.float32)
 
     def xla_run(self) -> None:
         args = self.forward_args()
@@ -388,7 +590,7 @@ class MoE(Forward):
         if not stats:
             return
         stats.map_read()
-        e = self.n_experts
+        e = self.n_local
         tail = np.asarray(stats.mem[e:], np.float64)
         steps = max(float(tail[_STEPS]), 1.0)
         if obs_metrics.enabled() and tail[_STEPS]:
@@ -401,55 +603,73 @@ class MoE(Forward):
                 tail[_LB] / steps)
             obs_metrics.moe_aux_loss(self.name, "z").set(
                 tail[_Z] / steps)
+            if self.held is not None:
+                here, routed, over = tail[5:8] / steps
+                for stat, value in (
+                        ("held", e), ("of", self.n_experts),
+                        ("rows_here", here), ("rows_routed", routed),
+                        ("capacity", getattr(self, "_capacity", 0)),
+                        ("rows_over", over)):
+                    obs_metrics.moe_held(self.name, stat).set(value)
         stats.map_invalidate()
         stats.mem[...] = 0.0      # uploaded on the next region fire
 
     # -- numpy oracle ---------------------------------------------------
     def _forward_np(self, x):
-        """``(y, cache)``; a loop over the experts, each computing the
-        rows routed to it."""
+        """``(y, cache)``; a loop over the experts held here, each
+        computing the rows routed to it."""
         b, t, d = x.shape
         n = b * t
         m = (rms_norm(np, x, self.gain_norm.mem, self.norm_eps)
              if self.pre_norm else x).reshape(n, d)
         logits, p, raw_p, top_e = self.route(np, m, self.weights.mem)
-        top_p = raw_p / raw_p.sum(axis=-1, keepdims=True) \
-            if self.norm_topk else raw_p
+        top_p = self._weights_of(raw_p)
         f = np.zeros((n, d), np.float32)
         per_expert = []
-        for e in range(self.n_experts):
+        experts = range(self.n_experts) if self.held is None \
+            else self.held
+        for slot, e in enumerate(experts):
             rows, slots = np.nonzero(top_e == e)
             me = m[rows]
-            gate = me @ self.weights_gate.mem[e]
-            up = me @ self.weights_up.mem[e]
+            gate = me @ self.weights_gate.mem[slot]
+            up = me @ self.weights_up.mem[slot]
             hidden = _silu(np, gate) * up
-            out = hidden @ self.weights_down.mem[e]
+            out = hidden @ self.weights_down.mem[slot]
             np.add.at(f, rows, out * top_p[rows, slots][:, None])
             per_expert.append((rows, slots, me, gate, up, hidden, out))
+        if self.shared_width:
+            f = f + gated_mlp(np, _np_dot, m, *(
+                getattr(self, attr).mem for attr in self.SHARED))
         y = f.reshape(b, t, d)
         if self.residual:
             y = x + y
-        counts = np.asarray([len(pe[0]) for pe in per_expert],
-                            np.float32)
-        return y, (m, logits, p, raw_p, top_p, top_e, per_expert, counts)
+        # rows per expert over ALL the experts routed over (what the
+        # load-balancing loss counts); ``per_expert`` has the held ones
+        routed = np.asarray([(top_e == e).sum()
+                             for e in range(self.n_experts)], np.float32)
+        return y, (m, logits, p, raw_p, top_p, top_e, per_expert, routed)
 
     def numpy_run(self) -> None:
         for vec in (self.input, self.weights, self.weights_gate,
-                    self.weights_up, self.weights_down, self.gain_norm):
+                    self.weights_up, self.weights_down, self.gain_norm,
+                    *(getattr(self, attr) for attr in self.SHARED)):
             if vec:
                 vec.map_read()
         y, cache = self._forward_np(self.input.mem.astype(np.float32))
         self.output.map_invalidate()
         self.output.mem[...] = y
-        logits, p, counts = cache[1], cache[2], cache[-1]
+        logits, p, routed = cache[1], cache[2], cache[-1]
+        counts = np.asarray([len(pe[0]) for pe in cache[-2]], np.float32)
         for vec, value in ((self.router_logits, logits),
                            (self.last_choice, cache[5])):
             vec.map_invalidate()
             vec.mem[...] = value.reshape(vec.shape)
-        lb, z = self.aux_losses(np, logits, p, counts)
+        lb, z = self.aux_losses(np, logits, p, routed)
+        held = [] if self.held is None \
+            else [counts.sum(), routed.sum(), 0.0]
         self.moe_stats.map_write()
         self.moe_stats.mem[...] += np.concatenate(
-            [counts, [lb, z, 1.0, counts.max(), counts.min()]]
+            [counts, [lb, z, 1.0, counts.max(), counts.min()], held]
         ).astype(np.float32)
 
 
@@ -461,19 +681,24 @@ class GDMoE(GradientDescentBase):
     MATCHES = (MoE,)
     REQUIRES_FORWARD_UNIT = True
     REQUIRES_INPUT = True
-    #: parameters beside the router (``weights``, the base's own pair)
-    EXTRA = ("weights_gate", "weights_up", "weights_down", "gain_norm")
+    #: parameters beside the router (``weights``, the base's own pair),
+    #: in the order of the forward's arguments
+    EXTRA = ("weights_gate", "weights_up", "weights_down", "gain_norm",
+             "weights_shared_gate", "weights_shared_up",
+             "weights_shared_down")
+    #: the forward returns what routing did beside its output
+    HAS_AUX = True
 
     def __init__(self, workflow, name=None, **kwargs):
         super().__init__(workflow, name=name, **kwargs)
-        self.forward_unit: MoE | None = None
+        self.forward_unit = None
         for attr in self.EXTRA:
             setattr(self, f"accumulated_gradient_{attr}",
                     Vector(name=f"{self.name}.acc_{attr}"))
 
     def _extra_pairs(self) -> list:
         """``(attr, parameter Vector, its accumulator)`` beside the
-        router."""
+        base's own pair."""
         fwd = self.forward_unit
         return [(attr, getattr(fwd, attr),
                  getattr(self, f"accumulated_gradient_{attr}"))
@@ -511,20 +736,24 @@ class GDMoE(GradientDescentBase):
         return (err, (xp.float32(fwd.aux_loss_weight),
                       xp.float32(fwd.z_loss_weight)))
 
-    def xla_run(self) -> None:
+    def _pullback(self):
+        """The forward's stashed pullback — valid only inside the trace
+        that made it (see GDMultiHeadAttention.xla_run) — or a new
+        one."""
         fwd = self.forward_unit
-        # the stash is valid only inside the trace that made it (see
-        # GDMultiHeadAttention.xla_run)
         vjp = fwd._traced_vjp if self.err_output._tracing else None
         fwd._traced_vjp = None
         if vjp is None:
-            _, vjp, _ = jax.vjp(fwd.xla_forward, *fwd.forward_args(),
-                                has_aux=True)
-        gx, g_router, *g_extra = vjp(self._cotangent(
+            vjp = jax.vjp(fwd.xla_forward, *fwd.forward_args(),
+                          has_aux=self.HAS_AUX)[1]
+        return vjp
+
+    def xla_run(self) -> None:
+        gx, g_own, *g_extra = self._pullback()(self._cotangent(
             jnp, self.err_output.devmem.astype(jnp.float32)))
         if self.need_err_input:
             self.err_input.devmem = gx
-        self._apply_weights_xla(g_router)
+        self._apply_weights_xla(g_own)
         grads = dict(zip(self.EXTRA, g_extra))
         for attr, param, acc in self._extra_pairs():
             self._apply_weights_xla(grads[attr], vec=param, acc_vec=acc)
@@ -540,40 +769,59 @@ class GDMoE(GradientDescentBase):
         x = self.input.mem.astype(np.float32)
         b, t, d = x.shape
         n, e_n = b * t, fwd.n_experts
-        _, (m, logits, p, raw_p, top_p, top_e, per_expert, counts) = \
+        _, (m, logits, p, raw_p, top_p, top_e, per_expert, routed) = \
             fwd._forward_np(x)
         dy = self.err_output.mem.astype(np.float32).reshape(n, d)
         grads = {attr: np.zeros_like(getattr(fwd, attr).mem)
                  for attr in ("weights_gate", "weights_up",
                               "weights_down")}
         dm = np.zeros_like(m)
-        dtop = np.zeros_like(top_p)
+        dtop = np.zeros_like(top_p)     # 0 for an expert held elsewhere
         for e, (rows, slots, me, gate, up, hidden, out) in \
                 enumerate(per_expert):
             dye = dy[rows]
             dtop[rows, slots] = (out * dye).sum(axis=-1)
             dout = dye * top_p[rows, slots][:, None]
             grads["weights_down"][e] = hidden.T @ dout
-            dhidden = dout @ fwd.weights_down.mem[e].T
-            sig = 1.0 / (1.0 + np.exp(-gate))
-            dgate = dhidden * up * sig * (1.0 + gate * (1.0 - sig))
-            dup = dhidden * gate * sig
+            dgate, dup, dme = _gated_mlp_backward(
+                me, gate, up, dout, fwd.weights_gate.mem[e],
+                fwd.weights_up.mem[e], fwd.weights_down.mem[e])
             grads["weights_gate"][e] = me.T @ dgate
             grads["weights_up"][e] = me.T @ dup
-            np.add.at(dm, rows, dgate @ fwd.weights_gate.mem[e].T
-                      + dup @ fwd.weights_up.mem[e].T)
+            np.add.at(dm, rows, dme)
+        if fwd.shared_width:
+            w_g, w_u, w_d = (getattr(fwd, attr).mem
+                             for attr in fwd.SHARED)
+            gate, up = m @ w_g, m @ w_u
+            grads["weights_shared_down"] = (_silu(np, gate) * up).T @ dy
+            dgate, dup, dme = _gated_mlp_backward(m, gate, up, dy, w_g,
+                                                  w_u, w_d)
+            grads["weights_shared_gate"] = m.T @ dgate
+            grads["weights_shared_up"] = m.T @ dup
+            dm += dme
+        dtop = dtop * fwd.routed_scale
         if fwd.norm_topk:      # back through top_p = raw_p / Σ raw_p
             total = raw_p.sum(axis=-1, keepdims=True)
-            dtop = (dtop - (dtop * top_p).sum(axis=-1, keepdims=True)) \
+            unit = raw_p / total
+            dtop = (dtop - (dtop * unit).sum(axis=-1, keepdims=True)) \
                 / total
         dp = np.zeros_like(p)
         np.put_along_axis(dp, top_e, dtop, axis=-1)
-        # the load-balancing loss reaches p through its column means
-        dp += fwd.aux_loss_weight * e_n * (counts / n)[None, :] / n
-        dlogits = p * (dp - (dp * p).sum(axis=-1, keepdims=True))
+        # the load-balancing loss reaches the scores through their
+        # column means
+        d_lb = fwd.aux_loss_weight * e_n * (routed / n)[None, :] / n
+        if fwd.score == "softmax":
+            dp = dp + d_lb
+            dlogits = p * (dp - (dp * p).sum(axis=-1, keepdims=True))
+        else:                  # … normalised to sum 1 first
+            total = p.sum(axis=-1, keepdims=True)
+            dp = dp + (d_lb - (d_lb * p / total).sum(
+                axis=-1, keepdims=True)) / total
+            dlogits = dp * p * (1.0 - p)
         top = logits.max(axis=-1)
         lse = top + np.log(np.exp(logits - top[:, None]).sum(axis=-1))
-        dlogits += fwd.z_loss_weight * (2.0 * lse / n)[:, None] * p
+        soft = np.exp(logits - lse[:, None])
+        dlogits += fwd.z_loss_weight * (2.0 * lse / n)[:, None] * soft
         grad_router = m.T @ dlogits
         dm += dlogits @ self.weights.mem.T
         dx = dm.reshape(b, t, d)
@@ -586,5 +834,142 @@ class GDMoE(GradientDescentBase):
             self.err_input.map_invalidate()
             self.err_input.mem[...] = dx
         self._apply_weights_np(grad_router)
+        for attr, param, acc in self._extra_pairs():
+            self._apply_weights_np(grads[attr], vec=param, acc_vec=acc)
+
+
+def _gated_mlp_backward(m, gate, up, dout, w_gate, w_up, w_down):
+    """``(dgate, dup, dm)`` of ``W_down (silu(gate) ⊙ up)`` given the
+    cotangent ``dout`` of its output."""
+    dhidden = dout @ w_down.T
+    sig = 1.0 / (1.0 + np.exp(-gate))
+    dgate = dhidden * up * sig * (1.0 + gate * (1.0 - sig))
+    dup = dhidden * gate * sig
+    return dgate, dup, dgate @ w_gate.T + dup @ w_up.T
+
+
+class GatedMLP(Forward):
+    """A dense gated feed-forward block (module docstring):
+    ``y = x + W_down (silu(W_gate m) ⊙ W_up m)``, m the input or its
+    RMSNorm.  ``weights`` is W_gate."""
+
+    EXPORT_PARAMS = ("weights", "weights_up", "weights_down",
+                     "gain_norm")
+
+    def __init__(self, workflow, width: int, pre_norm: str | None = None,
+                 residual: bool = False, norm_eps: float = 1e-5,
+                 name=None, **kwargs) -> None:
+        kwargs.setdefault("weights_filling", "xavier")
+        kwargs["include_bias"] = False
+        super().__init__(workflow, name=name, **kwargs)
+        if pre_norm not in (None, "rms"):
+            raise ValueError(f"pre_norm must be None or 'rms', got "
+                             f"{pre_norm!r}")
+        self.width = int(width)
+        self.pre_norm = pre_norm
+        self.residual = bool(residual)
+        self.norm_eps = float(norm_eps)
+        self.weights_up = Vector(name=f"{self.name}.weights_up")
+        self.weights_down = Vector(name=f"{self.name}.weights_down")
+        self.gain_norm = Vector(name=f"{self.name}.gain_norm")
+        self._traced_vjp = None
+
+    def initialize(self, device=None, **kwargs) -> None:
+        super().initialize(device=device, **kwargs)
+        if self.input is None or not self.input:
+            raise AttributeError(f"{self}: input not linked yet")
+        d, f = self.input.shape[-1], self.width
+        for vec, shape in ((self.weights, (d, f)),
+                           (self.weights_up, (d, f)),
+                           (self.weights_down, (f, d))):
+            if not vec:
+                vec.reset(self.fill_array(shape, self.weights_filling,
+                                          self.weights_stddev,
+                                          fan_in=shape[0]))
+        if self.pre_norm and not self.gain_norm:
+            self.gain_norm.reset(np.ones(d, np.float32))
+        self.output.reset(np.zeros(self.input.shape,
+                                   dtype=self.output_store_dtype))
+        self.inherit_model_shard(self.output)
+        self.init_vectors(self.input, self.output, self.weights,
+                          self.weights_up, self.weights_down,
+                          self.gain_norm)
+
+    def forward_args(self) -> tuple:
+        return (self.input.devmem, self.weights.devmem,
+                self.weights_up.devmem, self.weights_down.devmem,
+                self.gain_norm.devmem if self.gain_norm else None)
+
+    def xla_forward(self, x, w_g, w_u, w_d, g_norm=None):
+        x32 = x.astype(jnp.float32)
+        m = x32 if g_norm is None \
+            else rms_norm(jnp, x32, g_norm, self.norm_eps)
+        y = gated_mlp(jnp, self.mxu_dot, m.reshape(-1, x.shape[-1]),
+                      w_g, w_u, w_d).reshape(x.shape)
+        return x32 + y if self.residual else y
+
+    def xla_run(self) -> None:
+        args = self.forward_args()
+        if not self.output._tracing:
+            self._traced_vjp = None
+            self.output.devmem = self.xla_forward(*args)
+            return
+        self.output.devmem, self._traced_vjp = jax.vjp(
+            self.xla_forward, *args)
+
+    def _forward_np(self, x):
+        m = (rms_norm(np, x, self.gain_norm.mem, self.norm_eps)
+             if self.pre_norm else x).reshape(-1, x.shape[-1])
+        gate, up = m @ self.weights.mem, m @ self.weights_up.mem
+        y = ((_silu(np, gate) * up) @ self.weights_down.mem).reshape(
+            x.shape)
+        return (x + y if self.residual else y), (m, gate, up)
+
+    def numpy_run(self) -> None:
+        for vec in (self.input, self.weights, self.weights_up,
+                    self.weights_down, self.gain_norm):
+            if vec:
+                vec.map_read()
+        self.output.map_invalidate()
+        self.output.mem[...] = self._forward_np(
+            self.input.mem.astype(np.float32))[0]
+
+
+class GDGatedMLP(GDMoE):
+    """Backward of :class:`GatedMLP`: the stashed pullback, as the
+    expert layer's; the numpy oracle is analytic."""
+
+    MATCHES = (GatedMLP,)
+    EXTRA = ("weights_up", "weights_down", "gain_norm")
+    HAS_AUX = False
+
+    def _cotangent(self, xp, err):
+        return err
+
+    def numpy_run(self) -> None:
+        fwd = self.forward_unit
+        for vec in (self.err_output, self.input):
+            vec.map_read()
+        self.weights.map_write()
+        for _, param, _ in self._extra_pairs():
+            param.map_write()
+        x = self.input.mem.astype(np.float32)
+        _, (m, gate, up) = fwd._forward_np(x)
+        dy = self.err_output.mem.astype(np.float32).reshape(m.shape)
+        grads = {"weights_down": (_silu(np, gate) * up).T @ dy}
+        dgate, dup, dm = _gated_mlp_backward(
+            m, gate, up, dy, fwd.weights.mem, fwd.weights_up.mem,
+            fwd.weights_down.mem)
+        grads["weights_up"] = m.T @ dup
+        dx = dm.reshape(x.shape)
+        if fwd.pre_norm:
+            dx, grads["gain_norm"] = rms_norm_backward(
+                np, x, fwd.gain_norm.mem, fwd.norm_eps, dx)
+        if fwd.residual:
+            dx = dx + dy.reshape(x.shape)
+        if self.need_err_input:
+            self.err_input.map_invalidate()
+            self.err_input.mem[...] = dx
+        self._apply_weights_np(m.T @ dgate)
         for attr, param, acc in self._extra_pairs():
             self._apply_weights_np(grads[attr], vec=param, acc_vec=acc)

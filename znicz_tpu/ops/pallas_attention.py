@@ -153,13 +153,17 @@ _LANES = 8
 _STAT_LANES = 128
 
 
-def _causal_mask(row0, col0, sq: int, sk: int):
+def _causal_mask(row0, col0, sq: int, sk: int, window=None):
     """(sq, sk) visibility of one sub-tile from GLOBAL positions: its
     first row sits at ``row0``, its first column at ``col0`` (traced
-    int32 scalars: offset + grid tile + sub-tile)."""
+    int32 scalars: offset + grid tile + sub-tile).  With a ``window`` a
+    row also stops seeing columns ≤ row − window (the band's lower
+    edge)."""
     rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
     cols = col0 + jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
-    return rows >= cols
+    if window is None:
+        return rows >= cols
+    return (rows >= cols) & (cols > rows - window)
 
 
 def _dot(a, b, trans_a: bool = False, trans_b: bool = False):
@@ -203,7 +207,7 @@ def head_pack_for(n_heads: int, dh: int) -> int:
     return 1
 
 
-def head_layout(n_heads: int, dh: int) -> tuple:
+def head_layout(n_heads: int, dh: int, group: int = 1) -> tuple:
     """``(layout, pack)`` of a call over ``n_heads`` heads of ``dh``:
     ``"boundary"`` where a program's tile is a lane-legal column block
     of the (B, T, H·dh) projection (``pack·dh`` a multiple of 128:
@@ -211,7 +215,9 @@ def head_layout(n_heads: int, dh: int) -> tuple:
     it lies; else ``"head_major"`` (dh 32, 80, 96, 192, an odd head
     count at dh 64: no such block exists, the wrapper transposes).  Static per program:
     the attention unit reports it (info line, ``znicz_flash_layout``)."""
-    pack = head_pack_for(n_heads, dh)
+    # grouped queries: a pair of query heads does not read a pair of
+    # K/V heads, so every head is a program of its own
+    pack = head_pack_for(n_heads, dh) if group == 1 else 1
     if (pack * dh) % _STAT_LANES == 0:
         return "boundary", pack
     return "head_major", pack
@@ -262,7 +268,7 @@ def _sub_edge(block: int, want: int) -> int:
 
 def causal_tile_counts(t_q: int, t_k: int, bq: int, bk: int, sq: int,
                        sk: int, q_off: int = 0, k_off: int = 0,
-                       causal: bool = True) -> dict:
+                       causal: bool = True, window=None) -> dict:
     """How a causal call's (t_q, t_k) rectangle splits into compute
     sub-tiles: ``interior`` (wholly on or under the diagonal),
     ``crossing`` (the diagonal passes through), ``skipped`` (wholly
@@ -271,25 +277,98 @@ def causal_tile_counts(t_q: int, t_k: int, bq: int, bk: int, sq: int,
     (interior + crossing) ÷ all.  The classes are geometry: the kernels
     mask the run of ≤ 2 sub-tiles per block the diagonal CAN cross, so
     with aligned offsets one interior neighbour shares the masked run.
-    ``causal=False`` counts the whole rectangle as interior.  Static
-    per program: the attention unit reports it at ``initialize`` (info
-    line, ``znicz_flash_tiles``)."""
+    ``causal=False`` counts the whole rectangle as interior.  With a
+    ``window`` (row r sees columns in (r − window, r]) a sub-tile
+    wholly below the band is ``skipped`` too, one wholly inside it
+    ``interior``, and ``band_edge`` counts those the band's lower edge
+    passes through and the diagonal does not (computed under the mask,
+    so executed).  Static per program: the attention unit reports it
+    at ``initialize`` (info line, ``znicz_flash_tiles``)."""
     if t_q % bq or t_k % bk or bq % sq or bk % sk:
         raise ValueError(f"({t_q}, {t_k}) / ({bq}, {bk}) / ({sq}, {sk})"
                          f" do not tile")
     counts = {"interior": 0, "crossing": 0, "skipped": 0}
+    if window is not None:
+        counts["band_edge"] = 0
     for r0 in range(q_off, q_off + t_q, sq):
         for c0 in range(k_off, k_off + t_k, sk):
             if causal and r0 + sq - 1 < c0:
                 counts["skipped"] += 1
-            elif not causal or r0 >= c0 + sk - 1:
-                counts["interior"] += 1
-            else:
+            elif window is not None and c0 + sk - 1 <= r0 - window:
+                counts["skipped"] += 1          # below the band
+            elif causal and r0 < c0 + sk - 1:
                 counts["crossing"] += 1
+            elif window is not None and c0 <= r0 + sq - 1 - window:
+                counts["band_edge"] += 1
+            else:
+                counts["interior"] += 1
     total = (t_q // sq) * (t_k // sk)
-    counts["executed_share"] = \
-        (counts["interior"] + counts["crossing"]) / total
+    counts["executed_share"] = 1.0 - counts["skipped"] / total
     return counts
+
+
+def band_share(t: int, window) -> float:
+    """The share of the T × T square a causal row's band covers:
+    Σ_r min(r + 1, window) ÷ T² (the causal half where ``window`` is
+    None or ≥ T)."""
+    w = t if window is None else min(int(window), t)
+    return (w * (w + 1) / 2 + (t - w) * w) / (t * t)
+
+
+# ----------------------------------------------------------------------
+# the window: a grid that visits only the tiles a band touches
+# ----------------------------------------------------------------------
+#: grid tile edge of a windowed call (one body per tile, no sub-tile
+#: walk: a band of 512 is two or three tiles wide)
+BAND_BLOCK = 512
+
+
+def band_blocks(t: int, block_q=None, block_k=None) -> tuple:
+    """Grid tile (bq, bk) of a windowed call: the caller's, else the
+    largest power of two ≤ ``BAND_BLOCK`` that divides T."""
+    edge = BAND_BLOCK
+    while edge > 8 and t % edge:
+        edge //= 2
+    return min(block_q or edge, t), min(block_k or edge, t)
+
+
+def _band_k_first(row0, window: int, bk: int):
+    """The first K tile that rows from ``row0`` on can see."""
+    return jnp.maximum(row0 - window + 1, 0) // bk
+
+
+def _band_q_first(col0, bq: int):
+    """The first Q tile that can see columns from ``col0`` on."""
+    return col0 // bq
+
+
+def band_steps(t: int, bq: int, bk: int, window: int) -> tuple:
+    """``(K tiles a row block's band touches at most, Q tiles a column
+    block's)``: the length of the grid's last axis in the forward and
+    dq kernels, and in dk/dv."""
+    k_steps = max(
+        min(r0 + bq - 1, t - 1) // bk - max(r0 - window + 1, 0) // bk + 1
+        for r0 in range(0, t, bq))
+    q_steps = max(
+        min(c0 + bk + window - 2, t - 1) // bq - c0 // bq + 1
+        for c0 in range(0, t, bk))
+    return k_steps, q_steps
+
+
+def _band_visit(body, row0, col0, rows: int, cols: int, window: int,
+                cols_outer: bool = False, live=True):
+    """One grid tile of a windowed call = one visit: skipped where the
+    band misses it (or the step is past the sequence's end: ``live``),
+    with no mask code where it lies wholly inside."""
+    visible = (row0 + rows - 1 >= col0) \
+        & (col0 + cols - 1 > row0 - window) & live
+    interior = (row0 >= col0 + cols - 1) \
+        & (col0 > row0 + rows - 1 - window)
+    extent = rows if cols_outer else cols
+    pl.when(visible & interior)(
+        functools.partial(body, 0, [(0, extent, False)]))
+    pl.when(visible & jnp.logical_not(interior))(
+        functools.partial(body, 0, [(0, extent, True)]))
 
 
 def _row_walk_bounds(d, sq: int, sk: int, bk: int):
@@ -395,16 +474,39 @@ def _loop(n: int, visit):
         jax.lax.fori_loop(0, n, lambda i, _: visit(i), None)
 
 
+def _k_col0(koff_ref, row0, ik, bk: int, window):
+    """First column of the K tile at step ``ik`` of the grid's last
+    axis: the tiles in turn, or (windowed) those the band of the row
+    block at ``row0`` touches, the first of them at step 0."""
+    if window is None:
+        return koff_ref[0, 0] + ik * bk
+    return (_band_k_first(row0, window, bk) + ik) * bk
+
+
+def _fold_rows(body, causal: bool, row0, col0, bq: int, bk: int, sq: int,
+               sk: int, window, cols_outer: bool = False, live=True):
+    """Run ``body`` over what a grid tile holds of the visible region:
+    the causal sub-tile walk, or one visit of a windowed call's tile."""
+    if window is not None:
+        _band_visit(body, row0, col0, bq, bk, window, cols_outer, live)
+        return
+    visible = True if not causal else row0 + bq - 1 >= col0
+
+    @pl.when(visible)
+    def _fold():
+        _walk(body, causal, row0, col0, bq, bk, sq, sk, cols_outer)
+
+
 # ----------------------------------------------------------------------
 # forward
 # ----------------------------------------------------------------------
 def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, causal, bq, bk, sq, sk,
-                pack):
+                pack, window=None):
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
     row0 = qoff_ref[0, 0] + iq * bq
-    col0 = koff_ref[0, 0] + ik * bk
+    col0 = _k_col0(koff_ref, row0, ik, bk, window)
 
     @pl.when(ik == 0)
     def _init():
@@ -419,8 +521,8 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         q_all = q_ref[rs, :]
         d = q_all.shape[1]
         dh, sw = d // pack, _STAT_LANES // pack
-        masks = [_causal_mask(row0 + r, col0 + c, sq, n) if masked
-                 else None for c, n, masked in parts]
+        masks = [_causal_mask(row0 + r, col0 + c, sq, n, window)
+                 if masked else None for c, n, masked in parts]
         m_all, l_all, acc_all = m_scr[rs, :], l_scr[rs, :], acc_scr[rs, :]
         m_out, l_out, acc_out = [], [], []
         for p in range(pack):           # static: per-sub-head math
@@ -457,11 +559,7 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_scr[rs, :] = jnp.concatenate(l_out, axis=1)
         acc_scr[rs, :] = jnp.concatenate(acc_out, axis=1)
 
-    visible = True if not causal else row0 + bq - 1 >= col0
-
-    @pl.when(visible)
-    def _fold():
-        _walk(body, causal, row0, col0, bq, bk, sq, sk)
+    _fold_rows(body, causal, row0, col0, bq, bk, sq, sk, window)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -502,11 +600,11 @@ def _p_tile(q, k, lse_col, scale, mask):
 
 def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                lse_ref, delta_ref, dq_ref, dq_scr, *, scale, causal,
-               bq, bk, sq, sk, pack):
+               bq, bk, sq, sk, pack, window=None):
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
     row0 = qoff_ref[0, 0] + iq * bq
-    col0 = koff_ref[0, 0] + ik * bk
+    col0 = _k_col0(koff_ref, row0, ik, bk, window)
 
     @pl.when(ik == 0)
     def _init():
@@ -524,7 +622,7 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
             ls = slice(p * _LANES, p * _LANES + 1)
             acc = None
             for c, n, masked in parts:
-                mask = (_causal_mask(row0 + r, col0 + c, sq, n)
+                mask = (_causal_mask(row0 + r, col0 + c, sq, n, window)
                         if masked else None)
                 k, v = k_ref[c:c + n, fs], v_ref[c:c + n, fs]
                 pt = _p_tile(q_all[:, fs], k, lse[:, ls], scale, mask)
@@ -535,11 +633,7 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
             out.append(acc)
         dq_scr[rs, :] += jnp.concatenate(out, axis=1)
 
-    visible = True if not causal else row0 + bq - 1 >= col0
-
-    @pl.when(visible)
-    def _fold():
-        _walk(body, causal, row0, col0, bq, bk, sq, sk)
+    _fold_rows(body, causal, row0, col0, bq, bk, sq, sk, window)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -548,8 +642,13 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
 
 def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                 lse_ref, delta_ref, *rest, scale, causal, bq, bk, sq,
-                sk, pack, shared):
-    """``shared`` (None, or the first column blocks (k, v) of dk and
+                sk, pack, shared, window=None, q_steps=None, q_tiles=None):
+    """``q_steps`` (None, or the Q tiles one query head brings to the
+    grid's last axis): grouped queries and a window make that axis
+    something else than the Q tiles in turn — it runs over every query
+    head of this K/V head's group, each with the Q tiles its band
+    touches (all of them without a window), and dk, dv accumulate over
+    the lot.  ``shared`` (None, or the first column blocks (k, v) of dk and
     dv): both are column blocks of ONE result — the cotangent of a
     fused projection, which the dq call has begun and hands in aliased.
     A kernel's blocked output is one block per grid step, so the two
@@ -559,12 +658,17 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
     else:               # the aliased operand itself is never read
         _, out_ref, dk_scr, dv_scr, dk_tile, dv_tile, sems = rest
     batch, head = pl.program_id(0), pl.program_id(1)
-    ik, iq = pl.program_id(2), pl.program_id(3)
+    ik, step = pl.program_id(2), pl.program_id(3)
     nq = pl.num_programs(3)
-    row0 = qoff_ref[0, 0] + iq * bq
     col0 = koff_ref[0, 0] + ik * bk
+    iq = step if q_steps is None else step % q_steps
+    live = True
+    if window is not None:      # a band's last steps may pass the end
+        iq = _band_q_first(col0, bq) + iq
+        live = iq < q_tiles
+    row0 = qoff_ref[0, 0] + iq * bq
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
@@ -581,7 +685,7 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
             ls = slice(p * _LANES, p * _LANES + 1)
             dk = dv = None
             for r, n, masked in parts:
-                mask = (_causal_mask(row0 + r, col0 + c, n, sk)
+                mask = (_causal_mask(row0 + r, col0 + c, n, sk, window)
                         if masked else None)
                 q, do = q_ref[r:r + n, fs], do_ref[r:r + n, fs]
                 pt = _p_tile(q, k_all[:, fs],
@@ -599,13 +703,10 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         dk_scr[cs, :] += jnp.concatenate(dk_out, axis=1)
         dv_scr[cs, :] += jnp.concatenate(dv_out, axis=1)
 
-    visible = True if not causal else row0 + bq - 1 >= col0
+    _fold_rows(body, causal, row0, col0, bq, bk, sq, sk, window,
+               cols_outer=True, live=live)
 
-    @pl.when(visible)
-    def _fold():
-        _walk(body, causal, row0, col0, bq, bk, sq, sk, cols_outer=True)
-
-    @pl.when(iq == nq - 1)
+    @pl.when(step == nq - 1)
     def _finish():
         if shared is None:
             dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
@@ -625,45 +726,88 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
             copy.wait()
 
 
-def _tile(rows: int, width: int, col0, rows_of):
+def _tile(rows: int, width: int, col0, at):
     """``BlockSpec`` of one head's (rows, width) tile at grid position
-    (batch, head, i, j); ``rows_of(i, j)`` is its row block.  ``col0``
-    None: the operand is head-major (B, H, T, width).  Else it is in
-    the boundary layout (B, T, C) and the tile is column block
+    (batch, head, i, j); ``at(h, i, j)`` is its (head, row block).
+    ``col0`` None: the operand is head-major (B, H, T, width).  Else it
+    is in the boundary layout (B, T, C) and the tile is column block
     ``col0 + head`` — an address, so nothing is moved to where the
     kernel could have fetched it.  Leading dims are squeezed: the
     kernels see (rows, width) either way."""
     if col0 is None:
-        return pl.BlockSpec((None, None, rows, width),
-                            lambda b, h, i, j: (b, h, rows_of(i, j), 0))
-    return pl.BlockSpec((None, rows, width),
-                        lambda b, h, i, j: (b, rows_of(i, j), col0 + h))
+        def head_major(b, h, i, j):
+            head, block = at(h, i, j)
+            return b, head, block, 0
+        return pl.BlockSpec((None, None, rows, width), head_major)
+
+    def boundary(b, h, i, j):
+        head, block = at(h, i, j)
+        return b, block, col0 + head
+    return pl.BlockSpec((None, rows, width), boundary)
 
 
-def _first(i, j):
-    """The row block of a tile that follows the grid's third axis."""
-    return i
+def _first(h, i, j):
+    """The tile of the grid's own head whose row block follows the
+    grid's third axis."""
+    return h, i
 
 
-def _second(i, j):
+def _second(h, i, j):
     """… or its last."""
-    return j
+    return h, j
+
+
+def _k_side(group: int, window, t_k: int, bq: int, bk: int):
+    """Where the forward and dq kernels (grid: batch, QUERY head, Q
+    tile, step) find a K/V tile: the K/V head of the query head's group
+    and the step's tile — windowed, the step-th tile of the Q tile's
+    band, held at the last tile where the band has fewer (the kernel
+    skips the repeat, and a repeated block is not fetched again)."""
+    if group == 1 and window is None:
+        return _second
+
+    def at(h, i, j):
+        if window is not None:
+            j = jnp.minimum(_band_k_first(i * bq, window, bk) + j,
+                            t_k // bk - 1)
+        return h // group, j
+    return at
+
+
+def _q_side(group: int, window, q_steps: int, t_q: int, bq: int, bk: int):
+    """Where the dk/dv kernel (grid: batch, K/V head, K tile, step)
+    finds a tile of q, do, lse, delta: the step runs over the query
+    heads of the group, each with ``q_steps`` Q tiles — all of them,
+    or (windowed) those that can see the K tile."""
+    if group == 1 and window is None:
+        return _second
+
+    def at(h, i, j):
+        block = j % q_steps
+        if window is not None:
+            block = jnp.minimum(_band_q_first(i * bk, bq) + block,
+                                t_q // bq - 1)
+        return h * group + j // q_steps, block
+    return at
 
 
 def _operands(arrays, cols):
-    """(q, k, v), (batch, head programs, T_q, T_k, tile width) and the
-    three first column blocks of a call's ``arrays``.  ``cols`` None:
-    three head-major (B, Hp, T, width) arrays, no column blocks.  Else
-    ``cols`` = (head programs, width) of boundary-layout (B, T, C)
-    operands: three arrays, each from its block 0 on, or ONE projection
-    result whose column ranges are q, k, v in turn."""
+    """(q, k, v), (batch, query-head programs, K/V-head programs, T_q,
+    T_k, tile width) and the three first column blocks of a call's
+    ``arrays``.  ``cols`` None: three head-major (B, Hp, T, width)
+    arrays, no column blocks.  Else ``cols`` = (query-head programs,
+    width, K/V-head programs) of boundary-layout (B, T, C) operands:
+    three arrays, each from its block 0 on, or ONE projection result
+    whose column ranges are q, k, v in turn."""
     q, k, v = arrays if len(arrays) == 3 else arrays * 3
     if cols is None:
         b, h, t, d = q.shape
-        return (q, k, v), (b, h, t, k.shape[2], d), (None,) * 3
-    h, d = cols
-    first = (0, 0, 0) if len(arrays) == 3 else (0, h, 2 * h)
-    return (q, k, v), (q.shape[0], h, q.shape[1], k.shape[1], d), first
+        return (q, k, v), (b, h, k.shape[1], t, k.shape[2], d), \
+            (None,) * 3
+    h, d, h_kv = cols
+    first = (0, 0, 0) if len(arrays) == 3 else (0, h, h + h_kv)
+    return (q, k, v), (q.shape[0], h, h_kv, q.shape[1], k.shape[1], d), \
+        first
 
 
 # jitted: every layer of a model calls these with the same static
@@ -671,30 +815,39 @@ def _operands(arrays, cols):
 # program, not once per layer (on the chip's host, lowering the LM
 # cell's 18 flash calls cost 7 s of every start, cached program or not;
 # PERF.md §6, PR 24)
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _fwd_call(arrays, q_off, k_off, causal, bq, bk, interpret, pack,
-              sub=None, cols=None):
+              sub=None, cols=None, window=None):
     """``arrays``: (q, k, v) head-major (B, Hp, T, pack·dh) where
     ``cols`` is None; else boundary-layout (B, T, C) operands, ``cols``
-    = (head programs, tile width) — three arrays, or ONE that holds all
-    three (:func:`_operands`).  Returns
-    (out, lse): out in the operands' layout ((B, T, heads·width) for
-    the boundary layout), lse head-major (B, Hp, T, pack·_LANES)."""
-    (q, k, v), (b, h, t, tk, d), (cq, ck, cv) = _operands(arrays, cols)
+    = (head programs, tile width, K/V head programs) — three arrays,
+    or ONE that holds all three (:func:`_operands`).  Fewer K/V heads
+    than query heads are grouped queries: query head h reads K/V head
+    h // group.  ``window``: row r sees columns in (r − window, r] and
+    the grid's last axis visits only the K tiles a band touches.
+    Returns (out, lse): out in the operands' layout ((B, T,
+    heads·width) for the boundary layout), lse head-major (B, Hp, T,
+    pack·_LANES)."""
+    (q, k, v), (b, h, h_kv, t, tk, d), (cq, ck, cv) = \
+        _operands(arrays, cols)
     nq, nk = t // bq, tk // bk
     sq, sk = sub or sub_tile_for(causal, bq, bk)
-    kernel = functools.partial(_fwd_kernel,
-                               scale=1.0 / np.sqrt(d // pack),
-                               causal=causal, bq=bq, bk=bk, sq=sq,
-                               sk=sk, pack=pack)
+    static = dict(scale=1.0 / np.sqrt(d // pack), causal=causal, bq=bq,
+                  bk=bk, sq=sq, sk=sk, pack=pack)
+    name = "znicz_flash_fwd"
+    if window is not None:
+        nk = band_steps(t, bq, bk, window)[0]
+        static.update(sq=bq, sk=bk, window=window)
+        name += "_win"
+    k_at = _k_side(h // h_kv, window, tk, bq, bk)
     off_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     lanes = pack * _LANES
     out_shape = (b, h, t, d) if cols is None else (b, t, h * d)
     return pl.pallas_call(
-        kernel,
+        functools.partial(_fwd_kernel, **static),
         grid=(b, h, nq, nk),        # q rows follow axis 2, k rows axis 3
         in_specs=[off_spec, off_spec, _tile(bq, d, cq, _first),
-                  _tile(bk, d, ck, _second), _tile(bk, d, cv, _second)],
+                  _tile(bk, d, ck, k_at), _tile(bk, d, cv, k_at)],
         out_specs=(_tile(bq, d, None if cols is None else 0, _first),
                    _tile(bq, lanes, None, _first)),
         out_shape=(jax.ShapeDtypeStruct(out_shape, q.dtype),
@@ -706,13 +859,13 @@ def _fwd_call(arrays, q_off, k_off, causal, bq, bk, interpret, pack,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-        name="znicz_flash_fwd",
+        name=name,
     )(q_off, k_off, q, k, v)
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12))
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12, 13))
 def _bwd_call(arrays, lse, do, delta4, q_off, k_off, causal, bq, bk,
-              interpret, pack, sub=None, cols=None):
+              interpret, pack, sub=None, cols=None, window=None):
     """Cotangents of ``arrays`` (see :func:`_fwd_call`), in their
     layout: (dq, dk, dv), or for ONE fused array its one cotangent —
     the dq call writes q's column blocks of a (B, T, C) result, the
@@ -721,9 +874,14 @@ def _bwd_call(arrays, lse, do, delta4, q_off, k_off, causal, bq, bk,
     projection's backward.  ``do`` is in the layout of the forward's out;
     ``delta4``: (B, Hp, T, pack) f32 — rowsum(do·o) per SUB-head,
     already adjusted for any lse cotangent (the hop composition's
-    extra term)."""
-    (q, k, v), (b, h, t, tk, d), (cq, ck, cv) = _operands(arrays, cols)
+    extra term).  With grouped queries the dk/dv grid is over the K/V
+    heads, and its last axis over the group's query heads and their Q
+    tiles: a K/V head's dk, dv accumulate in VMEM over all of them and
+    are written once."""
+    (q, k, v), (b, h, h_kv, t, tk, d), (cq, ck, cv) = \
+        _operands(arrays, cols)
     shared = len(arrays) == 1
+    group = h // h_kv
     nq, nk = t // bq, tk // bk
     sq, sk = sub or sub_tile_for(causal, bq, bk)
     lanes = pack * _LANES
@@ -731,6 +889,11 @@ def _bwd_call(arrays, lse, do, delta4, q_off, k_off, causal, bq, bk,
     delta = jnp.repeat(delta4, _LANES, axis=-1)      # (B, H, T, lanes)
     static = dict(scale=1.0 / np.sqrt(d // pack), causal=causal, bq=bq,
                   bk=bk, sq=sq, sk=sk, pack=pack)
+    k_steps, q_steps, suffix = nk, nq, ""
+    if window is not None:
+        k_steps, q_steps = band_steps(t, bq, bk, window)
+        static.update(sq=bq, sk=bk, window=window)
+        suffix = "_win"
     off_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
@@ -740,35 +903,40 @@ def _bwd_call(arrays, lse, do, delta4, q_off, k_off, causal, bq, bk,
     def like(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype)
 
-    def specs(q_rows, k_rows):
-        return [off_spec, off_spec, _tile(bq, d, cq, q_rows),
-                _tile(bk, d, ck, k_rows), _tile(bk, d, cv, k_rows),
-                _tile(bq, d, c_do, q_rows),
-                _tile(bq, lanes, None, q_rows),
-                _tile(bq, lanes, None, q_rows)]
+    def specs(q_at, k_at):
+        return [off_spec, off_spec, _tile(bq, d, cq, q_at),
+                _tile(bk, d, ck, k_at), _tile(bk, d, cv, k_at),
+                _tile(bq, d, c_do, q_at),
+                _tile(bq, lanes, None, q_at),
+                _tile(bq, lanes, None, q_at)]
 
     operands = [q_off, k_off, q, k, v, do, lse, delta]
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **static),
-        grid=(b, h, nq, nk),
-        in_specs=specs(_first, _second),
+        grid=(b, h, nq, k_steps),
+        in_specs=specs(_first, _k_side(group, window, tk, bq, bk)),
         out_specs=_tile(bq, d, cq, _first),
         out_shape=like(q),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
-        name="znicz_flash_dq",
+        name="znicz_flash_dq" + suffix,
     )(*operands)
     # dk/dv: Q blocks innermost; the q-side specs index by the LAST
     # grid dim now, the k-side by dim 2
-    in_specs = specs(_second, _first)
+    in_specs = specs(_q_side(group, window, q_steps, t, bq, bk), _first)
+    if group > 1 or window is not None:
+        static["q_steps"] = q_steps
+    if window is not None:
+        static["q_tiles"] = nq
+    grid = (b, h_kv, nk, group * q_steps)
     accumulators = [pltpu.VMEM((bk, d), jnp.float32),
                     pltpu.VMEM((bk, d), jnp.float32)]
     if shared:
         anywhere = pl.BlockSpec(memory_space=pl.ANY)
         out = pl.pallas_call(
             functools.partial(_dkv_kernel, shared=(ck, cv), **static),
-            grid=(b, h, nk, nq),
+            grid=grid,
             in_specs=in_specs + [anywhere],
             out_specs=anywhere,
             out_shape=like(dq),
@@ -778,19 +946,19 @@ def _bwd_call(arrays, lse, do, delta4, q_off, k_off, causal, bq, bk,
                 pltpu.SemaphoreType.DMA((2,))],
             compiler_params=params,
             interpret=interpret,
-            name="znicz_flash_dkv",
+            name="znicz_flash_dkv" + suffix,
         )(*operands, dq)
         return (out,)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, shared=None, **static),
-        grid=(b, h, nk, nq),
+        grid=grid,
         in_specs=in_specs,
         out_specs=(_tile(bk, d, ck, _first), _tile(bk, d, cv, _first)),
         out_shape=(like(k), like(v)),
         scratch_shapes=accumulators,
         compiler_params=params,
         interpret=interpret,
-        name="znicz_flash_dkv",
+        name="znicz_flash_dkv" + suffix,
     )(*operands)
     return dq, dk, dv
 
@@ -829,9 +997,9 @@ def _delta(do, out, dlse, pack: int, boundary: bool):
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash_pass(arrays, q_off, k_off, causal, bq, bk, interpret, pack,
-                sub=None, cols=None):
+                sub=None, cols=None, window=None):
     """One flash pass at global positions (q_off, k_off) → (out, lse)
     over ``arrays`` as :func:`_fwd_call` takes them: head-major
     (``cols`` None) or addressed in the boundary layout.  This is BOTH
@@ -840,23 +1008,24 @@ def _flash_pass(arrays, q_off, k_off, causal, bq, bk, interpret, pack,
     combination); the lse cotangent folds into delta in the backward,
     so one custom_vjp serves both."""
     return _fwd_call(arrays, q_off, k_off, causal, bq, bk, interpret,
-                     pack, sub, cols)
+                     pack, sub, cols, window)
 
 
 def _pass_fwd(arrays, q_off, k_off, causal, bq, bk, interpret, pack,
-              sub, cols):
+              sub, cols, window):
     out, lse = _fwd_call(arrays, q_off, k_off, causal, bq, bk,
-                         interpret, pack, sub, cols)
+                         interpret, pack, sub, cols, window)
     return (out, lse), (arrays, out, lse, q_off, k_off)
 
 
-def _pass_bwd(causal, bq, bk, interpret, pack, sub, cols, res, cts):
+def _pass_bwd(causal, bq, bk, interpret, pack, sub, cols, window, res,
+              cts):
     arrays, out, lse, q_off, k_off = res
     do, dlse = cts
     do = do.astype(out.dtype)
     delta4 = _delta(do, out, dlse, pack, cols is not None)
     grads = _bwd_call(arrays, lse, do, delta4, q_off, k_off, causal,
-                      bq, bk, interpret, pack, sub, cols)
+                      bq, bk, interpret, pack, sub, cols, window)
     zero = np.zeros((1, 1), jax.dtypes.float0)
     return tuple(grads), zero, zero
 
@@ -896,28 +1065,29 @@ def unpack_heads(x, pack: int, n_heads: int):
 
 
 def _rows_pass(arrays, n_heads: int, q_off, k_off, causal, bq, bk,
-               interpret, sub):
-    """(B, T, ·) operands → (B, T, D) out, by the address their head
+               interpret, sub, n_kv_heads=None, window=None):
+    """(B, T, ·) operands → (B, T, H·dh) out, by the address their head
     width allows (:func:`head_layout`)."""
     fused = len(arrays) == 1
     b, t, c = arrays[0].shape
-    d = c // 3 if fused else c
-    dh = d // n_heads
-    layout, pack = head_layout(n_heads, dh)
+    h_kv = n_kv_heads or n_heads
+    dh = c // (n_heads + 2 * h_kv) if fused else c // n_heads
+    layout, pack = head_layout(n_heads, dh, n_heads // h_kv)
     if layout == "boundary":
+        cols = (n_heads // pack, pack * dh, h_kv // pack)
         return _flash_pass(arrays, q_off, k_off, causal, bq, bk,
-                           interpret, pack, sub,
-                           (n_heads // pack, pack * dh))[0]
+                           interpret, pack, sub, cols, window)[0]
     # a head width that is neither a multiple nor a divisor of the 128
     # lanes: no lane-legal column block, so the tiles are moved
     if fused:
-        arrays = tuple(arrays[0][..., i * d:(i + 1) * d]
-                       for i in range(3))
-    heads = tuple(pack_heads(a.reshape(b, a.shape[1], n_heads, dh), pack)
+        edges = np.cumsum([0, n_heads, h_kv, h_kv]) * dh
+        arrays = tuple(arrays[0][..., lo:hi]
+                       for lo, hi in zip(edges[:-1], edges[1:]))
+    heads = tuple(pack_heads(a.reshape(b, a.shape[1], -1, dh), pack)
                   for a in arrays)
     out = _flash_pass(heads, q_off, k_off, causal, bq, bk, interpret,
-                      pack, sub)[0]
-    return unpack_heads(out, pack, n_heads).reshape(b, t, d)
+                      pack, sub, None, window)[0]
+    return unpack_heads(out, pack, n_heads).reshape(b, t, n_heads * dh)
 
 
 def flash_attention_rows(arrays, n_heads: int, causal: bool = False,
@@ -925,12 +1095,24 @@ def flash_attention_rows(arrays, n_heads: int, causal: bool = False,
                          block_k: int | None = None,
                          dot_dtype=None, interpret: bool = False,
                          mesh=None, spec=None, q_offset=None,
-                         k_offset=None, sub_tile=None):
+                         k_offset=None, sub_tile=None,
+                         n_kv_heads: int | None = None,
+                         window: int | None = None):
     """Fused flash attention in the projections' own layout:
     ``arrays`` is ``(qkv,)`` — ONE (B, T, 3·D) projection result whose
     column ranges are q, k, v — or ``(q, k, v)``, each (B, T, D); the
     result is (B, T, D) in their dtype, ready for the out-projection as
-    (B·T, D).  Heads are column blocks (module docstring, "Layout
+    (B·T, D).
+
+    ``n_kv_heads`` < ``n_heads`` is grouped queries: k and v are
+    (B, T, n_kv_heads·dh) — the fused array (B, T, (H + 2·H_kv)·dh) —
+    and query head h reads K/V head h // (H / H_kv); nothing is
+    repeated in memory, the kernels' index maps share the tiles, and
+    dk/dv sum over a group inside the kernel.  ``window`` (causal
+    only): row r attends to columns in (r − window, r]; the kernels'
+    grids visit only the tiles a band touches and the three
+    ``pallas_call``s are named ``znicz_flash_*_win``.  A window that
+    covers the sequence is the causal call.  Heads are column blocks (module docstring, "Layout
     contract"): nothing is transposed, sliced or concatenated on the
     way in or out, forward or backward; a fused array's cotangent is
     ONE (B, T, 3·D) array.
@@ -964,7 +1146,21 @@ def flash_attention_rows(arrays, n_heads: int, causal: bool = False,
     batch element and head, so no cross-shard reduction exists).
     """
     t, tk = arrays[0].shape[1], arrays[-1].shape[1]
-    bq, bk = grid_blocks(causal, t, tk, block_q, block_k)
+    if window is not None and window >= tk:
+        window = None
+    if window is not None:
+        if not causal or t != tk or q_offset is not None \
+                or k_offset is not None or window < 1:
+            raise ValueError(
+                f"a window ({window}) needs a causal self-attention "
+                f"call without global offsets")
+        bq, bk = band_blocks(t, block_q, block_k)
+        sub_tile = None
+    else:
+        bq, bk = grid_blocks(causal, t, tk, block_q, block_k)
+    if n_kv_heads is not None and n_heads % n_kv_heads:
+        raise ValueError(f"{n_heads} query heads do not divide over "
+                         f"{n_kv_heads} K/V heads")
     if t % bq or tk % bk:
         raise ValueError(f"T {t}/{tk} not divisible by blocks "
                          f"({bq}, {bk})")
@@ -994,13 +1190,15 @@ def flash_attention_rows(arrays, n_heads: int, causal: bool = False,
         fn = jax.shard_map(
             lambda *shard: _rows_pass(
                 shard, n_heads // shards, _off_arr(None),
-                _off_arr(None), causal, bq, bk, interpret, sub_tile),
+                _off_arr(None), causal, bq, bk, interpret, sub_tile,
+                None if n_kv_heads is None else n_kv_heads // shards,
+                window),
             mesh=mesh, in_specs=(rspec,) * len(arrays),
             out_specs=rspec, check_vma=False)
         return fn(*arrays)
     return _rows_pass(arrays, n_heads, _off_arr(q_offset),
                       _off_arr(k_offset), causal, bq, bk, interpret,
-                      sub_tile)
+                      sub_tile, n_kv_heads, window)
 
 
 def flash_attention(q, k, v, **kwargs):
@@ -1008,7 +1206,9 @@ def flash_attention(q, k, v, **kwargs):
     (B, T, H, D) f32: at the boundary the heads are adjacent to D, so
     both reshapes are free."""
     b, t, h, d = q.shape
+    if k.shape[2] != h:
+        kwargs["n_kv_heads"] = k.shape[2]
     out = flash_attention_rows(
-        tuple(a.reshape(a.shape[0], a.shape[1], h * d)
+        tuple(a.reshape(a.shape[0], a.shape[1], -1)
               for a in (q, k, v)), h, **kwargs)
     return out.reshape(b, t, h, d).astype(jnp.float32)

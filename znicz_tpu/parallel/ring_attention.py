@@ -47,10 +47,15 @@ def _visibility(tq: int, tk: int, q_pos=None, k_pos=None):
     return (q_pos[:, None] >= k_pos[None, :])[None, None]
 
 
-def local_attention(q, k, v, causal: bool = False, dot_dtype=None):
+def local_attention(q, k, v, causal: bool = False, dot_dtype=None,
+                    window=None):
     """Single-device softmax attention — the oracle.
 
-    Shapes: q (B, Tq, H, D), k/v (B, Tk, H, D) → (B, Tq, H, D).
+    Shapes: q (B, Tq, H, D), k/v (B, Tk, H_kv, D) → (B, Tq, H, D).
+    Grouped queries (H a multiple of H_kv; query head h reads K/V head
+    ``h // (H / H_kv)``) go through an einsum over the group, K and V
+    not repeated; a causal ``window`` also hides columns ≤ row − window.
+    Both unset, the function traces what it always has.
 
     ``dot_dtype`` (e.g. ``jnp.bfloat16``) casts the GEMM operands AND
     the materialized (T, T) score/probability tensors to that dtype —
@@ -65,12 +70,25 @@ def local_attention(q, k, v, causal: bool = False, dot_dtype=None):
     d = q.shape[-1]
     if dot_dtype is not None:
         q, k, v = (a.astype(dot_dtype) for a in (q, k, v))
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) / np.sqrt(d)
+    b, tq, h, _ = q.shape
+    tk, h_kv = k.shape[1], k.shape[2]
+    group = h // h_kv
+    if group > 1:
+        s = jnp.einsum("bqhgd,bkhd->bhgqk",
+                       q.reshape(b, tq, h_kv, group, d), k,
+                       preferred_element_type=jnp.float32
+                       ).reshape(b, h, tq, tk) / np.sqrt(d)
+    else:
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                       preferred_element_type=jnp.float32) / np.sqrt(d)
     if causal:
-        tq, tk = q.shape[1], k.shape[1]
-        mask = (jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :])
+        rows, cols = jnp.arange(tq)[:, None], jnp.arange(tk)[None, :]
+        mask = rows >= cols
+        if window is not None:
+            mask = mask & (cols > rows - window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
+    elif window is not None:
+        raise ValueError("a window needs causal attention")
     if dot_dtype is not None:
         # stabilized softmax with the big (T, T) tensors STORED in
         # dot_dtype; exp/normalizer math in f32
@@ -81,6 +99,11 @@ def local_attention(q, k, v, causal: bool = False, dot_dtype=None):
         p = (e / e.sum(axis=-1, keepdims=True)).astype(dot_dtype)
     else:
         p = jax.nn.softmax(s, axis=-1)
+    if group > 1:
+        return jnp.einsum("bhgqk,bkhd->bqhgd",
+                          p.reshape(b, h_kv, group, tq, tk), v,
+                          preferred_element_type=jnp.float32
+                          ).reshape(b, tq, h, d)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v,
                      preferred_element_type=jnp.float32)
     return out
