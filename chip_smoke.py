@@ -11,9 +11,10 @@ block, weights are random from a seed):
   ``export_forward``;
 - **kernels**: the sequence stack (attention → layer_norm → softmax) at
   T=2048, batch 16, bf16, 8 heads of 64 (non-causal, causal) and of
-  128 (causal): a few TRAINING steps, so the flash forward, dq, dk/dv
-  and both layer-norm kernels go through Mosaic, and one forward
-  compared with the XLA cores on the same device;
+  128 (causal): a few TRAINING steps, so the flash forward, both forms
+  of its backward (dq + dk/dv non-causal, the one pass causal) and both
+  layer-norm kernels go through Mosaic, and one forward compared with
+  the XLA cores on the same device;
 - **serve**: the exported AlexNet behind ``ServingEngine`` (ragged
   requests of 1, 3, 8 rows, checked against the numpy oracle), then an
   LM (embedding → pos_encoding → causal attention → last_token →

@@ -165,9 +165,9 @@ def test_uneven_tiles_and_a_band_that_ends_past_the_sequence():
 
 def test_a_window_is_named_and_refuses_what_it_cannot_do():
     q = _rand((1, BAND_T, 2, 16), 1)
-    def loss(q, window):
+    def loss(q, window, block_k=16):
         return jnp.sum(pa.flash_attention(
-            q, q, q, causal=True, block_q=16, block_k=16,
+            q, q, q, causal=True, block_q=16, block_k=block_k,
             interpret=True, window=window))
 
     banded = str(jax.make_jaxpr(jax.grad(lambda q: loss(q, 8)))(q))
@@ -175,6 +175,15 @@ def test_a_window_is_named_and_refuses_what_it_cannot_do():
     for name in ("znicz_flash_fwd", "znicz_flash_dq", "znicz_flash_dkv"):
         assert name + "_win" in banded and name + "_win" not in plain
         assert name in plain
+    # under ONE K tile the causal call's backward is one kernel; a
+    # window keeps its dq and dk/dv kernels there too
+    banded = str(jax.make_jaxpr(jax.grad(
+        lambda q: loss(q, 8, BAND_T)))(q))
+    plain = str(jax.make_jaxpr(jax.grad(
+        lambda q: loss(q, None, BAND_T)))(q))
+    assert "znicz_flash_bwd" in plain and "znicz_flash_dq" not in plain
+    assert "znicz_flash_bwd" not in banded
+    assert "znicz_flash_dq_win" in banded and "znicz_flash_dkv_win" in banded
     with pytest.raises(ValueError, match="window"):
         pa.flash_attention(q, q, q, causal=False, interpret=True,
                            window=8)

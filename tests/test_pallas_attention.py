@@ -6,6 +6,8 @@ plain-XLA oracle to float32 tolerance, causal and not, across block
 geometries including partial diagonal tiles.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -389,6 +391,231 @@ def test_tile_schedule_is_derived_from_the_shapes():
     assert schedule(True, 256, block_q=128, block_k=128) \
         == ((128, 128), (128, 128))
     assert sub_tile_for(True, 16, 16) == (16, 16)
+
+
+# ----------------------------------------------------------------------
+# the one-pass backward: dq accumulates in the dk/dv walk
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("causal,t_k,bk,window,want", [
+    (True, 2048, 2048, None, (2048, 1)),    # attn_lm_train_t2048
+    (True, 4096, 2048, None, (4096, 1)),    # olmoe_train_t4096, Laguna's
+                                            # full layers: K taken whole
+    (True, 8192, 2048, None, (2048, 2)),    # past WHOLE_BLOCK_K
+    (True, 16384, 2048, None, (2048, 2)),
+    (True, 3072, 1024, None, (1024, 2)),    # 2048 does not tile T
+    (True, 4096, 1024, None, (1024, 2)),    # a caller's (the ring's) tile
+    (True, 1024, 1024, None, (1024, 1)),
+    (True, 4096, 512, 512, (512, 2)),       # Laguna's sliding layers
+    (True, 4096, 2048, 512, (2048, 2)),
+    (False, 2048, 1024, None, (1024, 2)),   # non-causal
+    (False, 1024, 1024, None, (1024, 2)),
+])
+def test_backward_tile_and_passes_of_the_cells(causal, t_k, bk, window,
+                                               want):
+    """The backward's K tile and its passes at the chooser's tiles, next
+    to ``grid_blocks`` / ``sub_tile_for``: from ``causal``, T_k, the
+    forward's K tile and the window alone."""
+    from znicz_tpu.ops import pallas_attention as pa
+    if window is None:
+        assert pa.grid_blocks(causal, t_k, t_k, block_k=bk)[1] == bk
+    assert (pa.backward_block_k(causal, t_k, bk, window),
+            pa.backward_passes(causal, t_k, bk, window)) == want
+
+
+def _kernel_names(fn, *args) -> list:
+    """The ``name`` of every ``pallas_call`` ``fn`` traces, in order."""
+    return re.findall(r"name=(znicz_flash_\w+)",
+                      str(jax.make_jaxpr(fn)(*args)))
+
+
+#: (K tiles, causal, window, query heads per K/V head) → passes over
+#: the score tiles in the backward, and the kernels the program holds
+PASSES = [
+    (1, True, None, 1, 1),      # the LM cell: T 2048 under a 2048 K tile
+    (1, True, None, 6, 1),      # grouped queries change nothing
+    (2, True, None, 1, 2),      # a caller's shorter K tiles
+    (2, True, None, 6, 2),
+    (4, True, None, 1, 2),
+    (1, False, None, 1, 2),     # non-causal: the two kernels
+    (1, True, 24, 1, 2),        # a window: a Q tile meets two K tiles
+    (4, True, 24, 6, 2),
+    (1, True, 64, 6, 1),        # a window that covers T is the causal call
+]
+
+
+@pytest.mark.parametrize("nk,causal,window,group,passes", PASSES)
+def test_backward_passes_are_read_from_the_shapes(nk, causal, window,
+                                                  group, passes):
+    """The rule (``backward_passes``) and the program it gives: ONE
+    ``znicz_flash_bwd`` where a causal, un-windowed call's K side is one
+    grid tile, else ``znicz_flash_dq`` + ``znicz_flash_dkv`` — no
+    argument but the call's own shapes enters."""
+    from znicz_tpu.ops import pallas_attention as pa
+    t, dh, bk = 64, 16, 64 // nk
+    live = window if window is not None and window < t else None
+    assert pa.backward_passes(causal, t, bk, live) == passes
+    q = _rand((1, t, group, dh), 0)
+    k = _rand((1, t, 1, dh), 1)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=16,
+                               block_k=bk, interpret=True,
+                               window=window).sum()
+
+    names = _kernel_names(jax.grad(loss, argnums=(0, 1, 2)), q, k, k)
+    win = "_win" if live is not None else ""
+    assert names == [f"znicz_flash_fwd{win}"] + (
+        ["znicz_flash_bwd"] if passes == 1 else
+        [f"znicz_flash_dq{win}", f"znicz_flash_dkv{win}"])
+
+
+#: shape → (query heads, K/V heads, dh, one fused array?): the pair body
+#: with ONE cotangent array (the LM cell's form), dh 128 apart (OLMoE's),
+#: six query heads on a K/V head apart (Laguna's) and fused
+ONE_PASS = {
+    "pair_fused": (4, 4, 64, True),
+    "dh128_apart": (2, 2, 128, False),
+    "gqa6_apart": (12, 2, 128, False),
+    "gqa6_fused": (6, 1, 128, True),
+    "gqa2_head_major": (4, 2, 32, False),
+}
+
+
+@pytest.mark.parametrize("shape", list(ONE_PASS))
+def test_one_pass_backward_matches_the_core_and_the_two_kernels(shape):
+    """dq, dk, dv of the one-pass call (K tile = T: two Q tiles walk it
+    in 32² sub-tiles, interior, crossing and skipped) against the
+    plain-XLA core, and against the two-kernel call the same operands
+    get under two K tiles — only the order of dq's f32 partial sums
+    differs between those."""
+    from znicz_tpu.ops.pallas_attention import flash_attention_rows
+    h, h_kv, dh, fused = ONE_PASS[shape]
+    b, t, group = 2, 128, h // h_kv
+    widths = [h * dh, h_kv * dh, h_kv * dh]
+    edges = np.cumsum([0] + widths)
+    x = _rand((b, t, edges[-1]), 21)
+    dy = _rand((b, t, h * dh), 22)
+
+    def split(x):
+        return tuple(x[..., lo:hi] for lo, hi in zip(edges[:-1], edges[1:]))
+
+    def core(x):
+        q, k, v = (a.reshape(b, t, -1, dh) for a in split(x))
+        k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
+        return local_attention(q, k, v, causal=True).reshape(b, t, -1)
+
+    def kernels(block_k):
+        def run(x):
+            return flash_attention_rows(
+                (x,) if fused else split(x), h, causal=True, block_q=64,
+                block_k=block_k, sub_tile=(32, 32), interpret=True,
+                n_kv_heads=h_kv)
+        return run
+
+    def grad(fn):
+        return jax.grad(lambda x: jnp.vdot(fn(x), dy))
+
+    one, two = kernels(t), kernels(t // 2)
+    assert _kernel_names(grad(one), x) == ["znicz_flash_fwd",
+                                           "znicz_flash_bwd"]
+    assert "znicz_flash_dq" in _kernel_names(grad(two), x)
+    np.testing.assert_allclose(one(x), core(x), atol=2e-5)
+    want, got, apart = grad(core)(x), grad(one)(x), grad(two)(x)
+    for name, (lo, hi) in zip(("dq", "dk", "dv"),
+                              zip(edges[:-1], edges[1:])):
+        np.testing.assert_allclose(got[..., lo:hi], want[..., lo:hi],
+                                   atol=5e-5, err_msg=name)
+        np.testing.assert_allclose(got[..., lo:hi], apart[..., lo:hi],
+                                   atol=1e-5, err_msg=name + " / two")
+
+
+@pytest.mark.parametrize("heads", [(1, 1), (2, 1)], ids=["mha", "gqa2"])
+def test_backward_takes_a_4096_key_range_whole_at_the_choosers_tiles(
+        heads):
+    """T 4096 × dh 128 with NO block named (OLMoE's and Laguna's full
+    layers' call): the forward walks two 2048-long K tiles, the
+    backward takes the keys whole — one ``znicz_flash_bwd`` on four Q
+    tiles of 1024 under (256, 512) sub-tiles — and every gradient
+    matches the core."""
+    h, h_kv = heads
+    t, dh = 4096, 128
+    q = _rand((1, t, h, dh), 41)
+    k, v = _rand((1, t, h_kv, dh), 42), _rand((1, t, h_kv, dh), 43)
+    dy = _rand((1, t, h, dh), 44)
+
+    def core(q, k, v):
+        k, v = (jnp.repeat(a, h // h_kv, axis=2) for a in (k, v))
+        return jnp.vdot(local_attention(q, k, v, causal=True), dy)
+
+    def kernels(q, k, v):
+        return jnp.vdot(flash_attention(q, k, v, causal=True,
+                                        interpret=True), dy)
+
+    assert _kernel_names(jax.grad(kernels, (0, 1, 2)), q, k, v) \
+        == ["znicz_flash_fwd", "znicz_flash_bwd"]
+    for name, got, want in zip(("dq", "dk", "dv"),
+                               jax.grad(kernels, (0, 1, 2))(q, k, v),
+                               jax.grad(core, (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(got, want, atol=5e-5, err_msg=name)
+
+
+def _hop_oracle(q, k, v, q_off, k_off):
+    """(out, lse) of one head-major hop, masked by global position."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    mask = (q_off + jnp.arange(q.shape[2])[:, None]) \
+        >= (k_off + jnp.arange(k.shape[2])[None, :])
+    s = jnp.where(mask, s, -1e30)
+    return (jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v),
+            jax.nn.logsumexp(s, axis=-1))
+
+
+@pytest.mark.parametrize("pack", [1, 2])
+@pytest.mark.parametrize("sub", [None, (8, 8), (16, 8)])
+def test_one_pass_hop_with_offsets_masked_rows_and_an_lse_cotangent(
+        sub, pack):
+    """The ring's hop through the one pass: q rows 8…71 against k
+    columns 40…103 in ONE K tile — the first Q tile (rows 8…39) sees no
+    key at all, the diagonal crosses the second mid-tile — with a
+    cotangent on the hop's lse as the cross-hop combination sends one.
+    Every gradient matches the oracle and the two-kernel hop; the
+    masked rows' dq is exactly 0."""
+    from znicz_tpu.ops.pallas_attention import ring_hop
+    b, hp, t, dh = 2, 2, 64, 16
+    q_off, k_off = 8, 40
+    q, k, v = (_rand((b, hp, t, pack * dh), s) for s in (31, 32, 33))
+    vis = (q_off + np.arange(t)) >= k_off
+    rows = jnp.asarray(vis, jnp.float32)[None, None, :, None]
+    dy = _rand((b, hp, t, pack * dh), 34) * rows
+    dl = _rand((b, hp, t, pack), 35) * rows
+
+    def heads(a):       # (B, Hp, T, pack·dh) → one head per program
+        return a.reshape(b, hp, t, pack, dh).transpose(0, 1, 3, 2, 4) \
+            .reshape(b, hp * pack, t, dh)
+
+    def core(q, k, v):
+        out, lse = _hop_oracle(heads(q), heads(k), heads(v), q_off, k_off)
+        lse = jnp.where(rows > 0, lse.reshape(b, hp, pack, t)
+                        .transpose(0, 1, 3, 2), 0.0)
+        return jnp.vdot(out, heads(dy)) + jnp.vdot(lse, dl)
+
+    def hop(block_k):
+        def loss(q, k, v):
+            out, lse = ring_hop(q, k, v, q_off, k_off, True, 32, block_k,
+                                interpret=True, pack=pack, sub_tile=sub)
+            return jnp.vdot(out, dy) \
+                + jnp.vdot(jnp.where(rows > 0, lse, 0.0), dl)
+        return loss
+
+    assert _kernel_names(jax.grad(hop(t), (0, 1, 2)), q, k, v) \
+        == ["znicz_flash_fwd", "znicz_flash_bwd"]
+    want = jax.grad(core, (0, 1, 2))(q, k, v)
+    got = jax.grad(hop(t), (0, 1, 2))(q, k, v)
+    apart = jax.grad(hop(t // 2), (0, 1, 2))(q, k, v)
+    for name, a, w, two in zip(("dq", "dk", "dv"), got, want, apart):
+        np.testing.assert_allclose(a, w, atol=5e-5, err_msg=name)
+        np.testing.assert_allclose(a, two, atol=1e-5,
+                                   err_msg=name + " / two")
+    assert np.all(np.asarray(got[0])[:, :, ~vis] == 0.0)
 
 
 def test_unit_engages_flash_only_on_tpu(monkeypatch):

@@ -332,6 +332,12 @@ def test_head_pack_gate(monkeypatch, caplog):
     assert ('znicz_flash_layout{unit="%s",layout="boundary",pack="2",'
             'kv_group="1"} 1' % unit.name) \
         in obs_metrics.REGISTRY.to_prometheus()
+    # non-causal: the backward keeps its dq and dk/dv kernels
+    assert unit._flash_backward == 2
+    assert "backward passes 2" in caplog.text
+    assert obs_metrics.flash_backward(unit.name, 2).value == 1
+    assert 'znicz_flash_backward{unit="%s",passes="2"} 1' % unit.name \
+        in obs_metrics.REGISTRY.to_prometheus()
     unit = _attention_unit(XLADevice(), d=256, heads=2)  # dh = 128
     assert unit._flash_layout == ("boundary", 1)
     # an odd head count keeps one head per program, never raises
@@ -374,6 +380,19 @@ def test_causal_schedule_resolves_from_shapes_not_options(monkeypatch,
             == tiles[cls]
     assert 'znicz_flash_tiles{unit="%s",class="skipped"}' % unit.name \
         in obs_metrics.REGISTRY.to_prometheus()
+    # one K tile holds every key a Q tile sees: the backward is ONE
+    # kernel, from the shapes (pallas_attention.backward_passes)
+    assert unit._flash_backward == pa.backward_passes(True, 2048, 2048) == 1
+    assert "backward passes 1" in caplog.text
+    assert obs_metrics.flash_backward(unit.name, 1).value == 1
+    assert 'znicz_flash_backward{unit="%s",passes="1"} 1' % unit.name \
+        in obs_metrics.REGISTRY.to_prometheus()
+    # T 4096: the backward takes the two 2048-long K tiles whole; past
+    # that a dq and a dk/dv kernel
+    assert _attention_unit(XLADevice(), t=4096,
+                           causal=True)._flash_backward == 1
+    assert _attention_unit(XLADevice(), t=8192,
+                           causal=True)._flash_backward == 2
     # an option of that name steers nothing any more
     root.common.engine.flash_causal_block = 256
     again = _attention_unit(XLADevice(), t=2048, causal=True)

@@ -594,6 +594,22 @@ def flash_layout(unit: str, layout: str, pack: int,
             kv_group=str(kv_group))
 
 
+def flash_backward(unit: str, passes: int) -> Gauge:
+    """How often an attention unit's flash BACKWARD recomputes a score
+    sub-tile (``pallas_attention.backward_passes``, from the call's
+    shapes): ``passes`` = ``1`` — one kernel, ``znicz_flash_bwd``, dq
+    accumulating in the dk/dv walk (a causal, un-windowed call whose K
+    side is one grid tile) — or ``2``: ``znicz_flash_dq`` +
+    ``znicz_flash_dkv``.  Static per program, 1 for the form in force,
+    set once at ``initialize``."""
+    return REGISTRY.gauge(
+        "znicz_flash_backward",
+        "Passes over the score tiles in the flash-attention backward "
+        "(1: one kernel for dq, dk and dv; 2: a dq and a dk/dv kernel); "
+        "1 for the form in force",
+        labels=("unit", "passes")).labels(unit=unit, passes=str(passes))
+
+
 def moe_expert_tokens(unit: str, stat: str) -> Gauge:
     """Rows (token, expert) pairs an expert of a ``MoE`` unit computed
     per step, over the last epoch: ``stat`` = ``max`` / ``min`` (the

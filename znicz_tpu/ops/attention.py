@@ -475,8 +475,12 @@ class MultiHeadAttention(Forward):
         self._flash_sub_tile = None
         self._flash_tiles = None
         self._flash_layout = None
+        #: passes over the score tiles in the backward (1: one kernel)
+        self._flash_backward = None
         if self._flash_pallas:
             self._flash_layout = (layout, head_pack)
+            self._flash_backward = pallas_attention.backward_passes(
+                self.causal, t, bk, window)
             sq, sk = pallas_attention.sub_tile_for(self.causal, bq, bk) \
                 if window is None else (bq, bk)
             self._flash_sub_tile = (sq, sk)
@@ -489,6 +493,8 @@ class MultiHeadAttention(Forward):
                         self._flash_tiles[cls])
             obs_metrics.flash_layout(self.name, layout, head_pack,
                                      group).set(1)
+            obs_metrics.flash_backward(self.name,
+                                       self._flash_backward).set(1)
             if window is not None:
                 share = pallas_attention.band_share(t, window)
                 for stat, value in (
@@ -503,15 +509,15 @@ class MultiHeadAttention(Forward):
             tiles = self._flash_tiles
             self.info("%s: flash kernel, blocks (%d, %d), sub-tiles "
                       "(%d, %d): %d interior + %d crossing of %d = "
-                      "%.4f of T×T executed, layout=%s, head pack %d"
-                      "%s%s%s",
+                      "%.4f of T×T executed, layout=%s, head pack %d, "
+                      "backward passes %d%s%s%s",
                       self.name, bq, bk, *self._flash_sub_tile,
                       tiles["interior"], tiles["crossing"]
                       + tiles.get("band_edge", 0),
                       sum(n for cls, n in tiles.items()
                           if cls != "executed_share"),
                       tiles["executed_share"],
-                      layout, head_pack,
+                      layout, head_pack, self._flash_backward,
                       "" if group == 1 and window is None else
                       ", %d query heads to a K/V head, window %s (band "
                       "%.4f of T×T)" % (

@@ -20,10 +20,36 @@ Design (the standard flash decomposition, implemented TPU-first):
   logsumexp are written once at the last K block.
 - **backward**: recompute-from-lse form — no (T, T) residual is ever
   stored.  Saves (q, k, v, o, lse) from the forward, precomputes
-  ``delta = rowsum(do·o)`` (one cheap XLA pass), then two kernels:
-  ``dq`` (grid over K blocks innermost, accumulating dq tiles) and
-  ``dk/dv`` (grid over Q blocks innermost, accumulating dk/dv tiles);
-  each recomputes the score tile p = exp(s − lse) in VMEM.
+  ``delta = rowsum(do·o)`` (one cheap XLA pass), then recomputes the
+  score sub-tiles p = exp(s − lse), dp = do·vᵀ and ds = p·(dp − delta)
+  in VMEM — ONCE where the call's shapes allow, else twice
+  (:func:`backward_passes`, never an option):
+
+  - *one pass*, ``znicz_flash_bwd`` — a causal, un-windowed call whose
+    K side is ONE grid tile of the backward: T ≤ 2048 under
+    ``CAUSAL_BLOCK_K`` (the LM cell; a ring hop whose keys are one
+    tile), and up to ``WHOLE_BLOCK_K`` 4096 because the backward takes
+    such a key range whole (:func:`backward_block_k`; OLMoE, Laguna's
+    full layers).  The grid is the dk/dv grid, (B, H_kv, 1, group ·
+    Q tiles): a step's Q tile meets every key it can see, so beside
+    ``dv += pᵀ·do`` and ``dk += dsᵀ·q`` the walk adds ``dq[rows] +=
+    ds·k`` into an f32 (bq, width) scratch that is zeroed as the step
+    starts and leaves, cast, as it ends: five matmuls and one pass of
+    exponentials per visible sub-tile, the count FlashAttention-2 gives
+    and ``znbench/flops.py`` holds the kernels to.  On a v5e (PERF.md
+    §6, PR 30): the LM cell's backward 42.9 → 30.9 ms a step; a layer
+    at T 4096 × 128 lanes 2.01 → 1.40 ms (16 heads), 5.48 → 3.71 (48
+    query heads on 8).
+  - *two passes*, ``znicz_flash_dq`` (grid over K blocks innermost,
+    accumulating dq tiles) and ``znicz_flash_dkv`` (grid over Q blocks
+    innermost, accumulating dk/dv tiles): seven matmuls and the
+    exponentials twice.  A deeper K grid (T > 4096, a caller's shorter
+    K tiles), a window (a Q tile meets two K tiles and a K tile two Q
+    tiles: ``znicz_flash_*_win``) and non-causal calls leave dq
+    unfinished at a dk/dv step's end and keep them.
+
+  Both forms share one dk/dv body (:func:`_dkv_kernel`), `_p_tile`,
+  the walk, the masks and the fully-masked-row guards.
 - **dtypes**: tile GEMMs run at the input dtype (bf16 in the
   framework's mixed-precision mode) with f32 accumulation via
   ``preferred_element_type``; softmax statistics, lse, delta and all
@@ -86,11 +112,14 @@ so no transpose, slice or concatenate stands between a projection and
 a kernel, forward or backward.  ``o`` is written at its column block
 of a (B, T, D) result (the out-projection's (B·T, D) by a free
 reshape), ``do`` is read in place, and dq, dk, dv land in column
-blocks of the cotangent: for one fused array the dq call begins a
-(B, T, 3·D) result and the dk/dv call takes it aliased and writes the
-rest (its grid's last axis has one step more, at which the output
-block moves from dk's to dv's).  ``lse`` and ``delta`` stay head-major
-(B, Hp, T, lanes): they are the kernels' own.  Head widths with no
+blocks of the cotangent: for one fused array ONE (B, T, 3·D) result —
+the one-pass call writes all of it (a blocked output is one block per
+grid step, so its finished tiles leave by DMA from VMEM: a Q tile's dq
+as its step ends, under the next step's walk; dk and dv at the last);
+under two passes the dq call begins the result and the dk/dv call
+takes it aliased and puts its two tiles beside.  ``lse`` and ``delta``
+stay head-major (B, Hp, T, lanes): they are the kernels' own.  Head
+widths with no
 lane-legal column block (dh 32, 80, 96, 192, an odd head count at
 dh 64: :func:`head_layout`) keep the head-major address (B, Hp, T,
 pack·dh) — ``pack_heads`` / ``unpack_heads`` transpose around the
@@ -144,6 +173,16 @@ _SUB_TILE = 512
 #: 2048 × 512 do not)
 _ROW_VISIT_ELEMS = 512 * 2048
 _COL_VISIT_ELEMS = 1024 * 512
+#: the BACKWARD takes a causal call's key range whole up to here: one
+#: K tile makes it one pass (`backward_passes`).  At T 4096 × 128 lanes
+#: that pass takes 1.40 ms where dq + dk/dv under 2048-long tiles take
+#: 2.01 (16 MHA heads), 3.71 against 5.48 (48 query heads on 8) on a
+#: v5e (PERF.md §6, PR 30); the forward keeps ``CAUSAL_BLOCK_K`` …
+WHOLE_BLOCK_K = 4096
+#: … and the call then asks for this much VMEM: K, V tiles and their
+#: f32 accumulators of 4096 × 128 overflow the 16 MB a call gets
+#: unasked by 0.4–1.4 MB (compiled for a described v5e, PR 30)
+_WHOLE_K_VMEM = 40 * 2 ** 20
 #: lane width for the per-row statistics arrays (lse, delta): the
 #: minimum tile-legal last dim — the value is replicated across lanes
 #: (with head packing, each sub-head owns one _LANES-wide lane group)
@@ -313,6 +352,36 @@ def band_share(t: int, window) -> float:
     None or ≥ T)."""
     w = t if window is None else min(int(window), t)
     return (w * (w + 1) / 2 + (t - w) * w) / (t * t)
+
+
+def backward_block_k(causal: bool, t_k: int, bk: int, window=None) -> int:
+    """K-side grid tile of the BACKWARD of a call whose forward walks
+    K tiles of ``bk``: the same — except that a causal, un-windowed
+    call already at the chooser's longest tile (``CAUSAL_BLOCK_K``)
+    takes a key range of up to ``WHOLE_BLOCK_K`` whole, because the
+    backward then is one pass (:func:`backward_passes`).  The
+    statistics the forward saved do not depend on its tiles."""
+    if causal and window is None \
+            and bk == CAUSAL_BLOCK_K < t_k <= WHOLE_BLOCK_K:
+        return t_k
+    return bk
+
+
+def backward_passes(causal: bool, t_k: int, bk: int, window=None) -> int:
+    """How many times the backward recomputes a score sub-tile, from
+    the call's shapes alone (``bk``: the forward's K tile): 1 where a
+    causal, un-windowed call's K side is ONE grid tile of the backward
+    (:func:`backward_block_k`) — a dk/dv grid step then holds every key
+    its Q tile can see, so dq finishes inside it and the backward is
+    one ``pallas_call``, ``znicz_flash_bwd`` (five matmuls and one pass
+    of exponentials per visible sub-tile); else 2, ``znicz_flash_dq`` +
+    ``znicz_flash_dkv`` (seven and two): a deeper K grid, a window (a
+    Q tile meets two K tiles and a K tile two Q tiles) or a non-causal
+    call leaves dq unfinished at a dk/dv step's end.  Static per
+    program: the attention unit reports it (info line,
+    ``znicz_flash_backward``)."""
+    whole = backward_block_k(causal, t_k, bk, window) == t_k
+    return 1 if causal and window is None and whole else 2
 
 
 # ----------------------------------------------------------------------
@@ -642,21 +711,43 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
 
 def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                 lse_ref, delta_ref, *rest, scale, causal, bq, bk, sq,
-                sk, pack, shared, window=None, q_steps=None, q_tiles=None):
+                sk, pack, shared, window=None, q_steps=None, q_tiles=None,
+                group=1, one_pass=False):
     """``q_steps`` (None, or the Q tiles one query head brings to the
     grid's last axis): grouped queries and a window make that axis
     something else than the Q tiles in turn — it runs over every query
     head of this K/V head's group, each with the Q tiles its band
     touches (all of them without a window), and dk, dv accumulate over
-    the lot.  ``shared`` (None, or the first column blocks (k, v) of dk and
-    dv): both are column blocks of ONE result — the cotangent of a
-    fused projection, which the dq call has begun and hands in aliased.
-    A kernel's blocked output is one block per grid step, so the two
-    finished tiles go there by DMA from VMEM."""
+    the lot.  ``shared`` (None, or the first column blocks (q, k, v) of
+    dq, dk and dv): all are column blocks of ONE result — the cotangent
+    of a fused projection.  A kernel's blocked output is one block per
+    grid step, so the finished tiles go there by DMA from VMEM.
+
+    ``one_pass`` (the K side is ONE grid tile: :func:`backward_passes`):
+    a grid step's Q tile meets here every key it can see, so its dq is
+    complete at the step's end.  The body then also contracts the ``ds``
+    it has with k, into an f32 (bq, width) scratch that is zeroed as the
+    step starts and leaves as it ends — this kernel is the whole
+    backward (``znicz_flash_bwd``) and nothing is recomputed twice.
+    Otherwise the dq call (:func:`_dq_kernel`) has begun a fused result
+    and hands it in aliased."""
+    refs = list(rest)
+    dq_ref = dq_scr = dq_tile = None
     if shared is None:
-        dk_ref, dv_ref, dk_scr, dv_scr = rest
-    else:               # the aliased operand itself is never read
-        _, out_ref, dk_scr, dv_scr, dk_tile, dv_tile, sems = rest
+        if one_pass:
+            dq_ref = refs.pop(0)
+        dk_ref, dv_ref = refs.pop(0), refs.pop(0)
+    else:
+        if not one_pass:
+            refs.pop(0)     # the aliased operand itself is never read
+        out_ref = refs.pop(0)
+    dk_scr, dv_scr = refs.pop(0), refs.pop(0)
+    if one_pass:
+        dq_scr = refs.pop(0)
+        if shared is not None:
+            dq_tile = refs.pop(0)
+    if shared is not None:
+        dk_tile, dv_tile, sems = refs
     batch, head = pl.program_id(0), pl.program_id(1)
     ik, step = pl.program_id(2), pl.program_id(3)
     nq = pl.num_programs(3)
@@ -673,18 +764,23 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
+    if one_pass:
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
     def body(c, parts):
         """dk, dv of columns [c, c+sk) from every row run in
-        ``parts``."""
+        ``parts`` — and, one pass, those columns' share of the runs'
+        dq."""
         cs = _ds(c, sk)
         k_all, v_all = k_ref[cs, :], v_ref[cs, :]
         dh = k_all.shape[1] // pack
         dk_out, dv_out = [], []
+        dq_out = [[] for _ in parts]
         for p in range(pack):
             fs = slice(p * dh, (p + 1) * dh)
             ls = slice(p * _LANES, p * _LANES + 1)
             dk = dv = None
-            for r, n, masked in parts:
+            for (r, n, masked), dq_run in zip(parts, dq_out):
                 mask = (_causal_mask(row0 + r, col0 + c, n, sk, window)
                         if masked else None)
                 q, do = q_ref[r:r + n, fs], do_ref[r:r + n, fs]
@@ -694,34 +790,62 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                 # materializing pᵀ
                 dv_part = _dot(pt.astype(do.dtype), do, trans_a=True)
                 dp = _dot(do, v_all[:, fs], trans_b=True)
-                ds = pt * (dp - delta_ref[r:r + n, ls]) * scale
-                dk_part = _dot(ds.astype(q.dtype), q, trans_a=True)
+                ds = (pt * (dp - delta_ref[r:r + n, ls])
+                      * scale).astype(q.dtype)
+                dk_part = _dot(ds, q, trans_a=True)
                 dk = dk_part if dk is None else dk + dk_part
                 dv = dv_part if dv is None else dv + dv_part
+                if one_pass:    # the fifth matmul, on the ds at hand
+                    dq_run.append(_dot(ds, k_all[:, fs]))
             dk_out.append(dk)
             dv_out.append(dv)
         dk_scr[cs, :] += jnp.concatenate(dk_out, axis=1)
         dv_scr[cs, :] += jnp.concatenate(dv_out, axis=1)
+        if one_pass:
+            for (r, n, _), dq_run in zip(parts, dq_out):
+                dq_scr[r:r + n, :] += jnp.concatenate(dq_run, axis=1)
 
     _fold_rows(body, causal, row0, col0, bq, bk, sq, sk, window,
                cols_outer=True, live=live)
 
-    @pl.when(step == nq - 1)
+    d = dk_scr.shape[1]
+    last = step == nq - 1
+
+    def to_column_block(tile, rows, block, sem):
+        """The DMA of a finished tile to ``rows`` of column block
+        ``block`` of the one result."""
+        lanes = pl.ds(pl.multiple_of(block * d, d), d)
+        return pltpu.make_async_copy(
+            tile, out_ref.at[batch, rows, lanes], sems.at[sem])
+
+    if one_pass and shared is None:
+        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
+    elif one_pass:
+        # the step's own Q tile: the copy runs under the next step's
+        # walk and is waited for where its tile is written again
+        q_head = head if q_steps is None else head * group + step // q_steps
+        dq_copy = to_column_block(
+            dq_tile, pl.ds(pl.multiple_of(iq * bq, bq), bq),
+            shared[0] + q_head, 2)
+        pl.when(step > 0)(dq_copy.wait)
+        dq_tile[...] = dq_scr[...].astype(dq_tile.dtype)
+        dq_copy.start()
+
+    @pl.when(last)
     def _finish():
         if shared is None:
             dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
             dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
             return
-        d = dk_scr.shape[1]
         rows = pl.ds(pl.multiple_of(ik * bk, bk), bk)
         copies = []
-        for acc, tile, first, sem in ((dk_scr, dk_tile, shared[0], 0),
-                                      (dv_scr, dv_tile, shared[1], 1)):
+        for acc, tile, first, sem in ((dk_scr, dk_tile, shared[1], 0),
+                                      (dv_scr, dv_tile, shared[2], 1)):
             tile[...] = acc[...].astype(tile.dtype)
-            lanes = pl.ds(pl.multiple_of((first + head) * d, d), d)
-            copies.append(pltpu.make_async_copy(
-                tile, out_ref.at[batch, rows, lanes], sems.at[sem]))
+            copies.append(to_column_block(tile, rows, first + head, sem))
             copies[-1].start()
+        if one_pass:
+            copies.append(dq_copy)
         for copy in copies:
             copy.wait()
 
@@ -868,10 +992,12 @@ def _bwd_call(arrays, lse, do, delta4, q_off, k_off, causal, bq, bk,
               interpret, pack, sub=None, cols=None, window=None):
     """Cotangents of ``arrays`` (see :func:`_fwd_call`), in their
     layout: (dq, dk, dv), or for ONE fused array its one cotangent —
-    the dq call writes q's column blocks of a (B, T, C) result, the
-    dk/dv call takes that result aliased and puts its tiles beside
-    them, so no concatenate of activation size stands before the
-    projection's backward.  ``do`` is in the layout of the forward's out;
+    written whole by the one-pass call (:func:`backward_passes`); under
+    two passes the dq call writes q's column blocks of a (B, T, C)
+    result and the dk/dv call takes that result aliased and puts its
+    tiles beside them — so no concatenate of activation size stands
+    before the projection's backward.  ``do`` is in the layout of the
+    forward's out;
     ``delta4``: (B, Hp, T, pack) f32 — rowsum(do·o) per SUB-head,
     already adjusted for any lse cotangent (the hop composition's
     extra term).  With grouped queries the dk/dv grid is over the K/V
@@ -882,6 +1008,8 @@ def _bwd_call(arrays, lse, do, delta4, q_off, k_off, causal, bq, bk,
         _operands(arrays, cols)
     shared = len(arrays) == 1
     group = h // h_kv
+    one_pass = backward_passes(causal, tk, bk, window) == 1
+    bk = backward_block_k(causal, tk, bk, window)
     nq, nk = t // bq, tk // bk
     sq, sk = sub or sub_tile_for(causal, bq, bk)
     lanes = pack * _LANES
@@ -911,56 +1039,65 @@ def _bwd_call(arrays, lse, do, delta4, q_off, k_off, causal, bq, bk,
                 _tile(bq, lanes, None, q_at)]
 
     operands = [q_off, k_off, q, k, v, do, lse, delta]
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **static),
-        grid=(b, h, nq, k_steps),
-        in_specs=specs(_first, _k_side(group, window, tk, bq, bk)),
-        out_specs=_tile(bq, d, cq, _first),
-        out_shape=like(q),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=params,
-        interpret=interpret,
-        name="znicz_flash_dq" + suffix,
-    )(*operands)
+    if not one_pass:
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, **static),
+            grid=(b, h, nq, k_steps),
+            in_specs=specs(_first, _k_side(group, window, tk, bq, bk)),
+            out_specs=_tile(bq, d, cq, _first),
+            out_shape=like(q),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+            compiler_params=params,
+            interpret=interpret,
+            name="znicz_flash_dq" + suffix,
+        )(*operands)
     # dk/dv: Q blocks innermost; the q-side specs index by the LAST
     # grid dim now, the k-side by dim 2
-    in_specs = specs(_q_side(group, window, q_steps, t, bq, bk), _first)
+    q_at = _q_side(group, window, q_steps, t, bq, bk)
+    in_specs = specs(q_at, _first)
     if group > 1 or window is not None:
         static["q_steps"] = q_steps
     if window is not None:
         static["q_tiles"] = nq
-    grid = (b, h_kv, nk, group * q_steps)
-    accumulators = [pltpu.VMEM((bk, d), jnp.float32),
-                    pltpu.VMEM((bk, d), jnp.float32)]
-    if shared:
-        anywhere = pl.BlockSpec(memory_space=pl.ANY)
-        out = pl.pallas_call(
-            functools.partial(_dkv_kernel, shared=(ck, cv), **static),
-            grid=grid,
-            in_specs=in_specs + [anywhere],
-            out_specs=anywhere,
-            out_shape=like(dq),
-            input_output_aliases={len(operands): 0},
-            scratch_shapes=accumulators + [
-                pltpu.VMEM((bk, d), k.dtype), pltpu.VMEM((bk, d), v.dtype),
-                pltpu.SemaphoreType.DMA((2,))],
-            compiler_params=params,
-            interpret=interpret,
-            name="znicz_flash_dkv" + suffix,
-        )(*operands, dq)
-        return (out,)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, shared=None, **static),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=(_tile(bk, d, ck, _first), _tile(bk, d, cv, _first)),
-        out_shape=(like(k), like(v)),
-        scratch_shapes=accumulators,
-        compiler_params=params,
-        interpret=interpret,
-        name="znicz_flash_dkv" + suffix,
-    )(*operands)
-    return dq, dk, dv
+    name = "znicz_flash_dkv" + suffix
+    outs = [(k, ck, bk, _first), (v, cv, bk, _first)]
+    scratch = [pltpu.VMEM((bk, d), jnp.float32),
+               pltpu.VMEM((bk, d), jnp.float32)]
+    if one_pass:        # the whole backward: dq leaves with dk and dv
+        static.update(one_pass=True, group=group)
+        name = "znicz_flash_bwd"
+        outs.insert(0, (q, cq, bq, q_at))
+        scratch.append(pltpu.VMEM((bq, d), jnp.float32))
+        if bk > CAUSAL_BLOCK_K:
+            params = pltpu.CompilerParams(
+                dimension_semantics=params.dimension_semantics,
+                vmem_limit_bytes=_WHOLE_K_VMEM)
+    call = functools.partial(
+        pl.pallas_call, grid=(b, h_kv, nk, group * q_steps),
+        compiler_params=params, interpret=interpret, name=name)
+    if not shared:
+        grads = call(
+            functools.partial(_dkv_kernel, shared=None, **static),
+            in_specs=in_specs,
+            out_specs=tuple(_tile(rows, d, col, at)
+                            for _, col, rows, at in outs),
+            out_shape=tuple(like(a) for a, *_ in outs),
+            scratch_shapes=scratch,
+        )(*operands)
+        return tuple(grads) if one_pass else (dq, *grads)
+    # ONE result: every finished tile leaves by DMA from a VMEM copy in
+    # the cotangent's dtype
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    scratch += [pltpu.VMEM((rows, d), a.dtype) for a, _, rows, _ in outs]
+    scratch.append(pltpu.SemaphoreType.DMA((len(outs),)))
+    kernel = functools.partial(_dkv_kernel, shared=(cq, ck, cv), **static)
+    if one_pass:
+        return (call(kernel, in_specs=in_specs, out_specs=anywhere,
+                     out_shape=like(q), scratch_shapes=scratch)(*operands),)
+    return (call(kernel, in_specs=in_specs + [anywhere],
+                 out_specs=anywhere, out_shape=like(dq),
+                 input_output_aliases={len(operands): 0},
+                 scratch_shapes=scratch)(*operands, dq),)
 
 
 # ----------------------------------------------------------------------
