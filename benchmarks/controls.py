@@ -1,0 +1,93 @@
+"""The runner of the cells' CONTROLS (``benchmarks/laguna_controls.py``,
+``benchmarks/olmo_hybrid_controls.py``): do the limits of a cell's
+comparison have teeth at the cell's sizes?
+
+Builds the cell's workflow as its driver does, runs one epoch of steps,
+and calls the driver's own ``check`` on it: once with the plain
+reference (has to pass), then once per control, the reference replaced
+by one that is wrong in a stated way (has to come out as not correct).
+The exit code is 0 only if every one of these came out as it has to.
+
+A READING is run and printed the same way and decides nothing: its
+line carries ``"reading": true`` and no ``as_expected``, and the exit
+code does not know it.  It is for a wrong reference the cell's limits
+are known NOT to separate, so that the number is on record beside the
+limit it passes under.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import tempfile
+import time
+
+T_START = time.perf_counter()
+
+
+def run_checks(cell_name: str, make_checks, make_readings=None,
+               doc: str = "") -> int:
+    """``make_checks(reference, layers)`` and ``make_readings(…)`` give
+    ``(name, module)`` pairs, ``module`` standing where the driver
+    loads the cell's reference; one JSON line each, ``ok`` last."""
+    parser = argparse.ArgumentParser(description=doc.split("\n")[0])
+    parser.add_argument("--seed", type=int, default=0)
+    parser.add_argument("--toy", action="store_true")
+    args = parser.parse_args()
+
+    from znbench.harness import discovery, programs
+    from znbench.harness.program import engine_options, layer_table
+    from znbench.harness.window import Context
+    import znbench.run as bench
+
+    cell = discovery.find_cell(cell_name, toy=args.toy)
+    devices = bench.take_devices(cell, args.toy)
+    programs.listen()
+    driver = discovery.load_module("drivers", cell.driver)
+    reference = discovery.load_module("reference",
+                                      cell.config["reference"])
+    scratch = tempfile.mkdtemp(prefix="znbench-")
+    ctx = Context(cell, args.seed, 0.0, False, args.toy, devices,
+                  T_START, scratch)
+    layers = layer_table(cell.config)
+    load_module = discovery.load_module
+    ok = True
+    with engine_options(cell.traffic.get("engine", {})):
+        wf, _ = driver.build(ctx, layers)
+        trainer = driver.train.Trainer(ctx, wf)
+        trainer.epoch()
+        trainer.fence()
+        checks = [("reference", None, False)] + [
+            (name, module, False)
+            for name, module in make_checks(reference, layers)] + [
+            (name, module, True) for name, module in
+            (make_readings(reference, layers) if make_readings else [])]
+        for name, module, reading in checks:
+            if module is not None:
+                discovery.load_module = (
+                    lambda kind, what, module=module: module
+                    if kind == "reference" else load_module(kind, what))
+            t0 = time.perf_counter()
+            try:
+                problems, notes = driver.check(ctx, wf, layers)
+            finally:
+                discovery.load_module = load_module
+            said = next((n for n in notes if "worst layer" in n), "")
+            line = {"check": name}
+            if reading:
+                line["reading"] = True
+            else:
+                good = (not problems) if module is None else any(
+                    "forward differs" in p for p in problems)
+                ok = ok and good
+                line["as_expected"] = good
+            line.update({
+                "correct": not problems, "problems": problems,
+                "layers": said.split("reference: ", 1)[-1],
+                "limit": cell.config["reference_tolerance"]["layers"],
+                "seq_len": cell.traffic["seq_len"],
+                "platform": devices[0].platform,
+                "seconds": round(time.perf_counter() - t0, 1)})
+            print(json.dumps(line), flush=True)
+    print(json.dumps({"ok": ok}), flush=True)
+    return 0 if ok else 1

@@ -25,16 +25,11 @@ check, ``ok`` last.
 
 from __future__ import annotations
 
-import argparse
 import copy
-import json
 import os
 import sys
-import tempfile
-import time
 import types
 
-T_START = time.perf_counter()
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
@@ -85,61 +80,10 @@ def spoiled(reference, where: list, last: int, edit: dict, inputs):
 
 
 def main() -> int:
-    parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    parser.add_argument("--seed", type=int, default=0)
-    parser.add_argument("--toy", action="store_true")
-    args = parser.parse_args()
-
-    from znbench.harness import discovery, programs
-    from znbench.harness.program import engine_options, layer_table
-    from znbench.harness.window import Context
-    import znbench.run as bench
-
-    cell = discovery.find_cell(CELL, toy=args.toy)
-    devices = bench.take_devices(cell, args.toy)
-    programs.listen()
-    driver = discovery.load_module("drivers", cell.driver)
-    reference = discovery.load_module("reference",
-                                      cell.config["reference"])
-    scratch = tempfile.mkdtemp(prefix="znbench-")
-    ctx = Context(cell, args.seed, 0.0, False, args.toy, devices,
-                  T_START, scratch)
-    layers = layer_table(cell.config)
-    load_module = discovery.load_module
-    ok = True
-    with engine_options(cell.traffic.get("engine", {})):
-        wf, _ = driver.build(ctx, layers)
-        trainer = driver.train.Trainer(ctx, wf)
-        trainer.epoch()
-        trainer.fence()
-        checks = [("reference", None)] + [
-            (name, spoiled(reference, *how))
-            for name, *how in controls(layers)]
-        for name, module in checks:
-            if module is not None:
-                discovery.load_module = (
-                    lambda kind, what, module=module: module
-                    if kind == "reference" else load_module(kind, what))
-            t0 = time.perf_counter()
-            try:
-                problems, notes = driver.check(ctx, wf, layers)
-            finally:
-                discovery.load_module = load_module
-            said = next((n for n in notes if "worst layer" in n), "")
-            good = (not problems) if module is None else any(
-                "forward differs" in p for p in problems)
-            ok = ok and good
-            print(json.dumps({
-                "check": name, "as_expected": good,
-                "correct": not problems, "problems": problems,
-                "layers": said.split("reference: ", 1)[-1],
-                "limit": cell.config["reference_tolerance"]["layers"],
-                "seq_len": cell.traffic["seq_len"],
-                "platform": devices[0].platform,
-                "seconds": round(time.perf_counter() - t0, 1)}),
-                flush=True)
-    print(json.dumps({"ok": ok}), flush=True)
-    return 0 if ok else 1
+    from benchmarks.controls import run_checks
+    return run_checks(CELL, lambda reference, layers: [
+        (name, spoiled(reference, *how))
+        for name, *how in controls(layers)], doc=__doc__)
 
 
 if __name__ == "__main__":

@@ -210,8 +210,8 @@ def attach_decode_meta(path: str, *, page_tokens: int | None = None,
 
 
 #: the attention options of the pre-norm residual block (ROADMAP R0)
-_BLOCK_OPTIONS = ("pre_norm", "qk_norm", "rope_theta", "residual",
-                  "head_dim", "window", "head_gate")
+_BLOCK_OPTIONS = ("pre_norm", "post_norm", "qk_norm", "rope_theta",
+                  "residual", "head_dim", "window", "head_gate")
 
 
 def refuse_unserved(forwards, what: str) -> None:
@@ -227,6 +227,13 @@ def refuse_unserved(forwards, what: str) -> None:
                 f"{what}: layer {i} is a gated MLP block (gated_mlp); "
                 f"serving runs no feed-forward sublayer yet (ROADMAP R1, "
                 f"serving half)")
+        if kind == "GatedDeltaNet":
+            raise NotImplementedError(
+                f"{what}: layer {i} is a gated-delta-rule linear-"
+                f"attention layer (gated_delta_net); serving has no "
+                f"state slot yet — the recurrent state and the "
+                f"convolution's tail exist on the training path only "
+                f"(ROADMAP R6, serving half)")
         if kind == "MoE":
             raise NotImplementedError(
                 f"{what}: layer {i} is a sparse-expert layer (moe); "

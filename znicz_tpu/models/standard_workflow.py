@@ -37,7 +37,7 @@ from znicz_tpu.loader.base import TRAIN, Loader
 from znicz_tpu.mutable import Bool
 from znicz_tpu.ops import activation, all2all, conv, cutter, dropout, pooling
 from znicz_tpu.ops import attention, deconv, depooling, lstm, normalization
-from znicz_tpu.ops import embedding, layer_norm, moe, pos_encoding
+from znicz_tpu.ops import delta_net, embedding, layer_norm, moe, pos_encoding
 from znicz_tpu.ops import rms_norm
 from znicz_tpu.ops import seq_reshape
 from znicz_tpu.ops import gd, gd_conv, gd_pooling  # noqa: F401 (pairs)
@@ -105,6 +105,7 @@ for _name, _cls in {
     "rms_norm": rms_norm.RMSNorm,
     "moe": moe.MoE,
     "gated_mlp": moe.GatedMLP,
+    "gated_delta_net": delta_net.GatedDeltaNet,
 }.items():
     register_layer_type(_name, _cls)
 

@@ -641,6 +641,24 @@ def moe_held(unit: str, stat: str) -> Gauge:
         labels=("unit", "stat")).labels(unit=unit, stat=stat)
 
 
+def delta_scan(unit: str, stat: str) -> Gauge:
+    """A ``GatedDeltaNet`` unit's chunked state scan (``stat`` =
+    ``chunk``: positions per chunk; ``chunks``: ⌈T / chunk⌉, the length
+    of the sequential walk; ``key_dim`` / ``value_dim``: the state's
+    d_k × d_v; ``padded_share``: elements of the 128-lane tiles a
+    d_k × d_v product occupies in the kernels ÷ d_k · d_v — 1.0 when
+    nothing is padded or no kernel runs, 1.78 at 96 × 192;
+    ``state_mb``: MB of per-chunk states kept for the backward;
+    ``path``: 1 the ``znicz_delta_state_*`` kernels, 0 the plain
+    scan).  Static per program, set once at ``initialize``."""
+    return REGISTRY.gauge(
+        "znicz_delta_scan",
+        "Chunked state scan of a gated-delta-rule layer: chunk length, "
+        "chunks walked, state size, the kernels' tile padding, MB of "
+        "states kept for the backward, kernels (1) or plain scan (0)",
+        labels=("unit", "stat")).labels(unit=unit, stat=stat)
+
+
 def moe_aux_loss(unit: str, kind: str) -> Gauge:
     """A ``MoE`` unit's auxiliary router losses, mean per step over
     the last epoch, unweighted (``kind`` = ``load_balance``: top_k

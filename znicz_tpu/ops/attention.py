@@ -33,6 +33,12 @@ position over the full head in the half-split ("rotate_half")
 convention.  Norms and rotation run in f32 between the QKV projection
 and the attention core; the kernels are untouched.
 
+``post_norm="rms"`` (Olmo-Hybrid-7B, PR 31) is the family's other
+placement of the block's one norm: on the sublayer's OUTPUT, inside the
+skip — ``y = x + RMSNorm(f(x))``.  Its gain is ``gain_norm`` too, so a
+layer moves between the placements by one word of the layer table;
+both at once are refused at ``initialize``.
+
 Grouped queries, a window and a per-head gate (Laguna-S-2.1, PR 29)
 are options of the same kind, per unit, so that one model mixes layers
 of different head counts, windows and rotary rules: ``n_kv_heads`` (K
@@ -65,7 +71,8 @@ import jax.numpy as jnp
 
 from znicz_tpu.memory import Vector
 from znicz_tpu.ops.nn_units import Forward, GradientDescentBase
-from znicz_tpu.ops.rms_norm import rms_norm, rms_norm_backward
+from znicz_tpu.ops.rms_norm import (one_norm_placement, rms_norm,
+                                    rms_norm_backward)
 from znicz_tpu.parallel.axis import DATA_AXIS, MODEL_AXIS, SEQ_AXIS
 
 
@@ -212,6 +219,7 @@ class MultiHeadAttention(Forward):
                  n_kv_heads: int | None = None,
                  head_dim: int | None = None,
                  window: int | None = None, head_gate: bool = False,
+                 post_norm: str | None = None,
                  name=None, **kwargs) -> None:
         # attention defaults to fan-scaled init (the reference's
         # fixed-stddev fillings predate attention entirely)
@@ -234,13 +242,19 @@ class MultiHeadAttention(Forward):
         self.seq_parallel = bool(seq_parallel)
         self._ring_active = False
         for option, value in (("pre_norm", pre_norm),
-                              ("qk_norm", qk_norm)):
+                              ("qk_norm", qk_norm),
+                              ("post_norm", post_norm)):
             if value not in (None, "rms"):
                 raise ValueError(f"{option} must be None or 'rms', got "
                                  f"{value!r}")
         #: the pre-norm residual block (module docstring); all off =
         #: the bare layer, whose program these options leave untouched
         self.pre_norm = pre_norm
+        #: the norm on the sublayer's OUTPUT, inside the skip:
+        #: x + RMSNorm(f(x)) (OLMo 2's placement); its gain is
+        #: ``gain_norm``, the block's one norm, so the two placements
+        #: exclude each other (refused at ``initialize``)
+        self.post_norm = post_norm
         self.residual = bool(residual)
         self.qk_norm = qk_norm
         self.rope_theta = None if rope is None else float(rope["theta"])
@@ -284,6 +298,7 @@ class MultiHeadAttention(Forward):
             raise ValueError(f"{self}: expected (batch, time, features) "
                              f"input, got {self.input.shape}")
         b, t, d = self.input.shape
+        one_norm_placement(self)
         if self.head_dim is None and d % self.n_heads:
             raise ValueError(f"{self}: features {d} not divisible by "
                              f"{self.n_heads} heads")
@@ -311,7 +326,8 @@ class MultiHeadAttention(Forward):
                 self.bias.reset(np.zeros(wide, np.float32))
             if not self.bias_out:
                 self.bias_out.reset(np.zeros(d, np.float32))
-        for gain, width in ((self.gain_norm, d if self.pre_norm else 0),
+        for gain, width in ((self.gain_norm,
+                             d if self.pre_norm or self.post_norm else 0),
                             (self.gain_q,
                              q_width if self.qk_norm else 0),
                             (self.gain_k,
@@ -594,7 +610,7 @@ class MultiHeadAttention(Forward):
         wide = w_qkv.shape[1]
         grouped = self.n_kv_heads != self.n_heads
         x32 = x.astype(jnp.float32)
-        h = x32 if g_norm is None \
+        h = x32 if g_norm is None or self.post_norm \
             else rms_norm(jnp, x32, g_norm, self.norm_eps)
         qkv = self.mxu_dot(jnp, h.reshape(b * t, d), w_qkv)
         if b_qkv is not None:
@@ -641,7 +657,7 @@ class MultiHeadAttention(Forward):
                 interpret=getattr(self, "_flash_interpret", False),
                 mesh=getattr(self, "_flash_mesh", None),
                 spec=getattr(self, "_flash_spec", None), **more)
-            return self._project_out(x32, h, o, w_out, b_out, w_gate)
+            return self._project_out(x32, h, o, w_out, b_out, w_gate, g_norm)
         q, k, v = self._normed_rotated(jnp, arrays[0], None, None) \
             if fused else arrays      # fused: neither norm nor rotation
         if self.ring_active:
@@ -673,14 +689,16 @@ class MultiHeadAttention(Forward):
             from znicz_tpu.parallel.ring_attention import local_attention
             o = local_attention(q, k, v, causal=self.causal,
                                 dot_dtype=dot_dtype, window=self.window)
-        return self._project_out(x32, h, o, w_out, b_out, w_gate)
+        return self._project_out(x32, h, o, w_out, b_out, w_gate, g_norm)
 
-    def _project_out(self, x32, h, o, w_out, b_out, w_gate=None):
+    def _project_out(self, x32, h, o, w_out, b_out, w_gate=None,
+                     g_norm=None):
         """The out-projection over the core's result — (B, T, H, dh)
         or (B, T, H·dh), (B·T, H·dh) by a free reshape either way —
         and the residual; with ``w_gate`` every head's output first
         multiplied by its sigmoid gate, computed from the sublayer's
-        (normed) input ``h``."""
+        (normed) input ``h``; with ``post_norm`` the projection's
+        result normed (gain ``g_norm``) before the skip adds it."""
         b, t, d = x32.shape
         o = o.reshape(b * t, w_out.shape[0])
         if w_gate is not None:
@@ -692,6 +710,8 @@ class MultiHeadAttention(Forward):
         if b_out is not None:
             y = y + b_out
         y = y.reshape(b, t, d)
+        if self.post_norm:
+            y = rms_norm(jnp, y, g_norm, self.norm_eps)
         return x32 + y if self.residual else y
 
     def xla_run(self) -> None:
@@ -986,11 +1006,12 @@ class MultiHeadAttention(Forward):
 
     # -- numpy oracle ---------------------------------------------------
     def _forward_np(self, x):
-        """``(y, (h, qkv, q, k, v, o, p, gate))``: ``h`` is what the
-        QKV projection saw (the input, or its pre-norm), ``qkv`` the raw
-        projections, ``q``/``k`` what the core saw (normed, rotated),
-        ``o`` the core's output BEFORE the per-head ``gate`` (None
-        without one)."""
+        """``(y, (h, qkv, q, k, v, o, p, gate, raw))``: ``h`` is what
+        the QKV projection saw (the input, or its pre-norm), ``qkv`` the
+        raw projections, ``q``/``k`` what the core saw (normed,
+        rotated), ``o`` the core's output BEFORE the per-head ``gate``
+        (None without one), ``raw`` the out-projection's result BEFORE
+        the ``post_norm`` (None without one)."""
         b, t, d = x.shape
         h = rms_norm(np, x, self.gain_norm.mem, self.norm_eps) \
             if self.pre_norm else x
@@ -1010,10 +1031,13 @@ class MultiHeadAttention(Forward):
         y = out.reshape(b * t, -1) @ self.weights_out.mem
         if self.include_bias:
             y = y + self.bias_out.mem
-        y = y.reshape(b, t, d)
+        y, raw = y.reshape(b, t, d), None
+        if self.post_norm:
+            raw, y = y, rms_norm(np, y, self.gain_norm.mem,
+                                 self.norm_eps)
         if self.residual:
             y = x + y
-        return y, (h, qkv, q, k, v, o, p, gate)
+        return y, (h, qkv, q, k, v, o, p, gate, raw)
 
     def numpy_run(self) -> None:
         self.input.map_read()
@@ -1169,9 +1193,14 @@ class GDMultiHeadAttention(GradientDescentBase):
         b, t, d = x.shape
         h, h_kv = fwd.n_heads, fwd.n_kv_heads
         qw, kw, dh = fwd._widths(d)
-        _, (hidden, qkv, q, k, v, o, p, gate) = fwd._forward_np(x)
-        dy = self.err_output.mem.astype(np.float32).reshape(b * t, d)
+        _, (hidden, qkv, q, k, v, o, p, gate, raw) = fwd._forward_np(x)
+        err = self.err_output.mem.astype(np.float32).reshape(b, t, d)
         grad_gains = {}
+        dy = err
+        if fwd.post_norm:                 # back through the output norm
+            dy, grad_gains["norm"] = rms_norm_backward(
+                np, raw, fwd.gain_norm.mem, fwd.norm_eps, err)
+        dy = dy.reshape(b * t, d)
         # output projection (over the gated heads, where there is a gate)
         do = (dy @ fwd.weights_out.mem.T).reshape(b, t, h, dh)
         d_hidden = 0.0
@@ -1223,7 +1252,7 @@ class GDMultiHeadAttention(GradientDescentBase):
             dx, grad_gains["norm"] = rms_norm_backward(
                 np, x, fwd.gain_norm.mem, fwd.norm_eps, dx)
         if fwd.residual:
-            dx = dx + dy.reshape(b, t, d)
+            dx = dx + err
         if self.need_err_input:
             self.err_input.map_invalidate()
             self.err_input.mem[...] = dx

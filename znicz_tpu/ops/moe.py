@@ -91,7 +91,8 @@ import jax.numpy as jnp
 
 from znicz_tpu.memory import Vector
 from znicz_tpu.ops.nn_units import Forward, GradientDescentBase
-from znicz_tpu.ops.rms_norm import rms_norm, rms_norm_backward
+from znicz_tpu.ops.rms_norm import (one_norm_placement, rms_norm,
+                                    rms_norm_backward)
 
 #: slots of ``moe_stats`` after the E per-expert row totals
 _LB, _Z, _STEPS, _MAX, _MIN = range(5)
@@ -858,15 +859,21 @@ class GatedMLP(Forward):
 
     def __init__(self, workflow, width: int, pre_norm: str | None = None,
                  residual: bool = False, norm_eps: float = 1e-5,
+                 post_norm: str | None = None,
                  name=None, **kwargs) -> None:
         kwargs.setdefault("weights_filling", "xavier")
         kwargs["include_bias"] = False
         super().__init__(workflow, name=name, **kwargs)
-        if pre_norm not in (None, "rms"):
-            raise ValueError(f"pre_norm must be None or 'rms', got "
-                             f"{pre_norm!r}")
+        for option, value in (("pre_norm", pre_norm),
+                              ("post_norm", post_norm)):
+            if value not in (None, "rms"):
+                raise ValueError(f"{option} must be None or 'rms', got "
+                                 f"{value!r}")
         self.width = int(width)
         self.pre_norm = pre_norm
+        #: the norm on the block's OUTPUT inside the skip,
+        #: x + RMSNorm(f(x)), gain ``gain_norm`` (see ops/attention.py)
+        self.post_norm = post_norm
         self.residual = bool(residual)
         self.norm_eps = float(norm_eps)
         self.weights_up = Vector(name=f"{self.name}.weights_up")
@@ -878,6 +885,7 @@ class GatedMLP(Forward):
         super().initialize(device=device, **kwargs)
         if self.input is None or not self.input:
             raise AttributeError(f"{self}: input not linked yet")
+        one_norm_placement(self)
         d, f = self.input.shape[-1], self.width
         for vec, shape in ((self.weights, (d, f)),
                            (self.weights_up, (d, f)),
@@ -886,7 +894,7 @@ class GatedMLP(Forward):
                 vec.reset(self.fill_array(shape, self.weights_filling,
                                           self.weights_stddev,
                                           fan_in=shape[0]))
-        if self.pre_norm and not self.gain_norm:
+        if (self.pre_norm or self.post_norm) and not self.gain_norm:
             self.gain_norm.reset(np.ones(d, np.float32))
         self.output.reset(np.zeros(self.input.shape,
                                    dtype=self.output_store_dtype))
@@ -902,10 +910,12 @@ class GatedMLP(Forward):
 
     def xla_forward(self, x, w_g, w_u, w_d, g_norm=None):
         x32 = x.astype(jnp.float32)
-        m = x32 if g_norm is None \
+        m = x32 if g_norm is None or self.post_norm \
             else rms_norm(jnp, x32, g_norm, self.norm_eps)
         y = gated_mlp(jnp, self.mxu_dot, m.reshape(-1, x.shape[-1]),
                       w_g, w_u, w_d).reshape(x.shape)
+        if self.post_norm:
+            y = rms_norm(jnp, y, g_norm, self.norm_eps)
         return x32 + y if self.residual else y
 
     def xla_run(self) -> None:
@@ -923,7 +933,11 @@ class GatedMLP(Forward):
         gate, up = m @ self.weights.mem, m @ self.weights_up.mem
         y = ((_silu(np, gate) * up) @ self.weights_down.mem).reshape(
             x.shape)
-        return (x + y if self.residual else y), (m, gate, up)
+        raw = None
+        if self.post_norm:
+            raw, y = y, rms_norm(np, y, self.gain_norm.mem,
+                                 self.norm_eps)
+        return (x + y if self.residual else y), (m, gate, up, raw)
 
     def numpy_run(self) -> None:
         for vec in (self.input, self.weights, self.weights_up,
@@ -954,9 +968,14 @@ class GDGatedMLP(GDMoE):
         for _, param, _ in self._extra_pairs():
             param.map_write()
         x = self.input.mem.astype(np.float32)
-        _, (m, gate, up) = fwd._forward_np(x)
-        dy = self.err_output.mem.astype(np.float32).reshape(m.shape)
-        grads = {"weights_down": (_silu(np, gate) * up).T @ dy}
+        _, (m, gate, up, raw) = fwd._forward_np(x)
+        err = self.err_output.mem.astype(np.float32)
+        grads, dy = {}, err
+        if fwd.post_norm:                 # back through the output norm
+            dy, grads["gain_norm"] = rms_norm_backward(
+                np, raw, fwd.gain_norm.mem, fwd.norm_eps, err)
+        dy = dy.reshape(m.shape)
+        grads["weights_down"] = (_silu(np, gate) * up).T @ dy
         dgate, dup, dm = _gated_mlp_backward(
             m, gate, up, dy, fwd.weights.mem, fwd.weights_up.mem,
             fwd.weights_down.mem)
@@ -966,7 +985,7 @@ class GDGatedMLP(GDMoE):
             dx, grads["gain_norm"] = rms_norm_backward(
                 np, x, fwd.gain_norm.mem, fwd.norm_eps, dx)
         if fwd.residual:
-            dx = dx + dy.reshape(x.shape)
+            dx = dx + err
         if self.need_err_input:
             self.err_input.map_invalidate()
             self.err_input.mem[...] = dx

@@ -45,6 +45,14 @@ def rms_norm_backward(xp, x, gain, eps: float, err):
     return dx, grad_gain
 
 
+def one_norm_placement(unit) -> None:
+    """A block has ONE norm (gain ``gain_norm``): before the sublayer
+    (``pre_norm``) or on its output inside the skip (``post_norm``)."""
+    if unit.pre_norm and unit.post_norm:
+        raise ValueError(f"{unit}: pre_norm and post_norm are two "
+                         f"placements of the block's one norm; set one")
+
+
 class RMSNorm(Forward):
     """Per-position RMS normalization with a learned gain."""
 
