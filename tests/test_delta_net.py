@@ -167,6 +167,7 @@ def test_a_sequence_that_is_not_whole_chunks_is_padded():
     assert not unit._kernels               # off a TPU: the plain scan
     assert obs_metrics.delta_scan(unit.name, "chunks").value == 3
     assert obs_metrics.delta_scan(unit.name, "path").value == 0
+    assert obs_metrics.delta_scan(unit.name, "chunk_path").value == 0
     for attr in unit.EXPORT_PARAMS:
         getattr(unit, attr).map_read()
     want = unit._forward_np(x)
@@ -189,13 +190,17 @@ def test_what_the_unit_reports(interpreted_kernels):
                      delta_net.GDGatedDeltaNet)
     stats = {stat: obs_metrics.delta_scan(unit.name, stat).value
              for stat in ("chunk", "chunks", "key_dim", "value_dim",
-                          "padded_share", "state_mb", "path")}
+                          "padded_share", "state_mb", "path",
+                          "chunk_path")}
     assert stats == {
         "chunk": 16, "chunks": 2, "key_dim": 8, "value_dim": 12,
         "padded_share": pytest.approx(128 * 128 / (8 * 12)),
         "state_mb": pytest.approx(2 * 3 * 2 * 8 * 12 * 4 / 1e6),
-        "path": 1}
-    assert "znicz_delta_scan{" in obs_metrics.REGISTRY.to_prometheus()
+        "path": 1, "chunk_path": 1}
+    scrape = obs_metrics.REGISTRY.to_prometheus()
+    assert "znicz_delta_scan{" in scrape and 'stat="chunk_path"' in scrape
+    assert "znicz_gdr_chunk_fwd" in obs_metrics.delta_scan.__doc__ \
+        or "znicz_gdr_chunk_*" in obs_metrics.delta_scan.__doc__
 
 
 def test_decay_parameters_are_drawn_as_the_paper_s_layer_draws_them():

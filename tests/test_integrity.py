@@ -312,6 +312,53 @@ def test_attention_layer_compiled_for_v5e_moves_no_activation(
     assert not moves, moves
 
 
+def test_gated_delta_rule_compiled_for_v5e_keeps_a_chunk_in_vmem(v5e_chip):
+    """The rule's forward + backward at the Olmo-Hybrid cell's shape
+    (T 4,096, 30 heads of 96 × 192, bf16 products), compiled for the
+    chip: Mosaic takes the four kernels at the real widths, and of the
+    (heads, chunks, 64, 64) arrays a chunk's algebra is made of the
+    program writes only what the kernels hand on — P, its bf16 cast
+    and its cotangent (one product), and (I + L)⁻¹ — where
+    ``jax.numpy`` under autodiff wrote sixty (PERF.md §6, PR 32)."""
+    import jax
+    import jax.numpy as jnp
+
+    from znicz_tpu.ops import pallas_delta
+    b, t, h, dk, dv = 1, 4096, 30, 96, 192
+
+    def struct(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=v5e_chip)
+
+    def step(do, *rows):
+        out, pullback = jax.vjp(
+            lambda *r: pallas_delta.gated_delta_rule(
+                *r, kernel=True, dot_dtype=jnp.bfloat16), *rows)
+        return out, pullback(do)
+
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:   # a described chip's executable cannot be read back here
+        text = jax.jit(step).lower(
+            struct(b, t, h, dv), struct(b, t, h, dk), struct(b, t, h, dk),
+            struct(b, t, h, dv), struct(b, t, h), struct(b, t, h)) \
+            .compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    for kernel in ("znicz_gdr_chunk_fwd", "znicz_gdr_chunk_bwd",
+                   "znicz_delta_state_fwd", "znicz_delta_state_bwd"):
+        assert re.search(rf"%\w*{kernel}", text), kernel
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    chunk_matrices = re.compile(
+        r"^(?:ROOT )?%\S+ = \w+\[(?:30,64|1920),64,64\]\S* (\w[\w\-]*)\(")
+    written = [m.group(1) for m in map(
+        chunk_matrices.match, map(str.strip, entry.splitlines())) if m]
+    # a view costs nothing; copy-start / -done is XLA's own prefetch
+    written = [op for op in written if op not in (
+        "bitcast", "get-tuple-element", "copy-start", "copy-done")]
+    assert sorted(written) == ["convert", "fusion"], written
+
+
 def test_vote_verdict_clean_selfbad_majority_tie():
     v = integrity.vote_verdict([1.0, 1.0, 1.0], [1.0, 1.0, 1.0], 1e-3)
     assert v == {"divergent": False, "culprits": [], "self_bad": []}

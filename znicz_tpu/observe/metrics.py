@@ -650,12 +650,16 @@ def delta_scan(unit: str, stat: str) -> Gauge:
     nothing is padded or no kernel runs, 1.78 at 96 × 192;
     ``state_mb``: MB of per-chunk states kept for the backward;
     ``path``: 1 the ``znicz_delta_state_*`` kernels, 0 the plain
-    scan).  Static per program, set once at ``initialize``."""
+    scan; ``chunk_path``: 1 the ``znicz_gdr_chunk_*`` kernels for what
+    is local to a chunk — Γ, the triangular inverse, W, U, K̂, Qc, P —
+    0 ``jax.numpy`` under autodiff).  Static per program, set once at
+    ``initialize``."""
     return REGISTRY.gauge(
         "znicz_delta_scan",
         "Chunked state scan of a gated-delta-rule layer: chunk length, "
         "chunks walked, state size, the kernels' tile padding, MB of "
-        "states kept for the backward, kernels (1) or plain scan (0)",
+        "states kept for the backward, kernels (1) or plain scan (0) "
+        "for the walk and for what is local to a chunk",
         labels=("unit", "stat")).labels(unit=unit, stat=stat)
 
 

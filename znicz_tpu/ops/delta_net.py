@@ -23,19 +23,23 @@ along the sequence instead of a cache of keys.
     out = x + y                 (post_norm="rms": x + RMSNorm(y))
 
 The recurrence runs in the chunked form of ``ops/pallas_delta.py``
-(chunks of 64 positions; exactly the recurrence in exact arithmetic):
-on a TPU the walk from chunk to chunk is the ``znicz_delta_state_*``
-kernels, elsewhere — or for a sequence that is not whole chunks, which
-is padded with positions that write nothing (β 0, α 1) — the same
-algebra as a ``lax.scan``.  Never another formula.
+(chunks of 64 positions; exactly the recurrence in exact arithmetic).
+On a TPU it is four kernels: ``znicz_gdr_chunk_fwd`` / ``_bwd`` compute
+what is local to a chunk (Γ, the triangular inverse, W, U, K̂, Qc, P —
+every (64, 64) matrix in VMEM), ``znicz_delta_state_fwd`` / ``_bwd``
+walk the state from chunk to chunk; elsewhere, or on a mesh, the same
+algebra in ``jax.numpy`` and a ``lax.scan``.  A sequence that is not
+whole chunks is padded with positions that write nothing (β 0, α 1) on
+either path.  Never another formula.
 
-Precision: the projections take the unit's matmul inputs (bf16 in
-mixed precision) with f32 accumulation; the convolution, the norms, the
-gates, the decay's logarithms and their sums, Γ, the triangular inverse
-(f32 matmuls at the highest precision) and the state stay f32.  In
-mixed precision the products into W, U, V′, O and the state's update
-take bf16 INPUTS with f32 accumulation: the state itself is
-accumulated and stored f32.
+Precision, the same on both paths: the projections take the unit's
+matmul inputs (bf16 in mixed precision) with f32 accumulation; the
+convolution, the norms, the gates, the decay's logarithms and their
+sums, Γ, K Kᵀ, the triangular inverse (f32 matmuls at the highest
+precision, in the kernels too) and the state stay f32.  In mixed
+precision the products into W, U, P, V′, O and the state's update take
+bf16 INPUTS with f32 accumulation: the state itself is accumulated and
+stored f32.
 
 Parameters: ``weights`` (D, H·(2 d_k + d_v)) = W_q ‖ W_k ‖ W_v,
 ``weights_conv`` (H·(2 d_k + d_v), J), ``weights_gate`` (D, H·d_v),
@@ -172,7 +176,9 @@ class GatedDeltaNet(Forward):
                           *(getattr(self, a) for a in self.EXPORT_PARAMS))
 
     def _resolve_path(self, t: int) -> None:
-        """Kernels or the plain scan, once per ``initialize``; the
+        """Kernels or ``jax.numpy`` and the plain scan, once per
+        ``initialize`` and by one rule for what is local to a chunk
+        (``chunk_path``) and the walk over the chunks (``path``); the
         gauge ``znicz_delta_scan`` and the info line say which."""
         from znicz_tpu.ops import pallas_kernels
         from znicz_tpu.utils.config import root
@@ -197,7 +203,8 @@ class GatedDeltaNet(Forward):
             "padded_share": pallas_delta.padded_share(dk, dv)
             if self._kernels else 1.0,
             "state_mb": b * self.n_heads * chunks * dk * dv * 4 / 1e6,
-            "path": 1.0 if self._kernels else 0.0}
+            "path": 1.0 if self._kernels else 0.0,
+            "chunk_path": 1.0 if self._kernels else 0.0}
         for stat, value in stats.items():
             _metrics.delta_scan(self.name, stat).set(value)
         self.info(
@@ -207,7 +214,8 @@ class GatedDeltaNet(Forward):
             self.name, chunks, chunk, self.n_heads, dk, dv,
             f", {chunks * chunk - t} positions of padding"
             if t % chunk else "",
-            "znicz_delta_state_fwd / _bwd kernels"
+            "znicz_gdr_chunk_fwd / _bwd kernels for what is local to a "
+            "chunk, znicz_delta_state_fwd / _bwd for the walk"
             + (" (interpreted)" if interpret else "")
             if self._kernels else f"plain scan ({refused})",
             stats["state_mb"], stats["padded_share"])
@@ -321,7 +329,8 @@ class GatedDeltaNet(Forward):
 
 class GDGatedDeltaNet(GDMoE):
     """Backward of :class:`GatedDeltaNet`: the forward's stashed
-    pullback (autodiff around the two state kernels' own ``custom_vjp``),
+    pullback (autodiff around the ``custom_vjp`` of the chunk-local
+    kernels and of the state kernels),
     every parameter through the base's update rule.  There is no
     analytic numpy backward: the numpy path differentiates the XLA
     forward on the host (the recurrence is checked against

@@ -1,6 +1,8 @@
 """The gated delta rule in its chunked form (Yang, Kautz, Hatamizadeh
-2024, arXiv:2412.06464): what is local to a chunk in plain
-``jax.numpy``, the state handed from chunk to chunk as Pallas kernels.
+2024, arXiv:2412.06464) as four Pallas kernels: what is local to a
+chunk — forward and backward — and the state handed from chunk to
+chunk — forward and reverse; the same algebra in plain ``jax.numpy``
+beside them.
 
 Per head, with keys q_t, k_t of d_k, values v_t of d_v, a decay
 α_t ∈ (0, 1) and a write strength β_t, the recurrence is
@@ -18,26 +20,41 @@ the chunk's rows:
 
     A  = (I + strict_lower(diag(β)(Γ ⊙ K Kᵀ)))⁻¹ diag(β)
     W  = A (exp(c) ⊙ K)        U = A V         K̂ = K ⊙ exp(c_C − c)
+    Qc = exp(c) ⊙ Q            P = Q Kᵀ ⊙ Γ, lower triangle         (·)
     V′ = U − W S_n             S_{n+1} = exp(c_C) S_n + K̂ᵀ V′     (*)
-    O  = (exp(c) ⊙ Q) S_n + (Q Kᵀ ⊙ Γ, lower triangle) V′
+    O  = Qc S_n + P V′
 
 The matrix under the inverse is unit lower triangular, its strict part
 L nilpotent.  Its inverse is built by halves from the 2 × 2 blocks of
-the diagonal (:func:`unit_lower_inverse`): ten whole-chunk batched
-matmuls on the MXU at C 64, differentiable, no triangular solve — and
-not the series Π_p (I + (−L)^(2^p)), whose high powers cancel too many
-digits.
+the diagonal (:func:`unit_lower_inverse`): ten whole-chunk matmuls on
+the MXU at C 64, no triangular solve — and not the series
+Π_p (I + (−L)^(2^p)), whose high powers cancel too many digits.
 
-Only (*) is sequential, and only (*) is a kernel:
-``znicz_delta_state_fwd`` walks a head's chunks along the grid's last
-axis with S in VMEM and writes V′ and, for the backward, every chunk's
-S_n; ``znicz_delta_state_bwd`` walks them in reverse with the state's
-cotangent carried.  Everything around them is ``jax.numpy`` under
-autodiff (the (C, C) matrices a chunk keeps for it are 31 MB each a
-layer at T 4,096 × 30 heads; the inverse keeps ONE, its result, and has
-its own derivative rule).  :func:`state_scan` without the kernels is
-the same algebra as a ``lax.scan`` over the chunks — the path off a
-TPU.
+(·) is local to a chunk.  On a TPU it is ``znicz_gdr_chunk_fwd``: a
+grid step takes ``CHUNKS_PER_STEP`` chunks' q, k, v, log α and β, and
+Γ, K Kᵀ, L, the levels of the inverse and A live and die in VMEM; it
+writes W, K̂, U, exp(c_C), Qc, P and ONE (C, C) matrix a chunk for the
+backward, (I + L)⁻¹ (31 MB a layer at T 4,096 × 30 heads; under
+autodiff Γ, K Kᵀ, L, the inverse and P's factors were kept, and every
+level of the inverse was a round trip of such an array through HBM).
+``znicz_gdr_chunk_bwd`` is written by hand under one ``custom_vjp``:
+from the cotangents of the six outputs and that matrix to those of q,
+k, v, log α and β, with d(M⁻¹) = −M⁻ᵀ dM M⁻ᵀ; Γ and K Kᵀ are computed
+again, nothing of shape (C, C) is written.  Precision is
+:func:`chunk_local`'s: the logarithms' sums (each from its own terms,
+a 0/1 product, never a difference of prefixes), Γ, K Kᵀ, the inverse
+and their cotangents f32 with f32 products at the highest precision;
+the products into W, U and P, and their transposes in the backward,
+take ``dot_dtype`` inputs with f32 accumulation.  :func:`chunk_local`
+is the path off a TPU and on a mesh, and the kernels' oracle.
+
+(*) is sequential: ``znicz_delta_state_fwd`` walks a head's chunks
+along the grid's last axis with S in VMEM and writes V′ and, for the
+backward, every chunk's S_n; ``znicz_delta_state_bwd`` walks them in
+reverse with the state's cotangent carried.  :func:`state_scan`
+without the kernels is the same algebra as a ``lax.scan`` over the
+chunks.  O's two products and the moves between (B, T, H, ·) and the
+chunked view are ``jax.numpy`` under autodiff.
 
 Head sizes need not fill a 128-lane tile: a block spans a whole
 (C, d_k) or (d_k, d_v) face of its array, which Mosaic lays out in
@@ -49,6 +66,7 @@ HBM holds no padding.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -202,6 +220,359 @@ def chunk_local(q, k, v, log_alpha, beta, dot_dtype=None):
 
 
 # ----------------------------------------------------------------------
+# what is local to a chunk: kernels
+# ----------------------------------------------------------------------
+#: chunks a grid step of the chunk-local kernels (≈ 7 MB of
+#: double-buffered blocks in the backward at 96 × 192; 4, 8 and 16 read
+#: the same on the chip to 2%: the kernels are bound by the MXU's
+#: passes, not by a grid step's fixed cost — PERF.md §6, PR 32)
+CHUNKS_PER_STEP = 8
+#: of which this many stand in one basic block (:func:`_over_chunks`):
+#: independent chains of the inverse for the scheduler to interleave
+#: (2 is 3% slower on the chip, 8 2% faster and twice the lowering)
+_TOGETHER = 4
+
+
+def _dot(a, b, dot_dtype, trans_a=False, trans_b=False, exact=False):
+    """2-D ``a @ b`` inside a kernel (either side transposed): inputs
+    in ``dot_dtype`` with f32 accumulation, or f32 at the highest
+    precision where ``exact``."""
+    dims = (((0,) if trans_a else (1,), (1,) if trans_b else (0,)),
+            ((), ()))
+    if exact:
+        return jax.lax.dot_general(a, b, dims, precision=_HIGHEST,
+                                   preferred_element_type=jnp.float32)
+    if dot_dtype is not None:
+        a, b = a.astype(dot_dtype), b.astype(dot_dtype)
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+#: a kernel's 2-D product in f32 at the highest precision
+_exact = functools.partial(_dot, dot_dtype=None, exact=True)
+
+
+def _mixed(dot_dtype):
+    """A kernel's 2-D product that takes ``dot_dtype`` inputs (f32 at
+    the highest precision where there is none)."""
+    return _exact if dot_dtype is None else functools.partial(
+        _dot, dot_dtype=dot_dtype)
+
+
+def _ones_where(condition):
+    """0/1 in f32: a mask to multiply by.  (A ``jnp.where`` or an
+    integer ``//`` in a kernel's body is a nested call that the host
+    traces and lowers once per use, a third of a process's start for
+    these kernels; a product is one equation.)"""
+    return condition.astype(jnp.float32)
+
+
+def _positions(c: int):
+    """A (C, C) matrix's entries on and below the diagonal, strictly
+    below, strictly above, and on it, as 0/1."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    return (_ones_where(col <= row), _ones_where(col < row),
+            _ones_where(col > row), _ones_where(col == row))
+
+
+def _rows(x):
+    """Σ over a row's entries, (C, ·) → (C, 1)."""
+    return jnp.sum(x, axis=1, keepdims=True)
+
+
+def _cols(x):
+    """Σ over a column's entries, (C, ·) → (1, ·)."""
+    return jnp.sum(x, axis=0, keepdims=True)
+
+
+def _inverse_levels(c: int, n: int):
+    """0/1 masks of :func:`_inverses_in_vmem` for ``n`` (C, C) matrices
+    side by side, from the indices: the identity; per level of sizes
+    1, 2, 4, … < C the blocks below the diagonal that join two inverted
+    blocks (:func:`_pair_masks`); and each matrix's own lanes."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, n * c), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (c, n * c), 1)
+    col = lane & (c - 1)                  # within a lane's own matrix
+    joins, size, shift = [], 1, 1
+    while size < c:
+        joins.append(_ones_where(
+            (row >> shift == col >> shift) & (row & size != 0)
+            & (col & size == 0)))
+        size, shift = 2 * size, shift + 1
+    own = [_ones_where(lane >> (shift - 1) == m) for m in range(n)]
+    return _ones_where(row == col), joins, own
+
+
+def _inverses_in_vmem(lowers, levels):
+    """:func:`unit_lower_inverse` on one (C, C) matrix, or on several
+    side by side in the lanes of one (C, n·C) array — two chunks of 64
+    fill a 128-lane tile, and a product of the chain then costs the MXU
+    what one chunk's did (0.10 against 0.18 µs a chunk on the chip;
+    PERF.md §6, PR 32).  The first level is I − B exactly (I B I), the
+    others X − X B X with B and X each on the diagonal of an
+    (n·C, n·C) right operand; ``levels`` from :func:`_inverse_levels`."""
+    c, n = lowers[0].shape[0], len(lowers)
+    eye, joins, own = levels
+    lower = lowers[0] if n == 1 else jnp.concatenate(lowers, axis=1)
+
+    def diagonal(x):    # every matrix opposite its own lanes' rows
+        return x if n == 1 else jnp.concatenate(
+            [x * lanes for lanes in own], axis=0)
+
+    x = eye - lower * joins[0]
+    for join in joins[1:]:
+        x = x - _exact(_exact(x, diagonal(lower * join)), diagonal(x))
+    return [x[:, m * c:(m + 1) * c] for m in range(n)]
+
+
+def _decays(log_alpha, upto, below, above):
+    """From a chunk's log α as a row (1, C), every sum from its own
+    terms: Γ (C, C), exp(c) and exp(c_C − c) as columns (C, 1), exp(c_C)
+    (1, 1)."""
+    own = upto * log_alpha                             # [i, m]: m ≤ i
+    gamma = upto * jnp.exp(_exact(own, below))    # Σ_{j<m≤i} log α_m
+    return (gamma, jnp.exp(_rows(own)), jnp.exp(_rows(above * log_alpha)),
+            jnp.exp(_rows(log_alpha)))
+
+
+def _over_chunks(block: int, together: int, step: int, some) -> None:
+    """``some(first)`` for every ``step`` of a grid step's ``block``
+    chunks, ``together`` chunks in one basic block (Mosaic unrolls a
+    loop whole or not at all): independent chains for the scheduler to
+    interleave."""
+    assert together % step == 0, (together, step)
+
+    def group(at, carry):
+        for first in range(0, together, step):
+            some(at * together + first)
+        return carry
+    jax.lax.fori_loop(0, block // together, group, None)
+
+
+# A grid step's chunks all do the same arithmetic on their own rows.
+# It is written as jitted functions of VALUES, called once per chunk
+# (or pair) from the kernels' bodies: the host traces each once and
+# the body holds a call per chunk, where the same lines written into
+# the body were traced once per chunk of a basic block — seconds of
+# every process's start on the chip's host (PERF.md §6, PR 32).
+@jax.jit
+def _chunk_lower(positions, log_alpha, beta, k):
+    """A chunk's decays (:func:`_decays`) and its L."""
+    upto, below, above, eye = positions
+    gamma, grown, rest, decay = _decays(log_alpha, upto, below, above)
+    # β as a column: (1, C) → (C, 1) without a transpose
+    lower = below * (_rows(eye * beta) * gamma
+                     * _exact(k, k, trans_b=True))
+    return gamma, grown, rest, decay, lower
+
+
+_inverses = jax.jit(_inverses_in_vmem)
+
+
+@functools.partial(jax.jit, static_argnames=("dot_dtype",))
+def _chunk_outputs(q, k, v, beta, x, gamma, grown, rest, *, dot_dtype):
+    """W, K̂, U, Qc, P from a chunk's rows, decays and (I + L)⁻¹."""
+    mixed = _mixed(dot_dtype)
+    a = x * beta
+    return (mixed(a, grown * k), rest * k, mixed(a, v), grown * q,
+            mixed(q, k, trans_b=True) * gamma)
+
+
+def _chunk_fwd_kernel(q_ref, k_ref, v_ref, a_ref, b_ref, w_ref, kh_ref,
+                      u_ref, d_ref, qc_ref, p_ref, x_ref, *, dot_dtype,
+                      together, side_by_side):
+    block, c = q_ref.shape[0], q_ref.shape[1]
+    positions = _positions(c)
+    levels = _inverse_levels(c, side_by_side)
+
+    def some(first):
+        at = [first + m for m in range(side_by_side)]
+        held = [_chunk_lower(positions, a_ref[i], b_ref[i], k_ref[i])
+                for i in at]
+        inverses = _inverses([lower for *_, lower in held], levels)
+        for i, (gamma, grown, rest, decay, _), x in zip(at, held,
+                                                        inverses):
+            x_ref[i] = x
+            w_ref[i], kh_ref[i], u_ref[i], qc_ref[i], p_ref[i] = \
+                _chunk_outputs(q_ref[i], k_ref[i], v_ref[i], b_ref[i], x,
+                               gamma, grown, rest, dot_dtype=dot_dtype)
+            d_ref[i] = jnp.broadcast_to(decay, (1, c))
+
+    _over_chunks(block, together, side_by_side, some)
+
+
+@functools.partial(jax.jit, static_argnames=("dot_dtype",))
+def _chunk_cotangents(positions, q, k, v, log_alpha, beta, x, d_w, d_kh,
+                      d_u, d_decay, d_qc, d_p, *, dot_dtype):
+    """Cotangents of a chunk's q, k, v, log α, β from those of its W, K̂,
+    U, decay (a row), Qc and P, and its (I + L)⁻¹."""
+    upto, below, above, eye = positions
+    exact, mixed = _exact, _mixed(dot_dtype)
+    gamma, grown, rest, decay = _decays(log_alpha, upto, below, above)
+    beta_col = _rows(eye * beta)
+    kk = exact(k, k, trans_b=True)
+    a = x * beta
+    # W = A (exp(c) ⊙ K), U = A V
+    d_a = mixed(d_w, grown * k, trans_b=True) \
+        + mixed(d_u, v, trans_b=True)
+    d_kg = mixed(a, d_w, trans_a=True)
+    d_v = mixed(a, d_u, trans_a=True)
+    # d(M⁻¹) = −M⁻ᵀ dM M⁻ᵀ, on the strictly lower part
+    d_lower = -below * exact(exact(x, d_a * beta, trans_a=True), x,
+                             trans_b=True)
+    # L = β_i Γ_ij (K Kᵀ)_ij;  P = Q Kᵀ ⊙ Γ
+    d_gamma = d_lower * beta_col * kk + d_p * mixed(q, k, trans_b=True)
+    d_kk = d_lower * beta_col * gamma
+    d_qk = d_p * gamma
+    d_q = mixed(d_qk, k) + grown * d_qc
+    d_k = exact(d_kk, k) + exact(d_kk, k, trans_a=True) \
+        + mixed(d_qk, q, trans_a=True) + grown * d_kg + rest * d_kh
+    # β: a column's sum where it scales A's columns, a row's (as a
+    # row: (C, 1) → (1, C) without a transpose) where L's rows
+    d_beta = _cols(d_a * x) + _cols(eye * _rows(d_lower * gamma * kk))
+    # log α: Γ = exp(Σ_{j<m≤i}), exp(c), exp(c_C − c), exp(c_C)
+    d_upto = exact(d_gamma * gamma, below, trans_b=True)
+    d_c = grown * (_rows(d_qc * q) + _rows(d_kg * k))
+    d_rest = rest * _rows(d_kh * k)
+    d_alpha = _cols(upto * (d_upto + d_c) + above * d_rest) \
+        + d_decay * decay
+    return d_q, d_k, d_v, d_alpha, d_beta
+
+
+def _chunk_bwd_kernel(*refs, dot_dtype, together):
+    """:func:`_chunk_cotangents` for a grid step's chunks: the five
+    rows, (I + L)⁻¹ — the one (C, C) matrix kept — and six cotangents
+    in, five cotangents out."""
+    ins, outs = refs[:12], refs[12:]
+    block, c = ins[0].shape[0], ins[0].shape[1]
+    positions = _positions(c)
+
+    def one(i):
+        results = _chunk_cotangents(
+            positions, *(ref[i] for ref in ins), dot_dtype=dot_dtype)
+        for ref, result in zip(outs, results):
+            ref[i] = result
+
+    _over_chunks(block, together, 1, one)
+
+
+def _chunk_call(kernel, name: str, arrays, outs, block: int, interpret):
+    """``kernel`` over (chunks, rows, width) ``arrays``, ``block``
+    chunks a grid step (the last may hold fewer), to f32 results of
+    ``outs`` = (rows, width) each: independent steps, and room in VMEM
+    for their double-buffered blocks."""
+    total = arrays[0].shape[0]
+    faces = [a.shape[1:] for a in arrays] + list(outs)
+    held = sum(-(-rows // _SUBLANES) * _SUBLANES
+               * -(-width // _LANES) * _LANES * 4 for rows, width in faces)
+
+    def spec(face):
+        return pl.BlockSpec((block,) + tuple(face), lambda n: (n, 0, 0))
+
+    return pl.pallas_call(
+        kernel, grid=(pl.cdiv(total, block),),
+        in_specs=[spec(a.shape[1:]) for a in arrays],
+        out_specs=tuple(spec(face) for face in outs),
+        out_shape=tuple(jax.ShapeDtypeStruct((total,) + face, jnp.float32)
+                        for face in outs),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=2 * block * held + (24 << 20)),
+        interpret=interpret, name=name)(*arrays)
+
+
+def _flat(a, c: int):
+    """(G, N, C, ·) → (G·N, C, ·); a chunk's vector (G, N, C) as a row
+    of lanes, (G·N, 1, C)."""
+    total = a.shape[0] * a.shape[1]
+    return a.reshape((total, 1, c) if a.ndim == 3
+                     else (total,) + a.shape[2:])
+
+
+# jitted: a model's linear layers call these with the same shapes and
+# static arguments, so each kernel is traced and lowered once per
+# program, not once per layer (ROADMAP S6)
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
+def _chunk_forward_call(q, k, v, log_alpha, beta, interpret, dot_dtype,
+                        block):
+    """W, K̂, U, decay, Qc, P of :func:`chunk_local` and (I + L)⁻¹ for
+    the backward, everything else of a chunk's (C, C) in VMEM."""
+    g, n, c, dk = q.shape
+    dv, block = v.shape[-1], min(block, g * n)
+    side_by_side = 2 if block % 2 == 0 and 2 * c <= _LANES else 1
+    w, k_hat, u, decay, qc, p, x = _chunk_call(
+        functools.partial(
+            _chunk_fwd_kernel, dot_dtype=dot_dtype,
+            together=max(math.gcd(block, _TOGETHER), side_by_side),
+            side_by_side=side_by_side),
+        "znicz_gdr_chunk_fwd",
+        [_flat(a, c) for a in (q, k, v, log_alpha, beta)],
+        [(c, dk), (c, dk), (c, dv), (1, c), (c, dk), (c, c), (c, c)],
+        block, interpret)
+
+    def heads(a):
+        return a.reshape((g, n) + a.shape[1:])
+
+    return (heads(w), heads(k_hat), heads(u),
+            decay[:, 0, 0].reshape(g, n), heads(qc), heads(p)), heads(x)
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9))
+def _chunk_backward_call(q, k, v, log_alpha, beta, x, cotangent,
+                         interpret, dot_dtype, block):
+    g, n, c, dk = q.shape
+    dv, block = v.shape[-1], min(block, g * n)
+    d_w, d_kh, d_u, d_decay, d_qc, d_p = cotangent
+    d_decay = jnp.broadcast_to(d_decay[..., None], (g, n, c))
+    d_q, d_k, d_v, d_a, d_b = _chunk_call(
+        functools.partial(_chunk_bwd_kernel, dot_dtype=dot_dtype,
+                          together=math.gcd(block, _TOGETHER)),
+        "znicz_gdr_chunk_bwd",
+        [_flat(a, c) for a in (q, k, v, log_alpha, beta, x, d_w, d_kh,
+                               d_u, d_decay, d_qc, d_p)],
+        [(c, dk), (c, dk), (c, dv), (1, c), (1, c)], block, interpret)
+    return (d_q.reshape(q.shape), d_k.reshape(k.shape),
+            d_v.reshape(v.shape), d_a.reshape(g, n, c),
+            d_b.reshape(g, n, c))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _chunk_local_kernels(q, k, v, log_alpha, beta, interpret, dot_dtype,
+                         block):
+    return _chunk_forward_call(q, k, v, log_alpha, beta, interpret,
+                               dot_dtype, block)[0]
+
+
+def _chunk_fwd(q, k, v, log_alpha, beta, interpret, dot_dtype, block):
+    out, x = _chunk_forward_call(q, k, v, log_alpha, beta, interpret,
+                                 dot_dtype, block)
+    return out, (q, k, v, log_alpha, beta, x)
+
+
+def _chunk_bwd(interpret, dot_dtype, block, residual, cotangent):
+    return _chunk_backward_call(*residual, cotangent, interpret,
+                                dot_dtype, block)
+
+
+_chunk_local_kernels.defvjp(_chunk_fwd, _chunk_bwd)
+
+
+def chunk_local_kernels(q, k, v, log_alpha, beta, dot_dtype=None,
+                        interpret: bool = False,
+                        block: int = CHUNKS_PER_STEP):
+    """:func:`chunk_local` as ``znicz_gdr_chunk_fwd`` and, under
+    differentiation, ``znicz_gdr_chunk_bwd``: ``block`` chunks a grid
+    step (the last step may hold fewer), one (C, C) matrix a chunk kept
+    between them."""
+    f32 = jnp.float32
+    return _chunk_local_kernels(
+        q.astype(f32), k.astype(f32), v.astype(f32),
+        log_alpha.astype(f32), beta.astype(f32), interpret,
+        None if dot_dtype is None else jnp.dtype(dot_dtype), block)
+
+
+# ----------------------------------------------------------------------
 # the walk over the chunks: plain
 # ----------------------------------------------------------------------
 def _state_scan_plain(w, k_hat, u, decay, dot_dtype):
@@ -222,15 +593,6 @@ def _state_scan_plain(w, k_hat, u, decay, dot_dtype):
 # ----------------------------------------------------------------------
 # the walk over the chunks: kernels
 # ----------------------------------------------------------------------
-def _dot(a, b, dot_dtype, trans_a=False, trans_b=False):
-    dims = (((0,) if trans_a else (1,), (1,) if trans_b else (0,)),
-            ((), ()))
-    if dot_dtype is not None:
-        a, b = a.astype(dot_dtype), b.astype(dot_dtype)
-    return jax.lax.dot_general(a, b, dims,
-                               preferred_element_type=jnp.float32)
-
-
 def _fwd_kernel(w_ref, k_ref, u_ref, d_ref, v_ref, s_ref, state, *,
                 dot_dtype):
     @pl.when(pl.program_id(1) == 0)
@@ -381,9 +743,11 @@ def gated_delta_rule(q, k, v, log_alpha, beta, chunk: int = CHUNK,
         a = jnp.moveaxis(a.astype(jnp.float32), 2, 1)
         return a.reshape((b * h, n, chunk) + a.shape[3:])
 
-    w, k_hat, u, decay, q_grown, p = chunk_local(
-        chunks(q), chunks(k), chunks(v), chunks(log_alpha), chunks(beta),
-        dot_dtype)
+    rows = (chunks(q), chunks(k), chunks(v), chunks(log_alpha),
+            chunks(beta))
+    w, k_hat, u, decay, q_grown, p = chunk_local_kernels(
+        *rows, dot_dtype, interpret) if kernel \
+        else chunk_local(*rows, dot_dtype)
     v_new, states = state_scan(w, k_hat, u, decay, kernel, interpret,
                                dot_dtype)
     o = _mm(q_grown, states, dot_dtype) + _mm(p, v_new, dot_dtype)
