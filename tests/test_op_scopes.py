@@ -1,0 +1,356 @@
+"""``observe.op_scopes()``: which unit, and which phase of it, every
+HLO instruction of the compiled region programs belongs to — the
+program's own key to a profile's ``fusion.362``."""
+
+import gc
+import json
+import weakref
+
+import jax
+import numpy as np
+import pytest
+
+from znicz_tpu import observe
+from znicz_tpu.backends import XLADevice
+from znicz_tpu.loader.fullbatch import ArrayLoader
+from znicz_tpu.models.standard_workflow import StandardWorkflow
+from znicz_tpu.observe import metrics as obs_metrics
+from znicz_tpu.observe import scopes
+from znicz_tpu.utils import prng
+from znicz_tpu.utils.config import root
+
+GD = {"learning_rate": 0.05, "gradient_moment": 0.9}
+
+
+@pytest.fixture(autouse=True)
+def _own_records():
+    """Every test reads the programs IT dispatched; compile counters
+    are deltas, so the opt-in suite store stays out."""
+    root.common.engine.aot_cache = False
+    scopes.forget()
+    yield
+    scopes.forget()
+
+
+def conv_dense(name: str, epochs: int = 1, **loader) -> StandardWorkflow:
+    rng = np.random.default_rng(0)
+    data = rng.normal(size=(64, 8, 8, 3)).astype(np.float32)
+    labels = rng.integers(0, 4, 64).astype(np.int32)
+    prng.seed_all(3)
+    wf = StandardWorkflow(
+        name=name,
+        loader_factory=lambda w: ArrayLoader(
+            w, train_data=data, train_labels=labels, minibatch_size=4,
+            **loader),
+        layers=[{"type": "conv_relu",
+                 "->": {"n_kernels": 4, "kx": 3, "ky": 3}, "<-": GD},
+                {"type": "max_pooling", "->": {"kx": 2, "ky": 2}},
+                {"type": "all2all_tanh",
+                 "->": {"output_sample_shape": 8}, "<-": GD},
+                {"type": "softmax", "->": {"output_sample_shape": 4},
+                 "<-": GD}],
+        decision_config={"max_epochs": epochs})
+    wf._max_fires = 100_000
+    wf.initialize(device=XLADevice())
+    return wf
+
+
+def attention_moe(name: str) -> StandardWorkflow:
+    vocab, seq, dim = 29, 8, 16
+    ids = np.random.default_rng(6).integers(0, vocab, (8, seq + 1))
+    prng.seed_all(21)
+    wf = StandardWorkflow(
+        name=name,
+        loader_factory=lambda w: ArrayLoader(
+            w, train_data=ids[:, :-1].astype(np.float32),
+            train_labels=ids[:, 1:].astype(np.int32),
+            minibatch_size=4, shuffle_limit=0),
+        layers=[
+            {"type": "embedding",
+             "->": {"vocab_size": vocab, "dim": dim}, "<-": GD},
+            {"type": "attention",
+             "->": {"n_heads": 2, "causal": True, "include_bias": False,
+                    "pre_norm": "rms", "residual": True}, "<-": GD},
+            {"type": "moe",
+             "->": {"n_experts": 4, "top_k": 2, "width": 16,
+                    "pre_norm": "rms", "residual": True}, "<-": GD},
+            {"type": "softmax",
+             "->": {"output_sample_shape": vocab, "per_position": True,
+                    "include_bias": False}, "<-": GD}],
+        decision_config={"max_epochs": 1})
+    wf.initialize(device=XLADevice())
+    return wf
+
+
+def seen_units(ops: dict) -> set:
+    return {unit for entry in ops.values()
+            for unit in ([entry["unit"]] if entry["unit"]
+                         else entry["units"])}
+
+
+def phases_of(ops: dict, unit: str) -> set:
+    return {e["phase"] for e in ops.values() if e["unit"] == unit}
+
+
+def only_program(prefix: str) -> dict:
+    found = {name: ops for name, ops in observe.op_scopes().items()
+             if name.startswith(prefix)}
+    assert len(found) == 1, sorted(observe.op_scopes())
+    return next(iter(found.values()))
+
+
+# ----------------------------------------------------------------------
+# the map of a step program
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("build", [conv_dense, attention_moe])
+def test_every_member_unit_is_in_the_map(build):
+    wf = build(f"scopes_{build.__name__}")
+    wf.run()
+    region = wf._region_unit.region
+    ops = only_program(f"znicz_step__{region.name}")
+    members = {u.name for u in region.units}
+    assert seen_units(ops) == members
+    # (at this size XLA may fuse ALL of a small unit into a
+    # neighbour's operations: it is then in mixed entries only)
+    by_unit = {e["unit"]: e for e in ops.values() if e["unit"]}
+    kinds = {u.name: type(u).__name__ for u in region.units}
+    assert len(by_unit) >= len(members) - 2
+    assert all(e["kind"] == kinds[name] for name, e in by_unit.items())
+    for fwd, gd in zip(wf.forwards, wf.gds):
+        # a layer's two units are one family: the forward's class
+        assert {by_unit[u.name]["family"] for u in (fwd, gd)
+                if u.name in by_unit} == {type(fwd).__name__}
+        assert phases_of(ops, fwd.name) <= {"forward"}
+        assert phases_of(ops, gd.name) \
+            <= {"backward", "update", "fingerprint"}
+
+
+def test_update_and_fingerprint_are_phases_of_the_backward_unit():
+    wf = conv_dense("scopes_phases")
+    wf.run()
+    ops = only_program("znicz_step__")
+    for gd in wf.gds:
+        if gd.weights is None or not gd.weights:
+            assert phases_of(ops, gd.name) <= {"backward"}, gd.name
+            continue
+        # momentum, decay, the guard's select under ``update``; the
+        # SDC fold inside it under ``fingerprint``; the gradient's
+        # own matmuls stay ``backward``
+        assert {"update", "fingerprint"} <= phases_of(ops, gd.name), \
+            (gd.name, phases_of(ops, gd.name))
+    assert {e["phase"] for e in ops.values() if e["unit"]} \
+        <= {"forward", "backward", "update", "fingerprint"}
+
+
+def test_a_fusion_of_two_units_reads_mixed():
+    wf = conv_dense("scopes_mixed")
+    wf.run()
+    ops = only_program("znicz_step__")
+    mixed = [e for e in ops.values() if e["unit"] is None]
+    members = {u.name for u in wf._region_unit.region.units}
+    assert mixed, "XLA fused nothing across units?"
+    by_name = {u.name: u for u in wf._region_unit.region.units}
+    for entry in mixed:
+        assert set(entry) == {"unit", "units", "kinds", "families",
+                              "phases"}
+        assert len(entry["units"]) > 1 and set(entry["units"]) <= members
+        assert entry["kinds"] == [type(by_name[name]).__name__
+                                  for name in entry["units"]]
+        assert len(entry["families"]) == len(entry["phases"]) \
+            == len(entry["units"])
+
+
+SYNTHETIC = """HloModule jit_znicz_step__r, is_scheduled=true, entry_computation_layout={(f32[8]{0:T(256)})->f32[8]{0:T(256)}}
+
+%region_0.1 (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %add.9 = f32[] add(%a, %b), metadata={op_name="reduce_sum"}
+}
+
+%fused_computation (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0:T(256)} parameter(0)
+  %mul.1 = f32[8]{0:T(256)} multiply(%p, %p), metadata={op_name="jit(znicz_step__r)/GDFc/update/mul"}
+  ROOT %sub.1 = f32[8]{0:T(256)} subtract(%mul.1, %p), metadata={op_name="jit(znicz_step__r)/GDFc/update/fingerprint/sub"}
+}
+
+%fused_computation.1 (p: f32[8]) -> f32[] {
+  %p.1 = f32[8]{0:T(256)} parameter(0)
+  %neg.1 = f32[8]{0:T(256)} negate(%p.1), metadata={op_name="jit(znicz_step__r)/GDFc/transpose(jvp(Fc/dot))/neg"}
+  %c.1 = f32[] constant(0)
+  ROOT %reduce.1 = f32[] reduce(%neg.1, %c.1), dimensions={0}, to_apply=%region_0.1, metadata={op_name="jit(znicz_step__r)/Fc/reduce_sum"}
+}
+
+%body (t: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %t = (s32[], f32[8]{0:T(256)}) parameter(0)
+  %g.1 = f32[8]{0:T(256)} get-tuple-element(%t), index=1
+  %fusion.7 = f32[8]{0:T(256)} fusion(%g.1), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(znicz_step__r)/while/body/GDFc/update/mul"}
+  %g.0 = s32[] get-tuple-element(%t), index=0
+  ROOT %tuple.1 = (s32[], f32[8]{0:T(256)}) tuple(%g.0, %fusion.7)
+}
+
+%cond (t.1: (s32[], f32[8])) -> pred[] {
+  %t.1 = (s32[], f32[8]{0:T(256)}) parameter(0)
+  ROOT %lt.1 = pred[] constant(true)
+}
+
+ENTRY %main.3 (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0:T(256)} parameter(0)
+  %zero = s32[] constant(0)
+  %tuple.0 = (s32[], f32[8]{0:T(256)}) tuple(%zero, %x)
+  %while.1 = (s32[], /*index=1*/f32[8]{0:T(8,128)(2,1)}) while(%tuple.0), condition=%cond, body=%body, metadata={op_name="jit(znicz_step__r)/while"}
+  %fusion.8 = f32[] fusion(%x), kind=kInput, calls=%fused_computation.1, metadata={op_name="jit(znicz_step__r)/Fc/reduce_sum"}
+  %copy.1 = f32[8]{0:T(256)} copy(%x)
+  ROOT %custom-call.1 = f32[8]{0:T(256)} custom-call(%x), custom_call_target="tpu_custom_call", metadata={op_name="jit(znicz_step__r)/Fc/znicz_flash_fwd"}
+}
+"""
+
+
+def test_the_text_is_attributed_by_all_the_fused_instructions():
+    units = (("Fc", "All2All", "All2All", False),
+             ("GDFc", "GradientDescent", "All2All", True))
+    ops = scopes.attribute(SYNTHETIC, units)
+    # a fusion wholly inside ``update`` with a fold in it reads
+    # ``update``; found through the while body (a TPU's tuple type with
+    # tiled layouts does not hide the opcode)
+    assert ops["fusion.7"] == {"unit": "GDFc", "kind": "GradientDescent",
+                               "family": "All2All", "phase": "update"}
+    # its root alone says Fc; the body holds a backward instruction
+    assert ops["fusion.8"] == {
+        "unit": None, "units": ["Fc", "GDFc"],
+        "kinds": ["All2All", "GradientDescent"],
+        "families": ["All2All", "All2All"],
+        "phases": ["forward", "backward"]}
+    assert ops["custom-call.1"]["unit"] == "Fc" \
+        and ops["custom-call.1"]["phase"] == "forward"
+    # no scope, no entry: the scan's while, the compiler's copy, and
+    # nothing from inside a fused computation
+    assert set(ops) == {"fusion.7", "fusion.8", "custom-call.1"}
+
+
+def test_the_outermost_scope_names_the_unit():
+    names = ["Attn", "GDAttn", "update"]
+    assert scopes.scope_of(
+        "jit(p)/GDAttn/transpose(jvp(Attn/rope))/mul", names) \
+        == (1, False, False)
+    assert scopes.scope_of("jit(p)/while/body/Attn/dot", names) \
+        == (0, False, False)
+    assert scopes.scope_of("jit(p)/GDAttn/update/sub", names) \
+        == (1, True, False)
+    assert scopes.scope_of(
+        "jit(p)/GDAttn/update/fingerprint/gather", names) \
+        == (1, True, True)
+    assert scopes.scope_of("jit(p)/while", names) is None
+
+
+# ----------------------------------------------------------------------
+# the other programs of a region
+# ----------------------------------------------------------------------
+def test_chunk_and_accum_programs_keep_their_units():
+    wf = conv_dense("scopes_chunk")
+    wf.run_chunked(16)
+    region = wf._region_unit.region
+    chunk = only_program(f"znicz_chunk16__{region.name}")
+    assert seen_units(chunk) == {u.name for u in region.units}
+    assert "update" in phases_of(chunk, wf.gds[0].name)
+
+    scopes.forget()
+    root.common.engine.grad_accum = 4
+    wf = conv_dense("scopes_accum")
+    wf.run_accumulated()
+    region = wf._region_unit.region
+    accum = only_program(f"znicz_accum4__{region.name}")
+    assert seen_units(accum) == {u.name for u in region.units}
+    assert {"backward", "update", "fingerprint"} \
+        <= phases_of(accum, wf.gds[0].name)
+
+
+def test_train_and_eval_variants_are_two_programs():
+    rng = np.random.default_rng(0)
+    wf = conv_dense(
+        "scopes_variants",
+        valid_data=rng.normal(size=(8, 8, 8, 3)).astype(np.float32),
+        valid_labels=rng.integers(0, 4, 8).astype(np.int32))
+    wf.run()
+    region = wf._region_unit.region
+    found = observe.op_scopes()
+    name = f"znicz_step__{region.name}"
+    assert set(found) == {name, f"{name}#2"}
+    backward = {u.name for u in wf.gds}
+    with_gd = [n for n, ops in found.items()
+               if seen_units(ops) & backward]
+    assert len(with_gd) == 1      # the eval variant skips every GD
+
+
+# ----------------------------------------------------------------------
+# what asking costs, and what not asking costs
+# ----------------------------------------------------------------------
+def test_asking_compiles_nothing_and_is_memoised():
+    built = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, _secs, **kw: built.append(event)
+        if event.endswith("backend_compile_duration") else None)
+    wf = conv_dense("scopes_cost", epochs=2)
+    wf.run()
+    region = wf._region_unit.region
+    compiles = obs_metrics.xla_compiles(f"region:{region.name}")
+    spans = observe.TRACER.mark()
+    before = (compiles.value, len(built), len(region._cache))
+    first = observe.op_scopes()
+    assert first and all(first.values())
+    assert (compiles.value, len(built), len(region._cache)) == before
+    assert not [ev for ev in observe.TRACER.to_chrome_trace(
+        since=spans)["traceEvents"] if ev.get("cat") == "compile"]
+    again = observe.op_scopes()
+    assert all(again[name] is first[name] for name in first)
+    # the text's thunk went with its first reading
+    assert all(p.text is None for p in scopes._PROGRAMS.values())
+    # and the region goes on where it was: no tracer left in a Vector
+    wf.decision.max_epochs = 3
+    wf.decision.complete.value = False
+    wf.run()
+    assert (compiles.value, len(built)) == before[:2]
+
+
+def test_a_retrace_puts_the_vectors_back():
+    wf = conv_dense("scopes_retrace", epochs=2)
+    wf.run()
+    region = wf._region_unit.region
+    leaves = [vec._devmem for vec in region._vectors]
+    jax.clear_caches()      # JAX forgets the lowering: the body re-runs
+    assert only_program("znicz_step__")
+    assert all(vec._devmem is leaf and not vec._tracing
+               for vec, leaf in zip(region._vectors, leaves))
+
+
+def test_the_map_outlives_the_workflow_and_pins_none_of_it():
+    wf = conv_dense("scopes_gone")
+    wf.run()
+    name = f"znicz_step__{wf._region_unit.region.name}"
+    alive = weakref.ref(wf._region_unit.region)
+    del wf
+    gc.collect()
+    assert alive() is None      # a record holds no unit, no Vector
+    assert observe.op_scopes()[name]
+
+
+def test_the_scopes_do_not_hang_on_telemetry():
+    root.common.engine.telemetry = False
+    wf = conv_dense("scopes_quiet")
+    wf.run()
+    ops = only_program("znicz_step__")
+    assert seen_units(ops) == {u.name
+                               for u in wf._region_unit.region.units}
+
+
+def test_profile_window_writes_the_map(tmp_path, monkeypatch):
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda outdir, profiler_options=None: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    wf = conv_dense("scopes_window")
+    with observe.profile_window(str(tmp_path), n_steps=16):
+        wf.run()
+    with open(tmp_path / "op_scopes.json") as fh:
+        written = json.load(fh)
+    assert written == observe.op_scopes() and written
+    assert (tmp_path / "host_spans.trace.json").exists()

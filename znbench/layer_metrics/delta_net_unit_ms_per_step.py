@@ -1,0 +1,15 @@
+"""Device time of the linear-attention mixers per training step: the
+self time of the operations the program's map puts in one unit of
+family ``GatedDeltaNet``, forward + backward (projections, the short
+convolution, norms, gates, the four delta-rule kernels AND the plain
+XLA around them), updates left out, ÷ steps.  Buckets and their
+identity: ``unit_attributed_share``.  Nothing where the program hands
+out no map."""
+
+from znbench.harness import discovery
+
+
+def read(obs):
+    return discovery.load_module(
+        "layer_metrics", "unit_attributed_share").ms_per_step(
+            obs, "delta_net")

@@ -31,6 +31,7 @@ Unit contract for region membership:
 from __future__ import annotations
 
 import re
+import weakref
 from typing import Sequence
 
 import numpy as np
@@ -40,6 +41,7 @@ import jax
 from znicz_tpu.backends import Device, NumpyDevice
 from znicz_tpu.memory import Vector
 from znicz_tpu.observe import metrics as _metrics
+from znicz_tpu.observe import scopes as _scopes
 from znicz_tpu.observe import tracing as _tracing
 from znicz_tpu.units import Unit
 from znicz_tpu.utils import prng
@@ -330,7 +332,9 @@ class JitRegion(Logger):
         (:meth:`_persisted_program`, which counts and spans its own
         eager compile) or from a lazy ``jax.jit`` whose first call is
         the compile — counted on ``znicz_xla_compiles_total`` and
-        spanned ``compile:<region>``; a hit is the cat-``region``
+        spanned ``compile:<region>`` — and is remembered for
+        ``observe.op_scopes()`` (:meth:`_remember`: nothing is lowered
+        for it unless someone asks); a hit is the cat-``region``
         span alone.  Then the step count, then the leaves go back
         into their Vectors.
 
@@ -378,6 +382,12 @@ class JitRegion(Logger):
                        "(%d units, %d leaves)", self.name, variant, key,
                        len(self.units), len(vectors))
             body = build(skips, leaves)
+            # taken before the call: a donated leaf is gone after it
+            structs = [jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype,
+                sharding=getattr(leaf, "sharding", None))
+                for leaf in leaves]
+            jitted = None
             if not checks:  # checkify programs are never persisted
                 fn = self._persisted_program(variant + key, body,
                                              leaves, donate=donate)
@@ -398,10 +408,11 @@ class JitRegion(Logger):
                 _metrics.xla_compiles(f"region:{self.name}").inc()
                 with _tracing.TRACER.span(f"compile:{self.name}",
                                           cat="compile", **span_args):
-                    fn = self._cache[key] = jax.jit(
+                    fn = self._cache[key] = jitted = jax.jit(
                         body, donate_argnums=(
                             tuple(range(len(leaves))) if donate else ()))
                     out = fn(*leaves)
+            self._remember(body, jitted, structs, donate)
         else:
             # the warmed program's call is a span of its own (under
             # wf.run() a child of the region unit's fire): past the few
@@ -417,6 +428,39 @@ class JitRegion(Logger):
         _metrics.region_steps(self.name).inc(count)
         for vec, leaf in zip(vectors, out):
             vec.devmem = leaf
+
+    def _remember(self, body, jitted, structs, donate: bool) -> None:
+        """Hand ``observe.op_scopes()`` what it needs to read this
+        program's compiled text, should anyone ask: the jitted function
+        (a program of the persisted store has none: one is made when
+        asked), the leaves' shapes, dtypes and shardings, donation, and
+        the member units by name, class and family.  Nothing is
+        lowered here.  When asked, the lowering of the SAME jitted
+        function finds JAX's own record of the running executable; a
+        re-trace (JAX dropped that record, or the store's program)
+        runs the body, which leaves tracers in the Vectors — they are
+        put back."""
+        from znicz_tpu.ops.nn_units import family_of
+        ref = weakref.ref(self)
+
+        def text() -> str:
+            region = ref()
+            held = [] if region is None else \
+                [(vec, vec._devmem) for vec in region._vectors]
+            try:
+                fn = jitted if jitted is not None else jax.jit(
+                    body, donate_argnums=(
+                        tuple(range(len(structs))) if donate else ()))
+                return fn.lower(*structs).compile().as_text()
+            finally:
+                for vec, leaf in held:
+                    vec._devmem = leaf
+
+        _scopes.remember(
+            body.__name__,
+            tuple((unit.name, type(unit).__name__) + family_of(unit)
+                  for unit in self.units),
+            text)
 
     def run(self) -> None:
         """One region step: the donated ``znicz_step__<region>``
@@ -457,17 +501,19 @@ class JitRegion(Logger):
         ``step``, or ``accum_micro`` / ``apply_micro`` in a phase)."""
         if self._vectors is None:
             self._vectors = self._collect_vectors()
-        vectors = self._vectors
-        units = self.units
         precision = getattr(self.device, "matmul_precision", "default")
-        # telemetry: trace each member under jax.named_scope so the
-        # compiled program's op metadata carries unit attribution;
-        # resolved at trace time so a cached program keeps whatever
-        # naming it compiled with
-        named = _metrics.enabled()
+        # the body reaches its region through a weak reference: a
+        # jitted body that outlives the workflow (``observe.scopes``
+        # keeps one per program until its text is read) pins no unit
+        # and no Vector
+        ref = weakref.ref(self)
 
         def fn(*leaves):
             global _ACCUM_PHASE
+            region = ref()
+            if region is None:
+                raise RuntimeError("the region of this body is gone")
+            vectors, units = region._vectors, region.units
             prev_phase = _ACCUM_PHASE
             _ACCUM_PHASE = accum_phase
             for vec, leaf in zip(vectors, leaves):
@@ -478,10 +524,13 @@ class JitRegion(Logger):
                     for unit, skip in zip(units, skips):
                         if skip:
                             continue
-                        if named:
-                            with jax.named_scope(unit.name):
-                                unit.xla_run()
-                        else:
+                        # every member traces under its name, always:
+                        # the compiled program's op metadata says which
+                        # unit an instruction belongs to
+                        # (``observe.op_scopes()`` reads it).  A scope
+                        # costs nothing at run time and is not part of
+                        # JAX's cache key
+                        with jax.named_scope(unit.name):
                             unit.xla_run()
                 return tuple(vec._devmem for vec in vectors)
             finally:
@@ -521,8 +570,8 @@ class JitRegion(Logger):
                 self.build_callable(skips), leaves)
 
             def chunk_fn(*leaves):
-                scanned = self._scan_body(body, invariant, leaves,
-                                          n_steps)
+                scanned = JitRegion._scan_body(body, invariant, leaves,
+                                               n_steps)
                 return tuple(scanned)
 
             return self._named(chunk_fn, f"chunk{n_steps}")
@@ -617,8 +666,8 @@ class JitRegion(Logger):
                 skips, accum_phase=("apply", n_micro))
 
             def accum_fn(*leaves):
-                merged = self._scan_body(accum_body, invariant, leaves,
-                                         n_micro - 1)
+                merged = JitRegion._scan_body(accum_body, invariant,
+                                              leaves, n_micro - 1)
                 return apply_body(*merged)
 
             # what the persisted key hashes is the jaxpr of this FULL

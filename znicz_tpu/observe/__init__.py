@@ -16,7 +16,12 @@ introspection — plotters and ``veles/web_status.py``):
   :func:`profile_window`; ``znbench/run.py`` shifts the ring onto a
   device trace and ``znbench/trace_reduce.py`` is the reduction.
 - :func:`profile_window` — capture a ``jax.profiler`` device trace
-  (Python tracer off) + the window's host spans around any region.
+  (Python tracer off) + the window's host spans around any region,
+  and ``op_scopes.json`` beside them.
+- :func:`op_scopes` (:mod:`znicz_tpu.observe.scopes`) — which unit,
+  and which phase of it (forward, backward, ``update``,
+  ``fingerprint``), every HLO instruction of the compiled region
+  programs belongs to: the key to a profile's ``fusion.362``.
 - :mod:`znicz_tpu.observe.recorder` (round 24) — the ops flight
   recorder: a bounded crash-safe JSONL journal of consequential ops
   events (swaps, canary verdicts, restarts, quarantines, breaker
@@ -40,6 +45,7 @@ from znicz_tpu.observe.metrics import (  # noqa: F401
     enabled,
     window_p99,
 )
+from znicz_tpu.observe.scopes import op_scopes  # noqa: F401
 from znicz_tpu.observe.tracing import (  # noqa: F401
     NULL_TRACE,
     TRACER,

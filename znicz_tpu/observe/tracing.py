@@ -27,14 +27,16 @@ offset of one annotation both clocks saw (``znbench/run.py``
 ``host_spans_on_trace_clock``; ``tests/test_observe_spans.py`` pins
 the two copies to within 200 µs).  With no trace open a span costs
 two clock reads and one ring append.  (``jax.named_scope`` is the
-tracing-time cousin: the jit-region builder enters it per member unit
-so device-op metadata carries the unit — see
-``JitRegion.build_callable``.)
+tracing-time cousin: the jit-region builder enters it per member unit,
+always, so device-op metadata carries the unit — see
+``JitRegion.build_callable``; ``observe.op_scopes()`` reads it back
+from the compiled program.)
 
 :func:`profile_window` is the capture helper: a context manager that
 opens a ``jax.profiler`` trace (Python tracer off) around any region
 and drops the window's host spans beside it as
-``host_spans.trace.json``.
+``host_spans.trace.json``, and the region programs' op → unit map as
+``op_scopes.json``.
 
 All recording is gated on :func:`znicz_tpu.observe.metrics.enabled`
 (``root.common.engine.telemetry``); a disabled tracer costs one dict
@@ -394,9 +396,12 @@ def profile_window(outdir: str, n_steps: int | None = None,
     spans around the with-body.
 
     ``outdir`` receives the profiler's trace directory (the
-    ``.xplane.pb`` that ``znbench/trace_reduce.py`` reads) and
+    ``.xplane.pb`` that ``znbench/trace_reduce.py`` reads),
     ``host_spans.trace.json`` (Chrome-trace JSON of the host spans
-    recorded during the window).  The profiler runs with its Python
+    recorded during the window) and, beside a device trace,
+    ``op_scopes.json`` (:func:`znicz_tpu.observe.op_scopes`: the unit
+    and phase behind every operation name of the region programs, so
+    ``fusion.362`` can be looked up).  The profiler runs with its Python
     tracer off: a per-call tracer on a host-bound loop measures
     itself.
     ``n_steps`` is recorded on the window span so per-step math in the
@@ -436,3 +441,7 @@ def profile_window(outdir: str, n_steps: int | None = None,
                 pass
         tracer.export(os.path.join(outdir, "host_spans.trace.json"),
                       since=mark)
+        if device:
+            from znicz_tpu.observe import scopes
+            with open(os.path.join(outdir, "op_scopes.json"), "w") as fh:
+                json.dump(scopes.op_scopes(), fh)

@@ -68,6 +68,22 @@ def gd_for(forward_cls: type) -> Type["GradientDescentBase"]:
     raise KeyError(f"no gradient unit registered for {forward_cls.__name__}")
 
 
+def family_of(unit) -> tuple[str, bool]:
+    """``(family, backward)`` of a region member, for
+    ``observe.op_scopes()``: a backward unit's family is the forward
+    class it is paired with (``MATCHES``), a forward unit's the class
+    the pairing registered (its own, or the parent it inherits the
+    pairing from), so a layer's two units share one; any other unit
+    (loader, evaluator, guard) is its own family."""
+    backward = isinstance(unit, GradientDescentBase)
+    for klass in type(unit).__mro__:
+        if backward and klass.__dict__.get("MATCHES"):
+            return klass.MATCHES[0].__name__, True
+        if not backward and klass in _GD_FOR_FORWARD:
+            return klass.__name__, False
+    return type(unit).__name__, backward
+
+
 # ----------------------------------------------------------------------
 # Forward base
 # ----------------------------------------------------------------------
@@ -487,11 +503,14 @@ class GradientDescentBase(AcceleratedUnit, metaclass=MatchingObject):
         if fpv is None or not fpv:
             return
         from znicz_tpu.resilience.integrity import tensor_fingerprint
-        contrib = tensor_fingerprint(xp, value)
         if xp is np:
-            fpv.mem[slot] += np.float32(contrib)
-        else:
-            fpv.devmem = fpv.devmem.at[slot].add(contrib)
+            fpv.mem[slot] += np.float32(tensor_fingerprint(xp, value))
+            return
+        # the fold's device work has a scope of its own inside
+        # ``update``: ``observe.op_scopes()`` phase ``fingerprint``
+        with jax.named_scope("fingerprint"):
+            fpv.devmem = fpv.devmem.at[slot].add(
+                tensor_fingerprint(xp, value))
 
     def _np_grad_ok(self, grad: np.ndarray) -> bool:
         """Numpy-path mirror of the guard's on-device finite check:
@@ -584,9 +603,13 @@ class GradientDescentBase(AcceleratedUnit, metaclass=MatchingObject):
                               self._lr_bias(xla=True),
                               self.gradient_moment_bias)
 
+    @jax.named_scope("update")
     def _apply_param_xla(self, grad, vec: Vector, acc_vec, decay: float,
                          lr, moment: float) -> None:
-        """One parameter tensor's update on the XLA path.
+        """One parameter tensor's update on the XLA path, traced under
+        the scope ``update`` (either form; the fingerprint folds inside
+        it under ``fingerprint``): ``observe.op_scopes()`` reads the
+        two as phases of the unit.
 
         Two forms, same math (``tests/test_zero1.py`` pins parity):
 
