@@ -139,9 +139,9 @@ def stage_held(t: int, compile_only: bool, sharding) -> None:
 
     def run(rows, w_up, w_down, cot, sizes):
         def f(rows, w_up, w_down):
-            hidden = grouped_matmul(rows, w_up, sizes, True, False, True)
+            hidden = grouped_matmul(rows, w_up, sizes, True, False)
             return grouped_matmul(hidden.astype(jnp.bfloat16), w_down,
-                                  sizes, True, False, True)
+                                  sizes, True, False)
         out, pull = jax.vjp(f, rows, w_up, w_down)
         return (out,) + pull(cot)
 

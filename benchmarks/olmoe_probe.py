@@ -6,12 +6,18 @@
            causal, forward and backward, against the XLA core
            (``parallel.ring_attention.local_attention``): the shape the
            cell runs and that no earlier run had.
-``gmm``    the grouped matmul of the expert layer at the cell's shape
-           (65,536 rows in 64 groups, 2048 × 1024 and 1024 × 2048),
-           forward, row gradient and weight gradient, two arms:
-           ``jax.lax.ragged_dot`` and JAX's Pallas grouped matmul
-           (``jax.experimental.pallas.ops.tpu.megablox``) at a few
-           tilings.  Times are ``block_until_ready`` medians.
+``gmm``    the grouped matmuls of the expert layers at two cells'
+           shapes — ``olmoe_train_t4096`` (32,768 rows in 64 groups,
+           2048 × 1024 and 1024 × 2048) and ``laguna_train_1of32``'s
+           held share (a 5,120-row buffer, ≈ 1,280 rows real, 8 groups,
+           3072 × 1024 and back) — forward, row gradient and weight
+           gradient, three arms: ``jax.lax.ragged_dot``, JAX's Pallas
+           grouped matmul (``jax.experimental.pallas.ops.tpu.megablox``,
+           what the program ran from PR 25 to PR 33) and the repo's own
+           kernels (``ops/pallas_gmm.py``, PR 34) at the tile rule's
+           choice and around it (``--no-sweep``: the rule's alone).
+           Times are ``block_until_ready`` medians over streams of ten
+           calls.
 
     chiprun -- python3 benchmarks/olmoe_probe.py            # both
     python3 benchmarks/olmoe_probe.py --compile-only        # here: the
@@ -36,12 +42,19 @@ import jax                                     # noqa: E402
 import jax.numpy as jnp                        # noqa: E402
 import numpy as np                             # noqa: E402
 
-ROWS, GROUPS, D, F = 65536, 64, 2048, 1024
-#: (m, k, n) tiles; past 512 x 1024 x 1024 the chip's compiler refuses
-#: the kernels for the 16 MB of scoped VMEM (CPU, compile only, PR 25)
-TILINGS = ((128, 128, 128), (512, 512, 512), (256, 1024, 1024),
-           (512, 1024, 512), (512, 512, 1024), (512, 1024, 1024),
-           (512, 2048, 512), (1024, 512, 1024), (1024, 1024, 512))
+#: the two cells' grouped matmuls: the rows of the buffer, the rows that
+#: are real (a held share's buffer is four times what uniform routing
+#: sends: ``ops.moe.HELD_SLACK``), groups, model width, expert width
+SHAPES = {
+    "olmoe": dict(rows=32768, real=32768, groups=64, d=2048, f=1024),
+    "laguna_held": dict(rows=5120, real=1280, groups=8, d=3072, f=1024),
+}
+#: (m, k, n) tiles of the library kernel: the one the program ran until
+#: PR 34 first; past 512 x 1024 x 1024 the chip's compiler refuses it
+#: for the 16 MB of scoped VMEM (CPU, compile only, PR 25)
+TILINGS = ((256, 1024, 1024), (512, 512, 512), (512, 1024, 1024))
+#: row tiles of the repo's kernels to try beside the rule's own
+ROW_TILES = (128, 256, 512, 1024)
 
 
 def emit(**line) -> None:
@@ -60,15 +73,32 @@ def timed(fn, *args, reps: int = 10) -> float:
     return statistics.median(times)
 
 
-def group_sizes(seed: int = 0) -> np.ndarray:
+def timed_stream(fn, *args, calls: int = 10, reps: int = 5) -> float:
+    """Median milliseconds a call over ``reps`` streams of ``calls``
+    calls, each stream ended by ``block_until_ready``: the device's
+    time where the host's part of one call (≈ 0.1 ms) would show in a
+    call of 1 ms."""
+    jax.block_until_ready(fn(*args))
+    times = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        times.append((time.perf_counter() - t0) * 1e3 / calls)
+    return statistics.median(times)
+
+
+def group_sizes(real: int, groups: int, seed: int = 0) -> np.ndarray:
     """Rows per expert as a uniform router would leave them: a
-    multinomial around ROWS / GROUPS, not multiples of any tile."""
+    multinomial around real / groups, not multiples of any tile."""
     rng = np.random.default_rng(seed)
-    return rng.multinomial(ROWS, np.full(GROUPS, 1.0 / GROUPS)).astype(
+    return rng.multinomial(real, np.full(groups, 1.0 / groups)).astype(
         np.int32)
 
 
-# -- the two arms: (fwd, dlhs, drhs), each (lhs, rhs, grad, sizes) -----
+# -- the arms: {"fwd": (lhs, rhs, sizes), "dlhs": (grad, rhs, sizes),
+#    "drhs": (lhs, grad, sizes)} -> callables; an arm may bring some ---
 def ragged_arm():
     def fwd(lhs, rhs, sizes):
         return jax.lax.ragged_dot(lhs, rhs, sizes,
@@ -85,7 +115,7 @@ def ragged_arm():
         return jax.lax.ragged_dot_general(
             lhs, grad, sizes, dims, preferred_element_type=jnp.float32)
 
-    return fwd, dlhs, drhs
+    return {"fwd": fwd, "dlhs": dlhs, "drhs": drhs}
 
 
 def megablox_arm(tiling):
@@ -107,68 +137,132 @@ def megablox_arm(tiling):
         return backend.tgmm(lhs.swapaxes(0, 1), grad, sizes, jnp.float32,
                             tiling)
 
-    return fwd, dlhs, drhs
+    return {"fwd": fwd, "dlhs": dlhs, "drhs": drhs}
 
 
-def gmm_cases():
+def znicz_arm(gmm_tiles=None, gmm_t_tiles=None, tgmm_tiles=None,
+              sub=None):
+    """The repo's kernels (``ops/pallas_gmm.py``, PR 34); ``None`` =
+    the tile rule's own choice; ``sub`` = the rows a straddling tile is
+    computed by.  The row gradient leaves in bf16, as the program
+    takes it."""
+    from znicz_tpu.ops import pallas_gmm
+
+    def fwd(lhs, rhs, sizes):
+        return pallas_gmm.znicz_gmm(lhs, rhs, sizes, tiles=gmm_tiles,
+                                    sub=sub)
+
+    def dlhs(grad, rhs, sizes):
+        return pallas_gmm.znicz_gmm(grad, rhs, sizes, transpose_rhs=True,
+                                    out_dtype=jnp.bfloat16,
+                                    tiles=gmm_t_tiles, sub=sub)
+
+    def drhs(lhs, grad, sizes):
+        return pallas_gmm.znicz_tgmm(lhs, grad, sizes, tiles=tgmm_tiles,
+                                     sub=sub)
+
+    return {"fwd": fwd, "dlhs": dlhs, "drhs": drhs}
+
+
+def _only(arm: dict, name: str) -> dict:
+    return {name: arm[name]}
+
+
+def gmm_cases(rows: int, k: int, n: int, sweep: bool):
+    """``(arm, tiling, {name: callable})``; the first is the
+    yardstick."""
+    from znicz_tpu.ops import pallas_gmm
     yield "ragged_dot", None, ragged_arm()
-    for tiling in TILINGS:
+    for tiling in TILINGS if sweep else TILINGS[:1]:
         yield "megablox", tiling, megablox_arm(tiling)
+    rule = {"gmm": pallas_gmm.gmm_tiles(rows, k, n),
+            "gmm_t": pallas_gmm.gmm_tiles(rows, n, k),
+            "tgmm": pallas_gmm.tgmm_tiles(rows, k, n),
+            "sub": pallas_gmm.PART_ROWS}
+    yield "znicz", rule, znicz_arm()
+    if not sweep:
+        return
+    for tm in ROW_TILES:
+        for sub in sorted({min(tm, 128), tm}):
+            label = {"gmm": (tm, n), "gmm_t": (tm, k), "sub": sub}
+            if (tm, sub) != (rule["gmm"][0], 128):
+                arm = znicz_arm((tm, n), (tm, k), sub=sub)
+                yield "znicz", label, {"fwd": arm["fwd"],
+                                       "dlhs": arm["dlhs"]}
+            if (tm, sub) != (rule["tgmm"][0], 128):
+                yield "znicz", {"tgmm": (tm, k, n), "sub": sub}, _only(
+                    znicz_arm(tgmm_tiles=(tm, k, n), sub=sub), "drhs")
 
 
-def gmm_shapes(k: int, n: int, sharding=None):
+def gmm_shapes(rows: int, groups: int, k: int, n: int, sharding=None):
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-    return (spec((ROWS, k), jnp.bfloat16), spec((GROUPS, k, n),
-                                                jnp.bfloat16),
-            spec((ROWS, n), jnp.bfloat16), spec((GROUPS,), jnp.int32))
+    return {"lhs": spec((rows, k), jnp.bfloat16),
+            "rhs": spec((groups, k, n), jnp.bfloat16),
+            "grad": spec((rows, n), jnp.bfloat16),
+            "sizes": spec((groups,), jnp.int32)}
 
 
-def stage_gmm(compile_only: bool, sharding=None) -> None:
-    sizes_np = group_sizes()
-    for k, n in ((D, F), (F, D)):
-        flops = 2.0 * ROWS * k * n
-        if not compile_only:
-            key = jax.random.key(0)
-            lhs = jax.random.normal(key, (ROWS, k), jnp.bfloat16)
-            rhs = (jax.random.normal(key, (GROUPS, k, n), jnp.float32)
-                   / np.sqrt(k)).astype(jnp.bfloat16)
-            grad = jax.random.normal(key, (ROWS, n), jnp.bfloat16)
-            sizes = jnp.asarray(sizes_np)
-            want = None
-        for arm, tiling, (fwd, dlhs, drhs) in gmm_cases():
-            line = {"stage": "gmm", "arm": arm, "tiling": tiling,
-                    "k": k, "n": n}
-            if not compile_only:
-                try:
-                    out = jax.jit(fwd)(lhs, rhs, sizes)
-                    if want is None:
-                        want = out      # the first arm is the yardstick
-                    line["max_diff_vs_ragged"] = float(
-                        jnp.abs(out - want).max())
-                except Exception as exc:
-                    line["max_diff_vs_ragged"] = type(exc).__name__
-                runs = (("fwd", fwd, (lhs, rhs, sizes)),
-                        ("dlhs", dlhs, (grad, rhs, sizes)),
-                        ("drhs", drhs, (lhs, grad, sizes)))
+#: what each function takes
+TAKES = {"fwd": ("lhs", "rhs", "sizes"), "dlhs": ("grad", "rhs", "sizes"),
+         "drhs": ("lhs", "grad", "sizes")}
+
+
+def stage_gmm(compile_only: bool, sharding=None, sweep: bool = True,
+              shapes=tuple(SHAPES)) -> None:
+    """Every arm at both cells' shapes, both orientations of the slab
+    (model width × expert width: gate and up; expert width × model
+    width: down): ms a call (:func:`timed_stream`), TFLOP/s over the
+    REAL rows, and the largest difference from ``ragged_dot`` over the
+    real rows (the rows past the groups are each arm's own)."""
+    for shape in shapes:
+        dims = SHAPES[shape]
+        rows, real, groups = dims["rows"], dims["real"], dims["groups"]
+        sizes_np = group_sizes(real, groups)
+        for k, n in ((dims["d"], dims["f"]), (dims["f"], dims["d"])):
+            flops = 2.0 * real * k * n
+            if compile_only:
+                data = gmm_shapes(rows, groups, k, n, sharding)
             else:
-                s_lhs, s_rhs, s_grad, s_sizes = gmm_shapes(k, n, sharding)
-                runs = (("fwd", fwd, (s_lhs, s_rhs, s_sizes)),
-                        ("dlhs", dlhs, (s_grad, s_rhs, s_sizes)),
-                        ("drhs", drhs, (s_lhs, s_grad, s_sizes)))
-            for name, fn, args in runs:
-                try:
-                    if compile_only:
-                        jax.jit(fn).lower(*args).compile()
-                        line[name] = "compiles"
-                    else:
-                        ms = timed(jax.jit(fn), *args)
-                        line[f"{name}_ms"] = ms
-                        line[f"{name}_tflops"] = flops / ms / 1e9
-                except Exception as exc:  # the compiler refuses it
-                    line[name] = f"{type(exc).__name__}: " \
-                                 f"{str(exc)[:160]}"
-            emit(**line)
+                key = jax.random.key(0)
+                live = (np.arange(rows) < real)[:, None]
+                data = {
+                    "lhs": jnp.where(live, jax.random.normal(
+                        key, (rows, k), jnp.bfloat16), 0),
+                    "rhs": (jax.random.normal(key, (groups, k, n),
+                                              jnp.float32)
+                            / np.sqrt(k)).astype(jnp.bfloat16),
+                    "grad": jnp.where(live, jax.random.normal(
+                        key, (rows, n), jnp.bfloat16), 0),
+                    "sizes": jnp.asarray(sizes_np)}
+            want = {}
+            for arm, tiling, fns in gmm_cases(rows, k, n, sweep):
+                line = {"stage": "gmm", "shape": shape, "arm": arm,
+                        "tiling": tiling, "k": k, "n": n}
+                for name, fn in fns.items():
+                    args = [data[a] for a in TAKES[name]]
+                    try:
+                        if compile_only:
+                            jax.jit(fn).lower(*args).compile()
+                            line[name] = "compiles"
+                            continue
+                        jitted = jax.jit(fn)
+                        out = jitted(*args).astype(jnp.float32)
+                        if name != "drhs":
+                            out = out[:real]
+                        if name not in want:   # the first arm
+                            want[name] = out
+                        line[f"{name}_diff"] = float(
+                            jnp.abs(out - want[name]).max()
+                            / jnp.abs(want[name]).max())
+                        ms = timed_stream(jitted, *args)
+                        line[f"{name}_ms"] = round(ms, 4)
+                        line[f"{name}_tflops"] = round(
+                            flops / ms / 1e9, 1)
+                    except Exception as exc:  # the compiler refuses it
+                        line[name] = f"{type(exc).__name__}: " \
+                                     f"{str(exc)[:160]}"
+                emit(**line)
 
 
 def stage_flash(compile_only: bool, sharding=None) -> None:
@@ -219,6 +313,10 @@ def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     parser.add_argument("stages", nargs="*", default=["flash", "gmm"])
     parser.add_argument("--compile-only", action="store_true")
+    parser.add_argument("--no-sweep", action="store_true",
+                        help="gmm: one tiling an arm, not the table")
+    parser.add_argument("--shapes", nargs="*", default=list(SHAPES),
+                        choices=list(SHAPES), help="gmm: the cells")
     args = parser.parse_args()
     sharding = None
     if args.compile_only:
@@ -233,8 +331,11 @@ def main() -> int:
               "compiles", file=sys.stderr)
         return 2
     for stage in args.stages:
-        {"flash": stage_flash, "gmm": stage_gmm}[stage](
-            args.compile_only, sharding)
+        if stage == "gmm":
+            stage_gmm(args.compile_only, sharding, not args.no_sweep,
+                      tuple(args.shapes))
+        else:
+            stage_flash(args.compile_only, sharding)
     return 0
 
 

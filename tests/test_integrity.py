@@ -359,6 +359,60 @@ def test_gated_delta_rule_compiled_for_v5e_keeps_a_chunk_in_vmem(v5e_chip):
     assert sorted(written) == ["convert", "fusion"], written
 
 
+#: (rows, groups, model width, expert width) of the two expert cells'
+#: grouped matmuls: all 64 experts, and a held share's row buffer
+_EXPERT_CELLS = {"olmoe_t4096": (32768, 64, 2048, 1024),
+                 "laguna_held_t4096": (5120, 8, 3072, 1024)}
+
+
+@pytest.mark.parametrize("cell", list(_EXPERT_CELLS))
+def test_grouped_matmul_kernels_compiled_for_v5e_at_the_cells_shapes(
+        v5e_chip, cell):
+    """An expert's gated MLP through ``grouped_matmul(kernel=True)``,
+    forward + backward at a cell's shape (bf16 rows, f32 slabs),
+    compiled for the chip: Mosaic takes the three kernels at the tile
+    rule's choice under the VMEM each asks for, the program holds no
+    operation of JAX's library kernel, and the row gradients leave the
+    kernels in bf16 (PERF.md §6, PR 34)."""
+    import jax
+    import jax.numpy as jnp
+
+    from znicz_tpu.ops.moe import grouped_matmul
+    rows, groups, d, f = _EXPERT_CELLS[cell]
+
+    def struct(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def mlp(x, w_up, w_down, sizes):
+        hidden = grouped_matmul(x, w_up, sizes, True, False)
+        return grouped_matmul(hidden.astype(x.dtype), w_down, sizes,
+                              True, False)
+
+    def step(dy, x, w_up, w_down, sizes):
+        out, pullback = jax.vjp(
+            lambda *args: mlp(*args, sizes), x, w_up, w_down)
+        return out, pullback(dy)
+
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:   # a described chip's executable cannot be read back here
+        text = jax.jit(step).lower(
+            struct((rows, d)), struct((rows, d), jnp.bfloat16),
+            struct((groups, d, f)), struct((groups, f, d)),
+            struct((groups,), jnp.int32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    calls = re.findall(r"%(\w*gmm[\w.]*) = (\w+)\[[\d,]*\]\S* custom-call",
+                       text)
+    names = sorted(re.sub(r"[.\d]+$", "", name) for name, _ in calls)
+    assert len(calls) == 6, calls         # 2 forward, 2 row, 2 weight
+    assert all("znicz_" in name for name in names), names
+    for kernel in ("znicz_gmm", "znicz_gmm_t", "znicz_tgmm"):
+        assert sum(name.endswith(kernel) for name in names) == 2, names
+    assert all(dtype == "bf16" for name, dtype in calls
+               if re.sub(r"[.\d]+$", "", name).endswith("znicz_gmm_t"))
+
+
 def test_vote_verdict_clean_selfbad_majority_tie():
     v = integrity.vote_verdict([1.0, 1.0, 1.0], [1.0, 1.0, 1.0], 1e-3)
     assert v == {"divergent": False, "culprits": [], "self_bad": []}

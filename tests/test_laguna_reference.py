@@ -366,7 +366,7 @@ def test_attention_options_against_the_numpy_oracle(case, kernels):
 
 
 @pytest.mark.parametrize("kernels", [False, True],
-                         ids=["ragged_dot", "megablox_interpreted"])
+                         ids=["ragged_dot", "kernels_interpreted"])
 @pytest.mark.parametrize("case", list(MOE))
 def test_expert_layer_options_against_the_numpy_oracle(case, kernels):
     options = dict(n_experts=8, top_k=3, width=12, pre_norm="rms",

@@ -97,6 +97,7 @@ def test_canonical_families_render_in_exposition():
         m.flash_backward("cov_attention", 1).set(1),
         m.flash_band("cov_attention", "band_share").set(0.06),
         m.moe_held("cov_moe", "rows_here").set(1280),
+        m.moe_gmm_rows("cov_moe", "visited").set(41216),
         m.delta_scan("cov_delta", "padded_share").set(1.78),
         m.loader_pipeline_restarts("cov").inc(),
         m.moe_aux_loss("cov_moe", "z").set(17.3),

@@ -641,6 +641,23 @@ def moe_held(unit: str, stat: str) -> Gauge:
         labels=("unit", "stat")).labels(unit=unit, stat=stat)
 
 
+def moe_gmm_rows(unit: str, stat: str) -> Gauge:
+    """What the grids of a ``MoE`` layer's grouped-matmul kernels did,
+    per call and step over the last epoch (``stat`` = ``visited``: the
+    rows their visits cover, Σ over the non-empty groups of the row
+    tiles a group touches × the tile — a tile that straddles a group
+    boundary is visited once per group; ``real``: the rows routed).
+    ``visited`` ÷ ``real`` is the kernels' overwork, 1 when every group
+    ends on a tile edge.  No series on the XLA path (``ragged_dot``).
+    Fed from totals the unit keeps on the device, read once per
+    epoch."""
+    return REGISTRY.gauge(
+        "znicz_moe_gmm_rows",
+        "Rows the grouped-matmul kernels' visits cover and rows that "
+        "are real, per call and step, of a MoE layer",
+        labels=("unit", "stat")).labels(unit=unit, stat=stat)
+
+
 def delta_scan(unit: str, stat: str) -> Gauge:
     """A ``GatedDeltaNet`` unit's chunked state scan (``stat`` =
     ``chunk``: positions per chunk; ``chunks``: ⌈T / chunk⌉, the length

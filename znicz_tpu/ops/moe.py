@@ -20,11 +20,17 @@ are sorted by expert, the rows gathered in that order, and three
 GROUPED matmuls (one weight slab per expert, ``group_sizes`` = rows
 per expert, a traced value) run over them; the results are put back in
 token order, weighted and summed.  The grouped matmul
-(:func:`grouped_matmul`) is JAX's own Pallas kernel
-(``jax.experimental.pallas.ops.tpu.megablox``) on a TPU — 27% faster
-than ``jax.lax.ragged_dot`` at the OLMoE shapes on the chip (PERF.md
-§6, PR 25) — and ``ragged_dot``, the XLA core, elsewhere; the gate is
-``engine.moe_grouped_matmul`` ("auto", like the flash kernels').  Both
+(:func:`grouped_matmul`) is the repo's own pair of Pallas kernels on a
+TPU (``ops/pallas_gmm.py``: ``znicz_gmm`` forward and row gradient,
+``znicz_tgmm`` weight gradient; their grids follow the groups, so a
+slab is read once per group and only a tile that straddles a group
+boundary pays for a mask — PERF.md §6, PR 34, which has them beside
+JAX's library kernel, the path from PR 25 to PR 33, and beside
+``jax.lax.ragged_dot`` on the chip) and ``ragged_dot``, the XLA core,
+elsewhere; the gate is ``engine.moe_grouped_matmul`` ("auto", like the
+flash kernels').  What the grids did is counted beside the routing
+totals (rows the visits cover over rows that are real: the gauge
+``znicz_moe_gmm_rows``).  Both
 permutations are GATHERS in both directions (:func:`_dispatch`,
 :func:`_unpermute`: a permutation's adjoint is its inverse), so no
 scatter-add runs in the step.
@@ -90,6 +96,7 @@ import jax
 import jax.numpy as jnp
 
 from znicz_tpu.memory import Vector
+from znicz_tpu.ops import pallas_gmm
 from znicz_tpu.ops.nn_units import Forward, GradientDescentBase
 from znicz_tpu.ops.rms_norm import (one_norm_placement, rms_norm,
                                     rms_norm_backward)
@@ -101,94 +108,57 @@ _LB, _Z, _STEPS, _MAX, _MIN = range(5)
 # ----------------------------------------------------------------------
 # the device path's three primitives
 # ----------------------------------------------------------------------
-#: (rows, contraction, columns) tile of the Pallas grouped matmul: the
-#: fastest of nine on the chip at the OLMoE shapes, forward and both
-#: gradients (PERF.md §6, PR 25); larger tiles overflow scoped VMEM
-GMM_TILING = (256, 1024, 1024)
-
-
 #: a held share's row buffer, as a multiple of what uniform routing
 #: sends to the experts held (module docstring)
 HELD_SLACK = 4
 
 
-def gmm_tiling(rows: int, k: int, n: int) -> tuple:
-    """``GMM_TILING`` cut to a small problem (the tests'): the kernels
-    want the row tile to divide the rows."""
-    return (min(GMM_TILING[0], rows), min(GMM_TILING[1], k),
-            min(GMM_TILING[2], n))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm_kernels(lhs, rhs, group_sizes, interpret):
+    """The grouped matmul through ``ops/pallas_gmm.py``; the groups may
+    end before the rows do (one chip's share of the pairs, under a
+    static capacity): the kernels' work follows the groups, and the
+    rows past them come back zero, forward and backward."""
+    return pallas_gmm.znicz_gmm(lhs, rhs.astype(lhs.dtype), group_sizes,
+                                interpret=interpret)
 
 
-def _megablox():
-    # the package's ``gmm`` attribute is its custom-vjp wrapper, which
-    # hides the module of that name — and whose backward would hand
-    # back the weight gradient in the weights' bf16
-    import importlib
-    return importlib.import_module(
-        "jax.experimental.pallas.ops.tpu.megablox.gmm")
-
-
-def _zero_tail(out, group_sizes):
-    """Rows past the last group: the kernel never writes them."""
-    live = jnp.arange(out.shape[0])[:, None] < group_sizes.sum()
-    return jnp.where(live, out, 0.0)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _gmm_pallas(lhs, rhs, group_sizes, interpret, tail=False):
-    """``tail``: the groups may end before the rows do (one chip's
-    share of the pairs, under a static capacity) — the kernel's work
-    follows the groups, and the rows it leaves unwritten are zeroed."""
-    out = _megablox().gmm(lhs, rhs.astype(lhs.dtype), group_sizes,
-                          jnp.float32, gmm_tiling(*lhs.shape,
-                                                  rhs.shape[2]),
-                          interpret=interpret)
-    return _zero_tail(out, group_sizes) if tail else out
-
-
-def _gmm_pallas_fwd(lhs, rhs, group_sizes, interpret, tail):
-    return (_gmm_pallas(lhs, rhs, group_sizes, interpret, tail),
+def _gmm_kernels_fwd(lhs, rhs, group_sizes, interpret):
+    return (_gmm_kernels(lhs, rhs, group_sizes, interpret),
             (lhs, rhs, group_sizes))
 
 
-def _gmm_pallas_bwd(interpret, tail, residual, grad):
+def _gmm_kernels_bwd(interpret, residual, grad):
     lhs, rhs, group_sizes = residual
-    kernels = _megablox()
     grad = grad.astype(lhs.dtype)
-    if tail:
-        grad = _zero_tail(grad, group_sizes).astype(lhs.dtype)
-    tiling = gmm_tiling(*lhs.shape, rhs.shape[2])
-    d_lhs = kernels.gmm(grad, rhs.astype(lhs.dtype), group_sizes,
-                        jnp.float32, tiling, transpose_rhs=True,
-                        interpret=interpret)
-    if tail:
-        d_lhs = _zero_tail(d_lhs, group_sizes)
-    d_rhs = kernels.tgmm(lhs.swapaxes(0, 1), grad, group_sizes,
-                         jnp.float32, tiling,
-                         num_actual_groups=rhs.shape[0],
-                         interpret=interpret)
-    return d_lhs.astype(lhs.dtype), d_rhs.astype(rhs.dtype), None
+    # the row gradient leaves the kernel in the rows' dtype: one
+    # rounding of its f32 accumulator; the weight gradient stays f32
+    d_lhs = pallas_gmm.znicz_gmm(grad, rhs.astype(lhs.dtype), group_sizes,
+                                 transpose_rhs=True, out_dtype=lhs.dtype,
+                                 interpret=interpret)
+    d_rhs = pallas_gmm.znicz_tgmm(lhs, grad, group_sizes,
+                                  interpret=interpret)
+    return d_lhs, d_rhs.astype(rhs.dtype), None
 
 
-_gmm_pallas.defvjp(_gmm_pallas_fwd, _gmm_pallas_bwd)
+_gmm_kernels.defvjp(_gmm_kernels_fwd, _gmm_kernels_bwd)
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+@functools.partial(jax.jit, static_argnums=(3, 4))
 def grouped_matmul(lhs, rhs, group_sizes, kernel: bool = False,
-                   interpret: bool = False, tail: bool = False):
+                   interpret: bool = False):
     """(M, K) rows in E contiguous groups × (E, K, N) f32 slabs →
     (M, N) f32: row r of group e is multiplied by ``rhs[e]``, the slabs
-    cast to the rows' dtype on the way in.  ``kernel`` runs JAX's
-    Pallas grouped matmul (``megablox``; the weight gradient comes back
-    in f32), else ``jax.lax.ragged_dot``, the XLA core.  Jitted so that
-    the three call sites of a layer, and every layer, lower it once
-    (PERF.md §6, PR 24: lowering is a set-up cost the compile cache
-    does not hide).  ``tail``: rows may follow the last group; they
-    come back zero (``ragged_dot`` zeroes them itself)."""
-    if kernel and tail:
-        return _gmm_pallas(lhs, rhs, group_sizes, interpret, True)
+    cast to the rows' dtype on the way in.  ``kernel`` runs the repo's
+    Pallas kernels (``znicz_gmm`` / ``znicz_tgmm``; the weight gradient
+    comes back in f32, the row gradient in the rows' dtype), else
+    ``jax.lax.ragged_dot``, the XLA core.  Jitted so that the three
+    call sites of a layer, and every layer, lower it once (PERF.md §6,
+    PR 24: lowering is a set-up cost the compile cache does not hide).
+    Rows may follow the last group; they come back zero on either
+    path."""
     if kernel:
-        return _gmm_pallas(lhs, rhs, group_sizes, interpret)
+        return _gmm_kernels(lhs, rhs, group_sizes, interpret)
     return jax.lax.ragged_dot(lhs, rhs.astype(lhs.dtype), group_sizes,
                               preferred_element_type=jnp.float32)
 
@@ -313,7 +283,8 @@ class MoE(Forward):
         #: [rows per expert held here (all E without ``held``) | lb
         #: loss, z loss, steps, per-step max and min rows of an expert;
         #: with ``held`` also: rows here, rows routed, rows over the
-        #: capacity], summed on the device
+        #: capacity | rows the kernels' visits cover, rows that are
+        #: real (both 0 on the XLA path)], summed on the device
         self.moe_stats = Vector(name=f"{self.name}.moe_stats")
         #: what the router did in the last step: its (N, E) logits and
         #: the (N, top_k) experts chosen — what a check against a plain
@@ -358,9 +329,10 @@ class MoE(Forward):
                                           fan_in=fan_in))
         if self.pre_norm and not self.gain_norm:
             self.gain_norm.reset(np.ones(d, np.float32))
-        if not self.moe_stats:
-            self.moe_stats.reset(np.zeros(
-                local + 5 + (3 if self.held else 0), np.float32))
+        slots = local + 5 + (3 if self.held else 0) + 2
+        if not self.moe_stats or self.moe_stats.shape != (slots,):
+            # (a snapshot from before PR 34 holds two slots fewer)
+            self.moe_stats.reset(np.zeros(slots, np.float32))
         self.output.reset(np.zeros((b, t, d),
                                    dtype=self.output_store_dtype))
         self.router_logits.reset(np.zeros((b, t, e), np.float32))
@@ -379,15 +351,17 @@ class MoE(Forward):
         pairs = rows = b * t * self.top_k
         if self.held is not None:
             # the rows the step's buffers hold (module docstring), a
-            # whole number of the kernel's row tiles where they fit
+            # whole number of the kernels' row tiles where they fit
             rows = min(-(-HELD_SLACK * pairs * local // e),
                        b * t * min(self.top_k, local))
-            tile = min(GMM_TILING[0], rows)
+            tile = min(pallas_gmm.ROW_TILE, rows)
             rows = min(-(-rows // tile) * tile, pairs)
             self._capacity = rows
-        if refused is None and rows % min(GMM_TILING[0], rows):
-            refused = (f"{rows} rows do not divide by the kernel's "
-                       f"row tile {GMM_TILING[0]}")
+        #: the kernels' row tile: one for the layer's nine calls
+        self._gmm_row_tile = tile = pallas_gmm.row_tile(rows)
+        if refused is None and rows % tile:
+            refused = (f"{rows} rows do not divide by the kernels' "
+                       f"row tile {tile}")
         self._gmm_kernel = refused is None
         self._gmm_interpret = interpret
         self.info("%s: %d experts top %d%s, dropless; grouped matmul %s "
@@ -396,7 +370,7 @@ class MoE(Forward):
                   "" if self.held is None else
                   f", {local} held here (capacity {rows} of "
                   f"{pairs} pairs)",
-                  f"megablox kernel, tiles {GMM_TILING}"
+                  f"znicz_gmm / znicz_tgmm kernels, row tile {tile}"
                   + (", INTERPRETED" if interpret else "")
                   if self._gmm_kernel
                   else f"jax.lax.ragged_dot ({refused})",
@@ -490,7 +464,7 @@ class MoE(Forward):
             cap - (jnp.cumsum(sizes) - sizes), 0))
         dt = self.mxu_dtype or jnp.float32
         path = (getattr(self, "_gmm_kernel", False),
-                getattr(self, "_gmm_interpret", False), True)
+                getattr(self, "_gmm_interpret", False))
         rows = jnp.where(live[:, None], jnp.take(m, token, axis=0),
                          0.0).astype(dt)
         gate = grouped_matmul(rows, w_g, sizes, *path)
@@ -569,8 +543,19 @@ class MoE(Forward):
             counts, held = counts[:self.n_local], [counts[self.n_local:]]
         tail = jnp.stack([lb, z, jnp.float32(1.0), counts.max(),
                           counts.min()])
-        self.moe_stats.devmem = self.moe_stats.devmem \
-            + jnp.concatenate([counts, tail] + held).astype(jnp.float32)
+        grid = jnp.zeros(2, jnp.float32)
+        if getattr(self, "_gmm_kernel", False):
+            # what the kernels' grids did with these groups: an E-long
+            # computation beside theirs, on the device (a straddling
+            # tile is computed part by part, so a call covers what
+            # tiles of a part's rows would)
+            sizes = counts.astype(jnp.int32)
+            grid = jnp.stack([
+                pallas_gmm.visited_rows(
+                    sizes, pallas_gmm.part_rows(self._gmm_row_tile)),
+                sizes.sum()])
+        self.moe_stats.devmem = self.moe_stats.devmem + jnp.concatenate(
+            [counts, tail] + held + [grid]).astype(jnp.float32)
 
     def xla_run(self) -> None:
         args = self.forward_args()
@@ -612,6 +597,10 @@ class MoE(Forward):
                         ("capacity", getattr(self, "_capacity", 0)),
                         ("rows_over", over)):
                     obs_metrics.moe_held(self.name, stat).set(value)
+            if tail[-1]:
+                for stat, value in (("visited", tail[-2] / steps),
+                                    ("real", tail[-1] / steps)):
+                    obs_metrics.moe_gmm_rows(self.name, stat).set(value)
         stats.map_invalidate()
         stats.mem[...] = 0.0      # uploaded on the next region fire
 
@@ -670,8 +659,8 @@ class MoE(Forward):
             else [counts.sum(), routed.sum(), 0.0]
         self.moe_stats.map_write()
         self.moe_stats.mem[...] += np.concatenate(
-            [counts, [lb, z, 1.0, counts.max(), counts.min()], held]
-        ).astype(np.float32)
+            [counts, [lb, z, 1.0, counts.max(), counts.min()], held,
+             [0.0, 0.0]]).astype(np.float32)
 
 
 class GDMoE(GradientDescentBase):
