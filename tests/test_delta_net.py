@@ -217,20 +217,30 @@ def test_decay_parameters_are_drawn_as_the_paper_s_layer_draws_them():
 
 
 def test_both_norm_placements_at_once_are_refused():
+    """… by the linear mixer, whose block has ONE norm; attention and
+    the gated MLP take both since PR 35 (the sandwich norm), with a
+    gain each."""
     x = np.zeros((1, 16, D), np.float32)
-    for make in (
-            lambda wf: delta_net.GatedDeltaNet(
-                wf, **DELTA, pre_norm="rms", post_norm="rms"),
-            lambda wf: attention.MultiHeadAttention(
-                wf, n_heads=4, pre_norm="rms", post_norm="rms"),
-            lambda wf: moe.GatedMLP(wf, width=8, pre_norm="rms",
-                                    post_norm="rms")):
+
+    def made(make):
         wf = DummyWorkflow()
         unit = make(wf)
         unit.link_attrs(DummyUnit(wf, output=Vector(x, name="x")),
                         ("input", "output"))
-        with pytest.raises(ValueError, match="pre_norm and post_norm"):
-            unit.initialize(device=NumpyDevice())
+        return unit
+
+    with pytest.raises(ValueError, match="pre_norm and post_norm"):
+        made(lambda wf: delta_net.GatedDeltaNet(
+            wf, **DELTA, pre_norm="rms", post_norm="rms")).initialize(
+                device=NumpyDevice())
+    for make in (
+            lambda wf: attention.MultiHeadAttention(
+                wf, n_heads=4, pre_norm="rms", post_norm="rms"),
+            lambda wf: moe.GatedMLP(wf, width=8, pre_norm="rms",
+                                    post_norm="rms")):
+        unit = made(make)
+        unit.initialize(device=NumpyDevice())
+        assert unit.gain_norm.shape == unit.gain_post.shape == (D,)
     with pytest.raises(ValueError, match="post_norm must be"):
         moe.GatedMLP(DummyWorkflow(), width=8, post_norm="layer")
     with pytest.raises(ValueError, match="post_norm must be"):

@@ -69,6 +69,27 @@ def current_accum_phase() -> "tuple[str, int] | None":
     return _ACCUM_PHASE
 
 
+#: the pass of a looped span whose backward is being traced
+#: (``znicz_tpu.pass_span``): ``None`` outside a span; ``("partial",
+#: span)`` while walking back a pass that is not the last to be walked
+#: (its parameter gradients are parts of a sum), ``("whole", span)`` in
+#: the last one walked (pass 0), where the sum is complete.  Set at
+#: trace time like :data:`_ACCUM_PHASE` and read at the same place,
+#: ``GradientDescentBase._whole_gradient``.
+_PASS_PHASE: "tuple | None" = None
+
+
+def current_pass_phase() -> "tuple | None":
+    return _PASS_PHASE
+
+
+def set_pass_phase(phase: "tuple | None") -> "tuple | None":
+    """Install ``phase``; returns the one it replaces."""
+    global _PASS_PHASE
+    previous, _PASS_PHASE = _PASS_PHASE, phase
+    return previous
+
+
 class AcceleratedUnit(Unit):
     """Base class for compute units with oracle + XLA paths."""
 
@@ -279,19 +300,23 @@ class JitRegion(Logger):
     XLA program per static-key combination."""
 
     def __init__(self, name: str, units: Sequence[AcceleratedUnit],
-                 device: Device) -> None:
+                 device: Device, pass_spans: Sequence = ()) -> None:
         super().__init__()
         self.name = name
         self.units = list(units)
         self.device = device
         for unit in self.units:
             unit._in_region = True
+        #: looped spans among the members (``znicz_tpu.pass_span``):
+        #: the members of one are traced R times, forward in order and
+        #: backward in reverse, by the span (:meth:`build_callable`)
+        self.pass_spans = list(pass_spans)
         self._vectors: list[Vector] | None = None
         self._cache: dict[tuple, object] = {}
 
     def _collect_vectors(self) -> list[Vector]:
         seen: dict[int, Vector] = {}
-        for unit in self.units:
+        for unit in (*self.units, *self.pass_spans):
             for vec in unit.region_vectors():
                 seen.setdefault(id(vec), vec)
         return list(seen.values())
@@ -497,6 +522,11 @@ class JitRegion(Logger):
         :data:`_ACCUM_PHASE`), so phase-aware units branch statically
         — each phase is its own compiled program variant.
 
+        The members are traced by :meth:`_trace_members`, which hands
+        a looped span (``znicz_tpu.pass_span``) its members; what a
+        trace leaves on the units and the spans — single-use pullbacks,
+        the passes' tape and partial sums — is dropped in ``finally``.
+
         The function is named for what it is (:meth:`program_name`:
         ``step``, or ``accum_micro`` / ``apply_micro`` in a phase)."""
         if self._vectors is None:
@@ -521,17 +551,7 @@ class JitRegion(Logger):
                 vec._devmem = leaf
             try:
                 with jax.default_matmul_precision(precision):
-                    for unit, skip in zip(units, skips):
-                        if skip:
-                            continue
-                        # every member traces under its name, always:
-                        # the compiled program's op metadata says which
-                        # unit an instruction belongs to
-                        # (``observe.op_scopes()`` reads it).  A scope
-                        # costs nothing at run time and is not part of
-                        # JAX's cache key
-                        with jax.named_scope(unit.name):
-                            unit.xla_run()
+                    region._trace_members(units, skips)
                 return tuple(vec._devmem for vec in vectors)
             finally:
                 _ACCUM_PHASE = prev_phase
@@ -540,12 +560,64 @@ class JitRegion(Logger):
                 for unit in units:
                     # drop any intra-trace pullback stash a forward
                     # left for a (possibly gate-skipped) GD pair —
-                    # escaped tracers must not outlive the trace
+                    # escaped tracers must not outlive the trace; a
+                    # looped span keeps R of them per member and the
+                    # passes' partial gradient sums, dropped likewise
                     if getattr(unit, "_traced_vjp", None) is not None:
                         unit._traced_vjp = None
+                for span in region.pass_spans:
+                    span.forget_trace()
 
         return self._named(
             fn, f"{accum_phase[0]}_micro" if accum_phase else "step")
+
+    def _trace_members(self, units, skips) -> None:
+        """Trace every member that is not skipped, in order, each under
+        ``jax.named_scope(unit.name)``, always: the compiled program's
+        op metadata says which unit an instruction belongs to
+        (``observe.op_scopes()`` reads it).  A scope costs nothing at
+        run time and is not part of JAX's cache key.
+
+        The members of a looped span are handed to the span where its
+        first forward member and where its first backward member (in
+        this order: the LAST layer's) stand: it applies the forward
+        members R times and walks the backward members back R times,
+        each application under ``<unit>/pass<r>``."""
+        def trace(unit, pass_index: int | None = None) -> None:
+            with jax.named_scope(unit.name):
+                if pass_index is None:
+                    return unit.xla_run()
+                with jax.named_scope(f"pass{pass_index}"):
+                    unit.xla_run()
+
+        heads = {}
+        for span in self.pass_spans:
+            heads[id(span.forwards[0])] = (span, span.forwards,
+                                           span.trace_forward)
+            heads[id(span.gds[-1])] = (span, span.gds[::-1],
+                                       span.trace_backward)
+        i = 0
+        while i < len(units):
+            head = heads.get(id(units[i]))
+            if head is None:
+                if not skips[i]:
+                    trace(units[i])
+                i += 1
+                continue
+            span, members, walk = head
+            n = len(members)
+            if [id(u) for u in units[i:i + n]] != [id(u) for u in members]:
+                raise RuntimeError(
+                    f"region '{self.name}': the members of looped span "
+                    f"'{span.name}' are not contiguous in the region")
+            skipped = set(skips[i:i + n])
+            if len(skipped) != 1:
+                raise RuntimeError(
+                    f"region '{self.name}': looped span '{span.name}' "
+                    f"is skipped in part ({skips[i:i + n]})")
+            if not skipped.pop():
+                walk(trace)
+            i += n
 
     def run_chunk(self, n_steps: int) -> None:
         """Execute ``n_steps`` region steps in ONE dispatch:
@@ -769,9 +841,11 @@ class RegionUnit(AcceleratedUnit):
     """
 
     def __init__(self, workflow, units: Sequence[AcceleratedUnit],
-                 name: str | None = None, **kwargs) -> None:
+                 name: str | None = None, pass_spans: Sequence = (),
+                 **kwargs) -> None:
         super().__init__(workflow, name=name or "jit_region", **kwargs)
         self._member_units = list(units)
+        self._pass_spans = list(pass_spans)
         self.region: JitRegion | None = None
 
     def initialize(self, device: Device | None = None, **kwargs) -> None:
@@ -786,7 +860,8 @@ class RegionUnit(AcceleratedUnit):
             if not unit.is_initialized:
                 raise AttributeError(f"region member {unit} not initialized")
         assert self.device is not None
-        self.region = JitRegion(self.name, self._member_units, self.device)
+        self.region = JitRegion(self.name, self._member_units, self.device,
+                                pass_spans=self._pass_spans)
 
     def run(self) -> None:
         assert self.region is not None

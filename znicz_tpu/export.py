@@ -222,6 +222,21 @@ def refuse_unserved(forwards, what: str) -> None:
     serve another model than was trained (ROADMAP R1, serving half)."""
     for i, unit in enumerate(forwards):
         kind = type(unit).__name__
+        span = getattr(unit, "pass_span", None)
+        if span is not None:
+            raise NotImplementedError(
+                f"{what}: layer {i} is a member of a looped span (table "
+                f"key 'passes': {span.passes} passes over "
+                f"{len(span.forwards)} layers on shared weights); "
+                f"serving runs a chain once — a pass has no K/V cache "
+                f"of its own and no early exit yet (ROADMAP R7, serving "
+                f"half)")
+        if kind == "All2AllExits":
+            raise NotImplementedError(
+                f"{what}: layer {i} is a multi-exit head (loop_exits); "
+                f"serving has no exit rule yet — the exit gate and the "
+                f"exit distribution exist on the training path only "
+                f"(ROADMAP R7, serving half)")
         if kind == "GatedMLP":
             raise NotImplementedError(
                 f"{what}: layer {i} is a gated MLP block (gated_mlp); "

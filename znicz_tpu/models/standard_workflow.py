@@ -38,13 +38,14 @@ from znicz_tpu.mutable import Bool
 from znicz_tpu.ops import activation, all2all, conv, cutter, dropout, pooling
 from znicz_tpu.ops import attention, deconv, depooling, lstm, normalization
 from znicz_tpu.ops import delta_net, embedding, layer_norm, moe, pos_encoding
-from znicz_tpu.ops import rms_norm
+from znicz_tpu.ops import loop_exits, rms_norm
 from znicz_tpu.ops import seq_reshape
 from znicz_tpu.ops import gd, gd_conv, gd_pooling  # noqa: F401 (pairs)
 from znicz_tpu.ops.decision import DecisionGD, DecisionMSE
 from znicz_tpu.ops.lr_adjust import LearningRateAdjust
 from znicz_tpu.ops.evaluator import EvaluatorMSE, EvaluatorSoftmax
 from znicz_tpu.ops.nn_units import Forward, gd_for
+from znicz_tpu.pass_span import TABLE_KEY as PASSES_KEY, PassSpan, spans_of
 from znicz_tpu.units import Repeater
 from znicz_tpu.utils.snapshotter import Snapshotter
 
@@ -106,6 +107,7 @@ for _name, _cls in {
     "moe": moe.MoE,
     "gated_mlp": moe.GatedMLP,
     "gated_delta_net": delta_net.GatedDeltaNet,
+    "loop_exits": loop_exits.All2AllExits,
 }.items():
     register_layer_type(_name, _cls)
 
@@ -118,7 +120,11 @@ class StandardWorkflow(AcceleratedWorkflow):
     loader_factory:
         ``callable(workflow) -> Loader`` building the dataset unit.
     layers:
-        list of layer dicts (``{"type", "->", "<-"}``).
+        list of layer dicts (``{"type", "->", "<-"}``).  Adjacent
+        entries with ``"passes": R`` form a looped span: that stretch
+        of the chain runs R times a step on shared weights, one
+        gradient summed over the passes and one update
+        (:mod:`znicz_tpu.pass_span`; XLA path only).
     loss:
         ``"softmax"`` (classification) or ``"mse"``.
     decision_config / snapshotter_config:
@@ -152,6 +158,8 @@ class StandardWorkflow(AcceleratedWorkflow):
         assert isinstance(self.loader, Loader)
         self.forwards: list[Forward] = []
         self.gds: list = []
+        #: the looped spans of the table, one :class:`PassSpan` each
+        self.pass_spans: list[PassSpan] = []
         self.anomaly_guard = None
         self.integrity = None  # the round-19 SDC sentinel
         self._pipeline = None  # round-20 pipeline executor (lazy)
@@ -185,7 +193,21 @@ class StandardWorkflow(AcceleratedWorkflow):
     # ------------------------------------------------------------------
     def link_forwards(self) -> None:
         prev = None
-        for spec in self.layers_config:
+        # table index → the looped span that ENDS before it / holds it
+        ends, member_of = {}, {}
+        for first, stop, passes in spans_of(self.layers_config):
+            if stop == len(self.layers_config):
+                raise ValueError(
+                    f"layers {first}–{stop - 1}: a looped span "
+                    f"('{PASSES_KEY}') cannot end the table — the unit "
+                    f"after it takes its state (or, as loop_exits, "
+                    f"every pass's)")
+            span = PassSpan(self, passes,
+                            name=f"pass_span_{len(self.pass_spans)}")
+            self.pass_spans.append(span)
+            ends[stop] = span
+            member_of.update((i, span) for i in range(first, stop))
+        for index, spec in enumerate(self.layers_config):
             cls = layer_type(spec["type"])
             cfg = dict(spec.get("->", {}))
             tied = spec.get("tied_to")  # autoencoder decoder layers
@@ -214,19 +236,37 @@ class StandardWorkflow(AcceleratedWorkflow):
                     raise ValueError(
                         f"layer type '{spec['type']}' does not "
                         f"support tied_to")
+            span = ends.get(index)
             if prev is None:
                 unit.link_attrs(self.loader, ("input", "minibatch_data"))
+            elif span is not None and getattr(cls, "TAKES_PASSES", False):
+                # every pass's state of the span before it, not the last
+                span.takes_passes = True
+                unit.link_attrs(span, ("input", "states"))
             else:
                 unit.link_attrs(prev, ("input", "output"))
             if "forward_mode" in unit.__dict__:  # stochastic units track
                 unit.link_attrs(self.loader, "forward_mode",
                                 two_way=False)  # the minibatch class
+            if index in member_of:
+                member_of[index].forwards.append(unit)
+                unit.pass_span = member_of[index]
             self.forwards.append(unit)
             prev = unit
 
     def link_evaluator(self, **config) -> None:
         last = self.forwards[-1]
-        if self.loss == "softmax":
+        if self.loss == "softmax" and isinstance(last,
+                                                 loop_exits.All2AllExits):
+            # a head with an exit at every pass brings its own loss
+            ev = loop_exits.EvaluatorLoopExits(self, name="evaluator",
+                                               **config)
+            ev.exits_unit = last
+            ev.link_attrs(last, "output", "max_idx", "exit_q",
+                          "exit_stats")
+            ev.link_attrs(self.loader, ("labels", "minibatch_labels"),
+                          "minibatch_valid", "minibatch_class")
+        elif self.loss == "softmax":
             ev = EvaluatorSoftmax(self, name="evaluator", **config)
             ev.link_attrs(last, "output", "max_idx")
             ev.link_attrs(self.loader, ("labels", "minibatch_labels"),
@@ -256,14 +296,27 @@ class StandardWorkflow(AcceleratedWorkflow):
             cls = gd_for(type(fwd))
             gd_kwargs = {k: v for k, v in spec.get("<-", {}).items()
                          if k not in ("lr_policy", "bias_lr_policy")}
-            unit = cls(self, need_err_input=(i != len(self.forwards) - 1),
-                       **gd_kwargs)
+            span = getattr(fwd, "pass_span", None)
+            # a looped span's first member sends its input's cotangent
+            # back to the pass before, whatever precedes the span
+            unit = cls(self, need_err_input=(
+                i != len(self.forwards) - 1
+                or (span is not None and span.passes > 1)), **gd_kwargs)
             unit.forward_unit = fwd  # geometry/mask/activation source
             unit.link_attrs(fwd, "input", "output", "weights", "bias")
             if next_gd is None:
                 unit.link_attrs(self.evaluator, "err_output")
+                if isinstance(fwd, loop_exits.All2AllExits):
+                    unit.link_attrs(self.evaluator, "err_exit_q")
+            elif span is not None and fwd is span.forwards[-1]:
+                # the span joins what reaches its end from ``next_gd``
+                # with the later pass's own (PassSpan.trace_backward)
+                span.consumer_gd = next_gd
+                unit.link_attrs(span, ("err_output", "err_last"))
             else:
                 unit.link_attrs(next_gd, ("err_output", "err_input"))
+            if span is not None:
+                span.gds.insert(0, unit)
             # train minibatches only (reference: decision.gd_skip)
             unit.gate_skip = Bool._derived(
                 lambda: self.loader.minibatch_class != TRAIN)
@@ -723,6 +776,13 @@ class StandardWorkflow(AcceleratedWorkflow):
         program unchanged.
         """
         from znicz_tpu.parallel.pipeline import PipelineExecutor
+        if self.pass_spans:
+            raise NotImplementedError(
+                f"workflow '{self.name}': run_pipelined does not run a "
+                f"looped span (table key '{PASSES_KEY}'): its stages "
+                f"are built from one forward and one backward per "
+                f"layer, and a state that returns to an earlier stage "
+                f"has no place in their schedule")
         n_micro = self._microbatches(microbatches)
         self._require_microbatchable(n_micro, "pipelined")
         executor = self._pipeline
@@ -806,7 +866,8 @@ class StandardWorkflow(AcceleratedWorkflow):
         """Swap the eager hot chain for one jit region (xla backend)."""
         members = self.hot_chain_units()
         guard = self.anomaly_guard
-        region = RegionUnit(self, members, name="train_region")
+        region = RegionUnit(self, members, name="train_region",
+                            pass_spans=self.pass_spans)
         region.initialize(device=self.device)
         region._initialized = True
         # rewire: loader → [guard host hook] → region → decision (drop

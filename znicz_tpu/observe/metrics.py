@@ -680,6 +680,34 @@ def delta_scan(unit: str, stat: str) -> Gauge:
         labels=("unit", "stat")).labels(unit=unit, stat=stat)
 
 
+def loop(group: str, stat: str) -> Gauge:
+    """A looped span of the layer table (``znicz_tpu.pass_span``;
+    ``stat`` = ``passes``: R; ``layers``: its members; ``applications``:
+    R × members, what one step should apply — the three static, set at
+    ``initialize``; ``applications_per_step``: member applications the
+    step program ran per step over the last epoch, counted on the
+    device and read once per epoch)."""
+    return REGISTRY.gauge(
+        "znicz_loop",
+        "Passes, layers and member applications per step of a looped "
+        "span of the layer table",
+        labels=("group", "stat")).labels(group=group, stat=stat)
+
+
+def loop_exit(unit: str, exit_index: int, stat: str) -> Gauge:
+    """One exit of a ``All2AllExits`` head over the last epoch (``stat`` =
+    ``loss``: mean cross-entropy of that exit's prediction; ``mass``:
+    mean exit probability q_r; exit ``entropy``'s ``value``: mean
+    entropy of the exit distribution).  Fed from totals the evaluator
+    keeps on the device, read once per epoch."""
+    return REGISTRY.gauge(
+        "znicz_loop_exit",
+        "Mean cross-entropy and exit mass per exit, and mean entropy of "
+        "the exit distribution, of a multi-exit head over the last epoch",
+        labels=("unit", "exit", "stat")).labels(
+            unit=unit, exit=str(exit_index), stat=stat)
+
+
 def moe_aux_loss(unit: str, kind: str) -> Gauge:
     """A ``MoE`` unit's auxiliary router losses, mean per step over
     the last epoch, unweighted (``kind`` = ``load_balance``: top_k

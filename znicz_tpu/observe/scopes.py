@@ -12,7 +12,8 @@ of the COMPILED step program carries
 
     {program name: {HLO instruction name: {
         "unit": "GDMoE_2", "kind": "GDMoE", "family": "MoE",
-        "phase": "forward" | "backward" | "update" | "fingerprint"}}}
+        "phase": "forward" | "backward" | "update" | "fingerprint"
+                 | "pass_sum"}}}
 
 - ``kind`` is the unit's class, ``family`` the forward class a
   backward unit is paired with (a forward unit's own pairing class):
@@ -20,8 +21,11 @@ of the COMPILED step program carries
 - forward and backward are told apart by the unit's class, ``update``
   and ``fingerprint`` by the scope: an operation reads ``update`` only
   if EVERY scoped instruction in it lies inside that scope
-  (``fingerprint`` is nested in ``update`` and reads likewise), else
-  its unit's forward / backward;
+  (``fingerprint`` is nested in ``update`` and reads likewise; so does
+  ``pass_sum``, the sum of a looped span's partial gradients over its
+  passes), else its unit's forward / backward.  A member of a looped
+  span traces each application under ``<unit>/pass<r>/``: the pass is
+  in the ``op_name`` path, the unit is still the outermost scope;
 - a fusion is attributed by ALL the instructions fused into it (the
   fused computation's body in the same text), not by its root alone:
   instructions of more than one unit make it mixed,
@@ -227,8 +231,14 @@ def attribute(text: str, units: tuple) -> dict:
     computations, entry = parse(text)
     names = [unit[0] for unit in units]
     nested: dict = {}
-    scope = functools.lru_cache(maxsize=None)(   # few distinct names
-        lambda op_name: scope_of(op_name, names) if op_name else None)
+    @functools.lru_cache(maxsize=None)           # few distinct names
+    def scope(op_name: str):
+        """:func:`scope_of`, and whether the instruction is one of the
+        adds of a looped span's gradient sum (``pass_sum``, a scope
+        inside ``update``)."""
+        found = scope_of(op_name, names) if op_name else None
+        return found and found + (
+            found[1] and "/pass_sum/" in f"/{op_name}/",)
 
     def scopes_in(computation: str) -> frozenset:
         """Scopes of every instruction in a computation and in what
@@ -267,14 +277,16 @@ def attribute(text: str, units: tuple) -> dict:
 
 def _entry(scopes: set, units: tuple) -> dict:
     by_unit: dict = {}
-    for index, update, fingerprint in scopes:
-        by_unit.setdefault(index, []).append((update, fingerprint))
+    for index, *inside in scopes:
+        by_unit.setdefault(index, []).append(inside)
     parts = []
     for index in sorted(by_unit):
         name, kind, family, backward = units[index]
-        if all(fingerprint for _u, fingerprint in by_unit[index]):
+        if all(fingerprint for _u, fingerprint, _s in by_unit[index]):
             phase = "fingerprint"
-        elif all(update for update, _f in by_unit[index]):
+        elif all(pass_sum for _u, _f, pass_sum in by_unit[index]):
+            phase = "pass_sum"
+        elif all(update for update, _f, _s in by_unit[index]):
             phase = "update"
         else:
             phase = "backward" if backward else "forward"

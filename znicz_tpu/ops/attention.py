@@ -34,10 +34,13 @@ convention.  Norms and rotation run in f32 between the QKV projection
 and the attention core; the kernels are untouched.
 
 ``post_norm="rms"`` (Olmo-Hybrid-7B, PR 31) is the family's other
-placement of the block's one norm: on the sublayer's OUTPUT, inside the
-skip — ``y = x + RMSNorm(f(x))``.  Its gain is ``gain_norm`` too, so a
-layer moves between the placements by one word of the layer table;
-both at once are refused at ``initialize``.
+placement of the block's norm: on the sublayer's OUTPUT, inside the
+skip — ``y = x + RMSNorm(f(x))``.  Alone, its gain is ``gain_norm``
+too, so a layer moves between the placements by one word of the layer
+table.  BOTH at once (Ouro's sandwich norm, PR 35) is
+``y = x + RMSNorm_post(f(RMSNorm_pre(x)))`` with a gain each:
+``gain_norm`` the norm's before the sublayer, ``gain_post`` the one's
+on its output (:func:`~znicz_tpu.ops.rms_norm.norm_gains`).
 
 Grouped queries, a window and a per-head gate (Laguna-S-2.1, PR 29)
 are options of the same kind, per unit, so that one model mixes layers
@@ -71,7 +74,7 @@ import jax.numpy as jnp
 
 from znicz_tpu.memory import Vector
 from znicz_tpu.ops.nn_units import Forward, GradientDescentBase
-from znicz_tpu.ops.rms_norm import (one_norm_placement, rms_norm,
+from znicz_tpu.ops.rms_norm import (norm_gains, post_gain, rms_norm,
                                     rms_norm_backward)
 from znicz_tpu.parallel.axis import DATA_AXIS, MODEL_AXIS, SEQ_AXIS
 
@@ -208,7 +211,11 @@ class MultiHeadAttention(Forward):
     """Weighted multi-head self-attention layer."""
 
     EXPORT_PARAMS = ("weights", "bias", "weights_out", "bias_out",
-                     "gain_norm", "gain_q", "gain_k", "weights_head_gate")
+                     "gain_norm", "gain_q", "gain_k", "weights_head_gate",
+                     "gain_post")
+    #: may be a member of a looped span (``znicz_tpu.pass_span``): the
+    #: backward needs the forward's input and pullback only
+    PASS_SAFE = True
 
     def __init__(self, workflow, n_heads: int, causal: bool = False,
                  seq_parallel: bool = False,
@@ -252,8 +259,8 @@ class MultiHeadAttention(Forward):
         self.pre_norm = pre_norm
         #: the norm on the sublayer's OUTPUT, inside the skip:
         #: x + RMSNorm(f(x)) (OLMo 2's placement); its gain is
-        #: ``gain_norm``, the block's one norm, so the two placements
-        #: exclude each other (refused at ``initialize``)
+        #: ``gain_norm`` where it is the block's one norm, ``gain_post``
+        #: beside a ``pre_norm`` (module docstring)
         self.post_norm = post_norm
         self.residual = bool(residual)
         self.qk_norm = qk_norm
@@ -282,10 +289,12 @@ class MultiHeadAttention(Forward):
         self.weights_head_gate = Vector(
             name=f"{self.name}.weights_head_gate")
         self.gain_norm = Vector(name=f"{self.name}.gain_norm")
+        self.gain_post = Vector(name=f"{self.name}.gain_post")
         self.gain_q = Vector(name=f"{self.name}.gain_q")
         self.gain_k = Vector(name=f"{self.name}.gain_k")
-        #: pullback stashed by xla_run for the GD pair (same trace;
-        #: transient — never pickled, cleared by the consumer)
+        #: pullback of the LAST application, stashed by xla_run for the
+        #: GD pair (same trace; transient — never pickled, cleared by
+        #: the consumer; a looped span keeps one per pass on its tape)
         self._traced_vjp = None
         self.weights_out = Vector(name=f"{self.name}.weights_out")
         self.bias_out = Vector(name=f"{self.name}.bias_out")
@@ -298,7 +307,6 @@ class MultiHeadAttention(Forward):
             raise ValueError(f"{self}: expected (batch, time, features) "
                              f"input, got {self.input.shape}")
         b, t, d = self.input.shape
-        one_norm_placement(self)
         if self.head_dim is None and d % self.n_heads:
             raise ValueError(f"{self}: features {d} not divisible by "
                              f"{self.n_heads} heads")
@@ -328,6 +336,8 @@ class MultiHeadAttention(Forward):
                 self.bias_out.reset(np.zeros(d, np.float32))
         for gain, width in ((self.gain_norm,
                              d if self.pre_norm or self.post_norm else 0),
+                            (self.gain_post,
+                             d if self.pre_norm and self.post_norm else 0),
                             (self.gain_q,
                              q_width if self.qk_norm else 0),
                             (self.gain_k,
@@ -548,7 +558,7 @@ class MultiHeadAttention(Forward):
         self.init_vectors(self.input, self.output, self.weights,
                           self.bias, self.weights_out, self.bias_out,
                           self.gain_norm, self.gain_q, self.gain_k,
-                          self.weights_head_gate)
+                          self.weights_head_gate, self.gain_post)
 
     @property
     def ring_active(self) -> bool:
@@ -567,7 +577,9 @@ class MultiHeadAttention(Forward):
                 self.weights_out.devmem,
                 self.bias_out.devmem if self.include_bias else None,
                 dev(self.gain_norm), dev(self.gain_q), dev(self.gain_k)) \
-            + ((self.weights_head_gate.devmem,) if self.head_gate else ())
+            + ((self.weights_head_gate.devmem,) if self.head_gate
+               else (None,) if self.gain_post else ()) \
+            + ((self.gain_post.devmem,) if self.gain_post else ())
 
     def _widths(self, d: int) -> tuple:
         """(q width, k/v width, head size) for a model width ``d``."""
@@ -605,13 +617,15 @@ class MultiHeadAttention(Forward):
         return q, k, v
 
     def xla_forward(self, x, w_qkv, b_qkv, w_out, b_out,
-                    g_norm=None, g_q=None, g_k=None, w_gate=None):
+                    g_norm=None, g_q=None, g_k=None, w_gate=None,
+                    g_post=None):
         b, t, d = x.shape
         wide = w_qkv.shape[1]
         grouped = self.n_kv_heads != self.n_heads
         x32 = x.astype(jnp.float32)
-        h = x32 if g_norm is None or self.post_norm \
-            else rms_norm(jnp, x32, g_norm, self.norm_eps)
+        g_pre, g_post = norm_gains(self, g_norm, g_post)
+        h = x32 if g_pre is None \
+            else rms_norm(jnp, x32, g_pre, self.norm_eps)
         qkv = self.mxu_dot(jnp, h.reshape(b * t, d), w_qkv)
         if b_qkv is not None:
             qkv = qkv + b_qkv
@@ -657,7 +671,7 @@ class MultiHeadAttention(Forward):
                 interpret=getattr(self, "_flash_interpret", False),
                 mesh=getattr(self, "_flash_mesh", None),
                 spec=getattr(self, "_flash_spec", None), **more)
-            return self._project_out(x32, h, o, w_out, b_out, w_gate, g_norm)
+            return self._project_out(x32, h, o, w_out, b_out, w_gate, g_post)
         q, k, v = self._normed_rotated(jnp, arrays[0], None, None) \
             if fused else arrays      # fused: neither norm nor rotation
         if self.ring_active:
@@ -689,16 +703,16 @@ class MultiHeadAttention(Forward):
             from znicz_tpu.parallel.ring_attention import local_attention
             o = local_attention(q, k, v, causal=self.causal,
                                 dot_dtype=dot_dtype, window=self.window)
-        return self._project_out(x32, h, o, w_out, b_out, w_gate, g_norm)
+        return self._project_out(x32, h, o, w_out, b_out, w_gate, g_post)
 
     def _project_out(self, x32, h, o, w_out, b_out, w_gate=None,
-                     g_norm=None):
+                     g_post=None):
         """The out-projection over the core's result — (B, T, H, dh)
         or (B, T, H·dh), (B·T, H·dh) by a free reshape either way —
         and the residual; with ``w_gate`` every head's output first
         multiplied by its sigmoid gate, computed from the sublayer's
         (normed) input ``h``; with ``post_norm`` the projection's
-        result normed (gain ``g_norm``) before the skip adds it."""
+        result normed (gain ``g_post``) before the skip adds it."""
         b, t, d = x32.shape
         o = o.reshape(b * t, w_out.shape[0])
         if w_gate is not None:
@@ -711,7 +725,7 @@ class MultiHeadAttention(Forward):
             y = y + b_out
         y = y.reshape(b, t, d)
         if self.post_norm:
-            y = rms_norm(jnp, y, g_norm, self.norm_eps)
+            y = rms_norm(jnp, y, g_post, self.norm_eps)
         return x32 + y if self.residual else y
 
     def xla_run(self) -> None:
@@ -1033,7 +1047,7 @@ class MultiHeadAttention(Forward):
             y = y + self.bias_out.mem
         y, raw = y.reshape(b, t, d), None
         if self.post_norm:
-            raw, y = y, rms_norm(np, y, self.gain_norm.mem,
+            raw, y = y, rms_norm(np, y, post_gain(self).mem,
                                  self.norm_eps)
         if self.residual:
             y = x + y
@@ -1047,7 +1061,7 @@ class MultiHeadAttention(Forward):
             self.bias.map_read()
             self.bias_out.map_read()
         for gain in (self.gain_norm, self.gain_q, self.gain_k,
-                     self.weights_head_gate):
+                     self.weights_head_gate, self.gain_post):
             if gain:
                 gain.map_read()
         y, _ = self._forward_np(self.input.mem.astype(np.float32))
@@ -1081,6 +1095,9 @@ class GDMultiHeadAttention(GradientDescentBase):
         # … and the per-head gate's projection
         self.accumulated_gradient_weights_head_gate = Vector(
             name=f"{self.name}.acc_gw_head_gate")
+        # … and the output norm's gain beside a pre-norm
+        self.accumulated_gradient_gain_post = Vector(
+            name=f"{self.name}.acc_gain_post")
 
     def _gain_pairs(self) -> list:
         """``(suffix, parameter Vector, its accumulator)`` for the
@@ -1094,6 +1111,9 @@ class GDMultiHeadAttention(GradientDescentBase):
         if fwd.weights_head_gate:
             pairs.append(("head_gate", fwd.weights_head_gate,
                           self.accumulated_gradient_weights_head_gate))
+        if fwd.gain_post:
+            pairs.append(("post", fwd.gain_post,
+                          self.accumulated_gradient_gain_post))
         return pairs
 
     def initialize(self, device=None, **kwargs) -> None:
@@ -1153,9 +1173,13 @@ class GDMultiHeadAttention(GradientDescentBase):
         # forward overwrites the stash at the top of every trace, so a
         # tracing consumer can never see a stale trace's closure); an
         # EAGER backward must rebuild — a stash from some earlier
-        # trace would hold escaped tracers
+        # trace would hold escaped tracers.  A member of a looped span
+        # is applied R times a step: the span keeps the R pullbacks and
+        # puts an application's back here before this unit fires for
+        # that pass (``pass_span.PassSpan.trace_backward``)
         vjp = fwd._traced_vjp if self.err_output._tracing else None
-        fwd._traced_vjp = None   # single-use: never reuse stale state
+        fwd._traced_vjp = None   # single-use: one backward per
+        #                          application, never a stale one
         if vjp is None:          # forward ran outside this trace
             _, vjp = jax.vjp(fwd.xla_forward, *fwd.forward_args())
         gx, gwq, gbq, gwo, gbo, *ggains = vjp(
@@ -1173,7 +1197,7 @@ class GDMultiHeadAttention(GradientDescentBase):
             self._apply_bias_xla(
                 gbo, vec=fwd.bias_out,
                 acc_vec=self.accumulated_gradient_bias_out)
-        grads = dict(zip(("norm", "q", "k", "head_gate"), ggains))
+        grads = dict(zip(("norm", "q", "k", "head_gate", "post"), ggains))
         for name, gain, acc in self._gain_pairs():
             self._apply_weights_xla(grads[name], vec=gain, acc_vec=acc)
 
@@ -1198,8 +1222,9 @@ class GDMultiHeadAttention(GradientDescentBase):
         grad_gains = {}
         dy = err
         if fwd.post_norm:                 # back through the output norm
-            dy, grad_gains["norm"] = rms_norm_backward(
-                np, raw, fwd.gain_norm.mem, fwd.norm_eps, err)
+            dy, grad_gains["post" if fwd.pre_norm else "norm"] = \
+                rms_norm_backward(np, raw, post_gain(fwd).mem,
+                                  fwd.norm_eps, err)
         dy = dy.reshape(b * t, d)
         # output projection (over the gated heads, where there is a gate)
         do = (dy @ fwd.weights_out.mem.T).reshape(b, t, h, dh)

@@ -57,9 +57,11 @@ class DecisionBase(Unit):
         self.accumulate_minibatch()
         if loader.epoch_ended:
             self.on_epoch_ended()
-            for unit in getattr(self.workflow, "forwards", ()):
+            for unit in (*getattr(self.workflow, "forwards", ()),
+                         *getattr(self.workflow, "pass_spans", ())):
                 # forwards that keep epoch totals on the device (the
-                # expert layer's routing) read them here, once
+                # expert layer's routing, the exits' losses, a looped
+                # span's applications) read them here, once
                 hook = getattr(unit, "on_epoch_ended", None)
                 if hook is not None:
                     hook()

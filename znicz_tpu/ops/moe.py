@@ -98,7 +98,7 @@ import jax.numpy as jnp
 from znicz_tpu.memory import Vector
 from znicz_tpu.ops import pallas_gmm
 from znicz_tpu.ops.nn_units import Forward, GradientDescentBase
-from znicz_tpu.ops.rms_norm import (one_norm_placement, rms_norm,
+from znicz_tpu.ops.rms_norm import (norm_gains, post_gain, rms_norm,
                                     rms_norm_backward)
 
 #: slots of ``moe_stats`` after the E per-expert row totals
@@ -728,8 +728,9 @@ class GDMoE(GradientDescentBase):
 
     def _pullback(self):
         """The forward's stashed pullback — valid only inside the trace
-        that made it (see GDMultiHeadAttention.xla_run) — or a new
-        one."""
+        that made it, single-use, the current pass's where a looped
+        span applies the unit R times (see
+        GDMultiHeadAttention.xla_run) — or a new one."""
         fwd = self.forward_unit
         vjp = fwd._traced_vjp if self.err_output._tracing else None
         fwd._traced_vjp = None
@@ -844,7 +845,9 @@ class GatedMLP(Forward):
     RMSNorm.  ``weights`` is W_gate."""
 
     EXPORT_PARAMS = ("weights", "weights_up", "weights_down",
-                     "gain_norm")
+                     "gain_norm", "gain_post")
+    #: may be a member of a looped span (``znicz_tpu.pass_span``)
+    PASS_SAFE = True
 
     def __init__(self, workflow, width: int, pre_norm: str | None = None,
                  residual: bool = False, norm_eps: float = 1e-5,
@@ -861,20 +864,21 @@ class GatedMLP(Forward):
         self.width = int(width)
         self.pre_norm = pre_norm
         #: the norm on the block's OUTPUT inside the skip,
-        #: x + RMSNorm(f(x)), gain ``gain_norm`` (see ops/attention.py)
+        #: x + RMSNorm(f(x)): gain ``gain_norm``, or ``gain_post``
+        #: beside a ``pre_norm`` (see ops/attention.py)
         self.post_norm = post_norm
         self.residual = bool(residual)
         self.norm_eps = float(norm_eps)
         self.weights_up = Vector(name=f"{self.name}.weights_up")
         self.weights_down = Vector(name=f"{self.name}.weights_down")
         self.gain_norm = Vector(name=f"{self.name}.gain_norm")
+        self.gain_post = Vector(name=f"{self.name}.gain_post")
         self._traced_vjp = None
 
     def initialize(self, device=None, **kwargs) -> None:
         super().initialize(device=device, **kwargs)
         if self.input is None or not self.input:
             raise AttributeError(f"{self}: input not linked yet")
-        one_norm_placement(self)
         d, f = self.input.shape[-1], self.width
         for vec, shape in ((self.weights, (d, f)),
                            (self.weights_up, (d, f)),
@@ -885,26 +889,30 @@ class GatedMLP(Forward):
                                           fan_in=shape[0]))
         if (self.pre_norm or self.post_norm) and not self.gain_norm:
             self.gain_norm.reset(np.ones(d, np.float32))
+        if self.pre_norm and self.post_norm and not self.gain_post:
+            self.gain_post.reset(np.ones(d, np.float32))
         self.output.reset(np.zeros(self.input.shape,
                                    dtype=self.output_store_dtype))
         self.inherit_model_shard(self.output)
         self.init_vectors(self.input, self.output, self.weights,
                           self.weights_up, self.weights_down,
-                          self.gain_norm)
+                          self.gain_norm, self.gain_post)
 
     def forward_args(self) -> tuple:
         return (self.input.devmem, self.weights.devmem,
                 self.weights_up.devmem, self.weights_down.devmem,
-                self.gain_norm.devmem if self.gain_norm else None)
+                self.gain_norm.devmem if self.gain_norm else None) \
+            + ((self.gain_post.devmem,) if self.gain_post else ())
 
-    def xla_forward(self, x, w_g, w_u, w_d, g_norm=None):
+    def xla_forward(self, x, w_g, w_u, w_d, g_norm=None, g_post=None):
         x32 = x.astype(jnp.float32)
-        m = x32 if g_norm is None or self.post_norm \
-            else rms_norm(jnp, x32, g_norm, self.norm_eps)
+        g_pre, g_post = norm_gains(self, g_norm, g_post)
+        m = x32 if g_pre is None \
+            else rms_norm(jnp, x32, g_pre, self.norm_eps)
         y = gated_mlp(jnp, self.mxu_dot, m.reshape(-1, x.shape[-1]),
                       w_g, w_u, w_d).reshape(x.shape)
         if self.post_norm:
-            y = rms_norm(jnp, y, g_norm, self.norm_eps)
+            y = rms_norm(jnp, y, g_post, self.norm_eps)
         return x32 + y if self.residual else y
 
     def xla_run(self) -> None:
@@ -924,13 +932,13 @@ class GatedMLP(Forward):
             x.shape)
         raw = None
         if self.post_norm:
-            raw, y = y, rms_norm(np, y, self.gain_norm.mem,
+            raw, y = y, rms_norm(np, y, post_gain(self).mem,
                                  self.norm_eps)
         return (x + y if self.residual else y), (m, gate, up, raw)
 
     def numpy_run(self) -> None:
         for vec in (self.input, self.weights, self.weights_up,
-                    self.weights_down, self.gain_norm):
+                    self.weights_down, self.gain_norm, self.gain_post):
             if vec:
                 vec.map_read()
         self.output.map_invalidate()
@@ -943,7 +951,7 @@ class GDGatedMLP(GDMoE):
     expert layer's; the numpy oracle is analytic."""
 
     MATCHES = (GatedMLP,)
-    EXTRA = ("weights_up", "weights_down", "gain_norm")
+    EXTRA = ("weights_up", "weights_down", "gain_norm", "gain_post")
     HAS_AUX = False
 
     def _cotangent(self, xp, err):
@@ -961,8 +969,10 @@ class GDGatedMLP(GDMoE):
         err = self.err_output.mem.astype(np.float32)
         grads, dy = {}, err
         if fwd.post_norm:                 # back through the output norm
-            dy, grads["gain_norm"] = rms_norm_backward(
-                np, raw, fwd.gain_norm.mem, fwd.norm_eps, err)
+            gain = post_gain(fwd)
+            dy, grads["gain_post" if gain is fwd.gain_post
+                      else "gain_norm"] = rms_norm_backward(
+                np, raw, gain.mem, fwd.norm_eps, err)
         dy = dy.reshape(m.shape)
         grads["weights_down"] = (_silu(np, gate) * up).T @ dy
         dgate, dup, dm = _gated_mlp_backward(

@@ -272,14 +272,9 @@ class GradientDescentBase(AcceleratedUnit, metaclass=MatchingObject):
             name=f"{self.name}.acc_grad_w")
         self.accumulated_gradient_bias = Vector(
             name=f"{self.name}.acc_grad_b")
-        #: round 20 microbatch gradient-accumulation buffers, keyed by
-        #: parameter Vector identity — allocated at initialize when
-        #: ``root.common.engine.grad_accum > 1`` (f32, replicated; the
-        #: ``acc_micro_*`` slot names ride the default ``acc_\w+``
-        #: partition rule).  During an ``("accum", M)`` region phase
-        #: every gradient sums in here instead of updating parameters;
-        #: the ``("apply", M)`` phase folds the mean through the
-        #: unchanged update path (see ``_apply_param_xla``).
+        #: the microbatch gradient-accumulation buffers, keyed by
+        #: parameter Vector identity (:meth:`_whole_gradient` says what
+        #: they are for, :meth:`_alloc_micro_accum` when they exist)
         self._micro_accum: dict[int, Vector] = {}
         # device-resident [lr, lr_bias]; only populated when a
         # LearningRateAdjust unit schedules this GD unit — a region
@@ -350,22 +345,22 @@ class GradientDescentBase(AcceleratedUnit, metaclass=MatchingObject):
         self._alloc_micro_accum()
 
     def _micro_accum_params(self) -> list:
-        """``(suffix, parameter Vector)`` pairs covered by microbatch
-        gradient accumulation; units with extra parameter pairs
-        (attention's output projection) extend this the same way they
-        extend ``EXPORT_PARAMS``."""
+        """``(suffix, parameter Vector)`` pairs that
+        :meth:`_alloc_micro_accum` gives a buffer; units with extra
+        parameter pairs (attention's output projection) extend this
+        the same way they extend ``EXPORT_PARAMS``."""
         return [("w", self.weights), ("b", self.bias)]
 
     def _alloc_micro_accum(self) -> None:
-        """Allocate the round-20 microbatch gradient-accumulation
-        buffers when ``root.common.engine.grad_accum > 1``: one f32
-        zero buffer per parameter tensor, registered as a region leaf
-        (the ``acc_micro_*`` attribute makes ``region_vectors`` pick
-        it up) and mapped from the parameter's identity so
-        ``_apply_param_xla`` finds it during accumulation phases.
-        Replicated placement (the ``acc_\\w+`` default rule): the
-        buffer holds the logically-global microbatch gradient sum;
-        ZeRO-1's reduce-scatter engages once, at apply."""
+        """Allocate the microbatch accumulation buffers of
+        :meth:`_whole_gradient` when ``root.common.engine.grad_accum``
+        > 1: one f32 zero buffer per parameter tensor, a region leaf
+        (the ``micro_accum_*`` attribute makes ``region_vectors`` pick
+        it up), found by the parameter's identity.  Replicated
+        placement (the ``acc_\\w+`` default rule): the buffer holds
+        the logically-global microbatch gradient sum; ZeRO-1's
+        reduce-scatter engages once, at apply.  (The passes of a looped
+        span need none: their sum lives inside one traced step.)"""
         from znicz_tpu.utils.config import root
         n_micro = int(root.common.engine.get("grad_accum", 1) or 1)
         if (n_micro < 2 or self.device is None
@@ -630,22 +625,66 @@ class GradientDescentBase(AcceleratedUnit, metaclass=MatchingObject):
         identical to the unguarded path (``where`` with a true
         predicate selects the new value exactly).
 
-        Round 20 — microbatch gradient accumulation: when the region
-        body traces in an accumulation phase
-        (:func:`~znicz_tpu.accelerated_units.current_accum_phase`),
-        an ``("accum", M)`` microbatch only sums its raw gradient into
-        the f32 micro-accumulation buffer and returns — no pmean, no
-        fingerprint fold, no guard gate, no parameter write; the
-        ``("apply", M)`` microbatch replaces its gradient with the
-        buffered mean ``(Σ grads)/M`` and falls through to the
-        UNCHANGED path below, then zeroes the buffer.  A non-finite
-        gradient in ANY microbatch propagates through the sum, so the
-        guard's finite check at apply skips the whole accumulated
-        step; the buffer zeroing is unconditional so a skipped step
-        cannot poison the next one.
+        A gradient that is not whole yet — a pass of a looped span, a
+        microbatch of an accumulated step — never reaches the update:
+        :meth:`_whole_gradient` is the one home of both, and this
+        method passes on only the whole one, ONCE per parameter and
+        optimizer step (:meth:`_update_param_xla`).
         """
-        from znicz_tpu.accelerated_units import current_accum_phase
-        from znicz_tpu.parallel.axis import current_data_axis
+        grad = self._whole_gradient(grad, vec)
+        if grad is not None:
+            self._update_param_xla(grad, vec, acc_vec, decay, lr, moment)
+
+    def _whole_gradient(self, grad, vec: Vector):
+        """The ONE home of "this gradient is not whole yet": returns the
+        whole gradient of ``vec`` for this optimizer step, or ``None``
+        where ``grad`` is a part that was put by.  Two ways a gradient
+        arrives in parts, and they compose — a looped table under
+        ``run_accumulated`` gives (Σ over microbatches of Σ over
+        passes) / M:
+
+        - **the passes of a looped span** (``znicz_tpu.pass_span``,
+          inside ONE traced step): the span walks its R passes back
+          from the last to the first and sets
+          :func:`~znicz_tpu.accelerated_units.current_pass_phase`;
+          a ``"partial"`` pass adds its gradient to the span's
+          trace-local sum (f32; no buffer on the device outlives the
+          step) and returns ``None``, the ``"whole"`` pass (pass 0,
+          the last walked) returns the SUM.  The adds trace under the
+          scope ``pass_sum`` (``observe.op_scopes()`` phase
+          ``pass_sum``);
+        - **the microbatches of an accumulated step** (round 20,
+          ``JitRegion.run_accum``; across traced bodies, so through
+          device buffers): :func:`~znicz_tpu.accelerated_units.
+          current_accum_phase` ``("accum", M)`` sums the gradient into
+          the f32 micro-accumulation buffer of ``vec``
+          (:meth:`_alloc_micro_accum`: the ``acc_micro_*`` leaves,
+          allocated where ``engine.grad_accum`` > 1, one per tensor of
+          :meth:`_micro_accum_params`) and returns ``None`` — no
+          pmean, no fingerprint fold, no guard gate, no parameter
+          write; ``("apply", M)`` returns the MEAN ``(Σ grads)/M`` and
+          zeroes the buffer.
+
+        A non-finite part propagates through either sum, so the guard's
+        finite check on the whole skips the whole step; the buffer's
+        zeroing is unconditional, so a skipped step cannot poison the
+        next one.
+        """
+        from znicz_tpu.accelerated_units import (current_accum_phase,
+                                                 current_pass_phase)
+        passes = current_pass_phase()
+        if passes is not None:
+            mode, span = passes
+            with jax.named_scope("pass_sum"):
+                part = span.partial.pop(id(vec), None)
+                if part is not None:
+                    grad = part + grad.astype(jnp.float32)
+                elif span.passes > 1:
+                    grad = grad.astype(jnp.float32)
+            if mode == "partial":
+                span.partial[id(vec)] = grad
+                return None
+            assert mode == "whole", passes
         phase = current_accum_phase()
         if phase is not None:
             mode, n_micro = phase
@@ -659,11 +698,18 @@ class GradientDescentBase(AcceleratedUnit, metaclass=MatchingObject):
                     f"_micro_accum_params for extra parameter pairs)")
             if mode == "accum":
                 acc.devmem = acc.devmem + grad.astype(jnp.float32)
-                return
+                return None
             assert mode == "apply", phase
             grad = (acc.devmem + grad.astype(jnp.float32)) \
                 / np.float32(n_micro)
             acc.devmem = jnp.zeros_like(acc.devmem)
+        return grad
+
+    def _update_param_xla(self, grad, vec: Vector, acc_vec, decay: float,
+                          lr, moment: float) -> None:
+        """The update proper, from a WHOLE gradient (see
+        :meth:`_apply_param_xla`, whose scope it traces under)."""
+        from znicz_tpu.parallel.axis import current_data_axis
         grad = maybe_pmean(grad)
         if getattr(self, "_fp8_matmul", False):
             # fp8 gradient round-trip (round 21): the optimizer sees

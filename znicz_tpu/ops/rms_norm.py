@@ -46,15 +46,37 @@ def rms_norm_backward(xp, x, gain, eps: float, err):
 
 
 def one_norm_placement(unit) -> None:
-    """A block has ONE norm (gain ``gain_norm``): before the sublayer
-    (``pre_norm``) or on its output inside the skip (``post_norm``)."""
+    """A block of this unit has ONE norm (gain ``gain_norm``): before
+    the sublayer (``pre_norm``) or on its output inside the skip
+    (``post_norm``).  (Attention and the gated MLP take both, with a
+    gain each: :func:`norm_gains`.)"""
     if unit.pre_norm and unit.post_norm:
         raise ValueError(f"{unit}: pre_norm and post_norm are two "
                          f"placements of the block's one norm; set one")
 
 
+def norm_gains(unit, g_norm, g_post=None) -> tuple:
+    """``(gain of the norm before the sublayer, gain of the norm on its
+    output)`` of a unit with ``pre_norm`` / ``post_norm``, ``None`` for
+    a norm it has not: ``gain_norm`` is the block's one norm's gain
+    whichever its placement, and the pre-norm's where the block has
+    both (x + RMSNorm_post(f(RMSNorm_pre(x))), the sandwich norm), the
+    output norm's then being ``gain_post``."""
+    if unit.pre_norm and unit.post_norm:
+        return g_norm, g_post
+    return (None, g_norm) if unit.post_norm else (g_norm, None)
+
+
+def post_gain(unit):
+    """The Vector of a unit's ``post_norm`` gain (:func:`norm_gains`)."""
+    return unit.gain_post if unit.pre_norm else unit.gain_norm
+
+
 class RMSNorm(Forward):
     """Per-position RMS normalization with a learned gain."""
+
+    #: may be a member of a looped span (``znicz_tpu.pass_span``)
+    PASS_SAFE = True
 
     def __init__(self, workflow, eps: float = 1e-5, name=None,
                  **kwargs) -> None:
