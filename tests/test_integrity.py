@@ -413,6 +413,67 @@ def test_grouped_matmul_kernels_compiled_for_v5e_at_the_cells_shapes(
                if re.sub(r"[.\d]+$", "", name).endswith("znicz_gmm_t"))
 
 
+#: forward calls no attention-layer test above reaches: name → (operand
+#: shapes, query heads, K/V heads, window, the form the chooser picks)
+_FORWARD_FORMS = {
+    "laguna_window_48_on_8": (
+        ((1, 4096, 6144), (1, 4096, 1024), (1, 4096, 1024)), 48, 8, 512,
+        ("carried", "lanes", "exp")),
+    "laguna_full_72_on_8": (
+        ((1, 4096, 9216), (1, 4096, 1024), (1, 4096, 1024)), 72, 8, None,
+        ("carried", "lanes", "exp")),
+    "head_major_dh96": (((2, 2048, 3 * 4 * 96),), 4, 4, None,
+                        ("none", "lanes", "exp")),
+    "head_major_dh192": (((1, 2048, 3 * 4 * 192),), 4, 4, None,
+                         ("none", "lanes", "exp")),
+}
+
+
+@pytest.mark.parametrize("name", list(_FORWARD_FORMS) + ["ring_hop_pairs"])
+def test_flash_forward_compiled_for_v5e_in_every_form(v5e_chip, name):
+    """The flash forward's visit at the shapes beside the two attention
+    layers above — a window, grouped queries, the head-major fallback
+    at dh 96 / 192, the ring's 1024-long K tile with pairs of dh-64
+    heads and traced offsets: Mosaic takes each form the chooser picks
+    (``pallas_attention.forward_form``) inside the scoped VMEM, under
+    the kernel's name (PERF.md §6, PR 36)."""
+    import jax
+    import jax.numpy as jnp
+
+    from znicz_tpu.ops import pallas_attention as pa
+
+    def struct(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    if name == "ring_hop_pairs":
+        want, kernel = ("none", "lanes", "q"), "znicz_flash_fwd"
+        assert tuple(pa.forward_form(1024, 1024, 1024, 64)) == want
+        fn = jax.jit(lambda q, k, v, q_off, k_off: pa.ring_hop(
+            q, k, v, q_off, k_off, True, 1024, 1024, pack=2))
+        args = [struct((1, 4, 1024, 128))] * 3 + [struct((), jnp.int32)] * 2
+    else:
+        shapes, heads, kv_heads, window, want = _FORWARD_FORMS[name]
+        t = shapes[0][1]
+        dh = shapes[0][2] // (heads + 2 * kv_heads
+                              if len(shapes) == 1 else heads)
+        blocks = pa.grid_blocks(True, t, t) if window is None \
+            else pa.band_blocks(t)
+        assert tuple(pa.forward_form(t, *blocks, dh, window)) == want
+        kernel = "znicz_flash_fwd" + ("_win" if window else "")
+        fn = jax.jit(lambda *arrays: pa.flash_attention_rows(
+            arrays, heads, causal=True, n_kv_heads=kv_heads,
+            window=window))
+        args = [struct(shape) for shape in shapes]
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:   # a described chip's executable cannot be read back here
+        text = fn.lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    calls = re.findall(r"%(znicz_flash_\w+?)(?:\.\d+)? = ", text)
+    assert calls == [kernel], calls
+
+
 def test_vote_verdict_clean_selfbad_majority_tie():
     v = integrity.vote_verdict([1.0, 1.0, 1.0], [1.0, 1.0, 1.0], 1e-3)
     assert v == {"divergent": False, "culprits": [], "self_bad": []}

@@ -618,6 +618,186 @@ def test_one_pass_hop_with_offsets_masked_rows_and_an_lse_cotangent(
     assert np.all(np.asarray(got[0])[:, :, ~vis] == 0.0)
 
 
+# ---- the forward's visit (PR 36): state, statistics, scale ------------
+def _plain_heads(q, k, v, causal, window=None, q_off=0, k_off=0):
+    """(out, lse) of (B, T, H, dh) q on (B, T_k, H_kv, dh) k, v in f32,
+    masked by global position; a fully-masked row reads out 0 and
+    lse −1e30."""
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    rows = q_off + jnp.arange(q.shape[1])[:, None]
+    cols = k_off + jnp.arange(k.shape[1])[None, :]
+    mask = jnp.ones_like(rows >= cols)
+    if causal:
+        mask = rows >= cols
+    if window is not None:
+        mask = mask & (cols > rows - window)
+    s = jnp.where(mask, s, -1e30)
+    seen = mask.any(axis=1)[None, None, :, None]
+    p = jnp.where(seen, jax.nn.softmax(s, axis=-1), 0.0)
+    return (jnp.einsum("bhqk,bkhd->bqhd", p, v),
+            jnp.where(seen[..., 0], jax.nn.logsumexp(s, axis=-1), -1e30))
+
+
+#: name → (T, query heads, K/V heads, dh, causal, grid tile, sub-tile,
+#: window, fused operand, the form the chooser has to pick): tiles and
+#: sub-tiles 128 lanes wide, so the lane-wise folds run as on the chip
+FORWARD_CASES = {
+    "one_k_tile_pairs": (256, 2, 2, 64, True, (128, 256), (128, 128),
+                         None, True, ("none", "lanes", "q")),
+    "one_k_tile_dh128": (256, 1, 1, 128, True, (128, 256), (128, 128),
+                         None, False, ("none", "lanes", "exp")),
+    "two_k_tiles_pairs": (512, 2, 2, 64, True, (256, 256), (128, 128),
+                          None, True, ("carried", "lanes", "q")),
+    "two_k_tiles_dh128": (512, 1, 1, 128, True, (256, 256), (128, 128),
+                          None, False, ("carried", "lanes", "exp")),
+    "grouped_4_on_2": (512, 4, 2, 128, True, (256, 256), (128, 128),
+                       None, False, ("carried", "lanes", "exp")),
+    "window_128": (512, 2, 1, 128, True, (128, 128), None, 128, False,
+                   ("carried", "lanes", "exp")),
+    "head_major_dh96": (256, 2, 2, 96, True, (128, 256), (128, 128),
+                        None, True, ("none", "lanes", "exp")),
+    "not_causal_two_k_tiles": (256, 2, 2, 64, False, (128, 128), None,
+                               None, True, ("carried", "lanes", "q")),
+}
+
+
+def _forward_case(name):
+    t, h, h_kv, dh, causal, blocks, sub, window, fused, _ = \
+        FORWARD_CASES[name]
+    q = _rand((1, t, h, dh), 50)
+    k, v = (_rand((1, t, h_kv, dh), s) for s in (51, 52))
+    kw = dict(causal=causal, block_q=blocks[0], block_k=blocks[1],
+              sub_tile=sub, window=window, interpret=True,
+              n_kv_heads=h_kv)
+
+    def rows(q, k, v):
+        flat = [a.reshape(1, t, -1) for a in (q, k, v)]
+        if fused:
+            flat = [jnp.concatenate(flat, axis=-1)]
+        from znicz_tpu.ops.pallas_attention import flash_attention_rows
+        return flash_attention_rows(tuple(flat), h, **kw) \
+            .reshape(1, t, h, dh)
+    return (q, k, v), rows, (causal, window)
+
+
+@pytest.mark.parametrize("name", list(FORWARD_CASES))
+def test_forward_form_o_and_lse_match_the_plain_reference(name,
+                                                          monkeypatch):
+    """Every form of the forward's visit — no state where a row block
+    meets its keys in one visit, state carried over K tiles (and left
+    out again for the Q tiles whose keys are all in the first),
+    statistics lane-replicated, 1/√dh in q or in the exponential —
+    gives the reference's ``o`` and the ``lse`` the backward reads."""
+    from znicz_tpu.ops import pallas_attention as pa
+    (q, k, v), rows, (causal, window) = _forward_case(name)
+    seen = {}
+    call = pa._fwd_call
+
+    def spy(*args):
+        seen["out"], seen["lse"] = call(*args)
+        bq, bk, pack, cols, win = args[4], args[5], args[7], args[9], \
+            args[10]
+        width = args[0][0].shape[-1] if cols is None else cols[1]
+        seen["form"] = tuple(pa.forward_form(q.shape[1], bq, bk,
+                                             width // pack, win))
+        return seen["out"], seen["lse"]
+
+    monkeypatch.setattr(pa, "_fwd_call", spy)
+    out = rows(q, k, v)
+    want, want_lse = _plain_heads(q, k, v, causal, window)
+    assert seen["form"] == FORWARD_CASES[name][-1]
+    np.testing.assert_allclose(out, want, atol=2e-5)
+    lse = seen["lse"]                   # (B, Hp, T, pack · _LANES)
+    b, hp, t, lanes = lse.shape
+    pack = lanes // pa._LANES
+    per_head = lse.reshape(b, hp, t, pack, pa._LANES) \
+        .transpose(0, 1, 3, 2, 4).reshape(b, hp * pack, t, pa._LANES)
+    np.testing.assert_allclose(
+        per_head, jnp.broadcast_to(want_lse[..., None], per_head.shape),
+        atol=2e-5, rtol=1e-6)
+
+
+@pytest.mark.parametrize("name", ["one_k_tile_pairs", "one_k_tile_dh128",
+                                  "two_k_tiles_pairs", "grouped_4_on_2",
+                                  "window_128", "head_major_dh96"])
+def test_backward_fed_by_the_forwards_lse_matches_autodiff(name):
+    """The backward recomputes p = exp(s − lse) from what the forward
+    saved: with every form's ``lse`` the three gradients are
+    ``jax.grad`` of the reference's."""
+    (q, k, v), rows, (causal, window) = _forward_case(name)
+    dy = _rand(q.shape, 53)
+    want = jax.grad(lambda *a: jnp.vdot(
+        _plain_heads(*a, causal, window)[0], dy), (0, 1, 2))(q, k, v)
+    got = jax.grad(lambda *a: jnp.vdot(rows(*a), dy), (0, 1, 2))(q, k, v)
+    for label, a, w in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(a, w, atol=5e-5, err_msg=label)
+
+
+@pytest.mark.parametrize("pack,t_k,block_k", [(1, 256, 256), (2, 256, 256),
+                                              (1, 512, 256), (2, 512, 256)])
+def test_forward_hop_with_fully_masked_rows_in_every_form(pack, t_k,
+                                                          block_k):
+    """A ring hop whose first rows see no key (q rows 64…319 against k
+    columns 192…): state-free (one K tile) and carried (two) alike keep
+    m at −1e30 there — ``lse`` ≈ −1e30, ``o`` exactly 0, weight 0 in the
+    cross-hop combination — and give the reference's values for the
+    rows that see a key."""
+    from znicz_tpu.ops.pallas_attention import ring_hop
+    b, hp, t, dh = 1, 2, 256, 128 // pack
+    q_off, k_off = 64, 192
+    q = _rand((b, hp, t, pack * dh), 60)
+    k, v = (_rand((b, hp, t_k, pack * dh), s) for s in (61, 62))
+    out, lse = ring_hop(q, k, v, q_off, k_off, True, 128, block_k,
+                        interpret=True, pack=pack, sub_tile=(128, 128))
+
+    def heads(a):       # (B, Hp, T, pack·dh) → (B, T, Hp·pack, dh)
+        return a.reshape(b, hp, a.shape[2], pack, dh) \
+            .transpose(0, 2, 1, 3, 4).reshape(b, a.shape[2], hp * pack, dh)
+
+    want, want_lse = _plain_heads(heads(q), heads(k), heads(v), True,
+                                  None, q_off, k_off)
+    hidden = (q_off + np.arange(t)) < k_off
+    got = np.asarray(heads(out))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert np.all(got[:, hidden] == 0.0)
+    got_lse = np.asarray(lse).transpose(0, 1, 3, 2) \
+        .reshape(b, hp * pack, t)
+    np.testing.assert_allclose(got_lse[..., ~hidden],
+                               np.asarray(want_lse)[..., ~hidden],
+                               atol=2e-5, rtol=1e-6)
+    assert np.all(got_lse[..., hidden] <= -0.99e30)
+
+
+@pytest.mark.parametrize("shape,t_k,blocks,dh,window,want", [
+    ("attn_lm_train_t2048", 2048, None, 64, None, ("none", "lanes", "q")),
+    ("olmoe / ouro / hybrid", 4096, None, 128, None,
+     ("carried", "lanes", "exp")),
+    ("laguna's window", 4096, None, 128, 512,
+     ("carried", "lanes", "exp")),
+    ("T 1024", 1024, None, 64, None, ("none", "lanes", "q")),
+    ("the ring's 1024-long K tile", 1024, (1024, 1024), 64, None,
+     ("none", "lanes", "q")),
+    ("a hop over two K tiles", 2048, (1024, 1024), 128, None,
+     ("carried", "lanes", "exp")),
+    ("dh 256", 2048, None, 256, None, ("none", "lanes", "q")),
+    ("dh 96, head-major", 2048, None, 96, None, ("none", "lanes", "exp")),
+])
+def test_forward_form_is_read_from_the_shapes(shape, t_k, blocks, dh,
+                                              window, want):
+    """No option picks the forward's body: one K step means no state,
+    and 1/√dh goes into q only where it is a power of two."""
+    from znicz_tpu.ops import pallas_attention as pa
+    if blocks is None:
+        blocks = pa.grid_blocks(True, t_k, t_k) if window is None \
+            else pa.band_blocks(t_k)
+    if window is not None:
+        assert blocks == (512, 512)
+        assert pa.band_steps(t_k, *blocks, window)[0] == 2
+    assert tuple(pa.forward_form(t_k, *blocks, dh, window)) == want
+
+
 def test_unit_engages_flash_only_on_tpu(monkeypatch):
     """The default-on resolution: CPU devices never engage the kernel
     (is_tpu_device gates it), so the oracle tests above are the

@@ -387,8 +387,20 @@ def test_causal_schedule_resolves_from_shapes_not_options(monkeypatch,
     assert obs_metrics.flash_backward(unit.name, 1).value == 1
     assert 'znicz_flash_backward{unit="%s",passes="1"} 1' % unit.name \
         in obs_metrics.REGISTRY.to_prometheus()
-    # T 4096: the backward takes the two 2048-long K tiles whole; past
-    # that a dq and a dk/dv kernel
+    # … and a row block of the forward meets its keys in ONE visit, so
+    # it carries no softmax state (pallas_attention.forward_form)
+    assert unit._flash_forward == pa.forward_form(2048, 1024, 2048, 16 // 2)
+    assert unit._flash_forward.state == "none"
+    assert "fwd_state: none, fwd_stats: lanes, fwd_scale: " in caplog.text
+    assert obs_metrics.flash_forward(
+        unit.name, *unit._flash_forward).value == 1
+    assert 'znicz_flash_forward{unit="%s",state="none",stats="lanes",' \
+        % unit.name in obs_metrics.REGISTRY.to_prometheus()
+    # T 4096: the backward takes the two 2048-long K tiles whole, the
+    # forward carries its state over them; past that a dq and a dk/dv
+    # kernel
+    assert _attention_unit(XLADevice(), t=4096,
+                           causal=True)._flash_forward.state == "carried"
     assert _attention_unit(XLADevice(), t=4096,
                            causal=True)._flash_backward == 1
     assert _attention_unit(XLADevice(), t=8192,

@@ -610,6 +610,29 @@ def flash_backward(unit: str, passes: int) -> Gauge:
         labels=("unit", "passes")).labels(unit=unit, passes=str(passes))
 
 
+def flash_forward(unit: str, state: str, stats: str, scale: str) -> Gauge:
+    """What ONE visit of an attention unit's flash FORWARD does between
+    the score product and the value product
+    (``pallas_attention.forward_form``, from the call's shapes):
+    ``state`` = ``none`` — a row block meets all its keys in one visit
+    (one K step: T ≤ 2048) and writes ``o`` and ``lse`` itself — or
+    ``carried``: m, l and the accumulator wait in VMEM for the next K
+    tile (a Q tile whose keys all lie in the first still visits
+    state-free); ``stats`` = ``lanes``: a row's m and l replicated over
+    a 128-lane tile through the arithmetic, folded across lanes once
+    per visit; ``scale`` = ``q`` (1/√dh a power of two: folded into q,
+    nothing rounded) or ``exp`` (into the exponential's own multiply).
+    Static per program, 1 for the form in force, set once at
+    ``initialize``."""
+    return REGISTRY.gauge(
+        "znicz_flash_forward",
+        "Form of a visit of the flash-attention forward: softmax state "
+        "(none, carried), layout of the row statistics, where 1/sqrt(dh) "
+        "enters; 1 for the form in force",
+        labels=("unit", "state", "stats", "scale")).labels(
+            unit=unit, state=state, stats=stats, scale=scale)
+
+
 def moe_expert_tokens(unit: str, stat: str) -> Gauge:
     """Rows (token, expert) pairs an expert of a ``MoE`` unit computed
     per step, over the last epoch: ``stat`` = ``max`` / ``min`` (the

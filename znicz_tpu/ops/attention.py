@@ -503,10 +503,15 @@ class MultiHeadAttention(Forward):
         self._flash_layout = None
         #: passes over the score tiles in the backward (1: one kernel)
         self._flash_backward = None
+        #: what a visit of the forward's walk does (state, statistics,
+        #: where 1/√dh enters): ``pallas_attention.forward_form``
+        self._flash_forward = None
         if self._flash_pallas:
             self._flash_layout = (layout, head_pack)
             self._flash_backward = pallas_attention.backward_passes(
                 self.causal, t, bk, window)
+            self._flash_forward = pallas_attention.forward_form(
+                t, bq, bk, dh, window)
             sq, sk = pallas_attention.sub_tile_for(self.causal, bq, bk) \
                 if window is None else (bq, bk)
             self._flash_sub_tile = (sq, sk)
@@ -521,6 +526,8 @@ class MultiHeadAttention(Forward):
                                      group).set(1)
             obs_metrics.flash_backward(self.name,
                                        self._flash_backward).set(1)
+            obs_metrics.flash_forward(self.name,
+                                      *self._flash_forward).set(1)
             if window is not None:
                 share = pallas_attention.band_share(t, window)
                 for stat, value in (
@@ -536,6 +543,7 @@ class MultiHeadAttention(Forward):
             self.info("%s: flash kernel, blocks (%d, %d), sub-tiles "
                       "(%d, %d): %d interior + %d crossing of %d = "
                       "%.4f of T×T executed, layout=%s, head pack %d, "
+                      "fwd_state: %s, fwd_stats: %s, fwd_scale: %s, "
                       "backward passes %d%s%s%s",
                       self.name, bq, bk, *self._flash_sub_tile,
                       tiles["interior"], tiles["crossing"]
@@ -543,7 +551,8 @@ class MultiHeadAttention(Forward):
                       sum(n for cls, n in tiles.items()
                           if cls != "executed_share"),
                       tiles["executed_share"],
-                      layout, head_pack, self._flash_backward,
+                      layout, head_pack, *self._flash_forward,
+                      self._flash_backward,
                       "" if group == 1 and window is None else
                       ", %d query heads to a K/V head, window %s (band "
                       "%.4f of T×T)" % (

@@ -14,10 +14,34 @@ core runs at MXU rate instead of bandwidth rate.
 Design (the standard flash decomposition, implemented TPU-first):
 
 - **forward**: grid (B, H, nq, nk), K-blocks innermost ("arbitrary"
-  semantics — sequential per core); online-softmax state (running row
-  max m, normalizer l, weighted accumulator) lives in VMEM scratch
-  across the K iterations; the output block and the per-row
-  logsumexp are written once at the last K block.
+  semantics — sequential per core).  What ONE visit of the walk does
+  between the score product and the value product follows from the
+  call's static shapes (:func:`forward_form`, never an option; PR 36):
+
+  - *state*.  Where the grid's last axis has ONE step (every causal
+    call at T ≤ 2048 — the LM cell; a ring hop whose keys are one tile)
+    a row block meets all its keys in one visit and carries nothing:
+    the body computes m, l and o of its rows and writes ``o`` and
+    ``lse`` itself — no initialisation, no scratch, no correction
+    factor, no finishing pass.  Else the online-softmax state (running
+    row max m, normalizer l, f32 accumulator) waits in VMEM scratch
+    for the next K tile and ``o`` / ``lse`` leave at the last — except
+    for a Q tile whose keys ALL lie in the first K tile (rows below
+    2048 at T 4096, read from scalars at run time): its row blocks
+    visit state-free too.
+  - *statistics*.  A row's m and l are lane-REPLICATED (rows, 128)
+    tiles through all of the arithmetic and in the scratch (a tile per
+    sub-head): nothing is narrowed to one lane, broadcast back or
+    concatenated; the max and the sum over a visit's keys are plain
+    elementwise folds over the runs' 128-column slabs and over the
+    runs, and the lanes are folded ONCE per visit and statistic.
+  - *scale*.  1/√dh goes into q once per visit where that rounds
+    nothing (a power of two: dh 64, 256); else into the multiply the
+    exponential already makes (p = 2^((s − m)·log2e/√dh), m the max
+    of the unscaled scores), so no pass over the scores is a multiply
+    only.  A masked score needs ONE select: fully-masked rows (offset
+    hops) exponentiate against 0 instead of their m of -1e30, so the
+    masked scores underflow to the zeros they are.
 - **backward**: recompute-from-lse form — no (T, T) residual is ever
   stored.  Saves (q, k, v, o, lse) from the forward, precomputes
   ``delta = rowsum(do·o)`` (one cheap XLA pass), then recomputes the
@@ -64,10 +88,12 @@ Design (the standard flash decomposition, implemented TPU-first):
   no mask code at all; and the short run the diagonal can cross —
   masked by global position (exact across block boundaries, the rule
   of ``ring_attention._visibility``).  A row block (fwd, dq) or column
-  block (dk/dv) is ONE visit over all its visible sub-tiles, so the
-  online-softmax statistics are read, rescaled and written once per
-  visit — on the chip that, not the skipped work, is most of what the
-  forward gains.  Shapes are static: the visit's extent is picked by
+  block (dk/dv) is ONE visit over all its visible sub-tiles, so
+  whatever the kernel keeps per row or column (the forward's softmax
+  state where it carries one, the backward's accumulators) is read
+  and written once per visit — on the chip that, not the skipped work,
+  is most of what the forward gained (PR 24).  Shapes are static: the
+  visit's extent is picked by
   ``pl.when`` among the few a tile allows.  Share of the T × T square
   executed at the chooser's tiles (`grid_blocks`, `sub_tile_for`,
   `causal_tile_counts`): T 512 1.0, T 1024 0.75, T 2048 0.625, T 4096
@@ -139,6 +165,8 @@ equality tests.
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -147,6 +175,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+_LOG2_E = math.log2(math.e)
 #: default GRID tile sizes — the unit of DMA, of the BlockSpecs and of
 #: the VMEM accumulators.  Chip-swept with one body per tile (PERF.md
 #: round 5): 1024×1024 beats 512×512 by 1.2–1.75× per kernel (PERF.md
@@ -183,12 +212,14 @@ WHOLE_BLOCK_K = 4096
 #: f32 accumulators of 4096 × 128 overflow the 16 MB a call gets
 #: unasked by 0.4–1.4 MB (compiled for a described v5e, PR 30)
 _WHOLE_K_VMEM = 40 * 2 ** 20
-#: lane width for the per-row statistics arrays (lse, delta): the
-#: minimum tile-legal last dim — the value is replicated across lanes
-#: (with head packing, each sub-head owns one _LANES-wide lane group)
+#: lane width of the per-row statistics that live in HBM (lse, delta):
+#: the minimum tile-legal last dim — the value is replicated across
+#: lanes (with head packing, each sub-head owns one _LANES-wide lane
+#: group)
 _LANES = 8
-#: lane width of the f32 stats scratch (one VMEM tile row); sub-heads
-#: split it into 128/pack-wide column groups
+#: the lane width of a vreg: the forward keeps a row's m and l
+#: replicated over this many lanes, in its arithmetic and (a tile per
+#: sub-head) in its scratch; also what a pair of dh-64 heads fills
 _STAT_LANES = 128
 
 
@@ -569,84 +600,210 @@ def _fold_rows(body, causal: bool, row0, col0, bq: int, bk: int, sq: int,
 # ----------------------------------------------------------------------
 # forward
 # ----------------------------------------------------------------------
+class ForwardForm(NamedTuple):
+    """What ONE visit of the forward's walk does between the score
+    product and the value product (:func:`forward_form`)."""
+    #: ``"none"`` — a row block meets all its keys in one visit and
+    #: writes ``o`` and ``lse`` itself; ``"carried"`` — m, l and the
+    #: accumulator wait in VMEM scratch for the next K tile
+    state: str
+    #: where a row's statistics live through the arithmetic
+    stats: str
+    #: where 1/√dh enters: ``"q"`` — into q, once per visit (a power of
+    #: two: nothing is rounded); ``"exp"`` — into the exponential's own
+    #: multiply, the scores staying unscaled
+    scale: str
+
+
+def forward_form(t_k: int, bq: int, bk: int, dh: int,
+                 window=None) -> ForwardForm:
+    """The forward body a call over ``t_k`` keys in (bq, bk) grid tiles
+    gets, from its static shapes alone: no state where the grid's last
+    axis has ONE step (the K tiles of a causal call, the tiles a band
+    touches of a windowed one), 1/√dh in q where it is a power of two.
+    Static per program: the attention unit reports it (info line,
+    ``znicz_flash_forward``)."""
+    k_steps = t_k // bk if window is None \
+        else band_steps(t_k, bq, bk, window)[0]
+    mantissa, _ = math.frexp(dh ** -0.5)
+    return ForwardForm("none" if k_steps == 1 else "carried", "lanes",
+                       "q" if mantissa == 0.5 else "exp")
+
+
+#: an elementwise fold → the reduction that finishes it across lanes
+_ACROSS = {jnp.maximum: jnp.max, jnp.add: jnp.sum}
+
+
+def _lane_fold(part, x, op):
+    """``part`` ∘ the 128-lane slabs of ``x``, elementwise: a row
+    statistic of a visit stays lane-wise — plain VPU operations — over
+    a run's slabs and over its runs, and the lanes are folded once per
+    visit.  A run narrower than the lanes (the tests') folds at once."""
+    if x.shape[1] % _STAT_LANES:
+        slabs = [_ACROSS[op](x, axis=1, keepdims=True)]
+    else:
+        slabs = [x[:, j:j + _STAT_LANES]
+                 for j in range(0, x.shape[1], _STAT_LANES)]
+    for slab in slabs:
+        part = slab if part is None else op(part, slab)
+    return part
+
+
+def _replicated(part, op):
+    """The lanes of a visit's partial statistic folded — the ONE
+    cross-lane reduction — and the value put back in every lane."""
+    return jnp.broadcast_to(_ACROSS[op](part, axis=1, keepdims=True),
+                            (part.shape[0], _STAT_LANES))
+
+
+def _spread(stat, width: int):
+    """A lane-replicated (rows, 128) statistic against ``width``
+    columns: its own lanes, side by side as often as needed."""
+    if width <= _STAT_LANES:
+        return stat[:, :width]
+    if width % _STAT_LANES:
+        return stat[:, :1]
+    return pltpu.repeat(stat, width // _STAT_LANES, 1)
+
+
+def _visit(q, k_ref, v_ref, fs, parts, masks, prev, mul):
+    """One sub-head's softmax of rows ``q`` (sq, dh) over every column
+    run of a visit: ``(m, l, acc)``, m and l lane-replicated (sq, 128)
+    through all of the arithmetic, so nothing is broadcast from or
+    narrowed to one lane.  ``prev`` is the state the rows bring (None:
+    they have no history); ``mul`` the factor of the exponential's
+    argument, p = 2^((s − m)·mul) — the EUP's exponential is a power of
+    two, so log2(e) and, where q could not take it, 1/√dh are ONE
+    multiply."""
+    scores, top = [], None
+    for (c, n, _), mask in zip(parts, masks):
+        s = _dot(q, k_ref[c:c + n, fs], trans_b=True)
+        if mask is not None:
+            s = jnp.where(mask, s, _NEG_INF)
+        scores.append(s)
+        top = _lane_fold(top, s, jnp.maximum)
+    m_new = _replicated(top, jnp.maximum)
+    if prev is not None:
+        m_new = jnp.maximum(prev[0], m_new)
+    m_exp = m_new
+    if any(mask is not None for mask in masks):
+        # offset hops can hold FULLY-masked rows (m stays -1e30, and
+        # exp(s − m) = exp(0) there): against 0 instead their masked
+        # scores underflow to the zeros they are.  A row with one
+        # visible score has a finite m and needs nothing; neither does
+        # a visit of interior runs only
+        m_exp = jnp.where(m_new > _NEG_INF, m_new, 0.0)
+    total, acc = None, None
+    for (c, n, _), s in zip(parts, scores):
+        pt = jnp.exp2((s - _spread(m_exp, n)) * mul)
+        total = _lane_fold(total, pt, jnp.add)
+        v = v_ref[c:c + n, fs]
+        part = _dot(pt.astype(v.dtype), v)
+        acc = part if acc is None else acc + part
+    l_new = _replicated(total, jnp.add)
+    if prev is not None:
+        corr = jnp.exp2((prev[0] - m_new) * mul)
+        l_new = prev[1] * corr + l_new
+        acc = prev[2] * _spread(corr, acc.shape[1]) + acc
+    return m_new, l_new, acc
+
+
 def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale, causal, bq, bk, sq, sk,
-                pack, window=None):
+                *scratch, scale, causal, bq, bk, sq, sk, pack, form,
+                window=None):
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
     row0 = qoff_ref[0, 0] + iq * bq
     col0 = _k_col0(koff_ref, row0, ik, bk, window)
+    carried = form.state == "carried"
+    dh = q_ref.shape[1] // pack
+    in_q = form.scale == "q"
+    mul = _LOG2_E if in_q else scale * _LOG2_E
 
-    @pl.when(ik == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    def body(r, parts):
-        """ONE online-softmax update of rows [r, r+sq) by every column
-        run in ``parts``."""
-        rs = _ds(r, sq)
-        q_all = q_ref[rs, :]
-        d = q_all.shape[1]
-        dh, sw = d // pack, _STAT_LANES // pack
-        masks = [_causal_mask(row0 + r, col0 + c, sq, n, window)
-                 if masked else None for c, n, masked in parts]
-        m_all, l_all, acc_all = m_scr[rs, :], l_scr[rs, :], acc_scr[rs, :]
-        m_out, l_out, acc_out = [], [], []
-        for p in range(pack):           # static: per-sub-head math
-            fs = slice(p * dh, (p + 1) * dh)
-            q = q_all[:, fs]
-            m_prev = m_all[:, p * sw:p * sw + 1]        # (sq, 1)
-            scores, m_new = [], m_prev
-            for (c, n, _), mask in zip(parts, masks):
-                s = _dot(q, k_ref[c:c + n, fs],
-                         trans_b=True) * scale
-                if mask is not None:
-                    s = jnp.where(mask, s, _NEG_INF)
-                scores.append(s)
-                m_new = jnp.maximum(
-                    m_new, jnp.max(s, axis=1, keepdims=True))
-            corr = jnp.exp(m_prev - m_new)              # (sq, 1)
-            l_new = l_all[:, p * sw:p * sw + 1] * corr
-            acc = acc_all[:, fs] * corr
-            for (c, n, _), mask, s in zip(parts, masks, scores):
-                pt = jnp.exp(s - m_new)
-                if mask is not None:
-                    # offset hops can hold FULLY-masked rows (m stays
-                    # -inf): exp(s - m) = exp(0) there without this
-                    # guard.  An interior run has no masked entry, so
-                    # its row maximum is finite: neither select
-                    pt = jnp.where(mask, pt, 0.0)
-                l_new = l_new + jnp.sum(pt, axis=1, keepdims=True)
-                v = v_ref[c:c + n, fs]
-                acc = acc + _dot(pt.astype(v.dtype), v)
-            l_out.append(jnp.broadcast_to(l_new, (sq, sw)))
-            acc_out.append(acc)
-            m_out.append(jnp.broadcast_to(m_new, (sq, sw)))
-        m_scr[rs, :] = jnp.concatenate(m_out, axis=1)
-        l_scr[rs, :] = jnp.concatenate(l_out, axis=1)
-        acc_scr[rs, :] = jnp.concatenate(acc_out, axis=1)
-
-    _fold_rows(body, causal, row0, col0, bq, bk, sq, sk, window)
-
-    @pl.when(ik == nk - 1)
-    def _finish():
-        d = o_ref.shape[1]
-        dh, sw = d // pack, _STAT_LANES // pack
+    def leave(rows, stats):
+        """``o`` and ``lse`` of ``rows`` from every sub-head's final
+        (m, l, acc)."""
         o_out, lse_out = [], []
-        for p in range(pack):
-            fs = slice(p * dh, (p + 1) * dh)
-            l = jnp.maximum(l_scr[:, p * sw:p * sw + 1], 1e-30)
-            o_out.append((acc_scr[:, fs] / l).astype(o_ref.dtype))
+        for m, l, acc in stats:
+            l = jnp.maximum(l, 1e-30)
+            if not in_q:    # m is a maximum of unscaled scores
+                m = jnp.where(m > _NEG_INF, m * scale, _NEG_INF)
+            o_out.append((acc / _spread(l, dh)).astype(o_ref.dtype))
             # row stats ride _LANES lanes per sub-head (minimum
             # tile-legal lane width; the value repeats in every lane)
-            lse_out.append(jnp.broadcast_to(
-                m_scr[:, p * sw:p * sw + 1] + jnp.log(l),
-                (bq, _LANES)))
-        o_ref[...] = jnp.concatenate(o_out, axis=1)
-        lse_ref[...] = jnp.concatenate(lse_out, axis=1)
+            lse_out.append((m + jnp.log(l))[:, :_LANES])
+        o_ref[rows, :] = jnp.concatenate(o_out, axis=1)
+        lse_ref[rows, :] = jnp.concatenate(lse_out, axis=1)
 
+    # a Q tile of a carried call whose keys ALL lie in the K tile of step
+    # 0, its first row seeing that tile's first key: each of its row
+    # blocks has exactly one visit there too, and carries nothing
+    once = False
+    if carried and causal and window is None:
+        once = (row0 + bq - 1 < koff_ref[0, 0] + bk) \
+            & (row0 >= koff_ref[0, 0])
+    if carried:
+        m_scr, l_scr, acc_scr = scratch
+
+        @pl.when((ik == 0) & jnp.logical_not(once))
+        def _init():
+            m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def state(p, rs):
+        """Sub-head ``p``'s (m, l, acc) of rows ``rs`` as the scratch
+        holds them."""
+        return (m_scr[p, rs, :], l_scr[p, rs, :],
+                acc_scr[rs, p * dh:(p + 1) * dh])
+
+    def body(r, parts, carried=carried):
+        """ONE softmax update of rows [r, r+sq) by every column run in
+        ``parts``."""
+        rs = _ds(r, sq)
+        q_all = q_ref[rs, :]
+        if in_q:
+            q_all = (q_all.astype(jnp.float32) * scale).astype(q_all.dtype)
+        masks = [_causal_mask(row0 + r, col0 + c, sq, n, window)
+                 if masked else None for c, n, masked in parts]
+        stats = []
+        for p in range(pack):           # static: per-sub-head math
+            fs = slice(p * dh, (p + 1) * dh)
+            stats.append(_visit(q_all[:, fs], k_ref, v_ref, fs, parts,
+                                masks, state(p, rs) if carried else None,
+                                mul))
+        if not carried:
+            leave(rs, stats)
+            return
+        for p, (m, l, _) in enumerate(stats):
+            m_scr[p, rs, :], l_scr[p, rs, :] = m, l
+        acc_scr[rs, :] = jnp.concatenate([acc for *_, acc in stats],
+                                         axis=1)
+
+    if causal and not carried:
+        # an offset hop's rows above every key get no visit at all, and
+        # no state waits here to speak for them: they read o 0 and
+        # lse -1e30 (the units' calls, at offsets 0, never come here)
+        @pl.when(row0 < col0)
+        def _unseen():
+            o_ref[...] = jnp.zeros_like(o_ref)
+            lse_ref[...] = jnp.full_like(lse_ref, _NEG_INF)
+
+    def fold(visit):
+        _fold_rows(visit, causal, row0, col0, bq, bk, sq, sk, window)
+
+    if once is False:
+        fold(body)
+    else:
+        pl.when(once)(functools.partial(
+            fold, functools.partial(body, carried=False)))
+        pl.when(jnp.logical_not(once))(functools.partial(fold, body))
+
+    if carried:
+        @pl.when((ik == nk - 1) & jnp.logical_not(once))
+        def _finish():
+            leave(slice(None),
+                  [state(p, slice(None)) for p in range(pack)])
 
 
 # ----------------------------------------------------------------------
@@ -963,12 +1120,19 @@ def _fwd_call(arrays, q_off, k_off, causal, bq, bk, interpret, pack,
         nk = band_steps(t, bq, bk, window)[0]
         static.update(sq=bq, sk=bk, window=window)
         name += "_win"
+    form = forward_form(tk, bq, bk, d // pack, window)
+    scratch = []
+    if form.state == "carried":
+        # m and l lane-replicated, a tile per sub-head, and the f32
+        # accumulator
+        stat = pltpu.VMEM((pack, bq, _STAT_LANES), jnp.float32)
+        scratch = [stat, stat, pltpu.VMEM((bq, d), jnp.float32)]
     k_at = _k_side(h // h_kv, window, tk, bq, bk)
     off_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     lanes = pack * _LANES
     out_shape = (b, h, t, d) if cols is None else (b, t, h * d)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, **static),
+        functools.partial(_fwd_kernel, form=form, **static),
         grid=(b, h, nq, nk),        # q rows follow axis 2, k rows axis 3
         in_specs=[off_spec, off_spec, _tile(bq, d, cq, _first),
                   _tile(bk, d, ck, k_at), _tile(bk, d, cv, k_at)],
@@ -976,9 +1140,7 @@ def _fwd_call(arrays, q_off, k_off, causal, bq, bk, interpret, pack,
                    _tile(bq, lanes, None, _first)),
         out_shape=(jax.ShapeDtypeStruct(out_shape, q.dtype),
                    jax.ShapeDtypeStruct((b, h, t, lanes), jnp.float32)),
-        scratch_shapes=[pltpu.VMEM((bq, _STAT_LANES), jnp.float32),
-                        pltpu.VMEM((bq, _STAT_LANES), jnp.float32),
-                        pltpu.VMEM((bq, d), jnp.float32)],
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
