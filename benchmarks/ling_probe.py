@@ -1,0 +1,234 @@
+"""The two kernel families Ling-3.0-flash brought (PR 37), alone at
+the cell's shapes: the delta rule with a decay per key channel
+(``znicz_kda_chunk_*`` / ``znicz_kda_state_*``, 32 heads of 128 × 128,
+T 4,096) and the two-width flash calls of the latent attention
+(``znicz_flash_*_mla``, 32 heads, keys 128 + 64 shared, values 128).
+
+    chiprun -- python3 benchmarks/ling_probe.py          # check + time
+    python3 benchmarks/ling_probe.py --compile-only      # here: the
+        real Mosaic / XLA-TPU compile for a described v5e, no chip
+    python3 benchmarks/ling_probe.py --compile-step [--t 8192]   # here:
+        the CELL's whole step program compiled for a described v5e
+        (≈ 4 minutes: 885 M parameters drawn on the host) — the
+        compiler's memory analysis, or its refusal; a compile is not a
+        run
+
+On the chip each family is checked in f32 against its ``jax.numpy``
+form at a length that form can hold, then timed in bf16 forward and
+forward + backward at T 4,096.  One JSON line per arm.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+import jax                                                  # noqa: E402
+import jax.numpy as jnp                                     # noqa: E402
+
+from znicz_tpu.ops import pallas_delta as pd                # noqa: E402
+from znicz_tpu.ops import pallas_mla                        # noqa: E402
+
+H, DK, DV, ROPE = 32, 128, 128, 64
+BF16 = jnp.dtype(jnp.bfloat16)
+
+
+def emit(**line) -> None:
+    print(json.dumps(line), flush=True)
+
+
+def delta_inputs(t: int, heads: int = H):
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    q = jax.random.normal(keys[0], (1, t, heads, DK), jnp.float32)
+    k = jax.random.normal(keys[1], (1, t, heads, DK), jnp.float32)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * DK ** -0.5
+    v = jax.random.normal(keys[2], (1, t, heads, DV), jnp.float32)
+    log_alpha = -5.0 * jax.random.uniform(keys[3], (1, t, heads, DK))
+    beta = jax.random.uniform(keys[4], (1, t, heads))
+    weight = jax.random.normal(keys[5], (1, t, heads, DV), jnp.float32)
+    return (q, k, v, log_alpha, beta), weight
+
+
+def mla_inputs(t: int, dtype, heads: int = H):
+    keys = jax.random.split(jax.random.PRNGKey(1), 6)
+
+    def draw(key, width):
+        return (0.3 * jax.random.normal(key, (1, t, width),
+                                        jnp.float32)).astype(dtype)
+    rows = (draw(keys[0], heads * DK), draw(keys[1], heads * ROPE),
+            draw(keys[2], heads * DK), draw(keys[3], ROPE),
+            draw(keys[4], heads * DV))
+    return rows, draw(keys[5], heads * DV)
+
+
+def mla_plain(qn, qr, kn, kr, v):
+    b, t, _ = qn.shape
+    h = qn.shape[-1] // DK
+    q = jnp.concatenate([qn.reshape(b, t, h, DK),
+                         qr.reshape(b, t, h, ROPE)], -1)
+    k = jnp.concatenate([kn.reshape(b, t, h, DK), jnp.broadcast_to(
+        kr[:, :, None, :], (b, t, h, ROPE))], -1)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest")
+    seen = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+    p = jax.nn.softmax(jnp.where(seen, s, -1e30), -1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v.reshape(b, t, h, DV),
+                      precision="highest").reshape(b, t, h * DV)
+
+
+def programs(rule):
+    forward = jax.jit(rule)
+
+    def both(*args):
+        *rows, weight = args
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(rule(*a).astype(jnp.float32) * weight),
+            tuple(range(len(rows))))(*rows)
+    return forward, jax.jit(both)
+
+
+def timed(fn, *args, runs: int = 5) -> float:
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(runs):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / runs * 1e3
+
+
+def worst(got, want) -> float:
+    return max(float(jnp.abs(g.astype(jnp.float32) - w).max()
+                     / (jnp.abs(w).max() + 1e-30))
+               for g, w in zip(jax.tree.leaves(got),
+                               jax.tree.leaves(want)))
+
+
+def kda(dot_dtype):
+    return lambda *a: pd.gated_delta_rule(*a, kernel=True,
+                                          dot_dtype=dot_dtype)
+
+
+def compile_step(t: int) -> int:
+    """The cell's step program at sequence length ``t``, compiled for a
+    described v5e: what the compiler says it needs."""
+    import numpy as np
+    from benchmarks.ouro_probe import compile_for_described_chip
+    from znbench.harness import discovery
+    from znbench.harness.program import engine_options, layer_table
+    from znicz_tpu.backends import XLADevice
+    from znicz_tpu.loader.fullbatch import ArrayLoader
+    from znicz_tpu.models.standard_workflow import StandardWorkflow
+    from znicz_tpu.utils import prng
+    from znicz_tpu.utils.config import root
+
+    cell = discovery.find_cell("ling_train_1of64")
+    config, traffic = cell.config, cell.traffic
+    root.common.precision_type = config["precision"]["precision_type"]
+    prng.seed_all(0)
+    steps = int(traffic["steps_per_epoch"])
+    ids = np.random.default_rng(0).integers(
+        0, config["input"]["vocab"], size=(steps, t + 1))
+    options = dict(config["precision"].get("engine", {}),
+                   **traffic.get("engine", {}))
+    # the kernels' set-up without a chip; Mosaic compiles them below
+    options.update(pallas_interpret=True, flash_attention=True,
+                   moe_grouped_matmul=True, delta_scan_kernel=True)
+    with engine_options(options):
+        wf = StandardWorkflow(
+            name=config["workflow"]["name"],
+            loader_factory=lambda w: ArrayLoader(
+                w, train_data=ids[:, :-1].astype(np.float32),
+                train_labels=ids[:, 1:].astype(np.int32),
+                minibatch_size=1),
+            layers=layer_table(config), decision_config={"max_epochs": 1})
+        wf._max_fires = 10 ** 9
+        wf.initialize(device=XLADevice())
+        for unit in wf.forwards:     # through Mosaic, not the interpreter
+            for flag in ("_interpret", "_gmm_interpret"):
+                if getattr(unit, flag, False):
+                    setattr(unit, flag, False)
+        line = {"t": t}
+        try:
+            line.update(compile_for_described_chip(wf), loads=True)
+        except Exception as exc:  # noqa: BLE001 — the refusal is the result
+            said = str(exc)
+            at = said.find("RESOURCE_EXHAUSTED")
+            line.update(loads=False, refusal=(
+                said[at:at + 300] if at >= 0
+                else f"{type(exc).__name__}: {said[:300]}"))
+    emit(**line)
+    return 0 if line["loads"] else 1
+
+
+def main() -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    parser.add_argument("--compile-only", action="store_true")
+    parser.add_argument("--compile-step", action="store_true")
+    parser.add_argument("--t", type=int, default=4096)
+    args = parser.parse_args()
+    if args.compile_step:
+        os.environ.setdefault("TPU_LOG_DIR", "disabled")
+        return compile_step(args.t)
+    if args.compile_only:
+        os.environ.setdefault("TPU_LOG_DIR", "disabled")
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+        jax.config.update("jax_enable_compilation_cache", False)
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+        one = SingleDeviceSharding(topo.devices[0])
+
+        def struct(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+        with jax.default_device(jax.devices("cpu")[0]):
+            rows, weight = jax.eval_shape(lambda: delta_inputs(args.t))
+            mla_rows, mla_weight = jax.eval_shape(
+                lambda: mla_inputs(args.t, BF16))
+        for name, rule, shaped in (
+                ("kda", kda(BF16), (rows, weight)),
+                ("mla", pallas_mla.latent_flash_attention,
+                 (mla_rows, mla_weight))):
+            forward, both = programs(rule)
+            rows_, weight_ = jax.tree.map(struct, shaped)
+            t0 = time.perf_counter()
+            forward.lower(*rows_).compile()
+            compiled = both.lower(*rows_, weight_).compile()
+            emit(family=name, t=args.t, compiled_s=time.perf_counter() - t0,
+                 temp_gb=compiled.memory_analysis().temp_size_in_bytes
+                 / 1e9)
+        return 0
+
+    # f32 against jax.numpy, at a length the plain forms can hold
+    small, heads = 512, 4
+    rows, weight = delta_inputs(small, heads)
+    with jax.default_matmul_precision("highest"):
+        want = programs(lambda *a: pd.gated_delta_rule(*a))[1](
+            *rows, weight)
+    got = programs(kda(None))[1](*rows, weight)
+    emit(family="kda", check_t=small, f32_against_jax_numpy=worst(
+        got, want))
+    rows, weight = mla_inputs(1024, jnp.float32, heads)
+    with jax.default_matmul_precision("highest"):
+        want = programs(mla_plain)[1](*rows, weight)
+        got = programs(pallas_mla.latent_flash_attention)[1](*rows, weight)
+    emit(family="mla", check_t=1024, f32_against_plain=worst(got, want))
+    # bf16, the cell's shapes
+    rows, weight = delta_inputs(args.t)
+    forward, both = programs(kda(BF16))
+    emit(family="kda", t=args.t, forward_ms=timed(forward, *rows),
+         forward_backward_ms=timed(both, *rows, weight))
+    rows, weight = mla_inputs(args.t, BF16)
+    forward, both = programs(pallas_mla.latent_flash_attention)
+    emit(family="mla", t=args.t, forward_ms=timed(forward, *rows),
+         forward_backward_ms=timed(both, *rows, weight))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -150,6 +150,7 @@ def compile_for_described_chip(wf) -> dict:
     import numpy as np
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
+    from znicz_tpu.accelerated_units import JitRegion
     for unit in wf.forwards:     # through Mosaic, not the interpreter
         if getattr(unit, "_flash_pallas", False):
             unit._flash_interpret = False
@@ -170,8 +171,9 @@ def compile_for_described_chip(wf) -> dict:
         int(np.prod(s.shape)) * s.dtype.itemsize for s in structs) / 1e9,
         3)}
     t0 = time.perf_counter()
-    compiled = jax.jit(body, donate_argnums=tuple(
-        range(len(structs)))).lower(*structs).compile()
+    # as the region jits it (``engine.keep_written_leaves`` included)
+    compiled = JitRegion._jit(body, True, len(structs)).lower(
+        *structs).compile()
     stats = compiled.memory_analysis()
     text = compiled.as_text()
     out.update(

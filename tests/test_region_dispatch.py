@@ -163,3 +163,83 @@ def test_store_is_keyed_on_the_variant_tag_and_reloads_it(
         assert len(fresh._cache) == 1
     finally:
         aot_cache._caches.clear()
+
+
+# ----------------------------------------------------------------------
+# engine.keep_written_leaves (PR 37): the leaves a step only writes
+# stay in the program's signature and lend their buffers to their
+# successors
+# ----------------------------------------------------------------------
+def _trained(name: str, keep: bool, store: str | bool = False):
+    """A toy after three hand-driven steps, its step program's compiled
+    text and its weights."""
+    import numpy as np
+
+    root.common.engine.keep_written_leaves = keep
+    toy = Toy(name, "step", store=store)
+    leaves = [vec._devmem for vec in toy.region._collect_vectors()]
+    structs = [(leaf.shape, leaf.dtype) for leaf in leaves]
+    for _ in range(3):
+        toy.call()
+    # a re-trace leaves tracers in the Vectors: they are put back
+    held = [(vec, vec._devmem) for vec in toy.region._vectors]
+    try:
+        text = JitRegion._jit(
+            toy.region.build_callable(
+                tuple(bool(u.gate_skip) for u in toy.region.units)),
+            True, len(structs)).lower(*[
+                np.zeros(shape, dtype)
+                for shape, dtype in structs]).as_text()
+    finally:
+        for vec, leaf in held:
+            vec._devmem = leaf
+    weights = []
+    for unit in toy.wf.forwards:
+        unit.weights.map_read()
+        weights.append(np.array(unit.weights.mem))
+    return toy, len(structs), text, weights
+
+
+@pytest.mark.parametrize("keep", [False, True], ids=["pruned", "kept"])
+def test_a_leaf_the_step_only_writes_is_a_parameter_only_when_kept(keep):
+    """Off (the default) jit drops the write-only leaves from the
+    program; on, every leaf is a parameter and every one is aliased to
+    an output — the old buffer IS the new one."""
+    import re
+    _, n_leaves, text, _ = _trained(f"kept_{keep}", keep)
+    (signature,) = re.findall(r"func\.func public @main\((.*?)\) ->",
+                              text, re.S)
+    params = signature.count("%arg")
+    aliased = signature.count("tf.aliasing_output")
+    if keep:
+        assert params == n_leaves and aliased == n_leaves
+    else:
+        assert aliased == params < n_leaves
+
+
+def test_kept_leaves_train_the_same_weights():
+    import numpy as np
+    from znicz_tpu.utils import prng
+    _, _, _, pruned = _trained("kept_same_a", False)
+    prng.seed_all(1234)
+    _, _, _, kept = _trained("kept_same_b", True)
+    for a, b in zip(pruned, kept):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_the_store_keys_a_kept_program_apart(tmp_path, monkeypatch):
+    """Same body, another signature: a program stored without the
+    option is not the one loaded with it."""
+    monkeypatch.delenv("ZNICZ_AOT_CACHE", raising=False)
+    aot_cache._caches.clear()
+    try:
+        store = str(tmp_path / "store")
+        _trained("kept_store", False, store=store)
+        one = {key for key, _ in aot_cache.active_cache().entries()}
+        from znicz_tpu.utils import prng
+        prng.seed_all(1234)
+        _trained("kept_store", True, store=store)
+        two = {key for key, _ in aot_cache.active_cache().entries()}
+        assert len(one) == 1 and len(two) == 2 and one < two
+    finally:
+        aot_cache._caches.clear()

@@ -333,6 +333,29 @@ class JitRegion(Logger):
         from znicz_tpu.utils.config import root
         return bool(root.common.engine.get("debug_checks", False))
 
+    @staticmethod
+    def _jit(body, donate: bool, n_leaves: int):
+        """``jax.jit`` of a region body over its leaves.
+
+        ``root.common.engine.keep_written_leaves`` (default off) keeps
+        in the program's signature the leaves a step only WRITES (unit
+        outputs, errors): jit drops an argument the body never reads,
+        donated or not, so the old value's buffer outlives the dispatch
+        beside the new value's — and the compiler plans its temporaries
+        against memory that is not free (a program that compiles and
+        does not load: PERF.md §6, PR 35 and PR 37).  Kept, a donated
+        leaf lends its buffer to its successor, and the compiler's
+        count of the chip is the runtime's."""
+        return jax.jit(
+            body, donate_argnums=tuple(range(n_leaves)) if donate else (),
+            keep_unused=JitRegion._keeps_written(donate))
+
+    @staticmethod
+    def _keeps_written(donate: bool) -> bool:
+        from znicz_tpu.utils.config import root
+        return donate and bool(
+            root.common.engine.get("keep_written_leaves", False))
+
     def _dispatch(self, variant: tuple, build, span: str,
                   count: int = 1, donate: bool = True,
                   **span_args) -> None:
@@ -433,9 +456,8 @@ class JitRegion(Logger):
                 _metrics.xla_compiles(f"region:{self.name}").inc()
                 with _tracing.TRACER.span(f"compile:{self.name}",
                                           cat="compile", **span_args):
-                    fn = self._cache[key] = jitted = jax.jit(
-                        body, donate_argnums=(
-                            tuple(range(len(leaves))) if donate else ()))
+                    fn = self._cache[key] = jitted = self._jit(
+                        body, donate, len(leaves))
                     out = fn(*leaves)
             self._remember(body, jitted, structs, donate)
         else:
@@ -473,9 +495,8 @@ class JitRegion(Logger):
             held = [] if region is None else \
                 [(vec, vec._devmem) for vec in region._vectors]
             try:
-                fn = jitted if jitted is not None else jax.jit(
-                    body, donate_argnums=(
-                        tuple(range(len(structs))) if donate else ()))
+                fn = jitted if jitted is not None else JitRegion._jit(
+                    body, donate, len(structs))
                 return fn.lower(*structs).compile().as_text()
             finally:
                 for vec, leaf in held:
@@ -787,8 +808,12 @@ class JitRegion(Logger):
         if cache is None:
             return None
         site = f"region:{self.name}"
+        # another program under ``engine.keep_written_leaves``: more
+        # parameters, each aliased to an output
+        kept = ("keep_written_leaves",) \
+            if self._keeps_written(donate) else ()
         key = _aot.jaxpr_key(fn, leaves,
-                             extra=(site, donate) + tuple(variant))
+                             extra=(site, donate) + tuple(variant) + kept)
         if key is None:
             return None
         donate_argnums = tuple(range(len(leaves))) if donate else ()
@@ -799,15 +824,17 @@ class JitRegion(Logger):
             _metrics.xla_compiles(site).inc()
             with _tracing.TRACER.span(f"compile:{self.name}",
                                       cat="compile"):
-                prog = jax.jit(fn, donate_argnums=donate_argnums).lower(
+                prog = self._jit(fn, donate, len(leaves)).lower(
                     *leaves).compile()
             cache.put(key, prog, site,
                       meta={"family": site,
                             "variant": [str(v) for v in variant[:2]]})
-        return self._respecialize_guard(prog, fn, donate_argnums, site)
+        return self._respecialize_guard(prog, fn, donate_argnums, site,
+                                        keep_unused=bool(kept))
 
     @staticmethod
-    def _respecialize_guard(prog, fn, donate_argnums, site):
+    def _respecialize_guard(prog, fn, donate_argnums, site,
+                            keep_unused: bool = False):
         """An AOT ``Compiled`` is pinned to the exact input shardings
         and devices it was lowered with; lazy ``jax.jit`` transparently
         respecializes when they change between fires (on a mesh the
@@ -826,7 +853,8 @@ class JitRegion(Logger):
                 except ValueError:
                     _metrics.xla_compiles(site).inc()
                     fallback = jax.jit(fn,
-                                       donate_argnums=donate_argnums)
+                                       donate_argnums=donate_argnums,
+                                       keep_unused=keep_unused)
             return fallback(*leaves)
 
         return call

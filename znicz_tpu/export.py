@@ -243,20 +243,35 @@ def refuse_unserved(forwards, what: str) -> None:
                 f"serving runs no feed-forward sublayer yet (ROADMAP R1, "
                 f"serving half)")
         if kind == "GatedDeltaNet":
+            channel = getattr(unit, "decay", "head") == "channel"
             raise NotImplementedError(
                 f"{what}: layer {i} is a gated-delta-rule linear-"
-                f"attention layer (gated_delta_net); serving has no "
+                f"attention layer (gated_delta_net"
+                f"{', decay=channel: a decay per key channel' if channel else ''}"
+                f"); serving has no "
                 f"state slot yet — the recurrent state and the "
                 f"convolution's tail exist on the training path only "
                 f"(ROADMAP R6, serving half)")
         if kind == "MoE":
+            choice = [name for name, on in (
+                ("select_bias", getattr(unit, "select_bias_on", False)),
+                ("groups", getattr(unit, "groups", None))) if on]
             raise NotImplementedError(
-                f"{what}: layer {i} is a sparse-expert layer (moe); "
-                f"serving has no expert dispatch yet — router, top-k "
+                f"{what}: layer {i} is a sparse-expert layer (moe"
+                f"{', ' + ', '.join(choice) if choice else ''}); "
+                f"serving has no expert dispatch yet — router, top-k"
+                f"{', the selection bias and the group limit' if choice else ''} "
                 f"and grouped matmul exist on the training path only "
                 f"(ROADMAP R1, serving half)")
         if kind != "MultiHeadAttention":
             continue
+        if getattr(unit, "kv_latent", None) is not None:
+            raise NotImplementedError(
+                f"{what}: attention layer {i} sets kv_latent (a latent "
+                f"K/V with qk_nope, qk_rope, v_head_dim); serving has "
+                f"no latent page and no absorbed projections yet — the "
+                f"prefill / decode steps cache whole keys and values "
+                f"(ROADMAP R5, serving half)")
         used = [name for name in _BLOCK_OPTIONS
                 if getattr(unit, name, None)]
         if getattr(unit, "n_kv_heads", unit.n_heads) != unit.n_heads:

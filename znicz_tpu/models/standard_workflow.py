@@ -98,6 +98,11 @@ for _name, _cls in {
     "depooling": depooling.Depooling,
     "lstm": lstm.LSTM,
     "attention": attention.MultiHeadAttention,
+    # the same unit under the name of what ``kv_latent`` makes it, so
+    # that a table says what it holds and a program without the
+    # mechanism refuses it by its first unknown NAME (units take unknown
+    # options silently: PR 37 found the parent training another model)
+    "latent_attention": attention.MultiHeadAttention,
     "to_sequence": seq_reshape.ToSequence,
     "last_token": seq_reshape.LastToken,
     "pos_encoding": pos_encoding.PositionalEncoding,

@@ -692,7 +692,11 @@ def delta_scan(unit: str, stat: str) -> Gauge:
     ``path``: 1 the ``znicz_delta_state_*`` kernels, 0 the plain
     scan; ``chunk_path``: 1 the ``znicz_gdr_chunk_*`` kernels for what
     is local to a chunk — Γ, the triangular inverse, W, U, K̂, Qc, P —
-    0 ``jax.numpy`` under autodiff).  Static per program, set once at
+    0 ``jax.numpy`` under autodiff; ``decay_channels``: decays a head,
+    1 or d_k — with d_k the kernels are ``znicz_kda_chunk_*`` /
+    ``znicz_kda_state_*``; ``sub_block``: positions whose decays are
+    exponentiated against one reference point, the chunk itself under
+    a decay per head).  Static per program, set once at
     ``initialize``."""
     return REGISTRY.gauge(
         "znicz_delta_scan",
@@ -700,6 +704,33 @@ def delta_scan(unit: str, stat: str) -> Gauge:
         "chunks walked, state size, the kernels' tile padding, MB of "
         "states kept for the backward, kernels (1) or plain scan (0) "
         "for the walk and for what is local to a chunk",
+        labels=("unit", "stat")).labels(unit=unit, stat=stat)
+
+
+def moe_router(unit: str, stat: str) -> Gauge:
+    """The choice of a ``MoE`` unit with ``select_bias`` (``stat`` =
+    ``groups_kept``: groups a token's top k are taken among, 0 without
+    a group limit — static; ``bias_abs_max``: the largest |b_e| of the
+    selection bias; ``bias_steps``: steps in which the step program's
+    own rule moved b — it is gated by the anomaly guard — since the
+    start).  The last two are read with the unit's epoch-end reads."""
+    return REGISTRY.gauge(
+        "znicz_moe_router",
+        "Group limit, selection-bias extreme and the steps its rule "
+        "ran, of a MoE layer that chooses by score + bias",
+        labels=("unit", "stat")).labels(unit=unit, stat=stat)
+
+
+def attention_latent(unit: str, stat: str) -> Gauge:
+    """The static sizes of a latent-K/V attention unit
+    (``MultiHeadAttention`` with ``kv_latent``; ``stat`` = ``latent``:
+    the compressed K/V's width; ``qk_nope`` / ``qk_rope``: a key's
+    per-head part and the rotary part all heads share; ``v``: a
+    value's width).  Set once at ``initialize``."""
+    return REGISTRY.gauge(
+        "znicz_attention_latent",
+        "Latent width, per-head and shared rotary key widths and value "
+        "width of a latent-K/V attention layer",
         labels=("unit", "stat")).labels(unit=unit, stat=stat)
 
 

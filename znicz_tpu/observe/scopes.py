@@ -13,7 +13,7 @@ of the COMPILED step program carries
     {program name: {HLO instruction name: {
         "unit": "GDMoE_2", "kind": "GDMoE", "family": "MoE",
         "phase": "forward" | "backward" | "update" | "fingerprint"
-                 | "pass_sum"}}}
+                 | "pass_sum" | "router_bias"}}}
 
 - ``kind`` is the unit's class, ``family`` the forward class a
   backward unit is paired with (a forward unit's own pairing class):
@@ -238,7 +238,8 @@ def attribute(text: str, units: tuple) -> dict:
         inside ``update``)."""
         found = scope_of(op_name, names) if op_name else None
         return found and found + (
-            found[1] and "/pass_sum/" in f"/{op_name}/",)
+            found[1] and "/pass_sum/" in f"/{op_name}/",
+            "/router_bias/" in f"/{op_name}/")
 
     def scopes_in(computation: str) -> frozenset:
         """Scopes of every instruction in a computation and in what
@@ -282,12 +283,14 @@ def _entry(scopes: set, units: tuple) -> dict:
     parts = []
     for index in sorted(by_unit):
         name, kind, family, backward = units[index]
-        if all(fingerprint for _u, fingerprint, _s in by_unit[index]):
+        if all(fingerprint for _u, fingerprint, _s, _r in by_unit[index]):
             phase = "fingerprint"
-        elif all(pass_sum for _u, _f, pass_sum in by_unit[index]):
+        elif all(pass_sum for _u, _f, pass_sum, _r in by_unit[index]):
             phase = "pass_sum"
-        elif all(update for update, _f, _s in by_unit[index]):
+        elif all(update for update, _f, _s, _r in by_unit[index]):
             phase = "update"
+        elif all(bias for _u, _f, _s, bias in by_unit[index]):
+            phase = "router_bias"
         else:
             phase = "backward" if backward else "forward"
         parts.append({"unit": name, "kind": kind, "family": family,

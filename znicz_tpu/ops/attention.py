@@ -59,6 +59,38 @@ flash kernels take the group and the window (``pallas_attention``); a
 shape they cannot tile takes the plain core WITH the band mask; the
 ring and the scan-blocked core refuse them at ``initialize``.
 
+A latent K/V (multi-head latent attention, arXiv:2405.04434 §2.1
+without the query latent; Ling-3.0-flash's full layers, PR 37) is one
+more set of per-unit options: ``kv_latent`` (the compressed K/V's
+width, with its own RMSNorm, gain ``gain_latent``), ``qk_nope`` (a
+key's per-head width, up-projected from the latent), ``qk_rope`` (the
+rotary width: ONE key of that width is shared by all heads) and
+``v_head_dim``:
+
+.. code-block:: text
+
+    [q_nope | q_rope | c | k_r] = m W        ``weights`` (D, H·nope + H·rope
+                                             + latent + rope): all heads'
+                                             q_nope, all heads' q_rope, c, k_r
+    c = RMSNorm(c) ;  [k_nope | v] = c W_up  ``weights_kv_up`` (latent,
+                                             H·nope + H·v): all heads'
+                                             k_nope, then all heads' v
+    q_rope, k_r rotated (``rope``, over those ``qk_rope`` only)
+    s_h = (q_nope,h·k_nope,h + q_rope,h·k_r) / √(nope + rope), causal
+    o_h = softmax(s_h) v_h ;  y = concat_h(σ((m W_gate)_h) o_h) W_out
+
+The column order of ``weights`` (parts side by side, not per head) is a
+fixed permutation of the published one; the rotation is this module's
+half-split convention, which differs from an interleaved one by a
+fixed permutation of the rotary columns of W on BOTH sides of the
+product, so the scores are the same
+(``tests/test_ling_reference.py``).  On a TPU the core is
+``ops/pallas_mla.py`` — keys of two widths, values not padded, nothing
+of shape (B, T, H, nope + rope) written; elsewhere, and for shapes
+those kernels do not tile, the plain core with K assembled in full.
+``head_layout``'s head-major fallback for dh 192 is never reached by
+such a layer.
+
 Backward (``GDMultiHeadAttention``): ``jax.vjp`` of the forward on
 the XLA path — this differentiates THROUGH the shard_map/ppermute
 ring, so sequence-parallel training needs no hand-written collective
@@ -207,12 +239,45 @@ def _local_attention_np(q, k, v, causal: bool, window=None):
     return o, p
 
 
+def latent_attention_plain(q_nope, q_rope, k_nope, k_rope, v,
+                           n_heads: int, xp=jnp):
+    """The plain core of a latent-K/V layer, causal: rows as the
+    kernels take them (q scaled already), K assembled in full — the
+    shared rotary key repeated for every head — and the (T, T) scores
+    in memory."""
+    b, t, _ = q_nope.shape
+    h = n_heads
+
+    def heads(a):
+        return a.reshape(b, t, h, -1)
+
+    q = xp.concatenate([heads(q_nope), heads(q_rope)], axis=-1)
+    k = xp.concatenate(
+        [heads(k_nope), xp.broadcast_to(
+            k_rope[:, :, None, :], (b, t, h, k_rope.shape[-1]))], axis=-1)
+    if xp is jnp:
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                       preferred_element_type=jnp.float32)
+    else:
+        s = np.einsum("bqhd,bkhd->bhqk", q, k)
+    s = xp.where(band_mask(xp, t, t)[None, None], s, -1e30)
+    s = s - s.max(axis=-1, keepdims=True)
+    p = xp.exp(s)
+    p = p / p.sum(axis=-1, keepdims=True)
+    if xp is jnp:
+        o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), heads(v),
+                       preferred_element_type=jnp.float32)
+    else:
+        o = np.einsum("bhqk,bkhd->bqhd", p, heads(v))
+    return o.reshape(b, t, -1)
+
+
 class MultiHeadAttention(Forward):
     """Weighted multi-head self-attention layer."""
 
     EXPORT_PARAMS = ("weights", "bias", "weights_out", "bias_out",
                      "gain_norm", "gain_q", "gain_k", "weights_head_gate",
-                     "gain_post")
+                     "gain_post", "weights_kv_up", "gain_latent")
     #: may be a member of a looped span (``znicz_tpu.pass_span``): the
     #: backward needs the forward's input and pullback only
     PASS_SAFE = True
@@ -227,6 +292,8 @@ class MultiHeadAttention(Forward):
                  head_dim: int | None = None,
                  window: int | None = None, head_gate: bool = False,
                  post_norm: str | None = None,
+                 kv_latent: int | None = None, qk_nope: int = 0,
+                 qk_rope: int = 0, v_head_dim: int = 0,
                  name=None, **kwargs) -> None:
         # attention defaults to fan-scaled init (the reference's
         # fixed-stddev fillings predate attention entirely)
@@ -286,6 +353,32 @@ class MultiHeadAttention(Forward):
             raise ValueError(f"window {window} needs causal=True and "
                              f"≥ 1 position")
         self.head_gate = bool(head_gate)
+        #: a latent K/V (module docstring): None = the layer as it was
+        self.kv_latent = None if kv_latent is None else int(kv_latent)
+        self.qk_nope, self.qk_rope = int(qk_nope), int(qk_rope)
+        self.v_head_dim = int(v_head_dim)
+        if self.kv_latent is not None:
+            if not (causal and rope and self.qk_nope > 0
+                    and self.qk_rope > 0 and self.qk_rope % 2 == 0
+                    and self.v_head_dim > 0 and self.kv_latent > 0):
+                raise ValueError(
+                    f"kv_latent {kv_latent} needs causal=True, rope and "
+                    f"qk_nope, qk_rope (even), v_head_dim > 0")
+            refused = [name for name, on in (
+                ("seq_parallel", seq_parallel),
+                ("flash_block_k", flash_block_k), ("qk_norm", qk_norm),
+                ("n_kv_heads", n_kv_heads), ("head_dim", head_dim),
+                ("window", window), ("post_norm", post_norm),
+                ("rope.rotary_dim", rope.get("rotary_dim")),
+                ("rope.yarn", rope.get("yarn")),
+                ("include_bias", kwargs.get("include_bias", True)))
+                if on]
+            if refused:
+                raise ValueError(
+                    f"kv_latent does not combine with "
+                    f"{', '.join(refused)}")
+        self.weights_kv_up = Vector(name=f"{self.name}.weights_kv_up")
+        self.gain_latent = Vector(name=f"{self.name}.gain_latent")
         self.weights_head_gate = Vector(
             name=f"{self.name}.weights_head_gate")
         self.gain_norm = Vector(name=f"{self.name}.gain_norm")
@@ -307,6 +400,8 @@ class MultiHeadAttention(Forward):
             raise ValueError(f"{self}: expected (batch, time, features) "
                              f"input, got {self.input.shape}")
         b, t, d = self.input.shape
+        if self.kv_latent is not None:
+            return self._initialize_latent(b, t, d)
         if self.head_dim is None and d % self.n_heads:
             raise ValueError(f"{self}: features {d} not divisible by "
                              f"{self.n_heads} heads")
@@ -569,6 +664,106 @@ class MultiHeadAttention(Forward):
                           self.gain_norm, self.gain_q, self.gain_k,
                           self.weights_head_gate, self.gain_post)
 
+    def _initialize_latent(self, b: int, t: int, d: int) -> None:
+        """``initialize`` of a layer with a latent K/V (module
+        docstring): its parameters, and the core — the two-width flash
+        kernels where they tile the call, else the plain core."""
+        h, nope, rope = self.n_heads, self.qk_nope, self.qk_rope
+        latent, dv = self.kv_latent, self.v_head_dim
+        for vec, shape in (
+                (self.weights, (d, h * (nope + rope) + latent + rope)),
+                (self.weights_kv_up, (latent, h * (nope + dv))),
+                (self.weights_out, (h * dv, d)),
+                (self.weights_head_gate, (d, h if self.head_gate else 0))):
+            if all(shape) and not vec:
+                vec.reset(self.fill_array(shape, self.weights_filling,
+                                          self.weights_stddev,
+                                          fan_in=shape[0]))
+        if not self.gain_latent:
+            self.gain_latent.reset(np.ones(latent, np.float32))
+        if self.pre_norm and not self.gain_norm:
+            self.gain_norm.reset(np.ones(d, np.float32))
+        self.output.reset(np.zeros((b, t, d),
+                                   dtype=self.output_store_dtype))
+        from znicz_tpu.observe import metrics as obs_metrics
+        from znicz_tpu.ops import pallas_kernels, pallas_mla
+        from znicz_tpu.parallel import partition
+        from znicz_tpu.utils.config import root
+        self.partition_leaf("output", partition.BATCH)
+        for attr in ("weights_kv_up", "gain_latent"):
+            self.partition_leaf(attr, partition.REPLICATED)
+        self._ring_active = False
+        interpret = bool(root.common.engine.get("pallas_interpret",
+                                                False))
+        refused = pallas_kernels.kernel_refusal(
+            self.device, "flash_attention", interpret)
+        mesh = getattr(self.device, "mesh", None)
+        if refused is None and mesh is not None and mesh.size > 1:
+            refused = (f"a mesh of {mesh.size} devices: the two-width "
+                       f"kernels have no sharding rule")
+        if refused is None and not pallas_mla.kernel_legal(
+                t, h, nope, rope, dv):
+            refused = (f"T={t}, {h} heads of {nope} + {rope} / {dv} do "
+                       f"not tile (128 + 64 / 128, an even head count, "
+                       f"T whole tiles)")
+        self._flash_pallas = refused is None
+        self._flash_interpret = interpret
+        for stat, value in (("latent", latent), ("qk_nope", nope),
+                            ("qk_rope", rope), ("v", dv)):
+            obs_metrics.attention_latent(self.name, stat).set(value)
+        self.info("%s: latent K/V of %d (+ %d shared rotary), %d heads, "
+                  "keys %d + %d, values %d: %s", self.name, latent, rope,
+                  h, nope, rope, dv,
+                  "znicz_flash_fwd_mla / znicz_flash_bwd_mla_dq / _dkv "
+                  "kernels, tiles of %d%s" % (
+                      min(pallas_mla.BLOCK, t),
+                      ", INTERPRETED" if interpret else "")
+                  if self._flash_pallas
+                  else f"plain core, K assembled in full ({refused})")
+        self.init_vectors(self.input, self.output, self.weights,
+                          self.weights_out, self.gain_norm,
+                          self.weights_head_gate, self.weights_kv_up,
+                          self.gain_latent)
+
+    def _latent_forward(self, x, w, w_out, g_norm, w_gate, w_up,
+                        g_latent):
+        b, t, d = x.shape
+        h, nope, rope = self.n_heads, self.qk_nope, self.qk_rope
+        latent, dv = self.kv_latent, self.v_head_dim
+        x32 = x.astype(jnp.float32)
+        m = x32 if g_norm is None \
+            else rms_norm(jnp, x32, g_norm, self.norm_eps)
+        rows = m.reshape(b * t, d)
+        proj = self.mxu_dot(jnp, rows, w).reshape(b, t, -1)
+        at = h * nope
+        c = rms_norm(jnp, proj[..., at + h * rope:at + h * rope + latent],
+                     g_latent, self.norm_eps).reshape(b * t, latent)
+        cos, sin = rope_tables(jnp, t, rope, self.rope_theta)
+        scale = (nope + rope) ** -0.5
+        q_nope = proj[..., :at] * scale
+        # the heads' rotary parts turn where they lie (PR 28's view)
+        q_rope = apply_rope_rows(
+            jnp, proj[..., at:at + h * rope], cos, sin, h) * scale
+        k_rope = apply_rope(jnp, proj[..., None, -rope:], cos,
+                            sin).reshape(b, t, rope)
+        # two products over the up-projection's column ranges, so that
+        # neither k_nope ‖ v nor its cotangent is ever cut or joined at
+        # activation size
+        k_nope = self.mxu_dot(jnp, c, w_up[:, :at]).reshape(b, t, at)
+        v = self.mxu_dot(jnp, c, w_up[:, at:]).reshape(b, t, h * dv)
+        arrays = (q_nope, q_rope, k_nope, k_rope, v)
+        if self.mxu_dtype is not None:
+            arrays = tuple(a.astype(self.mxu_dtype) for a in arrays)
+        if getattr(self, "_flash_pallas", False):
+            from znicz_tpu.ops import pallas_mla
+            o = pallas_mla.latent_flash_attention(
+                *arrays, interpret=getattr(self, "_flash_interpret",
+                                           False))
+        else:
+            o = latent_attention_plain(*arrays, h)
+        return self._project_out(x32, m, o.astype(jnp.float32), w_out,
+                                 None, w_gate, None)
+
     @property
     def ring_active(self) -> bool:
         """True when THIS initialization actually rides the ring
@@ -587,8 +782,11 @@ class MultiHeadAttention(Forward):
                 self.bias_out.devmem if self.include_bias else None,
                 dev(self.gain_norm), dev(self.gain_q), dev(self.gain_k)) \
             + ((self.weights_head_gate.devmem,) if self.head_gate
-               else (None,) if self.gain_post else ()) \
-            + ((self.gain_post.devmem,) if self.gain_post else ())
+               else (None,) if self.gain_post or self.kv_latent else ()) \
+            + ((self.gain_post.devmem,) if self.gain_post
+               else (None,) if self.kv_latent else ()) \
+            + ((self.weights_kv_up.devmem, self.gain_latent.devmem)
+               if self.kv_latent else ())
 
     def _widths(self, d: int) -> tuple:
         """(q width, k/v width, head size) for a model width ``d``."""
@@ -627,7 +825,10 @@ class MultiHeadAttention(Forward):
 
     def xla_forward(self, x, w_qkv, b_qkv, w_out, b_out,
                     g_norm=None, g_q=None, g_k=None, w_gate=None,
-                    g_post=None):
+                    g_post=None, w_kv_up=None, g_latent=None):
+        if w_kv_up is not None:
+            return self._latent_forward(x, w_qkv, w_out, g_norm, w_gate,
+                                        w_kv_up, g_latent)
         b, t, d = x.shape
         wide = w_qkv.shape[1]
         grouped = self.n_kv_heads != self.n_heads
@@ -1036,6 +1237,8 @@ class MultiHeadAttention(Forward):
         (None without one), ``raw`` the out-projection's result BEFORE
         the ``post_norm`` (None without one)."""
         b, t, d = x.shape
+        if self.kv_latent is not None:
+            return self._latent_forward_np(x), None
         h = rms_norm(np, x, self.gain_norm.mem, self.norm_eps) \
             if self.pre_norm else x
         qkv = h.reshape(b * t, d) @ self.weights.mem
@@ -1061,6 +1264,37 @@ class MultiHeadAttention(Forward):
         if self.residual:
             y = x + y
         return y, (h, qkv, q, k, v, o, p, gate, raw)
+
+    def _latent_forward_np(self, x):
+        """:meth:`_latent_forward` in numpy, the plain core."""
+        b, t, d = x.shape
+        h, nope, rope = self.n_heads, self.qk_nope, self.qk_rope
+        latent, dv = self.kv_latent, self.v_head_dim
+        m = rms_norm(np, x, self.gain_norm.mem, self.norm_eps) \
+            if self.pre_norm else x
+        proj = (m.reshape(b * t, d) @ self.weights.mem).reshape(b, t, -1)
+        at = h * nope
+        c = rms_norm(np, proj[..., at + h * rope:at + h * rope + latent],
+                     self.gain_latent.mem, self.norm_eps)
+        cos, sin = rope_tables(np, t, rope, self.rope_theta)
+        scale = (nope + rope) ** -0.5
+        q_rope = apply_rope(
+            np, proj[..., at:at + h * rope].reshape(b, t, h, rope),
+            cos, sin).reshape(b, t, h * rope) * scale
+        k_rope = apply_rope(np, proj[..., None, -rope:], cos,
+                            sin).reshape(b, t, rope)
+        kv = c.reshape(b * t, latent) @ self.weights_kv_up.mem
+        o = latent_attention_plain(
+            proj[..., :at] * scale, q_rope, kv[:, :at].reshape(b, t, at),
+            k_rope, kv[:, at:].reshape(b, t, h * dv), h, xp=np)
+        if self.head_gate:
+            gate = 1.0 / (1.0 + np.exp(
+                -(m.reshape(b * t, d) @ self.weights_head_gate.mem)))
+            o = (o.reshape(b, t, h, dv)
+                 * gate.reshape(b, t, h, 1)).reshape(b, t, h * dv)
+        y = (o.reshape(b * t, h * dv) @ self.weights_out.mem).reshape(
+            b, t, d)
+        return x + y if self.residual else y
 
     def numpy_run(self) -> None:
         self.input.map_read()
@@ -1107,6 +1341,16 @@ class GDMultiHeadAttention(GradientDescentBase):
         # … and the output norm's gain beside a pre-norm
         self.accumulated_gradient_gain_post = Vector(
             name=f"{self.name}.acc_gain_post")
+        # … and a latent K/V's up-projection and norm gain
+        self.accumulated_gradient_weights_kv_up = Vector(
+            name=f"{self.name}.acc_gw_kv_up")
+        self.accumulated_gradient_gain_latent = Vector(
+            name=f"{self.name}.acc_gain_latent")
+        self._host_pullback = None
+
+    #: ``_gain_pairs``' suffixes, in the order ``forward_args`` hands
+    #: the gains (and the latent's two parameters) to the forward
+    _GAINS = ("norm", "q", "k", "head_gate", "post", "kv_up", "latent")
 
     def _gain_pairs(self) -> list:
         """``(suffix, parameter Vector, its accumulator)`` for the
@@ -1123,6 +1367,11 @@ class GDMultiHeadAttention(GradientDescentBase):
         if fwd.gain_post:
             pairs.append(("post", fwd.gain_post,
                           self.accumulated_gradient_gain_post))
+        if fwd.weights_kv_up:
+            pairs.append(("kv_up", fwd.weights_kv_up,
+                          self.accumulated_gradient_weights_kv_up))
+            pairs.append(("latent", fwd.gain_latent,
+                          self.accumulated_gradient_gain_latent))
         return pairs
 
     def initialize(self, device=None, **kwargs) -> None:
@@ -1206,9 +1455,43 @@ class GDMultiHeadAttention(GradientDescentBase):
             self._apply_bias_xla(
                 gbo, vec=fwd.bias_out,
                 acc_vec=self.accumulated_gradient_bias_out)
-        grads = dict(zip(("norm", "q", "k", "head_gate", "post"), ggains))
+        grads = dict(zip(self._GAINS, ggains))
         for name, gain, acc in self._gain_pairs():
             self._apply_weights_xla(grads[name], vec=gain, acc_vec=acc)
+
+    def _latent_backward_np(self, x) -> None:
+        """A latent-K/V layer has no analytic numpy backward: the numpy
+        path differentiates the plain XLA forward on the host, as
+        ``GDGatedDeltaNet`` does (the layer is held to
+        ``znbench/reference/ling.py`` instead)."""
+        fwd = self.forward_unit
+        kernels, fwd._flash_pallas = fwd._flash_pallas, False
+        try:
+            if self._host_pullback is None:   # one host program
+                self._host_pullback = jax.jit(
+                    lambda err, *args: jax.vjp(fwd.xla_forward,
+                                               *args)[1](err))
+            args = (x,) + tuple(
+                None if a is None else np.asarray(a)
+                for a in (getattr(fwd, attr).mem
+                          if getattr(fwd, attr) else None
+                          for attr in fwd.EXPORT_PARAMS))
+            with jax.default_matmul_precision("highest"):
+                gx, gwq, _, gwo, _, *ggains = self._host_pullback(
+                    jnp.asarray(self.err_output.mem, jnp.float32), *args)
+        finally:
+            fwd._flash_pallas = kernels
+        if self.need_err_input:
+            self.err_input.map_invalidate()
+            self.err_input.mem[...] = np.asarray(gx)
+        self._apply_weights_np(np.asarray(gwq))
+        self._apply_weights_np(
+            np.asarray(gwo), vec=fwd.weights_out,
+            acc_vec=self.accumulated_gradient_weights_out)
+        grads = dict(zip(self._GAINS, ggains))
+        for name, gain, acc in self._gain_pairs():
+            self._apply_weights_np(np.asarray(grads[name]), vec=gain,
+                                   acc_vec=acc)
 
     def numpy_run(self) -> None:
         """Analytic attention backward (the oracle/spec)."""
@@ -1223,6 +1506,8 @@ class GDMultiHeadAttention(GradientDescentBase):
         for _, gain, _ in self._gain_pairs():
             gain.map_write()
         x = self.input.mem.astype(np.float32)
+        if fwd.kv_latent is not None:
+            return self._latent_backward_np(x)
         b, t, d = x.shape
         h, h_kv = fwd.n_heads, fwd.n_kv_heads
         qw, kw, dh = fwd._widths(d)

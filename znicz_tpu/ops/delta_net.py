@@ -41,6 +41,26 @@ precision the products into W, U, P, V′, O and the state's update take
 bf16 INPUTS with f32 accumulation: the state itself is accumulated and
 stored f32.
 
+Both decay shapes run through ONE family of kernels; the shape of
+log α — (B, T, H) or (B, T, H, d_k) — is a static property of the call
+and picks the body.  ``decay="channel"`` (Kimi Delta Attention,
+arXiv:2510.26692; Ling-3.0-flash, PR 37) gives every KEY CHANNEL its
+own decay, S_t = Diag(α_t) S_{t−1} + β_t k_t (v_t − (Diag(α_t)
+S_{t−1})ᵀ k_t)ᵀ, in the bounded form
+
+.. code-block:: text
+
+    log α_t = lower_bound · σ(exp(A_h) · (m W_f + b))   ∈ (lower_bound, 0)
+
+W_f (D, H·d_k), A per head, b per channel (``weights_ba`` is then
+W_b ‖ W_f, (D, H + H·d_k)); ``gate="sigmoid"`` makes the output gate
+σ((m W_g)_h).  The chunked algebra then has Γ inside the contraction
+and computes it by sub-blocks of ``pallas_delta.SUB_BLOCK`` positions
+(kernels ``znicz_kda_chunk_fwd`` / ``_bwd``, ``znicz_kda_state_fwd`` /
+``_bwd``); the exponent bound it relies on is stated ONCE, at
+``pallas_delta.MAX_EXPONENT``, and ``initialize`` refuses a
+``lower_bound`` that could pass it.
+
 Parameters: ``weights`` (D, H·(2 d_k + d_v)) = W_q ‖ W_k ‖ W_v,
 ``weights_conv`` (H·(2 d_k + d_v), J), ``weights_gate`` (D, H·d_v),
 ``weights_ba`` (D, 2H) = W_b ‖ W_a, ``decay_log`` A (H,),
@@ -78,6 +98,16 @@ def _softplus(xp, x):
     return xp.logaddexp(x, 0.0)
 
 
+def _logistic(xp, x):
+    """σ(x) whose gradient stays finite where exp(−x) leaves the f32
+    range (``_sigmoid``'s does not): the bounded decay's argument is
+    exp(A) · logits, tens in either direction."""
+    if xp is jnp:
+        return jax.nn.sigmoid(x)
+    e = np.exp(-np.abs(x))
+    return np.where(x >= 0, 1.0, e) / (1.0 + e)
+
+
 def l2_normalize(xp, x, eps: float):
     return x / xp.sqrt((x * x).sum(axis=-1, keepdims=True) + eps)
 
@@ -92,6 +122,8 @@ class GatedDeltaNet(Forward):
     def __init__(self, workflow, n_heads: int, key_dim: int,
                  value_dim: int, conv_kernel: int = 4,
                  allow_neg_eigval: bool = False,
+                 decay: str = "head", lower_bound: float | None = None,
+                 gate: str = "silu",
                  pre_norm: str | None = None,
                  post_norm: str | None = None, residual: bool = False,
                  norm_eps: float = 1e-5,
@@ -105,6 +137,20 @@ class GatedDeltaNet(Forward):
             if value not in (None, "rms"):
                 raise ValueError(f"{option} must be None or 'rms', got "
                                  f"{value!r}")
+        if decay not in ("head", "channel"):
+            raise ValueError(f"decay must be 'head' or 'channel', got "
+                             f"{decay!r}")
+        if gate not in ("silu", "sigmoid"):
+            raise ValueError(f"gate must be 'silu' or 'sigmoid', got "
+                             f"{gate!r}")
+        if lower_bound is not None and not lower_bound < 0:
+            raise ValueError(f"lower_bound {lower_bound} is not below 0")
+        #: one decay a head, or one per key channel (module docstring)
+        self.decay = decay
+        #: log α ∈ (lower_bound, 0) by a sigmoid; None: −exp(A)·softplus
+        self.lower_bound = None if lower_bound is None \
+            else float(lower_bound)
+        self.gate = gate
         self.n_heads = int(n_heads)
         self.key_dim, self.value_dim = int(key_dim), int(value_dim)
         self.conv_kernel = int(conv_kernel)
@@ -123,13 +169,19 @@ class GatedDeltaNet(Forward):
         self._interpret = False
 
     # -- parameters -----------------------------------------------------
+    @property
+    def decay_channels(self) -> int:
+        """Decays a head: 1, or d_k."""
+        return self.key_dim if self.decay == "channel" else 1
+
     def _decay_init(self) -> tuple:
         """A and b as the layer of arXiv:2412.06464 draws them: exp(A)
         uniform in (0, 16); softplus(b) log-uniform in (1e-3, 0.1)."""
         gen, h = prng.get(), self.n_heads
         a = gen.fill_uniform((h,), 1e-3, 16.0, dtype=np.float32)
         dt = np.exp(gen.fill_uniform(
-            (h,), np.log(1e-3), np.log(0.1), dtype=np.float32))
+            (h * self.decay_channels,), np.log(1e-3), np.log(0.1),
+            dtype=np.float32))
         dt = np.maximum(dt, 1e-4)
         return np.log(a).astype(np.float32), \
             (dt + np.log(-np.expm1(-dt))).astype(np.float32)
@@ -147,7 +199,8 @@ class GatedDeltaNet(Forward):
         wide = h * (2 * dk + dv)
         for vec, shape in ((self.weights, (d, wide)),
                            (self.weights_gate, (d, h * dv)),
-                           (self.weights_ba, (d, 2 * h)),
+                           (self.weights_ba,
+                            (d, h * (1 + self.decay_channels))),
                            (self.weights_out, (h * dv, d))):
             if not vec:
                 vec.reset(self.fill_array(shape, self.weights_filling,
@@ -193,6 +246,19 @@ class GatedDeltaNet(Forward):
         if refused is None and not pallas_delta.kernel_legal(
                 self.chunk):
             refused = f"a chunk of {self.chunk} is not whole sublanes"
+        sub = pallas_delta.sub_block(self.chunk)
+        if self.decay == "channel":
+            # the sub-block algebra exponentiates up to sub · |bound|
+            # (pallas_delta.MAX_EXPONENT: the bound's one statement)
+            reach = float("inf") if self.lower_bound is None \
+                else sub * -self.lower_bound
+            if reach > pallas_delta.MAX_EXPONENT:
+                raise ValueError(
+                    f"{self}: decay='channel' with lower_bound "
+                    f"{self.lower_bound} over sub-blocks of {sub} "
+                    f"positions exponentiates up to {reach:g}, past "
+                    f"pallas_delta.MAX_EXPONENT "
+                    f"{pallas_delta.MAX_EXPONENT:g}: the f32 range")
         self._kernels, self._interpret = refused is None, interpret
         dk, dv, chunk = self.key_dim, self.value_dim, self.chunk
         chunks = -(-t // chunk)
@@ -204,7 +270,9 @@ class GatedDeltaNet(Forward):
             if self._kernels else 1.0,
             "state_mb": b * self.n_heads * chunks * dk * dv * 4 / 1e6,
             "path": 1.0 if self._kernels else 0.0,
-            "chunk_path": 1.0 if self._kernels else 0.0}
+            "chunk_path": 1.0 if self._kernels else 0.0,
+            "decay_channels": self.decay_channels,
+            "sub_block": sub if self.decay == "channel" else chunk}
         for stat, value in stats.items():
             _metrics.delta_scan(self.name, stat).set(value)
         self.info(
@@ -214,8 +282,12 @@ class GatedDeltaNet(Forward):
             self.name, chunks, chunk, self.n_heads, dk, dv,
             f", {chunks * chunk - t} positions of padding"
             if t % chunk else "",
-            "znicz_gdr_chunk_fwd / _bwd kernels for what is local to a "
-            "chunk, znicz_delta_state_fwd / _bwd for the walk"
+            ("znicz_kda_chunk_fwd / _bwd kernels for what is local to a "
+             f"chunk (a decay per key channel, sub-blocks of {sub}), "
+             "znicz_kda_state_fwd / _bwd for the walk"
+             if self.decay == "channel" else
+             "znicz_gdr_chunk_fwd / _bwd kernels for what is local to a "
+             "chunk, znicz_delta_state_fwd / _bwd for the walk")
             + (" (interpreted)" if interpret else "")
             if self._kernels else f"plain scan ({refused})",
             stats["state_mb"], stats["padded_share"])
@@ -227,12 +299,29 @@ class GatedDeltaNet(Forward):
             for attr in self.EXPORT_PARAMS)
 
     def _gates(self, xp, ba, a_log, bias):
-        """(…, 2H) logits → β and log α, (…, H) each, f32."""
+        """(…, H + H·channels) logits → β (…, H) and log α, (…, H) or
+        (…, H, d_k) with a decay per key channel, f32."""
         h = self.n_heads
         beta = _sigmoid(xp, ba[..., :h])
         if self.allow_neg_eigval:
             beta = 2.0 * beta
-        return beta, -xp.exp(a_log) * _softplus(xp, ba[..., h:] + bias)
+        if self.decay == "head" and self.lower_bound is None:
+            # the scalar-decay program as it was, equation for equation
+            # (its persisted key does not move)
+            return beta, \
+                -xp.exp(a_log) * _softplus(xp, ba[..., h:] + bias)
+        rate, logits = xp.exp(a_log), ba[..., h:] + bias
+        if self.decay == "channel":
+            logits = logits.reshape(logits.shape[:-1]
+                                    + (h, self.key_dim))
+            rate = rate[:, None]
+        if self.lower_bound is None:
+            return beta, -rate * _softplus(xp, logits)
+        return beta, self.lower_bound * _logistic(xp, rate * logits)
+
+    def _gate_of(self, xp, gate):
+        return _silu(xp, gate) if self.gate == "silu" \
+            else _sigmoid(xp, gate)
 
     def _heads(self, xp, mixed):
         """The convolved q ‖ k ‖ v (B, T, ·) → q, k (B, T, H, d_k)
@@ -253,12 +342,23 @@ class GatedDeltaNet(Forward):
         m = x32 if g_norm is None or self.post_norm \
             else rms_norm(jnp, x32, g_norm, self.norm_eps)
         rows = m.reshape(b * t, d)
-        mixed = _silu(jnp, causal_conv(
-            jnp, self.mxu_dot(jnp, rows, w_qkv).reshape(b, t, -1),
-            w_conv))
-        q, k, v = self._heads(jnp, mixed)
+
+        def heads(projected, taps):
+            return self._heads(jnp, _silu(jnp, causal_conv(
+                jnp, projected, taps)))
+
+        if self.decay == "channel":
+            # the backward keeps the PROJECTION and runs the taps, the
+            # SiLU and the two norms again (elementwise: a pass over
+            # 3 · T · H · d values) — under plain autodiff the
+            # convolved and the activated copies are kept (2 · 192 MB a
+            # layer at T 4,096 × 32 heads of 128) and XLA, short of
+            # memory, makes the projection again as a MATMUL
+            heads = jax.checkpoint(heads)
+        q, k, v = heads(
+            self.mxu_dot(jnp, rows, w_qkv).reshape(b, t, -1), w_conv)
         beta, log_alpha = self._gates(
-            jnp, self.mxu_dot(jnp, rows, w_ba).reshape(b, t, 2 * h),
+            jnp, self.mxu_dot(jnp, rows, w_ba).reshape(b, t, -1),
             a_log, bias)
         pad = -t % self.chunk
         if pad:        # positions that write nothing and decay nothing
@@ -270,7 +370,8 @@ class GatedDeltaNet(Forward):
             kernel=self._kernels, interpret=self._interpret,
             dot_dtype=self.mxu_dtype)[:, :t]
         gate = self.mxu_dot(jnp, rows, w_gate).reshape(b, t, h, dv)
-        o = rms_norm(jnp, o, g_out, self.norm_eps) * _silu(jnp, gate)
+        o = rms_norm(jnp, o, g_out, self.norm_eps) \
+            * self._gate_of(jnp, gate)
         y = self.mxu_dot(jnp, o.reshape(b * t, h * dv),
                          w_out).reshape(b, t, d)
         if self.post_norm:
@@ -298,19 +399,21 @@ class GatedDeltaNet(Forward):
             self.weights_conv.mem))
         q, k, v = self._heads(np, mixed)
         beta, log_alpha = self._gates(
-            np, (rows @ self.weights_ba.mem).reshape(b, t, 2 * h),
+            np, (rows @ self.weights_ba.mem).reshape(b, t, -1),
             self.decay_log.mem, self.decay_bias.mem)
+        if self.decay == "head":
+            log_alpha = log_alpha[..., None]
         state = np.zeros((b, h, dk, dv), np.float32)
         o = np.zeros((b, t, h, dv), np.float32)
         for i in range(t):
-            state = state * np.exp(log_alpha[:, i])[..., None, None]
+            state = state * np.exp(log_alpha[:, i])[..., None]
             seen = np.einsum("bhkv,bhk->bhv", state, k[:, i])
             state = state + beta[:, i][..., None, None] * np.einsum(
                 "bhk,bhv->bhkv", k[:, i], v[:, i] - seen)
             o[:, i] = np.einsum("bhkv,bhk->bhv", state, q[:, i])
         gate = (rows @ self.weights_gate.mem).reshape(b, t, h, dv)
         o = rms_norm(np, o, self.gain_out.mem, self.norm_eps) \
-            * _silu(np, gate)
+            * self._gate_of(np, gate)
         y = (o.reshape(b * t, h * dv) @ self.weights_out.mem).reshape(
             b, t, d)
         if self.post_norm:

@@ -82,6 +82,23 @@ whole step.  What a user then sees: ``guard_skipped_steps`` rises WITH
 (raise ``aux_loss_weight``, or hold fewer tokens a step); skipped steps
 with ``rows_over`` 0 have another cause.
 
+``select_bias`` and ``groups`` (Ling-3.0-flash, PR 37; DeepSeek-V3's
+router, arXiv:2412.19437 §2.1.2) change the CHOICE only, beside
+``held``: with ``select_bias`` the top k are taken by ``score + b``
+while the weights stay the unbiased scores' (b enters no output and no
+gradient); with ``groups=(n, m)`` the experts stand in n groups, a
+group's score is the sum of its 2 largest (biased) scores, the m best
+groups are kept and the top k taken among their experts.  b (E,) is a
+leaf of the third kind — neither moved by a gradient nor fixed: once a
+step, inside the step program, ``GDMoE`` moves it by ``b_e += γ ·
+sign(mean load − load_e)`` (``bias_rate`` γ; load = this step's rows
+routed to e, all E counted) under the scope ``router_bias``, gated by
+the anomaly guard's running flag like every update (a skipped step
+moves no bias).  It is a Vector of the unit, so the snapshotter saves
+and restores it; it lies OUTSIDE the SDC fingerprint, which folds what
+``_apply_param_xla`` updates: a flipped bit in b moves a choice, not a
+value, and the next steps' rule pulls it back.
+
 ``GatedMLP`` is the same gated MLP with no router — a model's dense
 feed-forward block (``y = x + W_down (silu(W_gate m) ⊙ W_up m)``).
 """
@@ -103,6 +120,10 @@ from znicz_tpu.ops.rms_norm import (norm_gains, post_gain, rms_norm,
 
 #: slots of ``moe_stats`` after the E per-expert row totals
 _LB, _Z, _STEPS, _MAX, _MIN = range(5)
+#: … and, of a layer with ``select_bias``, just before the grouped
+#: matmuls' two at the end: steps the bias's rule ran, steps the guard
+#: held it back, the largest |b_e| it left
+_BIAS = slice(-5, -2)
 
 
 # ----------------------------------------------------------------------
@@ -242,6 +263,8 @@ class MoE(Forward):
                  z_loss_weight: float = 0.0, norm_eps: float = 1e-5,
                  score: str = "softmax", routed_scale: float = 1.0,
                  shared_width: int = 0, held=None,
+                 select_bias: bool = False, groups=None,
+                 bias_rate: float = 1e-3,
                  name=None, **kwargs) -> None:
         kwargs.setdefault("weights_filling", "xavier")
         kwargs["include_bias"] = False
@@ -274,6 +297,27 @@ class MoE(Forward):
                 and self.held[-1] < self.n_experts):
             raise ValueError(f"{self}: held {held} is not a set of "
                              f"experts below {n_experts}")
+        #: the choice's options (module docstring): a bias on the
+        #: selection and the group limit (n groups, m kept)
+        self.select_bias_on = bool(select_bias)
+        self._bias_steps = 0       # steps the bias's rule ran, so far
+        self.bias_rate = float(bias_rate)
+        self.groups = None if groups is None \
+            else (int(groups[0]), int(groups[1]))
+        if self.groups is not None:
+            n_group, kept = self.groups
+            per = self.n_experts // max(n_group, 1)
+            if (n_group < 1 or self.n_experts % n_group
+                    or not 1 <= kept <= n_group or per < 2
+                    or kept * per < self.top_k):
+                raise ValueError(
+                    f"{self}: groups {groups} do not split "
+                    f"{n_experts} experts into groups of at least 2 "
+                    f"whose kept ones hold top_k {top_k}")
+        #: the selection bias b (E,) and its last step's loads; what
+        #: the rule did is three slots of ``moe_stats`` (``_BIAS``)
+        self.select_bias = Vector(name=f"{self.name}.select_bias")
+        self.select_load = Vector(name=f"{self.name}.select_load")
         self.weights_gate = Vector(name=f"{self.name}.weights_gate")
         self.weights_up = Vector(name=f"{self.name}.weights_up")
         self.weights_down = Vector(name=f"{self.name}.weights_down")
@@ -329,7 +373,18 @@ class MoE(Forward):
                                           fan_in=fan_in))
         if self.pre_norm and not self.gain_norm:
             self.gain_norm.reset(np.ones(d, np.float32))
-        slots = local + 5 + (3 if self.held else 0) + 2
+        if self.select_bias_on:
+            from znicz_tpu.parallel import partition
+            for vec in (self.select_bias, self.select_load):
+                if not vec or vec.shape != (e,):
+                    vec.reset(np.zeros(e, np.float32))
+            for attr in ("select_bias", "select_load"):
+                self.partition_leaf(attr, partition.REPLICATED)
+            from znicz_tpu.observe import metrics as obs_metrics
+            obs_metrics.moe_router(self.name, "groups_kept").set(
+                self.groups[1] if self.groups else 0)
+        slots = local + 5 + (3 if self.held else 0) \
+            + (3 if self.select_bias_on else 0) + 2
         if not self.moe_stats or self.moe_stats.shape != (slots,):
             # (a snapshot from before PR 34 holds two slots fewer)
             self.moe_stats.reset(np.zeros(slots, np.float32))
@@ -375,15 +430,22 @@ class MoE(Forward):
                   if self._gmm_kernel
                   else f"jax.lax.ragged_dot ({refused})",
                   rows, local, d, f, f, d,
-                  f"; {self.score} scores x {self.routed_scale:g}, "
-                  f"shared expert of {shared}"
-                  if (self.score, self.routed_scale, shared)
-                  != ("softmax", 1.0, 0) else "")
+                  (f"; {self.score} scores x {self.routed_scale:g}, "
+                   f"shared expert of {shared}"
+                   if (self.score, self.routed_scale, shared)
+                   != ("softmax", 1.0, 0) else "")
+                  + (f"; the choice by score + bias (rate "
+                     f"{self.bias_rate:g}, moved in the step program "
+                     f"under scope router_bias)"
+                     if self.select_bias_on else "")
+                  + ("; group-limited: %d groups, %d kept" % self.groups
+                     if self.groups else ""))
         self.init_vectors(self.input, self.output, self.weights,
                           self.weights_gate, self.weights_up,
                           self.weights_down, self.gain_norm,
                           self.moe_stats, self.router_logits,
-                          self.last_choice,
+                          self.last_choice, self.select_bias,
+                          self.select_load,
                           *(getattr(self, attr) for attr in self.SHARED))
 
     # -- pure forward (jnp; the backward vjp's this) --------------------
@@ -395,20 +457,54 @@ class MoE(Forward):
         if self.shared_width:
             args += tuple(getattr(self, attr).devmem
                           for attr in self.SHARED)
+        elif self.select_bias_on:
+            args += (None, None, None)
+        if self.select_bias_on:     # LAST: no cotangent ever reaches it
+            args += (self.select_bias.devmem,)
         return args
 
-    def route(self, xp, m, w_r):
+    def _selection(self, xp, p, bias):
+        """The numbers the top k are taken by, (N, E): the scores, plus
+        the selection bias, −inf outside the groups kept (a group's
+        score: the sum of its 2 largest biased scores)."""
+        sel = p if bias is None else p + bias
+        if self.groups is None:
+            return sel
+        n_group, kept = self.groups
+        grouped = sel.reshape(sel.shape[0], n_group, -1)
+        if xp is jnp:
+            group_score = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)
+            best = jax.lax.top_k(group_score, kept)[1]
+        else:
+            group_score = np.sort(grouped, axis=-1)[..., -2:].sum(axis=-1)
+            best = np.argsort(-group_score, axis=-1,
+                              kind="stable")[:, :kept]
+        keep = (best[:, :, None]
+                == xp.arange(n_group)[None, None, :]).any(axis=1)
+        return xp.where(keep[:, :, None], grouped, -xp.inf).reshape(
+            sel.shape)
+
+    def route(self, xp, m, w_r, bias=None):
         """``(logits, p, top_p, top_e)`` for (N, D) rows — float32
         throughout, on the device at the highest matmul precision, so
         that the choice of experts does not ride bf16 rounding.  ``p``
         is the score the top k are taken by: the softmax over the
-        experts, or each expert's own sigmoid."""
+        experts, or each expert's own sigmoid — or, with ``bias`` or
+        ``groups``, :meth:`_selection` of it; ``top_p`` is ``p`` at
+        the chosen experts either way."""
+        plain = bias is None and self.groups is None
         if xp is jnp:
             logits = jnp.dot(m, w_r, precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
             p = jax.nn.softmax(logits, axis=-1) \
                 if self.score == "softmax" else jax.nn.sigmoid(logits)
-            top_p, top_e = jax.lax.top_k(p, self.top_k)
+            if plain:
+                top_p, top_e = jax.lax.top_k(p, self.top_k)
+            else:
+                top_e = jax.lax.top_k(
+                    self._selection(jnp, jax.lax.stop_gradient(p), bias),
+                    self.top_k)[1]
+                top_p = jnp.take_along_axis(p, top_e, axis=-1)
         else:
             logits = m @ w_r
             if self.score == "softmax":
@@ -417,7 +513,9 @@ class MoE(Forward):
             else:
                 p = _sigmoid(np, logits)
             # ties go to the lower index, as lax.top_k
-            top_e = np.argsort(-p, axis=-1, kind="stable")[:, :self.top_k]
+            sel = p if plain else self._selection(np, p, bias)
+            top_e = np.argsort(-sel, axis=-1,
+                               kind="stable")[:, :self.top_k]
             top_p = np.take_along_axis(p, top_e, axis=-1)
         return logits, p, top_p, top_e
 
@@ -481,7 +579,7 @@ class MoE(Forward):
         return f, sizes, (here, over)
 
     def xla_forward(self, x, w_r, w_g, w_u, w_d, g_norm=None,
-                    ws_g=None, ws_u=None, ws_d=None):
+                    ws_g=None, ws_u=None, ws_d=None, select_bias=None):
         """``((y, (lb, z)), (counts, logits, top_e))``: the output and
         the two auxiliary losses (differentiable); rows per expert, the
         router's logits and its choice (not)."""
@@ -490,7 +588,10 @@ class MoE(Forward):
         x32 = x.astype(jnp.float32)
         m = (x32 if g_norm is None
              else rms_norm(jnp, x32, g_norm, self.norm_eps)).reshape(n, d)
-        logits, p, top_p, top_e = self.route(jnp, m, w_r)
+        logits, p, top_p, top_e = self.route(
+            jnp, m, w_r,
+            None if select_bias is None
+            else jax.lax.stop_gradient(select_bias))
         top_p = self._weights_of(top_p)
         flat_e = top_e.reshape(n * k)
 
@@ -531,9 +632,12 @@ class MoE(Forward):
         routed = jax.lax.stop_gradient(sizes.astype(jnp.float32))
         return ((y, self.aux_losses(jnp, logits, p, routed)),
                 (routed if counts is None else counts,
-                 jax.lax.stop_gradient(logits), top_e))
+                 jax.lax.stop_gradient(logits), top_e)
+                + ((routed,) if self.select_bias_on else ()))
 
-    def _record(self, lb, z, counts, logits, top_e) -> None:
+    def _record(self, lb, z, counts, logits, top_e, load=None) -> None:
+        if load is not None:      # what GDMoE's rule reads this step
+            self.select_load.devmem = load
         self.router_logits.devmem = logits.reshape(
             self.router_logits.shape)
         self.last_choice.devmem = top_e.astype(jnp.int32).reshape(
@@ -554,6 +658,8 @@ class MoE(Forward):
                 pallas_gmm.visited_rows(
                     sizes, pallas_gmm.part_rows(self._gmm_row_tile)),
                 sizes.sum()])
+        if self.select_bias_on:    # GDMoE's rule fills them (_BIAS)
+            held = held + [jnp.zeros(3, jnp.float32)]
         self.moe_stats.devmem = self.moe_stats.devmem + jnp.concatenate(
             [counts, tail] + held + [grid]).astype(jnp.float32)
 
@@ -601,6 +707,13 @@ class MoE(Forward):
                 for stat, value in (("visited", tail[-2] / steps),
                                     ("real", tail[-1] / steps)):
                     obs_metrics.moe_gmm_rows(self.name, stat).set(value)
+            if self.select_bias_on:
+                ran, _skipped, extreme = tail[_BIAS]
+                self._bias_steps += int(ran)
+                obs_metrics.moe_router(self.name, "bias_steps").set(
+                    self._bias_steps)
+                obs_metrics.moe_router(self.name, "bias_abs_max").set(
+                    extreme)
         stats.map_invalidate()
         stats.mem[...] = 0.0      # uploaded on the next region fire
 
@@ -612,7 +725,11 @@ class MoE(Forward):
         n = b * t
         m = (rms_norm(np, x, self.gain_norm.mem, self.norm_eps)
              if self.pre_norm else x).reshape(n, d)
-        logits, p, raw_p, top_e = self.route(np, m, self.weights.mem)
+        if self.select_bias_on:
+            self.select_bias.map_read()
+        logits, p, raw_p, top_e = self.route(
+            np, m, self.weights.mem,
+            self.select_bias.mem if self.select_bias_on else None)
         top_p = self._weights_of(raw_p)
         f = np.zeros((n, d), np.float32)
         per_expert = []
@@ -655,8 +772,13 @@ class MoE(Forward):
             vec.map_invalidate()
             vec.mem[...] = value.reshape(vec.shape)
         lb, z = self.aux_losses(np, logits, p, routed)
+        if self.select_bias_on:
+            self.select_load.map_invalidate()
+            self.select_load.mem[...] = routed
         held = [] if self.held is None \
             else [counts.sum(), routed.sum(), 0.0]
+        if self.select_bias_on:
+            held = held + [0.0, 0.0, 0.0]
         self.moe_stats.map_write()
         self.moe_stats.mem[...] += np.concatenate(
             [counts, [lb, z, 1.0, counts.max(), counts.min()], held,
@@ -715,8 +837,14 @@ class GDMoE(GradientDescentBase):
     def region_vectors(self):
         vecs = super().region_vectors()
         seen = {id(v) for v in vecs}
+        fwd = self.forward_unit
         for _, param, acc in self._extra_pairs():
             for vec in (param, acc):
+                if vec and id(vec) not in seen:
+                    vecs.append(vec)
+        if getattr(fwd, "select_bias_on", False):
+            # what the bias's rule reads and writes of the forward unit
+            for vec in (fwd.select_bias, fwd.select_load, fwd.moe_stats):
                 if vec and id(vec) not in seen:
                     vecs.append(vec)
         return vecs
@@ -748,6 +876,48 @@ class GDMoE(GradientDescentBase):
         grads = dict(zip(self.EXTRA, g_extra))
         for attr, param, acc in self._extra_pairs():
             self._apply_weights_xla(grads[attr], vec=param, acc_vec=acc)
+        if getattr(self.forward_unit, "select_bias_on", False):
+            self._move_select_bias(jnp)
+
+    def _move_select_bias(self, xp) -> None:
+        """The selection bias's own rule, once per optimizer step:
+        ``b_e += γ · sign(mean load − load_e)`` from this step's loads
+        (module docstring) — no gradient, no momentum, gated by the
+        anomaly guard's running flag as every update is.  Under
+        accumulation the rule runs with the step that applies (its
+        loads: the last microbatch's); a pass of a looped span is
+        refused at ``initialize``."""
+        from znicz_tpu.accelerated_units import current_accum_phase
+        phase = current_accum_phase() if xp is jnp else None
+        if phase is not None and phase[0] == "accum":
+            return
+        fwd = self.forward_unit
+        guard = self.anomaly_flag \
+            if self.anomaly_flag is not None and self.anomaly_flag else None
+        if xp is np:
+            for vec in (fwd.select_bias, fwd.moe_stats):
+                vec.map_write()
+            fwd.select_load.map_read()
+            load = fwd.select_load.mem
+            ok = np.float32(1.0 if guard is None
+                            else guard.mem[0] > 0.5)
+            fwd.select_bias.mem[...] += ok * fwd.bias_rate * np.sign(
+                load.mean() - load)
+            did = fwd.moe_stats.mem[_BIAS]
+            did[:2] += (ok, 1.0 - ok)
+            did[2] = np.abs(fwd.select_bias.mem).max()
+            return
+        with jax.named_scope("router_bias"):
+            load = fwd.select_load.devmem
+            ok = jnp.float32(1.0) if guard is None \
+                else (guard.devmem[0] > 0.5).astype(jnp.float32)
+            bias = fwd.select_bias.devmem \
+                + ok * fwd.bias_rate * jnp.sign(load.mean() - load)
+            fwd.select_bias.devmem = bias
+            stats = fwd.moe_stats.devmem
+            fwd.moe_stats.devmem = stats.at[_BIAS].set(jnp.stack(
+                [stats[-5] + ok, stats[-4] + (1.0 - ok),
+                 jnp.abs(bias).max()]))
 
     def numpy_run(self) -> None:
         """Analytic backward (the oracle/spec)."""
@@ -827,6 +997,8 @@ class GDMoE(GradientDescentBase):
         self._apply_weights_np(grad_router)
         for attr, param, acc in self._extra_pairs():
             self._apply_weights_np(grads[attr], vec=param, acc_vec=acc)
+        if fwd.select_bias_on:
+            self._move_select_bias(np)
 
 
 def _gated_mlp_backward(m, gate, up, dout, w_gate, w_up, w_down):

@@ -283,7 +283,11 @@ def head_layout(n_heads: int, dh: int, group: int = 1) -> tuple:
     of the (B, T, H·dh) projection (``pack·dh`` a multiple of 128:
     dh 128, 256, …, or pairs of dh 64), so the kernels address it where
     it lies; else ``"head_major"`` (dh 32, 80, 96, 192, an odd head
-    count at dh 64: no such block exists, the wrapper transposes).  Static per program:
+    count at dh 64: no such block exists, the wrapper transposes — a
+    latent-K/V layer's keys of 128 + 64 never come here: its score is
+    the sum of two products, a 128-wide per-head block at the boundary
+    layout and a 64-wide rotary block shared by all heads,
+    ``ops/pallas_mla.py``).  Static per program:
     the attention unit reports it (info line, ``znicz_flash_layout``)."""
     # grouped queries: a pair of query heads does not read a pair of
     # K/V heads, so every head is a program of its own

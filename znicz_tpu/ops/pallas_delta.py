@@ -59,8 +59,43 @@ chunked view are ``jax.numpy`` under autodiff.
 Head sizes need not fill a 128-lane tile: a block spans a whole
 (C, d_k) or (d_k, d_v) face of its array, which Mosaic lays out in
 whole (8, 128) tiles — at d_k 96 × d_v 192 the state occupies
-128 × 256 lanes' worth, 1.78 × its elements (:func:`padded_share`);
-HBM holds no padding.
+128 × 256 lanes' worth, 1.78 × its elements (:func:`padded_share`; at
+128 × 128 exactly its own, 1.0); HBM holds no padding.
+
+**Both decay shapes** run through this ONE family.  The shape of log α
+is a static property of the call and picks the body: (…, H), one decay
+a head, is everything above (``znicz_gdr_chunk_*``,
+``znicz_delta_state_*``); (…, H, d_k), one decay per KEY CHANNEL (Kimi
+Delta Attention, arXiv:2510.26692; PR 37), is
+
+.. code-block:: text
+
+    S_t = Diag(α_t) S_{t−1} + β_t k_t (v_t − (Diag(α_t) S_{t−1})ᵀ k_t)ᵀ
+
+with c_i ∈ R^{d_k}.  Γ then stands INSIDE the contraction,
+M_ij = Σ_d K_id K_jd e^(c_id − c_jd) and P likewise, which two
+(C, d_k) factors give only by exponentiating c_i − r and r − c_j
+apart, r a reference point: "only differences ≤ 0 are exponentiated"
+cannot hold for a whole chunk.  It holds by SUB-BLOCKS of
+``SUB_BLOCK`` = 16 positions: a row's sub-block takes the prefix at
+its own start as r, so against every earlier sub-block both exponents
+are ≤ 0, and inside the sub-block r − c_j ≤ 16 · |lower bound| — **the
+exponent bound, stated once: ``MAX_EXPONENT`` = 80** (e^80 · d_k = 128
+terms stays under the f32 maximum, log 3.4e38 = 88.7), which is what a
+bounded decay (log α ≥ −5) is published FOR; ``GatedDeltaNet``
+refuses, by name at ``initialize``, a bound whose 16-fold passes it.
+The rest is the scalar algebra with rows for numbers: W = A (e^c ⊙ K),
+K̂ = K ⊙ e^(c_C − c), Qc = e^c ⊙ Q, S_{n+1} = Diag(e^(c_C)) S_n +
+K̂ᵀ V′ — the walk scales S's ROWS.  Kernels ``znicz_kda_chunk_fwd`` /
+``_bwd`` (the hand-written backward gives d log α per channel) and
+``znicz_kda_state_fwd`` / ``_bwd``; precision as above (the sums of
+log α, Γ's factors, M, the inverse f32 at the highest precision; W, U,
+P and the state's update ``dot_dtype`` inputs, f32 accumulation, f32
+state in VMEM — the per-chunk S_n this walk WRITES for the backward are
+at ``dot_dtype``, the width every product takes them in: 64 MB a layer
+less than f32 at T 4,096 × 32 heads of 128 × 128); :func:`chunk_local` / :func:`state_scan` in ``jax.numpy``,
+which write Γ out as a (C, C, d_k) array, stay the oracle and the path
+off a TPU.  Neither family's kernel names hold the other's.
 """
 
 from __future__ import annotations
@@ -90,8 +125,8 @@ def padded_share(dk: int, dv: int) -> float:
     """Elements of the 128-lane tiles a d_k × d_v product occupies over
     d_k · d_v: d_k is the lane axis of W and K̂ and the contraction of
     their products with the state, d_v the lane axis of S, U and V′;
-    each goes to the next multiple of 128 (1.0: nothing padded; 1.78
-    at 96 × 192)."""
+    each goes to the next multiple of 128 (1.0: nothing padded, as at
+    Ling-3.0-flash's 128 × 128; 1.78 at Olmo-Hybrid's 96 × 192)."""
     def whole(n: int) -> int:
         return -(-n // _LANES) * _LANES
     return whole(dk) * whole(dv) / float(dk * dv)
@@ -185,7 +220,11 @@ def chunk_local(q, k, v, log_alpha, beta, dot_dtype=None):
     v (G, N, C, d_v), log α and β (G, N, C) — ``decay`` = exp(c_C)
     (G, N), ``Qc`` = exp(c) ⊙ Q, ``P`` = Q Kᵀ ⊙ Γ on and below the
     diagonal.  The logarithms, their sums, Γ and the inverse are f32;
-    the products into W, U and P take ``dot_dtype`` inputs."""
+    the products into W, U and P take ``dot_dtype`` inputs.  A decay
+    per key channel — log α (G, N, C, d_k) — takes
+    :func:`_chunk_local_channels`, whose ``decay`` is (G, N, d_k)."""
+    if log_alpha.ndim == q.ndim:
+        return _chunk_local_channels(q, k, v, log_alpha, beta, dot_dtype)
     chunk = q.shape[-2]
     log_alpha = log_alpha.astype(jnp.float32)
     c = jnp.cumsum(log_alpha, axis=-1)
@@ -566,7 +605,9 @@ def chunk_local_kernels(q, k, v, log_alpha, beta, dot_dtype=None,
     step (the last step may hold fewer), one (C, C) matrix a chunk kept
     between them."""
     f32 = jnp.float32
-    return _chunk_local_kernels(
+    rule = _kda_local_kernels if log_alpha.ndim == q.ndim \
+        else _chunk_local_kernels
+    return rule(
         q.astype(f32), k.astype(f32), v.astype(f32),
         log_alpha.astype(f32), beta.astype(f32), interpret,
         None if dot_dtype is None else jnp.dtype(dot_dtype), block)
@@ -579,7 +620,9 @@ def _state_scan_plain(w, k_hat, u, decay, dot_dtype):
     def step(s, chunk):
         w_n, k_n, u_n, d_n = chunk
         v_new = u_n - _mm(w_n, s, dot_dtype)
-        s_next = d_n[:, None, None] * s + _mm(
+        # one number a head, or one per key channel: S's rows
+        kept = d_n[:, None, None] if d_n.ndim == 1 else d_n[:, :, None]
+        s_next = kept * s + _mm(
             jnp.swapaxes(k_n, -1, -2), v_new, dot_dtype)
         return s_next, (v_new, s)
 
@@ -716,12 +759,427 @@ def state_scan(w, k_hat, u, decay, kernel: bool = False,
                interpret: bool = False, dot_dtype=None):
     """(*) of the module docstring over a head's chunks: W, K̂
     (G, N, C, d_k), U (G, N, C, d_v), decay (G, N) → V′ (G, N, C, d_v)
-    and every chunk's starting state S_n (G, N, d_k, d_v), f32."""
+    and every chunk's starting state S_n (G, N, d_k, d_v), f32.  A
+    ``decay`` per key channel, (G, N, d_k), scales S's ROWS
+    (``znicz_kda_state_fwd`` / ``_bwd``, which write S_n at
+    ``dot_dtype``)."""
     if kernel:
-        return _state_scan_kernels(
+        rule = _kda_scan_kernels if decay.ndim == 3 \
+            else _state_scan_kernels
+        return rule(
             *(a.astype(jnp.float32) for a in (w, k_hat, u, decay)),
             interpret, dot_dtype)
     return _state_scan_plain(w, k_hat, u, decay, dot_dtype)
+
+
+# ----------------------------------------------------------------------
+# a decay per key channel (Kimi Delta Attention, arXiv:2510.26692)
+# ----------------------------------------------------------------------
+#: positions per sub-block of a chunk under a per-channel decay: Γ is
+#: then INSIDE the contraction, Σ_d K_id K_jd e^(c_id − c_jd), which two
+#: (C, d_k) factors give only by exponentiating c_i − r and r − c_j
+#: apart, r a reference point.  A row's sub-block takes the prefix at
+#: its own start as r: against every EARLIER sub-block both exponents
+#: are ≤ 0; inside the sub-block r − c_j ≤ SUB_BLOCK · |lower bound|.
+SUB_BLOCK = 16
+#: the largest such exponent admitted: a product of the two factors
+#: summed over d_k ≤ 128 channels stays under the f32 maximum
+#: (log 3.4e38 = 88.7; 80 + log 128 = 84.9) — the ONE place the bound
+#: is stated; ``GatedDeltaNet.initialize`` refuses a ``lower_bound``
+#: whose SUB_BLOCK-fold passes it
+MAX_EXPONENT = 80.0
+
+
+def sub_block(chunk: int) -> int:
+    return min(SUB_BLOCK, chunk)
+
+
+def _chunk_local_channels(q, k, v, log_alpha, beta, dot_dtype=None):
+    """:func:`chunk_local` for log α (G, N, C, d_k): Γ_ijd =
+    exp(c_id − c_jd) written out as a (C, C, d_k) array — plain, and
+    as large as that reads; the kernels' oracle and the path off a TPU
+    (``decay`` comes back (G, N, d_k))."""
+    chunk = q.shape[-2]
+    log_alpha = log_alpha.astype(jnp.float32)
+    at = np.arange(chunk)
+    rows, cols = at[:, None], at[None, :]
+    # [i, j, m]: j < m ≤ i — every sum from its own terms
+    between = ((at[None, None, :] <= at[:, None, None])
+               & (at[None, :, None] < at[None, None, :])).astype(np.float32)
+    gamma = jnp.exp(jnp.where(
+        (rows >= cols)[..., None],
+        jnp.einsum("ijm,...md->...ijd", between, log_alpha,
+                   precision=_HIGHEST), -jnp.inf))
+    kk = jnp.einsum("...id,...jd,...ijd->...ij", k, k, gamma,
+                    precision=_HIGHEST)
+    lower = jnp.where(rows > cols, beta[..., :, None] * kk, 0.0)
+    a = unit_lower_inverse(lower) * beta[..., None, :]
+    c = jnp.einsum("im,...md->...id", (rows >= cols).astype(np.float32),
+                   log_alpha, precision=_HIGHEST)
+    after = jnp.einsum("im,...md->...id", (rows < cols).astype(np.float32),
+                       log_alpha, precision=_HIGHEST)
+    grown = jnp.exp(c)
+    w = _mm(a, grown * k, dot_dtype)
+    u = _mm(a, v, dot_dtype)
+    k_hat = k * jnp.exp(after)
+    # P exact here: the kernels round its two factors to ``dot_dtype``
+    p = jnp.einsum("...id,...jd,...ijd->...ij", q, k, gamma,
+                   precision=_HIGHEST)
+    return w, k_hat, u, jnp.exp(c[..., -1, :]), q * grown, p
+
+
+def _kda_positions(c: int, sub: int):
+    """0/1 (C, C) matrices of a chunk of ``sub``-blocks, from the
+    indices: on and below the diagonal, strictly below, strictly above,
+    on it; ``within`` [i, m]: m ≤ i in i's own sub-block (c̃ = within·g,
+    a row's prefix from its sub-block's start); and per sub-block A the
+    signed ``reach`` [j, m]: +1 where j < m < A's start (r_A − c_j for an
+    earlier row), −1 where A's start ≤ m ≤ j inside A (−c̃_j), rows past
+    A zero."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    shift = sub.bit_length() - 1
+    upto, below, above, eye = (
+        _ones_where(col <= row), _ones_where(col < row),
+        _ones_where(col > row), _ones_where(col == row))
+    within = _ones_where((col <= row) & (row >> shift == col >> shift))
+    reach = []
+    for a in range(c // sub):
+        start = a * sub
+        reach.append(
+            _ones_where((col > row) & (col < start))
+            - _ones_where((col >= start) & (col <= row)
+                          & (row < start + sub)))
+    return upto, below, above, eye, within, tuple(reach)
+
+
+def _kda_factors(positions, q, k, log_alpha, sub):
+    """The two-factor form of Γ inside the contraction: the left
+    factors K ⊙ e^c̃ and Q ⊙ e^c̃ (every exponent ≤ 0), and per
+    sub-block A the right factor's scale e^(r_A − c_j) (≤ 1 for the
+    rows before A, ≤ e^MAX_EXPONENT inside it)."""
+    *_, within, reach = positions
+    near = jnp.exp(_exact(within, log_alpha))
+    scales = [jnp.exp(_exact(r, log_alpha)) for r in reach]
+    return near, k * near, q * near, scales
+
+
+def _kda_products(left, rights, sub, dot):
+    """(C, C): rows of sub-block A = left[A] · rights[A]ᵀ."""
+    return jnp.concatenate(
+        [dot(left[a * sub:(a + 1) * sub], right, trans_b=True)
+         for a, right in enumerate(rights)], axis=0)
+
+
+def _kda_chunk_lower(positions, log_alpha, beta, q, k, *, sub):
+    """A chunk's L = strict_lower(diag(β) M), and what the outputs need
+    of the decays: e^c, e^(c_C − c), e^(c_C), Q's left factor and K's
+    right factors (P is the same two-factor product as M)."""
+    upto, below, above, eye, _, _ = positions
+    _, left_k, left_q, scales = _kda_factors(positions, q, k, log_alpha,
+                                             sub)
+    rights = [k * e for e in scales]
+    m = _kda_products(left_k, rights, sub, _exact)
+    lower = below * (_rows(eye * beta) * m)
+    grown = jnp.exp(_exact(upto, log_alpha))
+    rest = jnp.exp(_exact(above, log_alpha))
+    decay = jnp.exp(_cols(log_alpha))
+    return lower, grown, rest, decay, left_q, rights
+
+
+_kda_lower = jax.jit(_kda_chunk_lower, static_argnames=("sub",))
+
+
+@functools.partial(jax.jit, static_argnames=("dot_dtype", "sub"))
+def _kda_outputs(upto, q, k, v, beta, x, grown, rest, left_q, rights, *,
+                 dot_dtype, sub):
+    """W, K̂, U, Qc, P from a chunk's rows, decays, Γ's factors and
+    (I + L)⁻¹."""
+    mixed = _mixed(dot_dtype)
+    p = upto * _kda_products(left_q, rights, sub, mixed)
+    a = x * beta
+    return mixed(a, grown * k), rest * k, mixed(a, v), grown * q, p
+
+
+def _kda_fwd_kernel(q_ref, k_ref, v_ref, a_ref, b_ref, w_ref, kh_ref,
+                    u_ref, d_ref, qc_ref, p_ref, x_ref, *, dot_dtype,
+                    together, side_by_side, sub):
+    block, c = q_ref.shape[0], q_ref.shape[1]
+    positions = _kda_positions(c, sub)
+    levels = _inverse_levels(c, side_by_side)
+
+    def some(first):
+        at = [first + m for m in range(side_by_side)]
+        held = [_kda_lower(positions, a_ref[i], b_ref[i], q_ref[i],
+                           k_ref[i], sub=sub) for i in at]
+        inverses = _inverses([lower for lower, *_ in held], levels)
+        for i, (_, grown, rest, decay, left_q, rights), x in zip(
+                at, held, inverses):
+            x_ref[i] = x
+            w_ref[i], kh_ref[i], u_ref[i], qc_ref[i], p_ref[i] = \
+                _kda_outputs(positions[0], q_ref[i], k_ref[i], v_ref[i],
+                             b_ref[i], x, grown, rest, left_q, rights,
+                             dot_dtype=dot_dtype, sub=sub)
+            d_ref[i] = decay
+
+    _over_chunks(block, together, side_by_side, some)
+
+
+@functools.partial(jax.jit, static_argnames=("dot_dtype", "sub"))
+def _kda_cotangents(positions, q, k, v, log_alpha, beta, x, d_w, d_kh,
+                    d_u, d_decay, d_qc, d_p, *, dot_dtype, sub):
+    """Cotangents of a chunk's q, k, v, log α (C, d_k), β from those of
+    its W, K̂, U, decay (1, d_k), Qc and P, and its (I + L)⁻¹."""
+    upto, below, above, eye, within, reach = positions
+    exact, mixed = _exact, _mixed(dot_dtype)
+    near, left_k, left_q, scales = _kda_factors(positions, q, k,
+                                                log_alpha, sub)
+    rights = [k * e for e in scales]
+    m = _kda_products(left_k, rights, sub, exact)
+    grown = jnp.exp(exact(upto, log_alpha))
+    rest = jnp.exp(exact(above, log_alpha))
+    decay = jnp.exp(_cols(log_alpha))
+    beta_col = _rows(eye * beta)
+    a = x * beta
+    # W = A (exp(c) ⊙ K), U = A V
+    d_a = mixed(d_w, grown * k, trans_b=True) \
+        + mixed(d_u, v, trans_b=True)
+    d_kg = mixed(a, d_w, trans_a=True)
+    d_v = mixed(a, d_u, trans_a=True)
+    # d(M⁻¹) = −M⁻ᵀ dM M⁻ᵀ, on the strictly lower part; L = β_i M_ij
+    d_lower = -below * exact(exact(x, d_a * beta, trans_a=True), x,
+                             trans_b=True)
+    d_m = d_lower * beta_col
+    d_pl = upto * d_p
+    d_beta = _cols(d_a * x) + _cols(eye * _rows(d_lower * m))
+    # M and P by sub-blocks: rows A = left[A] · (K ⊙ scale_A)ᵀ
+    d_k = grown * d_kg + rest * d_kh
+    d_alpha = exact(upto, grown * (d_qc * q + d_kg * k), trans_a=True) \
+        + exact(above, rest * d_kh * k, trans_a=True) + d_decay * decay
+    d_left_k, d_left_q = [], []
+    for at, (right, scale, signed) in enumerate(zip(rights, scales,
+                                                    reach)):
+        rows = slice(at * sub, (at + 1) * sub)
+        d_left_k.append(exact(d_m[rows], right))
+        d_left_q.append(mixed(d_pl[rows], right))
+        d_right = exact(d_m[rows], left_k[rows], trans_a=True) \
+            + mixed(d_pl[rows], left_q[rows], trans_a=True)
+        d_k = d_k + d_right * scale
+        d_alpha = d_alpha + exact(signed, d_right * right, trans_a=True)
+    d_left_k = jnp.concatenate(d_left_k, axis=0)
+    d_left_q = jnp.concatenate(d_left_q, axis=0)
+    d_k = d_k + d_left_k * near
+    d_q = d_left_q * near + grown * d_qc
+    d_alpha = d_alpha + exact(
+        within, d_left_k * left_k + d_left_q * left_q, trans_a=True)
+    return d_q, d_k, d_v, d_alpha, d_beta
+
+
+def _kda_bwd_kernel(*refs, dot_dtype, together, sub):
+    ins, outs = refs[:12], refs[12:]
+    block, c = ins[0].shape[0], ins[0].shape[1]
+    positions = _kda_positions(c, sub)
+
+    def one(i):
+        results = _kda_cotangents(
+            positions, *(ref[i] for ref in ins), dot_dtype=dot_dtype,
+            sub=sub)
+        for ref, result in zip(outs, results):
+            ref[i] = result
+
+    _over_chunks(block, together, 1, one)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
+def _kda_forward_call(q, k, v, log_alpha, beta, interpret, dot_dtype,
+                      block):
+    g, n, c, dk = q.shape
+    dv, block = v.shape[-1], min(block, g * n)
+    side_by_side = 2 if block % 2 == 0 and 2 * c <= _LANES else 1
+    w, k_hat, u, decay, qc, p, x = _chunk_call(
+        functools.partial(
+            _kda_fwd_kernel, dot_dtype=dot_dtype,
+            together=max(math.gcd(block, _TOGETHER), side_by_side),
+            side_by_side=side_by_side, sub=sub_block(c)),
+        "znicz_kda_chunk_fwd",
+        [_flat(a, c) for a in (q, k, v, log_alpha, beta)],
+        [(c, dk), (c, dk), (c, dv), (1, dk), (c, dk), (c, c), (c, c)],
+        block, interpret)
+
+    def heads(a):
+        return a.reshape((g, n) + a.shape[1:])
+
+    return (heads(w), heads(k_hat), heads(u), decay.reshape(g, n, dk),
+            heads(qc), heads(p)), heads(x)
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9))
+def _kda_backward_call(q, k, v, log_alpha, beta, x, cotangent,
+                       interpret, dot_dtype, block):
+    g, n, c, dk = q.shape
+    dv, block = v.shape[-1], min(block, g * n)
+    d_w, d_kh, d_u, d_decay, d_qc, d_p = cotangent
+    d_q, d_k, d_v, d_a, d_b = _chunk_call(
+        functools.partial(_kda_bwd_kernel, dot_dtype=dot_dtype,
+                          together=math.gcd(block, _TOGETHER),
+                          sub=sub_block(c)),
+        "znicz_kda_chunk_bwd",
+        [_flat(a, c) for a in (q, k, v, log_alpha, beta, x, d_w, d_kh,
+                               d_u)]
+        + [d_decay.reshape(g * n, 1, dk)]
+        + [_flat(a, c) for a in (d_qc, d_p)],
+        [(c, dk), (c, dk), (c, dv), (c, dk), (1, c)], block, interpret)
+    return (d_q.reshape(q.shape), d_k.reshape(k.shape),
+            d_v.reshape(v.shape), d_a.reshape(log_alpha.shape),
+            d_b.reshape(g, n, c))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _kda_local_kernels(q, k, v, log_alpha, beta, interpret, dot_dtype,
+                       block):
+    return _kda_forward_call(q, k, v, log_alpha, beta, interpret,
+                             dot_dtype, block)[0]
+
+
+def _kda_fwd(q, k, v, log_alpha, beta, interpret, dot_dtype, block):
+    out, x = _kda_forward_call(q, k, v, log_alpha, beta, interpret,
+                               dot_dtype, block)
+    # V enters the backward as a ``dot_dtype`` matmul input only (dA =
+    # … + dU Vᵀ): kept at that width, as the walk keeps W, K̂ and V′
+    kept = v if dot_dtype is None else v.astype(dot_dtype)
+    return out, (q, k, kept, log_alpha, beta, x)
+
+
+def _kda_bwd(interpret, dot_dtype, block, residual, cotangent):
+    return _kda_backward_call(*residual, cotangent, interpret,
+                              dot_dtype, block)
+
+
+_kda_local_kernels.defvjp(_kda_fwd, _kda_bwd)
+
+
+def _column(row):
+    """(1, d) → (d, 1) without a transpose."""
+    d = row.shape[1]
+    eye = _ones_where(jax.lax.broadcasted_iota(jnp.int32, (d, d), 0)
+                      == jax.lax.broadcasted_iota(jnp.int32, (d, d), 1))
+    return _rows(eye * row), eye
+
+
+def _kda_state_fwd_kernel(w_ref, k_ref, u_ref, d_ref, v_ref, s_ref,
+                          state, *, dot_dtype):
+    """:func:`_fwd_kernel` with the chunk's decay a number per key
+    channel: diag(e^(c_C)) S_n scales S's rows."""
+    @pl.when(pl.program_id(1) == 0)
+    def _start():
+        state[...] = jnp.zeros_like(state)
+
+    s = state[...]
+    s_ref[...] = s.astype(s_ref.dtype)
+    v_new = u_ref[...] - _dot(w_ref[...], s, dot_dtype)
+    v_ref[...] = v_new
+    state[...] = _column(d_ref[...])[0] * s + _dot(
+        k_ref[...], v_new, dot_dtype, trans_a=True)
+
+
+def _kda_state_bwd_kernel(w_ref, k_ref, d_ref, s_ref, v_ref, dv_ref,
+                          ds_ref, dw_ref, dk_ref, du_ref, dd_ref, carry,
+                          *, dot_dtype):
+    @pl.when(pl.program_id(1) == 0)
+    def _start():
+        carry[...] = jnp.zeros_like(carry)
+
+    g, s = carry[...], s_ref[...].astype(jnp.float32)
+    kept, eye = _column(d_ref[...])
+    dv = dv_ref[...] + _dot(k_ref[...], g, dot_dtype)
+    du_ref[...] = dv
+    dk_ref[...] = _dot(v_ref[...], g, dot_dtype, trans_b=True)
+    dw_ref[...] = -_dot(dv, s, dot_dtype, trans_b=True)
+    dd_ref[...] = _cols(eye * _rows(g * s))       # (d_k, 1) as a row
+    carry[...] = ds_ref[...] + kept * g - _dot(
+        w_ref[...], dv, dot_dtype, trans_a=True)
+
+
+def _kda_state_forward(w, k_hat, u, decay, interpret, dot_dtype):
+    g, n, c, dk = w.shape
+    dv = u.shape[-1]
+
+    def first(i):
+        return i
+
+    return pl.pallas_call(
+        functools.partial(_kda_state_fwd_kernel, dot_dtype=dot_dtype),
+        grid=(g, n),
+        in_specs=[_face(c, dk, first), _face(c, dk, first),
+                  _face(c, dv, first), _face(1, dk, first)],
+        out_specs=(_face(c, dv, first), _face(dk, dv, first)),
+        out_shape=(jax.ShapeDtypeStruct((g, n, c, dv), jnp.float32),
+                   jax.ShapeDtypeStruct((g, n, dk, dv),
+                                        dot_dtype or jnp.float32)),
+        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        compiler_params=_PARAMS, interpret=interpret,
+        name="znicz_kda_state_fwd",
+    )(w, k_hat, u, decay[:, :, None, :])
+
+
+def _kda_state_backward(w, k_hat, decay, states, v_new, d_v, d_s,
+                        interpret, dot_dtype):
+    g, n, c, dk = w.shape
+    dv = v_new.shape[-1]
+
+    def back(i):
+        return n - 1 - i
+
+    f32 = jnp.float32
+    dw, dk_hat, du, dd = pl.pallas_call(
+        functools.partial(_kda_state_bwd_kernel, dot_dtype=dot_dtype),
+        grid=(g, n),
+        in_specs=[_face(c, dk, back), _face(c, dk, back),
+                  _face(1, dk, back), _face(dk, dv, back),
+                  _face(c, dv, back), _face(c, dv, back),
+                  _face(dk, dv, back)],
+        out_specs=(_face(c, dk, back), _face(c, dk, back),
+                   _face(c, dv, back), _face(1, dk, back)),
+        out_shape=(jax.ShapeDtypeStruct((g, n, c, dk), f32),
+                   jax.ShapeDtypeStruct((g, n, c, dk), f32),
+                   jax.ShapeDtypeStruct((g, n, c, dv), f32),
+                   jax.ShapeDtypeStruct((g, n, 1, dk), f32)),
+        scratch_shapes=[pltpu.VMEM((dk, dv), f32)],
+        compiler_params=_PARAMS, interpret=interpret,
+        name="znicz_kda_state_bwd",
+    )(w, k_hat, decay[:, :, None, :], states, v_new, d_v, d_s)
+    return dw, dk_hat, du, dd[:, :, 0, :]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _kda_scan_kernels(w, k_hat, u, decay, interpret, dot_dtype):
+    return _kda_state_forward(w, k_hat, u, decay, interpret, dot_dtype)
+
+
+def _kda_scan_fwd(w, k_hat, u, decay, interpret, dot_dtype):
+    v_new, states = _kda_state_forward(w, k_hat, u, decay, interpret,
+                                       dot_dtype)
+    # kept for the backward at the width its products take them in: W,
+    # K̂ and V′ enter the reverse walk as ``dot_dtype`` matmul inputs
+    # only, so a bf16 copy gives the same numbers at half the bytes
+    # (the f32 arrays die with the forward).  The per-chunk states are
+    # WRITTEN at that width (the carried state stays f32 in VMEM): O's
+    # product and dW take them as ``dot_dtype`` inputs, and only the
+    # decay's cotangent Σ g ⊙ S reads the rounded copy elementwise
+    kept = (lambda a: a) if dot_dtype is None \
+        else (lambda a: a.astype(dot_dtype))
+    return (v_new, states), (kept(w), kept(k_hat), decay, states,
+                             kept(v_new))
+
+
+def _kda_scan_bwd(interpret, dot_dtype, residual, cotangent):
+    w, k_hat, decay, states, v_new = residual
+    d_v, d_s = cotangent
+    return _kda_state_backward(w, k_hat, decay, states, v_new, d_v, d_s,
+                               interpret, dot_dtype)
+
+
+_kda_scan_kernels.defvjp(_kda_scan_fwd, _kda_scan_bwd)
 
 
 # ----------------------------------------------------------------------
@@ -732,7 +1190,8 @@ def gated_delta_rule(q, k, v, log_alpha, beta, chunk: int = CHUNK,
                      dot_dtype=None):
     """o (B, T, H, d_v) of the recurrence in its chunked form for
     q, k (B, T, H, d_k), v (B, T, H, d_v), log α ≤ 0 and β (B, T, H);
-    T a multiple of ``chunk``."""
+    T a multiple of ``chunk``.  log α (B, T, H, d_k) is a decay per key
+    channel: its shape picks the body (module docstring)."""
     b, t, h, _ = q.shape
     if t % chunk:
         raise ValueError(f"gated_delta_rule: {t} positions are not "

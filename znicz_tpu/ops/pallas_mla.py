@@ -1,0 +1,329 @@
+"""Causal flash attention over keys of TWO widths (multi-head latent
+attention as it trains, DeepSeek-V2, arXiv:2405.04434 §2.1;
+Ling-3.0-flash's full layers, PR 37): a head's score is the sum of two
+products,
+
+.. code-block:: text
+
+    s_h = q_nope,h · k_nope,h  +  q_rope,h · k_r        o_h = softmax(s_h) v_h
+
+the per-head part (``qk_nope`` wide, from the latent) and the rotary
+part, whose key ``k_r`` is ONE for all heads.  Nothing of shape
+(B, T, H, qk_nope + qk_rope) exists on either pass: the kernels read
+q_nope, k_nope and v as 128-lane column blocks of (B, T, H·128) arrays
+— the projections' own layout, as ``ops/pallas_attention.py`` reads its
+operands since PR 28 —, q_rope as (B, T, H·64), and k_r as (B, T, 64);
+values are not padded to the keys' width.
+
+A grid step computes a PAIR of heads, so that the pair's two 64-wide
+rotary queries are one 128-lane block.  The shared key comes in twice,
+``[k_r | 0]`` and ``[0 | k_r]`` (T × 256 numbers, made outside): the
+pair's rotary block times the first is the even head's rotary score,
+times the second the odd head's — a contraction over 128 lanes, the
+MXU's own depth, and no lane is sliced.  The same two operands turn
+``ds`` into the pair's ``dq_rope`` block in one sum.
+
+Three kernels, all ``name=``\\ d for the trace: ``znicz_flash_fwd_mla``
+(online softmax over K tiles, the tiles above the diagonal neither
+computed nor fetched), and a two-pass backward that recomputes a score
+tile in each — ``znicz_flash_bwd_mla_dq`` (dq_nope, dq_rope) and
+``znicz_flash_bwd_mla_dkv`` (dk_nope, dv, and dk_r per head pair:
+dk_r is the sum over ALL heads, accumulated over a pair's Q tiles in
+VMEM as dk, dv are over a group in ``pallas_attention``, and over the
+pairs by one small sum outside).  The caller scales q by
+(qk_nope + qk_rope)^(−1/2) before the cast; softmax statistics and
+every accumulator are f32; the products take the operands' dtype (bf16
+in mixed precision) with f32 accumulation.
+
+The kernels are simple on purpose: one (512, 512) tile a visit, the
+diagonal's mask applied to every visited tile.  One layer in six of
+Ling-3.0-flash is of this kind; what the visit of PR 36 would save
+here is PERF.md §7's to weigh.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+#: positions per Q and K tile
+BLOCK = 512
+_LANES = 128
+_NEG = -1e30
+#: grid (batch, head pair, resident tile, passing tile)
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
+
+
+def kernel_legal(t: int, n_heads: int, qk_nope: int, qk_rope: int,
+                 v_dim: int) -> bool:
+    """Whether the kernels tile the call: a head's per-head key and its
+    value are whole 128-lane blocks, a pair of rotary queries is one,
+    and T is whole tiles."""
+    block = min(BLOCK, t)
+    return (qk_nope == _LANES and v_dim == _LANES
+            and 2 * qk_rope == _LANES and n_heads % 2 == 0
+            and t % block == 0 and block % 8 == 0)
+
+
+def _dot(a, b, trans_a=False, trans_b=False):
+    dims = (((0,) if trans_a else (1,), (1,) if trans_b else (0,)),
+            ((), ()))
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _visible(iq, ik, bq: int, bk: int):
+    rows = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    cols = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    return rows >= cols
+
+
+def _scores(qn_ref, qr_ref, kn_ref, kr_ref, j: int):
+    """Head ``j`` of the pair: its per-head product plus the pair's
+    rotary block against the shared key in that head's lanes."""
+    lanes = slice(j * _LANES, (j + 1) * _LANES)
+    return _dot(qn_ref[:, lanes], kn_ref[:, lanes], trans_b=True) \
+        + _dot(qr_ref[...], kr_ref[:, lanes], trans_b=True)
+
+
+def _fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
+                m_ref, l_ref, acc_ref):
+    iq, ik = pl.program_id(2), pl.program_id(3)
+    bq, bk = qn_ref.shape[0], kn_ref.shape[0]
+
+    @pl.when(ik == 0)
+    def _start():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(ik <= iq)
+    def _visit():
+        seen = _visible(iq, ik, bq, bk)
+        for j in range(2):
+            lanes = slice(j * _LANES, (j + 1) * _LANES)
+            s = jnp.where(seen, _scores(qn_ref, qr_ref, kn_ref, kr_ref, j),
+                          _NEG)
+            m_prev = m_ref[:, lanes]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new[:, :1])
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[:, lanes] = alpha * l_ref[:, lanes] \
+                + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[:, lanes] = alpha * acc_ref[:, lanes] + _dot(
+                p.astype(v_ref.dtype), v_ref[:, lanes])
+            m_ref[:, lanes] = m_new
+
+    @pl.when(ik == iq)
+    def _leave():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        lse_ref[...] = m_ref[...] + jnp.log(l_ref[...])
+
+
+def _p_and_ds(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, do_ref,
+              lse_ref, seen, j: int):
+    """A head's probabilities and score cotangents on one tile."""
+    lanes = slice(j * _LANES, (j + 1) * _LANES)
+    s = _scores(qn_ref, qr_ref, kn_ref, kr_ref, j)
+    p = jnp.where(seen, jnp.exp(s - lse_ref[:, lanes][:, :1]), 0.0)
+    do = do_ref[:, lanes]
+    delta = jnp.sum(do.astype(jnp.float32)
+                    * o_ref[:, lanes].astype(jnp.float32),
+                    axis=1, keepdims=True)
+    dp = _dot(do, v_ref[:, lanes], trans_b=True)
+    return p, p * (dp - delta)
+
+
+def _dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, do_ref,
+               lse_ref, dqn_ref, dqr_ref, dqn_acc, dqr_acc):
+    iq, ik = pl.program_id(2), pl.program_id(3)
+    bq, bk = qn_ref.shape[0], kn_ref.shape[0]
+
+    @pl.when(ik == 0)
+    def _start():
+        dqn_acc[...] = jnp.zeros_like(dqn_acc)
+        dqr_acc[...] = jnp.zeros_like(dqr_acc)
+
+    @pl.when(ik <= iq)
+    def _visit():
+        seen = _visible(iq, ik, bq, bk)
+        for j in range(2):
+            lanes = slice(j * _LANES, (j + 1) * _LANES)
+            _, ds = _p_and_ds(qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
+                              o_ref, do_ref, lse_ref, seen, j)
+            ds = ds.astype(kn_ref.dtype)
+            dqn_acc[:, lanes] += _dot(ds, kn_ref[:, lanes])
+            # [ds·k_r | 0] for the even head, [0 | ds·k_r] for the odd
+            dqr_acc[...] += _dot(ds, kr_ref[:, lanes])
+
+    @pl.when(ik == iq)
+    def _leave():
+        dqn_ref[...] = dqn_acc[...]
+        dqr_ref[...] = dqr_acc[...]
+
+
+def _dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, do_ref,
+                lse_ref, dkn_ref, dv_ref, dkr_ref, dkn_acc, dv_acc,
+                dkr_acc):
+    ik, iq = pl.program_id(2), pl.program_id(3)
+    bq, bk = qn_ref.shape[0], kn_ref.shape[0]
+    half = qr_ref.shape[1] // 2
+
+    @pl.when(iq == 0)
+    def _start():
+        dkn_acc[...] = jnp.zeros_like(dkn_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+        dkr_acc[...] = jnp.zeros_like(dkr_acc)
+
+    @pl.when(iq >= ik)
+    def _visit():
+        seen = _visible(iq, ik, bq, bk)
+        lane = jax.lax.broadcasted_iota(jnp.int32, qr_ref.shape, 1)
+        for j in range(2):
+            lanes = slice(j * _LANES, (j + 1) * _LANES)
+            p, ds = _p_and_ds(qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
+                              o_ref, do_ref, lse_ref, seen, j)
+            ds = ds.astype(qn_ref.dtype)
+            dv_acc[:, lanes] += _dot(p.astype(do_ref.dtype),
+                                     do_ref[:, lanes], trans_a=True)
+            dkn_acc[:, lanes] += _dot(ds, qn_ref[:, lanes], trans_a=True)
+            # the head's own rotary lanes only: the pair's two halves
+            # are the even and the odd heads' sums, folded outside
+            own = (lane >= j * half) & (lane < (j + 1) * half)
+            dkr_acc[...] += _dot(
+                ds, jnp.where(own, qr_ref[...], 0).astype(qr_ref.dtype),
+                trans_a=True)
+
+    @pl.when(iq == pl.num_programs(3) - 1)
+    def _leave():
+        dkn_ref[...] = dkn_acc[...]
+        dv_ref[...] = dv_acc[...]
+        dkr_ref[...] = dkr_acc[...]
+
+
+def _specs(bq: int, bk: int, q_at, k_at):
+    """Blocks of a pair of heads, column block ``p`` of each per-head
+    array: the two block makers, a pair's width, and the specs of the
+    operands in the kernels' order — q_nope, q_rope, k_nope, k_r twice,
+    v (the forward's five), then o, do, lse (the backward's eight)."""
+    pair = 2 * _LANES
+
+    def q_side(width):
+        return pl.BlockSpec((None, bq, width),
+                            lambda b, p, i, j: (b, q_at(i, j), p))
+
+    def k_side(width, shared=False):
+        return pl.BlockSpec(
+            (None, bk, width),
+            lambda b, p, i, j: (b, k_at(i, j), 0 if shared else p))
+
+    operands = [q_side(pair), q_side(_LANES), k_side(pair),
+                k_side(pair, shared=True), k_side(pair), q_side(pair),
+                q_side(pair), q_side(pair)]
+    return q_side, k_side, pair, operands
+
+
+def _twice(kr):
+    """(B, T, r) → (B, T, 4r) = [k_r | 0 | 0 | k_r]."""
+    zero = jnp.zeros_like(kr)
+    return jnp.concatenate([kr, zero, zero, kr], axis=-1)
+
+
+@functools.partial(jax.jit, static_argnums=(5,))
+def _forward(qn, qr, kn, kr, v, interpret):
+    b, t, wide = qn.shape
+    pairs = wide // (2 * _LANES)
+    bq = bk = min(BLOCK, t)
+    steps = t // bq
+    q_side, _, pair, ins = _specs(
+        bq, bk, lambda i, j: i, lambda i, j: jnp.minimum(i, j))
+    return pl.pallas_call(
+        _fwd_kernel, grid=(b, pairs, steps, steps), in_specs=ins[:5],
+        out_specs=(q_side(pair), q_side(pair)),
+        out_shape=(jax.ShapeDtypeStruct((b, t, wide), qn.dtype),
+                   jax.ShapeDtypeStruct((b, t, wide), jnp.float32)),
+        scratch_shapes=[pltpu.VMEM((bq, pair), jnp.float32)] * 3,
+        compiler_params=_PARAMS,
+        interpret=interpret, name="znicz_flash_fwd_mla",
+    )(qn, qr, kn, _twice(kr), v)
+
+
+@functools.partial(jax.jit, static_argnums=(8,))
+def _backward(qn, qr, kn, kr, v, o, lse, do, interpret):
+    b, t, wide = qn.shape
+    pairs = wide // (2 * _LANES)
+    bq = bk = min(BLOCK, t)
+    steps = t // bq
+    f32 = jnp.float32
+    kr2 = _twice(kr)
+    # dq: a Q tile stays, the K tiles up to the diagonal pass
+    q_side, k_side, pair, ins = _specs(
+        bq, bk, lambda i, j: i, lambda i, j: jnp.minimum(i, j))
+    dqn, dqr = pl.pallas_call(
+        _dq_kernel, grid=(b, pairs, steps, steps), in_specs=ins,
+        out_specs=(q_side(pair), q_side(_LANES)),
+        out_shape=(jax.ShapeDtypeStruct((b, t, wide), f32),
+                   jax.ShapeDtypeStruct((b, t, pairs * _LANES), f32)),
+        scratch_shapes=[pltpu.VMEM((bq, pair), f32),
+                        pltpu.VMEM((bq, _LANES), f32)],
+        compiler_params=_PARAMS, interpret=interpret,
+        name="znicz_flash_bwd_mla_dq",
+    )(qn, qr, kn, kr2, v, o, do, lse)
+    # dk, dv: a K tile stays (grid axis 2), the Q tiles from the
+    # diagonal down pass (axis 3)
+    q_side, k_side, pair, ins = _specs(
+        bq, bk, lambda i, j: jnp.maximum(i, j), lambda i, j: i)
+    dkn, dv, dkr = pl.pallas_call(
+        _dkv_kernel, grid=(b, pairs, steps, steps), in_specs=ins,
+        out_specs=(k_side(pair), k_side(pair),
+                   pl.BlockSpec((None, None, bk, _LANES),
+                                lambda b_, p, i, j: (b_, p, i, 0))),
+        out_shape=(jax.ShapeDtypeStruct((b, t, wide), f32),
+                   jax.ShapeDtypeStruct((b, t, wide), f32),
+                   jax.ShapeDtypeStruct((b, pairs, t, _LANES), f32)),
+        scratch_shapes=[pltpu.VMEM((bk, pair), f32),
+                        pltpu.VMEM((bk, pair), f32),
+                        pltpu.VMEM((bk, _LANES), f32)],
+        compiler_params=_PARAMS, interpret=interpret,
+        name="znicz_flash_bwd_mla_dkv",
+    )(qn, qr, kn, kr2, v, o, do, lse)
+    # the shared key's cotangent: over the pairs, then the even and
+    # the odd heads' halves
+    dkr = dkr.sum(axis=1)
+    half = dkr.shape[-1] // 2
+    return dqn, dqr, dkn, dkr[..., :half] + dkr[..., half:], dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _attend(qn, qr, kn, kr, v, interpret):
+    return _forward(qn, qr, kn, kr, v, interpret)[0]
+
+
+def _attend_fwd(qn, qr, kn, kr, v, interpret):
+    o, lse = _forward(qn, qr, kn, kr, v, interpret)
+    return o, (qn, qr, kn, kr, v, o, lse)
+
+
+def _attend_bwd(interpret, residual, do):
+    qn, qr, kn, kr, v, o, lse = residual
+    grads = _backward(qn, qr, kn, kr, v, o, lse, do.astype(o.dtype),
+                      interpret)
+    return tuple(g.astype(a.dtype)
+                 for g, a in zip(grads, (qn, qr, kn, kr, v)))
+
+
+_attend.defvjp(_attend_fwd, _attend_bwd)
+
+
+def latent_flash_attention(q_nope, q_rope, k_nope, k_rope, v,
+                           interpret: bool = False):
+    """Causal attention of H heads over two-width keys, rows in the
+    projections' layout: q_nope, k_nope, v (B, T, H·128), q_rope
+    (B, T, H·64) — q already scaled —, k_rope (B, T, 64) shared by all
+    heads → o (B, T, H·128) in the operands' dtype."""
+    return _attend(q_nope, q_rope, k_nope, k_rope, v, interpret)
