@@ -1,10 +1,15 @@
-"""The two kernel families Ling-3.0-flash brought (PR 37), alone at
-the cell's shapes: the delta rule with a decay per key channel
+"""The kernel families of the Ling-3.0-flash cell, alone at the cell's
+shapes: the delta rule with a decay per key channel
 (``znicz_kda_chunk_*`` / ``znicz_kda_state_*``, 32 heads of 128 × 128,
-T 4,096) and the two-width flash calls of the latent attention
-(``znicz_flash_*_mla``, 32 heads, keys 128 + 64 shared, values 128).
+T 4,096), the two-width flash calls of the latent attention
+(``znicz_flash_*_mla``, 32 heads, keys 128 + 64 shared, values 128;
+both PR 37), and what lies between the q ‖ k ‖ v projection and the
+rule (``znicz_qkv_prep_fwd`` / ``_bwd``, PR 40: 4 taps, SiLU, L2 norms
+over (4,096, 12,288) f32, against their bytes and against the plain
+``jax.numpy`` form under autodiff).
 
     chiprun -- python3 benchmarks/ling_probe.py          # check + time
+    chiprun -- python3 benchmarks/ling_probe.py --only prep [--rows 1024 2048 4096]
     python3 benchmarks/ling_probe.py --compile-only      # here: the
         real Mosaic / XLA-TPU compile for a described v5e, no chip
     python3 benchmarks/ling_probe.py --compile-step [--t 8192]   # here:
@@ -25,6 +30,7 @@ import json
 import os
 import sys
 import time
+import types
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -32,10 +38,12 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 import jax                                                  # noqa: E402
 import jax.numpy as jnp                                     # noqa: E402
 
+from znicz_tpu.ops import delta_net                         # noqa: E402
 from znicz_tpu.ops import pallas_delta as pd                # noqa: E402
 from znicz_tpu.ops import pallas_mla                        # noqa: E402
+from znicz_tpu.ops.moe import _silu                         # noqa: E402
 
-H, DK, DV, ROPE = 32, 128, 128, 64
+H, DK, DV, ROPE, TAPS, EPS = 32, 128, 128, 64, 4, 1e-6
 BF16 = jnp.dtype(jnp.bfloat16)
 
 
@@ -54,6 +62,69 @@ def delta_inputs(t: int, heads: int = H):
     beta = jax.random.uniform(keys[4], (1, t, heads))
     weight = jax.random.normal(keys[5], (1, t, heads, DV), jnp.float32)
     return (q, k, v, log_alpha, beta), weight
+
+
+def prep_inputs(t: int, heads: int = H):
+    keys = jax.random.split(jax.random.PRNGKey(2), 5)
+    wide = heads * (2 * DK + DV)
+    rows = (jax.random.normal(keys[0], (1, t, wide), jnp.float32),
+            0.5 * jax.random.normal(keys[1], (wide, TAPS), jnp.float32))
+    return rows, tuple(
+        jax.random.normal(key, (1, heads, t, d), jnp.float32)
+        for key, d in zip(keys[2:], (DK, DK, DV)))
+
+
+def prep_plain(u, taps):
+    """The unit's plain form (``GatedDeltaNet._heads`` of the SiLU of
+    ``causal_conv``), then the move to head-major."""
+    unit = types.SimpleNamespace(
+        n_heads=u.shape[-1] // (2 * DK + DV), key_dim=DK, value_dim=DV,
+        norm_eps=EPS)
+    return tuple(jnp.moveaxis(a, 2, 1)
+                 for a in delta_net.GatedDeltaNet._heads(
+                     unit, jnp, _silu(jnp, delta_net.causal_conv(
+                         jnp, u, taps))))
+
+
+def prep_kernels(rows=None):
+    return lambda u, taps: pd.qkv_prep(
+        u, taps, u.shape[-1] // (2 * DK + DV), DK, DV, EPS, rows=rows)
+
+
+def prep_programs(rule):
+    def both(u, taps, weights):
+        return jax.grad(lambda *a: sum(
+            jnp.sum(out * w) for out, w in zip(rule(*a), weights)),
+            (0, 1))(u, taps)
+    return jax.jit(rule), jax.jit(both)
+
+
+def prep_arm(t: int, row_tiles) -> None:
+    """``znicz_qkv_prep_*`` checked in f32 against the plain form, then
+    timed against their bytes: the forward reads u and writes q, k, v
+    (2 arrays of u's size), the backward reads u and three cotangents
+    and writes du (3)."""
+    rows, weights = prep_inputs(512, 4)
+    want_f, want_b = prep_programs(prep_plain)
+    got_f, got_b = prep_programs(prep_kernels(128))      # four row tiles
+    emit(family="prep", check_t=512, f32_forward_against_plain=worst(
+        got_f(*rows), want_f(*rows)),
+        f32_backward_against_plain=worst(got_b(*rows, weights),
+                                         want_b(*rows, weights)))
+    rows, weights = prep_inputs(t)
+    one = rows[0].size * 4 / 1e9                  # GB an array of u's
+    plain_f, plain_b = prep_programs(prep_plain)
+    emit(family="prep", t=t, form="jax.numpy", gb_an_array=one,
+         forward_ms=timed(plain_f, *rows),
+         backward_ms=timed(plain_b, *rows, weights))
+    for tile in row_tiles:
+        forward, backward = prep_programs(prep_kernels(tile))
+        f_ms, b_ms = timed(forward, *rows), timed(backward, *rows,
+                                                  weights)
+        emit(family="prep", t=t, form="kernels", rows=tile,
+             forward_ms=f_ms, backward_ms=b_ms,
+             forward_gb_s=2 * one / f_ms * 1e3,
+             backward_gb_s=3 * one / b_ms * 1e3)
 
 
 def mla_inputs(t: int, dtype, heads: int = H):
@@ -171,6 +242,11 @@ def main() -> int:
     parser.add_argument("--compile-only", action="store_true")
     parser.add_argument("--compile-step", action="store_true")
     parser.add_argument("--t", type=int, default=4096)
+    parser.add_argument("--only", choices=("kda", "mla", "prep"),
+                        nargs="+", default=("kda", "mla", "prep"))
+    parser.add_argument("--rows", type=int, nargs="+",
+                        default=[pd.PREP_ROWS],
+                        help="row tiles of the prep kernels to time")
     args = parser.parse_args()
     if args.compile_step:
         os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -190,11 +266,16 @@ def main() -> int:
             rows, weight = jax.eval_shape(lambda: delta_inputs(args.t))
             mla_rows, mla_weight = jax.eval_shape(
                 lambda: mla_inputs(args.t, BF16))
-        for name, rule, shaped in (
-                ("kda", kda(BF16), (rows, weight)),
-                ("mla", pallas_mla.latent_flash_attention,
-                 (mla_rows, mla_weight))):
-            forward, both = programs(rule)
+            prep_rows, prep_weights = jax.eval_shape(
+                lambda: prep_inputs(args.t))
+        for name, (forward, both), shaped in (
+                ("kda", programs(kda(BF16)), (rows, weight)),
+                ("mla", programs(pallas_mla.latent_flash_attention),
+                 (mla_rows, mla_weight)),
+                ("prep", prep_programs(prep_kernels()),
+                 (prep_rows, prep_weights))):
+            if name not in args.only:
+                continue
             rows_, weight_ = jax.tree.map(struct, shaped)
             t0 = time.perf_counter()
             forward.lower(*rows_).compile()
@@ -204,29 +285,35 @@ def main() -> int:
                  / 1e9)
         return 0
 
-    # f32 against jax.numpy, at a length the plain forms can hold
-    small, heads = 512, 4
-    rows, weight = delta_inputs(small, heads)
-    with jax.default_matmul_precision("highest"):
-        want = programs(lambda *a: pd.gated_delta_rule(*a))[1](
-            *rows, weight)
-    got = programs(kda(None))[1](*rows, weight)
-    emit(family="kda", check_t=small, f32_against_jax_numpy=worst(
-        got, want))
-    rows, weight = mla_inputs(1024, jnp.float32, heads)
-    with jax.default_matmul_precision("highest"):
-        want = programs(mla_plain)[1](*rows, weight)
-        got = programs(pallas_mla.latent_flash_attention)[1](*rows, weight)
-    emit(family="mla", check_t=1024, f32_against_plain=worst(got, want))
-    # bf16, the cell's shapes
-    rows, weight = delta_inputs(args.t)
-    forward, both = programs(kda(BF16))
-    emit(family="kda", t=args.t, forward_ms=timed(forward, *rows),
-         forward_backward_ms=timed(both, *rows, weight))
-    rows, weight = mla_inputs(args.t, BF16)
-    forward, both = programs(pallas_mla.latent_flash_attention)
-    emit(family="mla", t=args.t, forward_ms=timed(forward, *rows),
-         forward_backward_ms=timed(both, *rows, weight))
+    if "kda" in args.only:
+        # f32 against jax.numpy, at a length the plain form can hold
+        small, heads = 512, 4
+        rows, weight = delta_inputs(small, heads)
+        with jax.default_matmul_precision("highest"):
+            want = programs(lambda *a: pd.gated_delta_rule(*a))[1](
+                *rows, weight)
+        got = programs(kda(None))[1](*rows, weight)
+        emit(family="kda", check_t=small, f32_against_jax_numpy=worst(
+            got, want))
+        # bf16, the cell's shapes
+        rows, weight = delta_inputs(args.t)
+        forward, both = programs(kda(BF16))
+        emit(family="kda", t=args.t, forward_ms=timed(forward, *rows),
+             forward_backward_ms=timed(both, *rows, weight))
+    if "mla" in args.only:
+        rows, weight = mla_inputs(1024, jnp.float32, 4)
+        with jax.default_matmul_precision("highest"):
+            want = programs(mla_plain)[1](*rows, weight)
+            got = programs(pallas_mla.latent_flash_attention)[1](
+                *rows, weight)
+        emit(family="mla", check_t=1024, f32_against_plain=worst(
+            got, want))
+        rows, weight = mla_inputs(args.t, BF16)
+        forward, both = programs(pallas_mla.latent_flash_attention)
+        emit(family="mla", t=args.t, forward_ms=timed(forward, *rows),
+             forward_backward_ms=timed(both, *rows, weight))
+    if "prep" in args.only:
+        prep_arm(args.t, args.rows)
     return 0
 
 

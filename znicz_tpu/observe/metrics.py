@@ -692,7 +692,12 @@ def delta_scan(unit: str, stat: str) -> Gauge:
     ``path``: 1 the ``znicz_delta_state_*`` kernels, 0 the plain
     scan; ``chunk_path``: 1 the ``znicz_gdr_chunk_*`` kernels for what
     is local to a chunk — Γ, the triangular inverse, W, U, K̂, Qc, P —
-    0 ``jax.numpy`` under autodiff; ``decay_channels``: decays a head,
+    0 ``jax.numpy`` under autodiff; ``prep_path``: 1 the
+    ``znicz_qkv_prep_fwd`` / ``_bwd`` kernels from the q ‖ k ‖ v
+    projection where it lies to head-major q, k, v — the taps, the
+    SiLU, the L2 norms; the kernels path AND heads whose d_k and d_v
+    are whole 128-lane tiles —, 0 ``jax.numpy`` and a move;
+    ``decay_channels``: decays a head,
     1 or d_k — with d_k the kernels are ``znicz_kda_chunk_*`` /
     ``znicz_kda_state_*``; ``sub_block``: positions whose decays are
     exponentiated against one reference point, the chunk itself under
@@ -703,7 +708,8 @@ def delta_scan(unit: str, stat: str) -> Gauge:
         "Chunked state scan of a gated-delta-rule layer: chunk length, "
         "chunks walked, state size, the kernels' tile padding, MB of "
         "states kept for the backward, kernels (1) or plain scan (0) "
-        "for the walk and for what is local to a chunk",
+        "for the walk, for what is local to a chunk and for the taps, "
+        "SiLU and norms before them",
         labels=("unit", "stat")).labels(unit=unit, stat=stat)
 
 

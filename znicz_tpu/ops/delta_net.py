@@ -24,17 +24,33 @@ along the sequence instead of a cache of keys.
 
 The recurrence runs in the chunked form of ``ops/pallas_delta.py``
 (chunks of 64 positions; exactly the recurrence in exact arithmetic).
-On a TPU it is four kernels: ``znicz_gdr_chunk_fwd`` / ``_bwd`` compute
-what is local to a chunk (Γ, the triangular inverse, W, U, K̂, Qc, P —
-every (64, 64) matrix in VMEM), ``znicz_delta_state_fwd`` / ``_bwd``
-walk the state from chunk to chunk; elsewhere, or on a mesh, the same
-algebra in ``jax.numpy`` and a ``lax.scan``.  A sequence that is not
-whole chunks is padded with positions that write nothing (β 0, α 1) on
-either path.  Never another formula.
+On a TPU the rule is four kernels: ``znicz_gdr_chunk_fwd`` / ``_bwd``
+compute what is local to a chunk (Γ, the triangular inverse, W, U, K̂,
+Qc, P — every (64, 64) matrix in VMEM), ``znicz_delta_state_fwd`` /
+``_bwd`` walk the state from chunk to chunk; elsewhere, or on a mesh,
+the same algebra in ``jax.numpy`` and a ``lax.scan``.  A sequence that
+is not whole chunks is padded with positions that write nothing (β 0,
+α 1) on either path.  Never another formula.
 
-Precision, the same on both paths: the projections take the unit's
+What lies between the q ‖ k ‖ v projection and the rule — the taps,
+the SiLU, the two L2 norms, d_k^(−1/2) — is two more kernels on that
+path WHERE d_k and d_v are whole 128-lane tiles (PR 40):
+``znicz_qkv_prep_fwd`` reads a head's columns of the projection where
+the matmul wrote them and writes q, k, v head-major (B, H, T, ·) and
+padded to whole chunks, the rows the rule's kernels read, so the rule
+is handed them without a move (log α, β and o keep theirs);
+``znicz_qkv_prep_bwd`` makes c, a and the norms again in VMEM from the
+projection and the taps — all its ``custom_vjp`` keeps — and writes the
+projection's cotangent and the taps'.  Elsewhere (96 × 192, no TPU, a
+mesh) the same lines in ``jax.numpy`` and a ``moveaxis`` a tensor;
+``_resolve_path`` decides once, from what it can see, and the gauge
+``znicz_delta_scan{stat="prep_path"}`` says which.
+
+Precision, the same on every path: the projections take the unit's
 matmul inputs (bf16 in mixed precision) with f32 accumulation; the
-convolution, the norms, the gates, the decay's logarithms and their
+convolution, the SiLU, the norms (in the prep kernels too: u is read
+and du written at the f32 the matmuls hand over and take), the gates,
+the decay's logarithms and their
 sums, Γ, K Kᵀ, the triangular inverse (f32 matmuls at the highest
 precision, in the kernels too) and the state stay f32.  In mixed
 precision the products into W, U, P, V′, O and the state's update take
@@ -166,6 +182,7 @@ class GatedDeltaNet(Forward):
             setattr(self, attr, Vector(name=f"{self.name}.{attr}"))
         self._traced_vjp = None
         self._kernels = False
+        self._prep = False
         self._interpret = False
 
     # -- parameters -----------------------------------------------------
@@ -231,8 +248,10 @@ class GatedDeltaNet(Forward):
     def _resolve_path(self, t: int) -> None:
         """Kernels or ``jax.numpy`` and the plain scan, once per
         ``initialize`` and by one rule for what is local to a chunk
-        (``chunk_path``) and the walk over the chunks (``path``); the
-        gauge ``znicz_delta_scan`` and the info line say which."""
+        (``chunk_path``) and the walk over the chunks (``path``); on
+        that path the taps, the SiLU and the norms are kernels too
+        where a head's columns are whole lane tiles (``prep_path``);
+        the gauge ``znicz_delta_scan`` and the info line say which."""
         from znicz_tpu.ops import pallas_kernels
         from znicz_tpu.utils.config import root
         interpret = bool(root.common.engine.get("pallas_interpret",
@@ -261,6 +280,9 @@ class GatedDeltaNet(Forward):
                     f"{pallas_delta.MAX_EXPONENT:g}: the f32 range")
         self._kernels, self._interpret = refused is None, interpret
         dk, dv, chunk = self.key_dim, self.value_dim, self.chunk
+        # the taps, the SiLU and the norms as kernels too where a head's
+        # columns are whole lane tiles of the projection
+        self._prep = self._kernels and pallas_delta.prep_legal(dk, dv)
         chunks = -(-t // chunk)
         b = self.input.shape[0]
         stats = {
@@ -271,13 +293,14 @@ class GatedDeltaNet(Forward):
             "state_mb": b * self.n_heads * chunks * dk * dv * 4 / 1e6,
             "path": 1.0 if self._kernels else 0.0,
             "chunk_path": 1.0 if self._kernels else 0.0,
+            "prep_path": 1.0 if self._prep else 0.0,
             "decay_channels": self.decay_channels,
             "sub_block": sub if self.decay == "channel" else chunk}
         for stat, value in stats.items():
             _metrics.delta_scan(self.name, stat).set(value)
         self.info(
             "%s: gated delta rule over %d chunks of %d (%d heads, "
-            "d_k %d, d_v %d%s): %s; %.1f MB of per-chunk states kept "
+            "d_k %d, d_v %d%s): %s; %s; %.1f MB of per-chunk states kept "
             "for the backward, tiles hold %.2f x d_k x d_v",
             self.name, chunks, chunk, self.n_heads, dk, dv,
             f", {chunks * chunk - t} positions of padding"
@@ -290,6 +313,11 @@ class GatedDeltaNet(Forward):
              "chunk, znicz_delta_state_fwd / _bwd for the walk")
             + (" (interpreted)" if interpret else "")
             if self._kernels else f"plain scan ({refused})",
+            "znicz_qkv_prep_fwd / _bwd kernels from the projection to "
+            "head-major q, k, v" if self._prep else
+            "taps, SiLU and norms in jax.numpy (" + (
+                f"heads of {dk} x {dv} are not whole 128-lane tiles"
+                if self._kernels else "no kernels") + ")",
             stats["state_mb"], stats["padded_share"])
 
     # -- pure forward ---------------------------------------------------
@@ -347,28 +375,41 @@ class GatedDeltaNet(Forward):
             return self._heads(jnp, _silu(jnp, causal_conv(
                 jnp, projected, taps)))
 
-        if self.decay == "channel":
-            # the backward keeps the PROJECTION and runs the taps, the
-            # SiLU and the two norms again (elementwise: a pass over
-            # 3 · T · H · d values) — under plain autodiff the
-            # convolved and the activated copies are kept (2 · 192 MB a
-            # layer at T 4,096 × 32 heads of 128) and XLA, short of
-            # memory, makes the projection again as a MATMUL
-            heads = jax.checkpoint(heads)
-        q, k, v = heads(
-            self.mxu_dot(jnp, rows, w_qkv).reshape(b, t, -1), w_conv)
+        pad = -t % self.chunk
+        projected = self.mxu_dot(jnp, rows, w_qkv).reshape(b, t, -1)
+        if self._prep:
+            # ONE kernel each way from the projection where it lies to
+            # q, k, v (B, H, T + pad, ·), the rows the rule's kernels
+            # read; its backward keeps the projection and the taps
+            q, k, v = pallas_delta.qkv_prep(
+                projected, w_conv, h, self.key_dim, dv, self.norm_eps,
+                pad=pad, interpret=self._interpret)
+        else:
+            if self.decay == "channel":
+                # the backward keeps the PROJECTION and runs the taps,
+                # the SiLU and the two norms again (elementwise: a pass
+                # over 3 · T · H · d values) — under plain autodiff the
+                # convolved and the activated copies are kept (2 · 192
+                # MB a layer at T 4,096 × 32 heads of 128) and XLA,
+                # short of memory, makes the projection again as a
+                # MATMUL
+                heads = jax.checkpoint(heads)
+            q, k, v = heads(projected, w_conv)
         beta, log_alpha = self._gates(
             jnp, self.mxu_dot(jnp, rows, w_ba).reshape(b, t, -1),
             a_log, bias)
-        pad = -t % self.chunk
         if pad:        # positions that write nothing and decay nothing
-            q, k, v, beta, log_alpha = (
-                jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-                for a in (q, k, v, beta, log_alpha))
+            def padded(*arrays):
+                return (jnp.pad(a, ((0, 0), (0, pad))
+                                + ((0, 0),) * (a.ndim - 2))
+                        for a in arrays)
+            if not self._prep:          # the kernel wrote them padded
+                q, k, v = padded(q, k, v)
+            beta, log_alpha = padded(beta, log_alpha)
         o = pallas_delta.gated_delta_rule(
             q, k, v, log_alpha, beta, chunk=self.chunk,
             kernel=self._kernels, interpret=self._interpret,
-            dot_dtype=self.mxu_dtype)[:, :t]
+            dot_dtype=self.mxu_dtype, head_major=self._prep)[:, :t]
         gate = self.mxu_dot(jnp, rows, w_gate).reshape(b, t, h, dv)
         o = rms_norm(jnp, o, g_out, self.norm_eps) \
             * self._gate_of(jnp, gate)
@@ -432,8 +473,8 @@ class GatedDeltaNet(Forward):
 
 class GDGatedDeltaNet(GDMoE):
     """Backward of :class:`GatedDeltaNet`: the forward's stashed
-    pullback (autodiff around the ``custom_vjp`` of the chunk-local
-    kernels and of the state kernels),
+    pullback (autodiff around the ``custom_vjp`` of the prep kernels,
+    of the chunk-local kernels and of the state kernels),
     every parameter through the base's update rule.  There is no
     analytic numpy backward: the numpy path differentiates the XLA
     forward on the host (the recurrence is checked against

@@ -2,7 +2,8 @@
 2024, arXiv:2412.06464) as four Pallas kernels: what is local to a
 chunk — forward and backward — and the state handed from chunk to
 chunk — forward and reverse; the same algebra in plain ``jax.numpy``
-beside them.
+beside them.  And, since PR 40, what a layer does between its
+q ‖ k ‖ v projection and the rule as two more (the last section below).
 
 Per head, with keys q_t, k_t of d_k, values v_t of d_v, a decay
 α_t ∈ (0, 1) and a write strength β_t, the recurrence is
@@ -96,6 +97,39 @@ at ``dot_dtype``, the width every product takes them in: 64 MB a layer
 less than f32 at T 4,096 × 32 heads of 128 × 128); :func:`chunk_local` / :func:`state_scan` in ``jax.numpy``,
 which write Γ out as a (C, C, d_k) array, stay the oracle and the path
 off a TPU.  Neither family's kernel names hold the other's.
+
+**Between the projection and the rule** (:func:`qkv_prep`, PR 40): per
+column c of u = m (W_q ‖ W_k ‖ W_v), (B, T, H·(2 d_k + d_v)) f32,
+
+.. code-block:: text
+
+    c_t = Σ_{j<J} taps[c, j] · u_{t−J+1+j}     zeros before the sequence
+    a_t = c_t / (1 + e^(−c_t))
+    q: a_t / √(Σ_head a_t² + ε) · d_k^(−1/2)   k: a_t / √(Σ_head a_t² + ε)
+    v: a_t
+
+``znicz_qkv_prep_fwd`` takes a head's columns where the matmul wrote
+them — d_k and d_v whole 128-lane tiles (:func:`prep_legal`), so a
+``BlockSpec`` addresses a head and its norm is a reduction across the
+lanes of its own tiles — and writes (B, H, T + pad, ·), the rows the
+chunk kernels read (:func:`gated_delta_rule` ``head_major``), the
+padding zeros.  ``znicz_qkv_prep_bwd`` reads u, the taps and the three
+cotangents, makes c, a and the norms again in VMEM and writes du where
+u lies and dtaps (summed over the row tiles and the batch in a block
+that stays in VMEM): what its ``custom_vjp`` keeps is u and the taps.
+Each way is ONE body called for three column ranges — q (normed,
+scaled), k (normed), v (128 lanes at a time) — because their results
+are three arrays forward and ONE array backward (du, handed from call
+to call as an aliased operand, each writing its own columns).  A grid
+step takes ``PREP_ROWS`` rows of one column block and walks them in
+sub-tiles of 64; u_{t−s} is a sublane rotation of the sub-tile with
+the 8 rows before it, which at a grid step's first rows come through a
+second ``BlockSpec`` over the same array (8 rows: the halo; zeros at a
+sequence's start), and the backward's dc_{t+s} likewise from the 8 rows
+AFTER (u's and the cotangent's, dc made again for them; zero past the
+sequence's end).  Sequences are a grid axis: nothing crosses from one
+into the next.  Everything f32, the formulas and ε
+``delta_net.causal_conv`` / ``moe._silu`` / ``delta_net.l2_normalize``'s.
 """
 
 from __future__ import annotations
@@ -1183,27 +1217,371 @@ _kda_scan_kernels.defvjp(_kda_scan_fwd, _kda_scan_bwd)
 
 
 # ----------------------------------------------------------------------
+# between the projection and the rule: the taps, the SiLU, the L2 norms
+# ----------------------------------------------------------------------
+#: rows of a sequence a grid step of the prep kernels takes (a block is
+#: rows × one head's columns: 1 MB at 128 lanes), walked inside the step
+#: in sub-tiles of ``_PREP_SUB`` rows, whose values stay near the
+#: registers
+PREP_ROWS = 2048
+_PREP_SUB = 64
+
+
+def prep_legal(dk: int, dv: int) -> bool:
+    """A head's q, k and v are whole 128-lane column blocks of the
+    q ‖ k ‖ v projection: a ``BlockSpec`` addresses them where the
+    matmul wrote them, and a head's L2 norm is a reduction across the
+    lanes of its own tiles."""
+    return dk % _LANES == 0 and dv % _LANES == 0
+
+
+def _prep_sections(heads: int, dk: int, dv: int):
+    """The projection's three column ranges as the calls walk them:
+    ``(width, first, blocks, per_head, scale)`` — column blocks of
+    ``width`` lanes from block ``first`` on, ``per_head`` of them a
+    head, L2-normed and scaled where ``scale`` is a number.  q and k
+    take a head whole (its norm runs across it); v has no norm and goes
+    128 lanes at a time, so that its first column is a whole block
+    whatever d_k and d_v are."""
+    return ((dk, 0, heads, 1, dk ** -0.5), (dk, heads, heads, 1, 1.0),
+            (_LANES, 2 * heads * dk // _LANES, heads * dv // _LANES,
+             dv // _LANES, None))
+
+
+def _shifted(ext, width: int, rows: int):
+    """u_{t−s} for s < ``width`` over ``rows`` rows, from ``ext`` — a
+    stretch of u that starts 8 rows before the first of them — by a
+    sublane rotation each (s < 8: nothing wraps into the rows kept)."""
+    return [ext[_SUBLANES:_SUBLANES + rows]] + [
+        pltpu.roll(ext, s, 0)[_SUBLANES:_SUBLANES + rows]
+        for s in range(1, width)]
+
+
+def _conv(shifted, taps):
+    """c_t = Σ_j taps[j] · u_{t−J+1+j} (``delta_net.causal_conv``)."""
+    width = len(taps)
+    c = shifted[width - 1] * taps[0]
+    for j in range(1, width):
+        c = c + shifted[width - 1 - j] * taps[j]
+    return c
+
+
+def _prep_rows(ext, taps, scale, eps):
+    """A sub-tile's rows of q or k (``scale`` a number: x / √(‖x‖² + ε)
+    · scale, ``delta_net.l2_normalize``'s formula as a reciprocal root
+    a ROW and a product: a row statistic costs a whole vreg whatever it
+    holds, so a division by it was as dear as the SiLU's) or of v, from
+    u."""
+    c = _conv(_shifted(ext, len(taps), ext.shape[0] - _SUBLANES), taps)
+    a = c / (1.0 + jnp.exp(-c))                  # ``moe._silu``'s form
+    if scale is None:
+        return a
+    a = a * jax.lax.rsqrt(_rows(a * a) + eps)
+    return a if scale == 1.0 else a * scale
+
+
+def _prep_cotangents(ext, dy, taps, scale, eps):
+    """From u (8 rows before a sub-tile, the sub-tile, 8 rows after it)
+    and the cotangent of the sub-tile's and the next 8 rows' q, k or v:
+    du of the sub-tile's rows, and per tap Σ_t dc_t · u_{t−J+1+j} over
+    them as a row.  c, a and the norm are made again."""
+    width, rows = len(taps), dy.shape[0]          # the sub-tile's + 8
+    shifted = _shifted(ext, width, rows)
+    c = _conv(shifted, taps)
+    sigma = 1.0 / (1.0 + jnp.exp(-c))
+    if scale is not None:
+        a = c * sigma              # silu(c): one division, not two
+        inverse = jax.lax.rsqrt(_rows(a * a) + eps)
+        dy = inverse * (dy - a * (inverse * inverse * _rows(dy * a)))
+        if scale != 1.0:
+            dy = dy * scale
+    # silu′(c) = σ (1 + c (1 − σ))
+    dc = dy * (sigma * (1.0 + c * (1.0 - sigma)))
+    own = rows - _SUBLANES
+    du = dc[:own] * taps[width - 1]
+    for s in range(1, width):         # dc_{t+s}: up to J − 1 rows after
+        du = du + pltpu.roll(dc, rows - s, 0)[:own] * taps[width - 1 - s]
+    return du, [_cols(dc[:own] * shifted[width - 1 - j][:own])
+                for j in range(width)]
+
+
+def _eight(ref, start):
+    """8 rows of a block from the sublane-aligned ``start``, f32."""
+    return ref[pl.ds(pl.multiple_of(start, _SUBLANES), _SUBLANES),
+               :].astype(jnp.float32)
+
+
+def _before(ref, halo, k, start):
+    """The 8 rows before sub-tile ``k`` of a block, which starts at
+    ``start``: the ``halo`` before the block's first."""
+    return jax.lax.select(
+        k == 0, halo, _eight(ref, jnp.maximum(start - _SUBLANES, 0)))
+
+
+def _unless(seen, x):
+    """``x`` where ``seen`` (a column of booleans), zeros elsewhere —
+    a select, not a product: a block past an array's end holds
+    anything."""
+    return jax.lax.select(jnp.broadcast_to(seen, x.shape), x,
+                          jnp.zeros_like(x))
+
+
+def _prep_fwd_kernel(u_ref, before_ref, taps_ref, out_ref, *, sub, scale,
+                     eps, length, masked):
+    tile, rows = pl.program_id(2), u_ref.shape[0]
+    taps = [taps_ref[j:j + 1, :] for j in range(taps_ref.shape[0])]
+    f32 = jnp.float32
+    # zeros before the sequence
+    halo = before_ref[...].astype(f32) * _ones_where(tile > 0)
+    at = jax.lax.broadcasted_iota(jnp.int32, (sub + _SUBLANES, 1), 0)
+
+    def some(k, carry):
+        start = pl.multiple_of(k * sub, sub)
+        ext = jnp.concatenate(
+            [_before(u_ref, halo, k, start),
+             u_ref[pl.ds(start, sub), :].astype(f32)], axis=0)
+        if masked:
+            seen = tile * rows + start - _SUBLANES + at < length
+            ext = _unless(seen, ext)
+        out = _prep_rows(ext, taps, scale, eps)
+        if masked:      # the padding's rows are zeros, as jnp.pad's were
+            out = _unless(seen[_SUBLANES:], out)
+        out_ref[pl.ds(start, sub), :] = out
+        return carry
+
+    jax.lax.fori_loop(0, rows // sub, some, None)
+
+
+def _prep_bwd_kernel(u_ref, before_ref, after_ref, dy_ref, dy_after_ref,
+                     taps_ref, *rest, sub, scale, eps, length, masked):
+    du_ref, dtaps_ref = rest[-2:]       # after du itself, where aliased
+    tile, rows = pl.program_id(2), u_ref.shape[0]
+    width, steps = taps_ref.shape[0], rows // sub
+    taps = [taps_ref[j:j + 1, :] for j in range(width)]
+    f32 = jnp.float32
+    halo = before_ref[...].astype(f32) * _ones_where(tile > 0)
+    # nothing follows the last tile: a zero cotangent there makes dc 0
+    tail = after_ref[...].astype(f32)
+    dy_tail = dy_after_ref[...] * _ones_where(
+        tile < pl.num_programs(2) - 1)
+    at = jax.lax.broadcasted_iota(jnp.int32, (sub + 2 * _SUBLANES, 1), 0)
+
+    @pl.when((pl.program_id(1) == 0) & (tile == 0))
+    def _start():
+        dtaps_ref[...] = jnp.zeros_like(dtaps_ref)
+
+    def some(k, sums):
+        start = pl.multiple_of(k * sub, sub)
+        last = k == steps - 1
+        after = jnp.minimum(start + sub, rows - _SUBLANES)
+        ext = jnp.concatenate(
+            [_before(u_ref, halo, k, start),
+             u_ref[pl.ds(start, sub), :].astype(f32),
+             jax.lax.select(last, tail, _eight(u_ref, after))], axis=0)
+        dy = jnp.concatenate(
+            [dy_ref[pl.ds(start, sub), :],
+             jax.lax.select(last, dy_tail, _eight(dy_ref, after))],
+            axis=0)
+        if masked:
+            seen = tile * rows + start - _SUBLANES + at < length
+            ext, dy = _unless(seen, ext), _unless(seen[_SUBLANES:], dy)
+        du, parts = _prep_cotangents(ext, dy, taps, scale, eps)
+        du_ref[pl.ds(start, sub), :] = du.astype(du_ref.dtype)
+        return [s + part for s, part in zip(sums, parts)]
+
+    sums = jax.lax.fori_loop(
+        0, steps, some,
+        [jnp.zeros((1, u_ref.shape[1]), f32) for _ in range(width)])
+    for j, s in enumerate(sums):
+        dtaps_ref[j:j + 1, :] += s
+
+
+def _prep_walk(length: int, pad: int, rows):
+    """Rows a grid step, the row tiles and the padded length for a
+    sequence of ``length`` positions + ``pad``, and what the kernels'
+    bodies are told of them: the sub-tile, the length, and whether any
+    block reaches past it."""
+    padded = length + pad
+    rows = min(rows or PREP_ROWS, padded)
+    if padded % _SUBLANES or rows % _SUBLANES:
+        raise ValueError(f"qkv_prep: {padded} positions in tiles of "
+                         f"{rows} are not whole sublanes")
+    tiles = pl.cdiv(padded, rows)
+    return rows, tiles, padded, dict(
+        sub=math.gcd(rows, _PREP_SUB), length=length,
+        masked=length != tiles * rows)
+
+
+def _prep_specs(section, rows: int, length: int, padded: int, taps: int):
+    """The ``BlockSpec``s of one section's walk over the grid (column
+    block, sequence, row tile): the projection's (rows, width) block
+    where the matmul wrote it, the 8 rows before and after it, the
+    head-major (B, H, T, ·) block of q, k or v with ITS next 8 rows,
+    the taps' (J, width) block, and a section's own taps' block."""
+    width, first, _, per_head, _ = section
+    eighth = rows // _SUBLANES
+
+    def head(n):
+        return (n, 0) if per_head == 1 else (
+            jax.lax.div(n, per_head), jax.lax.rem(n, per_head))
+
+    def after(i, positions):    # held inside the array; masked past it
+        return jnp.minimum((i + 1) * eighth,
+                           pl.cdiv(positions, _SUBLANES) - 1)
+
+    return dict(
+        u=pl.BlockSpec((None, rows, width),
+                       lambda n, b, i: (b, i, first + n)),
+        before=pl.BlockSpec(
+            (None, _SUBLANES, width),
+            lambda n, b, i: (b, jnp.maximum(i * eighth - 1, 0),
+                             first + n)),
+        after=pl.BlockSpec(
+            (None, _SUBLANES, width),
+            lambda n, b, i: (b, after(i, length), first + n)),
+        heads=pl.BlockSpec(
+            (None, None, rows, width),
+            lambda n, b, i: (b, head(n)[0], i, head(n)[1])),
+        heads_after=pl.BlockSpec(
+            (None, None, _SUBLANES, width),
+            lambda n, b, i: (b, head(n)[0], after(i, padded),
+                             head(n)[1])),
+        taps=pl.BlockSpec((taps, width), lambda n, b, i: (0, first + n)),
+        own_taps=pl.BlockSpec((taps, width), lambda n, b, i: (0, n)))
+
+
+def _prep_params(semantics, rows: int, width: int, blocks: int):
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics,
+        vmem_limit_bytes=2 * blocks * rows * width * 4 + (16 << 20))
+
+
+# jitted, as the chunk kernels' entries are: a model's linear layers
+# trace and lower each of these once per program
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7, 8))
+def _qkv_prep_forward(u, taps, heads, dk, dv, eps, pad, rows, interpret):
+    b, length, _ = u.shape
+    rows, tiles, padded, walk = _prep_walk(length, pad, rows)
+    taps = taps.astype(jnp.float32).T                 # (J, columns)
+    out = []
+    for section in _prep_sections(heads, dk, dv):
+        width, _, blocks, per_head, scale = section
+        spec = _prep_specs(section, rows, length, padded, taps.shape[0])
+        out.append(pl.pallas_call(
+            functools.partial(_prep_fwd_kernel, scale=scale, eps=eps,
+                              **walk),
+            grid=(blocks, b, tiles),
+            in_specs=[spec["u"], spec["before"], spec["taps"]],
+            out_specs=spec["heads"],
+            out_shape=jax.ShapeDtypeStruct(
+                (b, heads, padded, width * per_head), jnp.float32),
+            compiler_params=_prep_params(("parallel",) * 3, rows, width,
+                                         2),
+            interpret=interpret, name="znicz_qkv_prep_fwd",
+        )(u, u, taps))
+    return tuple(out)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _qkv_prep_backward(u, taps, cotangents, heads, dk, dv, eps, pad, rows,
+                       interpret):
+    b, length, _ = u.shape
+    rows, tiles, padded, walk = _prep_walk(length, pad, rows)
+    taps_t = taps.astype(jnp.float32).T
+    du, d_taps = None, []
+    for section, dy in zip(_prep_sections(heads, dk, dv), cotangents):
+        width, _, blocks, _, scale = section
+        spec = _prep_specs(section, rows, length, padded, taps_t.shape[0])
+        operands = [u, u, u, dy, dy, taps_t]
+        in_specs = [spec[s] for s in ("u", "before", "after", "heads",
+                                      "heads_after", "taps")]
+        aliases = {}
+        if du is not None:      # ONE du: a section's columns at a time
+            operands.append(du)
+            in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+            aliases = {len(operands) - 1: 0}
+        du, part = pl.pallas_call(
+            functools.partial(_prep_bwd_kernel, scale=scale, eps=eps,
+                              **walk),
+            grid=(blocks, b, tiles), in_specs=in_specs,
+            out_specs=(spec["u"], spec["own_taps"]),
+            out_shape=(jax.ShapeDtypeStruct(u.shape, u.dtype),
+                       jax.ShapeDtypeStruct(
+                           (taps_t.shape[0], blocks * width),
+                           jnp.float32)),
+            input_output_aliases=aliases,
+            compiler_params=_prep_params(
+                ("parallel", "arbitrary", "arbitrary"), rows, width, 3),
+            interpret=interpret, name="znicz_qkv_prep_bwd",
+        )(*operands)
+        d_taps.append(part)
+    return du, jnp.concatenate(d_taps, axis=1).T.astype(taps.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7, 8))
+def _qkv_prep(u, taps, heads, dk, dv, eps, pad, rows, interpret):
+    return _qkv_prep_forward(u, taps, heads, dk, dv, eps, pad, rows,
+                             interpret)
+
+
+def _qkv_prep_fwd(u, taps, *static):
+    # what the backward keeps is the projection and the taps
+    return _qkv_prep_forward(u, taps, *static), (u, taps)
+
+
+def _qkv_prep_bwd(*args):
+    *static, (u, taps), cotangents = args
+    return _qkv_prep_backward(u, taps, tuple(cotangents), *static)
+
+
+_qkv_prep.defvjp(_qkv_prep_fwd, _qkv_prep_bwd)
+
+
+def qkv_prep(u, taps, heads: int, dk: int, dv: int, eps: float,
+             pad: int = 0, rows: int | None = None,
+             interpret: bool = False):
+    """What lies between the q ‖ k ‖ v projection and the rule, as
+    ``znicz_qkv_prep_fwd`` and, under differentiation,
+    ``znicz_qkv_prep_bwd``: from ``u`` (B, T, H·(2 d_k + d_v)) where the
+    matmul wrote it and the ``taps`` (columns, J) — the causal
+    convolution, the SiLU, q's and k's L2 norms over a head and q's
+    d_k^(−1/2) — to q, k (B, H, T + pad, d_k) and v (B, H, T + pad, d_v),
+    f32 and head-major, the ``pad`` positions zeros.  The backward keeps
+    u and the taps and makes the rest again in VMEM.  Needs
+    :func:`prep_legal` head sizes."""
+    if not prep_legal(dk, dv):
+        raise ValueError(f"qkv_prep: heads of {dk} x {dv} are not whole "
+                         f"{_LANES}-lane tiles")
+    return _qkv_prep(u, taps, int(heads), int(dk), int(dv), float(eps),
+                     int(pad), rows, bool(interpret))
+
+
+# ----------------------------------------------------------------------
 # the rule
 # ----------------------------------------------------------------------
 def gated_delta_rule(q, k, v, log_alpha, beta, chunk: int = CHUNK,
                      kernel: bool = False, interpret: bool = False,
-                     dot_dtype=None):
+                     dot_dtype=None, head_major: bool = False):
     """o (B, T, H, d_v) of the recurrence in its chunked form for
-    q, k (B, T, H, d_k), v (B, T, H, d_v), log α ≤ 0 and β (B, T, H);
-    T a multiple of ``chunk``.  log α (B, T, H, d_k) is a decay per key
-    channel: its shape picks the body (module docstring)."""
-    b, t, h, _ = q.shape
+    q, k (B, T, H, d_k), v (B, T, H, d_v) — (B, H, T, ·) where
+    ``head_major``, as :func:`qkv_prep` writes them: no move —, log α ≤ 0
+    and β (B, T, H); T a multiple of ``chunk``.  log α (B, T, H, d_k) is
+    a decay per key channel: its shape picks the body (module
+    docstring)."""
+    b, t, h = beta.shape
     if t % chunk:
         raise ValueError(f"gated_delta_rule: {t} positions are not "
                          f"whole chunks of {chunk}")
     n = t // chunk
 
-    def chunks(a):                # (B, T, H, ·) → (B·H, N, C, ·)
-        a = jnp.moveaxis(a.astype(jnp.float32), 2, 1)
+    def chunks(a, moved=False):   # (B, T, H, ·) → (B·H, N, C, ·)
+        a = a.astype(jnp.float32)
+        if not moved:
+            a = jnp.moveaxis(a, 2, 1)
         return a.reshape((b * h, n, chunk) + a.shape[3:])
 
-    rows = (chunks(q), chunks(k), chunks(v), chunks(log_alpha),
-            chunks(beta))
+    rows = (chunks(q, head_major), chunks(k, head_major),
+            chunks(v, head_major), chunks(log_alpha), chunks(beta))
     w, k_hat, u, decay, q_grown, p = chunk_local_kernels(
         *rows, dot_dtype, interpret) if kernel \
         else chunk_local(*rows, dot_dtype)
