@@ -10,6 +10,12 @@ PR 32).  The kernels' results are compared on the device with
     chiprun -- python3 benchmarks/delta_chunk_probe.py
     python3 benchmarks/delta_chunk_probe.py --compile-only      # here:
         the chip's compiler on a described v5e, nothing runs
+    python3 benchmarks/delta_chunk_probe.py --compile-only --kernel kda_chunk_bwd
+        # ONE chunk kernel as a program of its own — either family
+        # (gdr: this cell's shapes; kda: Ling's 32 heads of 128 × 128,
+        # a decay per key channel), either way — so that a dump of the
+        # compiler's schedule (benchmarks/static_schedule.py), which
+        # holds the FIRST kernel a process compiles, holds that one
 
 Each line is JSON and names the platform it ran on; times are
 ``block_until_ready`` medians of ``REPEAT`` calls in one program, per
@@ -97,9 +103,37 @@ def worst(got, want) -> float:
                for a, b in zip(got, want))
 
 
+def one_kernel(name: str, struct):
+    """The chunk kernel ``name`` (``gdr_chunk_bwd``, …) lowered alone
+    at its cell's shapes with bf16 products: the backward from the
+    shapes of what the forward hands it."""
+    family, _, way = name.split("_")
+    g, dk, dv = (32, 128, 128) if family == "kda" else (G, DK, DV)
+    forward, backward = (
+        (pd._kda_forward_call, pd._kda_backward_call) if family == "kda"
+        else (pd._chunk_forward_call, pd._chunk_backward_call))
+    static = (False, jnp.dtype(jnp.bfloat16), pd.CHUNKS_PER_STEP)
+    f32 = jnp.float32
+    rows = [jax.ShapeDtypeStruct(shape, f32) for shape in (
+        (g, N, C, dk), (g, N, C, dk), (g, N, C, dv),
+        (g, N, C, dk) if family == "kda" else (g, N, C), (g, N, C))]
+    if way == "fwd":
+        return forward.lower(*map(struct, rows), *static)
+    outputs, inverse = jax.eval_shape(
+        lambda *a: forward(*a, *static), *rows)
+    if family == "kda":     # the backward is handed V at bf16
+        rows[2] = jax.ShapeDtypeStruct(rows[2].shape, static[1])
+    return backward.lower(*jax.tree.map(
+        struct, (*rows, inverse, outputs)), *static)
+
+
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     parser.add_argument("--compile-only", action="store_true")
+    parser.add_argument("--kernel", choices=[
+        f"{family}_chunk_{way}" for family in ("gdr", "kda")
+        for way in ("fwd", "bwd")], help="with --compile-only: this "
+        "one kernel alone, for a dump of its schedule")
     args = parser.parse_args()
     bf16 = jnp.dtype(jnp.bfloat16)
 
@@ -117,6 +151,10 @@ def main() -> int:
 
         def struct(a):
             return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+        if args.kernel:
+            one_kernel(args.kernel, struct).compile()
+            emit(kernel=args.kernel, kernels="compile")
+            return 0
         with jax.default_device(jax.devices("cpu")[0]):
             (q, k, v, log_alpha, betas), weights = jax.eval_shape(inputs)
         shaped = jax.tree.map(struct, (q, k, v, log_alpha, betas))

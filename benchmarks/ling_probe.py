@@ -12,6 +12,9 @@ over (4,096, 12,288) f32, against their bytes and against the plain
     chiprun -- python3 benchmarks/ling_probe.py --only prep [--rows 1024 2048 4096]
     python3 benchmarks/ling_probe.py --compile-only      # here: the
         real Mosaic / XLA-TPU compile for a described v5e, no chip
+        (one of the rule's chunk kernels alone, for a dump of its
+        schedule: benchmarks/delta_chunk_probe.py --compile-only
+        --kernel kda_chunk_bwd)
     python3 benchmarks/ling_probe.py --compile-step [--t 8192]   # here:
         the CELL's whole step program compiled for a described v5e
         (≈ 4 minutes: 885 M parameters drawn on the host) — the

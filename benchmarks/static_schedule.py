@@ -14,12 +14,22 @@ the dump with any compile for a described v5e::
 (the compiling process aborts once the kernel's files are written: the
 dumper then looks for a report template this installation lacks).  Per
 region: its bundles and, per unit, the slots used and their share of
-what the region's bundles offer.  A count, not a time: DMA waits and a
-grid step's own prologue are not in it.
+what the region's bundles offer; for the kernel whole, its MXU
+operations by kind (weight loads ``vmatpush`` and row streams
+``vmatmul``, f32 or bf16: an f32 product at the highest precision is
+six of each per tile, a bf16 one one).  A count, not a time: DMA waits
+and a grid step's own prologue are not in it.
+
+The dumper writes ONE kernel's files before it aborts, so give it a
+compile whose first Mosaic kernel is the one to read: for the delta
+rule's four chunk kernels ``benchmarks/delta_chunk_probe.py
+--compile-only --kernel kda_chunk_bwd`` (``gdr_`` / ``kda_``, ``_fwd`` /
+``_bwd``) compiles that one alone (PR 41).
 """
 
 from __future__ import annotations
 
+import collections
 import glob
 import re
 import sys
@@ -59,6 +69,16 @@ def occupancy(path: str) -> list:
     return rows
 
 
+def mxu_operations(path: str) -> dict:
+    """``{"vmatpush f32": n, "vmatmul bf16": n, …}`` over a
+    ``*-final_bundles.txt``."""
+    return dict(collections.Counter(
+        f"{kind} {'bf16' if '.bf16' in rest else 'f32'}"
+        for kind, rest in re.findall(
+            r"= (vmatpush|vmatmul)([a-z0-9.]*)",
+            open(path, errors="replace").read())))
+
+
 def main(directory: str, kernel: str, least: int = 300) -> int:
     bundles = glob.glob(f"{directory}/*{kernel}*[0-9]-final_bundles.txt")
     used = glob.glob(f"{directory}/*{kernel}*[0-9]-final_hlo-static-"
@@ -67,7 +87,13 @@ def main(directory: str, kernel: str, least: int = 300) -> int:
         print(f"no final schedule of {kernel} under {directory}")
         return 1
     rows = occupancy(used[0])
-    print(f"{kernel}: {len(rows)} bundles")
+    print(f"{kernel}: {len(rows)} bundles; " + ", ".join(
+        f"{name} {n}" for name, n in sorted(
+            mxu_operations(bundles[0]).items())))
+    slots = [sum(row[i] for row in rows) for i in range(len(UNITS))]
+    print("  whole  " + "  ".join(
+        f"{unit} {n} ({n / have / len(rows):.0%})"
+        for unit, n, have in zip(UNITS, slots, SLOTS)))
     for region, (lo, hi) in sorted(regions(bundles[0]).items(),
                                    key=lambda item: item[1]):
         if hi - lo < least:

@@ -697,6 +697,12 @@ def delta_scan(unit: str, stat: str) -> Gauge:
     projection where it lies to head-major q, k, v — the taps, the
     SiLU, the L2 norms; the kernels path AND heads whose d_k and d_v
     are whole 128-lane tiles —, 0 ``jax.numpy`` and a move;
+    ``exact_products`` / ``mask_products``: what the chunk kernels'
+    bodies multiply a chunk, forward + backward
+    (``pallas_delta.chunk_products``) — products of two real f32
+    factors at the highest precision, six bf16 passes each, and
+    products with a 0 / ±1 matrix built from indices, the f32 factor's
+    three bf16 parts in one contraction — 0 without the kernels;
     ``decay_channels``: decays a head,
     1 or d_k — with d_k the kernels are ``znicz_kda_chunk_*`` /
     ``znicz_kda_state_*``; ``sub_block``: positions whose decays are

@@ -285,6 +285,15 @@ class GatedDeltaNet(Forward):
         self._prep = self._kernels and pallas_delta.prep_legal(dk, dv)
         chunks = -(-t // chunk)
         b = self.input.shape[0]
+        # what a chunk's kernels multiply, forward + backward: products
+        # of two real f32 factors (six bf16 passes) and products with a
+        # 0 / ±1 matrix (the f32 factor's three parts, one contraction)
+        exact = masks = 0
+        if self._kernels:
+            products = pallas_delta.chunk_products(
+                self.decay == "channel", chunk, sub, self.mxu_dtype)
+            exact = products["exact_fwd"] + products["exact_bwd"]
+            masks = products["mask_fwd"] + products["mask_bwd"]
         stats = {
             "chunk": chunk, "chunks": chunks, "key_dim": dk,
             "value_dim": dv,
@@ -294,6 +303,7 @@ class GatedDeltaNet(Forward):
             "path": 1.0 if self._kernels else 0.0,
             "chunk_path": 1.0 if self._kernels else 0.0,
             "prep_path": 1.0 if self._prep else 0.0,
+            "exact_products": exact, "mask_products": masks,
             "decay_channels": self.decay_channels,
             "sub_block": sub if self.decay == "channel" else chunk}
         for stat, value in stats.items():
@@ -311,6 +321,8 @@ class GatedDeltaNet(Forward):
              if self.decay == "channel" else
              "znicz_gdr_chunk_fwd / _bwd kernels for what is local to a "
              "chunk, znicz_delta_state_fwd / _bwd for the walk")
+            + f", {exact} f32 products at six passes and {masks} with a "
+              "0/1 matrix at three a chunk"
             + (" (interpreted)" if interpret else "")
             if self._kernels else f"plain scan ({refused})",
             "znicz_qkv_prep_fwd / _bwd kernels from the projection to "

@@ -46,8 +46,20 @@ again, nothing of shape (C, C) is written.  Precision is
 a 0/1 product, never a difference of prefixes), Γ, K Kᵀ, the inverse
 and their cotangents f32 with f32 products at the highest precision;
 the products into W, U and P, and their transposes in the backward,
-take ``dot_dtype`` inputs with f32 accumulation.  :func:`chunk_local`
-is the path off a TPU and on a mesh, and the kernels' oracle.
+take ``dot_dtype`` inputs with f32 accumulation.  "The highest
+precision" is six bf16 passes — both factors in three bf16 parts, six
+of the nine cross products — for a product of two real f32 factors:
+K Kᵀ (M under a decay per channel), the inverse's levels, d(M⁻¹) and
+their cotangents keep it (:data:`_exact`).  Where one factor is a
+0 / ±1 matrix built from indices — every sum of log α, and the way
+back into d log α — that factor IS its own first part and three of the
+six passes multiply zeros: the kernels split the f32 factor alone into
+its three bf16 parts (they add back to it bit for bit) and contract
+them against the mask laid thrice along the contraction
+(:func:`_mask_product`, PR 41): the same terms, each exact, summed in
+f32 — three passes, and the masks that share a factor stacked into one
+product.  :func:`chunk_local` is the path off a TPU and on a mesh, and
+the kernels' oracle.
 
 (*) is sequential: ``znicz_delta_state_fwd`` walks a head's chunks
 along the grid's last axis with S in VMEM and writes V′ and, for the
@@ -90,7 +102,10 @@ K̂ = K ⊙ e^(c_C − c), Qc = e^c ⊙ Q, S_{n+1} = Diag(e^(c_C)) S_n +
 K̂ᵀ V′ — the walk scales S's ROWS.  Kernels ``znicz_kda_chunk_fwd`` /
 ``_bwd`` (the hand-written backward gives d log α per channel) and
 ``znicz_kda_state_fwd`` / ``_bwd``; precision as above (the sums of
-log α, Γ's factors, M, the inverse f32 at the highest precision; W, U,
+log α, Γ's factors, M, the inverse f32 at the highest precision — the
+C/16 + 3 sums of log α ONE :func:`_mask_product` with the masks stacked
+row block under row block, their cotangents one more with the masks'
+transposes side by side, M and the inverse six passes; W, U,
 P and the state's update ``dot_dtype`` inputs, f32 accumulation, f32
 state in VMEM — the per-chunk S_n this walk WRITES for the backward are
 at ``dot_dtype``, the width every product takes them in: 64 MB a layer
@@ -332,6 +347,48 @@ def _mixed(dot_dtype):
         _dot, dot_dtype=dot_dtype)
 
 
+def _three_parts(x):
+    """An f32 array as three bf16 arrays, ``hi + mid + lo == x`` bit for
+    bit: 24 significant bits in 3 × 8, each part the rounding of what
+    the parts before it left.  Exact for every finite x of magnitude
+    from 2^-100 up (``lo`` is 2^-16 of x and has to stay a normal bf16):
+    log α ∈ [−5, 0) and its cotangents are; below that ``lo``, then
+    ``mid``, flush to zero and x keeps 16, then 8, bits of a number
+    that small."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    return hi, mid, (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def _for_mask_product(mask, right: bool = False):
+    """A 0 / ±1 matrix as :func:`_mask_product` takes it: bf16 (its own
+    first part — the other two are zero), thrice along the contraction
+    so that ONE product sums the three parts of the other factor inside
+    the MXU.  Built once a kernel body, beside the positions."""
+    return jnp.concatenate([mask.astype(jnp.bfloat16)] * 3,
+                           axis=0 if right else 1)
+
+
+def _mask_product(mask, x, right: bool = False):
+    """``mask @ x`` (``x @ mask`` where ``right``) for a 0 / ±1 matrix
+    from :func:`_for_mask_product` and a real f32 ``x``, to f32's last
+    bits in three bf16 passes: the highest precision splits BOTH
+    factors into three bf16 parts and runs six of the nine cross
+    products, but a mask is its own first part, so the three passes
+    that meet its second and third multiply zeros.  What is left is
+    mask · (hi + mid + lo) — the same non-zero terms, each exact in the
+    f32 accumulator (a part times 0 or ±1), so every sum is still made
+    from its own terms in f32.  Only for factors that are 0 or ±1 BY
+    CONSTRUCTION (:func:`_positions`, :func:`_kda_positions`): a
+    product of two real f32 factors (M, K Kᵀ, the inverse, their
+    cotangents) stays :data:`_exact`."""
+    parts = jnp.concatenate(_three_parts(x), axis=1 if right else 0)
+    a, b = (parts, mask) if right else (mask, parts)
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _ones_where(condition):
     """0/1 in f32: a mask to multiply by.  (A ``jnp.where`` or an
     integer ``//`` in a kernel's body is a nested call that the host
@@ -340,13 +397,20 @@ def _ones_where(condition):
     return condition.astype(jnp.float32)
 
 
-def _positions(c: int):
+def _positions(c: int, backward: bool = False):
     """A (C, C) matrix's entries on and below the diagonal, strictly
-    below, strictly above, and on it, as 0/1."""
+    below, strictly above, and on it, as 0/1 in f32 to multiply by; and
+    as right factors of a :func:`_mask_product` ``sums`` [m, j]: j < m
+    (Σ_{j<m≤i} log α_m from the row's own terms) and, for the
+    ``backward``, its transpose ``back`` [j, m]."""
     row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    return (_ones_where(col <= row), _ones_where(col < row),
-            _ones_where(col > row), _ones_where(col == row))
+    upto, below, above, eye = (
+        _ones_where(col <= row), _ones_where(col < row),
+        _ones_where(col > row), _ones_where(col == row))
+    return (upto, below, above, eye,
+            _for_mask_product(below, right=True),
+            _for_mask_product(above, right=True) if backward else None)
 
 
 def _rows(x):
@@ -399,14 +463,49 @@ def _inverses_in_vmem(lowers, levels):
     return [x[:, m * c:(m + 1) * c] for m in range(n)]
 
 
-def _decays(log_alpha, upto, below, above):
+def _decays(log_alpha, upto, above, sums):
     """From a chunk's log α as a row (1, C), every sum from its own
     terms: Γ (C, C), exp(c) and exp(c_C − c) as columns (C, 1), exp(c_C)
     (1, 1)."""
     own = upto * log_alpha                             # [i, m]: m ≤ i
-    gamma = upto * jnp.exp(_exact(own, below))    # Σ_{j<m≤i} log α_m
+    # Σ_{j<m≤i} log α_m
+    gamma = upto * jnp.exp(_mask_product(sums, own, right=True))
     return (gamma, jnp.exp(_rows(own)), jnp.exp(_rows(above * log_alpha)),
             jnp.exp(_rows(log_alpha)))
+
+
+def _side_by_side(block: int, c: int) -> int:
+    """Chunks whose inverses share one chain of products
+    (:func:`_inverses_in_vmem`): two where they fill a 128-lane tile
+    and a grid step holds an even number."""
+    return 2 if block % 2 == 0 and 2 * c <= _LANES else 1
+
+
+def chunk_products(channels: bool, chunk: int = CHUNK, sub: int = None,
+                   dot_dtype=jnp.bfloat16) -> dict:
+    """What the chunk-local kernels' bodies hold a chunk, forward and
+    backward, counted from the algebra — ``{"exact_fwd", "exact_bwd"``:
+    products of two real f32 factors at the highest precision, six
+    bf16 passes each; ``"mask_fwd", "mask_bwd"``: products with a
+    0 / ±1 matrix as three bf16 parts in one contraction
+    (:func:`_mask_product`) ``}`` — for a decay per key ``channels``
+    (sub-blocks of ``sub``) or per head.  The inverse's chain is
+    2 (log₂ C − 1) whole products shared by the chunks side by side;
+    without a ``dot_dtype`` the products into W, U and P, and their
+    transposes, are exact ones too.  ``tests/test_delta_chunk_kernels``
+    pins the bodies' jaxprs to it; ``GatedDeltaNet`` publishes it."""
+    blocks = chunk // (sub or sub_block(chunk)) if channels else 0
+    inverse = 2 * (chunk.bit_length() - 2) // _side_by_side(
+        CHUNKS_PER_STEP, chunk)
+    if channels:    # M by sub-blocks; d_lower, d_left_k and d_right
+        exact = {"fwd": blocks + inverse, "bwd": 3 * blocks + 2}
+        mixed = {"fwd": blocks + 2, "bwd": 2 * blocks + 4}
+    else:           # K Kᵀ; d_lower, d_kk · k both ways
+        exact = {"fwd": 1 + inverse, "bwd": 5}
+        mixed = {"fwd": 3, "bwd": 7}
+    return {f"exact_{way}": exact[way] + (mixed[way] if dot_dtype is None
+                                          else 0) for way in exact} \
+        | {"mask_fwd": 1, "mask_bwd": 2}
 
 
 def _over_chunks(block: int, together: int, step: int, some) -> None:
@@ -432,8 +531,8 @@ def _over_chunks(block: int, together: int, step: int, some) -> None:
 @jax.jit
 def _chunk_lower(positions, log_alpha, beta, k):
     """A chunk's decays (:func:`_decays`) and its L."""
-    upto, below, above, eye = positions
-    gamma, grown, rest, decay = _decays(log_alpha, upto, below, above)
+    upto, below, above, eye, sums, _ = positions
+    gamma, grown, rest, decay = _decays(log_alpha, upto, above, sums)
     # β as a column: (1, C) → (C, 1) without a transpose
     lower = below * (_rows(eye * beta) * gamma
                      * _exact(k, k, trans_b=True))
@@ -480,9 +579,9 @@ def _chunk_cotangents(positions, q, k, v, log_alpha, beta, x, d_w, d_kh,
                       d_u, d_decay, d_qc, d_p, *, dot_dtype):
     """Cotangents of a chunk's q, k, v, log α, β from those of its W, K̂,
     U, decay (a row), Qc and P, and its (I + L)⁻¹."""
-    upto, below, above, eye = positions
+    upto, below, above, eye, sums, back = positions
     exact, mixed = _exact, _mixed(dot_dtype)
-    gamma, grown, rest, decay = _decays(log_alpha, upto, below, above)
+    gamma, grown, rest, decay = _decays(log_alpha, upto, above, sums)
     beta_col = _rows(eye * beta)
     kk = exact(k, k, trans_b=True)
     a = x * beta
@@ -505,7 +604,7 @@ def _chunk_cotangents(positions, q, k, v, log_alpha, beta, x, d_w, d_kh,
     # row: (C, 1) → (1, C) without a transpose) where L's rows
     d_beta = _cols(d_a * x) + _cols(eye * _rows(d_lower * gamma * kk))
     # log α: Γ = exp(Σ_{j<m≤i}), exp(c), exp(c_C − c), exp(c_C)
-    d_upto = exact(d_gamma * gamma, below, trans_b=True)
+    d_upto = _mask_product(back, d_gamma * gamma, right=True)
     d_c = grown * (_rows(d_qc * q) + _rows(d_kg * k))
     d_rest = rest * _rows(d_kh * k)
     d_alpha = _cols(upto * (d_upto + d_c) + above * d_rest) \
@@ -519,7 +618,7 @@ def _chunk_bwd_kernel(*refs, dot_dtype, together):
     in, five cotangents out."""
     ins, outs = refs[:12], refs[12:]
     block, c = ins[0].shape[0], ins[0].shape[1]
-    positions = _positions(c)
+    positions = _positions(c, backward=True)
 
     def one(i):
         results = _chunk_cotangents(
@@ -573,7 +672,7 @@ def _chunk_forward_call(q, k, v, log_alpha, beta, interpret, dot_dtype,
     the backward, everything else of a chunk's (C, C) in VMEM."""
     g, n, c, dk = q.shape
     dv, block = v.shape[-1], min(block, g * n)
-    side_by_side = 2 if block % 2 == 0 and 2 * c <= _LANES else 1
+    side_by_side = _side_by_side(block, c)
     w, k_hat, u, decay, qc, p, x = _chunk_call(
         functools.partial(
             _chunk_fwd_kernel, dot_dtype=dot_dtype,
@@ -862,40 +961,57 @@ def _chunk_local_channels(q, k, v, log_alpha, beta, dot_dtype=None):
     return w, k_hat, u, jnp.exp(c[..., -1, :]), q * grown, p
 
 
-def _kda_positions(c: int, sub: int):
+def _kda_positions(c: int, sub: int, backward: bool = False):
     """0/1 (C, C) matrices of a chunk of ``sub``-blocks, from the
-    indices: on and below the diagonal, strictly below, strictly above,
-    on it; ``within`` [i, m]: m ≤ i in i's own sub-block (c̃ = within·g,
-    a row's prefix from its sub-block's start); and per sub-block A the
-    signed ``reach`` [j, m]: +1 where j < m < A's start (r_A − c_j for an
-    earlier row), −1 where A's start ≤ m ≤ j inside A (−c̃_j), rows past
-    A zero."""
+    indices: on and below the diagonal, strictly below, on it, in f32
+    to multiply by.  And the masks whose products with log α are its
+    sums (:func:`_kda_factors`), stacked for ONE
+    :func:`_mask_product`: ``within`` [i, m]: m ≤ i in i's own sub-block
+    (c̃ = within · log α, a row's prefix from its sub-block's start); per
+    sub-block A the signed ``reach`` [j, m]: +1 where j < m < A's start
+    (r_A − c_j for an earlier row), −1 where A's start ≤ m ≤ j inside A
+    (−c̃_j), rows past A zero; on and below the diagonal (c); strictly
+    above (c_C − c) — ``sums`` (C/sub + 3 of them row block under row
+    block, in that order) and, for the ``backward``, ``back``: their
+    transposes side by side, which sums the cotangents of all of them
+    into d log α in one contraction."""
+    shift = sub.bit_length() - 1
+
+    def masks(row, col):
+        within = _ones_where((col <= row)
+                             & (row >> shift == col >> shift))
+        reach = []
+        for a in range(c // sub):
+            start = a * sub
+            reach.append(
+                _ones_where((col > row) & (col < start))
+                - _ones_where((col >= start) & (col <= row)
+                              & (row < start + sub)))
+        return [within, *reach, _ones_where(col <= row),
+                _ones_where(col > row)]
+
     row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    shift = sub.bit_length() - 1
-    upto, below, above, eye = (
-        _ones_where(col <= row), _ones_where(col < row),
-        _ones_where(col > row), _ones_where(col == row))
-    within = _ones_where((col <= row) & (row >> shift == col >> shift))
-    reach = []
-    for a in range(c // sub):
-        start = a * sub
-        reach.append(
-            _ones_where((col > row) & (col < start))
-            - _ones_where((col >= start) & (col <= row)
-                          & (row < start + sub)))
-    return upto, below, above, eye, within, tuple(reach)
+    sums = _for_mask_product(jnp.concatenate(masks(row, col), axis=0))
+    back = _for_mask_product(jnp.concatenate(
+        masks(col, row), axis=1)) if backward else None
+    return (_ones_where(col <= row), _ones_where(col < row),
+            _ones_where(col == row), sums, back)
 
 
-def _kda_factors(positions, q, k, log_alpha, sub):
+def _kda_factors(sums, q, k, log_alpha):
     """The two-factor form of Γ inside the contraction: the left
     factors K ⊙ e^c̃ and Q ⊙ e^c̃ (every exponent ≤ 0), and per
     sub-block A the right factor's scale e^(r_A − c_j) (≤ 1 for the
-    rows before A, ≤ e^MAX_EXPONENT inside it)."""
-    *_, within, reach = positions
-    near = jnp.exp(_exact(within, log_alpha))
-    scales = [jnp.exp(_exact(r, log_alpha)) for r in reach]
-    return near, k * near, q * near, scales
+    rows before A, ≤ e^MAX_EXPONENT inside it); e^c and e^(c_C − c)
+    with them — every sum of a chunk's log α (C, d_k) that is
+    exponentiated, each from its own terms, out of ONE product with
+    the stacked masks of :func:`_kda_positions`."""
+    near, *scales, grown, rest = (
+        jnp.exp(block) for block in jnp.split(
+            _mask_product(sums, log_alpha),
+            sums.shape[0] // log_alpha.shape[0], axis=0))
+    return near, k * near, q * near, scales, grown, rest
 
 
 def _kda_products(left, rights, sub, dot):
@@ -909,14 +1025,12 @@ def _kda_chunk_lower(positions, log_alpha, beta, q, k, *, sub):
     """A chunk's L = strict_lower(diag(β) M), and what the outputs need
     of the decays: e^c, e^(c_C − c), e^(c_C), Q's left factor and K's
     right factors (P is the same two-factor product as M)."""
-    upto, below, above, eye, _, _ = positions
-    _, left_k, left_q, scales = _kda_factors(positions, q, k, log_alpha,
-                                             sub)
+    _, below, eye, sums, _ = positions
+    _, left_k, left_q, scales, grown, rest = _kda_factors(
+        sums, q, k, log_alpha)
     rights = [k * e for e in scales]
     m = _kda_products(left_k, rights, sub, _exact)
     lower = below * (_rows(eye * beta) * m)
-    grown = jnp.exp(_exact(upto, log_alpha))
-    rest = jnp.exp(_exact(above, log_alpha))
     decay = jnp.exp(_cols(log_alpha))
     return lower, grown, rest, decay, left_q, rights
 
@@ -964,14 +1078,12 @@ def _kda_cotangents(positions, q, k, v, log_alpha, beta, x, d_w, d_kh,
                     d_u, d_decay, d_qc, d_p, *, dot_dtype, sub):
     """Cotangents of a chunk's q, k, v, log α (C, d_k), β from those of
     its W, K̂, U, decay (1, d_k), Qc and P, and its (I + L)⁻¹."""
-    upto, below, above, eye, within, reach = positions
+    upto, below, eye, sums, back = positions
     exact, mixed = _exact, _mixed(dot_dtype)
-    near, left_k, left_q, scales = _kda_factors(positions, q, k,
-                                                log_alpha, sub)
+    near, left_k, left_q, scales, grown, rest = _kda_factors(
+        sums, q, k, log_alpha)
     rights = [k * e for e in scales]
     m = _kda_products(left_k, rights, sub, exact)
-    grown = jnp.exp(exact(upto, log_alpha))
-    rest = jnp.exp(exact(above, log_alpha))
     decay = jnp.exp(_cols(log_alpha))
     beta_col = _rows(eye * beta)
     a = x * beta
@@ -988,31 +1100,33 @@ def _kda_cotangents(positions, q, k, v, log_alpha, beta, x, d_w, d_kh,
     d_beta = _cols(d_a * x) + _cols(eye * _rows(d_lower * m))
     # M and P by sub-blocks: rows A = left[A] · (K ⊙ scale_A)ᵀ
     d_k = grown * d_kg + rest * d_kh
-    d_alpha = exact(upto, grown * (d_qc * q + d_kg * k), trans_a=True) \
-        + exact(above, rest * d_kh * k, trans_a=True) + d_decay * decay
-    d_left_k, d_left_q = [], []
-    for at, (right, scale, signed) in enumerate(zip(rights, scales,
-                                                    reach)):
+    d_left_k, d_left_q, d_scales = [], [], []
+    for at, (right, scale) in enumerate(zip(rights, scales)):
         rows = slice(at * sub, (at + 1) * sub)
         d_left_k.append(exact(d_m[rows], right))
         d_left_q.append(mixed(d_pl[rows], right))
         d_right = exact(d_m[rows], left_k[rows], trans_a=True) \
             + mixed(d_pl[rows], left_q[rows], trans_a=True)
         d_k = d_k + d_right * scale
-        d_alpha = d_alpha + exact(signed, d_right * right, trans_a=True)
+        d_scales.append(d_right * right)
     d_left_k = jnp.concatenate(d_left_k, axis=0)
     d_left_q = jnp.concatenate(d_left_q, axis=0)
     d_k = d_k + d_left_k * near
     d_q = d_left_q * near + grown * d_qc
-    d_alpha = d_alpha + exact(
-        within, d_left_k * left_k + d_left_q * left_q, trans_a=True)
+    # log α: the cotangents of its sums' exponents, in the order of
+    # :func:`_kda_positions`, through the masks' transposes — the MXU
+    # sums all of them over ONE contraction
+    d_alpha = _mask_product(back, jnp.concatenate(
+        [d_left_k * left_k + d_left_q * left_q, *d_scales,
+         grown * (d_qc * q + d_kg * k), rest * d_kh * k], axis=0)) \
+        + d_decay * decay
     return d_q, d_k, d_v, d_alpha, d_beta
 
 
 def _kda_bwd_kernel(*refs, dot_dtype, together, sub):
     ins, outs = refs[:12], refs[12:]
     block, c = ins[0].shape[0], ins[0].shape[1]
-    positions = _kda_positions(c, sub)
+    positions = _kda_positions(c, sub, backward=True)
 
     def one(i):
         results = _kda_cotangents(
@@ -1029,7 +1143,7 @@ def _kda_forward_call(q, k, v, log_alpha, beta, interpret, dot_dtype,
                       block):
     g, n, c, dk = q.shape
     dv, block = v.shape[-1], min(block, g * n)
-    side_by_side = 2 if block % 2 == 0 and 2 * c <= _LANES else 1
+    side_by_side = _side_by_side(block, c)
     w, k_hat, u, decay, qc, p, x = _chunk_call(
         functools.partial(
             _kda_fwd_kernel, dot_dtype=dot_dtype,
