@@ -108,22 +108,22 @@ def one_kernel(name: str, struct):
     at its cell's shapes with bf16 products: the backward from the
     shapes of what the forward hands it."""
     family, _, way = name.split("_")
-    g, dk, dv = (32, 128, 128) if family == "kda" else (G, DK, DV)
-    forward, backward = (
-        (pd._kda_forward_call, pd._kda_backward_call) if family == "kda"
-        else (pd._chunk_forward_call, pd._chunk_backward_call))
+    channels = family == "kda"
+    g, dk, dv = (32, 128, 128) if channels else (G, DK, DV)
+    rule = pd._rule_for(channels)
     static = (False, jnp.dtype(jnp.bfloat16), pd.CHUNKS_PER_STEP)
     f32 = jnp.float32
     rows = [jax.ShapeDtypeStruct(shape, f32) for shape in (
         (g, N, C, dk), (g, N, C, dk), (g, N, C, dv),
-        (g, N, C, dk) if family == "kda" else (g, N, C), (g, N, C))]
+        (g, N, C, dk) if channels else (g, N, C), (g, N, C))]
     if way == "fwd":
-        return forward.lower(*map(struct, rows), *static)
+        return pd._chunk_forward_call.lower(rule, *map(struct, rows),
+                                            *static)
     outputs, inverse = jax.eval_shape(
-        lambda *a: forward(*a, *static), *rows)
-    if family == "kda":     # the backward is handed V at bf16
-        rows[2] = jax.ShapeDtypeStruct(rows[2].shape, static[1])
-    return backward.lower(*jax.tree.map(
+        lambda *a: pd._chunk_forward_call(rule, *a, *static), *rows)
+    # the backward is handed V at the width the rule keeps it
+    rows[2] = jax.ShapeDtypeStruct(rows[2].shape, rule.width(static[1]))
+    return pd._chunk_backward_call.lower(rule, *jax.tree.map(
         struct, (*rows, inverse, outputs)), *static)
 
 

@@ -152,8 +152,9 @@ def compile_for_described_chip(wf) -> dict:
     from jax.sharding import SingleDeviceSharding
     from znicz_tpu.accelerated_units import JitRegion
     for unit in wf.forwards:     # through Mosaic, not the interpreter
-        if getattr(unit, "_flash_pallas", False):
-            unit._flash_interpret = False
+        plan = getattr(unit, "_flash", None)
+        if plan is not None and plan.runs:
+            unit._flash = plan._replace(interpret=False)
     region = wf._region_unit.region
     wf.loader.run()
     region._vectors = region._collect_vectors()

@@ -220,20 +220,21 @@ def main() -> None:
         mfu = round(attn_train_flops() / dt / n_devices
                     / (peak_tflops(jax_device) * 1e12), 4)
     attn_unit, ln_unit = wf.forwards[0], wf.forwards[1]
+    plan = attn_unit._flash     # pallas_attention.plan, at initialize
     line = json.dumps({
         "metric": "seq_stack_train_tokens_per_sec_per_chip",
         "value": round(tokens_per_sec, 1),
         "unit": "tokens/sec/chip",
         "batch": BATCH, "seq_len": SEQ_LEN, "dim": DIM,
         "heads": HEADS, "flash_block_k": FLASH or None,
-        "pallas": attn_unit._flash_pallas, "chunk": CHUNK,
+        "pallas": plan.runs, "chunk": CHUNK,
         "causal": CAUSAL,
         # the multi-device arm: devices > 1 means a DP mesh;
         # shard_map records whether the kernels ran MESH-NATIVE
         # (per-shard under shard_map) vs forcibly disengaged
         # (SEQ_SHARD_MAP=0 → XLA cores — the fallback gate)
         "devices": n_devices,
-        "shard_map": attn_unit._flash_mesh is not None,
+        "shard_map": plan.mesh is not None,
         # the SP arm: ring = model-axis size, ring_fold = which fold
         # the hops actually ran ("pallas" = the round-6 kernel fold,
         # "scan" = the XLA fallback; null = no ring)
@@ -242,12 +243,12 @@ def main() -> None:
         # where the kernels find a head's tiles and how many heads
         # share a program: from the shapes (pallas_attention.
         # head_layout); null off the one-chip kernel path
-        "flash_layout": getattr(attn_unit, "_flash_layout", None),
+        "flash_layout": (plan.layout, plan.head_pack) if plan.runs
+        else None,
         # the causal tile schedule the kernels derived: compute
         # sub-tile inside the grid tile, share of T × T executed
-        "sub_tile": getattr(attn_unit, "_flash_sub_tile", None),
-        "executed_share": (getattr(attn_unit, "_flash_tiles", None)
-                           or {}).get("executed_share"),
+        "sub_tile": plan.sub_tile,
+        "executed_share": (plan.tiles or {}).get("executed_share"),
         "pallas_ln": bool(getattr(ln_unit, "_pallas_ln", False)),
         "interpret": INTERPRET,
         "step_time_ms": round(dt * 1e3, 3),

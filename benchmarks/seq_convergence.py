@@ -87,10 +87,8 @@ def train_curve(precision: str) -> dict:
         decision_config={"max_epochs": EPOCHS})
     wf._max_fires = 10 ** 9
     wf.initialize(device=XLADevice())
-    flash = bool(getattr(
-        next(u for u in wf.forwards
-             if type(u).__name__ == "MultiHeadAttention"),
-        "_flash_pallas", False))
+    flash = next(u for u in wf.forwards
+                 if type(u).__name__ == "MultiHeadAttention")._flash.runs
 
     losses, errors, valid_errors = [], [], []
     orig = wf.decision.on_epoch_ended

@@ -342,15 +342,15 @@ def kernel_run(ctx: Ctx, tag: str, wf, ring: bool
               f"{tag}: ring did not fold through the kernel "
               f"(active={attn.ring_active}, fold={attn._ring_fold})")
     else:
-        check(attn._flash_pallas, f"{tag}: flash gate did not engage")
-        check((attn._flash_mesh is not None) == on_mesh,
+        check(attn._flash.runs, f"{tag}: flash gate did not engage")
+        check((attn._flash.mesh is not None) == on_mesh,
               f"{tag}: flash kernel not per shard under shard_map")
     check(ln._pallas_ln, f"{tag}: layer-norm gate did not engage")
     check((ln._ln_mesh is not None) == on_mesh,
           f"{tag}: layer-norm kernel not per shard under shard_map")
-    check(attn._flash_interpret == ctx.toy
+    check(attn._flash.interpret == ctx.toy
           and ln._ln_interpret == ctx.toy,
-          f"{tag}: kernels interpreted={attn._flash_interpret} on "
+          f"{tag}: kernels interpreted={attn._flash.interpret} on "
           f"platform {wf.device.jax_device.platform}")
     attn_out, ln_out = first_step(wf)
     sums = [0.0, train_loss_sum(wf)]
@@ -377,7 +377,7 @@ def kernel_run(ctx: Ctx, tag: str, wf, ring: bool
 def reference_run(tag: str, wf) -> np.ndarray:
     """The attention output of the first forward on the XLA cores."""
     attn, ln = wf.forwards[0], wf.forwards[1]
-    check(not attn._flash_pallas and not attn.ring_active
+    check(not attn._flash.runs and not attn.ring_active
           and not ln._pallas_ln,
           f"{tag}: the reference forward did not take the XLA cores")
     return first_step(wf)[0]

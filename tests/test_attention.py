@@ -592,8 +592,9 @@ def test_block_through_the_flash_kernels_matches_the_oracle(name, d):
     np_f, np_g = build_block(NumpyDevice(), x, options)
     xla_f, xla_g = build_block(XLADevice(), x, options,
                                params=block_params(np_f))
-    assert xla_f._flash_pallas
-    assert xla_f._flash_layout == ("boundary", 128 // (d // BLOCK_H))
+    assert xla_f._flash.runs
+    assert (xla_f._flash.layout, xla_f._flash.head_pack) \
+        == ("boundary", 128 // (d // BLOCK_H))
     results = []
     for device, fwd, gd_u in ((np_f.device, np_f, np_g),
                               (xla_f.device, xla_f, xla_g)):

@@ -267,8 +267,9 @@ def test_attention_layer_compiled_for_v5e_moves_no_activation(
                                         **options)
     unit.link_attrs(src, ("input", "output"))
     unit.initialize(device=XLADevice())
-    assert unit._flash_pallas
-    assert unit._flash_layout == ("boundary", 128 // (d // heads))
+    assert unit._flash.runs
+    assert (unit._flash.layout, unit._flash.head_pack) \
+        == ("boundary", 128 // (d // heads))
 
     def struct(a):
         return None if a is None else jax.ShapeDtypeStruct(
@@ -291,9 +292,9 @@ def test_attention_layer_compiled_for_v5e_moves_no_activation(
     # T 2048 meets its keys in one K tile: the backward is one kernel
     backward = {1: ("znicz_flash_bwd",),
                 2: ("znicz_flash_dq", "znicz_flash_dkv")}
-    for kernel in ("znicz_flash_fwd",) + backward[unit._flash_backward]:
+    for kernel in ("znicz_flash_fwd",) + backward[unit._flash.backward]:
         assert f"%{kernel}" in text, kernel
-    for kernel in backward[3 - unit._flash_backward]:
+    for kernel in backward[3 - unit._flash.backward]:
         assert f"%{kernel}" not in text, kernel
     entry = text[text.index("\nENTRY "):]
     entry = entry[:entry.index("\n}")]

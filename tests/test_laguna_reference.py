@@ -357,7 +357,7 @@ def test_attention_options_against_the_numpy_oracle(case, kernels):
     expect |= {"gain_q", "gain_k"} if options.get("qk_norm") else set()
     unit = _agree(lambda wf: attention.MultiHeadAttention(wf, **options),
                   attention.GDMultiHeadAttention, expect, kernels)
-    assert unit._flash_pallas == kernels
+    assert unit._flash.runs == kernels
     heads = options["n_heads"]
     dh = options.get("head_dim") or D // heads
     kv = options.get("n_kv_heads") or heads
@@ -433,7 +433,7 @@ def test_a_shape_the_kernels_cannot_tile_keeps_its_band():
                         MultiHeadAttention(wf, n_heads=3, causal=True,
                                            window=window),
                         attention.GDMultiHeadAttention)
-        assert not fwd._flash_pallas
+        assert not fwd._flash.runs
         fwd.run()
         fwd.output.map_read()
         outs[window] = np.array(fwd.output.mem)
@@ -561,7 +561,7 @@ def test_the_toy_model_is_the_cell_s_model_in_small(one_step):
         == [None, 8, None]
     for unit in units:
         if isinstance(unit, attention.MultiHeadAttention):
-            assert unit._flash_pallas and unit._flash_layout[1] == 1
+            assert unit._flash.runs and unit._flash.head_pack == 1
             assert unit.weights.shape == (64, (unit.n_heads + 4) * 16)
             assert unit.weights_head_gate.shape == (64, unit.n_heads)
         if isinstance(unit, moe.MoE):
@@ -643,12 +643,20 @@ def test_what_the_expert_layers_report(one_step):
         assert mean == pytest.approx(held["rows_here"] / 4)
     text = obs_metrics.REGISTRY.to_prometheus()
     assert "znicz_moe_held{" in text and "znicz_flash_band{" in text
-    assert 'kv_group="3"' in text and 'kv_group="2"' in text
+    groups = {unit._flash.n_heads // unit._flash.n_kv_heads
+              for unit in wf.forwards if hasattr(unit, "n_kv_heads")}
+    assert {2, 3} <= groups
     windowed = wf.forwards[3]
     assert obs_metrics.flash_band(windowed.name, "window").value == 8
     assert obs_metrics.flash_band(windowed.name, "band_share").value \
         == pytest.approx((8 * 9 / 2 + 24 * 8) / 32 ** 2)
-    assert obs_metrics.flash_tiles(windowed.name, "band_edge").value >= 0
+    plan = windowed._flash
+    assert plan.window == 8 and plan.tiles["band_edge"] >= 0
+    assert obs_metrics.flash_band(
+        windowed.name, "executed_share").value \
+        == plan.tiles["executed_share"]
+    assert "%d query heads to a K/V head, window 8" % (
+        plan.n_heads // plan.n_kv_heads) in plan.line()
 
 
 # ----------------------------------------------------------------------

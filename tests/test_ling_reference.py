@@ -727,7 +727,7 @@ def test_the_toy_model_is_the_cell_s_model_in_small(one_step):
             assert obs_metrics.attention_latent(
                 unit.name, "qk_rope").value == 64
             if backend == "xla":
-                assert unit._flash_pallas and unit._flash_interpret
+                assert unit._flash.runs and unit._flash.interpret
         if isinstance(unit, moe.MoE):
             assert unit.select_bias_on and unit.groups == (4, 2)
             assert unit.held == (0, 1)

@@ -92,10 +92,6 @@ def test_canonical_families_render_in_exposition():
         m.fleet_replicas("cov", "lm").set(2),
         m.fleet_tenant_tokens("cov", "tenant").set(8.0),
         m.fleet_traffic_weight("cov", "lm", "v2").set(0.25),
-        m.flash_tiles("cov_attention", "interior").set(28),
-        m.flash_layout("cov_attention", "boundary", 2).set(1),
-        m.flash_backward("cov_attention", 1).set(1),
-        m.flash_forward("cov_attention", "none", "lanes", "q").set(1),
         m.flash_band("cov_attention", "band_share").set(0.06),
         m.moe_held("cov_moe", "rows_here").set(1280),
         m.moe_gmm_rows("cov_moe", "visited").set(41216),

@@ -127,7 +127,7 @@ def test_the_toy_model_is_the_cell_s_model_in_small(one_step):
             assert unit.weights.shape == (64, 4 * (12 + 12 + 24))
             assert obs_metrics.delta_scan(unit.name, "chunks").value == 2
         if isinstance(unit, attention.MultiHeadAttention):
-            assert unit._flash_pallas and unit.post_norm == "rms"
+            assert unit._flash.runs and unit.post_norm == "rms"
             assert unit.rope_theta is None and unit.qk_norm == "rms"
             assert unit.gain_q.shape == (64,)
         if isinstance(unit, moe.GatedMLP):

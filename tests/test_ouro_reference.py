@@ -177,7 +177,7 @@ def test_the_toy_model_is_the_cell_s_model_in_small(one_step):
     assert len(wf.forwards) == len(wf.gds) == len(table)
     for unit in wf.forwards:
         if isinstance(unit, attention.MultiHeadAttention):
-            assert unit._flash_pallas and unit.rope_theta == 1e6
+            assert unit._flash.runs and unit.rope_theta == 1e6
             assert unit.pre_norm == unit.post_norm == "rms"
             assert unit.gain_norm.shape == unit.gain_post.shape == (64,)
         if isinstance(unit, moe.GatedMLP):

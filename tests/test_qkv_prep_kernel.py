@@ -193,17 +193,55 @@ PARENT = {("head", 64): (104, 513), ("head", 40): (110, 381),
           ("channel", 64): (57, 518), ("channel", 40): (63, 392)}
 
 
-@pytest.mark.parametrize("decay,t", sorted(PARENT))
-def test_at_96_by_192_the_program_is_the_parent_s(decay, t, engine):
+#: the same of the unit's forward-AND-backward program (``jax.vjp`` of
+#: ``xla_forward`` pulled back from ones, T 64), which holds the
+#: ``znicz_*_chunk_bwd`` and ``znicz_*_state_bwd`` kernels' bodies too, at
+#: 96 × 192 and at 128 × 128 (Ling's shape, the prep path), counted AT
+#: THE PARENT (commit 895744d, PR 41) with this file's own helpers
+#: before PR 42 put one scaffold under the two decay shapes: a scaffold
+#: that adds or drops an equation either way fails here.
+PARENT_BOTH_WAYS = {("head", 96, 192): (307, 1255),
+                    ("head", 128, 128): (164, 1659),
+                    ("channel", 96, 192): (206, 1284),
+                    ("channel", 128, 128): (152, 1644)}
+
+
+def _both_ways(forward):
+    def run(*args):
+        out, back = jax.vjp(forward, *args)
+        return out, back(jnp.ones_like(out))
+    return run
+
+
+@pytest.mark.parametrize(
+    "decay,t,head,both_ways",
+    [(decay, t, (96, 192), False) for decay, t in sorted(PARENT)]
+    + [(decay, 64, (dk, dv), True)
+       for decay, dk, dv in sorted(PARENT_BOTH_WAYS)])
+def test_at_96_by_192_the_program_is_the_parent_s(decay, t, head,
+                                                  both_ways, engine):
     _kernels_on(engine)
-    unit = _unit(96, 192, t=t, **(CHANNEL if decay == "channel" else {}))
-    assert unit._kernels and not unit._prep
-    assert obs_metrics.delta_scan("mixer", "prep_path").value == 0.0
+    unit = _unit(*head, t=t, **(CHANNEL if decay == "channel" else {}))
+    prep = pd.prep_legal(*head)
+    assert unit._kernels and unit._prep == prep
+    assert obs_metrics.delta_scan("mixer", "prep_path").value == prep
     assert obs_metrics.delta_scan("mixer", "chunk_path").value == 1.0
-    jaxpr = jax.make_jaxpr(unit.xla_forward.__wrapped__)(
+    forward = unit.xla_forward.__wrapped__
+    jaxpr = jax.make_jaxpr(_both_ways(forward) if both_ways else forward)(
         *unit.forward_args()).jaxpr
-    assert (len(jaxpr.eqns), _equations(jaxpr)) == PARENT[decay, t]
-    assert not [name for name in _calls(jaxpr) if "qkv_prep" in name]
+    assert (len(jaxpr.eqns), _equations(jaxpr)) == (
+        PARENT_BOTH_WAYS[(decay, *head)] if both_ways
+        else PARENT[decay, t])
+    kernels = [name for name in _calls(jaxpr) if name.startswith("znicz")]
+    assert ("qkv_prep" in " ".join(kernels)) == prep
+    if both_ways:   # each shape's four, and none of the other's
+        mine, other = ("kda", "gdr") if decay == "channel" \
+            else ("gdr", "kda")
+        state = "kda_state" if decay == "channel" else "delta_state"
+        assert [name for name in kernels if "qkv_prep" not in name] == [
+            f"znicz_{mine}_chunk_fwd", f"znicz_{state}_fwd",
+            f"znicz_{state}_bwd", f"znicz_{mine}_chunk_bwd"]
+        assert other not in " ".join(kernels)
 
 
 @pytest.mark.parametrize("decay", ["head", "channel"])

@@ -229,9 +229,9 @@ def _fake_tpu(monkeypatch):
 def test_flash_gate_engages_shard_map_on_mesh(monkeypatch):
     _fake_tpu(monkeypatch)
     unit = _attention_unit(XLADevice(mesh=make_mesh()))
-    assert unit._flash_pallas
-    assert unit._flash_mesh is not None
-    assert tuple(unit._flash_spec) == (DATA_AXIS, None, None, None)
+    assert unit._flash.runs
+    assert unit._flash.mesh is not None
+    assert tuple(unit._flash.spec) == (DATA_AXIS, None, None, None)
 
 
 def test_flash_gate_fallback_switch_guards_gspmd(monkeypatch):
@@ -241,10 +241,10 @@ def test_flash_gate_fallback_switch_guards_gspmd(monkeypatch):
     _fake_tpu(monkeypatch)
     root.common.engine.pallas_shard_map = False
     unit = _attention_unit(XLADevice(mesh=make_mesh()))
-    assert not unit._flash_pallas
-    assert unit._flash_mesh is None
+    assert not unit._flash.runs
+    assert unit._flash.mesh is None
     # single device is untouched by the switch
-    assert _attention_unit(XLADevice())._flash_pallas
+    assert _attention_unit(XLADevice())._flash.runs
 
 
 def test_flash_gate_rejects_illegal_head_dim(monkeypatch):
@@ -253,10 +253,10 @@ def test_flash_gate_rejects_illegal_head_dim(monkeypatch):
     shape)."""
     _fake_tpu(monkeypatch)
     unit = _attention_unit(XLADevice(), d=16, heads=16)   # dh = 1
-    assert not unit._flash_pallas
+    assert not unit._flash.runs
     unit = _attention_unit(XLADevice(), d=16, heads=4)    # dh = 4
-    assert not unit._flash_pallas
-    assert _attention_unit(XLADevice(), d=16, heads=2)._flash_pallas
+    assert not unit._flash.runs
+    assert _attention_unit(XLADevice(), d=16, heads=2)._flash.runs
 
 
 def test_ring_fold_gate_engages_kernel_on_capable_paths(monkeypatch):
@@ -312,42 +312,41 @@ def test_head_pack_gate(monkeypatch, caplog):
     a program, is resolved from the shapes alone — the address in the
     projections' own layout at dh 64 (pairs) and dh 128, the head-major
     one where no lane-legal column block exists — and reported in the
-    info line and in ``znicz_flash_layout``.  The retired
+    plan and its info line.  The retired
     ``engine.flash_head_pack`` steers nothing."""
     import logging
 
     from znicz_tpu.observe import metrics as obs_metrics
     _fake_tpu(monkeypatch)
     unit = _attention_unit(XLADevice(), d=32, heads=2)   # dh = 16
-    assert unit._flash_pallas
-    assert unit._flash_layout == ("head_major", 1)
+    assert unit._flash.runs
+    assert (unit._flash.layout, unit._flash.head_pack) == ("head_major", 1)
     root.common.engine.flash_head_pack = True
     unit = _attention_unit(XLADevice(), d=32, heads=2)
-    assert unit._flash_layout == ("head_major", 1)
+    assert (unit._flash.layout, unit._flash.head_pack) == ("head_major", 1)
     with caplog.at_level(logging.INFO):
         unit = _attention_unit(XLADevice(), d=128, heads=2)  # dh = 64
-    assert unit._flash_layout == ("boundary", 2)
+    assert (unit._flash.layout, unit._flash.head_pack) == ("boundary", 2)
     assert "layout=boundary, head pack 2" in caplog.text
-    assert obs_metrics.flash_layout(unit.name, "boundary", 2).value == 1
-    assert ('znicz_flash_layout{unit="%s",layout="boundary",pack="2",'
-            'kv_group="1"} 1' % unit.name) \
-        in obs_metrics.REGISTRY.to_prometheus()
+    assert unit._flash.n_heads == unit._flash.n_kv_heads == 2
+    assert "query heads to a K/V head" not in unit._flash.line()
     # non-causal: the backward keeps its dq and dk/dv kernels
-    assert unit._flash_backward == 2
+    assert unit._flash.backward == 2
     assert "backward passes 2" in caplog.text
-    assert obs_metrics.flash_backward(unit.name, 2).value == 1
-    assert 'znicz_flash_backward{unit="%s",passes="2"} 1' % unit.name \
-        in obs_metrics.REGISTRY.to_prometheus()
+    # the families the plan's line replaced are gone
+    for family in ("layout", "backward", "forward", "tiles"):
+        assert "znicz_flash_%s{" % family \
+            not in obs_metrics.REGISTRY.to_prometheus()
     unit = _attention_unit(XLADevice(), d=256, heads=2)  # dh = 128
-    assert unit._flash_layout == ("boundary", 1)
+    assert (unit._flash.layout, unit._flash.head_pack) == ("boundary", 1)
     # an odd head count keeps one head per program, never raises
     unit = _attention_unit(XLADevice(), d=192, heads=3)
-    assert unit._flash_layout == ("head_major", 1)
+    assert (unit._flash.layout, unit._flash.head_pack) == ("head_major", 1)
     # the ring keeps the head-major address and takes the same pack
     unit = _attention_unit(
         XLADevice(mesh=make_mesh(n_data=2, n_model=2)),
         seq_parallel=True, d=128, heads=2)
-    assert unit._ring_fold == "pallas" and unit._flash_layout is None
+    assert unit._ring_fold == "pallas" and unit._flash.layout is None
 
 
 def test_causal_schedule_resolves_from_shapes_not_options(monkeypatch,
@@ -357,7 +356,7 @@ def test_causal_schedule_resolves_from_shapes_not_options(monkeypatch,
     are the chooser's (1024 × 2048 at T 2048), the kernels walk compute
     sub-tiles inside them, and no engine option changes either — the retired
     ``engine.flash_causal_block`` included.  The split of the T × T
-    square is in the info line and in ``znicz_flash_tiles``."""
+    square is in the plan and its info line."""
     import logging
 
     from znicz_tpu.observe import metrics as obs_metrics
@@ -367,59 +366,53 @@ def test_causal_schedule_resolves_from_shapes_not_options(monkeypatch,
     # kernel, so the big T costs nothing here)
     with caplog.at_level(logging.INFO):
         unit = _attention_unit(XLADevice(), t=2048, causal=True)
-    assert unit._flash_pallas
-    assert (unit._flash_block_q, unit._flash_block_k) == (1024, 2048)
-    sub = unit._flash_sub_tile
+    assert unit._flash.runs
+    assert (unit._flash.block_q, unit._flash.block_k) == (1024, 2048)
+    sub = unit._flash.sub_tile
     assert sub == pa.sub_tile_for(True, 1024, 2048) == (512, 512)
-    tiles = unit._flash_tiles
+    tiles = unit._flash.tiles
     assert tiles == pa.causal_tile_counts(2048, 2048, 1024, 2048, *sub)
     assert tiles["executed_share"] <= 0.625
     assert f"{tiles['executed_share']:.4f} of T×T executed" in caplog.text
-    for cls in ("interior", "crossing", "skipped"):
-        assert obs_metrics.flash_tiles(unit.name, cls).value \
-            == tiles[cls]
-    assert 'znicz_flash_tiles{unit="%s",class="skipped"}' % unit.name \
-        in obs_metrics.REGISTRY.to_prometheus()
+    assert "%d interior + %d crossing of %d = " % (
+        tiles["interior"], tiles["crossing"], tiles["interior"]
+        + tiles["crossing"] + tiles["skipped"]) in unit._flash.line()
+    assert unit._flash.line() in caplog.text
     # one K tile holds every key a Q tile sees: the backward is ONE
     # kernel, from the shapes (pallas_attention.backward_passes)
-    assert unit._flash_backward == pa.backward_passes(True, 2048, 2048) == 1
+    assert unit._flash.backward == pa.backward_passes(True, 2048, 2048) == 1
     assert "backward passes 1" in caplog.text
-    assert obs_metrics.flash_backward(unit.name, 1).value == 1
-    assert 'znicz_flash_backward{unit="%s",passes="1"} 1' % unit.name \
-        in obs_metrics.REGISTRY.to_prometheus()
     # … and a row block of the forward meets its keys in ONE visit, so
     # it carries no softmax state (pallas_attention.forward_form)
-    assert unit._flash_forward == pa.forward_form(2048, 1024, 2048, 16 // 2)
-    assert unit._flash_forward.state == "none"
+    assert unit._flash.forward == pa.forward_form(2048, 1024, 2048, 16 // 2)
+    assert unit._flash.forward.state == "none"
     assert "fwd_state: none, fwd_stats: lanes, fwd_scale: " in caplog.text
-    assert obs_metrics.flash_forward(
-        unit.name, *unit._flash_forward).value == 1
-    assert 'znicz_flash_forward{unit="%s",state="none",stats="lanes",' \
-        % unit.name in obs_metrics.REGISTRY.to_prometheus()
+    assert unit._flash.forward.stats == "lanes"
+    assert unit._flash.window is None and "window" not in unit._flash.line()
     # T 4096: the backward takes the two 2048-long K tiles whole, the
     # forward carries its state over them; past that a dq and a dk/dv
     # kernel
     assert _attention_unit(XLADevice(), t=4096,
-                           causal=True)._flash_forward.state == "carried"
+                           causal=True)._flash.forward.state == "carried"
     assert _attention_unit(XLADevice(), t=4096,
-                           causal=True)._flash_backward == 1
+                           causal=True)._flash.backward == 1
     assert _attention_unit(XLADevice(), t=8192,
-                           causal=True)._flash_backward == 2
+                           causal=True)._flash.backward == 2
     # an option of that name steers nothing any more
     root.common.engine.flash_causal_block = 256
     again = _attention_unit(XLADevice(), t=2048, causal=True)
-    assert (again._flash_block_q, again._flash_block_k) == (1024, 2048)
-    assert again._flash_sub_tile == sub
+    assert (again._flash.block_q, again._flash.block_k) == (1024, 2048)
+    assert again._flash.sub_tile == sub
     # non-causal units keep the single-body tile and the whole square
     unit = _attention_unit(XLADevice(), t=2048)
-    assert unit._flash_sub_tile == (1024, 1024)
-    assert unit._flash_tiles["executed_share"] == 1.0
-    assert unit._flash_tiles["skipped"] == 0
+    assert unit._flash.sub_tile == (1024, 1024)
+    assert unit._flash.tiles["executed_share"] == 1.0
+    assert unit._flash.tiles["skipped"] == 0
     # off the kernel path there is no schedule to report
     monkeypatch.setattr(pallas_kernels, "is_tpu_device",
                         lambda device: False)
     unit = _attention_unit(XLADevice(), t=2048, causal=True)
-    assert not unit._flash_pallas and unit._flash_tiles is None
+    assert not unit._flash.runs and unit._flash.tiles is None
 
 
 def _ln_unit(device, shape=(8, 16), model_shard_dim=None):
@@ -513,8 +506,8 @@ def _train(engaged: bool):
     wf = _seq_workflow()
     wf.initialize(device=XLADevice(mesh=make_mesh()))
     attn, ln = wf.forwards[0], wf.forwards[1]
-    assert attn._flash_pallas == engaged
-    assert (attn._flash_mesh is not None) == engaged
+    assert attn._flash.runs == engaged
+    assert (attn._flash.mesh is not None) == engaged
     assert bool(ln._pallas_ln) == engaged
     wf.run()
     attn.weights.map_read()
@@ -548,7 +541,7 @@ def test_engaged_kernels_run_inside_run_chunk_scan():
     root.common.engine.pallas_interpret = True
     wf = _seq_workflow()
     wf.initialize(device=XLADevice(mesh=make_mesh()))
-    assert wf.forwards[0]._flash_mesh is not None
+    assert wf.forwards[0]._flash.mesh is not None
     assert wf.forwards[1]._ln_mesh is not None
     region = wf._region_unit.region
     before = wf.forwards[0].weights.mem.copy()

@@ -542,23 +542,6 @@ def grad_accum_microbatches(workflow: str) -> Gauge:
         labels=("workflow",)).labels(workflow=workflow)
 
 
-def flash_tiles(unit: str, cls: str) -> Gauge:
-    """How an attention unit's flash kernels split the T × T score
-    square into compute sub-tiles (``class`` = ``interior``: computed
-    without a mask, ``crossing``: computed under the causal mask,
-    ``skipped``: above the diagonal — or, for a windowed layer, below
-    its band — never visited; ``band_edge``, windowed layers only:
-    computed under the mask because the band's lower edge passes
-    through).  Static per program, set once at ``initialize``:
-    1 − skipped ÷ the sum is the share of T × T this model's
-    attention computes."""
-    return REGISTRY.gauge(
-        "znicz_flash_tiles",
-        "Flash-attention compute sub-tiles of the T x T square, by "
-        "class (interior, crossing, band_edge, skipped)",
-        labels=("unit", "class")).labels(**{"unit": unit, "class": cls})
-
-
 def flash_band(unit: str, stat: str) -> Gauge:
     """A windowed attention unit's band (``stat`` = ``window``: the
     positions a row sees; ``band_share``: Σ_r min(r + 1, window) ÷ T²,
@@ -571,66 +554,6 @@ def flash_band(unit: str, stat: str) -> Gauge:
         "Window of a flash-attention layer, the share of T x T inside "
         "its band and the share its tiles execute",
         labels=("unit", "stat")).labels(unit=unit, stat=stat)
-
-
-def flash_layout(unit: str, layout: str, pack: int,
-                 kv_group: int = 1) -> Gauge:
-    """Where an attention unit's flash kernels find a head's tiles:
-    ``layout`` = ``boundary`` (column blocks of the projections' own
-    (B, T, ·) arrays, addressed in place: no transpose, slice or
-    concatenate around a kernel) or ``head_major`` (a head width with
-    no lane-legal column block: the tiles are moved there first);
-    ``pack`` = heads per kernel program (2: pairs of dh-64 heads fill
-    the 128 lanes); ``kv_group`` = query heads that read one K/V head
-    (1: multi-head attention).  Static per program, 1 for the
-    combination in force, set once at ``initialize``."""
-    return REGISTRY.gauge(
-        "znicz_flash_layout",
-        "Address of the flash-attention kernels' tiles (boundary, "
-        "head_major), heads per kernel program and query heads per K/V "
-        "head; 1 for the combination in force",
-        labels=("unit", "layout", "pack", "kv_group")).labels(
-            unit=unit, layout=layout, pack=str(pack),
-            kv_group=str(kv_group))
-
-
-def flash_backward(unit: str, passes: int) -> Gauge:
-    """How often an attention unit's flash BACKWARD recomputes a score
-    sub-tile (``pallas_attention.backward_passes``, from the call's
-    shapes): ``passes`` = ``1`` — one kernel, ``znicz_flash_bwd``, dq
-    accumulating in the dk/dv walk (a causal, un-windowed call whose K
-    side is one grid tile) — or ``2``: ``znicz_flash_dq`` +
-    ``znicz_flash_dkv``.  Static per program, 1 for the form in force,
-    set once at ``initialize``."""
-    return REGISTRY.gauge(
-        "znicz_flash_backward",
-        "Passes over the score tiles in the flash-attention backward "
-        "(1: one kernel for dq, dk and dv; 2: a dq and a dk/dv kernel); "
-        "1 for the form in force",
-        labels=("unit", "passes")).labels(unit=unit, passes=str(passes))
-
-
-def flash_forward(unit: str, state: str, stats: str, scale: str) -> Gauge:
-    """What ONE visit of an attention unit's flash FORWARD does between
-    the score product and the value product
-    (``pallas_attention.forward_form``, from the call's shapes):
-    ``state`` = ``none`` — a row block meets all its keys in one visit
-    (one K step: T ≤ 2048) and writes ``o`` and ``lse`` itself — or
-    ``carried``: m, l and the accumulator wait in VMEM for the next K
-    tile (a Q tile whose keys all lie in the first still visits
-    state-free); ``stats`` = ``lanes``: a row's m and l replicated over
-    a 128-lane tile through the arithmetic, folded across lanes once
-    per visit; ``scale`` = ``q`` (1/√dh a power of two: folded into q,
-    nothing rounded) or ``exp`` (into the exponential's own multiply).
-    Static per program, 1 for the form in force, set once at
-    ``initialize``."""
-    return REGISTRY.gauge(
-        "znicz_flash_forward",
-        "Form of a visit of the flash-attention forward: softmax state "
-        "(none, carried), layout of the row statistics, where 1/sqrt(dh) "
-        "enters; 1 for the form in force",
-        labels=("unit", "state", "stats", "scale")).labels(
-            unit=unit, state=state, stats=stats, scale=scale)
 
 
 def moe_expert_tokens(unit: str, stat: str) -> Gauge:

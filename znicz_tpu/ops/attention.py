@@ -389,6 +389,10 @@ class MultiHeadAttention(Forward):
         #: GD pair (same trace; transient — never pickled, cleared by
         #: the consumer; a looped span keeps one per pass on its tape)
         self._traced_vjp = None
+        #: how the flash kernels run this layer's call, decided once at
+        #: ``initialize`` (``pallas_attention.plan``; ``pallas_mla.plan``
+        #: for a latent K/V): the ONE value the unit holds of them
+        self._flash = None
         self.weights_out = Vector(name=f"{self.name}.weights_out")
         self.bias_out = Vector(name=f"{self.name}.bias_out")
 
@@ -485,42 +489,18 @@ class MultiHeadAttention(Forward):
                 # time rides the ring: declared, not hand-set
                 self.partition_leaf(
                     "output", P(DATA_AXIS, self._ring_axis))
-        # fused flash-attention Pallas kernel (ops/pallas_attention):
-        # DEFAULT ON for real TPU devices — the measured winner at
-        # every T (chip A/B in PERF.md round 5 / SEQ_BENCH.json:
-        # 2.51M vs 1.63M tokens/s at T=2048, and the only form that
-        # runs T≥8k on one chip at speed).  Opt out with
-        # ``root.common.engine.flash_attention = False``; resolved
-        # ONCE here like every engine flag.  Since round 6 the RING
-        # path folds with the same kernel per hop
-        # (``engine.ring_pallas_fold``, auto = TPU/interpret); shapes
-        # the kernel's tiling cannot cover fall back to the XLA cores
-        # (local) or the scan fold (ring).
+        # the core: the fused flash kernels where their plan says they
+        # run this call (``pallas_attention.plan``), the RING folding
+        # its hops with the same kernels (``engine.ring_pallas_fold``,
+        # auto = TPU/interpret), else the XLA cores (local) or the scan
+        # fold (ring)
         from znicz_tpu.ops import pallas_attention, pallas_kernels
-        from znicz_tpu.parallel.mesh import kernel_shard_spec, \
-            spec_divides
         from znicz_tpu.utils.config import root
-        # interpret-mode lever: lets the virtual CPU mesh run the REAL
-        # kernels (shard_map oracle tests / dryruns); never default
-        interpret = bool(root.common.engine.get("pallas_interpret",
-                                                False))
-        tpu_capable = (pallas_kernels.is_tpu_device(self.device)
-                       or interpret)
-        # where a head's tiles lie and how many heads share a kernel
-        # program come from the shapes (pallas_attention.head_layout):
-        # at dh 128 a head, at dh 64 a pair of heads is a 128-lane
-        # column block of the projection, which the kernels address in
-        # place; no option steers it (``engine.flash_head_pack`` was
-        # the pair body's opt-in: measured on the LM cell, −1.4 ms of
-        # kernels per step with the copies still there, and gone with
-        # them; PERF.md §6, PR 28)
-        group = self.n_heads // self.n_kv_heads
-        layout, head_pack = pallas_attention.head_layout(self.n_heads,
-                                                         dh, group)
-        # a window that covers the sequence is the causal call
-        window = self.window if self.window is not None \
-            and self.window < t else None
-        self._flash_window = window
+        self._flash = pallas_attention.plan(
+            self.device, b, t, self.n_heads, self.n_kv_heads, dh,
+            self.causal, self.window, self.flash_block_k,
+            model_sharded=getattr(self.input, "model_shard_dim", None)
+            is not None, ring=self._ring_active)
         #: which fold the ring runs ("pallas"/"scan"; None = no ring)
         #: — the multichip dryrun attests this
         self._ring_fold = None
@@ -531,134 +511,26 @@ class MultiHeadAttention(Forward):
                 ring_fold_choice
             rflag = root.common.engine.get("ring_pallas_fold", "auto")
             if rflag == "auto":
-                rflag = tpu_capable
+                rflag = (pallas_kernels.is_tpu_device(self.device)
+                         or self._flash.interpret)
             self._ring_fold, self._ring_block_q, self._ring_block_k \
                 = ring_fold_choice(
                     mesh, (b, t, self.n_heads, dh),
                     axis_name=self._ring_axis,
                     block_k=self.flash_block_k,
                     pallas_fold=bool(rflag))
-        # the kernels' tile schedule comes from the shapes and
-        # ``causal`` alone (pallas_attention.grid_blocks /
-        # sub_tile_for; PERF.md §6, PR 24): the grid tile is the unit
-        # of DMA — for a causal unit its K side spans up to 2048 keys,
-        # so at T ≤ 2048 a row block meets its whole key range in one
-        # grid step — and inside it the kernels walk compute sub-tiles,
-        # skip those above the diagonal and mask only the run the
-        # diagonal can cross.  No engine option steers it
-        # (``engine.flash_causal_block`` shrank the GRID tile instead:
-        # 1.39 × slower at 512, 2.8 × at 256 on the chip, and is gone).
-        bq, bk = pallas_attention.grid_blocks(
-            self.causal, t, t, None, self.flash_block_k) \
-            if window is None else pallas_attention.band_blocks(t)
-        self._flash_block_q, self._flash_block_k = bq, bk
-        self._flash_interpret = interpret
-        self._flash_mesh = None
-        self._flash_spec = None
-        #: why the kernel did not engage (None = it did, or the ring
-        #: owns the core)
-        refused = pallas_kernels.kernel_refusal(
-            self.device, "flash_attention", interpret)
-        local = refused is None and not self._ring_active
-        if local and not pallas_attention.kernel_legal(t, t, dh, bq,
-                                                       bk):
-            # T must tile evenly and the head dim must be lane-legal
-            # (dh % 8 — e.g. dh=1 via a to_sequence net would crash
-            # Mosaic at trace instead of falling back; ADVICE round 5)
-            refused = (f"T={t}, head dim {dh} do not tile by blocks "
-                       f"({bq}, {bk})")
-        elif local and mesh is not None and mesh.size > 1:
-            # mesh-native path: the opaque pallas_call has no GSPMD
-            # sharding rule — un-shard_mapped on a multi-device mesh
-            # it would replicate-and-gather the batch-sharded operands
-            # onto every device.  Run it per-shard under shard_map
-            # with the batch riding the data axis instead;
-            # ``engine.pallas_shard_map = False`` restores the
-            # conservative single-device gate (kernel off on meshes —
-            # the safe fallback, mirroring _pallas_ln's old guard).
-            spec, _ = kernel_shard_spec(mesh, 4)
-            if not root.common.engine.get("pallas_shard_map", True):
-                refused = "engine.pallas_shard_map is off"
-            elif getattr(self.input, "model_shard_dim", None) \
-                    is not None:
-                refused = "the input is model-sharded"
-            elif not spec_divides(mesh, (b, t, self.n_heads, dh),
-                                  spec):
-                refused = (f"batch {b} does not divide over mesh "
-                           f"{dict(mesh.shape)}")
-            else:
-                self._flash_mesh, self._flash_spec = mesh, spec
-        self._flash_pallas = local and refused is None
-        #: the compute sub-tile the kernels walk inside a (bq, bk)
-        #: grid tile, how the T × T square splits over it, and where
-        #: the kernels find a head's tiles ("boundary": in the
-        #: projections' own layout; "head_major": moved there first)
-        self._flash_sub_tile = None
-        self._flash_tiles = None
-        self._flash_layout = None
-        #: passes over the score tiles in the backward (1: one kernel)
-        self._flash_backward = None
-        #: what a visit of the forward's walk does (state, statistics,
-        #: where 1/√dh enters): ``pallas_attention.forward_form``
-        self._flash_forward = None
-        if self._flash_pallas:
-            self._flash_layout = (layout, head_pack)
-            self._flash_backward = pallas_attention.backward_passes(
-                self.causal, t, bk, window)
-            self._flash_forward = pallas_attention.forward_form(
-                t, bq, bk, dh, window)
-            sq, sk = pallas_attention.sub_tile_for(self.causal, bq, bk) \
-                if window is None else (bq, bk)
-            self._flash_sub_tile = (sq, sk)
-            self._flash_tiles = pallas_attention.causal_tile_counts(
-                t, t, bq, bk, sq, sk, causal=self.causal, window=window)
-            from znicz_tpu.observe import metrics as obs_metrics
-            for cls in ("interior", "crossing", "skipped", "band_edge"):
-                if cls in self._flash_tiles:
-                    obs_metrics.flash_tiles(self.name, cls).set(
-                        self._flash_tiles[cls])
-            obs_metrics.flash_layout(self.name, layout, head_pack,
-                                     group).set(1)
-            obs_metrics.flash_backward(self.name,
-                                       self._flash_backward).set(1)
-            obs_metrics.flash_forward(self.name,
-                                      *self._flash_forward).set(1)
-            if window is not None:
-                share = pallas_attention.band_share(t, window)
-                for stat, value in (
-                        ("window", window), ("band_share", share),
-                        ("executed_share",
-                         self._flash_tiles["executed_share"])):
-                    obs_metrics.flash_band(self.name, stat).set(value)
-        if self._ring_active:
             self.info("%s: ring attention over '%s', %s fold",
                       self.name, self._ring_axis, self._ring_fold)
-        elif self._flash_pallas:
-            tiles = self._flash_tiles
-            self.info("%s: flash kernel, blocks (%d, %d), sub-tiles "
-                      "(%d, %d): %d interior + %d crossing of %d = "
-                      "%.4f of T×T executed, layout=%s, head pack %d, "
-                      "fwd_state: %s, fwd_stats: %s, fwd_scale: %s, "
-                      "backward passes %d%s%s%s",
-                      self.name, bq, bk, *self._flash_sub_tile,
-                      tiles["interior"], tiles["crossing"]
-                      + tiles.get("band_edge", 0),
-                      sum(n for cls, n in tiles.items()
-                          if cls != "executed_share"),
-                      tiles["executed_share"],
-                      layout, head_pack, *self._flash_forward,
-                      self._flash_backward,
-                      "" if group == 1 and window is None else
-                      ", %d query heads to a K/V head, window %s (band "
-                      "%.4f of T×T)" % (
-                          group, window,
-                          pallas_attention.band_share(t, window)),
-                      ", per shard under shard_map"
-                      if self._flash_mesh is not None else "",
-                      ", INTERPRETED" if interpret else "")
         else:
-            self.info("%s: XLA attention core — %s", self.name,
-                      refused)
+            self.info("%s: %s", self.name, self._flash.line())
+        if self._flash.runs and self._flash.window is not None:
+            from znicz_tpu.observe import metrics as obs_metrics
+            for stat, value in (
+                    ("window", self._flash.window),
+                    ("band_share", self._flash.band_share),
+                    ("executed_share",
+                     self._flash.tiles["executed_share"])):
+                obs_metrics.flash_band(self.name, stat).set(value)
         self.init_vectors(self.input, self.output, self.weights,
                           self.bias, self.weights_out, self.bias_out,
                           self.gain_norm, self.gain_q, self.gain_k,
@@ -686,40 +558,19 @@ class MultiHeadAttention(Forward):
         self.output.reset(np.zeros((b, t, d),
                                    dtype=self.output_store_dtype))
         from znicz_tpu.observe import metrics as obs_metrics
-        from znicz_tpu.ops import pallas_kernels, pallas_mla
+        from znicz_tpu.ops import pallas_mla
         from znicz_tpu.parallel import partition
-        from znicz_tpu.utils.config import root
         self.partition_leaf("output", partition.BATCH)
         for attr in ("weights_kv_up", "gain_latent"):
             self.partition_leaf(attr, partition.REPLICATED)
         self._ring_active = False
-        interpret = bool(root.common.engine.get("pallas_interpret",
-                                                False))
-        refused = pallas_kernels.kernel_refusal(
-            self.device, "flash_attention", interpret)
-        mesh = getattr(self.device, "mesh", None)
-        if refused is None and mesh is not None and mesh.size > 1:
-            refused = (f"a mesh of {mesh.size} devices: the two-width "
-                       f"kernels have no sharding rule")
-        if refused is None and not pallas_mla.kernel_legal(
-                t, h, nope, rope, dv):
-            refused = (f"T={t}, {h} heads of {nope} + {rope} / {dv} do "
-                       f"not tile (128 + 64 / 128, an even head count, "
-                       f"T whole tiles)")
-        self._flash_pallas = refused is None
-        self._flash_interpret = interpret
+        self._flash = pallas_mla.plan(self.device, t, h, nope, rope, dv)
         for stat, value in (("latent", latent), ("qk_nope", nope),
                             ("qk_rope", rope), ("v", dv)):
             obs_metrics.attention_latent(self.name, stat).set(value)
         self.info("%s: latent K/V of %d (+ %d shared rotary), %d heads, "
                   "keys %d + %d, values %d: %s", self.name, latent, rope,
-                  h, nope, rope, dv,
-                  "znicz_flash_fwd_mla / znicz_flash_bwd_mla_dq / _dkv "
-                  "kernels, tiles of %d%s" % (
-                      min(pallas_mla.BLOCK, t),
-                      ", INTERPRETED" if interpret else "")
-                  if self._flash_pallas
-                  else f"plain core, K assembled in full ({refused})")
+                  h, nope, rope, dv, self._flash.line())
         self.init_vectors(self.input, self.output, self.weights,
                           self.weights_out, self.gain_norm,
                           self.weights_head_gate, self.weights_kv_up,
@@ -754,11 +605,8 @@ class MultiHeadAttention(Forward):
         arrays = (q_nope, q_rope, k_nope, k_rope, v)
         if self.mxu_dtype is not None:
             arrays = tuple(a.astype(self.mxu_dtype) for a in arrays)
-        if getattr(self, "_flash_pallas", False):
-            from znicz_tpu.ops import pallas_mla
-            o = pallas_mla.latent_flash_attention(
-                *arrays, interpret=getattr(self, "_flash_interpret",
-                                           False))
+        if self._flash is not None and self._flash.runs:
+            o = self._flash.attend(*arrays)
         else:
             o = latent_attention_plain(*arrays, h)
         return self._project_out(x32, m, o.astype(jnp.float32), w_out,
@@ -845,7 +693,8 @@ class MultiHeadAttention(Forward):
         # Cast ONCE here so q/k/v reach the core at half width.
         dot_dtype = self.mxu_dtype
         fused = not self.qk_norm and self.rope_theta is None
-        flash = getattr(self, "_flash_pallas", False)   # never the ring
+        # never the ring; a unit that was not initialized has no plan
+        flash = self._flash is not None and self._flash.runs
         if fused:
             # q, k, v are column ranges of ONE projection result
             if dot_dtype is not None:
@@ -858,29 +707,13 @@ class MultiHeadAttention(Forward):
             if dot_dtype is not None:
                 arrays = tuple(a.astype(dot_dtype) for a in arrays)
         if flash:
-            from znicz_tpu.ops import pallas_attention
             # the kernels read q, k, v and write o where the
             # projections have them: a head (a pair at dh 64) is a
             # column block of (B, T, ·), so neither a transpose nor a
             # slice stands between a projection and a kernel, forward
             # or backward (24 copies of 0.6 ms a step in the LM cell
             # before; PERF.md §6, PR 28)
-            # the group and the window only where a layer has them: a
-            # layer without keeps the call (and the program) it had
-            more = {}
-            if grouped:
-                more["n_kv_heads"] = self.n_kv_heads
-            if getattr(self, "_flash_window", None) is not None:
-                more["window"] = self._flash_window
-            o = pallas_attention.flash_attention_rows(
-                arrays, self.n_heads, causal=self.causal,
-                block_q=getattr(self, "_flash_block_q", None),
-                block_k=getattr(self, "_flash_block_k",
-                                self.flash_block_k),
-                dot_dtype=dot_dtype,
-                interpret=getattr(self, "_flash_interpret", False),
-                mesh=getattr(self, "_flash_mesh", None),
-                spec=getattr(self, "_flash_spec", None), **more)
+            o = self._flash.attend(arrays, dot_dtype)
             return self._project_out(x32, h, o, w_out, b_out, w_gate, g_post)
         q, k, v = self._normed_rotated(jnp, arrays[0], None, None) \
             if fused else arrays      # fused: neither norm nor rotation
@@ -897,8 +730,7 @@ class MultiHeadAttention(Forward):
                 # fold is the gated fallback
                 pallas_fold=(getattr(self, "_ring_fold", None)
                              == "pallas"),
-                pallas_interpret=getattr(self, "_flash_interpret",
-                                         False),
+                pallas_interpret=self._flash.interpret,
                 pallas_block_q=getattr(self, "_ring_block_q", None))
         elif self.flash_block_k:
             from znicz_tpu.parallel.ring_attention import \
@@ -1465,7 +1297,7 @@ class GDMultiHeadAttention(GradientDescentBase):
         ``GDGatedDeltaNet`` does (the layer is held to
         ``znbench/reference/ling.py`` instead)."""
         fwd = self.forward_unit
-        kernels, fwd._flash_pallas = fwd._flash_pallas, False
+        plan, fwd._flash = fwd._flash, None      # the plain core
         try:
             if self._host_pullback is None:   # one host program
                 self._host_pullback = jax.jit(
@@ -1480,7 +1312,7 @@ class GDMultiHeadAttention(GradientDescentBase):
                 gx, gwq, _, gwo, _, *ggains = self._host_pullback(
                     jnp.asarray(self.err_output.mem, jnp.float32), *args)
         finally:
-            fwd._flash_pallas = kernels
+            fwd._flash = plan
         if self.need_err_input:
             self.err_input.map_invalidate()
             self.err_input.mem[...] = np.asarray(gx)

@@ -287,8 +287,8 @@ def head_layout(n_heads: int, dh: int, group: int = 1) -> tuple:
     latent-K/V layer's keys of 128 + 64 never come here: its score is
     the sum of two products, a 128-wide per-head block at the boundary
     layout and a 64-wide rotary block shared by all heads,
-    ``ops/pallas_mla.py``).  Static per program:
-    the attention unit reports it (info line, ``znicz_flash_layout``)."""
+    ``ops/pallas_mla.py``).  Static per program: in the plan
+    (:func:`plan`) and its line."""
     # grouped queries: a pair of query heads does not read a pair of
     # K/V heads, so every head is a program of its own
     pack = head_pack_for(n_heads, dh) if group == 1 else 1
@@ -356,8 +356,8 @@ def causal_tile_counts(t_q: int, t_k: int, bq: int, bk: int, sq: int,
     wholly below the band is ``skipped`` too, one wholly inside it
     ``interior``, and ``band_edge`` counts those the band's lower edge
     passes through and the diagonal does not (computed under the mask,
-    so executed).  Static per program: the attention unit reports it
-    at ``initialize`` (info line, ``znicz_flash_tiles``)."""
+    so executed).  Static per program: in the plan (:func:`plan`) and
+    its line."""
     if t_q % bq or t_k % bk or bq % sq or bk % sk:
         raise ValueError(f"({t_q}, {t_k}) / ({bq}, {bk}) / ({sq}, {sk})"
                          f" do not tile")
@@ -413,8 +413,7 @@ def backward_passes(causal: bool, t_k: int, bk: int, window=None) -> int:
     ``znicz_flash_dkv`` (seven and two): a deeper K grid, a window (a
     Q tile meets two K tiles and a K tile two Q tiles) or a non-causal
     call leaves dq unfinished at a dk/dv step's end.  Static per
-    program: the attention unit reports it (info line,
-    ``znicz_flash_backward``)."""
+    program: in the plan (:func:`plan`) and its line."""
     whole = backward_block_k(causal, t_k, bk, window) == t_k
     return 1 if causal and window is None and whole else 2
 
@@ -625,8 +624,7 @@ def forward_form(t_k: int, bq: int, bk: int, dh: int,
     gets, from its static shapes alone: no state where the grid's last
     axis has ONE step (the K tiles of a causal call, the tiles a band
     touches of a windowed one), 1/√dh in q where it is a power of two.
-    Static per program: the attention unit reports it (info line,
-    ``znicz_flash_forward``)."""
+    Static per program: in the plan (:func:`plan`) and its line."""
     k_steps = t_k // bk if window is None \
         else band_steps(t_k, bq, bk, window)[0]
     mantissa, _ = math.frexp(dh ** -0.5)
@@ -1515,3 +1513,146 @@ def flash_attention(q, k, v, **kwargs):
         tuple(a.reshape(a.shape[0], a.shape[1], -1)
               for a in (q, k, v)), h, **kwargs)
     return out.reshape(b, t, h, d).astype(jnp.float32)
+
+
+# ----------------------------------------------------------------------
+# the plan: how the kernels run ONE unit's call, decided once
+# ----------------------------------------------------------------------
+class FlashPlan(NamedTuple):
+    """What the choosers above make of one attention unit's call
+    (:func:`plan`): whether the kernels run it and in which form.  The
+    unit holds this ONE value; it says itself (:meth:`line`) and runs
+    itself (:meth:`attend`).  ``refused``: why the kernels do not run
+    the call (None: they do, and only then is anything from ``block_q``
+    on set); ``window`` as the kernels see it (None where it covers T);
+    ``sub_tile``, ``layout`` and ``head_pack``, ``forward``,
+    ``backward`` (passes), ``tiles`` and ``band_share`` (without a
+    window the causal half) are :func:`sub_tile_for`,
+    :func:`head_layout`, :func:`forward_form`, :func:`backward_passes`,
+    :func:`causal_tile_counts` and :func:`band_share` of the call;
+    ``mesh`` / ``spec``: per shard under ``shard_map``."""
+    refused: str | None
+    interpret: bool
+    n_heads: int
+    n_kv_heads: int
+    causal: bool
+    window: int | None
+    block_q: int | None = None
+    block_k: int | None = None
+    sub_tile: tuple | None = None
+    layout: str | None = None
+    head_pack: int | None = None
+    forward: ForwardForm | None = None
+    backward: int | None = None
+    tiles: dict | None = None
+    band_share: float | None = None
+    mesh: object = None
+    spec: object = None
+
+    @property
+    def runs(self) -> bool:
+        return self.refused is None
+
+    def attend(self, arrays, dot_dtype=None):
+        """:func:`flash_attention_rows` over ``arrays`` as planned."""
+        # the group and the window only where a layer has them: a layer
+        # without keeps the call (and the program) it had
+        more = {}
+        if self.n_kv_heads != self.n_heads:
+            more["n_kv_heads"] = self.n_kv_heads
+        if self.window is not None:
+            more["window"] = self.window
+        return flash_attention_rows(
+            arrays, self.n_heads, causal=self.causal,
+            block_q=self.block_q, block_k=self.block_k,
+            dot_dtype=dot_dtype, interpret=self.interpret, mesh=self.mesh,
+            spec=self.spec, **more)
+
+    def line(self) -> str:
+        """The plan in one line, for the unit's log."""
+        if not self.runs:
+            return f"XLA attention core — {self.refused}"
+        tiles, group = self.tiles, self.n_heads // self.n_kv_heads
+        text = (
+            "flash kernel, blocks (%d, %d), sub-tiles (%d, %d): %d "
+            "interior + %d crossing of %d = %.4f of T×T executed, "
+            "layout=%s, head pack %d, fwd_state: %s, fwd_stats: %s, "
+            "fwd_scale: %s, backward passes %d" % (
+                self.block_q, self.block_k, *self.sub_tile,
+                tiles["interior"],
+                tiles["crossing"] + tiles.get("band_edge", 0),
+                sum(n for cls, n in tiles.items()
+                    if cls != "executed_share"),
+                tiles["executed_share"], self.layout, self.head_pack,
+                *self.forward, self.backward))
+        if group != 1 or self.window is not None:
+            text += (", %d query heads to a K/V head, window %s (band "
+                     "%.4f of T×T)" % (group, self.window,
+                                       self.band_share))
+        if self.mesh is not None:
+            text += ", per shard under shard_map"
+        return text + (", INTERPRETED" if self.interpret else "")
+
+
+def plan(device, batch: int, t: int, n_heads: int, n_kv_heads: int,
+         dh: int, causal: bool, window: int | None = None,
+         flash_block_k: int | None = None, model_sharded: bool = False,
+         ring: bool = False) -> FlashPlan:
+    """The :class:`FlashPlan` of a self-attention call over (``batch``,
+    ``t``) positions of ``n_heads`` query and ``n_kv_heads`` K/V heads
+    of ``dh`` on ``device`` (and its mesh): the choosers above on what
+    the call can see (module docstring: no option steers a tile, a
+    layout or a form), and ``engine.flash_attention`` /
+    ``pallas_interpret`` / ``pallas_shard_map`` resolved ONCE here, like
+    every engine flag.  Default ON for real TPU devices (the measured
+    winner at every T: PERF.md round 5 / SEQ_BENCH.json); shapes the
+    tiling cannot cover fall back to the XLA cores, and where the
+    ``ring`` owns the core (it folds its hops with the same kernels)
+    the call is not the kernels'.  On a mesh they run per shard
+    (:func:`flash_attention_rows`, ``mesh`` / ``spec``);
+    ``engine.pallas_shard_map = False`` restores the conservative
+    single-device gate — kernel off on meshes, the safe fallback."""
+    from znicz_tpu.ops import pallas_kernels
+    from znicz_tpu.parallel.mesh import kernel_shard_spec, spec_divides
+    from znicz_tpu.utils.config import root
+    engine = root.common.engine
+    # interpret-mode lever: lets the virtual CPU mesh run the REAL
+    # kernels (shard_map oracle tests / dryruns); never default
+    interpret = bool(engine.get("pallas_interpret", False))
+    # a window that covers the sequence is the causal call
+    window = window if window is not None and window < t else None
+    bq, bk = grid_blocks(causal, t, t, None, flash_block_k) \
+        if window is None else band_blocks(t)
+    mesh, shard = getattr(device, "mesh", None), (None, None)
+    refused = pallas_kernels.kernel_refusal(device, "flash_attention",
+                                            interpret)
+    if refused is None and ring:
+        refused = "the ring owns the core"
+    elif refused is None and not kernel_legal(t, t, dh, bq, bk):
+        refused = (f"T={t}, head dim {dh} do not tile by blocks "
+                   f"({bq}, {bk})")
+    elif refused is None and mesh is not None and mesh.size > 1:
+        spec, _ = kernel_shard_spec(mesh, 4)
+        if not engine.get("pallas_shard_map", True):
+            refused = "engine.pallas_shard_map is off"
+        elif model_sharded:
+            refused = "the input is model-sharded"
+        elif not spec_divides(mesh, (batch, t, n_heads, dh), spec):
+            refused = (f"batch {batch} does not divide over mesh "
+                       f"{dict(mesh.shape)}")
+        else:
+            shard = (mesh, spec)
+    said = FlashPlan(refused, interpret, n_heads, n_kv_heads, causal,
+                     window)
+    if refused is not None:
+        return said
+    layout, head_pack = head_layout(n_heads, dh, n_heads // n_kv_heads)
+    sq, sk = sub_tile_for(causal, bq, bk) if window is None else (bq, bk)
+    return said._replace(
+        block_q=bq, block_k=bk, sub_tile=(sq, sk), layout=layout,
+        head_pack=head_pack, forward=forward_form(t, bq, bk, dh, window),
+        backward=backward_passes(causal, t, bk, window),
+        tiles=causal_tile_counts(t, t, bq, bk, sq, sk, causal=causal,
+                                 window=window),
+        band_share=band_share(t, window),
+        mesh=shard[0], spec=shard[1])

@@ -75,11 +75,18 @@ whole (8, 128) tiles — at d_k 96 × d_v 192 the state occupies
 128 × 256 lanes' worth, 1.78 × its elements (:func:`padded_share`; at
 128 × 128 exactly its own, 1.0); HBM holds no padding.
 
-**Both decay shapes** run through this ONE family.  The shape of log α
-is a static property of the call and picks the body: (…, H), one decay
-a head, is everything above (``znicz_gdr_chunk_*``,
-``znicz_delta_state_*``); (…, H, d_k), one decay per KEY CHANNEL (Kimi
-Delta Attention, arXiv:2510.26692; PR 37), is
+**Both decay shapes** run through ONE scaffold (PR 42): the chunk
+kernels' wrappers over a grid step's chunks, their jitted calls and
+``custom_vjp``, the walk's two kernels and theirs exist once and take a
+RULE (:class:`_Rule`) — a record of what differs and nothing else: a
+chunk's algebra (masks, L, outputs, cotangents), how a chunk's decay
+lies in a row of lanes, how the walk scales S by it and takes its
+cotangent, the width the backward's residuals are kept at, and the
+kernels' names.  The shape of log α is a static property of the call
+and picks the record: (…, H), one decay a head, is everything above
+(``znicz_gdr_chunk_*``, ``znicz_delta_state_*``); (…, H, d_k), one
+decay per KEY CHANNEL (Kimi Delta Attention, arXiv:2510.26692; PR 37),
+is
 
 .. code-block:: text
 
@@ -111,7 +118,7 @@ state in VMEM — the per-chunk S_n this walk WRITES for the backward are
 at ``dot_dtype``, the width every product takes them in: 64 MB a layer
 less than f32 at T 4,096 × 32 heads of 128 × 128); :func:`chunk_local` / :func:`state_scan` in ``jax.numpy``,
 which write Γ out as a (C, C, d_k) array, stay the oracle and the path
-off a TPU.  Neither family's kernel names hold the other's.
+off a TPU.  Neither rule's kernel names hold the other's.
 
 **Between the projection and the rule** (:func:`qkv_prep`, PR 40): per
 column c of u = m (W_q ‖ W_k ‖ W_v), (B, T, H·(2 d_k + d_v)) f32,
@@ -149,8 +156,10 @@ into the next.  Everything f32, the formulas and ε
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -530,21 +539,25 @@ def _over_chunks(block: int, together: int, step: int, some) -> None:
 # every process's start on the chip's host (PERF.md §6, PR 32).
 @jax.jit
 def _chunk_lower(positions, log_alpha, beta, k):
-    """A chunk's decays (:func:`_decays`) and its L."""
+    """A chunk's L, exp(c_C), and what the outputs need of the decays
+    (:func:`_decays`): Γ, exp(c), exp(c_C − c)."""
     upto, below, above, eye, sums, _ = positions
     gamma, grown, rest, decay = _decays(log_alpha, upto, above, sums)
     # β as a column: (1, C) → (C, 1) without a transpose
     lower = below * (_rows(eye * beta) * gamma
                      * _exact(k, k, trans_b=True))
-    return gamma, grown, rest, decay, lower
+    return lower, decay, gamma, grown, rest
 
 
 _inverses = jax.jit(_inverses_in_vmem)
 
 
 @functools.partial(jax.jit, static_argnames=("dot_dtype",))
-def _chunk_outputs(q, k, v, beta, x, gamma, grown, rest, *, dot_dtype):
-    """W, K̂, U, Qc, P from a chunk's rows, decays and (I + L)⁻¹."""
+def _chunk_outputs(positions, q, k, v, beta, x, gamma, grown, rest, *,
+                   dot_dtype):
+    """W, K̂, U, Qc, P from a chunk's rows, decays and (I + L)⁻¹ (Γ
+    carries P's mask: ``positions`` are the per-channel rule's to
+    use)."""
     mixed = _mixed(dot_dtype)
     a = x * beta
     return (mixed(a, grown * k), rest * k, mixed(a, v), grown * q,
@@ -552,24 +565,30 @@ def _chunk_outputs(q, k, v, beta, x, gamma, grown, rest, *, dot_dtype):
 
 
 def _chunk_fwd_kernel(q_ref, k_ref, v_ref, a_ref, b_ref, w_ref, kh_ref,
-                      u_ref, d_ref, qc_ref, p_ref, x_ref, *, dot_dtype,
-                      together, side_by_side):
+                      u_ref, d_ref, qc_ref, p_ref, x_ref, *, rule,
+                      dot_dtype, together, side_by_side):
+    """A ``rule``'s forward for a grid step's chunks: L from the rows
+    the rule names (``lower_reads``: a row it does not use is not
+    loaded), the inverses of ``side_by_side`` chunks in one chain, then
+    the six outputs and (I + L)⁻¹."""
     block, c = q_ref.shape[0], q_ref.shape[1]
-    positions = _positions(c)
+    positions = rule.positions(c)
     levels = _inverse_levels(c, side_by_side)
+    rows = dict(q=q_ref, k=k_ref, v=v_ref, a=a_ref, b=b_ref)
 
     def some(first):
         at = [first + m for m in range(side_by_side)]
-        held = [_chunk_lower(positions, a_ref[i], b_ref[i], k_ref[i])
+        held = [rule.lower(positions,
+                           *(rows[r][i] for r in rule.lower_reads))
                 for i in at]
-        inverses = _inverses([lower for *_, lower in held], levels)
-        for i, (gamma, grown, rest, decay, _), x in zip(at, held,
-                                                        inverses):
+        inverses = _inverses([lower for lower, *_ in held], levels)
+        for i, (_, decay, *factors), x in zip(at, held, inverses):
             x_ref[i] = x
             w_ref[i], kh_ref[i], u_ref[i], qc_ref[i], p_ref[i] = \
-                _chunk_outputs(q_ref[i], k_ref[i], v_ref[i], b_ref[i], x,
-                               gamma, grown, rest, dot_dtype=dot_dtype)
-            d_ref[i] = jnp.broadcast_to(decay, (1, c))
+                rule.outputs(positions, q_ref[i], k_ref[i], v_ref[i],
+                             b_ref[i], x, *factors, dot_dtype=dot_dtype)
+            # a number a head fills its row's lanes
+            d_ref[i] = jnp.broadcast_to(decay, d_ref.shape[1:])
 
     _over_chunks(block, together, side_by_side, some)
 
@@ -612,16 +631,16 @@ def _chunk_cotangents(positions, q, k, v, log_alpha, beta, x, d_w, d_kh,
     return d_q, d_k, d_v, d_alpha, d_beta
 
 
-def _chunk_bwd_kernel(*refs, dot_dtype, together):
-    """:func:`_chunk_cotangents` for a grid step's chunks: the five
-    rows, (I + L)⁻¹ — the one (C, C) matrix kept — and six cotangents
-    in, five cotangents out."""
+def _chunk_bwd_kernel(*refs, rule, dot_dtype, together):
+    """A ``rule``'s cotangents for a grid step's chunks: the five rows,
+    (I + L)⁻¹ — the one (C, C) matrix kept — and six cotangents in,
+    five cotangents out."""
     ins, outs = refs[:12], refs[12:]
     block, c = ins[0].shape[0], ins[0].shape[1]
-    positions = _positions(c, backward=True)
+    positions = rule.positions(c, backward=True)
 
     def one(i):
-        results = _chunk_cotangents(
+        results = rule.cotangents(
             positions, *(ref[i] for ref in ins), dot_dtype=dot_dtype)
         for ref, result in zip(outs, results):
             ref[i] = result
@@ -654,76 +673,83 @@ def _chunk_call(kernel, name: str, arrays, outs, block: int, interpret):
         interpret=interpret, name=name)(*arrays)
 
 
-def _flat(a, c: int):
-    """(G, N, C, ·) → (G·N, C, ·); a chunk's vector (G, N, C) as a row
-    of lanes, (G·N, 1, C)."""
+def _flat(a):
+    """(G, N, C, ·) → (G·N, C, ·); a chunk's vector (G, N, ·) as a row
+    of lanes, (G·N, 1, ·)."""
     total = a.shape[0] * a.shape[1]
-    return a.reshape((total, 1, c) if a.ndim == 3
+    return a.reshape((total, 1, a.shape[2]) if a.ndim == 3
                      else (total,) + a.shape[2:])
 
 
 # jitted: a model's linear layers call these with the same shapes and
 # static arguments, so each kernel is traced and lowered once per
 # program, not once per layer (ROADMAP S6)
-@functools.partial(jax.jit, static_argnums=(5, 6, 7))
-def _chunk_forward_call(q, k, v, log_alpha, beta, interpret, dot_dtype,
-                        block):
+@functools.partial(jax.jit, static_argnums=(0, 6, 7, 8))
+def _chunk_forward_call(rule, q, k, v, log_alpha, beta, interpret,
+                        dot_dtype, block):
     """W, K̂, U, decay, Qc, P of :func:`chunk_local` and (I + L)⁻¹ for
-    the backward, everything else of a chunk's (C, C) in VMEM."""
+    the backward, everything else of a chunk's (C, C) in VMEM.  A
+    chunk's decay comes out as a row of as many lanes as log α's last
+    axis: C for a number a head, d_k for one per channel."""
     g, n, c, dk = q.shape
     dv, block = v.shape[-1], min(block, g * n)
     side_by_side = _side_by_side(block, c)
     w, k_hat, u, decay, qc, p, x = _chunk_call(
         functools.partial(
-            _chunk_fwd_kernel, dot_dtype=dot_dtype,
+            _chunk_fwd_kernel, rule=rule, dot_dtype=dot_dtype,
             together=max(math.gcd(block, _TOGETHER), side_by_side),
             side_by_side=side_by_side),
-        "znicz_gdr_chunk_fwd",
-        [_flat(a, c) for a in (q, k, v, log_alpha, beta)],
-        [(c, dk), (c, dk), (c, dv), (1, c), (c, dk), (c, c), (c, c)],
-        block, interpret)
+        rule.chunk_kernel + "_fwd",
+        [_flat(a) for a in (q, k, v, log_alpha, beta)],
+        [(c, dk), (c, dk), (c, dv), (1, log_alpha.shape[-1]), (c, dk),
+         (c, c), (c, c)], block, interpret)
 
     def heads(a):
         return a.reshape((g, n) + a.shape[1:])
 
-    return (heads(w), heads(k_hat), heads(u),
-            decay[:, 0, 0].reshape(g, n), heads(qc), heads(p)), heads(x)
+    return (heads(w), heads(k_hat), heads(u), rule.from_row(decay, g, n),
+            heads(qc), heads(p)), heads(x)
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8, 9))
-def _chunk_backward_call(q, k, v, log_alpha, beta, x, cotangent,
+@functools.partial(jax.jit, static_argnums=(0, 8, 9, 10))
+def _chunk_backward_call(rule, q, k, v, log_alpha, beta, x, cotangent,
                          interpret, dot_dtype, block):
     g, n, c, dk = q.shape
     dv, block = v.shape[-1], min(block, g * n)
     d_w, d_kh, d_u, d_decay, d_qc, d_p = cotangent
-    d_decay = jnp.broadcast_to(d_decay[..., None], (g, n, c))
+    rows = [_flat(a) for a in (q, k, v, log_alpha, beta, x, d_w, d_kh,
+                               d_u, rule.as_row(d_decay, c), d_qc, d_p)]
     d_q, d_k, d_v, d_a, d_b = _chunk_call(
-        functools.partial(_chunk_bwd_kernel, dot_dtype=dot_dtype,
+        functools.partial(_chunk_bwd_kernel, rule=rule,
+                          dot_dtype=dot_dtype,
                           together=math.gcd(block, _TOGETHER)),
-        "znicz_gdr_chunk_bwd",
-        [_flat(a, c) for a in (q, k, v, log_alpha, beta, x, d_w, d_kh,
-                               d_u, d_decay, d_qc, d_p)],
-        [(c, dk), (c, dk), (c, dv), (1, c), (1, c)], block, interpret)
+        rule.chunk_kernel + "_bwd", rows,
+        [(c, dk), (c, dk), (c, dv), rows[3].shape[1:], (1, c)], block,
+        interpret)
     return (d_q.reshape(q.shape), d_k.reshape(k.shape),
-            d_v.reshape(v.shape), d_a.reshape(g, n, c),
+            d_v.reshape(v.shape), d_a.reshape(log_alpha.shape),
             d_b.reshape(g, n, c))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _chunk_local_kernels(q, k, v, log_alpha, beta, interpret, dot_dtype,
-                         block):
-    return _chunk_forward_call(q, k, v, log_alpha, beta, interpret,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 6, 7, 8))
+def _chunk_local_kernels(rule, q, k, v, log_alpha, beta, interpret,
+                         dot_dtype, block):
+    return _chunk_forward_call(rule, q, k, v, log_alpha, beta, interpret,
                                dot_dtype, block)[0]
 
 
-def _chunk_fwd(q, k, v, log_alpha, beta, interpret, dot_dtype, block):
-    out, x = _chunk_forward_call(q, k, v, log_alpha, beta, interpret,
-                                 dot_dtype, block)
-    return out, (q, k, v, log_alpha, beta, x)
+def _chunk_fwd(rule, q, k, v, log_alpha, beta, interpret, dot_dtype,
+               block):
+    out, x = _chunk_forward_call(rule, q, k, v, log_alpha, beta,
+                                 interpret, dot_dtype, block)
+    # V enters the backward as a ``dot_dtype`` matmul input only (dA =
+    # … + dU Vᵀ): a narrow rule keeps it at that width
+    kept = v.astype(rule.width(dot_dtype))
+    return out, (q, k, kept, log_alpha, beta, x)
 
 
-def _chunk_bwd(interpret, dot_dtype, block, residual, cotangent):
-    return _chunk_backward_call(*residual, cotangent, interpret,
+def _chunk_bwd(rule, interpret, dot_dtype, block, residual, cotangent):
+    return _chunk_backward_call(rule, *residual, cotangent, interpret,
                                 dot_dtype, block)
 
 
@@ -733,14 +759,14 @@ _chunk_local_kernels.defvjp(_chunk_fwd, _chunk_bwd)
 def chunk_local_kernels(q, k, v, log_alpha, beta, dot_dtype=None,
                         interpret: bool = False,
                         block: int = CHUNKS_PER_STEP):
-    """:func:`chunk_local` as ``znicz_gdr_chunk_fwd`` and, under
-    differentiation, ``znicz_gdr_chunk_bwd``: ``block`` chunks a grid
-    step (the last step may hold fewer), one (C, C) matrix a chunk kept
-    between them."""
+    """:func:`chunk_local` as the rule's chunk kernels
+    (``znicz_gdr_chunk_fwd``, or ``znicz_kda_chunk_fwd`` for a decay
+    per key channel, and under differentiation their ``_bwd``):
+    ``block`` chunks a grid step (the last step may hold fewer), one
+    (C, C) matrix a chunk kept between them."""
     f32 = jnp.float32
-    rule = _kda_local_kernels if log_alpha.ndim == q.ndim \
-        else _chunk_local_kernels
-    return rule(
+    return _chunk_local_kernels(
+        _rule_for(log_alpha.ndim == q.ndim),
         q.astype(f32), k.astype(f32), v.astype(f32),
         log_alpha.astype(f32), beta.astype(f32), interpret,
         None if dot_dtype is None else jnp.dtype(dot_dtype), block)
@@ -769,35 +795,37 @@ def _state_scan_plain(w, k_hat, u, decay, dot_dtype):
 # ----------------------------------------------------------------------
 # the walk over the chunks: kernels
 # ----------------------------------------------------------------------
-def _fwd_kernel(w_ref, k_ref, u_ref, d_ref, v_ref, s_ref, state, *,
-                dot_dtype):
+def _state_fwd_kernel(w_ref, k_ref, u_ref, d_ref, v_ref, s_ref, state, *,
+                      rule, dot_dtype):
     @pl.when(pl.program_id(1) == 0)
     def _start():
         state[...] = jnp.zeros_like(state)
 
     s = state[...]
-    s_ref[...] = s
+    s_ref[...] = s.astype(s_ref.dtype)
     v_new = u_ref[...] - _dot(w_ref[...], s, dot_dtype)
     v_ref[...] = v_new
-    state[...] = d_ref[...] * s + _dot(k_ref[...], v_new, dot_dtype,
-                                       trans_a=True)
+    state[...] = rule.scale(d_ref[...])[0] * s + _dot(
+        k_ref[...], v_new, dot_dtype, trans_a=True)
 
 
-def _bwd_kernel(w_ref, k_ref, d_ref, s_ref, v_ref, dv_ref, ds_ref,
-                dw_ref, dk_ref, du_ref, dd_ref, carry, *, dot_dtype):
+def _state_bwd_kernel(w_ref, k_ref, d_ref, s_ref, v_ref, dv_ref, ds_ref,
+                      dw_ref, dk_ref, du_ref, dd_ref, carry, *, rule,
+                      dot_dtype):
     """One chunk of the reverse walk; ``carry`` is the cotangent of
     S_{n+1}, this chunk's output state."""
     @pl.when(pl.program_id(1) == 0)
     def _start():
         carry[...] = jnp.zeros_like(carry)
 
-    g, s = carry[...], s_ref[...]
+    g, s = carry[...], s_ref[...].astype(jnp.float32)
+    kept, eye = rule.scale(d_ref[...])
     dv = dv_ref[...] + _dot(k_ref[...], g, dot_dtype)
     du_ref[...] = dv
     dk_ref[...] = _dot(v_ref[...], g, dot_dtype, trans_b=True)
     dw_ref[...] = -_dot(dv, s, dot_dtype, trans_b=True)
-    dd_ref[...] = jnp.sum(g * s, axis=0, keepdims=True)
-    carry[...] = ds_ref[...] + d_ref[...] * g - _dot(
+    dd_ref[...] = rule.scale_cotangent(g * s, eye)
+    carry[...] = ds_ref[...] + kept * g - _dot(
         w_ref[...], dv, dot_dtype, trans_a=True)
 
 
@@ -811,77 +839,86 @@ _PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"))
 
 
-def _lanes(decay, dv: int):
-    """(G, N) → (G, N, 1, d_v): a chunk's scalar as a row of lanes."""
-    return jnp.broadcast_to(decay[..., None, None],
-                            decay.shape + (1, dv)).astype(jnp.float32)
-
-
-def _forward_call(w, k_hat, u, decay, interpret, dot_dtype):
+def _forward_call(rule, w, k_hat, u, decay, interpret, dot_dtype):
     g, n, c, dk = w.shape
     dv = u.shape[-1]
+    decay = rule.walk_row(decay, dv)        # (G, N, 1, lanes)
 
     def first(i):
         return i
 
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, dot_dtype=dot_dtype),
+        functools.partial(_state_fwd_kernel, rule=rule,
+                          dot_dtype=dot_dtype),
         grid=(g, n),
         in_specs=[_face(c, dk, first), _face(c, dk, first),
-                  _face(c, dv, first), _face(1, dv, first)],
+                  _face(c, dv, first), _face(1, decay.shape[-1], first)],
         out_specs=(_face(c, dv, first), _face(dk, dv, first)),
         out_shape=(jax.ShapeDtypeStruct((g, n, c, dv), jnp.float32),
-                   jax.ShapeDtypeStruct((g, n, dk, dv), jnp.float32)),
+                   jax.ShapeDtypeStruct((g, n, dk, dv),
+                                        rule.width(dot_dtype))),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         compiler_params=_PARAMS, interpret=interpret,
-        name="znicz_delta_state_fwd",
-    )(w, k_hat, u, _lanes(decay, dv))
+        name=rule.state_kernel + "_fwd",
+    )(w, k_hat, u, decay)
 
 
-def _backward_call(w, k_hat, decay, states, v_new, d_v, d_s, interpret,
-                   dot_dtype):
+def _backward_call(rule, w, k_hat, decay, states, v_new, d_v, d_s,
+                   interpret, dot_dtype):
     g, n, c, dk = w.shape
     dv = v_new.shape[-1]
+    decay = rule.walk_row(decay, dv)
+    lanes = decay.shape[-1]
 
     def back(i):
         return n - 1 - i
 
     f32 = jnp.float32
     dw, dk_hat, du, dd = pl.pallas_call(
-        functools.partial(_bwd_kernel, dot_dtype=dot_dtype),
+        functools.partial(_state_bwd_kernel, rule=rule,
+                          dot_dtype=dot_dtype),
         grid=(g, n),
         in_specs=[_face(c, dk, back), _face(c, dk, back),
-                  _face(1, dv, back), _face(dk, dv, back),
+                  _face(1, lanes, back), _face(dk, dv, back),
                   _face(c, dv, back), _face(c, dv, back),
                   _face(dk, dv, back)],
         out_specs=(_face(c, dk, back), _face(c, dk, back),
-                   _face(c, dv, back), _face(1, dv, back)),
+                   _face(c, dv, back), _face(1, lanes, back)),
         out_shape=(jax.ShapeDtypeStruct((g, n, c, dk), f32),
                    jax.ShapeDtypeStruct((g, n, c, dk), f32),
                    jax.ShapeDtypeStruct((g, n, c, dv), f32),
-                   jax.ShapeDtypeStruct((g, n, 1, dv), f32)),
+                   jax.ShapeDtypeStruct((g, n, 1, lanes), f32)),
         scratch_shapes=[pltpu.VMEM((dk, dv), f32)],
         compiler_params=_PARAMS, interpret=interpret,
-        name="znicz_delta_state_bwd",
-    )(w, k_hat, _lanes(decay, dv), states, v_new, d_v, d_s)
-    return dw, dk_hat, du, dd.sum(axis=(-1, -2))
+        name=rule.state_kernel + "_bwd",
+    )(w, k_hat, decay, states, v_new, d_v, d_s)
+    return dw, dk_hat, du, rule.from_walk_row(dd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _state_scan_kernels(w, k_hat, u, decay, interpret, dot_dtype):
-    return _forward_call(w, k_hat, u, decay, interpret, dot_dtype)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 5, 6))
+def _state_scan_kernels(rule, w, k_hat, u, decay, interpret, dot_dtype):
+    return _forward_call(rule, w, k_hat, u, decay, interpret, dot_dtype)
 
 
-def _scan_fwd(w, k_hat, u, decay, interpret, dot_dtype):
-    v_new, states = _forward_call(w, k_hat, u, decay, interpret,
+def _scan_fwd(rule, w, k_hat, u, decay, interpret, dot_dtype):
+    v_new, states = _forward_call(rule, w, k_hat, u, decay, interpret,
                                   dot_dtype)
-    return (v_new, states), (w, k_hat, decay, states, v_new)
+    # W, K̂ and V′ enter the reverse walk as ``dot_dtype`` matmul inputs
+    # only, so a narrow rule's bf16 copies give the same numbers at half
+    # the bytes (the f32 arrays die with the forward); its per-chunk
+    # states are WRITTEN at that width (the carried state stays f32 in
+    # VMEM): O's product and dW take them as ``dot_dtype`` inputs, and
+    # only the decay's cotangent Σ g ⊙ S reads the rounded copy
+    # elementwise
+    width = rule.width(dot_dtype)
+    return (v_new, states), (w.astype(width), k_hat.astype(width), decay,
+                             states, v_new.astype(width))
 
 
-def _scan_bwd(interpret, dot_dtype, residual, cotangent):
+def _scan_bwd(rule, interpret, dot_dtype, residual, cotangent):
     w, k_hat, decay, states, v_new = residual
     d_v, d_s = cotangent
-    return _backward_call(w, k_hat, decay, states, v_new, d_v, d_s,
+    return _backward_call(rule, w, k_hat, decay, states, v_new, d_v, d_s,
                           interpret, dot_dtype)
 
 
@@ -897,9 +934,8 @@ def state_scan(w, k_hat, u, decay, kernel: bool = False,
     (``znicz_kda_state_fwd`` / ``_bwd``, which write S_n at
     ``dot_dtype``)."""
     if kernel:
-        rule = _kda_scan_kernels if decay.ndim == 3 \
-            else _state_scan_kernels
-        return rule(
+        return _state_scan_kernels(
+            _rule_for(decay.ndim == w.ndim - 1),
             *(a.astype(jnp.float32) for a in (w, k_hat, u, decay)),
             interpret, dot_dtype)
     return _state_scan_plain(w, k_hat, u, decay, dot_dtype)
@@ -1021,65 +1057,40 @@ def _kda_products(left, rights, sub, dot):
          for a, right in enumerate(rights)], axis=0)
 
 
-def _kda_chunk_lower(positions, log_alpha, beta, q, k, *, sub):
-    """A chunk's L = strict_lower(diag(β) M), and what the outputs need
-    of the decays: e^c, e^(c_C − c), e^(c_C), Q's left factor and K's
-    right factors (P is the same two-factor product as M)."""
+@jax.jit
+def _kda_chunk_lower(positions, log_alpha, beta, q, k):
+    """A chunk's L = strict_lower(diag(β) M), e^(c_C), and what the
+    outputs need of the decays: e^c, e^(c_C − c), Q's left factor and
+    K's right factors (P is the same two-factor product as M)."""
     _, below, eye, sums, _ = positions
     _, left_k, left_q, scales, grown, rest = _kda_factors(
         sums, q, k, log_alpha)
     rights = [k * e for e in scales]
-    m = _kda_products(left_k, rights, sub, _exact)
+    m = _kda_products(left_k, rights, sub_block(k.shape[0]), _exact)
     lower = below * (_rows(eye * beta) * m)
     decay = jnp.exp(_cols(log_alpha))
-    return lower, grown, rest, decay, left_q, rights
+    return lower, decay, grown, rest, left_q, rights
 
 
-_kda_lower = jax.jit(_kda_chunk_lower, static_argnames=("sub",))
-
-
-@functools.partial(jax.jit, static_argnames=("dot_dtype", "sub"))
-def _kda_outputs(upto, q, k, v, beta, x, grown, rest, left_q, rights, *,
-                 dot_dtype, sub):
+@functools.partial(jax.jit, static_argnames=("dot_dtype",))
+def _kda_outputs(positions, q, k, v, beta, x, grown, rest, left_q, rights,
+                 *, dot_dtype):
     """W, K̂, U, Qc, P from a chunk's rows, decays, Γ's factors and
     (I + L)⁻¹."""
     mixed = _mixed(dot_dtype)
-    p = upto * _kda_products(left_q, rights, sub, mixed)
+    p = positions[0] * _kda_products(left_q, rights,
+                                     sub_block(k.shape[0]), mixed)
     a = x * beta
     return mixed(a, grown * k), rest * k, mixed(a, v), grown * q, p
 
 
-def _kda_fwd_kernel(q_ref, k_ref, v_ref, a_ref, b_ref, w_ref, kh_ref,
-                    u_ref, d_ref, qc_ref, p_ref, x_ref, *, dot_dtype,
-                    together, side_by_side, sub):
-    block, c = q_ref.shape[0], q_ref.shape[1]
-    positions = _kda_positions(c, sub)
-    levels = _inverse_levels(c, side_by_side)
-
-    def some(first):
-        at = [first + m for m in range(side_by_side)]
-        held = [_kda_lower(positions, a_ref[i], b_ref[i], q_ref[i],
-                           k_ref[i], sub=sub) for i in at]
-        inverses = _inverses([lower for lower, *_ in held], levels)
-        for i, (_, grown, rest, decay, left_q, rights), x in zip(
-                at, held, inverses):
-            x_ref[i] = x
-            w_ref[i], kh_ref[i], u_ref[i], qc_ref[i], p_ref[i] = \
-                _kda_outputs(positions[0], q_ref[i], k_ref[i], v_ref[i],
-                             b_ref[i], x, grown, rest, left_q, rights,
-                             dot_dtype=dot_dtype, sub=sub)
-            d_ref[i] = decay
-
-    _over_chunks(block, together, side_by_side, some)
-
-
-@functools.partial(jax.jit, static_argnames=("dot_dtype", "sub"))
+@functools.partial(jax.jit, static_argnames=("dot_dtype",))
 def _kda_cotangents(positions, q, k, v, log_alpha, beta, x, d_w, d_kh,
-                    d_u, d_decay, d_qc, d_p, *, dot_dtype, sub):
+                    d_u, d_decay, d_qc, d_p, *, dot_dtype):
     """Cotangents of a chunk's q, k, v, log α (C, d_k), β from those of
     its W, K̂, U, decay (1, d_k), Qc and P, and its (I + L)⁻¹."""
     upto, below, eye, sums, back = positions
-    exact, mixed = _exact, _mixed(dot_dtype)
+    exact, mixed, sub = _exact, _mixed(dot_dtype), sub_block(k.shape[0])
     near, left_k, left_q, scales, grown, rest = _kda_factors(
         sums, q, k, log_alpha)
     rights = [k * e for e in scales]
@@ -1123,211 +1134,97 @@ def _kda_cotangents(positions, q, k, v, log_alpha, beta, x, d_w, d_kh,
     return d_q, d_k, d_v, d_alpha, d_beta
 
 
-def _kda_bwd_kernel(*refs, dot_dtype, together, sub):
-    ins, outs = refs[:12], refs[12:]
-    block, c = ins[0].shape[0], ins[0].shape[1]
-    positions = _kda_positions(c, sub, backward=True)
-
-    def one(i):
-        results = _kda_cotangents(
-            positions, *(ref[i] for ref in ins), dot_dtype=dot_dtype,
-            sub=sub)
-        for ref, result in zip(outs, results):
-            ref[i] = result
-
-    _over_chunks(block, together, 1, one)
-
-
-@functools.partial(jax.jit, static_argnums=(5, 6, 7))
-def _kda_forward_call(q, k, v, log_alpha, beta, interpret, dot_dtype,
-                      block):
-    g, n, c, dk = q.shape
-    dv, block = v.shape[-1], min(block, g * n)
-    side_by_side = _side_by_side(block, c)
-    w, k_hat, u, decay, qc, p, x = _chunk_call(
-        functools.partial(
-            _kda_fwd_kernel, dot_dtype=dot_dtype,
-            together=max(math.gcd(block, _TOGETHER), side_by_side),
-            side_by_side=side_by_side, sub=sub_block(c)),
-        "znicz_kda_chunk_fwd",
-        [_flat(a, c) for a in (q, k, v, log_alpha, beta)],
-        [(c, dk), (c, dk), (c, dv), (1, dk), (c, dk), (c, c), (c, c)],
-        block, interpret)
-
-    def heads(a):
-        return a.reshape((g, n) + a.shape[1:])
-
-    return (heads(w), heads(k_hat), heads(u), decay.reshape(g, n, dk),
-            heads(qc), heads(p)), heads(x)
-
-
-@functools.partial(jax.jit, static_argnums=(7, 8, 9))
-def _kda_backward_call(q, k, v, log_alpha, beta, x, cotangent,
-                       interpret, dot_dtype, block):
-    g, n, c, dk = q.shape
-    dv, block = v.shape[-1], min(block, g * n)
-    d_w, d_kh, d_u, d_decay, d_qc, d_p = cotangent
-    d_q, d_k, d_v, d_a, d_b = _chunk_call(
-        functools.partial(_kda_bwd_kernel, dot_dtype=dot_dtype,
-                          together=math.gcd(block, _TOGETHER),
-                          sub=sub_block(c)),
-        "znicz_kda_chunk_bwd",
-        [_flat(a, c) for a in (q, k, v, log_alpha, beta, x, d_w, d_kh,
-                               d_u)]
-        + [d_decay.reshape(g * n, 1, dk)]
-        + [_flat(a, c) for a in (d_qc, d_p)],
-        [(c, dk), (c, dk), (c, dv), (c, dk), (1, c)], block, interpret)
-    return (d_q.reshape(q.shape), d_k.reshape(k.shape),
-            d_v.reshape(v.shape), d_a.reshape(log_alpha.shape),
-            d_b.reshape(g, n, c))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _kda_local_kernels(q, k, v, log_alpha, beta, interpret, dot_dtype,
-                       block):
-    return _kda_forward_call(q, k, v, log_alpha, beta, interpret,
-                             dot_dtype, block)[0]
-
-
-def _kda_fwd(q, k, v, log_alpha, beta, interpret, dot_dtype, block):
-    out, x = _kda_forward_call(q, k, v, log_alpha, beta, interpret,
-                               dot_dtype, block)
-    # V enters the backward as a ``dot_dtype`` matmul input only (dA =
-    # … + dU Vᵀ): kept at that width, as the walk keeps W, K̂ and V′
-    kept = v if dot_dtype is None else v.astype(dot_dtype)
-    return out, (q, k, kept, log_alpha, beta, x)
-
-
-def _kda_bwd(interpret, dot_dtype, block, residual, cotangent):
-    return _kda_backward_call(*residual, cotangent, interpret,
-                              dot_dtype, block)
-
-
-_kda_local_kernels.defvjp(_kda_fwd, _kda_bwd)
-
-
 def _column(row):
-    """(1, d) → (d, 1) without a transpose."""
+    """(1, d) → (d, 1) without a transpose, and the identity that made
+    it."""
     d = row.shape[1]
     eye = _ones_where(jax.lax.broadcasted_iota(jnp.int32, (d, d), 0)
                       == jax.lax.broadcasted_iota(jnp.int32, (d, d), 1))
     return _rows(eye * row), eye
 
 
-def _kda_state_fwd_kernel(w_ref, k_ref, u_ref, d_ref, v_ref, s_ref,
-                          state, *, dot_dtype):
-    """:func:`_fwd_kernel` with the chunk's decay a number per key
-    channel: diag(e^(c_C)) S_n scales S's rows."""
-    @pl.when(pl.program_id(1) == 0)
-    def _start():
-        state[...] = jnp.zeros_like(state)
+# ----------------------------------------------------------------------
+# the two rules
+# ----------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True, eq=False)
+class _Rule:
+    """One decay shape as the ONE scaffold above takes it (the chunk
+    kernels' wrappers and calls, the walk's kernels, their
+    ``custom_vjp``s): what differs between a decay a head and a decay
+    per key channel, and nothing else.  The shape of log α picks the
+    record (:func:`_rule_for`); nothing else branches on it."""
+    #: the Mosaic kernels' names less ``_fwd`` / ``_bwd``: what is
+    #: local to a chunk, and the walk
+    chunk_kernel: str
+    state_kernel: str
+    #: a chunk's algebra.  ``positions(c, backward=False)``: its 0/1
+    #: masks; ``lower(positions, *rows)`` from the rows ``lower_reads``
+    #: names (of q k v a b: log α is a, β b) → L, the decay's row, then
+    #: what ``outputs(positions, q, k, v, β, (I + L)⁻¹, *those,
+    #: dot_dtype=)`` needs of the decays → W, K̂, U, Qc, P;
+    #: ``cotangents(positions, *rows, (I + L)⁻¹, *six cotangents,
+    #: dot_dtype=)`` → those of q, k, v, log α, β
+    positions: typing.Callable
+    lower_reads: str
+    lower: typing.Callable
+    outputs: typing.Callable
+    cotangents: typing.Callable
+    #: a chunk's decay between the chunk kernels' rows (G·N, 1, lanes)
+    #: and the (G, N[, d_k]) the walk is handed: out of the forward's,
+    #: and its cotangent into the backward's
+    from_row: typing.Callable
+    as_row: typing.Callable
+    #: the walk: the decay as one row a chunk (G, N, 1, lanes); that row
+    #: as what multiplies S (and what made it so); the row's cotangent
+    #: from g ⊙ S; and that out of the kernel's rows
+    walk_row: typing.Callable
+    scale: typing.Callable
+    scale_cotangent: typing.Callable
+    from_walk_row: typing.Callable
+    #: whether the backward's residuals that only ever enter a product
+    #: as ``dot_dtype`` inputs (the chunk's V; the walk's W, K̂, V′ and
+    #: the S_n it writes) are KEPT at ``dot_dtype``.  The two shapes
+    #: differ here for no reason the algebra gives (PR 37 measured the
+    #: narrow form in its own cell only): flipping the scalar rule's is
+    #: ROADMAP S6's lead, a change to measure
+    narrow: bool
 
-    s = state[...]
-    s_ref[...] = s.astype(s_ref.dtype)
-    v_new = u_ref[...] - _dot(w_ref[...], s, dot_dtype)
-    v_ref[...] = v_new
-    state[...] = _column(d_ref[...])[0] * s + _dot(
-        k_ref[...], v_new, dot_dtype, trans_a=True)
-
-
-def _kda_state_bwd_kernel(w_ref, k_ref, d_ref, s_ref, v_ref, dv_ref,
-                          ds_ref, dw_ref, dk_ref, du_ref, dd_ref, carry,
-                          *, dot_dtype):
-    @pl.when(pl.program_id(1) == 0)
-    def _start():
-        carry[...] = jnp.zeros_like(carry)
-
-    g, s = carry[...], s_ref[...].astype(jnp.float32)
-    kept, eye = _column(d_ref[...])
-    dv = dv_ref[...] + _dot(k_ref[...], g, dot_dtype)
-    du_ref[...] = dv
-    dk_ref[...] = _dot(v_ref[...], g, dot_dtype, trans_b=True)
-    dw_ref[...] = -_dot(dv, s, dot_dtype, trans_b=True)
-    dd_ref[...] = _cols(eye * _rows(g * s))       # (d_k, 1) as a row
-    carry[...] = ds_ref[...] + kept * g - _dot(
-        w_ref[...], dv, dot_dtype, trans_a=True)
-
-
-def _kda_state_forward(w, k_hat, u, decay, interpret, dot_dtype):
-    g, n, c, dk = w.shape
-    dv = u.shape[-1]
-
-    def first(i):
-        return i
-
-    return pl.pallas_call(
-        functools.partial(_kda_state_fwd_kernel, dot_dtype=dot_dtype),
-        grid=(g, n),
-        in_specs=[_face(c, dk, first), _face(c, dk, first),
-                  _face(c, dv, first), _face(1, dk, first)],
-        out_specs=(_face(c, dv, first), _face(dk, dv, first)),
-        out_shape=(jax.ShapeDtypeStruct((g, n, c, dv), jnp.float32),
-                   jax.ShapeDtypeStruct((g, n, dk, dv),
-                                        dot_dtype or jnp.float32)),
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=_PARAMS, interpret=interpret,
-        name="znicz_kda_state_fwd",
-    )(w, k_hat, u, decay[:, :, None, :])
+    def width(self, dot_dtype):
+        return dot_dtype if self.narrow and dot_dtype is not None \
+            else jnp.float32
 
 
-def _kda_state_backward(w, k_hat, decay, states, v_new, d_v, d_s,
-                        interpret, dot_dtype):
-    g, n, c, dk = w.shape
-    dv = v_new.shape[-1]
-
-    def back(i):
-        return n - 1 - i
-
-    f32 = jnp.float32
-    dw, dk_hat, du, dd = pl.pallas_call(
-        functools.partial(_kda_state_bwd_kernel, dot_dtype=dot_dtype),
-        grid=(g, n),
-        in_specs=[_face(c, dk, back), _face(c, dk, back),
-                  _face(1, dk, back), _face(dk, dv, back),
-                  _face(c, dv, back), _face(c, dv, back),
-                  _face(dk, dv, back)],
-        out_specs=(_face(c, dk, back), _face(c, dk, back),
-                   _face(c, dv, back), _face(1, dk, back)),
-        out_shape=(jax.ShapeDtypeStruct((g, n, c, dk), f32),
-                   jax.ShapeDtypeStruct((g, n, c, dk), f32),
-                   jax.ShapeDtypeStruct((g, n, c, dv), f32),
-                   jax.ShapeDtypeStruct((g, n, 1, dk), f32)),
-        scratch_shapes=[pltpu.VMEM((dk, dv), f32)],
-        compiler_params=_PARAMS, interpret=interpret,
-        name="znicz_kda_state_bwd",
-    )(w, k_hat, decay[:, :, None, :], states, v_new, d_v, d_s)
-    return dw, dk_hat, du, dd[:, :, 0, :]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _kda_scan_kernels(w, k_hat, u, decay, interpret, dot_dtype):
-    return _kda_state_forward(w, k_hat, u, decay, interpret, dot_dtype)
+#: one decay a head: a number, laid along a row's lanes
+_HEAD = _Rule(
+    chunk_kernel="znicz_gdr_chunk", state_kernel="znicz_delta_state",
+    positions=_positions, lower_reads="abk", lower=_chunk_lower,
+    outputs=_chunk_outputs, cotangents=_chunk_cotangents,
+    from_row=lambda rows, g, n: rows[:, 0, 0].reshape(g, n),
+    as_row=lambda d, c: jnp.broadcast_to(d[..., None], d.shape + (c,)),
+    walk_row=lambda decay, dv: jnp.broadcast_to(
+        decay[..., None, None], decay.shape + (1, dv)).astype(jnp.float32),
+    scale=lambda row: (row, None),
+    scale_cotangent=lambda gs, _: _cols(gs),
+    from_walk_row=lambda dd: dd.sum(axis=(-1, -2)),
+    narrow=False)
+#: one decay per key channel: a row of d_k, which scales S's ROWS
+_CHANNEL = _Rule(
+    chunk_kernel="znicz_kda_chunk", state_kernel="znicz_kda_state",
+    positions=lambda c, backward=False: _kda_positions(
+        c, sub_block(c), backward),
+    lower_reads="abqk", lower=_kda_chunk_lower, outputs=_kda_outputs,
+    cotangents=_kda_cotangents,
+    from_row=lambda rows, g, n: rows.reshape(g, n, rows.shape[-1]),
+    as_row=lambda d, c: d,
+    walk_row=lambda decay, dv: decay[:, :, None, :],
+    scale=_column,
+    scale_cotangent=lambda gs, eye: _cols(eye * _rows(gs)),
+    from_walk_row=lambda dd: dd[:, :, 0, :],
+    narrow=True)
 
 
-def _kda_scan_fwd(w, k_hat, u, decay, interpret, dot_dtype):
-    v_new, states = _kda_state_forward(w, k_hat, u, decay, interpret,
-                                       dot_dtype)
-    # kept for the backward at the width its products take them in: W,
-    # K̂ and V′ enter the reverse walk as ``dot_dtype`` matmul inputs
-    # only, so a bf16 copy gives the same numbers at half the bytes
-    # (the f32 arrays die with the forward).  The per-chunk states are
-    # WRITTEN at that width (the carried state stays f32 in VMEM): O's
-    # product and dW take them as ``dot_dtype`` inputs, and only the
-    # decay's cotangent Σ g ⊙ S reads the rounded copy elementwise
-    kept = (lambda a: a) if dot_dtype is None \
-        else (lambda a: a.astype(dot_dtype))
-    return (v_new, states), (kept(w), kept(k_hat), decay, states,
-                             kept(v_new))
-
-
-def _kda_scan_bwd(interpret, dot_dtype, residual, cotangent):
-    w, k_hat, decay, states, v_new = residual
-    d_v, d_s = cotangent
-    return _kda_state_backward(w, k_hat, decay, states, v_new, d_v, d_s,
-                               interpret, dot_dtype)
-
-
-_kda_scan_kernels.defvjp(_kda_scan_fwd, _kda_scan_bwd)
+def _rule_for(per_channel: bool) -> _Rule:
+    return _CHANNEL if per_channel else _HEAD
 
 
 # ----------------------------------------------------------------------

@@ -44,6 +44,7 @@ here is PERF.md §7's to weigh.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -327,3 +328,48 @@ def latent_flash_attention(q_nope, q_rope, k_nope, k_rope, v,
     (B, T, H·64) — q already scaled —, k_rope (B, T, 64) shared by all
     heads → o (B, T, H·128) in the operands' dtype."""
     return _attend(q_nope, q_rope, k_nope, k_rope, v, interpret)
+
+
+class LatentPlan(NamedTuple):
+    """Whether and how the two-width kernels run one latent-K/V layer's
+    call (:func:`plan`), as ``pallas_attention.FlashPlan`` does: why not
+    (None: they do), interpreted or not, the tile edge along T."""
+    refused: str | None
+    interpret: bool
+    tile: int
+
+    @property
+    def runs(self) -> bool:
+        return self.refused is None
+
+    def attend(self, *arrays):
+        return latent_flash_attention(*arrays, interpret=self.interpret)
+
+    def line(self) -> str:
+        if not self.runs:
+            return f"plain core, K assembled in full ({self.refused})"
+        return ("znicz_flash_fwd_mla / znicz_flash_bwd_mla_dq / _dkv "
+                "kernels, tiles of %d%s" % (
+                    self.tile, ", INTERPRETED" if self.interpret else ""))
+
+
+def plan(device, t: int, n_heads: int, qk_nope: int, qk_rope: int,
+         v_dim: int) -> LatentPlan:
+    """The :class:`LatentPlan` of a causal call over ``t`` positions on
+    ``device``: ``engine.flash_attention`` and ``pallas_interpret``
+    resolved once, one device, :func:`kernel_legal` shapes."""
+    from znicz_tpu.ops import pallas_kernels
+    from znicz_tpu.utils.config import root
+    interpret = bool(root.common.engine.get("pallas_interpret", False))
+    refused = pallas_kernels.kernel_refusal(device, "flash_attention",
+                                            interpret)
+    mesh = getattr(device, "mesh", None)
+    if refused is None and mesh is not None and mesh.size > 1:
+        refused = (f"a mesh of {mesh.size} devices: the two-width "
+                   f"kernels have no sharding rule")
+    if refused is None and not kernel_legal(t, n_heads, qk_nope, qk_rope,
+                                            v_dim):
+        refused = (f"T={t}, {n_heads} heads of {qk_nope} + {qk_rope} / "
+                   f"{v_dim} do not tile (128 + 64 / 128, an even head "
+                   f"count, T whole tiles)")
+    return LatentPlan(refused, interpret, min(BLOCK, t))
