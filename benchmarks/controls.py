@@ -18,6 +18,7 @@ limit it passes under.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import tempfile
 import time
@@ -25,16 +26,21 @@ import time
 T_START = time.perf_counter()
 
 
-def run_checks(cell_name: str, make_checks, make_readings=None,
-               doc: str = "") -> int:
-    """``make_checks(reference, layers)`` and ``make_readings(…)`` give
-    ``(name, module)`` pairs, ``module`` standing where the driver
-    loads the cell's reference; one JSON line each, ``ok`` last."""
+def arguments(doc: str = "") -> argparse.ArgumentParser:
+    """``--seed`` and ``--toy``; a cell's script may add its own."""
     parser = argparse.ArgumentParser(description=doc.split("\n")[0])
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument("--toy", action="store_true")
-    args = parser.parse_args()
+    return parser
 
+
+@contextlib.contextmanager
+def trained(cell_name: str, args, epochs: int = 1, edit=None):
+    """The cell's workflow as its driver builds it — from the layer
+    table ``edit`` has changed in place, if given: a SYSTEM made wrong
+    in a stated way — after ``epochs`` epochs of steps, under the
+    traffic's engine options: ``(ctx, driver, workflow, layers,
+    reference, devices)``."""
     from znbench.harness import discovery, programs
     from znbench.harness.program import engine_options, layer_table
     from znbench.harness.window import Context
@@ -50,18 +56,41 @@ def run_checks(cell_name: str, make_checks, make_readings=None,
     ctx = Context(cell, args.seed, 0.0, False, args.toy, devices,
                   T_START, scratch)
     layers = layer_table(cell.config)
-    load_module = discovery.load_module
-    ok = True
+    if edit is not None:
+        edit(layers)
     with engine_options(cell.traffic.get("engine", {})):
         wf, _ = driver.build(ctx, layers)
         trainer = driver.train.Trainer(ctx, wf)
-        trainer.epoch()
+        for _ in range(epochs):
+            trainer.epoch()
         trainer.fence()
+        yield ctx, driver, wf, layers, reference, devices
+
+
+def run_checks(cell_name: str, make_checks, make_readings=None,
+               doc: str = "", args=None) -> int:
+    """``make_checks(reference, layers, workflow)`` and
+    ``make_readings(…)`` give ``(name, module)`` pairs, ``module``
+    standing where the driver loads the cell's reference; one JSON line
+    each, ``ok`` last.  ``workflow`` is the cell's after its epoch of
+    steps, for a control that needs a value no bundle holds (a
+    selection bias).  ``args``: :func:`arguments`' as parsed, where the
+    script has options of its own."""
+    from znbench.harness import discovery
+
+    if args is None:
+        args = arguments(doc).parse_args()
+    load_module = discovery.load_module
+    ok = True
+    with trained(cell_name, args) as (ctx, driver, wf, layers, reference,
+                                      devices):
+        cell = ctx.cell
         checks = [("reference", None, False)] + [
             (name, module, False)
-            for name, module in make_checks(reference, layers)] + [
+            for name, module in make_checks(reference, layers, wf)] + [
             (name, module, True) for name, module in
-            (make_readings(reference, layers) if make_readings else [])]
+            (make_readings(reference, layers, wf)
+             if make_readings else [])]
         for name, module, reading in checks:
             if module is not None:
                 discovery.load_module = (

@@ -81,7 +81,7 @@ def spoiled(reference, where: list, last: int, edit: dict, inputs):
 
 def main() -> int:
     from benchmarks.controls import run_checks
-    return run_checks(CELL, lambda reference, layers: [
+    return run_checks(CELL, lambda reference, layers, _workflow: [
         (name, spoiled(reference, *how))
         for name, *how in controls(layers)], doc=__doc__)
 

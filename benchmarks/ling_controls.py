@@ -140,7 +140,7 @@ def main() -> int:
     from benchmarks.controls import run_checks
 
     def made(listed):
-        return lambda reference, layers: [
+        return lambda reference, layers, _workflow: [
             (name, spoiled(reference, *how))
             for name, *how in listed(reference, layers)]
     return run_checks(CELL, made(controls), made(readings), doc=__doc__)

@@ -120,7 +120,10 @@ def readings(reference, layers: list) -> list:
 
 def main() -> int:
     from benchmarks.controls import run_checks
-    return run_checks(CELL, controls, readings, doc=__doc__)
+    return run_checks(
+        CELL, lambda reference, layers, _workflow: controls(reference, layers),
+        lambda reference, layers, _workflow: readings(reference, layers),
+        doc=__doc__)
 
 
 if __name__ == "__main__":

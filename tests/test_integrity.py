@@ -772,3 +772,31 @@ def test_gang_sdc_exit_blocklists_and_resumes_pre_divergence(tmp_path):
     assert _counter("znicz_sdc_detected_total", kind="vote") \
         == det_before + 1, "worker attestations not folded"
     assert summary["resumed_step"] == 9
+
+
+@pytest.mark.parametrize("out_dtype", ["bfloat16", "float32"])
+def test_short_conv_kernels_compile_for_v5e_at_published_widths(
+        v5e_chip, out_dtype):
+    """``znicz_short_conv_fwd`` / ``_bwd`` (PR 43) at LFM2's widths,
+    T 4,096 × 3 · 2,048 columns, through Mosaic for the chip: the
+    sublane rotations, the 16-row halo blocks at either width of y's
+    cotangent and the VMEM the full-width blocks ask for are what
+    interpret mode cannot refuse.  Here and not beside the kernels'
+    other tests: only ONE test file of the suite may load libtpu."""
+    import jax
+    import jax.numpy as jnp
+    from znicz_tpu.ops import pallas_short_conv
+
+    def step(projected, taps, weight):
+        def loss(projected, taps):
+            y = pallas_short_conv.short_conv(projected, taps,
+                                             jnp.dtype(out_dtype))
+            return jnp.sum(y.astype(jnp.float32) * weight)
+        return jax.value_and_grad(loss, (0, 1))(projected, taps)
+
+    shapes = [jax.ShapeDtypeStruct(shape, jnp.float32, sharding=v5e_chip)
+              for shape in ((1, 4096, 6144), (2048, 3), (1, 4096, 2048))]
+    text = jax.jit(step).lower(*shapes).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    for name in ("znicz_short_conv_fwd", "znicz_short_conv_bwd"):
+        assert name in text

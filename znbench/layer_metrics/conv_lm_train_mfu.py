@@ -1,0 +1,29 @@
+"""Model FLOP/s utilization of the traced run of a language-model
+training cell whose mixers are gated short convolutions among
+grouped-query attention layers over expert layers that hold a share:
+the FLOPs forward and backward need per step (``znbench/flops_conv.py``:
+every projection at its own width, the convolution's chain, the causal
+half of the scores, the dense MLP, the routed rows this chip computed —
+read from ``znicz_moe_held`` where the program has it, else expected
+under uniform routing — the routers, the head; recomputed work not
+counted) times steps per second, over chips times the published bf16
+peak.  An end-to-end utilization from the host clock — not a roofline
+share.  Nothing where the table holds no short convolution."""
+
+from znbench import flops_conv
+from znbench.harness import discovery
+
+
+def read(obs):
+    if obs.peaks is None:       # no published peak off a TPU: no MFU
+        return None
+    seen = obs.observations
+    if not flops_conv.conv_layers(seen["layers"]):
+        return None
+    rows = discovery.load_module(
+        "layer_metrics", "band_lm_train_mfu").routed_rows(obs)
+    per_step = flops_conv.lm_train_flops(
+        seen["layers"], seen["sample_shape"][0], seen["batch"], rows)
+    rate = seen["steps"] / obs.window_s
+    return 100.0 * per_step * rate / (
+        obs.chips * obs.peaks["bf16_flops_per_s"])

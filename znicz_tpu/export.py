@@ -252,6 +252,12 @@ def refuse_unserved(forwards, what: str) -> None:
                 f"state slot yet — the recurrent state and the "
                 f"convolution's tail exist on the training path only "
                 f"(ROADMAP R6, serving half)")
+        if kind == "ShortConv":
+            raise NotImplementedError(
+                f"{what}: layer {i} is a gated short-convolution mixer "
+                f"(short_conv); serving has no rolling state for the "
+                f"convolution's last taps yet — the mixer exists on the "
+                f"training path only (ROADMAP R1, serving half)")
         if kind == "MoE":
             choice = [name for name, on in (
                 ("select_bias", getattr(unit, "select_bias_on", False)),
@@ -272,6 +278,13 @@ def refuse_unserved(forwards, what: str) -> None:
                 f"no latent page and no absorbed projections yet — the "
                 f"prefill / decode steps cache whole keys and values "
                 f"(ROADMAP R5, serving half)")
+        if getattr(unit, "qk_norm", None) == "rms_head":
+            raise NotImplementedError(
+                f"{what}: attention layer {i} sets qk_norm=rms_head (a "
+                f"norm over each head of q and k, one gain of the head's "
+                f"size); the manifest and the prefill / decode steps "
+                f"lack the gains and the norm of a cached key (ROADMAP "
+                f"R1, serving half)")
         used = [name for name in _BLOCK_OPTIONS
                 if getattr(unit, name, None)]
         if getattr(unit, "n_kv_heads", unit.n_heads) != unit.n_heads:

@@ -38,6 +38,7 @@ from znicz_tpu.mutable import Bool
 from znicz_tpu.ops import activation, all2all, conv, cutter, dropout, pooling
 from znicz_tpu.ops import attention, deconv, depooling, lstm, normalization
 from znicz_tpu.ops import delta_net, embedding, layer_norm, moe, pos_encoding
+from znicz_tpu.ops import short_conv
 from znicz_tpu.ops import loop_exits, rms_norm
 from znicz_tpu.ops import seq_reshape
 from znicz_tpu.ops import gd, gd_conv, gd_pooling  # noqa: F401 (pairs)
@@ -112,6 +113,7 @@ for _name, _cls in {
     "moe": moe.MoE,
     "gated_mlp": moe.GatedMLP,
     "gated_delta_net": delta_net.GatedDeltaNet,
+    "short_conv": short_conv.ShortConv,
     "loop_exits": loop_exits.All2AllExits,
 }.items():
     register_layer_type(_name, _cls)

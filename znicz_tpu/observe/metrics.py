@@ -642,6 +642,19 @@ def delta_scan(unit: str, stat: str) -> Gauge:
         labels=("unit", "stat")).labels(unit=unit, stat=stat)
 
 
+def short_conv(unit: str, stat: str) -> Gauge:
+    """The form a ``ShortConv`` unit's chain took (``stat`` = ``path``:
+    1 the ``znicz_short_conv_fwd`` / ``_bwd`` kernels from the
+    projection where it lies to W_out's input, 0 both gates and the
+    taps in ``jax.numpy``; ``taps``: J; ``channels``: D).  Static per
+    program, set once at ``initialize``."""
+    return REGISTRY.gauge(
+        "znicz_short_conv",
+        "Gated short-convolution mixer: kernels (1) or jax.numpy (0) "
+        "for the chain between its projections, taps, channels",
+        labels=("unit", "stat")).labels(unit=unit, stat=stat)
+
+
 def moe_router(unit: str, stat: str) -> Gauge:
     """The choice of a ``MoE`` unit with ``select_bias`` (``stat`` =
     ``groups_kept``: groups a token's top k are taken among, 0 without

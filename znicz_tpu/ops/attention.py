@@ -28,7 +28,9 @@ does not change: ``pre_norm="rms"`` attends over ``RMSNorm(x)``,
 ``residual=True`` adds the input back (``y = x + f(norm(x))`` is ONE
 unit with ONE GD pair, so the workflow's graph stays a chain),
 ``qk_norm="rms"`` normalizes the whole D-wide q and k projections (not
-per head) with a gain each, ``rope={"theta": …}`` rotates q and k by
+per head) with a gain each — ``qk_norm="rms_head"`` (LFM2, PR 43) each
+HEAD of q and of k over its own dh dims, one gain of dh shared by the
+heads —, ``rope={"theta": …}`` rotates q and k by
 position over the full head in the half-split ("rotate_half")
 convention.  Norms and rotation run in f32 between the QKV projection
 and the attention core; the kernels are untouched.
@@ -316,11 +318,13 @@ class MultiHeadAttention(Forward):
         self.seq_parallel = bool(seq_parallel)
         self._ring_active = False
         for option, value in (("pre_norm", pre_norm),
-                              ("qk_norm", qk_norm),
                               ("post_norm", post_norm)):
             if value not in (None, "rms"):
                 raise ValueError(f"{option} must be None or 'rms', got "
                                  f"{value!r}")
+        if qk_norm not in (None, "rms", "rms_head"):
+            raise ValueError(f"qk_norm must be None, 'rms' or "
+                             f"'rms_head', got {qk_norm!r}")
         #: the pre-norm residual block (module docstring); all off =
         #: the bare layer, whose program these options leave untouched
         self.pre_norm = pre_norm
@@ -437,10 +441,9 @@ class MultiHeadAttention(Forward):
                              d if self.pre_norm or self.post_norm else 0),
                             (self.gain_post,
                              d if self.pre_norm and self.post_norm else 0),
-                            (self.gain_q,
-                             q_width if self.qk_norm else 0),
+                            (self.gain_q, self._qk_gain_width(q_width, dh)),
                             (self.gain_k,
-                             kv_width if self.qk_norm else 0)):
+                             self._qk_gain_width(kv_width, dh))):
             if width and not gain:
                 gain.reset(np.ones(width, np.float32))
         rotated = self.rotary_dim or dh
@@ -641,14 +644,28 @@ class MultiHeadAttention(Forward):
         dh = self.head_dim or d // self.n_heads
         return self.n_heads * dh, self.n_kv_heads * dh, dh
 
+    def _qk_gain_width(self, width: int, dh: int) -> int:
+        """A q/k norm's gain: the projection's width, a head's under
+        ``rms_head``, none without the norm."""
+        return {None: 0, "rms": width, "rms_head": dh}[self.qk_norm]
+
+    def _qk_normed(self, xp, rows, gain, dh: int):
+        """(B, T, width) normed over the whole projection (``rms``) or
+        over each head's ``dh`` (``rms_head``)."""
+        if self.qk_norm == "rms":
+            return rms_norm(xp, rows, gain, self.norm_eps)
+        b, t, width = rows.shape
+        return rms_norm(xp, rows.reshape(b, t, width // dh, dh), gain,
+                        self.norm_eps).reshape(b, t, width)
+
     def _rope_tables(self, xp, t: int, dh: int):
         return rope_tables(xp, t, self.rotary_dim or dh, self.rope_theta,
                            self.rope_yarn)
 
     def _normed_rotated(self, xp, qkv, g_q, g_k, rows: bool = False):
         """(B, T, q + 2·kv widths) f32 projections → q (B, T, H, dh),
-        k, v (B, T, H_kv, dh) with the whole-projection q/k norms and
-        the rotation applied; ``rows`` keeps them (B, T, width), where
+        k, v (B, T, H_kv, dh) with the q/k norms (whole-projection or
+        per head) and the rotation applied; ``rows`` keeps them (B, T, width), where
         the flash kernels read them."""
         b, t, wide = qkv.shape
         h, h_kv = self.n_heads, self.n_kv_heads
@@ -656,8 +673,8 @@ class MultiHeadAttention(Forward):
         qw, kw = h * dh, h_kv * dh
         q, k, v = qkv[..., :qw], qkv[..., qw:qw + kw], qkv[..., qw + kw:]
         if self.qk_norm:
-            q = rms_norm(xp, q, g_q, self.norm_eps)
-            k = rms_norm(xp, k, g_k, self.norm_eps)
+            q = self._qk_normed(xp, q, g_q, dh)
+            k = self._qk_normed(xp, k, g_k, dh)
         if not rows:
             q = q.reshape(b, t, h, dh)
             k, v = k.reshape(b, t, h_kv, dh), v.reshape(b, t, h_kv, dh)
@@ -1386,12 +1403,16 @@ class GDMultiHeadAttention(GradientDescentBase):
             dk = apply_rope(np, dk, cos, sin, inverse=True)
         if fwd.qk_norm:                   # back through the q/k norms
             raw = qkv.reshape(b, t, -1)
+            # over the whole projection, or over each head's dh
+            over = (lambda a: a.reshape(b, t, -1, dh)) \
+                if fwd.qk_norm == "rms_head" \
+                else (lambda a: a.reshape(b, t, -1))
             dq, grad_gains["q"] = rms_norm_backward(
-                np, raw[..., :qw], fwd.gain_q.mem, fwd.norm_eps,
-                dq.reshape(b, t, qw))
+                np, over(raw[..., :qw]), fwd.gain_q.mem, fwd.norm_eps,
+                over(dq))
             dk, grad_gains["k"] = rms_norm_backward(
-                np, raw[..., qw:qw + kw], fwd.gain_k.mem, fwd.norm_eps,
-                dk.reshape(b, t, kw))
+                np, over(raw[..., qw:qw + kw]), fwd.gain_k.mem,
+                fwd.norm_eps, over(dk))
         dqkv = np.concatenate(
             [a.reshape(b, t, -1) for a in (dq, dk, dv)],
             axis=-1).reshape(b * t, qw + 2 * kw)

@@ -167,16 +167,19 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from znicz_tpu.ops.pallas_taps import (LANES, SUBLANES, column_sums, delayed,
+                                       eight_rows, ones_where, taps_sum,
+                                       unless)
+
 #: positions per chunk (the program's, not a model's)
 CHUNK = 64
-_LANES, _SUBLANES = 128, 8
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def kernel_legal(chunk: int = CHUNK) -> bool:
     """A chunk's rows are whole sublane tiles (and a power of two, as
     the inverse wants them)."""
-    return chunk % _SUBLANES == 0 and chunk & (chunk - 1) == 0
+    return chunk % SUBLANES == 0 and chunk & (chunk - 1) == 0
 
 
 def padded_share(dk: int, dv: int) -> float:
@@ -186,7 +189,7 @@ def padded_share(dk: int, dv: int) -> float:
     each goes to the next multiple of 128 (1.0: nothing padded, as at
     Ling-3.0-flash's 128 × 128; 1.78 at Olmo-Hybrid's 96 × 192)."""
     def whole(n: int) -> int:
-        return -(-n // _LANES) * _LANES
+        return -(-n // LANES) * LANES
     return whole(dk) * whole(dv) / float(dk * dv)
 
 
@@ -398,14 +401,6 @@ def _mask_product(mask, x, right: bool = False):
                                preferred_element_type=jnp.float32)
 
 
-def _ones_where(condition):
-    """0/1 in f32: a mask to multiply by.  (A ``jnp.where`` or an
-    integer ``//`` in a kernel's body is a nested call that the host
-    traces and lowers once per use, a third of a process's start for
-    these kernels; a product is one equation.)"""
-    return condition.astype(jnp.float32)
-
-
 def _positions(c: int, backward: bool = False):
     """A (C, C) matrix's entries on and below the diagonal, strictly
     below, strictly above, and on it, as 0/1 in f32 to multiply by; and
@@ -415,8 +410,8 @@ def _positions(c: int, backward: bool = False):
     row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
     upto, below, above, eye = (
-        _ones_where(col <= row), _ones_where(col < row),
-        _ones_where(col > row), _ones_where(col == row))
+        ones_where(col <= row), ones_where(col < row),
+        ones_where(col > row), ones_where(col == row))
     return (upto, below, above, eye,
             _for_mask_product(below, right=True),
             _for_mask_product(above, right=True) if backward else None)
@@ -425,11 +420,6 @@ def _positions(c: int, backward: bool = False):
 def _rows(x):
     """Σ over a row's entries, (C, ·) → (C, 1)."""
     return jnp.sum(x, axis=1, keepdims=True)
-
-
-def _cols(x):
-    """Σ over a column's entries, (C, ·) → (1, ·)."""
-    return jnp.sum(x, axis=0, keepdims=True)
 
 
 def _inverse_levels(c: int, n: int):
@@ -442,12 +432,12 @@ def _inverse_levels(c: int, n: int):
     col = lane & (c - 1)                  # within a lane's own matrix
     joins, size, shift = [], 1, 1
     while size < c:
-        joins.append(_ones_where(
+        joins.append(ones_where(
             (row >> shift == col >> shift) & (row & size != 0)
             & (col & size == 0)))
         size, shift = 2 * size, shift + 1
-    own = [_ones_where(lane >> (shift - 1) == m) for m in range(n)]
-    return _ones_where(row == col), joins, own
+    own = [ones_where(lane >> (shift - 1) == m) for m in range(n)]
+    return ones_where(row == col), joins, own
 
 
 def _inverses_in_vmem(lowers, levels):
@@ -487,7 +477,7 @@ def _side_by_side(block: int, c: int) -> int:
     """Chunks whose inverses share one chain of products
     (:func:`_inverses_in_vmem`): two where they fill a 128-lane tile
     and a grid step holds an even number."""
-    return 2 if block % 2 == 0 and 2 * c <= _LANES else 1
+    return 2 if block % 2 == 0 and 2 * c <= LANES else 1
 
 
 def chunk_products(channels: bool, chunk: int = CHUNK, sub: int = None,
@@ -621,12 +611,13 @@ def _chunk_cotangents(positions, q, k, v, log_alpha, beta, x, d_w, d_kh,
         + mixed(d_qk, q, trans_a=True) + grown * d_kg + rest * d_kh
     # β: a column's sum where it scales A's columns, a row's (as a
     # row: (C, 1) → (1, C) without a transpose) where L's rows
-    d_beta = _cols(d_a * x) + _cols(eye * _rows(d_lower * gamma * kk))
+    d_beta = column_sums(d_a * x) \
+        + column_sums(eye * _rows(d_lower * gamma * kk))
     # log α: Γ = exp(Σ_{j<m≤i}), exp(c), exp(c_C − c), exp(c_C)
     d_upto = _mask_product(back, d_gamma * gamma, right=True)
     d_c = grown * (_rows(d_qc * q) + _rows(d_kg * k))
     d_rest = rest * _rows(d_kh * k)
-    d_alpha = _cols(upto * (d_upto + d_c) + above * d_rest) \
+    d_alpha = column_sums(upto * (d_upto + d_c) + above * d_rest) \
         + d_decay * decay
     return d_q, d_k, d_v, d_alpha, d_beta
 
@@ -655,8 +646,8 @@ def _chunk_call(kernel, name: str, arrays, outs, block: int, interpret):
     for their double-buffered blocks."""
     total = arrays[0].shape[0]
     faces = [a.shape[1:] for a in arrays] + list(outs)
-    held = sum(-(-rows // _SUBLANES) * _SUBLANES
-               * -(-width // _LANES) * _LANES * 4 for rows, width in faces)
+    held = sum(-(-rows // SUBLANES) * SUBLANES
+               * -(-width // LANES) * LANES * 4 for rows, width in faces)
 
     def spec(face):
         return pl.BlockSpec((block,) + tuple(face), lambda n: (n, 0, 0))
@@ -1014,25 +1005,25 @@ def _kda_positions(c: int, sub: int, backward: bool = False):
     shift = sub.bit_length() - 1
 
     def masks(row, col):
-        within = _ones_where((col <= row)
+        within = ones_where((col <= row)
                              & (row >> shift == col >> shift))
         reach = []
         for a in range(c // sub):
             start = a * sub
             reach.append(
-                _ones_where((col > row) & (col < start))
-                - _ones_where((col >= start) & (col <= row)
+                ones_where((col > row) & (col < start))
+                - ones_where((col >= start) & (col <= row)
                               & (row < start + sub)))
-        return [within, *reach, _ones_where(col <= row),
-                _ones_where(col > row)]
+        return [within, *reach, ones_where(col <= row),
+                ones_where(col > row)]
 
     row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
     sums = _for_mask_product(jnp.concatenate(masks(row, col), axis=0))
     back = _for_mask_product(jnp.concatenate(
         masks(col, row), axis=1)) if backward else None
-    return (_ones_where(col <= row), _ones_where(col < row),
-            _ones_where(col == row), sums, back)
+    return (ones_where(col <= row), ones_where(col < row),
+            ones_where(col == row), sums, back)
 
 
 def _kda_factors(sums, q, k, log_alpha):
@@ -1068,7 +1059,7 @@ def _kda_chunk_lower(positions, log_alpha, beta, q, k):
     rights = [k * e for e in scales]
     m = _kda_products(left_k, rights, sub_block(k.shape[0]), _exact)
     lower = below * (_rows(eye * beta) * m)
-    decay = jnp.exp(_cols(log_alpha))
+    decay = jnp.exp(column_sums(log_alpha))
     return lower, decay, grown, rest, left_q, rights
 
 
@@ -1095,7 +1086,7 @@ def _kda_cotangents(positions, q, k, v, log_alpha, beta, x, d_w, d_kh,
         sums, q, k, log_alpha)
     rights = [k * e for e in scales]
     m = _kda_products(left_k, rights, sub, exact)
-    decay = jnp.exp(_cols(log_alpha))
+    decay = jnp.exp(column_sums(log_alpha))
     beta_col = _rows(eye * beta)
     a = x * beta
     # W = A (exp(c) ⊙ K), U = A V
@@ -1108,7 +1099,7 @@ def _kda_cotangents(positions, q, k, v, log_alpha, beta, x, d_w, d_kh,
                              trans_b=True)
     d_m = d_lower * beta_col
     d_pl = upto * d_p
-    d_beta = _cols(d_a * x) + _cols(eye * _rows(d_lower * m))
+    d_beta = column_sums(d_a * x) + column_sums(eye * _rows(d_lower * m))
     # M and P by sub-blocks: rows A = left[A] · (K ⊙ scale_A)ᵀ
     d_k = grown * d_kg + rest * d_kh
     d_left_k, d_left_q, d_scales = [], [], []
@@ -1138,7 +1129,7 @@ def _column(row):
     """(1, d) → (d, 1) without a transpose, and the identity that made
     it."""
     d = row.shape[1]
-    eye = _ones_where(jax.lax.broadcasted_iota(jnp.int32, (d, d), 0)
+    eye = ones_where(jax.lax.broadcasted_iota(jnp.int32, (d, d), 0)
                       == jax.lax.broadcasted_iota(jnp.int32, (d, d), 1))
     return _rows(eye * row), eye
 
@@ -1204,7 +1195,7 @@ _HEAD = _Rule(
     walk_row=lambda decay, dv: jnp.broadcast_to(
         decay[..., None, None], decay.shape + (1, dv)).astype(jnp.float32),
     scale=lambda row: (row, None),
-    scale_cotangent=lambda gs, _: _cols(gs),
+    scale_cotangent=lambda gs, _: column_sums(gs),
     from_walk_row=lambda dd: dd.sum(axis=(-1, -2)),
     narrow=False)
 #: one decay per key channel: a row of d_k, which scales S's ROWS
@@ -1218,7 +1209,7 @@ _CHANNEL = _Rule(
     as_row=lambda d, c: d,
     walk_row=lambda decay, dv: decay[:, :, None, :],
     scale=_column,
-    scale_cotangent=lambda gs, eye: _cols(eye * _rows(gs)),
+    scale_cotangent=lambda gs, eye: column_sums(eye * _rows(gs)),
     from_walk_row=lambda dd: dd[:, :, 0, :],
     narrow=True)
 
@@ -1243,7 +1234,7 @@ def prep_legal(dk: int, dv: int) -> bool:
     q ‖ k ‖ v projection: a ``BlockSpec`` addresses them where the
     matmul wrote them, and a head's L2 norm is a reduction across the
     lanes of its own tiles."""
-    return dk % _LANES == 0 and dv % _LANES == 0
+    return dk % LANES == 0 and dv % LANES == 0
 
 
 def _prep_sections(heads: int, dk: int, dv: int):
@@ -1255,26 +1246,8 @@ def _prep_sections(heads: int, dk: int, dv: int):
     128 lanes at a time, so that its first column is a whole block
     whatever d_k and d_v are."""
     return ((dk, 0, heads, 1, dk ** -0.5), (dk, heads, heads, 1, 1.0),
-            (_LANES, 2 * heads * dk // _LANES, heads * dv // _LANES,
-             dv // _LANES, None))
-
-
-def _shifted(ext, width: int, rows: int):
-    """u_{t−s} for s < ``width`` over ``rows`` rows, from ``ext`` — a
-    stretch of u that starts 8 rows before the first of them — by a
-    sublane rotation each (s < 8: nothing wraps into the rows kept)."""
-    return [ext[_SUBLANES:_SUBLANES + rows]] + [
-        pltpu.roll(ext, s, 0)[_SUBLANES:_SUBLANES + rows]
-        for s in range(1, width)]
-
-
-def _conv(shifted, taps):
-    """c_t = Σ_j taps[j] · u_{t−J+1+j} (``delta_net.causal_conv``)."""
-    width = len(taps)
-    c = shifted[width - 1] * taps[0]
-    for j in range(1, width):
-        c = c + shifted[width - 1 - j] * taps[j]
-    return c
+            (LANES, 2 * heads * dk // LANES, heads * dv // LANES,
+             dv // LANES, None))
 
 
 def _prep_rows(ext, taps, scale, eps):
@@ -1283,7 +1256,7 @@ def _prep_rows(ext, taps, scale, eps):
     a ROW and a product: a row statistic costs a whole vreg whatever it
     holds, so a division by it was as dear as the SiLU's) or of v, from
     u."""
-    c = _conv(_shifted(ext, len(taps), ext.shape[0] - _SUBLANES), taps)
+    c = taps_sum(delayed(ext, len(taps), ext.shape[0] - SUBLANES), taps)
     a = c / (1.0 + jnp.exp(-c))                  # ``moe._silu``'s form
     if scale is None:
         return a
@@ -1297,8 +1270,8 @@ def _prep_cotangents(ext, dy, taps, scale, eps):
     du of the sub-tile's rows, and per tap Σ_t dc_t · u_{t−J+1+j} over
     them as a row.  c, a and the norm are made again."""
     width, rows = len(taps), dy.shape[0]          # the sub-tile's + 8
-    shifted = _shifted(ext, width, rows)
-    c = _conv(shifted, taps)
+    shifted = delayed(ext, width, rows)
+    c = taps_sum(shifted, taps)
     sigma = 1.0 / (1.0 + jnp.exp(-c))
     if scale is not None:
         a = c * sigma              # silu(c): one division, not two
@@ -1308,33 +1281,19 @@ def _prep_cotangents(ext, dy, taps, scale, eps):
             dy = dy * scale
     # silu′(c) = σ (1 + c (1 − σ))
     dc = dy * (sigma * (1.0 + c * (1.0 - sigma)))
-    own = rows - _SUBLANES
+    own = rows - SUBLANES
     du = dc[:own] * taps[width - 1]
     for s in range(1, width):         # dc_{t+s}: up to J − 1 rows after
         du = du + pltpu.roll(dc, rows - s, 0)[:own] * taps[width - 1 - s]
-    return du, [_cols(dc[:own] * shifted[width - 1 - j][:own])
+    return du, [column_sums(dc[:own] * shifted[width - 1 - j][:own])
                 for j in range(width)]
-
-
-def _eight(ref, start):
-    """8 rows of a block from the sublane-aligned ``start``, f32."""
-    return ref[pl.ds(pl.multiple_of(start, _SUBLANES), _SUBLANES),
-               :].astype(jnp.float32)
 
 
 def _before(ref, halo, k, start):
     """The 8 rows before sub-tile ``k`` of a block, which starts at
     ``start``: the ``halo`` before the block's first."""
     return jax.lax.select(
-        k == 0, halo, _eight(ref, jnp.maximum(start - _SUBLANES, 0)))
-
-
-def _unless(seen, x):
-    """``x`` where ``seen`` (a column of booleans), zeros elsewhere —
-    a select, not a product: a block past an array's end holds
-    anything."""
-    return jax.lax.select(jnp.broadcast_to(seen, x.shape), x,
-                          jnp.zeros_like(x))
+        k == 0, halo, eight_rows(ref, jnp.maximum(start - SUBLANES, 0)))
 
 
 def _prep_fwd_kernel(u_ref, before_ref, taps_ref, out_ref, *, sub, scale,
@@ -1343,8 +1302,8 @@ def _prep_fwd_kernel(u_ref, before_ref, taps_ref, out_ref, *, sub, scale,
     taps = [taps_ref[j:j + 1, :] for j in range(taps_ref.shape[0])]
     f32 = jnp.float32
     # zeros before the sequence
-    halo = before_ref[...].astype(f32) * _ones_where(tile > 0)
-    at = jax.lax.broadcasted_iota(jnp.int32, (sub + _SUBLANES, 1), 0)
+    halo = before_ref[...].astype(f32) * ones_where(tile > 0)
+    at = jax.lax.broadcasted_iota(jnp.int32, (sub + SUBLANES, 1), 0)
 
     def some(k, carry):
         start = pl.multiple_of(k * sub, sub)
@@ -1352,11 +1311,11 @@ def _prep_fwd_kernel(u_ref, before_ref, taps_ref, out_ref, *, sub, scale,
             [_before(u_ref, halo, k, start),
              u_ref[pl.ds(start, sub), :].astype(f32)], axis=0)
         if masked:
-            seen = tile * rows + start - _SUBLANES + at < length
-            ext = _unless(seen, ext)
+            seen = tile * rows + start - SUBLANES + at < length
+            ext = unless(seen, ext)
         out = _prep_rows(ext, taps, scale, eps)
         if masked:      # the padding's rows are zeros, as jnp.pad's were
-            out = _unless(seen[_SUBLANES:], out)
+            out = unless(seen[SUBLANES:], out)
         out_ref[pl.ds(start, sub), :] = out
         return carry
 
@@ -1370,12 +1329,12 @@ def _prep_bwd_kernel(u_ref, before_ref, after_ref, dy_ref, dy_after_ref,
     width, steps = taps_ref.shape[0], rows // sub
     taps = [taps_ref[j:j + 1, :] for j in range(width)]
     f32 = jnp.float32
-    halo = before_ref[...].astype(f32) * _ones_where(tile > 0)
+    halo = before_ref[...].astype(f32) * ones_where(tile > 0)
     # nothing follows the last tile: a zero cotangent there makes dc 0
     tail = after_ref[...].astype(f32)
-    dy_tail = dy_after_ref[...] * _ones_where(
+    dy_tail = dy_after_ref[...] * ones_where(
         tile < pl.num_programs(2) - 1)
-    at = jax.lax.broadcasted_iota(jnp.int32, (sub + 2 * _SUBLANES, 1), 0)
+    at = jax.lax.broadcasted_iota(jnp.int32, (sub + 2 * SUBLANES, 1), 0)
 
     @pl.when((pl.program_id(1) == 0) & (tile == 0))
     def _start():
@@ -1384,18 +1343,19 @@ def _prep_bwd_kernel(u_ref, before_ref, after_ref, dy_ref, dy_after_ref,
     def some(k, sums):
         start = pl.multiple_of(k * sub, sub)
         last = k == steps - 1
-        after = jnp.minimum(start + sub, rows - _SUBLANES)
+        after = jnp.minimum(start + sub, rows - SUBLANES)
         ext = jnp.concatenate(
             [_before(u_ref, halo, k, start),
              u_ref[pl.ds(start, sub), :].astype(f32),
-             jax.lax.select(last, tail, _eight(u_ref, after))], axis=0)
+             jax.lax.select(last, tail, eight_rows(u_ref, after))],
+            axis=0)
         dy = jnp.concatenate(
             [dy_ref[pl.ds(start, sub), :],
-             jax.lax.select(last, dy_tail, _eight(dy_ref, after))],
+             jax.lax.select(last, dy_tail, eight_rows(dy_ref, after))],
             axis=0)
         if masked:
-            seen = tile * rows + start - _SUBLANES + at < length
-            ext, dy = _unless(seen, ext), _unless(seen[_SUBLANES:], dy)
+            seen = tile * rows + start - SUBLANES + at < length
+            ext, dy = unless(seen, ext), unless(seen[SUBLANES:], dy)
         du, parts = _prep_cotangents(ext, dy, taps, scale, eps)
         du_ref[pl.ds(start, sub), :] = du.astype(du_ref.dtype)
         return [s + part for s, part in zip(sums, parts)]
@@ -1414,7 +1374,7 @@ def _prep_walk(length: int, pad: int, rows):
     block reaches past it."""
     padded = length + pad
     rows = min(rows or PREP_ROWS, padded)
-    if padded % _SUBLANES or rows % _SUBLANES:
+    if padded % SUBLANES or rows % SUBLANES:
         raise ValueError(f"qkv_prep: {padded} positions in tiles of "
                          f"{rows} are not whole sublanes")
     tiles = pl.cdiv(padded, rows)
@@ -1430,7 +1390,7 @@ def _prep_specs(section, rows: int, length: int, padded: int, taps: int):
     head-major (B, H, T, ·) block of q, k or v with ITS next 8 rows,
     the taps' (J, width) block, and a section's own taps' block."""
     width, first, _, per_head, _ = section
-    eighth = rows // _SUBLANES
+    eighth = rows // SUBLANES
 
     def head(n):
         return (n, 0) if per_head == 1 else (
@@ -1438,23 +1398,23 @@ def _prep_specs(section, rows: int, length: int, padded: int, taps: int):
 
     def after(i, positions):    # held inside the array; masked past it
         return jnp.minimum((i + 1) * eighth,
-                           pl.cdiv(positions, _SUBLANES) - 1)
+                           pl.cdiv(positions, SUBLANES) - 1)
 
     return dict(
         u=pl.BlockSpec((None, rows, width),
                        lambda n, b, i: (b, i, first + n)),
         before=pl.BlockSpec(
-            (None, _SUBLANES, width),
+            (None, SUBLANES, width),
             lambda n, b, i: (b, jnp.maximum(i * eighth - 1, 0),
                              first + n)),
         after=pl.BlockSpec(
-            (None, _SUBLANES, width),
+            (None, SUBLANES, width),
             lambda n, b, i: (b, after(i, length), first + n)),
         heads=pl.BlockSpec(
             (None, None, rows, width),
             lambda n, b, i: (b, head(n)[0], i, head(n)[1])),
         heads_after=pl.BlockSpec(
-            (None, None, _SUBLANES, width),
+            (None, None, SUBLANES, width),
             lambda n, b, i: (b, head(n)[0], after(i, padded),
                              head(n)[1])),
         taps=pl.BlockSpec((taps, width), lambda n, b, i: (0, first + n)),
@@ -1562,7 +1522,7 @@ def qkv_prep(u, taps, heads: int, dk: int, dv: int, eps: float,
     :func:`prep_legal` head sizes."""
     if not prep_legal(dk, dv):
         raise ValueError(f"qkv_prep: heads of {dk} x {dv} are not whole "
-                         f"{_LANES}-lane tiles")
+                         f"{LANES}-lane tiles")
     return _qkv_prep(u, taps, int(heads), int(dk), int(dv), float(eps),
                      int(pad), rows, bool(interpret))
 
