@@ -158,8 +158,11 @@ def znicz_arm(gmm_tiles=None, gmm_t_tiles=None, tgmm_tiles=None,
                                     tiles=gmm_t_tiles, sub=sub)
 
     def drhs(lhs, grad, sizes):
+        # the slabs; the kernel sums their squares beside them (PR 44)
+        # whether or not anyone reads the sum, so the call is timed
+        # as the step runs it
         return pallas_gmm.znicz_tgmm(lhs, grad, sizes, tiles=tgmm_tiles,
-                                     sub=sub)
+                                     sub=sub)[0]
 
     return {"fwd": fwd, "dlhs": dlhs, "drhs": drhs}
 

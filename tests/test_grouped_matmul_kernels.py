@@ -4,8 +4,9 @@ reference on the bf16-rounded operands — forward, row gradient
 (``transpose_rhs``), weight gradient — over the group layouts that
 exercise each branch of a visit (inside one group, straddling a
 boundary, an empty group, rows past the last group); the bf16 store;
-``jax.grad`` through ``grouped_matmul``; the tile rule at the two
-cells' shapes; the visited / real row counts."""
+``jax.grad`` through ``grouped_matmul``; the weight gradient's sum of
+squares beside it (PR 44) and its way out of the pullback; the tile
+rule at the two cells' shapes; the visited / real row counts."""
 
 import numpy as np
 import pytest
@@ -89,8 +90,8 @@ def test_gmm_against_the_per_group_reference(layout, transpose_rhs):
 def test_tgmm_against_the_per_group_reference(layout):
     sizes = LAYOUTS[layout]
     lhs, _, grad = operands()
-    got = pallas_gmm.znicz_tgmm(lhs, grad, jnp.asarray(sizes, jnp.int32),
-                                tiles=(TM, K, N), interpret=True)
+    got, _ = pallas_gmm.znicz_tgmm(lhs, grad, jnp.asarray(sizes, jnp.int32),
+                                   tiles=(TM, K, N), interpret=True)
     assert got.dtype == jnp.float32 and got.shape == (E, K, N)
     np.testing.assert_allclose(got, slabs_of_rows(lhs, grad, sizes),
                                rtol=1e-5, atol=1e-5)
@@ -100,10 +101,91 @@ def test_tgmm_against_the_per_group_reference(layout):
     # tiles of two and of four parts: a straddling tile is computed
     # part by part
     for parts in (2, 4):
-        by_parts = pallas_gmm.znicz_tgmm(
+        by_parts, _ = pallas_gmm.znicz_tgmm(
             lhs, grad, jnp.asarray(sizes, jnp.int32),
             tiles=(parts * TM, K, N), sub=TM, interpret=True)
         np.testing.assert_allclose(by_parts, got, rtol=1e-5, atol=1e-5)
+
+
+#: ``znicz_tgmm`` masks the operand with the narrower block
+#: (``mask_grad`` = tn <= tk): (k, n, tiles) of either orientation, the
+#: second with two column tiles, so two partial results
+ORIENTATIONS = {"lhs_masked": (K, N, (TM, K, N)),
+                "grad_masked": (64, 64, (TM, 64, 32))}
+
+
+@pytest.mark.parametrize("orientation", list(ORIENTATIONS))
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_tgmm_sums_the_squares_of_the_slabs_it_writes(layout,
+                                                      orientation):
+    """The second result against ``jnp.sum(slabs ** 2)`` of the first:
+    groups that straddle row tiles, an empty group (its block is zeroed
+    at its one visit and adds 0), rows past the last group (in no
+    block), no rows at all; row tiles of one, two and four parts."""
+    k, n, (tm, tk, tn) = ORIENTATIONS[orientation]
+    lhs, _, grad = operands(4, k=k, n=n)
+    sizes = jnp.asarray(LAYOUTS[layout], jnp.int32)
+    for parts in (1, 2, 4):
+        slabs, squares = pallas_gmm.znicz_tgmm(
+            lhs, grad, sizes, tiles=(parts * tm, tk, tn), sub=tm,
+            interpret=True)
+        assert squares.dtype == jnp.float32
+        assert squares.shape == (k // tk, n // tn, 1, tn)
+        want = float(jnp.sum(slabs ** 2))
+        assert float(squares.sum()) == pytest.approx(want, rel=1e-5)
+        assert (want > 0) == bool(sum(LAYOUTS[layout]))
+
+
+@pytest.mark.parametrize("orientation", list(ORIENTATIONS))
+@pytest.mark.parametrize("planted", [np.nan, np.inf, -np.inf],
+                         ids=["nan", "inf", "minus_inf"])
+def test_a_non_finite_gradient_row_reads_a_non_finite_sum(planted,
+                                                          orientation):
+    """One element of one ``grad`` row: the slab of the row's group
+    is non-finite, and so is the sum — what the update's guard reads
+    in the slab's place."""
+    k, n, tiles = ORIENTATIONS[orientation]
+    lhs, _, grad = operands(5, k=k, n=n)
+    sizes = jnp.asarray(LAYOUTS["ragged_with_an_empty_group"], jnp.int32)
+    clean = pallas_gmm.znicz_tgmm(lhs, grad, sizes, tiles=tiles,
+                                  interpret=True)[1]
+    assert np.isfinite(float(clean.sum()))
+    row = 12                  # in group 2, on a tile group 0 ends in
+    slabs, squares = pallas_gmm.znicz_tgmm(
+        lhs, grad.at[row, 3].set(planted), sizes, tiles=tiles,
+        interpret=True)
+    assert not np.isfinite(np.asarray(slabs[2])).all()
+    assert not np.isfinite(float(squares.sum()))
+
+
+@pytest.mark.parametrize("kernel", [True, False],
+                         ids=["kernels_interpreted", "ragged_dot"])
+def test_the_tap_of_grouped_matmul_brings_the_sum_out_of_the_pullback(
+        kernel):
+    """``grouped_matmul``'s ``tap`` enters no result; on the kernel
+    path the pullback returns Σ (weight gradient)² in its place, on the
+    ``ragged_dot`` path 0 — and the gradients are the untapped
+    call's."""
+    sizes = jnp.asarray(LAYOUTS["rows_past_the_last_group"], jnp.int32)
+    lhs, rhs, grad = (a.astype(jnp.float32) for a in operands(6))
+    tap = jnp.zeros((), jnp.float32)
+    out, pullback = jax.vjp(
+        lambda lhs, rhs, tap: grouped_matmul(lhs, rhs, sizes, kernel,
+                                             kernel, tap=tap),
+        lhs, rhs, tap)
+    plain, plain_pullback = jax.vjp(
+        lambda lhs, rhs: grouped_matmul(lhs, rhs, sizes, kernel, kernel),
+        lhs, rhs)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(plain))
+    d_lhs, d_rhs, tapped = pullback(grad)
+    for got, want in zip((d_lhs, d_rhs), plain_pullback(grad)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert tapped.shape == () and tapped.dtype == jnp.float32
+    if kernel:
+        assert float(tapped) == pytest.approx(
+            float(jnp.sum(d_rhs ** 2)), rel=1e-5)
+    else:
+        assert float(tapped) == 0.0
 
 
 @pytest.mark.parametrize("kernel", ["gmm", "gmm_t", "tgmm"])
@@ -114,8 +196,8 @@ def test_two_tiles_along_the_contraction_and_the_columns(kernel):
     lhs, rhs, grad = operands(1, k=64, n=64)
     group_sizes = jnp.asarray(sizes, jnp.int32)
     if kernel == "tgmm":
-        got = pallas_gmm.znicz_tgmm(lhs, grad, group_sizes,
-                                    tiles=(TM, 32, 32), interpret=True)
+        got, _ = pallas_gmm.znicz_tgmm(lhs, grad, group_sizes,
+                                       tiles=(TM, 32, 32), interpret=True)
         want = slabs_of_rows(lhs, grad, sizes)
     else:
         trans = kernel == "gmm_t"

@@ -2,7 +2,9 @@
 (forward, every gradient, the momentum update), droplessness under a
 forced router, top-k without renormalising, the two auxiliary losses,
 the Pallas grouped matmul interpreted, the device-kept routing totals,
-and the three training drivers agreeing on an OLMoE-shaped chain."""
+the anomaly guard reading the kernels' Σ g² of a slab's gradient in the
+slab's place (PR 44), and the three training drivers agreeing on an
+OLMoE-shaped chain."""
 
 import numpy as np
 import pytest
@@ -25,7 +27,8 @@ PARAMS = ("weights", "weights_gate", "weights_up", "weights_down",
           "gain_norm")
 
 
-def build(device, x, params=None, lr=0.05, moment=0.9, **options):
+def build(device, x, params=None, lr=0.05, moment=0.9, guard=False,
+          **options):
     prng.seed_all(5)
     wf = DummyWorkflow()
     src = DummyUnit(wf, output=Vector(np.asarray(x), name="x"))
@@ -45,6 +48,10 @@ def build(device, x, params=None, lr=0.05, moment=0.9, **options):
     gd_u.link_attrs(fwd, "input", "output", "weights", "bias")
     gd_u.err_output = Vector(np.zeros(np.shape(x), np.float32),
                              name="err")
+    if guard:       # the anomaly guard's running flags, as linked
+        gd_u.anomaly_flag = Vector(np.ones(2, np.float32),
+                                   name="step_flags")
+        gd_u.anomaly_flag.initialize(device)
     gd_u.initialize(device=device)
     return fwd, gd_u
 
@@ -238,6 +245,123 @@ def test_oracle_gradients_match_finite_differences():
 
 
 # ----------------------------------------------------------------------
+# the guard's Σ g² of a slab's gradient, from the kernel that made it
+# ----------------------------------------------------------------------
+SLABS = ("weights_gate", "weights_up", "weights_down")
+HELD = {"every_expert_held": None, "four_of_eight_held": (1, 3, 4, 6)}
+
+
+def kernels_interpreted() -> None:
+    root.common.engine.pallas_interpret = True
+    root.common.engine.moe_grouped_matmul = True
+
+
+def state_of(fwd, gd_u) -> dict:
+    """Every parameter, its momentum and the guard's flags, read
+    back."""
+    out = params_of(fwd)
+    vecs = {"acc_weights": gd_u.accumulated_gradient_weights,
+            **{f"acc_{attr}": acc
+               for attr, _, acc in gd_u._extra_pairs()},
+            "flags": gd_u.anomaly_flag}
+    for key, vec in vecs.items():
+        vec.map_read()
+        out[key] = np.array(vec.mem, np.float32)
+    return out
+
+
+def guard_sums(fwd) -> float:
+    return obs_metrics.moe_guard_sum(fwd.name, "from_kernel").value
+
+
+def sums_recomputed(monkeypatch) -> None:
+    """The update as it was before PR 44: whatever sum is handed over
+    is dropped, so the guard makes its own pass over the gradient."""
+    handed = moe.GDMoE._apply_weights_xla
+    monkeypatch.setattr(
+        moe.GDMoE, "_apply_weights_xla",
+        lambda self, grad, vec=None, acc_vec=None, grad_sq=None:
+        handed(self, grad, vec=vec, acc_vec=acc_vec))
+
+
+@pytest.mark.parametrize("held", list(HELD))
+def test_a_finite_step_is_bitwise_the_step_with_the_sum_recomputed(
+        held, monkeypatch):
+    """Guard linked, kernels interpreted: two momentum steps with the
+    guard reading the kernels' sums leave every parameter, every
+    momentum and the flags bitwise what they are when the guard sums
+    the slabs itself; the gauge says which of the two ran."""
+    kernels_interpreted()
+    x, err = _data(7)
+    got = []
+    for recompute in (False, True):
+        if recompute:
+            sums_recomputed(monkeypatch)
+        fwd, gd_u = build(XLADevice(), x, guard=True, held=HELD[held])
+        fwd.name = f"moe_sums_{held}_{recompute}"
+        assert fwd._gmm_kernel
+        for _ in range(2):
+            step(fwd, gd_u, err)
+        assert guard_sums(fwd) == (0 if recompute else 3)
+        got.append(state_of(fwd, gd_u))
+    assert got[0]["flags"][0] == 1.0
+    assert set(got[0]) == set(got[1]) and len(got[0]) == 11
+    for key, want in got[1].items():
+        np.testing.assert_array_equal(got[0][key], want, err_msg=key)
+    drawn = params_of(build(XLADevice(), x, held=HELD[held])[0])
+    for attr in SLABS:            # and every slab MOVED
+        assert np.abs(got[0][attr] - drawn[attr]).max() > 0, attr
+
+
+@pytest.mark.parametrize("recompute", [False, True],
+                         ids=["sum_from_the_kernel", "sum_recomputed"])
+@pytest.mark.parametrize("held", list(HELD))
+def test_an_inf_in_one_experts_gradient_skips_the_step(
+        held, recompute, monkeypatch):
+    """An inf planted in ONE row of what ``znicz_tgmm`` takes for the
+    down slabs' gradient — no other gradient of the step sees it: the
+    slabs and their momentum stay bitwise untouched, the guard's flag
+    goes down (and holds back ``gain_norm``, updated after it); the
+    tensors updated before it moved.  The same with the guard summing
+    the slab itself."""
+    from znicz_tpu.ops import pallas_gmm
+    kernels_interpreted()
+    if recompute:
+        sums_recomputed(monkeypatch)
+    x, err = _data(8)
+    fwd, gd_u = build(XLADevice(), x, guard=True, held=HELD[held],
+                      width=20)    # a shape no other test has traced
+    fwd.name = f"moe_inf_{held}_{recompute}"
+    step(fwd, gd_u, err)
+    before = state_of(fwd, gd_u)
+    assert before["flags"][0] == 1.0
+    real_tgmm = pallas_gmm.znicz_tgmm
+
+    def planted(lhs, grad, group_sizes, **kwargs):
+        if grad.shape[1] == D:            # the down slabs' call
+            grad = grad.at[0, 5].set(np.inf)   # a row of the first group
+        return real_tgmm(lhs, grad, group_sizes, **kwargs)
+
+    monkeypatch.setattr(pallas_gmm, "znicz_tgmm", planted)
+    moe.grouped_matmul.clear_cache()
+    try:
+        step(fwd, gd_u, err)
+    finally:
+        monkeypatch.setattr(pallas_gmm, "znicz_tgmm", real_tgmm)
+        moe.grouped_matmul.clear_cache()
+    after = state_of(fwd, gd_u)
+    assert guard_sums(fwd) == (0 if recompute else 3)
+    assert after["flags"][0] == 0.0
+    for key in ("weights_down", "acc_weights_down", "gain_norm",
+                "acc_gain_norm"):
+        np.testing.assert_array_equal(after[key], before[key],
+                                      err_msg=key)
+    for key in ("weights", "weights_gate", "weights_up"):
+        assert np.abs(after[key] - before[key]).max() > 0, key
+        assert np.isfinite(after[key]).all(), key
+
+
+# ----------------------------------------------------------------------
 # the chain: run, run_chunked and run_accumulated agree
 # ----------------------------------------------------------------------
 VOCAB, SEQ = 29, 8
@@ -306,6 +430,50 @@ def test_run_accumulated_agrees_with_run_at_the_fused_batch():
     want, _ = train("run", 8, aux=0.0)
     root.common.engine.grad_accum = 2
     got, _ = train("accumulated", 4, aux=0.0)
+    for key in want:
+        np.testing.assert_allclose(got[key], want[key], rtol=2e-4,
+                                   atol=2e-6, err_msg=key)
+
+
+def _slab_sized_reductions(wf) -> list:
+    """``reduce_sum`` equations of the workflow's step program, nested
+    ones included, whose operand has the shape of an expert slab."""
+    import jax
+    from tests.test_integrity import _all_eqns
+    region = wf._region_unit.region
+    for vec in region._vectors:
+        vec.unmap()
+    closed = jax.make_jaxpr(region.build_callable(
+        tuple(bool(u.gate_skip) for u in region.units)))(
+            *[v.devmem for v in region._vectors])
+    slabs = {(E, D, F), (E, F, D)}
+    return [eqn for eqn in _all_eqns(closed.jaxpr)
+            if eqn.primitive.name == "reduce_sum"
+            and eqn.invars[0].aval.shape in slabs]
+
+
+@pytest.mark.parametrize("kernel", [True, False],
+                         ids=["kernels_interpreted", "ragged_dot"])
+def test_accumulation_keeps_its_pass_and_the_gauge_says_so(kernel):
+    """The guard is linked by default.  Under ``run`` with the kernels
+    the three slabs' sums come from ``znicz_tgmm`` and the traced step
+    holds NO reduction over a slab-shaped operand; under
+    ``run_accumulated`` the update applies a mean of microbatches, not
+    what the kernel wrote, so the gauge reads 0 and the drivers still
+    agree; on the ``ragged_dot`` path no sum is handed over and the
+    step holds the three passes it held before."""
+    if kernel:
+        kernels_interpreted()
+    want, wf = train("run", 8, aux=0.0)
+    layer = next(u for u in wf.forwards if isinstance(u, moe.MoE))
+    assert layer._gmm_kernel == kernel
+    assert guard_sums(layer) == (3 if kernel else 0)
+    assert len(_slab_sized_reductions(wf)) == (0 if kernel else 3)
+    root.common.engine.grad_accum = 2
+    got, wf = train("accumulated", 4, aux=0.0)
+    layer = next(u for u in wf.forwards if isinstance(u, moe.MoE))
+    assert layer._gmm_kernel == kernel
+    assert guard_sums(layer) == 0
     for key in want:
         np.testing.assert_allclose(got[key], want[key], rtol=2e-4,
                                    atol=2e-6, err_msg=key)

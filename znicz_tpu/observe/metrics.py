@@ -604,6 +604,23 @@ def moe_gmm_rows(unit: str, stat: str) -> Gauge:
         labels=("unit", "stat")).labels(unit=unit, stat=stat)
 
 
+def moe_guard_sum(unit: str, stat: str) -> Gauge:
+    """Where the anomaly guard's Σ g² of a ``MoE`` layer's gradients
+    came from (``stat`` = ``from_kernel``: of the layer's parameter
+    tensors, those whose sum ``znicz_tgmm`` made beside the gradient
+    and the update read instead of the tensor — 3, the expert slabs,
+    where the kernels engage and the gradient reaches the update as
+    the kernel wrote it; 0 on the ``ragged_dot`` path, under
+    accumulation, with no guard linked).  Static per program, set when
+    the layer's backward is traced."""
+    return REGISTRY.gauge(
+        "znicz_moe_guard_sum",
+        "Parameter tensors of a MoE layer whose gradient's sum of "
+        "squares, which the anomaly guard reads, came from the kernel "
+        "that made the gradient",
+        labels=("unit", "stat")).labels(unit=unit, stat=stat)
+
+
 def delta_scan(unit: str, stat: str) -> Gauge:
     """A ``GatedDeltaNet`` unit's chunked state scan (``stat`` =
     ``chunk``: positions per chunk; ``chunks``: ⌈T / chunk⌉, the length

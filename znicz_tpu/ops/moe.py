@@ -30,7 +30,19 @@ JAX's library kernel, the path from PR 25 to PR 33, and beside
 elsewhere; the gate is ``engine.moe_grouped_matmul`` ("auto", like the
 flash kernels').  What the grids did is counted beside the routing
 totals (rows the visits cover over rows that are real: the gauge
-``znicz_moe_gmm_rows``).  Both
+``znicz_moe_gmm_rows``).  ``znicz_tgmm`` has a second result: Σ g² of
+the slabs it writes, summed as each finished block lay in VMEM — what
+the update's anomaly guard otherwise reads the whole (E, K, N) f32
+gradient from HBM once more for (PERF.md §6, PR 44).  The number
+leaves the layer's ONE pullback as the "cotangent" of a zero scalar
+per slab that ``xla_forward`` takes (``taps``) and adds nowhere: a
+TAP, not a derivative — it carries no gradient of anything
+(:func:`_gmm_kernels`).  ``GDMoE`` hands it to the update with the
+slab's gradient, and the update uses it under its one rule
+(``GradientDescentBase._update_param_xla``: the gradient it applies
+IS the tensor the sum was made of); the gauge
+``znicz_moe_guard_sum{stat="from_kernel"}`` says for how many of the
+layer's tensors it did (3, or 0: ``ragged_dot``, accumulation).  Both
 permutations are GATHERS in both directions (:func:`_dispatch`,
 :func:`_unpermute`: a permutation's adjoint is its inverse), so no
 scatter-add runs in the step.
@@ -134,18 +146,25 @@ _BIAS = slice(-5, -2)
 HELD_SLACK = 4
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _gmm_kernels(lhs, rhs, group_sizes, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _gmm_kernels(lhs, rhs, group_sizes, tap, interpret):
     """The grouped matmul through ``ops/pallas_gmm.py``; the groups may
     end before the rows do (one chip's share of the pairs, under a
     static capacity): the kernels' work follows the groups, and the
-    rows past them come back zero, forward and backward."""
+    rows past them come back zero, forward and backward.
+
+    ``tap`` is a scalar the forward adds nowhere.  It is a TAP, not an
+    operand: what the backward returns as its "cotangent" is no
+    derivative of anything but Σ d_rhs² — the sum of the squares of
+    the weight gradient this very backward returns, as ``znicz_tgmm``
+    summed them while the slabs lay in VMEM.  A pullback can hand out
+    nothing but cotangents, so that is how the number leaves it."""
     return pallas_gmm.znicz_gmm(lhs, rhs.astype(lhs.dtype), group_sizes,
                                 interpret=interpret)
 
 
-def _gmm_kernels_fwd(lhs, rhs, group_sizes, interpret):
-    return (_gmm_kernels(lhs, rhs, group_sizes, interpret),
+def _gmm_kernels_fwd(lhs, rhs, group_sizes, tap, interpret):
+    return (_gmm_kernels(lhs, rhs, group_sizes, tap, interpret),
             (lhs, rhs, group_sizes))
 
 
@@ -157,9 +176,9 @@ def _gmm_kernels_bwd(interpret, residual, grad):
     d_lhs = pallas_gmm.znicz_gmm(grad, rhs.astype(lhs.dtype), group_sizes,
                                  transpose_rhs=True, out_dtype=lhs.dtype,
                                  interpret=interpret)
-    d_rhs = pallas_gmm.znicz_tgmm(lhs, grad, group_sizes,
-                                  interpret=interpret)
-    return d_lhs, d_rhs.astype(rhs.dtype), None
+    d_rhs, squares = pallas_gmm.znicz_tgmm(lhs, grad, group_sizes,
+                                           interpret=interpret)
+    return d_lhs, d_rhs.astype(rhs.dtype), None, squares.sum()
 
 
 _gmm_kernels.defvjp(_gmm_kernels_fwd, _gmm_kernels_bwd)
@@ -167,7 +186,7 @@ _gmm_kernels.defvjp(_gmm_kernels_fwd, _gmm_kernels_bwd)
 
 @functools.partial(jax.jit, static_argnums=(3, 4))
 def grouped_matmul(lhs, rhs, group_sizes, kernel: bool = False,
-                   interpret: bool = False):
+                   interpret: bool = False, tap=None):
     """(M, K) rows in E contiguous groups × (E, K, N) f32 slabs →
     (M, N) f32: row r of group e is multiplied by ``rhs[e]``, the slabs
     cast to the rows' dtype on the way in.  ``kernel`` runs the repo's
@@ -177,9 +196,17 @@ def grouped_matmul(lhs, rhs, group_sizes, kernel: bool = False,
     call sites of a layer, and every layer, lower it once (PERF.md §6,
     PR 24: lowering is a set-up cost the compile cache does not hide).
     Rows may follow the last group; they come back zero on either
-    path."""
+    path.
+
+    ``tap``, a zero scalar that enters no result: on the kernel path
+    the pullback's "cotangent" of it is Σ (weight gradient)², made
+    where the gradient is made (:func:`_gmm_kernels`: a tap, not a
+    derivative); on the ``ragged_dot`` path it is 0 and means
+    nothing — only a caller that knows which path ran may read it."""
     if kernel:
-        return _gmm_kernels(lhs, rhs, group_sizes, interpret)
+        return _gmm_kernels(
+            lhs, rhs, group_sizes,
+            jnp.zeros((), jnp.float32) if tap is None else tap, interpret)
     return jax.lax.ragged_dot(lhs, rhs.astype(lhs.dtype), group_sizes,
                               preferred_element_type=jnp.float32)
 
@@ -256,6 +283,10 @@ class MoE(Forward):
     #: the always-on shared expert's three matrices
     SHARED = ("weights_shared_gate", "weights_shared_up",
               "weights_shared_down")
+    #: the expert slabs, in the order of ``xla_forward``'s ``taps``: on
+    #: the kernel path the pullback hands out Σ g² of each one's
+    #: gradient beside the gradient (``_gmm_kernels``)
+    TAPPED = ("weights_gate", "weights_up", "weights_down")
 
     def __init__(self, workflow, n_experts: int, top_k: int, width: int,
                  norm_topk: bool = False, pre_norm: str | None = None,
@@ -454,14 +485,19 @@ class MoE(Forward):
                 self.weights_gate.devmem, self.weights_up.devmem,
                 self.weights_down.devmem,
                 self.gain_norm.devmem if self.gain_norm else None)
-        if self.shared_width:
-            args += tuple(getattr(self, attr).devmem
-                          for attr in self.SHARED)
-        elif self.select_bias_on:
-            args += (None, None, None)
-        if self.select_bias_on:     # LAST: no cotangent ever reaches it
-            args += (self.select_bias.devmem,)
-        return args
+        # what only some layers have, None where this one has not, the
+        # Nones at the end left out; no cotangent ever reaches
+        # ``select_bias``, and what comes back for ``taps`` is no
+        # cotangent (``_gmm_kernels``)
+        tail = [getattr(self, attr).devmem if self.shared_width else None
+                for attr in self.SHARED]
+        tail.append(self.select_bias.devmem if self.select_bias_on
+                    else None)
+        tail.append((jnp.zeros((), jnp.float32),) * len(self.TAPPED)
+                    if getattr(self, "_gmm_kernel", False) else None)
+        while tail and tail[-1] is None:
+            tail.pop()
+        return args + tuple(tail)
 
     def _selection(self, xp, p, bias):
         """The numbers the top k are taken by, (N, E): the scores, plus
@@ -539,7 +575,7 @@ class MoE(Forward):
             top_p = top_p * self.routed_scale
         return top_p
 
-    def _held_experts(self, m, top_p, top_e, w_g, w_u, w_d):
+    def _held_experts(self, m, top_p, top_e, w_g, w_u, w_d, taps):
         """``(f, local counts, (rows here, rows over))``: the routed
         sum of the pairs whose expert lives here (module docstring)."""
         n, d = m.shape
@@ -565,10 +601,10 @@ class MoE(Forward):
                 getattr(self, "_gmm_interpret", False))
         rows = jnp.where(live[:, None], jnp.take(m, token, axis=0),
                          0.0).astype(dt)
-        gate = grouped_matmul(rows, w_g, sizes, *path)
-        up = grouped_matmul(rows, w_u, sizes, *path)
+        gate = grouped_matmul(rows, w_g, sizes, *path, tap=taps[0])
+        up = grouped_matmul(rows, w_u, sizes, *path, tap=taps[1])
         hidden = (_silu(jnp, gate) * up).astype(dt)
-        out = grouped_matmul(hidden, w_d, sizes, *path)
+        out = grouped_matmul(hidden, w_d, sizes, *path, tap=taps[2])
         weight = jnp.where(live, jnp.take(top_p.reshape(n * k), pair),
                            0.0)
         f = jnp.zeros((n, d), jnp.float32).at[token].add(
@@ -579,10 +615,15 @@ class MoE(Forward):
         return f, sizes, (here, over)
 
     def xla_forward(self, x, w_r, w_g, w_u, w_d, g_norm=None,
-                    ws_g=None, ws_u=None, ws_d=None, select_bias=None):
+                    ws_g=None, ws_u=None, ws_d=None, select_bias=None,
+                    taps=None):
         """``((y, (lb, z)), (counts, logits, top_e))``: the output and
         the two auxiliary losses (differentiable); rows per expert, the
-        router's logits and its choice (not)."""
+        router's logits and its choice (not).  ``taps``: a zero scalar
+        for each slab of ``TAPPED``, which enters nothing — the
+        pullback returns Σ g² of that slab's gradient in its place
+        (``grouped_matmul``; the kernel path only)."""
+        taps = taps or (None,) * len(self.TAPPED)
         b, t, d = x.shape
         n, k, e = b * t, self.top_k, self.n_experts
         x32 = x.astype(jnp.float32)
@@ -604,7 +645,7 @@ class MoE(Forward):
         if self.held is not None:
             sizes = rows_per_expert()
             f, local, here = self._held_experts(m, top_p, top_e, w_g,
-                                                w_u, w_d)
+                                                w_u, w_d, taps)
             y = f.reshape(b, t, d)
             extra = jnp.stack([here[0], jnp.int32(n * k), here[1]])
             counts = jax.lax.stop_gradient(
@@ -617,10 +658,10 @@ class MoE(Forward):
             path = (getattr(self, "_gmm_kernel", False),
                     getattr(self, "_gmm_interpret", False))
             rows = _dispatch(m, order, inverse, dt)
-            gate = grouped_matmul(rows, w_g, sizes, *path)
-            up = grouped_matmul(rows, w_u, sizes, *path)
+            gate = grouped_matmul(rows, w_g, sizes, *path, tap=taps[0])
+            up = grouped_matmul(rows, w_u, sizes, *path, tap=taps[1])
             hidden = (_silu(jnp, gate) * up).astype(dt)
-            out = grouped_matmul(hidden, w_d, sizes, *path)
+            out = grouped_matmul(hidden, w_d, sizes, *path, tap=taps[2])
             out = _unpermute(out, inverse, order).reshape(n, k, d)
             y = (out * top_p[..., None]).sum(axis=1).reshape(b, t, d)
             counts = None
@@ -868,15 +909,26 @@ class GDMoE(GradientDescentBase):
         return vjp
 
     def xla_run(self) -> None:
+        fwd = self.forward_unit
         gx, g_own, *g_extra = self._pullback()(self._cotangent(
             jnp, self.err_output.devmem.astype(jnp.float32)))
         if self.need_err_input:
             self.err_input.devmem = gx
         self._apply_weights_xla(g_own)
         grads = dict(zip(self.EXTRA, g_extra))
+        # Σ g² of a slab's gradient where the kernels made it: what the
+        # pullback returns for the forward's ``taps``, its last argument
+        tapped = getattr(fwd, "_gmm_kernel", False)
+        sums = dict(zip(fwd.TAPPED, g_extra[-1])) if tapped else {}
+        taken = 0
         for attr, param, acc in self._extra_pairs():
-            self._apply_weights_xla(grads[attr], vec=param, acc_vec=acc)
-        if getattr(self.forward_unit, "select_bias_on", False):
+            taken += self._apply_weights_xla(
+                grads[attr], vec=param, acc_vec=acc,
+                grad_sq=sums.get(attr))
+        if isinstance(fwd, MoE):
+            from znicz_tpu.observe import metrics as obs_metrics
+            obs_metrics.moe_guard_sum(fwd.name, "from_kernel").set(taken)
+        if getattr(fwd, "select_bias_on", False):
             self._move_select_bias(jnp)
 
     def _move_select_bias(self, xp) -> None:

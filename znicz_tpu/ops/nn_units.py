@@ -460,17 +460,20 @@ class GradientDescentBase(AcceleratedUnit, metaclass=MatchingObject):
             reg = reg + 0.5 * l1 * xp.sign(weights)
         return grad + decay * reg
 
-    def _clipped(self, xp, grad):
+    def _clipped(self, xp, grad, grad_sq=None):
         """Per-tensor L2 gradient-norm clipping (``gradient_clip``).
         The norm is a full-tensor reduction: under ZeRO-1 it runs on
         the scattered shard (partial sums + one scalar all-reduce),
-        so clipping does not resurrect the full-gradient all-reduce."""
+        so clipping does not resurrect the full-gradient all-reduce.
+        ``grad_sq``: Σ grad², where the caller has it."""
         clip = self.gradient_clip
         if not clip:
             return grad
-        g32 = grad.astype(np.float32) if xp is np \
-            else grad.astype(jnp.float32)
-        norm = xp.sqrt(xp.sum(g32 * g32))
+        if grad_sq is None:
+            g32 = grad.astype(np.float32) if xp is np \
+                else grad.astype(jnp.float32)
+            grad_sq = xp.sum(g32 * g32)
+        norm = xp.sqrt(grad_sq)
         scale = xp.minimum(1.0, clip / xp.maximum(norm, 1e-30))
         return grad * scale
 
@@ -580,12 +583,18 @@ class GradientDescentBase(AcceleratedUnit, metaclass=MatchingObject):
             b -= lr * g
         self._fold_fingerprint(np, 0, b)
 
-    def _apply_weights_xla(self, grad_w, vec=None, acc_vec=None) -> None:
+    def _apply_weights_xla(self, grad_w, vec=None, acc_vec=None,
+                           grad_sq=None) -> bool:
+        """``grad_sq``: Σ grad_w² where whoever made the gradient has
+        it (a kernel that summed the squares as it stored them); the
+        return says whether the guard took it
+        (:meth:`_update_param_xla`)."""
         vec = vec if vec is not None else self.weights
         acc_vec = acc_vec if acc_vec is not None \
             else self.accumulated_gradient_weights
-        self._apply_param_xla(grad_w, vec, acc_vec, self.weights_decay,
-                              self._lr(xla=True), self.gradient_moment)
+        return self._apply_param_xla(
+            grad_w, vec, acc_vec, self.weights_decay, self._lr(xla=True),
+            self.gradient_moment, grad_sq)
 
     def _apply_bias_xla(self, grad_b, vec=None, acc_vec=None) -> None:
         vec = vec if vec is not None else self.bias
@@ -600,7 +609,7 @@ class GradientDescentBase(AcceleratedUnit, metaclass=MatchingObject):
 
     @jax.named_scope("update")
     def _apply_param_xla(self, grad, vec: Vector, acc_vec, decay: float,
-                         lr, moment: float) -> None:
+                         lr, moment: float, grad_sq=None) -> bool:
         """One parameter tensor's update on the XLA path, traced under
         the scope ``update`` (either form; the fingerprint folds inside
         it under ``fingerprint``): ``observe.op_scopes()`` reads the
@@ -630,10 +639,15 @@ class GradientDescentBase(AcceleratedUnit, metaclass=MatchingObject):
         :meth:`_whole_gradient` is the one home of both, and this
         method passes on only the whole one, ONCE per parameter and
         optimizer step (:meth:`_update_param_xla`).
+
+        ``grad_sq`` is Σ grad² of ``grad`` as it was handed in, from
+        whoever made it, or None; :meth:`_update_param_xla` holds the
+        one rule for it and returns whether the guard read it.
         """
-        grad = self._whole_gradient(grad, vec)
-        if grad is not None:
-            self._update_param_xla(grad, vec, acc_vec, decay, lr, moment)
+        whole = self._whole_gradient(grad, vec)
+        return whole is not None and self._update_param_xla(
+            whole, vec, acc_vec, decay, lr, moment,
+            None if grad_sq is None else (grad, grad_sq))
 
     def _whole_gradient(self, grad, vec: Vector):
         """The ONE home of "this gradient is not whole yet": returns the
@@ -706,9 +720,22 @@ class GradientDescentBase(AcceleratedUnit, metaclass=MatchingObject):
         return grad
 
     def _update_param_xla(self, grad, vec: Vector, acc_vec, decay: float,
-                          lr, moment: float) -> None:
+                          lr, moment: float, known=None) -> bool:
         """The update proper, from a WHOLE gradient (see
-        :meth:`_apply_param_xla`, whose scope it traces under)."""
+        :meth:`_apply_param_xla`, whose scope it traces under).
+
+        ``known`` = ``(tensor, Σ tensor²)`` from whoever made a
+        gradient, or None.  ONE rule decides whether the guard's (and
+        the clip's) Σ g² is that number or a pass over ``grad``: it is
+        the number exactly where the gradient the update applies IS
+        that tensor — the same traced value, which nothing between its
+        maker and here has replaced.  Every step that changes a
+        gradient makes a new value, so the rule needs no list of them:
+        a looped span's passes and an accumulated step's microbatches
+        (:meth:`_whole_gradient` returns their sum or mean), the mean
+        over a mapped axis, the fp8 round trip and the seeded SDC flip
+        below all fail it, and the pass over ``grad`` stays.  Returns
+        whether the guard read ``known``'s number."""
         from znicz_tpu.parallel.axis import current_data_axis
         grad = maybe_pmean(grad)
         if getattr(self, "_fp8_matmul", False):
@@ -738,9 +765,14 @@ class GradientDescentBase(AcceleratedUnit, metaclass=MatchingObject):
         self._fold_fingerprint(jnp, 1, grad)
         guard = self.anomaly_flag \
             if self.anomaly_flag is not None and self.anomaly_flag else None
+        grad_sq = known[1] if known is not None and known[0] is grad \
+            else None
         if guard is not None:
-            g32 = grad.astype(jnp.float32)
-            own_ok = jnp.isfinite(jnp.sum(g32 * g32))
+            if grad_sq is None:
+                g32 = grad.astype(jnp.float32)
+                own_ok = jnp.isfinite(jnp.sum(g32 * g32))
+            else:
+                own_ok = jnp.isfinite(grad_sq)
             flags = guard.devmem
             step_ok = (flags[0] > 0.5) & own_ok
             guard.devmem = flags.at[0].set(
@@ -753,7 +785,8 @@ class GradientDescentBase(AcceleratedUnit, metaclass=MatchingObject):
             self._apply_param_zero1(grad, vec, acc_vec, decay, lr, moment)
         else:
             w = vec.devmem
-            g = self._regularized(jnp, self._clipped(jnp, grad), w, decay)
+            g = self._regularized(
+                jnp, self._clipped(jnp, grad, grad_sq), w, decay)
             if moment:
                 # momentum math in f32 regardless of the accumulator's
                 # STORAGE dtype (opt_state_dtype); the setter rounds
@@ -774,6 +807,7 @@ class GradientDescentBase(AcceleratedUnit, metaclass=MatchingObject):
         # claimed checksum, which is what the guard's sticky
         # self-check detects
         self._fold_fingerprint(jnp, 0, vec.devmem)
+        return guard is not None and grad_sq is not None
 
     def _apply_param_zero1(self, grad, vec: Vector, acc_vec,
                            decay: float, lr, moment: float) -> None:

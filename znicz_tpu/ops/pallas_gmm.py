@@ -8,7 +8,8 @@ traced value); group e multiplies the slab ``rhs[e]``:
                 ``transpose_rhs`` the row gradient (the slab's other
                 dim contracted, same kernel).
 ``znicz_tgmm``  rowsᵀ × rows → (E, K, N): the weight gradient, one f32
-                slab a group.
+                slab a group — and, beside it, Σ slab² in a few
+                partial sums (what the update's anomaly guard reads).
 
 Both walk the same list of VISITS (:func:`group_visits`): group by
 group, the row tiles a group touches, first to last.  What that buys:
@@ -30,7 +31,12 @@ group, the row tiles a group touches, first to last.  What that buys:
   128-row tile's overwork.
 * ``znicz_tgmm`` reads both operands as rows, where they lie, and
   contracts their row dim; its f32 result block stays resident over a
-  group's visits and is written once per group.
+  group's visits and is written once per group.  At a group's LAST
+  visit the finished block is squared and summed down its rows into a
+  second, small result that stays resident over all the visits: the
+  whole result's Σ x² costs a multiply-add an element of a block that
+  is in VMEM anyway, where a reduction outside the kernel reads the
+  (E, K, N) slab from HBM once more (PERF.md §6, PR 44).
 * Rows past the last group (one chip's share of the pairs under a
   static capacity) are a last group of their own with no slab:
   ``znicz_gmm`` writes them zero, ``znicz_tgmm`` never visits them.
@@ -335,9 +341,14 @@ def znicz_gmm(lhs, rhs, group_sizes, *, transpose_rhs: bool = False,
 # ----------------------------------------------------------------------
 # rowsᵀ × rows
 # ----------------------------------------------------------------------
-def _tgmm_kernel(offsets, groups, tiles, lhs, grad, out, *, tm: int,
-                 sub: int, mask_grad: bool):
+#: rows of a finished block squared at a time: a register's sublanes
+SQUARE_ROWS = 8
+
+
+def _tgmm_kernel(offsets, groups, tiles, lhs, grad, out, squares, *,
+                 tm: int, sub: int, mask_grad: bool):
     visit = pl.program_id(2)
+    last_visit = pl.num_programs(2) - 1
     group = groups[visit]
     start, end = offsets[group], offsets[group + 1]
     row0 = tiles[visit] * tm
@@ -348,6 +359,10 @@ def _tgmm_kernel(offsets, groups, tiles, lhs, grad, out, *, tm: int,
         visit == 0, groups[jnp.maximum(visit - 1, 0)] != group))
     def _first_of_its_group():
         out[...] = jnp.zeros(out.shape, out.dtype)
+
+    @pl.when(visit == 0)
+    def _first_of_all():
+        squares[...] = jnp.zeros(squares.shape, squares.dtype)
 
     @pl.when(inside)
     def _whole():
@@ -374,13 +389,36 @@ def _tgmm_kernel(offsets, groups, tiles, lhs, grad, out, *, tm: int,
             jnp.logical_not(inside),
             _meet(start, end, row0 + lo, sub)))(functools.partial(part, lo))
 
+    @pl.when(jnp.logical_or(
+        visit == last_visit,
+        groups[jnp.minimum(visit + 1, last_visit)] != group))
+    def _last_of_its_group():
+        # the block is whole (an empty group's: zero): its squares,
+        # summed down the rows, join the other groups' — strip by
+        # strip into a running sum of a few registers: the vector
+        # unit's two operations an element and nothing else (the whole
+        # block squared at once spills every register it fills, 2.5
+        # times the bundles: PERF.md §6, PR 44)
+        rows = out.shape[0]
+        strip = SQUARE_ROWS if rows % SQUARE_ROWS == 0 else rows
+        total = jnp.zeros((strip, out.shape[1]), jnp.float32)
+        for lo in range(0, rows, strip):
+            part = out[lo:lo + strip, :]
+            total += part * part
+        squares[...] += jnp.sum(total, axis=0, keepdims=True)
+
 
 def znicz_tgmm(lhs, grad, group_sizes, *, tiles: tuple | None = None,
                sub: int | None = None, interpret: bool = False):
-    """(M, K) rows and (M, N) rows in the same E groups → (E, K, N)
-    f32: ``lhs[rows of e]ᵀ @ grad[rows of e]``, an empty group's slab
-    zero; rows past the last group belong to none.  ``tiles`` =
-    ``(tm, tk, tn)``, else :func:`tgmm_tiles`; ``tm`` divides M."""
+    """(M, K) rows and (M, N) rows in the same E groups → ``(slabs,
+    squares)``: the (E, K, N) f32 ``lhs[rows of e]ᵀ @ grad[rows of
+    e]``, an empty group's slab zero, rows past the last group
+    belonging to none; and (K / tk, N / tn, 1, tn) f32 partial sums of
+    the slabs' squares, ``squares.sum() == (slabs ** 2).sum()`` up to
+    the order of the additions — non-finite where any element of the
+    slabs is.  A caller that wants no sum ignores a few kilobytes.
+    ``tiles`` = ``(tm, tk, tn)``, else :func:`tgmm_tiles`; ``tm``
+    divides M."""
     m, k = lhs.shape
     n = grad.shape[1]
     n_groups = group_sizes.shape[0]
@@ -402,16 +440,22 @@ def znicz_tgmm(lhs, grad, group_sizes, *, tiles: tuple | None = None,
     def out_index(i, j, v, offsets, groups, tiles_):
         return groups[v], i, j
 
+    def squares_index(i, j, v, offsets, groups, tiles_):
+        return i, j, 0, 0
+
     itemsize = lhs.dtype.itemsize
     return pl.pallas_call(
         functools.partial(_tgmm_kernel, tm=tm, sub=sub,
                           mask_grad=tn <= tk),
-        out_shape=jax.ShapeDtypeStruct((n_groups, k, n), jnp.float32),
+        out_shape=(
+            jax.ShapeDtypeStruct((n_groups, k, n), jnp.float32),
+            jax.ShapeDtypeStruct((k // tk, n // tn, 1, tn), jnp.float32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             in_specs=[pl.BlockSpec((tm, tk), lhs_index),
                       pl.BlockSpec((tm, tn), grad_index)],
-            out_specs=pl.BlockSpec((None, tk, tn), out_index),
+            out_specs=(pl.BlockSpec((None, tk, tn), out_index),
+                       pl.BlockSpec((None, None, 1, tn), squares_index)),
             grid=(k // tk, n // tn, visits)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
