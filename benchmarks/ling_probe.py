@@ -19,7 +19,7 @@ over (4,096, 12,288) f32, against their bytes and against the plain
         the CELL's whole step program compiled for a described v5e
         (≈ 4 minutes: 885 M parameters drawn on the host) — the
         compiler's memory analysis, or its refusal; a compile is not a
-        run
+        run (``--workload lfm2_train_1of2``: another LM cell's step)
 
 On the chip each family is checked in f32 against its ``jax.numpy``
 form at a length that form can hold, then timed in bf16 forward and
@@ -188,8 +188,9 @@ def kda(dot_dtype):
                                           dot_dtype=dot_dtype)
 
 
-def compile_step(t: int) -> int:
-    """The cell's step program at sequence length ``t``, compiled for a
+def compile_step(t: int, workload: str = "ling_train_1of64") -> int:
+    """A cell's step program (this cell's, or another LM cell's of the
+    ``train_lm`` driver) at sequence length ``t``, compiled for a
     described v5e: what the compiler says it needs."""
     import numpy as np
     from benchmarks.ouro_probe import compile_for_described_chip
@@ -201,7 +202,7 @@ def compile_step(t: int) -> int:
     from znicz_tpu.utils import prng
     from znicz_tpu.utils.config import root
 
-    cell = discovery.find_cell("ling_train_1of64")
+    cell = discovery.find_cell(workload)
     config, traffic = cell.config, cell.traffic
     root.common.precision_type = config["precision"]["precision_type"]
     prng.seed_all(0)
@@ -227,7 +228,7 @@ def compile_step(t: int) -> int:
             for flag in ("_interpret", "_gmm_interpret"):
                 if getattr(unit, flag, False):
                     setattr(unit, flag, False)
-        line = {"t": t}
+        line = {"workload": workload, "t": t}
         try:
             line.update(compile_for_described_chip(wf), loads=True)
         except Exception as exc:  # noqa: BLE001 — the refusal is the result
@@ -245,6 +246,8 @@ def main() -> int:
     parser.add_argument("--compile-only", action="store_true")
     parser.add_argument("--compile-step", action="store_true")
     parser.add_argument("--t", type=int, default=4096)
+    parser.add_argument("--workload", default="ling_train_1of64",
+                        help="the cell whose step --compile-step compiles")
     parser.add_argument("--only", choices=("kda", "mla", "prep"),
                         nargs="+", default=("kda", "mla", "prep"))
     parser.add_argument("--rows", type=int, nargs="+",
@@ -253,7 +256,7 @@ def main() -> int:
     args = parser.parse_args()
     if args.compile_step:
         os.environ.setdefault("TPU_LOG_DIR", "disabled")
-        return compile_step(args.t)
+        return compile_step(args.t, args.workload)
     if args.compile_only:
         os.environ.setdefault("TPU_LOG_DIR", "disabled")
         from jax.experimental import topologies

@@ -3,7 +3,8 @@
 forced router, top-k without renormalising, the two auxiliary losses,
 the Pallas grouped matmul interpreted, the device-kept routing totals,
 the anomaly guard reading the kernels' Σ g² of a slab's gradient in the
-slab's place (PR 44), and the three training drivers agreeing on an
+slab's place (PR 44), a held share's row buffer at its fit size and at
+its capacity (PR 45), and the three training drivers agreeing on an
 OLMoE-shaped chain."""
 
 import numpy as np
@@ -21,6 +22,7 @@ from znicz_tpu.utils.config import root
 
 B, T, D = 2, 8, 16
 E, K, F = 8, 2, 12
+_STEPS, HELD_FIT = moe._STEPS, moe.HELD_FIT
 OPTIONS = dict(n_experts=E, top_k=K, width=F, pre_norm="rms",
                residual=True, aux_loss_weight=0.01, z_loss_weight=0.001)
 PARAMS = ("weights", "weights_gate", "weights_up", "weights_down",
@@ -342,13 +344,17 @@ def test_an_inf_in_one_experts_gradient_skips_the_step(
             grad = grad.at[0, 5].set(np.inf)   # a row of the first group
         return real_tgmm(lhs, grad, group_sizes, **kwargs)
 
+    def retrace():     # what holds a trace of the kernel's call
+        moe.grouped_matmul.clear_cache()
+        moe._fit_or_capacity_bwd.clear_cache()
+
     monkeypatch.setattr(pallas_gmm, "znicz_tgmm", planted)
-    moe.grouped_matmul.clear_cache()
+    retrace()
     try:
         step(fwd, gd_u, err)
     finally:
         monkeypatch.setattr(pallas_gmm, "znicz_tgmm", real_tgmm)
-        moe.grouped_matmul.clear_cache()
+        retrace()
     after = state_of(fwd, gd_u)
     assert guard_sums(fwd) == (0 if recompute else 3)
     assert after["flags"][0] == 0.0
@@ -359,6 +365,326 @@ def test_an_inf_in_one_experts_gradient_skips_the_step(
     for key in ("weights", "weights_gate", "weights_up"):
         assert np.abs(after[key] - before[key]).max() > 0, key
         assert np.isfinite(after[key]).all(), key
+
+
+# ----------------------------------------------------------------------
+# a held share's row buffer: the fit size, the capacity (PR 45)
+# ----------------------------------------------------------------------
+#: 32 tokens, top 2 of 16 experts, 3 held: the uniform share is 12
+#: pairs, the fit size 15, the capacity 48 of the 64 pairs — and the
+#: router's first row, which every token sees as 4.0, says where they
+#: all go
+WIDE = dict(n_experts=16, top_k=2, held=(1, 6, 11), norm_topk=True,
+            score="sigmoid")
+FIT, CAPACITY = 15, 48       # four windows of the fit size: a scan
+#: … and 4 of 8 held: the fit size 40, the capacity every one of the
+#: 64 pairs — two windows, written out
+NEAR = dict(n_experts=8, top_k=2, held=(1, 3, 4, 6), norm_topk=True,
+            score="sigmoid")
+ROUTED = {"under_the_fit_size": {},
+          "over_the_fit_size": {1: 50.0},          # one pair a token
+          "over_the_capacity": {1: 50.0, 6: 50.0}}  # every pair
+
+
+def held_pair(routing, kernel, monkeypatch, fit, shared=0, wide=WIDE,
+              sizes=(FIT, CAPACITY)):
+    """A held layer and its backward, the router pushed as ``ROUTED``
+    says; ``fit`` None: the buffer has ONE length, its capacity — the
+    layer as it was before it had two."""
+    if kernel:
+        kernels_interpreted()
+    monkeypatch.setattr(moe, "HELD_FIT",
+                        moe.HELD_SLACK if fit is None else fit)
+    rng = np.random.default_rng(11)
+    x = rng.normal(0, 1, (4, T, D)).astype(np.float32)
+    x[..., 0] = 4.0
+    fwd, gd_u = build(XLADevice(), x, guard=True, pre_norm=None, lr=0.0,
+                      shared_width=shared, **wide)    # the same pairs
+                                                      # here every step
+    fwd.weights.map_write()
+    fwd.weights.mem[0] = 0.0
+    for expert, logit in ROUTED[routing].items():
+        fwd.weights.mem[0, expert] = logit
+    fwd.weights.unmap()
+    fwd.name = f"moe_fit_{routing}_{kernel}_{fit}_{sizes[0]}"
+    assert fwd._gmm_kernel == kernel and fwd._capacity == sizes[1]
+    assert fwd._fit == (sizes[1] if fit is None else sizes[0])
+    return fwd, gd_u, rng.normal(0, 0.1, x.shape).astype(np.float32)
+
+
+def leaves_of(tree) -> list:
+    import jax
+    return [np.array(leaf) for leaf in jax.tree.leaves(tree)]
+
+
+@pytest.mark.parametrize("kernel", [True, False],
+                         ids=["kernels_interpreted", "ragged_dot"])
+def test_two_windows_written_out_are_the_buffer_at_its_capacity(
+        kernel, monkeypatch):
+    """The capacity branch of a layer whose capacity is two windows of
+    its fit size (written out, where four are a scan): the same
+    equalities, and no scan in either direction."""
+    import jax
+    test_a_buffer_of_two_lengths_is_the_buffer_at_its_capacity(
+        "over_the_fit_size", kernel, monkeypatch, NEAR, (40, 64))
+    fwd, _, err = held_pair("over_the_fit_size", kernel, monkeypatch,
+                            HELD_FIT, wide=NEAR, sizes=(40, 64))
+    text, conds = pullback_text(fwd, err)
+    assert len(conds) == 2 and "scan[" not in "".join(
+        str(branch.jaxpr) for eqn in conds
+        for branch in eqn.params["branches"]).replace(
+            "cumsum", "")          # (an interpreted kernel's own apart)
+
+
+@pytest.mark.parametrize("kernel", [True, False],
+                         ids=["kernels_interpreted", "ragged_dot"])
+@pytest.mark.parametrize("routing", list(ROUTED))
+def test_a_buffer_of_two_lengths_is_the_buffer_at_its_capacity(
+        routing, kernel, monkeypatch, wide=WIDE, sizes=(FIT, CAPACITY)):
+    """The output, both auxiliary losses, what routing did, the
+    cotangent of every argument and the three taps (Σ g² of a slab's
+    gradient, on the kernel path) of a held layer whose buffer runs at
+    the fit size or at the capacity are those of the layer with the one
+    length, to f32 rounding — with the pairs here under the fit size,
+    between the two (the capacity branch runs, and makes its forward
+    again in the pullback), and over the capacity (the output NaN
+    either way, ``rows_over`` raised: the guard of a chain refuses the
+    step).  The device's counter says which branch ran, and the
+    epoch's gauge hands it out."""
+    import jax
+    got = []
+    for fit in (moe.HELD_FIT, None):
+        fwd, gd_u, err = held_pair(routing, kernel, monkeypatch, fit,
+                                   wide=wide, sizes=sizes)
+        args = fwd.forward_args()
+        assert (len(args) == 11) == kernel      # … the taps the last
+        primal, pullback, aux = jax.vjp(fwd.xla_forward, *args,
+                                        has_aux=True)
+        grads = pullback((jax.numpy.asarray(err),
+                          (np.float32(0.01), np.float32(0.001))))
+        got.append((leaves_of(primal), leaves_of(aux), leaves_of(grads)))
+        for _ in range(3):
+            step(fwd, gd_u, err)
+        fwd.on_epoch_ended()
+        held = {stat: obs_metrics.moe_held(fwd.name, stat).value
+                for stat in ("rows_here", "rows_over", "capacity", "fit",
+                             "fit_steps", "steps")}
+        fwd.last_choice.map_read()
+        here = np.isin(fwd.last_choice.mem, fwd.held).sum()
+        assert {"under_the_fit_size": 0 < here <= sizes[0],
+                "over_the_fit_size": sizes[0] < here <= sizes[1],
+                "over_the_capacity": here == 64}[routing]
+        assert held == {
+            "rows_here": here, "rows_over": max(here - sizes[1], 0),
+            "capacity": sizes[1], "fit": fwd._fit, "steps": 3,
+            "fit_steps": 3 if here <= fwd._fit else 0}
+    (y, *losses), aux, grads = got[0]
+    assert np.isfinite(y).all() == (routing != "over_the_capacity")
+    assert len(grads) == 5 + (3 if kernel else 0)     # … and the taps
+    if kernel and routing != "over_the_capacity":
+        assert all(tap > 0 for tap in grads[-3:])
+    # (what routing did: [rows of the held | here, all, over, and the
+    # flag of the branch, which the one length always raises])
+    assert aux[0][-1] == (routing == "under_the_fit_size")
+    aux[0][-1] = got[1][1][0][-1] = 0
+    for mine, want in zip(got[0], got[1]):
+        assert len(mine) == len(want)
+        for a, b in zip(mine, want):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+#: ``MoE._held_experts`` as it was before PR 45 (the one length; it
+#: returns the flag of its only branch besides), kept as the text the
+#: layer must still trace where its two sizes are one
+def held_experts_of_the_parent(self, m, top_p, top_e, w_g, w_u, w_d, taps):
+    import jax.numpy as jnp
+    n, d = m.shape
+    k, local, cap = self.top_k, self.n_local, self._capacity
+    table = np.full(self.n_experts, local, np.int32)
+    table[list(self.held)] = np.arange(local, dtype=np.int32)
+    slot = jnp.asarray(table)[top_e.reshape(n * k)]
+    order = jnp.argsort(slot, stable=True).astype(jnp.int32)
+    sizes = (slot[:, None] == jnp.arange(local)[None, :]).sum(
+        axis=0, dtype=jnp.int32)
+    here = sizes.sum()
+    pair = order[:cap]
+    live = jnp.arange(cap) < here
+    token = pair // k
+    sizes = jnp.minimum(sizes, jnp.maximum(
+        cap - (jnp.cumsum(sizes) - sizes), 0))
+    dt = self.mxu_dtype or jnp.float32
+    path = (getattr(self, "_gmm_kernel", False),
+            getattr(self, "_gmm_interpret", False))
+    rows = jnp.where(live[:, None], jnp.take(m, token, axis=0),
+                     0.0).astype(dt)
+    gate = moe.grouped_matmul(rows, w_g, sizes, *path, tap=taps[0])
+    up = moe.grouped_matmul(rows, w_u, sizes, *path, tap=taps[1])
+    hidden = (moe._silu(jnp, gate) * up).astype(dt)
+    out = moe.grouped_matmul(hidden, w_d, sizes, *path, tap=taps[2])
+    weight = jnp.where(live, jnp.take(top_p.reshape(n * k), pair),
+                       0.0)
+    f = jnp.zeros((n, d), jnp.float32).at[token].add(
+        out * weight[:, None])
+    over = jnp.maximum(here - cap, 0)
+    f = f + jnp.where(over > 0, jnp.float32(jnp.nan), 0.0)
+    return f, sizes, (here, over, here <= cap)
+
+
+def pullback_text(unit, err) -> tuple:
+    """The text of a layer's forward and pullback, traced as the
+    backward unit traces them, and the ``cond`` equations of the two
+    lengths in it."""
+    import jax
+    args = unit.forward_args()
+    cotangent = jax.numpy.asarray(err)
+    if isinstance(unit, moe.MoE):
+        cotangent = (cotangent, (np.float32(0.01), np.float32(0.001)))
+
+    def both(*args):
+        primal, pullback, *aux = jax.vjp(
+            unit.xla_forward, *args, has_aux=isinstance(unit, moe.MoE))
+        return primal, aux, pullback(cotangent)
+
+    closed = jax.make_jaxpr(both)(*args)
+    return str(closed), conds_of_the_two_lengths(closed)
+
+
+def conds_of_the_two_lengths(closed) -> list:
+    """The ``cond`` equations of ``_fit_or_capacity``'s two jitted
+    directions among a traced program's own equations (an interpreted
+    kernel's ``cond``s lie further in)."""
+    inner = [eqn.params["jaxpr"].jaxpr for eqn in closed.jaxpr.eqns
+             if eqn.primitive.name == "jit"
+             and eqn.params["name"].startswith("_fit_or_capacity")]
+    return [eqn for jaxpr in inner for eqn in jaxpr.eqns
+            if eqn.primitive.name == "cond"]
+
+
+def gated_mlp_unit(x):
+    wf = DummyWorkflow()
+    src = DummyUnit(wf, output=Vector(np.asarray(x), name="x"))
+    unit = moe.GatedMLP(wf, width=F, pre_norm="rms", residual=True)
+    unit.link_attrs(src, ("input", "output"))
+    unit.initialize(device=XLADevice())
+    return unit
+
+
+@pytest.mark.parametrize("layer", ["held_at_one_length", "dropless",
+                                   "gated_mlp"])
+def test_where_there_is_one_length_the_text_is_the_parents(
+        layer, monkeypatch):
+    """A held layer whose fit size IS its capacity traces the text it
+    traced before it had two sizes — forward and pullback, no ``cond``
+    of the two lengths in it —, and a dropless
+    layer and a dense gated MLP trace one text whatever the fit size:
+    the mechanism is in no program but a held layer's with two
+    lengths."""
+    kernels_interpreted()
+    x, err = _data(9)
+    texts = []
+    for fit in (moe.HELD_FIT, moe.HELD_SLACK):
+        monkeypatch.setattr(moe, "HELD_FIT", fit)
+        if layer == "gated_mlp":
+            texts.append(pullback_text(gated_mlp_unit(x), err))
+            continue
+        held = (1, 3, 4, 6) if layer == "held_at_one_length" else None
+        unit = build(XLADevice(), x, held=held)[0]
+        texts.append(pullback_text(unit, err))
+        if held and fit == moe.HELD_SLACK:
+            monkeypatch.setattr(moe.MoE, "_held_experts",
+                                held_experts_of_the_parent)
+            texts.append(pullback_text(unit, err))
+    two_lengths = layer == "held_at_one_length"
+    assert len(texts[0][1]) == (2 if two_lengths else 0)
+    assert (texts[0][0] != texts[1][0]) == two_lengths
+    assert not texts[1][1] and texts[1][0] == texts[-1][0]
+
+
+def test_a_step_at_the_fit_size_writes_nothing_of_the_capacitys(
+        monkeypatch):
+    """The forward and the pullback of a held layer with two lengths
+    hold ONE ``cond`` each; no result of either has the capacity's
+    rows (what ``jax.vjp`` of a plain ``cond`` would hand out: both
+    branches' residuals, the absent one's as zeros), and the fit
+    branch holds no value of that length at all, forward or backward —
+    while the capacity branch, ⌈48 / 15⌉ = 4 windows of the fit size's
+    rows, writes zeros of the FIT branch's five saved arrays, when it
+    runs."""
+    import jax
+    from tests.test_integrity import _all_eqns
+    fwd, _, err = held_pair("under_the_fit_size", True, monkeypatch,
+                            moe.HELD_FIT, shared=F)
+    fit, cap = fwd._fit, fwd._capacity
+
+    def both(*args):
+        primal, pullback, _ = jax.vjp(fwd.xla_forward, *args,
+                                      has_aux=True)
+        return primal, pullback((jax.numpy.asarray(err),
+                                 (np.float32(0.01), np.float32(0.001))))
+
+    conds = conds_of_the_two_lengths(
+        jax.make_jaxpr(both)(*fwd.forward_args()))
+    assert len(conds) == 2
+
+    def lengths(eqns) -> set:
+        return {var.aval.shape[0] for eqn in eqns
+                for var in list(eqn.invars) + list(eqn.outvars)
+                if getattr(var.aval, "shape", ())}
+
+    saved = []
+    for eqn in conds:
+        at_capacity, at_fit = eqn.params["branches"]
+        assert cap not in lengths([eqn])
+        assert cap not in lengths(_all_eqns(at_fit.jaxpr))
+        # … nor has the capacity branch: it is the fit size's body
+        # over four windows of the order under a scan (in the pullback
+        # forward again, then backward), the same kernels at the same
+        # shapes
+        assert cap not in lengths(_all_eqns(at_capacity.jaxpr))
+        windows = [e for e in at_capacity.jaxpr.eqns
+                   if e.primitive.name == "scan"]
+        assert len(windows) == (1 if eqn is conds[0] else 2)
+        assert {e.params["length"] for e in windows} == {4}
+        calls = [{str(e.params["jaxpr"].in_avals)
+                  for e in _all_eqns(branch.jaxpr)
+                  if e.primitive.name == "jit"
+                  and e.params["name"] == "grouped_matmul"}
+                 for branch in (at_fit, at_capacity)]
+        assert calls[0] and (eqn is conds[1] or calls[0] == calls[1])
+        saved.append([var.aval for var in eqn.outvars
+                      if var.aval.shape[:1] == (fit,)])
+    d = fwd.input.shape[-1]      # rows, gate, up, hidden, out
+    assert [aval.shape[1] for aval in saved[0]] == [d, F, F, F, d]
+    made = {eqn.outvars[0]: eqn
+            for eqn in conds[0].params["branches"][0].jaxpr.eqns}
+    for var in conds[0].params["branches"][0].jaxpr.outvars[-5:]:
+        zeros = made[var]        # of the capacity branch's five: zeros
+        assert zeros.primitive.name == "broadcast_in_dim"
+        assert float(zeros.invars[0].val) == 0.0
+    assert not saved[1]
+
+
+def test_a_snapshot_from_before_the_fit_size_restores():
+    """``moe_stats`` of a held layer saved before PR 45 is one slot
+    short (no count of the steps at the fit size): the layer starts
+    its totals over at the shape it has now, as it does for a snapshot
+    from before PR 34 (two slots short)."""
+    x, _ = _data(10)
+    prng.seed_all(5)
+    wf = DummyWorkflow()
+    src = DummyUnit(wf, output=Vector(np.asarray(x), name="x"))
+    unit = moe.MoE(wf, **{**OPTIONS, "held": (1, 3, 4, 6)})
+    unit.link_attrs(src, ("input", "output"))
+    unit.moe_stats.reset(np.full(4 + 5 + 3 + 2, 7.0, np.float32))
+    unit.initialize(device=XLADevice())
+    assert unit.moe_stats.shape == (4 + 5 + 4 + 2,)
+    unit.moe_stats.map_read()
+    assert not np.asarray(unit.moe_stats.mem).any()
+    unit.run()
+    unit.moe_stats.map_read()
+    stats = np.asarray(unit.moe_stats.mem)
+    assert stats[4 + _STEPS] == 1
+    assert stats[4 + 5 + 3] == (stats[4 + 5] <= unit._fit)
 
 
 # ----------------------------------------------------------------------

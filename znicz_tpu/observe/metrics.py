@@ -576,10 +576,14 @@ def moe_held(unit: str, stat: str) -> Gauge:
     live here; ``of``: experts the router chooses among; ``rows_here``:
     (token, expert) pairs routed to the held experts, which this chip
     computes; ``rows_routed``: all N·k pairs; ``capacity``: rows the
-    step's buffers hold; ``rows_over``: pairs beyond it, which poison
-    the step so that the guard refuses it).  A layer that holds every
-    expert has no series.  Fed from totals the unit keeps on the
-    device, read once per epoch."""
+    step's buffers hold at most, ``fit``: rows they hold in a step
+    whose pairs here fit that many; ``rows_over``: pairs beyond the
+    capacity, which poison the step so that the guard refuses it;
+    ``fit_steps`` of the epoch's ``steps``: the steps that ran at the
+    fit size — a count, not a mean: the others ran the whole capacity
+    and made their forward again in the backward).  A layer that
+    holds every expert has no series.  Fed from totals the unit keeps
+    on the device, read once per epoch."""
     return REGISTRY.gauge(
         "znicz_moe_held",
         "Experts held on this chip, experts routed over, and rows "

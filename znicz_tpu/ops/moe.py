@@ -77,22 +77,40 @@ top k; slabs, momentum and gradients exist for the held experts only;
 the layer computes the (token, expert) pairs routed to them and nothing
 stands in for the others: ``y = x + Shared(m) + Σ_{e ∈ top_k ∩ held}
 w_e · Expert_e(m)``.  The pairs here are a traced number under a static
-shape: they are sorted to the front and the first ``capacity`` rows go
-through the grouped matmuls, whose work follows the rows that are real,
-and come back by a scatter-add.  ONE rule sizes that buffer:
-``HELD_SLACK`` (4) times the share uniform routing sends here,
-N · k · |held| / E, and never more than the N · min(k, |held|) pairs
-that CAN arrive — as an expert-parallel exchange buffer is sized, and
-what keeps the dead rows cheap (the worst case is 25.6 times the
-uniform share at 8 of 256 experts, top 10, and the gathers, the
-weighting and the scatter-add run over every row of the buffer, real or
-not: PERF.md §6, PR 29).  A step that routes more pairs here is
-not computed short: its output is NaN, so the anomaly guard refuses the
-whole step.  What a user then sees: ``guard_skipped_steps`` rises WITH
-``znicz_moe_held{stat="rows_over"}`` above 0 and ``rows_here`` near
-``capacity`` — the router has collapsed onto this chip's experts
-(raise ``aux_loss_weight``, or hold fewer tokens a step); skipped steps
-with ``rows_over`` 0 have another cause.
+shape: they are sorted to the front and the first rows of that order
+go through the grouped matmuls, whose work follows the rows that are
+real, and come back by a scatter-add.  The gathers, the weighting and
+the scatter-add run over every row of the buffer, real or not
+(PERF.md §6, PR 29), so the buffer has TWO static lengths and the
+device picks one a step, from the count it has (PR 45).  ONE rule
+sizes the longer, the **capacity**: ``HELD_SLACK`` (4) times the share
+uniform routing sends here, N · k · |held| / E, and never more than
+the N · min(k, |held|) pairs that CAN arrive — as an expert-parallel
+exchange buffer is sized (the worst case is 25.6 times the uniform
+share at 8 of 256 experts, top 10).  The shorter, the **fit** size, is
+``HELD_FIT`` (1.25) times the uniform share, in whole row tiles and no
+more than the capacity: what a step needs whose routing is near
+uniform.  A step whose pairs here fit it runs at it (ONE ``cond``
+forward and one backward, :func:`_fit_or_capacity`); a step that
+routes more here runs the whole capacity — as windows of the fit
+size's rows, through the same kernels — and makes the routed part's
+forward again in its backward, once or twice (the forward keeps for
+the backward what the FIT branch made, nothing of the capacity's: a
+step at the fit size writes nothing it would not write if the buffer
+had that one length);
+where the two lengths are one the layer is the plain body under plain
+autodiff.  Nothing is dropped and no precision changes either way.  A
+step that routes more pairs here than the capacity is not computed
+short: its output is NaN, so the anomaly guard refuses the whole step.
+What a user sees: ``znicz_moe_held{stat="fit_steps"}`` beside
+``steps`` — equal while routing stays near uniform; ``fit_steps``
+under ``steps`` with ``rows_here`` over ``fit``: the router sends this
+chip more than its share, the steps are right and cost a buffer of the
+whole capacity and a forward or two more (raise ``aux_loss_weight``);
+``guard_skipped_steps`` rising WITH ``rows_over`` above 0 and
+``rows_here`` near ``capacity``: the router has collapsed onto this
+chip's experts (the same cure, or hold fewer tokens a step); skipped
+steps with ``rows_over`` 0 have another cause.
 
 ``select_bias`` and ``groups`` (Ling-3.0-flash, PR 37; DeepSeek-V3's
 router, arXiv:2412.19437 §2.1.2) change the CHOICE only, beside
@@ -118,6 +136,8 @@ feed-forward block (``y = x + W_down (silu(W_gate m) ⊙ W_up m)``).
 from __future__ import annotations
 
 import functools
+import math
+import typing
 
 import numpy as np
 
@@ -142,8 +162,36 @@ _BIAS = slice(-5, -2)
 # the device path's three primitives
 # ----------------------------------------------------------------------
 #: a held share's row buffer, as a multiple of what uniform routing
-#: sends to the experts held (module docstring)
+#: sends to the experts held (module docstring): the CAPACITY, the most
+#: a step may route here …
 HELD_SLACK = 4
+#: … and the FIT size, which holds a step whose routing is near
+#: uniform: the length the buffer runs at whenever the pairs here fit
+HELD_FIT = 1.25
+
+
+@jax.custom_vjp
+def _kept(made, kept):
+    """``kept``, a value made and saved elsewhere, where the same value
+    is made again (``made``): every reader takes ``kept``, the
+    cotangent goes the way ``made`` came — what made it again is dead
+    code, and only its pullback runs."""
+    return kept
+
+
+_kept.defvjp(lambda made, kept: (kept, None),
+             lambda _, grad: (grad, None))
+
+
+def _cast(rhs, dtype):
+    """The slabs in the rows' dtype.  ``rhs`` may be a pair: the slabs
+    and the cast of them their caller made ONCE for several calls (a
+    held layer's two branches, forward and backward: each would cast
+    the 235 MB again) — the cast is read, and a cotangent goes to the
+    slabs as if they had been cast here."""
+    if isinstance(rhs, tuple):
+        return _kept(rhs[0].astype(dtype), rhs[1])
+    return rhs.astype(dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -159,7 +207,7 @@ def _gmm_kernels(lhs, rhs, group_sizes, tap, interpret):
     the weight gradient this very backward returns, as ``znicz_tgmm``
     summed them while the slabs lay in VMEM.  A pullback can hand out
     nothing but cotangents, so that is how the number leaves it."""
-    return pallas_gmm.znicz_gmm(lhs, rhs.astype(lhs.dtype), group_sizes,
+    return pallas_gmm.znicz_gmm(lhs, _cast(rhs, lhs.dtype), group_sizes,
                                 interpret=interpret)
 
 
@@ -173,12 +221,16 @@ def _gmm_kernels_bwd(interpret, residual, grad):
     grad = grad.astype(lhs.dtype)
     # the row gradient leaves the kernel in the rows' dtype: one
     # rounding of its f32 accumulator; the weight gradient stays f32
-    d_lhs = pallas_gmm.znicz_gmm(grad, rhs.astype(lhs.dtype), group_sizes,
+    d_lhs = pallas_gmm.znicz_gmm(grad, _cast(rhs, lhs.dtype), group_sizes,
                                  transpose_rhs=True, out_dtype=lhs.dtype,
                                  interpret=interpret)
     d_rhs, squares = pallas_gmm.znicz_tgmm(lhs, grad, group_sizes,
                                            interpret=interpret)
-    return d_lhs, d_rhs.astype(rhs.dtype), None, squares.sum()
+    if isinstance(rhs, tuple):       # (the slabs, their cast): no
+        d_rhs = (d_rhs.astype(rhs[0].dtype), None)    # cotangent to it
+    else:
+        d_rhs = d_rhs.astype(rhs.dtype)
+    return d_lhs, d_rhs, None, squares.sum()
 
 
 _gmm_kernels.defvjp(_gmm_kernels_fwd, _gmm_kernels_bwd)
@@ -189,9 +241,11 @@ def grouped_matmul(lhs, rhs, group_sizes, kernel: bool = False,
                    interpret: bool = False, tap=None):
     """(M, K) rows in E contiguous groups × (E, K, N) f32 slabs →
     (M, N) f32: row r of group e is multiplied by ``rhs[e]``, the slabs
-    cast to the rows' dtype on the way in.  ``kernel`` runs the repo's
-    Pallas kernels (``znicz_gmm`` / ``znicz_tgmm``; the weight gradient
-    comes back in f32, the row gradient in the rows' dtype), else
+    cast to the rows' dtype on the way in (or, ``rhs`` a pair, read
+    from the cast their caller made: :func:`_cast`).  ``kernel`` runs
+    the repo's Pallas kernels (``znicz_gmm`` / ``znicz_tgmm``; the
+    weight gradient comes back in f32, the row gradient in the rows'
+    dtype), else
     ``jax.lax.ragged_dot``, the XLA core.  Jitted so that the three
     call sites of a layer, and every layer, lower it once (PERF.md §6,
     PR 24: lowering is a set-up cost the compile cache does not hide).
@@ -207,7 +261,7 @@ def grouped_matmul(lhs, rhs, group_sizes, kernel: bool = False,
         return _gmm_kernels(
             lhs, rhs, group_sizes,
             jnp.zeros((), jnp.float32) if tap is None else tap, interpret)
-    return jax.lax.ragged_dot(lhs, rhs.astype(lhs.dtype), group_sizes,
+    return jax.lax.ragged_dot(lhs, _cast(rhs, lhs.dtype), group_sizes,
                               preferred_element_type=jnp.float32)
 
 
@@ -253,6 +307,190 @@ def _unpermute_bwd(residual, grad):
 
 
 _unpermute.defvjp(_unpermute_fwd, _unpermute_bwd)
+
+
+class _Held(typing.NamedTuple):
+    """What the routed sum of a held share reads of its layer beside
+    the arrays: hashable, so that the layers of a model that agree on
+    it share ONE traced and lowered program a direction
+    (:func:`_fit_or_capacity`)."""
+    top_k: int
+    dtype: object              # of the rows the matmuls take
+    kernel: bool               # ``grouped_matmul``'s path
+    interpret: bool
+    fit: int                   # the buffer's two static lengths
+    capacity: int
+
+
+def _cut(sizes, length):
+    """The groups' sizes cut to a buffer of ``length`` rows, in the
+    groups' order (all of them fit, unless the step is over its
+    capacity: then :meth:`MoE._held_experts` poisons it)."""
+    return jnp.minimum(sizes, jnp.maximum(
+        length - (jnp.cumsum(sizes) - sizes), 0))
+
+
+def _held_rows(plan, length, m, top_p, w_g, w_u, w_d, taps, casts, order,
+               sizes, here, kept=None):
+    """``(f, sizes, (rows, gate, up, hidden, out))``: the routed sum
+    of the first ``length`` (static) pairs of ``order``, of which
+    ``here`` are real; the groups cut to that buffer; and what a
+    pullback of it reads.  ``casts``: the three slabs in the rows'
+    dtype, made once by the caller, or None (cast at each call);
+    ``kept``: the five results from an earlier run of the same
+    function, which then stand in for the ones made here
+    (:func:`_kept`)."""
+    n, d = m.shape
+    k, dt = plan.top_k, plan.dtype
+    keep = iter(kept or ())
+    pair = order[:length]
+    live = jnp.arange(length) < here
+    token = pair // k
+    sizes = _cut(sizes, length)
+    path = (plan.kernel, plan.interpret)
+    w_g, w_u, w_d = (w_g, w_u, w_d) if casts is None \
+        else zip((w_g, w_u, w_d), casts)
+
+    def saved(made):
+        return made if kept is None else _kept(made, next(keep))
+
+    rows = saved(jnp.where(live[:, None], jnp.take(m, token, axis=0),
+                           0.0).astype(dt))
+    gate = saved(grouped_matmul(rows, w_g, sizes, *path, tap=taps[0]))
+    up = saved(grouped_matmul(rows, w_u, sizes, *path, tap=taps[1]))
+    hidden = saved((_silu(jnp, gate) * up).astype(dt))
+    out = saved(grouped_matmul(hidden, w_d, sizes, *path, tap=taps[2]))
+    weight = jnp.where(live, jnp.take(top_p.reshape(n * k), pair), 0.0)
+    f = jnp.zeros((n, d), jnp.float32).at[token].add(
+        out * weight[:, None])
+    return f, sizes, (rows, gate, up, hidden, out)
+
+
+#: the windows of a capacity branch written out one after the other;
+#: more of them are a scan
+UNROLLED_WINDOWS = 2
+
+
+def _held_windows(plan, m, top_p, w_g, w_u, w_d, taps, casts, order,
+                  sizes, here):
+    """``(f, sizes)`` of a buffer of the capacity's rows, made by
+    :func:`_held_rows` over ⌈capacity / fit⌉ windows of ``fit`` rows of
+    the order, one after the other: each window takes what is left of
+    the groups, and the results add up.  So the SAME kernels at the
+    same shapes run as in a step at the fit size — a second set, at
+    the capacity's length, would be traced, lowered and compiled for
+    steps that near-uniform routing never takes, at every start of the
+    program (PERF.md §6, PR 45: 8 s of a 60 s set-up).
+
+    Two windows are written out: they trace as fast as a scan, and
+    their pullback is the body's own.  From three on the traces grow
+    with the windows (3–8 s of a 49 s set-up at three), so they are a
+    scan whose body is traced once; it keeps nothing of a window for
+    its pullback but what went in (``jax.checkpoint``: a window's
+    forward is made again where its pullback runs — kept, the
+    windows' results are stacked, and the chip's compiler refused the
+    fusion that wrote a kernel's result into the stack)."""
+    fit, capacity = plan.fit, plan.capacity
+    # as one buffer of the capacity's rows would cut them
+    left = sizes = _cut(sizes, capacity)
+    here = jnp.minimum(here, capacity)
+    starts = range(0, capacity, fit)
+    order = jnp.pad(order, (0, max(starts[-1] + fit - order.shape[0], 0)))
+
+    def window(f, left, start, pairs):
+        part, took, _ = _held_rows(
+            plan, fit, m, top_p, w_g, w_u, w_d, taps, casts, pairs, left,
+            here - start)
+        return f + part, left - took
+
+    if len(starts) <= UNROLLED_WINDOWS:
+        f = 0.0
+        for start in starts:
+            f, left = window(f, left, start, order[start:start + fit])
+        return f, sizes
+
+    @jax.checkpoint
+    def step(carry, start):
+        return window(*carry, start, jax.lax.dynamic_slice(
+            order, (start,), (fit,))), None
+
+    (f, _), _ = jax.lax.scan(
+        step, (jnp.zeros(m.shape, jnp.float32), sizes),
+        jnp.arange(len(starts), dtype=jnp.int32) * fit)
+    return f, sizes
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _fit_or_capacity(plan, *args):
+    """:func:`_held_rows` at the fit size where the pairs here fit it,
+    :func:`_held_windows` over the capacity where they do not
+    (``args``: those functions', from ``m`` to ``here``) →
+    ``(f, sizes)``.
+
+    One ``cond``, and a pullback of its own: ``jax.vjp`` of a ``cond``
+    hands out BOTH branches' residuals, the absent branch's as zeros,
+    and a step at the fit size would write the capacity's every time.
+    Here the forward saves what the FIT branch made — its rows, gate,
+    up, hidden and out — and nothing of the capacity branch, which
+    writes zeros of the fit branch's shapes when it runs; the pullback
+    holds the same ``cond``: at the fit size it is ``jax.vjp`` of the
+    body with the saved values standing in for the ones made again
+    (:func:`_kept`), at the capacity it makes the windows' forward
+    again from the inputs (and, from three windows on, each window's
+    once more where its pullback runs) — one or two more forwards of
+    the layer's routed part than a buffer of one length would cost, in
+    the steps that take that branch only.  There the three taps are
+    summed from
+    the slabs' gradients (each window's kernels sum the squares of its
+    own part, and the parts add up before the update sees them).
+    Either direction is jitted on ``plan``: the layers of a model
+    trace and lower it once, not once each (PERF.md §6, PR 24 and
+    PR 45: what a warm compile cache does not hide)."""
+    return _fit_or_capacity_fwd(plan, *args)[0]
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _fit_or_capacity_fwd(plan, *args):
+    m, w_g = args[0], args[2]
+    (d, width), dt = (m.shape[1], w_g.shape[2]), plan.dtype
+
+    def at_capacity(*a):
+        # zeros where the fit branch has its rows, gate, up, hidden
+        # and out (``cond`` holds the two branches to one type)
+        return _held_windows(plan, *a) + (tuple(
+            jnp.zeros((plan.fit, columns), dtype) for columns, dtype in (
+                (d, dt), (width, jnp.float32), (width, jnp.float32),
+                (width, dt), (d, jnp.float32))),)
+
+    f, sizes, kept = jax.lax.cond(
+        args[-1] <= plan.fit,
+        lambda *a: _held_rows(plan, plan.fit, *a), at_capacity, *args)
+    return (f, sizes), (args, kept)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _fit_or_capacity_bwd(plan, residual, grads):
+    args, kept = residual
+    # the last four — the slabs' casts, the pairs' order, the groups'
+    # sizes, the pairs here — take no cotangent
+    diff, rest = args[:-4], args[-4:]
+
+    def at_fit(diff, kept, grad):
+        return jax.vjp(lambda *diff: _held_rows(
+            plan, plan.fit, *diff, *rest, kept=kept)[0], *diff)[1](grad)
+
+    def at_capacity(diff, kept, grad):
+        *grads, taps = jax.vjp(lambda *diff: _held_windows(
+            plan, *diff, *rest)[0], *diff)[1](grad)
+        return (*grads, tuple(
+            tap if tap is None else jnp.sum(slab * slab)
+            for tap, slab in zip(taps, grads[2:])))
+
+    return jax.lax.cond(args[-1] <= plan.fit, at_fit, at_capacity,
+                        diff, kept, grads[0]) + (None,) * 4
+
+
+_fit_or_capacity.defvjp(_fit_or_capacity_fwd, _fit_or_capacity_bwd)
 
 
 def _silu(xp, x):
@@ -358,8 +596,9 @@ class MoE(Forward):
         #: [rows per expert held here (all E without ``held``) | lb
         #: loss, z loss, steps, per-step max and min rows of an expert;
         #: with ``held`` also: rows here, rows routed, rows over the
-        #: capacity | rows the kernels' visits cover, rows that are
-        #: real (both 0 on the XLA path)], summed on the device
+        #: capacity, steps that ran at the fit size | rows the kernels'
+        #: visits cover, rows that are real (both 0 on the XLA path)],
+        #: summed on the device
         self.moe_stats = Vector(name=f"{self.name}.moe_stats")
         #: what the router did in the last step: its (N, E) logits and
         #: the (N, top_k) experts chosen — what a check against a plain
@@ -414,10 +653,11 @@ class MoE(Forward):
             from znicz_tpu.observe import metrics as obs_metrics
             obs_metrics.moe_router(self.name, "groups_kept").set(
                 self.groups[1] if self.groups else 0)
-        slots = local + 5 + (3 if self.held else 0) \
+        slots = local + 5 + (4 if self.held else 0) \
             + (3 if self.select_bias_on else 0) + 2
         if not self.moe_stats or self.moe_stats.shape != (slots,):
-            # (a snapshot from before PR 34 holds two slots fewer)
+            # (a snapshot from before PR 34 holds two slots fewer, a
+            # held layer's from before PR 45 one)
             self.moe_stats.reset(np.zeros(slots, np.float32))
         self.output.reset(np.zeros((b, t, d),
                                    dtype=self.output_store_dtype))
@@ -434,19 +674,26 @@ class MoE(Forward):
         mesh = getattr(self.device, "mesh", None)
         if refused is None and mesh is not None and mesh.size > 1:
             refused = "expert parallelism over a mesh is not built"
-        pairs = rows = b * t * self.top_k
+        pairs = rows = fit = b * t * self.top_k
         if self.held is not None:
+            def whole_tiles(rows, most):
+                tile = min(pallas_gmm.ROW_TILE, rows)
+                return min(-(-rows // tile) * tile, most)
+
             # the rows the step's buffers hold (module docstring), a
-            # whole number of the kernels' row tiles where they fit
-            rows = min(-(-HELD_SLACK * pairs * local // e),
-                       b * t * min(self.top_k, local))
-            tile = min(pallas_gmm.ROW_TILE, rows)
-            rows = min(-(-rows // tile) * tile, pairs)
-            self._capacity = rows
-        #: the kernels' row tile: one for the layer's nine calls
-        self._gmm_row_tile = tile = pallas_gmm.row_tile(rows)
-        if refused is None and rows % tile:
-            refused = (f"{rows} rows do not divide by the kernels' "
+            # whole number of the kernels' row tiles where they fit:
+            # the most a step may route here, and what a step near
+            # uniform routing needs
+            rows = whole_tiles(min(-(-HELD_SLACK * pairs * local // e),
+                                   b * t * min(self.top_k, local)), pairs)
+            fit = whole_tiles(math.ceil(HELD_FIT * pairs * local / e),
+                              rows)
+            self._capacity, self._fit = rows, fit
+        #: the kernels' row tile: one for the layer's nine calls (a
+        #: held layer's calls all run at the fit size's length)
+        self._gmm_row_tile = tile = pallas_gmm.row_tile(fit)
+        if refused is None and fit % tile:
+            refused = (f"{fit} rows do not divide by the kernels' "
                        f"row tile {tile}")
         self._gmm_kernel = refused is None
         self._gmm_interpret = interpret
@@ -454,13 +701,14 @@ class MoE(Forward):
                   "over %d rows in %d groups, %d x %d (gate, up) and "
                   "%d x %d (down)%s", self.name, e, self.top_k,
                   "" if self.held is None else
-                  f", {local} held here (capacity {rows} of "
-                  f"{pairs} pairs)",
+                  f", {local} held here (a step's buffer: {fit} rows "
+                  f"where the pairs here fit, else the capacity {rows}, "
+                  f"of {pairs} pairs)",
                   f"znicz_gmm / znicz_tgmm kernels, row tile {tile}"
                   + (", INTERPRETED" if interpret else "")
                   if self._gmm_kernel
                   else f"jax.lax.ragged_dot ({refused})",
-                  rows, local, d, f, f, d,
+                  fit, local, d, f, f, d,
                   (f"; {self.score} scores x {self.routed_scale:g}, "
                    f"shared expert of {shared}"
                    if (self.score, self.routed_scale, shared)
@@ -576,10 +824,15 @@ class MoE(Forward):
         return top_p
 
     def _held_experts(self, m, top_p, top_e, w_g, w_u, w_d, taps):
-        """``(f, local counts, (rows here, rows over))``: the routed
-        sum of the pairs whose expert lives here (module docstring)."""
-        n, d = m.shape
-        k, local, cap = self.top_k, self.n_local, self._capacity
+        """``(f, local counts, (rows here, rows over, whether the step
+        ran at the fit size))``: the routed sum of the pairs whose
+        expert lives here (module docstring)."""
+        n = m.shape[0]
+        k, local = self.top_k, self.n_local
+        plan = _Held(k, self.mxu_dtype or jnp.float32,
+                     getattr(self, "_gmm_kernel", False),
+                     getattr(self, "_gmm_interpret", False),
+                     self._fit, self._capacity)
         table = np.full(self.n_experts, local, np.int32)
         table[list(self.held)] = np.arange(local, dtype=np.int32)
         slot = jnp.asarray(table)[top_e.reshape(n * k)]
@@ -589,30 +842,22 @@ class MoE(Forward):
         sizes = (slot[:, None] == jnp.arange(local)[None, :]).sum(
             axis=0, dtype=jnp.int32)
         here = sizes.sum()
-        pair = order[:cap]
-        live = jnp.arange(cap) < here
-        token = pair // k
-        # the groups cut to the buffer (all of them fit, unless the
-        # step is over: then it is poisoned below)
-        sizes = jnp.minimum(sizes, jnp.maximum(
-            cap - (jnp.cumsum(sizes) - sizes), 0))
-        dt = self.mxu_dtype or jnp.float32
-        path = (getattr(self, "_gmm_kernel", False),
-                getattr(self, "_gmm_interpret", False))
-        rows = jnp.where(live[:, None], jnp.take(m, token, axis=0),
-                         0.0).astype(dt)
-        gate = grouped_matmul(rows, w_g, sizes, *path, tap=taps[0])
-        up = grouped_matmul(rows, w_u, sizes, *path, tap=taps[1])
-        hidden = (_silu(jnp, gate) * up).astype(dt)
-        out = grouped_matmul(hidden, w_d, sizes, *path, tap=taps[2])
-        weight = jnp.where(live, jnp.take(top_p.reshape(n * k), pair),
-                           0.0)
-        f = jnp.zeros((n, d), jnp.float32).at[token].add(
-            out * weight[:, None])
-        over = jnp.maximum(here - cap, 0)
+        if plan.fit == plan.capacity:
+            # one length: the plain body under plain autodiff
+            f, sizes, _ = _held_rows(plan, plan.capacity, m, top_p, w_g,
+                                     w_u, w_d, taps, None, order, sizes,
+                                     here)
+        else:
+            # each slab cast ONCE a step, outside the two ``cond``s:
+            # inside, each branch of each would cast it again
+            casts = tuple(jax.lax.stop_gradient(w.astype(plan.dtype))
+                          for w in (w_g, w_u, w_d))
+            f, sizes = _fit_or_capacity(plan, m, top_p, w_g, w_u, w_d,
+                                        taps, casts, order, sizes, here)
+        over = jnp.maximum(here - plan.capacity, 0)
         # never short: the guard refuses a step that is over
         f = f + jnp.where(over > 0, jnp.float32(jnp.nan), 0.0)
-        return f, sizes, (here, over)
+        return f, sizes, (here, over, here <= plan.fit)
 
     def xla_forward(self, x, w_r, w_g, w_u, w_d, g_norm=None,
                     ws_g=None, ws_u=None, ws_d=None, select_bias=None,
@@ -647,7 +892,8 @@ class MoE(Forward):
             f, local, here = self._held_experts(m, top_p, top_e, w_g,
                                                 w_u, w_d, taps)
             y = f.reshape(b, t, d)
-            extra = jnp.stack([here[0], jnp.int32(n * k), here[1]])
+            extra = jnp.stack([here[0], jnp.int32(n * k), here[1],
+                               here[2].astype(jnp.int32)])
             counts = jax.lax.stop_gradient(
                 jnp.concatenate([local, extra]).astype(jnp.float32))
         else:
@@ -684,7 +930,7 @@ class MoE(Forward):
         self.last_choice.devmem = top_e.astype(jnp.int32).reshape(
             self.last_choice.shape)
         held = []
-        if self.held is not None:      # [local rows | here, all, over]
+        if self.held is not None:   # [local rows | here, all, over, fit]
             counts, held = counts[:self.n_local], [counts[self.n_local:]]
         tail = jnp.stack([lb, z, jnp.float32(1.0), counts.max(),
                           counts.min()])
@@ -742,7 +988,9 @@ class MoE(Forward):
                         ("held", e), ("of", self.n_experts),
                         ("rows_here", here), ("rows_routed", routed),
                         ("capacity", getattr(self, "_capacity", 0)),
-                        ("rows_over", over)):
+                        ("fit", getattr(self, "_fit", 0)),
+                        ("rows_over", over),
+                        ("fit_steps", tail[8]), ("steps", tail[_STEPS])):
                     obs_metrics.moe_held(self.name, stat).set(value)
             if tail[-1]:
                 for stat, value in (("visited", tail[-2] / steps),
@@ -817,7 +1065,8 @@ class MoE(Forward):
             self.select_load.map_invalidate()
             self.select_load.mem[...] = routed
         held = [] if self.held is None \
-            else [counts.sum(), routed.sum(), 0.0]
+            else [counts.sum(), routed.sum(), 0.0,
+                  float(counts.sum() <= self._fit)]
         if self.select_bias_on:
             held = held + [0.0, 0.0, 0.0]
         self.moe_stats.map_write()
