@@ -117,10 +117,11 @@ def readings(reference, layers: list, bias: dict) -> list:
              {"weights_of": from_biased}, None)]
 
 
-def bias_rate_control(args) -> int:
+def bias_rate_control(args, cell_name: str = CELL) -> int:
     """The system with its bias ``args.bias_rate_times`` times as fast,
     after a run's steps, under the driver's own ``check`` (module
-    docstring)."""
+    docstring); ``cell_name``: another cell with such a router
+    (``benchmarks/xing_controls.py``)."""
     import json
     import time
 
@@ -133,11 +134,11 @@ def bias_rate_control(args) -> int:
             spec["bias_rate"] = times * float(spec.get("bias_rate", 1e-3))
 
     from znbench.harness import discovery
-    traffic = discovery.find_cell(CELL, toy=args.toy).traffic
+    traffic = discovery.find_cell(cell_name, toy=args.toy).traffic
     epochs = int(traffic.get("warmup_epochs", 2)) \
         + int(traffic.get("min_segments", 10)) \
         * int(traffic.get("epochs_per_segment", 1))
-    with trained(CELL, args, epochs, faster) as (
+    with trained(cell_name, args, epochs, faster) as (
             ctx, driver, wf, layers, _reference, devices):
         t0 = time.perf_counter()
         problems, notes = driver.check(ctx, wf, layers)

@@ -801,3 +801,119 @@ def test_short_conv_kernels_compile_for_v5e_at_published_widths(
     assert text.count("tpu_custom_call") >= 2
     for name in ("znicz_short_conv_fwd", "znicz_short_conv_bwd"):
         assert name in text
+
+
+def test_stream_pair_compiled_for_v5e_keeps_the_streams_where_they_lie(
+        v5e_chip):
+    """A READ + WRITE pair of the residual streams (PR 46) around a
+    stand-in sublayer, forward and every gradient, at Xing4.0's widths
+    — the stream (1, 4 · 3,584, 4,096) f32, position-minor — compiled
+    for the chip: Sinkhorn's twenty iterations are ONE loop in the
+    program, and no ``copy`` / ``transpose`` of n·D·T elements or more
+    stands in it (stored (T, n·D), every unit's stream was copied into
+    a position-minor layout, 224 MB each, and the cell's step asked for
+    18.1 GB of the chip's 15.75).  The D-wide transposes of h and of f
+    are the layout's price and are allowed."""
+    import jax
+    import jax.numpy as jnp
+
+    from znicz_tpu.dummy import DummyWorkflow
+    from znicz_tpu.ops import streams
+    b, t, d, n = 1, 4096, 3584, 4
+    read = streams.StreamRead(DummyWorkflow(), n_streams=n)
+
+    def step(x, phi, bias, alpha, w, g):
+        def loss(x, phi, bias, alpha, w):
+            (h, h_post, h_res), _ = read.xla_forward(x, phi, bias, alpha)
+            f = jnp.dot(h.astype(jnp.bfloat16).reshape(t, d),
+                        w.astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32)
+            out = streams.StreamWrite.xla_forward(
+                f.reshape(b, t, d), x, h_post, h_res)
+            return jnp.sum(out * g), out
+        return jax.value_and_grad(loss, (0, 1, 2, 3, 4), has_aux=True)(
+            x, phi, bias, alpha, w)
+
+    shapes = [jax.ShapeDtypeStruct(shape, jnp.float32, sharding=v5e_chip)
+              for shape in ((b, n * d, t), (n * d, 2 * n + n * n),
+                            (2 * n + n * n,), (3,), (d, d),
+                            (b, n * d, t))]
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:   # a described chip's executable cannot be read back here
+        compiled = jax.jit(step).lower(*shapes).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    text = compiled.as_text()
+    assert len(re.findall(r" while\(", text)) in (1, 2, 3)
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    moves = []
+    for line in entry.splitlines():
+        m = _MOVE.match(line.strip())
+        if m is None:
+            continue
+        _dtype, dims, op, _operand = m.groups()
+        size = math.prod(int(k) for k in dims.split(",") if k)
+        if size >= n * d * t and op in ("copy", "transpose"):
+            moves.append(line.strip()[:160])
+    assert not moves, moves
+    # x, g in; X′, dX out; and at most three more streams' worth between
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= 3 * 4 * n * d * t
+
+
+def test_query_latent_layer_compiled_for_v5e_at_published_widths(
+        v5e_chip, monkeypatch):
+    """The latent-K/V layer WITH its query latent (768; 512 + 64; 32
+    heads of 128 + 64 / 128) and the given score scale, forward and
+    backward at T 4,096 in bf16, through Mosaic for the chip: the
+    two-width flash kernels take the up-projected queries as they took
+    the fused projection's columns."""
+    import jax
+    import jax.numpy as jnp
+
+    from znicz_tpu.dummy import DummyUnit, DummyWorkflow
+    from znicz_tpu.memory import Vector
+    from znicz_tpu.ops import attention, pallas_kernels
+    b, t, d = 1, 4096, 3584
+    monkeypatch.setattr(pallas_kernels, "is_tpu_device",
+                        lambda device: True)
+    root.common.precision_type = "bfloat16"
+    wf = DummyWorkflow()
+    src = DummyUnit(wf, output=Vector(np.zeros((b, t, d), np.float32),
+                                      name="x"))
+    unit = attention.MultiHeadAttention(
+        wf, n_heads=32, causal=True, include_bias=False, pre_norm="rms",
+        q_latent=768, kv_latent=512, qk_nope=128, qk_rope=64,
+        v_head_dim=128, score_scale=0.14468, norm_eps=1e-6,
+        rope={"theta": 10000, "yarn": {
+            "factor": 64, "original_max_position_embeddings": 4096,
+            "beta_fast": 32, "beta_slow": 1, "attention_factor": 1.0}})
+    unit.link_attrs(src, ("input", "output"))
+    unit.initialize(device=XLADevice())
+    assert unit._flash.runs
+    assert unit.weights.shape == (d, 768 + 512 + 64)
+    assert unit.weights_q_up.shape == (768, 32 * 192)
+
+    def struct(a):
+        return None if a is None else jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e_chip)
+
+    def step(dy, *args):
+        out, pullback = jax.vjp(unit.xla_forward, *args)
+        return out, pullback(dy)
+
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(step).lower(
+            jax.ShapeDtypeStruct((b, t, d), jnp.float32,
+                                 sharding=v5e_chip),
+            *(struct(a) for a in unit.forward_args())) \
+            .compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    for kernel in ("znicz_flash_fwd_mla", "znicz_flash_bwd_mla_dq",
+                   "znicz_flash_bwd_mla_dkv"):
+        assert kernel in text, kernel

@@ -586,6 +586,9 @@ class JitRegion(Logger):
                     # passes' partial gradient sums, dropped likewise
                     if getattr(unit, "_traced_vjp", None) is not None:
                         unit._traced_vjp = None
+                    forget = getattr(unit, "forget_trace", None)
+                    if forget is not None:   # the stream units' hand-overs
+                        forget()
                 for span in region.pass_spans:
                     span.forget_trace()
 

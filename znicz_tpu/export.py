@@ -258,6 +258,16 @@ def refuse_unserved(forwards, what: str) -> None:
                 f"(short_conv); serving has no rolling state for the "
                 f"convolution's last taps yet — the mixer exists on the "
                 f"training path only (ROADMAP R1, serving half)")
+        if kind in ("StreamOpen", "StreamRead", "StreamWrite",
+                    "StreamClose"):
+            raise NotImplementedError(
+                f"{what}: layer {i} is a unit of a residual path of "
+                f"{getattr(unit, 'n_streams', 'n')} streams "
+                f"(stream_open / stream_read / stream_write / "
+                f"stream_close); serving carries one residual row a "
+                f"token — the n-stream state, its maps and Sinkhorn's "
+                f"iterations exist on the training path only (ROADMAP "
+                f"R1, serving half)")
         if kind == "MoE":
             choice = [name for name, on in (
                 ("select_bias", getattr(unit, "select_bias_on", False)),
@@ -272,12 +282,18 @@ def refuse_unserved(forwards, what: str) -> None:
         if kind != "MultiHeadAttention":
             continue
         if getattr(unit, "kv_latent", None) is not None:
+            more = [name for name in ("q_latent", "score_scale")
+                    if getattr(unit, name, None) is not None]
             raise NotImplementedError(
                 f"{what}: attention layer {i} sets kv_latent (a latent "
-                f"K/V with qk_nope, qk_rope, v_head_dim); serving has "
+                f"K/V with qk_nope, qk_rope, v_head_dim"
+                f"{'; ' + ', '.join(more) if more else ''}); serving has "
                 f"no latent page and no absorbed projections yet — the "
-                f"prefill / decode steps cache whole keys and values "
-                f"(ROADMAP R5, serving half)")
+                f"prefill / decode steps cache whole keys and values"
+                + (", and the manifest lacks the query latent's two "
+                   "projections, its norm's gain and the scores' factor"
+                   if more else "")
+                + " (ROADMAP R5, serving half)")
         if getattr(unit, "qk_norm", None) == "rms_head":
             raise NotImplementedError(
                 f"{what}: attention layer {i} sets qk_norm=rms_head (a "

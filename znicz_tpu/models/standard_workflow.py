@@ -38,7 +38,7 @@ from znicz_tpu.mutable import Bool
 from znicz_tpu.ops import activation, all2all, conv, cutter, dropout, pooling
 from znicz_tpu.ops import attention, deconv, depooling, lstm, normalization
 from znicz_tpu.ops import delta_net, embedding, layer_norm, moe, pos_encoding
-from znicz_tpu.ops import short_conv
+from znicz_tpu.ops import short_conv, streams
 from znicz_tpu.ops import loop_exits, rms_norm
 from znicz_tpu.ops import seq_reshape
 from znicz_tpu.ops import gd, gd_conv, gd_pooling  # noqa: F401 (pairs)
@@ -114,6 +114,12 @@ for _name, _cls in {
     "gated_mlp": moe.GatedMLP,
     "gated_delta_net": delta_net.GatedDeltaNet,
     "short_conv": short_conv.ShortConv,
+    # a residual path of n streams (ops/streams.py): open, then a READ
+    # and a WRITE around every sublayer, then close
+    "stream_open": streams.StreamOpen,
+    "stream_read": streams.StreamRead,
+    "stream_write": streams.StreamWrite,
+    "stream_close": streams.StreamClose,
     "loop_exits": loop_exits.All2AllExits,
 }.items():
     register_layer_type(_name, _cls)
@@ -258,8 +264,53 @@ class StandardWorkflow(AcceleratedWorkflow):
             if index in member_of:
                 member_of[index].forwards.append(unit)
                 unit.pass_span = member_of[index]
+            if isinstance(unit, streams._Stream):
+                self._link_stream(index, unit, prev)
             self.forwards.append(unit)
             prev = unit
+        unwritten = [i for i, u in enumerate(self.forwards)
+                     if isinstance(u, streams.StreamRead)
+                     and u.write_unit is None]
+        if unwritten:
+            raise ValueError(
+                f"layers {unwritten}: a stream_read with no stream_write "
+                f"after its sublayer — the stream it read would end "
+                f"there")
+
+    def _link_stream(self, index: int, unit, prev) -> None:
+        """The stream units' edges beside the chain's (ops/streams.py):
+        a WRITE is given its READ — the nearest before it that no WRITE
+        has yet — and every READ the OPEN unit's totals; a table that
+        does not pair them is refused here, by index."""
+        wide = (streams.StreamOpen, streams.StreamWrite)
+        if isinstance(unit, (streams.StreamRead, streams.StreamClose)):
+            if not isinstance(prev, wide):
+                raise ValueError(
+                    f"layer {index}: a {self.layers_config[index]['type']} "
+                    f"reads the streams, which a stream_open or a "
+                    f"stream_write hands it; layer {index - 1} is a "
+                    f"{self.layers_config[index - 1]['type'] if index else 'loader'}")
+        if isinstance(unit, streams.StreamRead):
+            opened = next((u for u in reversed(self.forwards)
+                           if isinstance(u, streams.StreamOpen)), None)
+            opened.reads += 1
+            unit.link_attrs(opened, "stream_stats")
+        elif isinstance(unit, streams.StreamWrite):
+            at = next((i for i in range(index - 1, -1, -1)
+                       if isinstance(self.forwards[i], streams._Stream)),
+                      None)
+            read = None if at is None else self.forwards[at]
+            if not isinstance(read, streams.StreamRead):
+                raise ValueError(
+                    f"layer {index}: a stream_write with no stream_read "
+                    f"of its own: the stream unit before it is "
+                    + ("none" if at is None else
+                       f"layer {at}, a {self.layers_config[at]['type']}"))
+            if at == index - 1:
+                raise ValueError(
+                    f"layer {index}: a stream_write with no sublayer "
+                    f"between it and its stream_read, layer {at}")
+            unit.read_unit, read.write_unit = read, unit
 
     def link_evaluator(self, **config) -> None:
         last = self.forwards[-1]
@@ -322,6 +373,16 @@ class StandardWorkflow(AcceleratedWorkflow):
                 unit.link_attrs(span, ("err_output", "err_last"))
             else:
                 unit.link_attrs(next_gd, ("err_output", "err_input"))
+            if getattr(cls, "STREAM_IN", False):
+                # what is n·D wide goes from stream GD to stream GD
+                # (ops/streams.py), not through a Vector
+                if not getattr(type(next_gd), "STREAM_OUT", False):
+                    raise ValueError(
+                        f"layer {len(self.forwards) - 1 - i}: a "
+                        f"{spec['type']} hands the streams on, and the "
+                        f"layer after it is "
+                        f"{'none' if next_gd is None else 'no stream_read or stream_close'}")
+                unit.stream_gd = next_gd
             if span is not None:
                 span.gds.insert(0, unit)
             # train minibatches only (reference: decision.gd_skip)

@@ -676,6 +676,23 @@ def short_conv(unit: str, stat: str) -> Gauge:
         labels=("unit", "stat")).labels(unit=unit, stat=stat)
 
 
+def stream_maps(unit: str, stat: str) -> Gauge:
+    """What the maps of a residual path of n streams did
+    (``ops/streams.py``; ``unit`` is the ``StreamOpen`` unit, which
+    keeps the totals of every READ after it; ``stat`` = ``row_gap`` /
+    ``col_gap``: the worst |row sum − 1| and |column sum − 1| of H_res
+    over the last epoch's tokens and sublayers — how near the doubly
+    stochastic matrices Sinkhorn's iterations came; ``clamped``: the
+    entries of M⁰'s logits the clamp touched, per step; ``sublayers``:
+    the READ / WRITE pairs; ``streams``: n).  Fed from totals kept on
+    the device, read once per epoch."""
+    return REGISTRY.gauge(
+        "znicz_stream_maps",
+        "Sinkhorn's worst row and column gap of H_res, clamped logits "
+        "per step, sublayers and streams of a residual path of n streams",
+        labels=("unit", "stat")).labels(unit=unit, stat=stat)
+
+
 def moe_router(unit: str, stat: str) -> Gauge:
     """The choice of a ``MoE`` unit with ``select_bias`` (``stat`` =
     ``groups_kept``: groups a token's top k are taken among, 0 without

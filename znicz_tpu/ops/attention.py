@@ -81,6 +81,25 @@ rotary width: ONE key of that width is shared by all heads) and
     s_h = (q_nope,h·k_nope,h + q_rope,h·k_r) / √(nope + rope), causal
     o_h = softmax(s_h) v_h ;  y = concat_h(σ((m W_gate)_h) o_h) W_out
 
+With ``q_latent`` (the query latent of the same paper, which Ling
+leaves out; Xing4.0-29B-A4B, PR 46) the queries are up-projected from a
+latent of their own, and ``weights`` holds the two DOWN-projections
+only:
+
+.. code-block:: text
+
+    [c_q | c | k_r] = m W                    ``weights`` (D, q_latent + latent
+                                             + rope)
+    c_q = RMSNorm(c_q)                       gain ``gain_q_latent``
+    [q_nope | q_rope] = c_q W_uq             ``weights_q_up`` (q_latent,
+                                             H·nope + H·rope): all heads'
+                                             q_nope, then all heads' q_rope
+
+``score_scale`` replaces the factor 1/√(nope + rope) of the scores (a
+family that scales the whole score by the square of YaRN's factor and
+leaves the tables alone gives ``rope.yarn`` with ``attention_factor``
+1 and the product here); both absent leave the layer as it was.
+
 The column order of ``weights`` (parts side by side, not per head) is a
 fixed permutation of the published one; the rotation is this module's
 half-split convention, which differs from an interleaved one by a
@@ -279,7 +298,8 @@ class MultiHeadAttention(Forward):
 
     EXPORT_PARAMS = ("weights", "bias", "weights_out", "bias_out",
                      "gain_norm", "gain_q", "gain_k", "weights_head_gate",
-                     "gain_post", "weights_kv_up", "gain_latent")
+                     "gain_post", "weights_kv_up", "gain_latent",
+                     "weights_q_up", "gain_q_latent")
     #: may be a member of a looped span (``znicz_tpu.pass_span``): the
     #: backward needs the forward's input and pullback only
     PASS_SAFE = True
@@ -296,6 +316,8 @@ class MultiHeadAttention(Forward):
                  post_norm: str | None = None,
                  kv_latent: int | None = None, qk_nope: int = 0,
                  qk_rope: int = 0, v_head_dim: int = 0,
+                 q_latent: int | None = None,
+                 score_scale: float | None = None,
                  name=None, **kwargs) -> None:
         # attention defaults to fan-scaled init (the reference's
         # fixed-stddev fillings predate attention entirely)
@@ -374,13 +396,22 @@ class MultiHeadAttention(Forward):
                 ("n_kv_heads", n_kv_heads), ("head_dim", head_dim),
                 ("window", window), ("post_norm", post_norm),
                 ("rope.rotary_dim", rope.get("rotary_dim")),
-                ("rope.yarn", rope.get("yarn")),
                 ("include_bias", kwargs.get("include_bias", True)))
                 if on]
             if refused:
                 raise ValueError(
                     f"kv_latent does not combine with "
                     f"{', '.join(refused)}")
+        elif q_latent is not None or score_scale is not None:
+            raise ValueError("q_latent and score_scale are options of a "
+                             "layer with kv_latent")
+        #: the query latent's width and the scores' factor (module
+        #: docstring): None = the layer as it was
+        self.q_latent = None if q_latent is None else int(q_latent)
+        self.score_scale = None if score_scale is None \
+            else float(score_scale)
+        self.weights_q_up = Vector(name=f"{self.name}.weights_q_up")
+        self.gain_q_latent = Vector(name=f"{self.name}.gain_q_latent")
         self.weights_kv_up = Vector(name=f"{self.name}.weights_kv_up")
         self.gain_latent = Vector(name=f"{self.name}.gain_latent")
         self.weights_head_gate = Vector(
@@ -545,8 +576,11 @@ class MultiHeadAttention(Forward):
         kernels where they tile the call, else the plain core."""
         h, nope, rope = self.n_heads, self.qk_nope, self.qk_rope
         latent, dv = self.kv_latent, self.v_head_dim
+        q_wide = h * (nope + rope)
         for vec, shape in (
-                (self.weights, (d, h * (nope + rope) + latent + rope)),
+                (self.weights, (d, (self.q_latent or q_wide)
+                                + latent + rope)),
+                (self.weights_q_up, (self.q_latent or 0, q_wide)),
                 (self.weights_kv_up, (latent, h * (nope + dv))),
                 (self.weights_out, (h * dv, d)),
                 (self.weights_head_gate, (d, h if self.head_gate else 0))):
@@ -556,6 +590,8 @@ class MultiHeadAttention(Forward):
                                           fan_in=shape[0]))
         if not self.gain_latent:
             self.gain_latent.reset(np.ones(latent, np.float32))
+        if self.q_latent and not self.gain_q_latent:
+            self.gain_q_latent.reset(np.ones(self.q_latent, np.float32))
         if self.pre_norm and not self.gain_norm:
             self.gain_norm.reset(np.ones(d, np.float32))
         self.output.reset(np.zeros((b, t, d),
@@ -564,23 +600,33 @@ class MultiHeadAttention(Forward):
         from znicz_tpu.ops import pallas_mla
         from znicz_tpu.parallel import partition
         self.partition_leaf("output", partition.BATCH)
-        for attr in ("weights_kv_up", "gain_latent"):
+        for attr in ("weights_kv_up", "gain_latent") + (
+                ("weights_q_up", "gain_q_latent") if self.q_latent else ()):
             self.partition_leaf(attr, partition.REPLICATED)
         self._ring_active = False
         self._flash = pallas_mla.plan(self.device, t, h, nope, rope, dv)
         for stat, value in (("latent", latent), ("qk_nope", nope),
-                            ("qk_rope", rope), ("v", dv)):
+                            ("qk_rope", rope), ("v", dv)) + (
+                (("q_latent", self.q_latent),) if self.q_latent else ()):
             obs_metrics.attention_latent(self.name, stat).set(value)
-        self.info("%s: latent K/V of %d (+ %d shared rotary), %d heads, "
+        self.info("%s: latent K/V of %d (+ %d shared rotary)%s, %d heads, "
                   "keys %d + %d, values %d: %s", self.name, latent, rope,
+                  f", query latent of {self.q_latent}"
+                  if self.q_latent else "",
                   h, nope, rope, dv, self._flash.line())
         self.init_vectors(self.input, self.output, self.weights,
                           self.weights_out, self.gain_norm,
                           self.weights_head_gate, self.weights_kv_up,
-                          self.gain_latent)
+                          self.gain_latent, self.weights_q_up,
+                          self.gain_q_latent)
+
+    def _latent_scale(self) -> float:
+        """The factor of a latent layer's scores."""
+        return (self.qk_nope + self.qk_rope) ** -0.5 \
+            if self.score_scale is None else self.score_scale
 
     def _latent_forward(self, x, w, w_out, g_norm, w_gate, w_up,
-                        g_latent):
+                        g_latent, w_q_up=None, g_q_latent=None):
         b, t, d = x.shape
         h, nope, rope = self.n_heads, self.qk_nope, self.qk_rope
         latent, dv = self.kv_latent, self.v_head_dim
@@ -590,14 +636,25 @@ class MultiHeadAttention(Forward):
         rows = m.reshape(b * t, d)
         proj = self.mxu_dot(jnp, rows, w).reshape(b, t, -1)
         at = h * nope
-        c = rms_norm(jnp, proj[..., at + h * rope:at + h * rope + latent],
+        # the queries: columns of the one projection, or up-projected
+        # from their own normed latent
+        wide = h * (nope + rope)
+        if w_q_up is None:
+            q, below = proj, wide
+        else:
+            below = w_q_up.shape[0]
+            c_q = rms_norm(jnp, proj[..., :below], g_q_latent,
+                           self.norm_eps).reshape(b * t, below)
+            q = self.mxu_dot(jnp, c_q, w_q_up).reshape(b, t, wide)
+        c = rms_norm(jnp, proj[..., below:below + latent],
                      g_latent, self.norm_eps).reshape(b * t, latent)
-        cos, sin = rope_tables(jnp, t, rope, self.rope_theta)
-        scale = (nope + rope) ** -0.5
-        q_nope = proj[..., :at] * scale
+        cos, sin = rope_tables(jnp, t, rope, self.rope_theta,
+                               self.rope_yarn)
+        scale = self._latent_scale()
+        q_nope = q[..., :at] * scale
         # the heads' rotary parts turn where they lie (PR 28's view)
         q_rope = apply_rope_rows(
-            jnp, proj[..., at:at + h * rope], cos, sin, h) * scale
+            jnp, q[..., at:wide], cos, sin, h) * scale
         k_rope = apply_rope(jnp, proj[..., None, -rope:], cos,
                             sin).reshape(b, t, rope)
         # two products over the up-projection's column ranges, so that
@@ -637,7 +694,9 @@ class MultiHeadAttention(Forward):
             + ((self.gain_post.devmem,) if self.gain_post
                else (None,) if self.kv_latent else ()) \
             + ((self.weights_kv_up.devmem, self.gain_latent.devmem)
-               if self.kv_latent else ())
+               if self.kv_latent else ()) \
+            + ((self.weights_q_up.devmem, self.gain_q_latent.devmem)
+               if self.q_latent else ())
 
     def _widths(self, d: int) -> tuple:
         """(q width, k/v width, head size) for a model width ``d``."""
@@ -690,10 +749,12 @@ class MultiHeadAttention(Forward):
 
     def xla_forward(self, x, w_qkv, b_qkv, w_out, b_out,
                     g_norm=None, g_q=None, g_k=None, w_gate=None,
-                    g_post=None, w_kv_up=None, g_latent=None):
+                    g_post=None, w_kv_up=None, g_latent=None,
+                    w_q_up=None, g_q_latent=None):
         if w_kv_up is not None:
             return self._latent_forward(x, w_qkv, w_out, g_norm, w_gate,
-                                        w_kv_up, g_latent)
+                                        w_kv_up, g_latent, w_q_up,
+                                        g_q_latent)
         b, t, d = x.shape
         wide = w_qkv.shape[1]
         grouped = self.n_kv_heads != self.n_heads
@@ -1122,19 +1183,26 @@ class MultiHeadAttention(Forward):
         m = rms_norm(np, x, self.gain_norm.mem, self.norm_eps) \
             if self.pre_norm else x
         proj = (m.reshape(b * t, d) @ self.weights.mem).reshape(b, t, -1)
-        at = h * nope
-        c = rms_norm(np, proj[..., at + h * rope:at + h * rope + latent],
+        at, wide = h * nope, h * (nope + rope)
+        below = self.q_latent or wide
+        q = proj
+        if self.q_latent:
+            q = (rms_norm(np, proj[..., :below], self.gain_q_latent.mem,
+                          self.norm_eps).reshape(b * t, below)
+                 @ self.weights_q_up.mem).reshape(b, t, wide)
+        c = rms_norm(np, proj[..., below:below + latent],
                      self.gain_latent.mem, self.norm_eps)
-        cos, sin = rope_tables(np, t, rope, self.rope_theta)
-        scale = (nope + rope) ** -0.5
+        cos, sin = rope_tables(np, t, rope, self.rope_theta,
+                               self.rope_yarn)
+        scale = self._latent_scale()
         q_rope = apply_rope(
-            np, proj[..., at:at + h * rope].reshape(b, t, h, rope),
+            np, q[..., at:wide].reshape(b, t, h, rope),
             cos, sin).reshape(b, t, h * rope) * scale
         k_rope = apply_rope(np, proj[..., None, -rope:], cos,
                             sin).reshape(b, t, rope)
         kv = c.reshape(b * t, latent) @ self.weights_kv_up.mem
         o = latent_attention_plain(
-            proj[..., :at] * scale, q_rope, kv[:, :at].reshape(b, t, at),
+            q[..., :at] * scale, q_rope, kv[:, :at].reshape(b, t, at),
             k_rope, kv[:, at:].reshape(b, t, h * dv), h, xp=np)
         if self.head_gate:
             gate = 1.0 / (1.0 + np.exp(
@@ -1153,7 +1221,9 @@ class MultiHeadAttention(Forward):
             self.bias.map_read()
             self.bias_out.map_read()
         for gain in (self.gain_norm, self.gain_q, self.gain_k,
-                     self.weights_head_gate, self.gain_post):
+                     self.weights_head_gate, self.gain_post,
+                     self.weights_kv_up, self.gain_latent,
+                     self.weights_q_up, self.gain_q_latent):
             if gain:
                 gain.map_read()
         y, _ = self._forward_np(self.input.mem.astype(np.float32))
@@ -1195,11 +1265,17 @@ class GDMultiHeadAttention(GradientDescentBase):
             name=f"{self.name}.acc_gw_kv_up")
         self.accumulated_gradient_gain_latent = Vector(
             name=f"{self.name}.acc_gain_latent")
+        # … and a query latent's up-projection and norm gain
+        self.accumulated_gradient_weights_q_up = Vector(
+            name=f"{self.name}.acc_gw_q_up")
+        self.accumulated_gradient_gain_q_latent = Vector(
+            name=f"{self.name}.acc_gain_q_latent")
         self._host_pullback = None
 
     #: ``_gain_pairs``' suffixes, in the order ``forward_args`` hands
     #: the gains (and the latent's two parameters) to the forward
-    _GAINS = ("norm", "q", "k", "head_gate", "post", "kv_up", "latent")
+    _GAINS = ("norm", "q", "k", "head_gate", "post", "kv_up", "latent",
+              "q_up", "q_latent")
 
     def _gain_pairs(self) -> list:
         """``(suffix, parameter Vector, its accumulator)`` for the
@@ -1221,6 +1297,11 @@ class GDMultiHeadAttention(GradientDescentBase):
                           self.accumulated_gradient_weights_kv_up))
             pairs.append(("latent", fwd.gain_latent,
                           self.accumulated_gradient_gain_latent))
+        if fwd.weights_q_up:
+            pairs.append(("q_up", fwd.weights_q_up,
+                          self.accumulated_gradient_weights_q_up))
+            pairs.append(("q_latent", fwd.gain_q_latent,
+                          self.accumulated_gradient_gain_q_latent))
         return pairs
 
     def initialize(self, device=None, **kwargs) -> None:

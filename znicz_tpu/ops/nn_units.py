@@ -74,8 +74,13 @@ def family_of(unit) -> tuple[str, bool]:
     class it is paired with (``MATCHES``), a forward unit's the class
     the pairing registered (its own, or the parent it inherits the
     pairing from), so a layer's two units share one; any other unit
-    (loader, evaluator, guard) is its own family."""
+    (loader, evaluator, guard) is its own family.  Units of several
+    classes that are one mechanism (the stream units) name it
+    themselves (``FAMILY``)."""
     backward = isinstance(unit, GradientDescentBase)
+    named = getattr(type(unit), "FAMILY", None)
+    if named:
+        return named, backward
     for klass in type(unit).__mro__:
         if backward and klass.__dict__.get("MATCHES"):
             return klass.MATCHES[0].__name__, True

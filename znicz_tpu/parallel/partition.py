@@ -220,6 +220,8 @@ _BATCH_SLOTS = (
     r"minibatch_data", r"minibatch_labels", r"minibatch_indices",
     r"minibatch_raw", r"mask", r"max_idx", r"winners", r"input",
     r"reconstruction", r"targets", r"last_choice", r"router_logits",
+    # the two maps a stream READ unit keeps for its WRITE (PR 46)
+    r"h_post", r"h_res",
 )
 #: replicated persistent / host-bookkeeping state: parameters,
 #: momentum (non-ZeRO-1 — the ZeRO-1 allocator declares overrides),
@@ -235,6 +237,8 @@ _REPLICATED_SLOTS = (
     # the pre-norm block's gains, the expert layer's weight slabs and
     # its routing totals (PR 25)
     r"gain_\w+", r"weights_\w+", r"moe_stats",
+    # the stream maps' biases and scalars, and what Sinkhorn reached
+    r"maps_\w+", r"stream_stats",
 )
 
 
