@@ -188,7 +188,8 @@ def kda(dot_dtype):
                                           dot_dtype=dot_dtype)
 
 
-def compile_step(t: int, workload: str = "ling_train_1of64") -> int:
+def compile_step(t: int, workload: str = "ling_train_1of64",
+                 text_to: str | None = None) -> int:
     """A cell's step program (this cell's, or another LM cell's of the
     ``train_lm`` driver) at sequence length ``t``, compiled for a
     described v5e: what the compiler says it needs."""
@@ -230,7 +231,8 @@ def compile_step(t: int, workload: str = "ling_train_1of64") -> int:
                     setattr(unit, flag, False)
         line = {"workload": workload, "t": t}
         try:
-            line.update(compile_for_described_chip(wf), loads=True)
+            line.update(compile_for_described_chip(wf, text_to),
+                        loads=True)
         except Exception as exc:  # noqa: BLE001 — the refusal is the result
             said = str(exc)
             at = said.find("RESOURCE_EXHAUSTED")
@@ -248,6 +250,9 @@ def main() -> int:
     parser.add_argument("--t", type=int, default=4096)
     parser.add_argument("--workload", default="ling_train_1of64",
                         help="the cell whose step --compile-step compiles")
+    parser.add_argument("--text", metavar="FILE",
+                        help="where --compile-step writes the compiled "
+                             "program's text")
     parser.add_argument("--only", choices=("kda", "mla", "prep"),
                         nargs="+", default=("kda", "mla", "prep"))
     parser.add_argument("--rows", type=int, nargs="+",
@@ -256,7 +261,7 @@ def main() -> int:
     args = parser.parse_args()
     if args.compile_step:
         os.environ.setdefault("TPU_LOG_DIR", "disabled")
-        return compile_step(args.t, args.workload)
+        return compile_step(args.t, args.workload, args.text)
     if args.compile_only:
         os.environ.setdefault("TPU_LOG_DIR", "disabled")
         from jax.experimental import topologies

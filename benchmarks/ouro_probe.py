@@ -145,7 +145,7 @@ def run_on_chip(wf, epochs: int, steps: int) -> dict:
     return out
 
 
-def compile_for_described_chip(wf) -> dict:
+def compile_for_described_chip(wf, text_to: str | None = None) -> dict:
     import jax
     import numpy as np
     from jax.experimental import topologies
@@ -172,12 +172,15 @@ def compile_for_described_chip(wf) -> dict:
         int(np.prod(s.shape)) * s.dtype.itemsize for s in structs) / 1e9,
         3)}
     t0 = time.perf_counter()
-    # as the region jits it (``engine.keep_written_leaves`` included)
+    # as the region jits it (the write-only leaves kept)
     lowered = JitRegion._jit(body, True, len(structs)).lower(*structs)
     t1 = time.perf_counter()      # tracing and lowering: what a warm
     compiled = lowered.compile()  # compile cache does not hide
     stats = compiled.memory_analysis()
     text = compiled.as_text()
+    if text_to:                   # the compiled program, to be read
+        with open(text_to, "w") as f:
+            f.write(text)
     out.update(
         lower_s=round(t1 - t0, 1),
         compile_s=round(time.perf_counter() - t0, 1),

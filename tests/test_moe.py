@@ -806,6 +806,419 @@ def test_accumulation_keeps_its_pass_and_the_gauge_says_so(kernel):
 
 
 # ----------------------------------------------------------------------
+# a slab's copy in the matmuls' dtype: a leaf the update writes (PR 47)
+# ----------------------------------------------------------------------
+COPIES = tuple(zip(SLABS, moe.MoE.SLAB_COPIES))
+FORMS = {
+    # four of eight held: the fit size 40, the capacity all 64 pairs
+    "held_two_lengths": (NEAR, None),
+    "held_one_length": (NEAR, moe.HELD_SLACK),
+    "dropless": ({}, None),
+}
+
+
+def bf16_matmuls() -> None:
+    root.common.precision_type = "bfloat16"
+
+
+def cast_in_the_step(monkeypatch) -> None:
+    """The layer as it was before it kept copies: the matmuls are
+    handed the plain slabs and cast them where they read them."""
+    monkeypatch.setattr(moe.MoE, "_keep_slab_copies", lambda self: None)
+
+
+def host(vec) -> np.ndarray:
+    vec.map_read()
+    return np.array(vec.mem)
+
+
+def assert_copies_are_the_casts(fwd) -> None:
+    import ml_dtypes
+    for attr, kept in COPIES:
+        slab, copy = getattr(fwd, attr), getattr(fwd, kept)
+        assert slab.cast_copy is copy and copy.dtype == ml_dtypes.bfloat16
+        np.testing.assert_array_equal(
+            host(copy), host(slab).astype(ml_dtypes.bfloat16),
+            err_msg=kept)
+
+
+def everything_of(fwd, gd_u) -> dict:
+    return {**state_of(fwd, gd_u),
+            "output": host(fwd.output).astype(np.float32),
+            "err_input": host(gd_u.err_input).astype(np.float32)}
+
+
+@pytest.mark.parametrize("kernel", [True, False],
+                         ids=["kernels_interpreted", "ragged_dot"])
+@pytest.mark.parametrize("form", list(FORMS))
+def test_the_update_writes_the_copy_and_the_steps_are_bitwise_the_steps_that_cast(
+        form, kernel, monkeypatch):
+    """bf16 matmuls, a dropless layer and a held one at two lengths
+    and at one: after each of three momentum steps a slab's copy IS the
+    slab cast, and the output, the input's gradient, every parameter,
+    every momentum and the guard's flags are, bit for bit, those of the
+    same steps with the plain slabs cast where the matmuls read them;
+    the gauge says which of the two the program reads."""
+    options, fit = FORMS[form]
+    if fit is not None:
+        monkeypatch.setattr(moe, "HELD_FIT", fit)
+    rng = np.random.default_rng(9)
+    x = rng.normal(0, 1.0, (4, T, D)).astype(np.float32)
+    err = rng.normal(0, 0.1, (4, T, D)).astype(np.float32)
+    runs = []
+    for copies in (True, False):
+        reset_engine(kernel)
+        if not copies:
+            cast_in_the_step(monkeypatch)
+        fwd, gd_u = build(XLADevice(), x, guard=True, **options)
+        fwd.name = f"moe_copy_{form}_{kernel}_{copies}"
+        assert fwd._gmm_kernel == kernel
+        if options:
+            assert (fwd._fit, fwd._capacity) == (64 if fit else 40, 64)
+        runs.append([])
+        for _ in range(3):
+            step(fwd, gd_u, err)
+            if copies:
+                assert_copies_are_the_casts(fwd)
+            runs[-1].append(everything_of(fwd, gd_u))
+        assert obs_metrics.moe_slab_copy(fwd.name, "slabs").value == \
+            (3 if copies else 0)
+        assert obs_metrics.moe_slab_copy(fwd.name,
+                                         "refreshed").value == 0
+    for mine, want in zip(*runs):
+        assert set(mine) == set(want) and len(want) == 13
+        for key in want:
+            np.testing.assert_array_equal(mine[key], want[key],
+                                          err_msg=key)
+    first, last = runs[0][0], runs[0][-1]
+    for attr in SLABS:            # and every slab MOVED, every step
+        assert np.abs(last[attr] - first[attr]).max() > 0, attr
+
+
+def reset_engine(kernel: bool) -> None:
+    from znicz_tpu.utils.config import reset_root
+    reset_root()
+    bf16_matmuls()
+    if kernel:
+        kernels_interpreted()
+
+
+def test_a_step_the_guard_refuses_leaves_the_slab_and_its_copy():
+    """A non-finite gradient: the guard's flag goes down and every
+    slab, its momentum AND its copy stay what they were, bit for bit;
+    the next finite step is refused likewise (the flag is the
+    run's)."""
+    bf16_matmuls()
+    x, err = _data(4)
+    fwd, gd_u = build(XLADevice(), x, guard=True)
+    step(fwd, gd_u, err)
+    before = {**state_of(fwd, gd_u),
+              **{kept: host(getattr(fwd, kept)) for _, kept in COPIES}}
+    assert before["flags"][0] == 1.0
+    bad = err.copy()
+    bad[0, 0, 0] = np.inf
+    step(fwd, gd_u, bad)
+    after = {**state_of(fwd, gd_u),
+             **{kept: host(getattr(fwd, kept)) for _, kept in COPIES}}
+    assert after["flags"][0] == 0.0
+    for key in before:
+        if key != "flags":
+            np.testing.assert_array_equal(after[key], before[key],
+                                          err_msg=key)
+    assert_copies_are_the_casts(fwd)
+
+
+def test_float32_matmuls_keep_no_copy_and_the_leaves_are_the_parents(
+        monkeypatch):
+    """The copy exists by dtype: under float32 matmuls the layer holds
+    no such leaf, its programs' leaves are those of the layer that
+    never kept one, and the matmuls are handed the plain slabs."""
+    x, err = _data(2)
+    leaves = []
+    for copies in (True, False):
+        if not copies:
+            cast_in_the_step(monkeypatch)
+        fwd, gd_u = build(XLADevice(), x)
+        assert fwd.mxu_dtype is None
+        for attr, kept in COPIES:
+            assert getattr(fwd, attr).cast_copy is None
+            assert not getattr(fwd, kept)
+        assert not any(isinstance(arg, tuple)
+                       for arg in fwd.forward_args())
+        step(fwd, gd_u, err)
+        assert obs_metrics.moe_slab_copy(fwd.name, "slabs").value == 0
+        leaves.append([[vec.name for vec in unit.region_vectors()]
+                       for unit in (fwd, gd_u)])
+    assert leaves[0] == leaves[1]
+    assert not any("_cast" in name for unit in leaves[0] for name in unit)
+    # … and under bf16 matmuls there are the three, no leaf else
+    bf16_matmuls()
+    monkeypatch.undo()
+    fwd, gd_u = build(XLADevice(), x)
+    more = [[vec.name for vec in unit.region_vectors()]
+            for unit in (fwd, gd_u)]
+    for had, has in zip(leaves[0], more):
+        assert sorted(set(has) - set(had)) == sorted(
+            f"MoE.{kept}" for kept in moe.MoE.SLAB_COPIES)
+        assert set(had) <= set(has)
+
+
+def test_a_host_only_device_keeps_no_copy():
+    bf16_matmuls()
+    fwd, _ = build(NumpyDevice(), _data()[0])
+    assert all(getattr(fwd, attr).cast_copy is None for attr in SLABS)
+    assert not any(getattr(fwd, kept) for kept in moe.MoE.SLAB_COPIES)
+
+
+def another_epoch(wf) -> None:
+    wf.decision.max_epochs += 1
+    wf.decision.complete.value = False
+    wf.run()
+
+
+def moe_pair(wf):
+    layer = next(u for u in wf.forwards if isinstance(u, moe.MoE))
+    return layer, next(g for g in wf.gds if g.forward_unit is layer)
+
+
+def slab_writes_to_bf16(wf) -> dict:
+    """``{(unit, inside its update): count}`` of the step program's
+    ``convert`` instructions that write an expert slab's shape in bf16
+    — compiled, read from the text as ``observe.op_scopes()`` reads
+    it; under ``None`` those the compiler made itself, with no scope
+    (the bf16 momentum's store, after the guard's select)."""
+    import collections
+    import re
+    import jax
+    from znicz_tpu.observe import scopes
+    region = wf._region_unit.region
+    for vec in region._vectors:
+        vec.unmap()
+    leaves = [vec.devmem for vec in region._vectors]
+    try:
+        text = jax.jit(region.build_callable(
+            tuple(bool(u.gate_skip) for u in region.units))).lower(
+                *leaves).compile().as_text()
+    finally:
+        for vec, leaf in zip(region._vectors, leaves):
+            vec._devmem = leaf
+    names = [unit.name for unit in region.units]
+    slab = re.compile(
+        rf"= bf16\[{E},(?:{D},{F}|{F},{D})\]\S* convert\(")
+    found = collections.Counter()
+    for line in text.splitlines():
+        if slab.search(line):
+            scoped = scopes._OP_NAME.search(line)
+            at = scoped and scopes.scope_of(scoped.group(1), names)
+            found[at and (names[at[0]], at[1])] += 1
+    return dict(found)
+
+
+def test_the_compiled_step_casts_no_slab_outside_its_update(
+        monkeypatch):
+    """The chain's compiled step program under bf16 matmuls, the
+    kernels interpreted: every ``convert`` that writes a slab's shape
+    in bf16 lies in the update of the expert layer's backward unit —
+    the copies' three writes beside the bf16 momentum's —, none in the
+    layer's forward or backward; the layer that casts in the step has
+    its three there and three fewer in the update.  (On the
+    ``ragged_dot`` path the weight gradient is rounded to bf16 in the
+    slab's shape inside the backward either way: the cast's own
+    transpose.)"""
+    reset_engine(True)
+    _, wf = train("run", 4, aux=0.01, epochs=1)
+    layer, gd_u = moe_pair(wf)
+    assert layer._gmm_kernel
+    assert obs_metrics.moe_slab_copy(layer.name, "slabs").value == 3
+    kept = slab_writes_to_bf16(wf)
+    assert set(kept) == {(gd_u.name, True), None}, kept
+    cast_in_the_step(monkeypatch)
+    _, wf = train("run", 4, aux=0.01, epochs=1)
+    layer, gd_u = moe_pair(wf)
+    assert obs_metrics.moe_slab_copy(layer.name, "slabs").value == 0
+    cast = slab_writes_to_bf16(wf)
+    assert cast == {(gd_u.name, True): kept[(gd_u.name, True)] - 3,
+                    None: kept[None], (layer.name, False): 3}, cast
+
+
+def test_host_writes_of_a_slab_reach_its_copy_before_the_next_program(
+        monkeypatch):
+    """A restored slab (``load_state``), the guard's seeded flip and a
+    plain ``map_write`` … ``unmap``: each is followed by steps whose
+    copy is the cast of the slab written (the slab's upload made it
+    again — counted), and the run stays, bit for bit, the run of the
+    layer that casts in the step.  What a workflow saves, folds into
+    its SDC fingerprint and counts as parameters is what it was."""
+    from types import SimpleNamespace
+    from znicz_tpu.publishing import _layer_rows
+    from znicz_tpu.resilience.guard import AnomalyGuard
+    bf16_matmuls()
+    runs = []
+    for copies in (True, False):
+        if not copies:
+            cast_in_the_step(monkeypatch)
+        _, wf = train("run", 4, aux=0.01, epochs=1)
+        layer, gd_u = moe_pair(wf)
+        refreshed = obs_metrics.moe_slab_copy(layer.name, "refreshed")
+        assert refreshed.value == 0 or not copies
+
+        def flip():
+            AnomalyGuard._host_flip_param(SimpleNamespace(
+                workflow=SimpleNamespace(gds=[SimpleNamespace(
+                    weights=layer.weights_up)]),
+                device=wf.device, warning=lambda *args: None), 1.5)
+
+        def edit():
+            layer.weights_down.map_write()
+            layer.weights_down.mem[1] *= 0.5
+            layer.weights_down.unmap()
+
+        for count, write in enumerate((
+                lambda: layer.load_state(
+                    {"weights_gate": 1.25 * host(layer.weights_gate)}),
+                flip, edit), 1):
+            write()
+            another_epoch(wf)
+            if copies:
+                assert_copies_are_the_casts(layer)
+                assert refreshed.value == count
+        units = list(wf.forwards) + list(wf.gds)
+        runs.append({
+            "saved": {u.name: sorted(u.state_dict()) for u in units},
+            "folded": [vec.name for g in wf.gds
+                       for vec in g._fp_folded.values()],
+            "counted": [row["parameters"] for row in _layer_rows(wf)],
+            "params": {f"{u.name}.{attr}": host(getattr(u, attr))
+                       for u in wf.forwards for attr in u.EXPORT_PARAMS
+                       if getattr(u, attr)}})
+    assert not any("_cast" in name for names in
+                   runs[0]["saved"].values() for name in names)
+    assert not any("_cast" in name for name in runs[0]["folded"])
+    for key in ("saved", "folded", "counted"):
+        assert runs[0][key] == runs[1][key], key
+    assert len(runs[0]["params"]) == 13
+    for key, want in runs[1]["params"].items():
+        np.testing.assert_array_equal(runs[0]["params"][key], want,
+                                      err_msg=key)
+
+
+def test_a_forward_only_program_reads_the_copy_of_the_slab_written(
+        monkeypatch):
+    """A forward with no backward (validation) after a host write of a
+    slab: its output is the output of the layer that casts the slab it
+    reads."""
+    bf16_matmuls()
+    x, err = _data(8)
+    got = []
+    for copies in (True, False):
+        if not copies:
+            cast_in_the_step(monkeypatch)
+        fwd, gd_u = build(XLADevice(), x)
+        step(fwd, gd_u, err)
+        fwd.weights_gate.map_write()
+        fwd.weights_gate.mem[...] *= -1.5
+        fwd.weights_gate.unmap()
+        fwd.run()
+        if copies:
+            assert_copies_are_the_casts(fwd)
+        got.append(host(fwd.output))
+    np.testing.assert_array_equal(got[0], got[1])
+
+
+def copy_writes(body, leaves, shapes) -> int:
+    """Equations of a traced body, under the scope ``update``, that
+    cast f32 of one of ``shapes`` to bf16 (elsewhere the trace holds
+    the casts ``_kept`` leaves dead)."""
+    import jax
+    import jax.numpy as jnp
+    from tests.test_integrity import _all_eqns
+    return sum(
+        eqn.primitive.name == "convert_element_type"
+        and eqn.params["new_dtype"] == jnp.bfloat16
+        and eqn.invars[0].aval.dtype == jnp.float32
+        and eqn.invars[0].aval.shape in shapes
+        and "update" in str(eqn.source_info.name_stack)
+        for eqn in _all_eqns(jax.make_jaxpr(body)(*leaves).jaxpr))
+
+
+def test_an_accumulated_step_writes_the_copy_once_with_the_parameter():
+    """``run_accum`` with two microbatches: the body of the microbatch
+    that only sums gradients passes slabs and copies through untouched
+    (the very leaves that came in), the one that applies writes each
+    copy once; after the run a copy is its slab cast, and the drivers
+    agree as they do without copies."""
+    reset_engine(True)        # (``ragged_dot`` rounds a slab's gradient
+    root.common.engine.grad_accum = 2            # to bf16 besides)
+    root.common.engine.bf16_optimizer_state = False   # no cast but the
+    _, wf = train("accumulated", 4, aux=0.0)           # copies'
+    layer, _ = moe_pair(wf)
+    assert_copies_are_the_casts(layer)
+    region = wf._region_unit.region
+    for vec in region._vectors:
+        vec.unmap()
+    leaves = [vec.devmem for vec in region._vectors]
+    skips = tuple(bool(u.gate_skip) for u in region.units)
+    slabs = {(E, D, F), (E, F, D)}
+    kept = [i for i, vec in enumerate(region._vectors)
+            if any(vec is getattr(layer, name)
+                   for pair in COPIES for name in pair)]
+    assert len(kept) == 6
+    import jax
+    closed = jax.make_jaxpr(region.build_callable(
+        skips, accum_phase=("accum", 2)))(*leaves)
+    for i in kept:
+        assert closed.jaxpr.outvars[i] is closed.jaxpr.invars[i]
+    for vec, leaf in zip(region._vectors, leaves):
+        vec._devmem = leaf
+    assert copy_writes(region.build_callable(
+        skips, accum_phase=("apply", 2)), leaves, slabs) == 3
+
+
+def test_a_looped_span_writes_a_kept_cast_once_with_the_parameter():
+    """No expert layer may loop, so the rule is shown where it lives,
+    on the update: a gated MLP inside a span of two passes whose
+    ``weights_up`` keeps a bf16 cast (``Vector.keep_cast``) — the step
+    casts it ONCE, where the summed gradient is applied, and the copy
+    is the updated matrix cast."""
+    import ml_dtypes
+    gd = {"learning_rate": 0.05, "gradient_moment": 0.9}
+    table = olmoe_layers(0.0)
+    table[2] = {"type": "gated_mlp", "passes": 2,
+                "->": {"width": F, "pre_norm": "rms", "residual": True},
+                "<-": gd}
+    del table[1]
+    rng = np.random.default_rng(6)
+    ids = rng.integers(0, VOCAB, (4, SEQ + 1))
+    prng.seed_all(21)
+    wf = StandardWorkflow(
+        name="kept_cast_span",
+        loader_factory=lambda w: ArrayLoader(
+            w, train_data=ids[:, :-1].astype(np.float32),
+            train_labels=ids[:, 1:].astype(np.int32),
+            minibatch_size=4, shuffle_limit=0),
+        layers=table, decision_config={"max_epochs": 1})
+    wf.initialize(device=XLADevice())
+    assert wf.pass_spans and wf.pass_spans[0].passes == 2
+    mlp = next(u for u in wf.forwards if isinstance(u, moe.GatedMLP))
+    copy = Vector(name=f"{mlp.name}.weights_up_cast")
+    mlp.weights_up.keep_cast(copy, ml_dtypes.bfloat16)
+    drawn = host(mlp.weights_up)
+    wf.run()
+    moved = host(mlp.weights_up)
+    assert np.abs(moved - drawn).max() > 0
+    np.testing.assert_array_equal(host(copy),
+                                  moved.astype(ml_dtypes.bfloat16))
+    region = wf._region_unit.region
+    assert any(vec is copy for vec in region._vectors)
+    for vec in region._vectors:
+        vec.unmap()
+    assert copy_writes(
+        region.build_callable(tuple(bool(u.gate_skip)
+                                    for u in region.units)),
+        [vec.devmem for vec in region._vectors], {(D, F)}) == 1
+
+
+# ----------------------------------------------------------------------
 # serving refuses what it cannot run (ROADMAP R1, serving half)
 # ----------------------------------------------------------------------
 def test_export_and_decode_refuse_the_expert_layer_and_the_block():

@@ -384,3 +384,57 @@ def test_ensemble_stacked_matches_sequential():
     assert got["ensemble_err_pt"] == want["ensemble_err_pt"]
     assert [s["validation_err_pt"] for s in stacked.member_stats] == \
         [s.get("validation_err_pt") for s in seq.member_stats]
+
+
+class _KeepsCast(StandardWorkflow):
+    """``build``'s net whose first layer's weights keep a bf16 cast of
+    themselves (``Vector.keep_cast``), a leaf of that layer's backward
+    unit."""
+
+    def initialize(self, device=None, **kwargs) -> None:
+        import ml_dtypes
+        from znicz_tpu.memory import Vector
+        super().initialize(device=device, **kwargs)
+        if self.device.is_host_only:
+            return
+        gd_unit = self.gds[0]
+        gd_unit.weights_cast = Vector(name=f"{gd_unit.name}.weights_cast")
+        self.forwards[0].weights.keep_cast(gd_unit.weights_cast,
+                                           ml_dtypes.bfloat16)
+
+
+def test_a_kept_cast_stays_the_cast_through_steps_writes_and_blends(
+        monkeypatch):
+    """A leaf that keeps a cast of itself keeps it in a population:
+    after vmapped steps, after a GA generation (whose blend of two
+    members' casts is not their blend's cast) and after a host write
+    of the stacked leaf, every member's copy is its weights cast."""
+    import ml_dtypes
+    import znicz_tpu.models.standard_workflow as sw
+    monkeypatch.setattr(sw, "StandardWorkflow", _KeepsCast)
+    monkeypatch.setattr("test_population.StandardWorkflow", _KeepsCast)
+    trainer = PopulationTrainer(
+        build, 4, base_seed=910, evolve="ga", evolve_every=1, elite=1,
+        lr_bounds=(0.005, 0.5), seed=3, name="pop_cast")
+    trainer.initialize()
+    region = trainer.region
+    weights = trainer.template.forwards[0].weights
+    copy = weights.cast_copy
+    assert region.svec(weights).cast_copy is region.svec(copy)
+
+    def assert_cast() -> np.ndarray:
+        stacked = np.array(region.read_leaf(weights), copy=True)
+        np.testing.assert_array_equal(
+            region.read_leaf(copy), stacked.astype(ml_dtypes.bfloat16))
+        return stacked
+
+    drawn = assert_cast()
+    trainer.run_epoch()
+    trained = assert_cast()
+    assert np.abs(trained - drawn).max() > 0
+    trainer.evolve_generation(np.array([0.0, 5.0, 1.0, 2.0]))
+    blended = assert_cast()
+    assert np.abs(blended - trained).max() > 0
+    region.write_leaf(weights, 0.5 * blended)
+    region.step()
+    assert np.abs(assert_cast() - blended).max() > 0

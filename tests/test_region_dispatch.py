@@ -166,30 +166,31 @@ def test_store_is_keyed_on_the_variant_tag_and_reloads_it(
 
 
 # ----------------------------------------------------------------------
-# engine.keep_written_leaves (PR 37): the leaves a step only writes
-# stay in the program's signature and lend their buffers to their
-# successors
+# The leaves a step only writes stay in a donated program's signature
+# and lend their buffers to their successors (an option since PR 37,
+# every donated program since PR 47)
 # ----------------------------------------------------------------------
-def _trained(name: str, keep: bool, store: str | bool = False):
-    """A toy after three hand-driven steps, its step program's compiled
-    text and its weights."""
+def _trained(name: str, variant: str = "step", store: str | bool = False):
+    """A toy after three hand-driven steps, its step program's lowered
+    text donated and not, and its weights."""
     import numpy as np
 
-    root.common.engine.keep_written_leaves = keep
-    toy = Toy(name, "step", store=store)
+    toy = Toy(name, variant, store=store)
     leaves = [vec._devmem for vec in toy.region._collect_vectors()]
     structs = [(leaf.shape, leaf.dtype) for leaf in leaves]
     for _ in range(3):
         toy.call()
     # a re-trace leaves tracers in the Vectors: they are put back
     held = [(vec, vec._devmem) for vec in toy.region._vectors]
+    texts = {}
     try:
-        text = JitRegion._jit(
-            toy.region.build_callable(
-                tuple(bool(u.gate_skip) for u in toy.region.units)),
-            True, len(structs)).lower(*[
-                np.zeros(shape, dtype)
-                for shape, dtype in structs]).as_text()
+        for donate in (True, False):
+            texts[donate] = JitRegion._jit(
+                toy.region.build_callable(
+                    tuple(bool(u.gate_skip) for u in toy.region.units)),
+                donate, len(structs)).lower(*[
+                    np.zeros(shape, dtype)
+                    for shape, dtype in structs]).as_text()
     finally:
         for vec, leaf in held:
             vec._devmem = leaf
@@ -197,49 +198,74 @@ def _trained(name: str, keep: bool, store: str | bool = False):
     for unit in toy.wf.forwards:
         unit.weights.map_read()
         weights.append(np.array(unit.weights.mem))
-    return toy, len(structs), text, weights
+    return toy, len(structs), texts, weights
 
 
-@pytest.mark.parametrize("keep", [False, True], ids=["pruned", "kept"])
-def test_a_leaf_the_step_only_writes_is_a_parameter_only_when_kept(keep):
-    """Off (the default) jit drops the write-only leaves from the
-    program; on, every leaf is a parameter and every one is aliased to
-    an output — the old buffer IS the new one."""
+@pytest.mark.parametrize("donate", [False, True],
+                         ids=["undonated", "donated"])
+def test_a_leaf_the_step_only_writes_is_a_parameter_where_donated(donate):
+    """Undonated, jit drops the write-only leaves from the program
+    (nothing could take their buffers); donated, every leaf is a
+    parameter and every one is aliased to an output — the old buffer
+    IS the new one."""
     import re
-    _, n_leaves, text, _ = _trained(f"kept_{keep}", keep)
+    _, n_leaves, texts, _ = _trained(f"kept_{donate}")
     (signature,) = re.findall(r"func\.func public @main\((.*?)\) ->",
-                              text, re.S)
+                              texts[donate], re.S)
     params = signature.count("%arg")
     aliased = signature.count("tf.aliasing_output")
-    if keep:
+    if donate:
         assert params == n_leaves and aliased == n_leaves
     else:
-        assert aliased == params < n_leaves
+        assert aliased == 0 and params < n_leaves
 
 
 def test_kept_leaves_train_the_same_weights():
+    """The donated program, every leaf kept, and the undonated one,
+    its write-only leaves dropped, train the same weights."""
     import numpy as np
     from znicz_tpu.utils import prng
-    _, _, _, pruned = _trained("kept_same_a", False)
+    _, _, _, kept = _trained("kept_same_a", "step")
     prng.seed_all(1234)
-    _, _, _, kept = _trained("kept_same_b", True)
+    _, _, _, pruned = _trained("kept_same_b", "nodonate")
     for a, b in zip(pruned, kept):
         np.testing.assert_array_equal(a, b)
 
 
 def test_the_store_keys_a_kept_program_apart(tmp_path, monkeypatch):
-    """Same body, another signature: a program stored without the
-    option is not the one loaded with it."""
+    """Same body, another signature: the donated program, which keeps
+    every leaf, is not stored under the key a store of before PR 47
+    holds the pruned one under."""
     monkeypatch.delenv("ZNICZ_AOT_CACHE", raising=False)
     aot_cache._caches.clear()
+    keys = []
+    real = aot_cache.jaxpr_key
+
+    def spy(fn, leaves, extra=()):
+        keys.append((real(fn, leaves, extra=extra),
+                     real(fn, leaves, extra=tuple(
+                         e for e in extra if e != "keep_written_leaves"))))
+        return keys[-1][0]
+
+    monkeypatch.setattr(aot_cache, "jaxpr_key", spy)
     try:
-        store = str(tmp_path / "store")
-        _trained("kept_store", False, store=store)
-        one = {key for key, _ in aot_cache.active_cache().entries()}
-        from znicz_tpu.utils import prng
-        prng.seed_all(1234)
-        _trained("kept_store", True, store=store)
-        two = {key for key, _ in aot_cache.active_cache().entries()}
-        assert len(one) == 1 and len(two) == 2 and one < two
+        _trained("kept_store", store=str(tmp_path / "store"))
+        stored = {key for key, _ in aot_cache.active_cache().entries()}
+        ((key, bare),) = set(keys)
+        assert stored == {key} and key != bare
     finally:
         aot_cache._caches.clear()
+
+
+@pytest.mark.parametrize("asked", [False, True])
+def test_the_old_option_is_accepted_and_decides_nothing(asked):
+    """``engine.keep_written_leaves`` (PR 37; two traffic mixes still
+    set it) changes no program: a donated one keeps its leaves
+    whatever it says."""
+    root.common.engine.keep_written_leaves = asked
+    try:
+        _, n_leaves, texts, _ = _trained(f"kept_asked_{asked}")
+        assert texts[True].count("tf.aliasing_output") == n_leaves
+        assert texts[False].count("tf.aliasing_output") == 0
+    finally:
+        del root.common.engine.keep_written_leaves

@@ -337,24 +337,18 @@ class JitRegion(Logger):
     def _jit(body, donate: bool, n_leaves: int):
         """``jax.jit`` of a region body over its leaves.
 
-        ``root.common.engine.keep_written_leaves`` (default off) keeps
-        in the program's signature the leaves a step only WRITES (unit
-        outputs, errors): jit drops an argument the body never reads,
-        donated or not, so the old value's buffer outlives the dispatch
-        beside the new value's — and the compiler plans its temporaries
+        A donated program keeps in its signature the leaves a step only
+        WRITES (unit outputs, errors, a slab's copy in its readers'
+        dtype): jit drops an argument the body never reads, donated or
+        not, so the old value's buffer would outlive the dispatch
+        beside the new value's — and the compiler plan its temporaries
         against memory that is not free (a program that compiles and
-        does not load: PERF.md §6, PR 35 and PR 37).  Kept, a donated
-        leaf lends its buffer to its successor, and the compiler's
-        count of the chip is the runtime's."""
+        does not load: PERF.md §6, PR 35, PR 37 and PR 47).  Kept, a
+        donated leaf lends its buffer to its successor, and the
+        compiler's count of the chip is the runtime's."""
         return jax.jit(
             body, donate_argnums=tuple(range(n_leaves)) if donate else (),
-            keep_unused=JitRegion._keeps_written(donate))
-
-    @staticmethod
-    def _keeps_written(donate: bool) -> bool:
-        from znicz_tpu.utils.config import root
-        return donate and bool(
-            root.common.engine.get("keep_written_leaves", False))
+            keep_unused=donate)
 
     def _dispatch(self, variant: tuple, build, span: str,
                   count: int = 1, donate: bool = True,
@@ -475,6 +469,11 @@ class JitRegion(Logger):
         _metrics.region_steps(self.name).inc(count)
         for vec, leaf in zip(vectors, out):
             vec.devmem = leaf
+        if checks:
+            # a unit that wrote a Vector on the device and left the
+            # cast kept of it behind is named here
+            for vec in vectors:
+                vec.check_cast()
 
     def _remember(self, body, jitted, structs, donate: bool) -> None:
         """Hand ``observe.op_scopes()`` what it needs to read this
@@ -811,10 +810,10 @@ class JitRegion(Logger):
         if cache is None:
             return None
         site = f"region:{self.name}"
-        # another program under ``engine.keep_written_leaves``: more
-        # parameters, each aliased to an output
-        kept = ("keep_written_leaves",) \
-            if self._keeps_written(donate) else ()
+        # a donated program has every leaf for a parameter, each
+        # aliased to an output: not the program a store of before
+        # PR 47 holds under the bare key
+        kept = ("keep_written_leaves",) if donate else ()
         key = _aot.jaxpr_key(fn, leaves,
                              extra=(site, donate) + tuple(variant) + kept)
         if key is None:
@@ -832,12 +831,10 @@ class JitRegion(Logger):
             cache.put(key, prog, site,
                       meta={"family": site,
                             "variant": [str(v) for v in variant[:2]]})
-        return self._respecialize_guard(prog, fn, donate_argnums, site,
-                                        keep_unused=bool(kept))
+        return self._respecialize_guard(prog, fn, donate_argnums, site)
 
     @staticmethod
-    def _respecialize_guard(prog, fn, donate_argnums, site,
-                            keep_unused: bool = False):
+    def _respecialize_guard(prog, fn, donate_argnums, site):
         """An AOT ``Compiled`` is pinned to the exact input shardings
         and devices it was lowered with; lazy ``jax.jit`` transparently
         respecializes when they change between fires (on a mesh the
@@ -857,7 +854,7 @@ class JitRegion(Logger):
                     _metrics.xla_compiles(site).inc()
                     fallback = jax.jit(fn,
                                        donate_argnums=donate_argnums,
-                                       keep_unused=keep_unused)
+                                       keep_unused=bool(donate_argnums))
             return fallback(*leaves)
 
         return call

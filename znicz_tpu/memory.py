@@ -68,7 +68,7 @@ class Vector:
     __slots__ = ("_mem", "_devmem", "_state", "_device", "_tracing", "name",
                  "batch_major", "model_shard_dim", "data_shard_dim",
                  "data_shard_pad", "member_axis", "model_shard_axis",
-                 "_partition")
+                 "_partition", "cast_copy", "_cast_made")
 
     def __init__(self, mem: np.ndarray | None = None,
                  name: str = "", batch_major: bool = False,
@@ -123,6 +123,11 @@ class Vector:
         #: attributes above are a compatibility layer populated FROM
         #: this resolution, not hand-set by units
         self._partition = None
+        #: the Vector that holds THIS one's device value in another
+        #: dtype (:meth:`keep_cast`), or None; what its owner wants
+        #: called when it was made again
+        self.cast_copy: "Vector | None" = None
+        self._cast_made = None
         if mem is not None:
             self.reset(mem)
 
@@ -154,9 +159,73 @@ class Vector:
         if device.is_host_only:
             return
         if self._state == _State.HOST:
-            self._devmem = device.put(self._mem, vector=self)
-            _count_transfer("h2d", self._mem.nbytes)
+            self._upload()
             self._state = _State.SYNCED
+
+    def _upload(self) -> None:
+        """The host copy to the device: the ONE way a host write
+        reaches it, whoever wrote (``reset``, ``map_write`` …
+        ``unmap``) — so a cast kept of the device value
+        (:meth:`keep_cast`) is made again here, and at no writer."""
+        self._devmem = self._device.put(self._mem, vector=self)
+        _count_transfer("h2d", self._mem.nbytes)
+        self._recast_for_host()
+
+    def keep_cast(self, copy: "Vector", dtype, made=None) -> None:
+        """From now on ``copy`` holds this Vector's device value in
+        ``dtype``: a leaf of its own, for a reader that cannot cast on
+        its way in (a kernel's operand) and would pay a pass over the
+        tensor for the cast every step.  It is the cast of this
+        Vector, bit for bit, wherever a program reads it:
+
+        - it is made here, on the device, from the device value (no
+          host array of its size ever exists), placed as this one is;
+        - it is made AGAIN wherever the host's copy of this Vector is
+          uploaded or an uploaded array adopted (:meth:`_upload`,
+          :meth:`accept_device`), and ``made()`` is called, for the
+          owner's count;
+        - who writes this Vector ON the device calls :meth:`recast`
+          (``GradientDescentBase._update_param_xla``: one more result
+          of the fusion that holds the new value)."""
+        if self._devmem is None or self._state == _State.HOST:
+            raise ValueError(f"Vector '{self.name}': keep_cast before "
+                             f"the value is on a device")
+        for attr in ("model_shard_dim", "model_shard_axis",
+                     "data_shard_dim", "data_shard_pad", "member_axis"):
+            setattr(copy, attr, getattr(self, attr))
+        copy._device, copy._mem = self._device, None
+        copy._devmem = self._devmem.astype(dtype)
+        copy._state = _State.DEVICE
+        self.cast_copy, self._cast_made = copy, made
+
+    def recast(self) -> None:
+        """Write the kept cast, if one is kept, from the device value
+        as it stands (traced, inside a region, or not)."""
+        copy = self.cast_copy
+        if copy is not None:
+            copy.devmem = self._devmem.astype(copy.dtype)
+
+    def check_cast(self) -> None:
+        """Raise if a cast is kept and is not the cast of the device
+        value as it stands: someone wrote this Vector on the device
+        and did not call :meth:`recast` (asked after every dispatch
+        under ``engine.debug_checks``)."""
+        copy = self.cast_copy
+        if copy is None:
+            return
+        import jax.numpy as jnp
+        if not bool(jnp.array_equal(
+                copy._devmem, self._devmem.astype(copy.dtype),
+                equal_nan=True)):
+            raise AssertionError(
+                f"Vector '{self.name}': the cast kept in '{copy.name}' "
+                f"is stale — a device-side writer did not recast()")
+
+    def _recast_for_host(self) -> None:
+        if self.cast_copy is not None:
+            self.recast()
+            if self._cast_made is not None:
+                self._cast_made()
 
     @property
     def needs_collective_read(self) -> bool:
@@ -230,8 +299,7 @@ class Vector:
         if self._device is None or self._device.is_host_only:
             return
         if self._state == _State.HOST:
-            self._devmem = self._device.put(self._mem, vector=self)
-            _count_transfer("h2d", self._mem.nbytes)
+            self._upload()
         self._state = _State.DEVICE
 
     # ------------------------------------------------------------------
@@ -318,6 +386,7 @@ class Vector:
                     f"declared {self._mem.shape}/{self._mem.dtype}")
         self._devmem = devarr
         self._state = _State.DEVICE
+        self._recast_for_host()
 
     @property
     def state_name(self) -> str:

@@ -625,6 +625,22 @@ def moe_guard_sum(unit: str, stat: str) -> Gauge:
         labels=("unit", "stat")).labels(unit=unit, stat=stat)
 
 
+def moe_slab_copy(unit: str, stat: str) -> Gauge:
+    """A ``MoE`` layer's expert slabs in the matmuls' dtype, kept as
+    leaves the update writes (``stat`` = ``slabs``: slabs of the layer
+    whose grouped matmuls read that leaf in the program last traced,
+    so that no step casts them — 3, or 0 where the matmuls run in the
+    slabs' own dtype; ``refreshed``: times a copy was made again from
+    a slab the HOST wrote, since ``initialize`` — a restored snapshot,
+    a seeded flip, any ``map_write``; 0 while a run only trains)."""
+    return REGISTRY.gauge(
+        "znicz_moe_slab_copy",
+        "Expert slabs of a MoE layer read from their copy in the "
+        "matmuls' dtype, and how often the host's writes had it made "
+        "again",
+        labels=("unit", "stat")).labels(unit=unit, stat=stat)
+
+
 def delta_scan(unit: str, stat: str) -> Gauge:
     """A ``GatedDeltaNet`` unit's chunked state scan (``stat`` =
     ``chunk``: positions per chunk; ``chunks``: ⌈T / chunk⌉, the length

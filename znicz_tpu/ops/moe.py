@@ -45,7 +45,12 @@ IS the tensor the sum was made of); the gauge
 layer's tensors it did (3, or 0: ``ragged_dot``, accumulation).  Both
 permutations are GATHERS in both directions (:func:`_dispatch`,
 :func:`_unpermute`: a permutation's adjoint is its inverse), so no
-scatter-add runs in the step.
+scatter-add runs in the step.  The slabs are stored in f32 and
+multiplied in the rows' dtype: where the two differ the layer keeps
+each slab once more in that dtype, a leaf the update writes beside the
+slab, and the matmuls of either path read it — no step casts a slab
+(:meth:`MoE._keep_slab_copies`, PR 47; the gauge
+``znicz_moe_slab_copy``).
 
 Two auxiliary losses of the router come out of the pure forward beside
 ``y``: the load-balancing loss ``E · Σ_e (rows_e / N) · mean_n p_ne``
@@ -185,10 +190,11 @@ _kept.defvjp(lambda made, kept: (kept, None),
 
 def _cast(rhs, dtype):
     """The slabs in the rows' dtype.  ``rhs`` may be a pair: the slabs
-    and the cast of them their caller made ONCE for several calls (a
-    held layer's two branches, forward and backward: each would cast
-    the 235 MB again) — the cast is read, and a cotangent goes to the
-    slabs as if they had been cast here."""
+    and the cast of them that is kept beside them, a leaf the update
+    writes (``MoE._keep_slab_copies``: a cast made here is a pass over
+    the slabs, 235 MB in Laguna, at every call) — the cast is read,
+    and a cotangent goes to the slabs as if they had been cast
+    here."""
     if isinstance(rhs, tuple):
         return _kept(rhs[0].astype(dtype), rhs[1])
     return rhs.astype(dtype)
@@ -336,7 +342,7 @@ def _held_rows(plan, length, m, top_p, w_g, w_u, w_d, taps, casts, order,
     of the first ``length`` (static) pairs of ``order``, of which
     ``here`` are real; the groups cut to that buffer; and what a
     pullback of it reads.  ``casts``: the three slabs in the rows'
-    dtype, made once by the caller, or None (cast at each call);
+    dtype, the layer's kept copies, or None (cast at each call);
     ``kept``: the five results from an earlier run of the same
     function, which then stand in for the ones made here
     (:func:`_kept`)."""
@@ -525,6 +531,10 @@ class MoE(Forward):
     #: the kernel path the pullback hands out Σ g² of each one's
     #: gradient beside the gradient (``_gmm_kernels``)
     TAPPED = ("weights_gate", "weights_up", "weights_down")
+    #: … and each one's copy in the matmuls' dtype
+    #: (:meth:`_keep_slab_copies`): made from its slab, never saved
+    SLAB_COPIES = tuple(f"{attr}_cast" for attr in TAPPED)
+    SNAPSHOT_EXCLUDE = SLAB_COPIES
 
     def __init__(self, workflow, n_experts: int, top_k: int, width: int,
                  norm_topk: bool = False, pre_norm: str | None = None,
@@ -591,7 +601,7 @@ class MoE(Forward):
         self.weights_up = Vector(name=f"{self.name}.weights_up")
         self.weights_down = Vector(name=f"{self.name}.weights_down")
         self.gain_norm = Vector(name=f"{self.name}.gain_norm")
-        for attr in self.SHARED:
+        for attr in self.SHARED + self.SLAB_COPIES:
             setattr(self, attr, Vector(name=f"{self.name}.{attr}"))
         #: [rows per expert held here (all E without ``held``) | lb
         #: loss, z loss, steps, per-step max and min rows of an expert;
@@ -726,12 +736,57 @@ class MoE(Forward):
                           self.last_choice, self.select_bias,
                           self.select_load,
                           *(getattr(self, attr) for attr in self.SHARED))
+        self._keep_slab_copies()
+
+    def _keep_slab_copies(self) -> None:
+        """Each slab once more in the matmuls' dtype, as a leaf of its
+        own (``Vector.keep_cast``) — exactly where that dtype is set
+        and is not the slab's: what the layer sees of itself, whichever
+        path the grouped matmuls take.  The grouped matmuls read the
+        copy (:meth:`forward_args`) and no step casts a slab: a Pallas
+        call takes no cast into its operand, so the cast was a pass of
+        its own over every slab every step (4 bytes a parameter read,
+        2 written, for nothing multiplied: PERF.md §6, PR 47).  Four
+        rules:
+
+        - **the update writes it**: ``_update_param_xla`` stores the
+          slab it committed once more, cast — the value the next step
+          would have cast, so nothing computed changes by a bit;
+        - **it is no parameter**: no snapshot holds it
+          (``SNAPSHOT_EXCLUDE``), no bundle (``EXPORT_PARAMS``), the
+          SDC fingerprint and its vote do not fold it, no count of
+          parameters counts it;
+        - **it is never stale**: whatever writes a slab from the host
+          reaches the device through the slab's upload, which makes
+          the copy again (``Vector._upload``; counted:
+          ``znicz_moe_slab_copy{stat="refreshed"}``);
+        - **it exists by dtype**: no option, and under float32
+          matmuls no leaf — the layer's programs are then what they
+          were."""
+        from znicz_tpu.observe import metrics as obs_metrics
+        dtype = None if self.device.is_host_only else self.mxu_dtype
+        obs_metrics.moe_slab_copy(self.name, "refreshed").set(0)
+        for attr, kept in zip(self.TAPPED, self.SLAB_COPIES):
+            slab = getattr(self, attr)
+            if dtype is not None and np.dtype(dtype) != slab.dtype:
+                slab.keep_cast(getattr(self, kept), dtype,
+                               made=self._slab_copy_refreshed)
+        self.init_vectors(*(getattr(self, kept)
+                            for kept in self.SLAB_COPIES))
+
+    def _slab_copy_refreshed(self) -> None:
+        from znicz_tpu.observe import metrics as obs_metrics
+        obs_metrics.moe_slab_copy(self.name, "refreshed").inc()
 
     # -- pure forward (jnp; the backward vjp's this) --------------------
     def forward_args(self) -> tuple:
-        args = (self.input.devmem, self.weights.devmem,
-                self.weights_gate.devmem, self.weights_up.devmem,
-                self.weights_down.devmem,
+        # a slab with its copy in the matmuls' dtype, where it keeps
+        # one (``_keep_slab_copies``): the matmuls read the copy
+        slabs = tuple(
+            slab.devmem if slab.cast_copy is None
+            else (slab.devmem, slab.cast_copy.devmem)
+            for slab in (getattr(self, attr) for attr in self.TAPPED))
+        args = (self.input.devmem, self.weights.devmem, *slabs,
                 self.gain_norm.devmem if self.gain_norm else None)
         # what only some layers have, None where this one has not, the
         # Nones at the end left out; no cotangent ever reaches
@@ -836,6 +891,9 @@ class MoE(Forward):
         table = np.full(self.n_experts, local, np.int32)
         table[list(self.held)] = np.arange(local, dtype=np.int32)
         slot = jnp.asarray(table)[top_e.reshape(n * k)]
+        casts = None
+        if isinstance(w_g, tuple):    # (slab, its copy): forward_args
+            (w_g, w_u, w_d), casts = zip(w_g, w_u, w_d)
         # the pairs here first, by expert and inside an expert by
         # token; the pairs of absent experts last
         order = jnp.argsort(slot, stable=True).astype(jnp.int32)
@@ -845,13 +903,9 @@ class MoE(Forward):
         if plan.fit == plan.capacity:
             # one length: the plain body under plain autodiff
             f, sizes, _ = _held_rows(plan, plan.capacity, m, top_p, w_g,
-                                     w_u, w_d, taps, None, order, sizes,
+                                     w_u, w_d, taps, casts, order, sizes,
                                      here)
         else:
-            # each slab cast ONCE a step, outside the two ``cond``s:
-            # inside, each branch of each would cast it again
-            casts = tuple(jax.lax.stop_gradient(w.astype(plan.dtype))
-                          for w in (w_g, w_u, w_d))
             f, sizes = _fit_or_capacity(plan, m, top_p, w_g, w_u, w_d,
                                         taps, casts, order, sizes, here)
         over = jnp.maximum(here - plan.capacity, 0)
@@ -867,7 +921,10 @@ class MoE(Forward):
         router's logits and its choice (not).  ``taps``: a zero scalar
         for each slab of ``TAPPED``, which enters nothing — the
         pullback returns Σ g² of that slab's gradient in its place
-        (``grouped_matmul``; the kernel path only)."""
+        (``grouped_matmul``; the kernel path only).  A slab may be a
+        pair, the slab and its kept copy in the matmuls' dtype
+        (:meth:`forward_args`): the matmuls read the copy, the
+        cotangent is the slab's."""
         taps = taps or (None,) * len(self.TAPPED)
         b, t, d = x.shape
         n, k, e = b * t, self.top_k, self.n_experts
@@ -960,6 +1017,9 @@ class MoE(Forward):
                 self.xla_forward, *args, has_aux=True)
         self.output.devmem = y
         self._record(lb, z, *routed)
+        from znicz_tpu.observe import metrics as obs_metrics
+        obs_metrics.moe_slab_copy(self.name, "slabs").set(
+            sum(isinstance(arg, tuple) for arg in args[2:5]))
 
     # -- the epoch-end read ---------------------------------------------
     def on_epoch_ended(self) -> None:
@@ -1129,7 +1189,8 @@ class GDMoE(GradientDescentBase):
         seen = {id(v) for v in vecs}
         fwd = self.forward_unit
         for _, param, acc in self._extra_pairs():
-            for vec in (param, acc):
+            # … and the copy the update writes beside a slab
+            for vec in (param, acc, param.cast_copy):
                 if vec and id(vec) not in seen:
                     vecs.append(vec)
         if getattr(fwd, "select_bias_on", False):
@@ -1164,7 +1225,10 @@ class GDMoE(GradientDescentBase):
         if self.need_err_input:
             self.err_input.devmem = gx
         self._apply_weights_xla(g_own)
-        grads = dict(zip(self.EXTRA, g_extra))
+        # (of a slab handed in with its copy, the slab's: the copy
+        # takes no cotangent)
+        grads = {attr: grad[0] if isinstance(grad, tuple) else grad
+                 for attr, grad in zip(self.EXTRA, g_extra)}
         # Σ g² of a slab's gradient where the kernels made it: what the
         # pullback returns for the forward's ``taps``, its last argument
         tapped = getattr(fwd, "_gmm_kernel", False)

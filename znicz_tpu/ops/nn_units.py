@@ -812,6 +812,11 @@ class GradientDescentBase(AcceleratedUnit, metaclass=MatchingObject):
         # claimed checksum, which is what the guard's sticky
         # self-check detects
         self._fold_fingerprint(jnp, 0, vec.devmem)
+        # the committed value once more in its readers' dtype, where
+        # a cast of it is kept (``Vector.keep_cast``): one more result
+        # of the fusion that holds it, where the next step would have
+        # read all of it again to cast it
+        vec.recast()
         return guard is not None and grad_sq is not None
 
     def _apply_param_zero1(self, grad, vec: Vector, acc_vec,

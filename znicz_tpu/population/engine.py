@@ -222,6 +222,13 @@ class PopulationRegion(Logger):
                     getattr(self.pop_device, "n_data_shards", 1)))
             svec.initialize(self.pop_device)
             self.svecs.append(svec)
+        # a leaf that keeps a cast of itself (``Vector.keep_cast``)
+        # keeps it stacked: an upload of the stacked leaf makes the
+        # stacked copy again, as the template's made its own
+        for vec, svec in zip(vectors, self.svecs):
+            if vec.cast_copy is not None:
+                svec.keep_cast(self.svec(vec.cast_copy),
+                               vec.cast_copy.dtype)
         # template device copies are dead weight now — the stacked
         # leaves are the live state; keep only the host mirrors (the
         # export path and schedule bookkeeping read those)
@@ -433,6 +440,9 @@ class PopulationRegion(Logger):
         out = fn(jnp.asarray(fitness, dtype=jnp.float32), key, *leaves)
         for i, leaf in zip(slots, out):
             self.svecs[i].devmem = leaf
+        for i in slots:
+            # (a blend of two members' casts is not their blend's cast)
+            self.svecs[i].recast()
 
 
 class PopulationTrainer(Logger):
