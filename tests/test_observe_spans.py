@@ -203,6 +203,8 @@ def test_run_chunked_records_the_spans_run_records(driver):
     assert [s["name"] for s in roots] == [f"workflow:spans_{driver}"]
     root_id = roots[0]["args"]["span_id"]
     for s in driven:
+        if s["name"].startswith("jax:"):
+            continue        # JAX's stamps: children of compile:<region>
         if s["cat"] in ("unit", "region", "compile"):
             assert s["args"]["parent_span_id"] == root_id
     if dispatch is not None:

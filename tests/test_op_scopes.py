@@ -299,8 +299,11 @@ def test_asking_compiles_nothing_and_is_memoised():
     first = observe.op_scopes()
     assert first and all(first.values())
     assert (compiles.value, len(built), len(region._cache)) == before
-    assert not [ev for ev in observe.TRACER.to_chrome_trace(
-        since=spans)["traceEvents"] if ev.get("cat") == "compile"]
+    # asking traces and lowers the program for its text (JAX's own
+    # stamps say so), and makes none
+    assert {ev["name"] for ev in observe.TRACER.to_chrome_trace(
+        since=spans)["traceEvents"] if ev.get("cat") == "compile"} \
+        <= {"jax:trace", "jax:lower"}
     again = observe.op_scopes()
     assert all(again[name] is first[name] for name in first)
     # the text's thunk went with its first reading

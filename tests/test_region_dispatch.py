@@ -79,8 +79,11 @@ class Toy:
 
 def _spans(since: int) -> list[dict]:
     events = obs_tracing.TRACER.to_chrome_trace(since=since)["traceEvents"]
+    # the sites' own spans: JAX's stamps (``jax:*``, children of a
+    # ``compile:`` span) are tests/test_observe_setup.py's
     return [ev for ev in events if ev.get("ph") == "X"
-            and ev.get("cat") in ("region", "compile")]
+            and ev.get("cat") in ("region", "compile")
+            and not ev["name"].startswith("jax:")]
 
 
 @variants

@@ -446,7 +446,10 @@ class JitRegion(Logger):
                 # guard asserts this stays flat once every variant is
                 # warmed.  jit compiles lazily, so the first dispatch
                 # rides inside the compile span — that is where the
-                # trace+compile cost actually lands.
+                # trace+compile cost actually lands.  JAX's own stamps
+                # come out as its children (jax:trace, jax:lower,
+                # jax:backend_compile; observe.tracing.watch_startup):
+                # what is left of the span is the first execution.
                 _metrics.xla_compiles(f"region:{self.name}").inc()
                 with _tracing.TRACER.span(f"compile:{self.name}",
                                           cat="compile", **span_args):

@@ -166,10 +166,23 @@ class Vector:
         """The host copy to the device: the ONE way a host write
         reaches it, whoever wrote (``reset``, ``map_write`` …
         ``unmap``) — so a cast kept of the device value
-        (:meth:`keep_cast`) is made again here, and at no writer."""
-        self._devmem = self._device.put(self._mem, vector=self)
+        (:meth:`keep_cast`) is made again here, and at no writer.
+        The mirror of :meth:`_read_back`: a span
+        (``upload:<name>``) and a phase of ``znicz_setup_seconds``.
+        They hold the HOST's time in the call and no fence: the copy
+        may land after ``put`` returns, and what is left of it is
+        waited for by whoever next blocks on the device."""
+        if not _metrics.enabled():
+            self._devmem = self._device.put(self._mem, vector=self)
+            self._recast_for_host()
+            return
+        with _tracing.TRACER.span(
+                f"upload:{self.name}", cat="transfer",
+                bytes=int(self._mem.nbytes)) as span:
+            self._devmem = self._device.put(self._mem, vector=self)
+            self._recast_for_host()
         _count_transfer("h2d", self._mem.nbytes)
-        self._recast_for_host()
+        _metrics.setup_seconds("upload").inc(span.dur_us / 1e6)
 
     def keep_cast(self, copy: "Vector", dtype, made=None) -> None:
         """From now on ``copy`` holds this Vector's device value in

@@ -9,7 +9,10 @@ introspection — plotters and ``veles/web_status.py``):
   text exposition.  ``WebStatusServer`` serves it at ``/metrics``.
 - :mod:`znicz_tpu.observe.tracing` — a host-side span tracer (unit
   fires, epochs, region dispatches, compiles, blocking device→host
-  reads, SDC votes, serving dispatches), every span naming its parent,
+  reads, SDC votes, serving dispatches) and of the process's own
+  start-up (``initialize:``, ``param_fill``, ``upload:``, JAX's
+  ``jax:trace`` / ``jax:lower`` / ``jax:backend_compile``; beside
+  them ``znicz_setup_seconds{phase}``), every span naming its parent,
   exporting Chrome-trace/Perfetto JSON, served live at
   ``/trace.json``.  Its clock is ``perf_counter``: a span rides the
   profiler's own clock (a ``TraceAnnotation``) only inside a

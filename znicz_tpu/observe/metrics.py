@@ -452,6 +452,34 @@ def host_read_wait_seconds() -> Counter:
     ).labels()
 
 
+def setup_seconds(phase: str) -> Counter:
+    """Host seconds of the process's own start-up by phase, added to
+    as each start-up span closes (``observe.tracing``): ``initialize``
+    (the ``initialize:<workflow>`` / ``initialize:<unit>`` spans' SELF
+    time, so the phases beside it are not in it), ``param_fill``
+    (``param_fill``), ``upload`` (``upload:<vector>``), ``trace``,
+    ``lower``, ``backend_compile`` and ``cache_load`` (the ``jax:*``
+    spans; a load from JAX's cache is inside a backend compile and in
+    both).  It outlives the span ring, and it should stand still once
+    every shape is warmed: growth in steady state is a recompile or a
+    host write that reaches the device."""
+    return REGISTRY.counter(
+        "znicz_setup_seconds",
+        "Host seconds of start-up work by phase (initialize, "
+        "param_fill, upload, trace, lower, backend_compile, "
+        "cache_load)", labels=("phase",)).labels(phase=phase)
+
+
+def process_start_time_seconds() -> Gauge:
+    """When the OS started this process, in Unix seconds (the
+    conventional Prometheus name): ``time()`` less it is the process's
+    age, and the zero the start-up spans count from."""
+    return REGISTRY.gauge(
+        "process_start_time_seconds",
+        "Start time of the process since the Unix epoch in seconds"
+    ).labels()
+
+
 def input_wait_seconds(loader: str) -> Histogram:
     """Host time a training step spent BLOCKED on the input pipeline
     (prefetch miss, empty prefetch queue).  A fully hidden input plane

@@ -6,10 +6,25 @@ intervals, self time per operation, idle gaps); this module records the
 HOST half — which unit, epoch, blocking read or serving request the
 host was in while the device worked or waited.  Spans (unit fires,
 workflow runs, epochs, region dispatches, compiles, blocking
-device→host reads, SDC votes, serving phases) go into ONE bounded ring
-buffer on ``perf_counter`` and are exported as Chrome-trace/Perfetto
-JSON (``ph: "X"`` complete events): ``chrome://tracing`` / Perfetto
-show them, ``WebStatusServer`` serves them live at ``/trace.json``.
+device→host reads, host→device uploads, SDC votes, serving phases) go
+into ONE bounded ring buffer on ``perf_counter`` and are exported as
+Chrome-trace/Perfetto JSON (``ph: "X"`` complete events):
+``chrome://tracing`` / Perfetto show them, ``WebStatusServer`` serves
+them live at ``/trace.json``.
+
+The process's own start-up is on the same ring, counted from the OS's
+start of the process (:func:`process_start_us`, negative: before the
+tracer's epoch): ``initialize:<workflow>`` with one
+``initialize:<unit>`` child per call of a unit's ``initialize`` (cat
+``setup``; ``deferred`` where the unit asked for a later pass),
+``param_fill`` (cat ``setup``: the host drawing random parameters),
+``upload:<vector>`` (cat ``transfer``: the host's time in the copy up,
+the mirror of ``host_read:<vector>``) and, made from JAX's own stamps
+(:func:`watch_startup`), ``jax:trace``, ``jax:lower``,
+``jax:backend_compile`` and ``jax:cache_load`` (cat ``compile``) under
+whatever was open on the thread — ``compile:<region>`` for a step
+program, whose remainder is then the first execution.  Each adds its
+seconds to ``znicz_setup_seconds{phase}`` as it closes.
 
 Every span names the span that caused it: ``args`` carries
 ``span_id`` (unique in the process), ``parent_span_id`` (the span
@@ -45,6 +60,7 @@ lookup per span.
 
 from __future__ import annotations
 
+import functools
 import itertools
 import json
 import os
@@ -63,6 +79,27 @@ def now_us() -> float:
     """Microseconds since the tracer epoch (the Chrome-trace ``ts``
     time base)."""
     return (time.perf_counter() - _EPOCH) * 1e6
+
+
+@functools.cache
+def process_start_us() -> float:
+    """The OS's start time of this process on the tracer's time base
+    (negative: the interpreter started before this module was
+    imported) — the zero every start-up reading counts from.  Linux
+    keeps it in ``/proc/self/stat`` (field 22, clock ticks since boot);
+    the boot time is taken as now less ``/proc/uptime``, which is good
+    to a tick where ``btime`` of ``/proc/stat`` is whole seconds.
+    Elsewhere the tracer's own epoch stands in."""
+    try:
+        with open("/proc/self/stat") as fh:
+            # field 2, the command, may hold spaces and parentheses
+            fields = fh.read().rpartition(")")[2].split()
+        with open("/proc/uptime") as fh:
+            uptime_s = float(fh.read().split()[0])
+        age_s = uptime_s - int(fields[19]) / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return 0.0
+    return now_us() - max(age_s, 0.0) * 1e6
 
 
 #: True while a :func:`profile_window` device trace is open — the ONLY
@@ -94,12 +131,16 @@ class _NullSpan:
 
     __slots__ = ()
     dur_us = 0.0
+    self_us = 0.0
 
     def __enter__(self):
         return self
 
     def __exit__(self, *exc):
         return False
+
+    def set(self, **args) -> None:
+        pass
 
 
 _NULL_SPAN = _NullSpan()
@@ -110,20 +151,31 @@ class _Span:
     ``@contextmanager`` is measurable at decode-step cadence)."""
 
     __slots__ = ("_tracer", "_name", "_cat", "_args", "_ann", "_t0",
-                 "_depth", "_id", "_parent", "dur_us")
+                 "_depth", "_id", "_parent", "_child_us", "dur_us")
 
     def __init__(self, tracer, name, cat, args) -> None:
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._args = args
+        self._child_us = 0.0
+
+    def set(self, **args) -> None:
+        """Arguments known only inside the with-body."""
+        self._args.update(args)
+
+    @property
+    def self_us(self) -> float:
+        """The duration less the children's, readable where
+        ``dur_us`` is."""
+        return self.dur_us - self._child_us
 
     def __enter__(self):
         stack = self._tracer._stack()
         self._depth = len(stack)
-        self._parent = stack[-1] if stack else 0
+        self._parent = stack[-1]._id if stack else 0
         self._id = next(_SPAN_SEQ)
-        stack.append(self._id)
+        stack.append(self)
         self._ann = (_trace_annotation(self._name)
                      if _DEVICE_TRACE_OPEN else None)
         if self._ann is not None:
@@ -135,10 +187,13 @@ class _Span:
         t1 = now_us()
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
-        self._tracer._stack().pop()
+        stack = self._tracer._stack()
+        stack.pop()
         #: readable after the with-body: the counter beside a span
         #: sums the very duration the span recorded
         self.dur_us = t1 - self._t0
+        if stack:
+            stack[-1]._child_us += self.dur_us
         self._tracer._append({
             "ph": "X", "name": self._name, "cat": self._cat,
             "pid": self._tracer._pid,
@@ -158,6 +213,7 @@ class SpanTracer:
         self._lock = threading.Lock()
         self._local = threading.local()
         self._seq = 0
+        self._dropped = 0
         self._pid = os.getpid()
 
     # ------------------------------------------------------------------
@@ -171,7 +227,16 @@ class SpanTracer:
         with self._lock:
             self._seq += 1
             event["_seq"] = self._seq
+            if len(self._events) == self._events.maxlen:
+                self._dropped += 1
             self._events.append(event)
+
+    def dropped(self) -> int:
+        """Events the full ring has pushed out, oldest first, since
+        the process began: a reader that needs the ring whole (the
+        start-up's partition) refuses one that has wrapped."""
+        with self._lock:
+            return self._dropped
 
     def mark(self) -> int:
         """A position marker; pass to :meth:`to_chrome_trace` /
@@ -205,22 +270,33 @@ class SpanTracer:
         return _Span(self, name, cat, args)
 
     def complete(self, name: str, t0_us: float, t1_us: float,
-                 cat: str = "host", **args) -> None:
+                 cat: str = "host", nested: bool = False,
+                 **args) -> int:
         """Record a retroactive span from explicit timestamps (epoch
-        boundaries are only known at the END of the epoch).  It began
-        before whatever is open now, so it is a root (parent 0) unless
-        the caller passes ids of its own, as :class:`RequestTrace`
-        does."""
+        boundaries are only known at the END of the epoch) and return
+        its id (0 with telemetry off).  It began before whatever is
+        open now, so it is a root (parent 0) unless the caller passes
+        ids of its own, as :class:`RequestTrace` does, or says that it
+        is ``nested``: it began and ended inside the span open on this
+        thread (a phase JAX stamped while the caller's span was open),
+        which is then its parent and counts it among its children."""
         if not _metrics.enabled():
-            return
+            return 0
+        dur = max(0.0, t1_us - t0_us)
+        depth = 0
         if "span_id" not in args:
+            stack = self._stack() if nested else ()
+            if stack:
+                depth = len(stack)
+                stack[-1]._child_us += dur
             args = {**args, "span_id": next(_SPAN_SEQ),
-                    "parent_span_id": 0}
+                    "parent_span_id": stack[-1]._id if stack else 0}
         self._append({
             "ph": "X", "name": name, "cat": cat,
             "pid": self._pid, "tid": threading.get_native_id(),
-            "ts": t0_us, "dur": max(0.0, t1_us - t0_us),
-            "args": {**args, "depth": 0}})
+            "ts": t0_us, "dur": dur,
+            "args": {"depth": depth, **args}})
+        return args["span_id"]
 
     def instant(self, name: str, cat: str = "host", **args) -> None:
         if not _metrics.enabled():
@@ -232,7 +308,8 @@ class SpanTracer:
 
     # ------------------------------------------------------------------
     def to_chrome_trace(self, since: int = 0) -> dict:
-        """The Chrome-trace/Perfetto JSON object (``traceEvents``)."""
+        """The Chrome-trace/Perfetto JSON object (``traceEvents``,
+        and ``dropped``: what the full ring has pushed out)."""
         with self._lock:
             events = [ev for ev in self._events if ev["_seq"] > since]
         out_events = [{"ph": "M", "name": "process_name",
@@ -242,7 +319,8 @@ class SpanTracer:
             ev = dict(ev)
             ev.pop("_seq", None)
             out_events.append(ev)
-        return {"traceEvents": out_events, "displayTimeUnit": "ms"}
+        return {"traceEvents": out_events, "displayTimeUnit": "ms",
+                "dropped": self.dropped()}
 
     def export(self, path: str, since: int = 0) -> str:
         with open(path, "w") as fh:
@@ -252,6 +330,98 @@ class SpanTracer:
 
 #: the process-global tracer every instrumentation site records on
 TRACER = SpanTracer()
+
+
+# ----------------------------------------------------------------------
+# the process's own start-up: JAX's stamps of a program's making
+# ----------------------------------------------------------------------
+#: what ``jax.monitoring`` reports as a program is made (jax 0.9.0
+#: ``_src/dispatch.py`` ``log_elapsed_time``: a scalar as the phase
+#: opens, its duration as it ends) → the span it becomes and its
+#: phase of ``znicz_setup_seconds``
+_JAX_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": ("jax:trace", "trace"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("jax:lower", "lower"),
+    "/jax/core/compile/backend_compile_duration":
+        ("jax:backend_compile", "backend_compile"),
+}
+#: a load from JAX's persistent cache (``_src/compiler.py``): a
+#: duration and no opening, reported inside the backend-compile phase
+#: that it ends
+_JAX_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_watching = False
+_watch_lock = threading.Lock()
+
+
+class _JaxPhases(threading.local):
+    #: phases JAX has open on this thread: a jitted function met
+    #: inside a trace is traced too, a lowering rule may trace, and
+    #: each reports its own event inside the outer one's time
+    open = 0
+    #: ``(t0, t1)`` of the cache loads since the last backend compile
+    loads: tuple = ()
+
+
+_jax_phases = _JaxPhases()
+
+
+def _on_jax_scalar(event: str, _value, **_kw) -> None:
+    if event in _JAX_PHASES:
+        _jax_phases.open += 1
+
+
+def _on_jax_duration(event: str, secs: float, **kw) -> None:
+    """One completed span per phase JAX reports, under the span open
+    on this thread.  The listener runs as the phase ends, so the span
+    ends now on the tracer's clock and began ``secs`` earlier: JAX
+    stamps ``time.time()``, which an offset would carry over only
+    until the wall clock is next slewed.  Of phases inside phases the
+    outermost is recorded — its time holds the others', and no instant
+    is then in two spans — but for a cache load, which comes out as
+    the child of the backend compile it ended."""
+    if event == _JAX_CACHE_LOAD:
+        t1 = now_us()
+        _jax_phases.loads += ((t1 - secs * 1e6, t1),)
+        return
+    if event not in _JAX_PHASES:
+        return
+    name, phase = _JAX_PHASES[event]
+    _jax_phases.open = inside = max(_jax_phases.open - 1, 0)
+    loads = ()
+    if phase == "backend_compile":
+        loads, _jax_phases.loads = _jax_phases.loads, ()
+    if inside or not _metrics.enabled():
+        return
+    t1 = now_us()
+    args = {"fun_name": kw["fun_name"]} if "fun_name" in kw else {}
+    span_id = TRACER.complete(name, t1 - secs * 1e6, t1, cat="compile",
+                              nested=True, **args)
+    _metrics.setup_seconds(phase).inc(secs)
+    for t0, t1 in loads:
+        TRACER.complete("jax:cache_load", t0, t1, cat="compile",
+                        span_id=next(_SPAN_SEQ), parent_span_id=span_id,
+                        depth=len(TRACER._stack()) + 1)
+        _metrics.setup_seconds("cache_load").inc((t1 - t0) / 1e6)
+
+
+def watch_startup() -> None:
+    """With telemetry on: say on ``/metrics`` when the process began
+    and, once per process however many workflows and regions ask,
+    start turning JAX's stamps into spans (``jax.monitoring`` listeners
+    cannot be removed; this is the one place they are registered)."""
+    if not _metrics.enabled():
+        return
+    _metrics.process_start_time_seconds().set(
+        time.time() - (now_us() - process_start_us()) / 1e6)
+    global _watching
+    with _watch_lock:
+        if _watching:
+            return
+        _watching = True
+    import jax
+    jax.monitoring.register_scalar_listener(_on_jax_scalar)
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 # ----------------------------------------------------------------------

@@ -21,6 +21,8 @@ import numpy as np
 
 import jax
 
+from znicz_tpu.observe import metrics as _metrics
+from znicz_tpu.observe import tracing as _tracing
 from znicz_tpu.utils.config import root
 
 
@@ -46,11 +48,24 @@ class RandomGenerator:
     # --- host-side convenience used for weight fills -------------------
     def fill_uniform(self, shape, vmin: float, vmax: float,
                      dtype=np.float32) -> np.ndarray:
-        return self.numpy.uniform(vmin, vmax, size=shape).astype(dtype)
+        return self._fill(self.numpy.uniform, vmin, vmax, shape, dtype)
 
     def fill_normal(self, shape, mean: float = 0.0, stddev: float = 1.0,
                     dtype=np.float32) -> np.ndarray:
-        return self.numpy.normal(mean, stddev, size=shape).astype(dtype)
+        return self._fill(self.numpy.normal, mean, stddev, shape, dtype)
+
+    @staticmethod
+    def _fill(draw, a: float, b: float, shape, dtype) -> np.ndarray:
+        """The draw and its cast: the host's time making random
+        parameters is a span (``param_fill``, a child of the unit
+        that asked) and a phase of ``znicz_setup_seconds``."""
+        if not _metrics.enabled():
+            return draw(a, b, size=shape).astype(dtype)
+        with _tracing.TRACER.span("param_fill", cat="setup") as span:
+            out = draw(a, b, size=shape).astype(dtype)
+            span.set(bytes=int(out.nbytes))
+        _metrics.setup_seconds("param_fill").inc(span.dur_us / 1e6)
+        return out
 
     def shuffle(self, arr: np.ndarray) -> None:
         self.numpy.shuffle(arr)

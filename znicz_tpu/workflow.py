@@ -18,6 +18,7 @@ from __future__ import annotations
 from collections import deque
 
 from znicz_tpu.mutable import Bool
+from znicz_tpu.observe import metrics as _metrics
 from znicz_tpu.observe import tracing as _tracing
 from znicz_tpu.units import Container, EndPoint, StartPoint, Unit
 
@@ -58,7 +59,30 @@ class Workflow(Container):
     def initialize(self, **kwargs) -> None:
         """Initialize all units, retrying ones whose linked attributes
         are produced by units initialized later (reference behavior:
-        multi-pass dependency resolution)."""
+        multi-pass dependency resolution).
+
+        Start-up is spanned here, for every workflow:
+        ``initialize:<workflow>`` (cat ``setup``) around the passes and
+        ``initialize:<unit>`` around each call of a unit's
+        ``initialize`` (``deferred`` where it asked for a later pass);
+        their self time adds to ``znicz_setup_seconds{initialize}``.  A
+        workflow nested as a unit comes out as a child of its unit
+        span."""
+        _tracing.watch_startup()
+        with _tracing.TRACER.span(f"initialize:{self.name}",
+                                  cat="setup") as span:
+            self._initialize_units(**kwargs)
+        self._count_initialize(span)
+        self._initialized = True
+
+    @staticmethod
+    def _count_initialize(span) -> None:
+        # JAX stamps its phases on another clock than the span's: a
+        # span that is all children may come out a hair under them
+        if span.self_us > 0:
+            _metrics.setup_seconds("initialize").inc(span.self_us / 1e6)
+
+    def _initialize_units(self, **kwargs) -> None:
         pending = list(self.units)
         passes = 0
         while pending:
@@ -68,16 +92,21 @@ class Workflow(Container):
             for unit in pending:
                 if unit.is_initialized:
                     continue
-                try:
-                    unit.initialize(**kwargs)
-                    unit._initialized = True
-                    progress = True
-                except AttributeError as exc:
-                    # a base-class initialize may have set the flag
-                    # before the subclass raised — the workflow loop is
-                    # authoritative about who still needs a pass
-                    unit._initialized = False
-                    deferred.append((unit, exc))
+                with _tracing.TRACER.span(
+                        f"initialize:{unit.name}", cat="setup",
+                        kind=type(unit).__name__) as span:
+                    try:
+                        unit.initialize(**kwargs)
+                        unit._initialized = True
+                        progress = True
+                    except AttributeError as exc:
+                        # a base-class initialize may have set the flag
+                        # before the subclass raised — the workflow loop
+                        # is authoritative about who still needs a pass
+                        unit._initialized = False
+                        deferred.append((unit, exc))
+                        span.set(deferred=True)
+                self._count_initialize(span)
             if not deferred:
                 break
             if not progress:
@@ -87,7 +116,6 @@ class Workflow(Container):
                     f"{passes} passes; first stuck unit: {unit} "
                     f"({exc})") from exc
             pending = [u for u, _ in deferred]
-        self._initialized = True
 
     def run(self) -> None:
         """Fire units from ``start_point`` until completion.
