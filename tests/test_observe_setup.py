@@ -5,7 +5,10 @@
   ``deferred`` where the unit asked for a later pass; a workflow nested
   as a unit comes out as a child of its unit span;
 - ``param_fill`` and ``upload:<vector>`` are children of the unit that
-  asked, with ``bytes``;
+  asked, with ``bytes``; a tensor drawn in chunks by the pool of host
+  threads is still ONE ``param_fill`` span (``chunks``, ``workers``),
+  and ``znicz_param_fill_bytes_total{path}`` says which way the bytes
+  went;
 - a region's first dispatch records ``jax:trace``, ``jax:lower`` and
   ``jax:backend_compile`` once each under ``compile:<region>`` (a
   jitted function traced inside the step's trace is not a span of its
@@ -175,7 +178,8 @@ def test_fill_and_upload_are_children_of_the_unit_that_asked(started):
     fills = [s for s in kids if s["name"] == "param_fill"]
     assert sorted(s["args"]["bytes"] for s in fills) == sorted(
         [unit.weights.devmem.nbytes, unit.bias.devmem.nbytes])
-    assert all(s["cat"] == "setup" for s in fills)
+    assert all(s["cat"] == "setup" and s["args"]["chunks"] == 1
+               for s in fills)
     uploads = {s["name"]: s for s in kids
                if s["name"].startswith("upload:")}
     up = uploads[f"upload:{unit.weights.name}"]
@@ -187,6 +191,52 @@ def test_fill_and_upload_are_children_of_the_unit_that_asked(started):
     assert all(s["args"]["parent_span_id"] in unit_ids for s in spans
                if s["name"] == "param_fill"
                or s["name"].startswith("upload:"))
+
+
+class _Filler(Unit):
+    """Draws one tensor above a chunk and one below, as a unit's
+    ``initialize`` does."""
+
+    def initialize(self, **kwargs) -> None:
+        from znicz_tpu.utils import prng
+        gen = prng.get()
+        self.large = gen.fill_normal((2, prng.CHUNK // 2 + 7), 0.0, 0.02)
+        self.small = gen.fill_uniform((5, 3), -1.0, 1.0)
+        super().initialize(**kwargs)
+
+
+def test_a_chunked_fill_is_still_one_span_of_the_unit_that_asked():
+    paths = ("stream", "chunked")
+    before = {p: obs_metrics.param_fill_bytes(p).value for p in paths}
+    secs = obs_metrics.setup_seconds("param_fill").value
+    wf = Workflow(name="chunked_fill")
+    unit = _Filler(wf, name="filler")
+    mark = obs_tracing.TRACER.mark()
+    wf.initialize()
+    spans = _spans(obs_tracing.TRACER, mark)
+    parent = next(s for s in spans if s["name"] == "initialize:filler")
+    fills = [s for s in spans if s["name"].startswith("param_fill")]
+    # one span a tensor, on the calling thread: the pool's threads
+    # open none (the benchmark sums ``param_fill*`` by prefix)
+    assert [s["name"] for s in fills] == ["param_fill", "param_fill"]
+    assert all(s["args"]["parent_span_id"] == parent["args"]["span_id"]
+               and s["cat"] == "setup" and s["tid"] == parent["tid"]
+               for s in fills)
+    large, small = fills
+    assert large["args"]["bytes"] == unit.large.nbytes
+    assert large["args"]["chunks"] == 2
+    assert 1 <= large["args"]["workers"] <= 8
+    assert (small["args"]["bytes"], small["args"]["chunks"],
+            small["args"]["workers"]) == (unit.small.nbytes, 1, 1)
+    grown = {p: obs_metrics.param_fill_bytes(p).value - before[p]
+             for p in paths}
+    assert grown == {"stream": unit.small.nbytes,
+                     "chunked": unit.large.nbytes}
+    assert obs_metrics.setup_seconds("param_fill").value - secs \
+        == pytest.approx(sum(s["dur"] for s in fills) / 1e6, abs=1e-9)
+    text = obs_metrics.REGISTRY.to_prometheus()
+    assert 'znicz_param_fill_bytes_total{path="chunked"}' in text
+    assert 'znicz_param_fill_bytes_total{path="stream"}' in text
 
 
 def test_a_host_write_that_reaches_the_device_is_one_upload_span():

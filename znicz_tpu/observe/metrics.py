@@ -457,7 +457,10 @@ def setup_seconds(phase: str) -> Counter:
     as each start-up span closes (``observe.tracing``): ``initialize``
     (the ``initialize:<workflow>`` / ``initialize:<unit>`` spans' SELF
     time, so the phases beside it are not in it), ``param_fill``
-    (``param_fill``), ``upload`` (``upload:<vector>``), ``trace``,
+    (``param_fill``: one span per tensor around the whole draw, so the
+    WALL time of the thread that asked and not the CPU time of the
+    pool that draws a large tensor's chunks — ``utils.prng``),
+    ``upload`` (``upload:<vector>``), ``trace``,
     ``lower``, ``backend_compile`` and ``cache_load`` (the ``jax:*``
     spans; a load from JAX's cache is inside a backend compile and in
     both).  It outlives the span ring, and it should stand still once
@@ -468,6 +471,19 @@ def setup_seconds(phase: str) -> Counter:
         "Host seconds of start-up work by phase (initialize, "
         "param_fill, upload, trace, lower, backend_compile, "
         "cache_load)", labels=("phase",)).labels(phase=phase)
+
+
+def param_fill_bytes(path: str) -> Counter:
+    """Bytes of random parameters drawn on the host
+    (``RandomGenerator.fill_normal`` / ``fill_uniform``) by how:
+    ``stream`` (a tensor of one chunk, from the generator's own
+    stream) or ``chunked`` (a larger one, a seed per chunk, drawn by
+    the pool of host threads).  The two shares say how much of a
+    model's parameters took the pool."""
+    return REGISTRY.counter(
+        "znicz_param_fill_bytes_total",
+        "Bytes of random parameters drawn on the host by path "
+        "(stream, chunked)", labels=("path",)).labels(path=path)
 
 
 def process_start_time_seconds() -> Gauge:
