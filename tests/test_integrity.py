@@ -313,6 +313,80 @@ def test_attention_layer_compiled_for_v5e_moves_no_activation(
     assert not moves, moves
 
 
+#: the two kinds of attention layer of ``smallthinker_train_1of8`` at the
+#: cell's T: (options, the kernels the program has to hold)
+_LONG_CONTEXT_LAYERS = {
+    "nope_full": (dict(rope=None),
+                  ("znicz_flash_fwd", "znicz_flash_dq", "znicz_flash_dkv")),
+    "rope_window_4096": (
+        dict(rope={"theta": 1500000.0}, window=4096),
+        ("znicz_flash_fwd_win", "znicz_flash_dq_win",
+         "znicz_flash_dkv_win")),
+}
+
+
+@pytest.mark.parametrize("kind", list(_LONG_CONTEXT_LAYERS))
+def test_long_context_attention_layers_compile_for_v5e_at_published_widths(
+        v5e_chip, monkeypatch, kind):
+    """SmallThinker's two attention layers at T 16,384 × 2,560, 28 query
+    heads on 4 K/V heads of 128 (a group of SEVEN), bf16 operands,
+    forward + backward, through Mosaic for a described v5e: the
+    un-windowed causal call past ``WHOLE_BLOCK_K`` keeps the TWO-pass
+    backward over a K grid eight tiles deep, the window of 4,096 runs
+    the banded kernels over a band nine tiles wide — shapes no other
+    cell has, which the chip's compiler has to take (PR 50)."""
+    import jax
+    import jax.numpy as jnp
+
+    from znicz_tpu.dummy import DummyUnit, DummyWorkflow
+    from znicz_tpu.memory import Vector
+    from znicz_tpu.ops import attention, pallas_attention, pallas_kernels
+    b, t, d = 1, 16384, 2560
+    options, kernels = _LONG_CONTEXT_LAYERS[kind]
+    monkeypatch.setattr(pallas_kernels, "is_tpu_device",
+                        lambda device: True)
+    root.common.precision_type = "bfloat16"
+    wf = DummyWorkflow()
+    src = DummyUnit(wf, output=Vector(np.zeros((b, t, d), np.float32),
+                                      name="x"))
+    unit = attention.MultiHeadAttention(
+        wf, n_heads=28, n_kv_heads=4, head_dim=128, causal=True,
+        pre_norm="rms", residual=True, include_bias=False,
+        norm_eps=1e-6, **options)
+    unit.link_attrs(src, ("input", "output"))
+    unit.initialize(device=XLADevice())
+    plan = unit._flash
+    assert plan.runs and plan.layout == "boundary"
+    assert plan.n_heads // plan.n_kv_heads == 7 and plan.backward == 2
+    if options.get("window"):
+        assert pallas_attention.band_steps(t, 512, 512, 4096) == (9, 9)
+    else:
+        assert pallas_attention.grid_blocks(True, t, t) == (1024, 2048)
+
+    def struct(a):
+        return None if a is None else jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e_chip)
+
+    def step(dy, *args):
+        out, pullback = jax.vjp(unit.xla_forward, *args)
+        return out, pullback(dy)
+
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:   # a described chip's executable cannot be read back here
+        text = jax.jit(step).lower(
+            jax.ShapeDtypeStruct((b, t, d), jnp.float32,
+                                 sharding=v5e_chip),
+            *(struct(a) for a in unit.forward_args())) \
+            .compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    for kernel in kernels:
+        assert re.search(rf"%\w*{kernel}[._\d]* = ", text), kernel
+    assert "znicz_flash_bwd" not in text
+    assert ("znicz_flash_fwd_win" in text) == bool(options.get("window"))
+
+
 def test_gated_delta_rule_compiled_for_v5e_keeps_a_chunk_in_vmem(v5e_chip):
     """The rule's forward + backward at the Olmo-Hybrid cell's shape
     (T 4,096, 30 heads of 96 × 192, bf16 products), compiled for the

@@ -1249,3 +1249,229 @@ def test_export_and_decode_refuse_the_expert_layer_and_the_block():
     from znicz_tpu.serving import decode
     assert "refuse_unserved(units" in inspect.getsource(
         decode.DecodeModel._build_plan)
+
+
+# ----------------------------------------------------------------------
+# the experts' gate function and a router that reads the block's input
+# (PR 50)
+# ----------------------------------------------------------------------
+def block(device, x, held, params=None, act="relu"):
+    """A residual sublayer (a dense gated MLP) and, after it, an expert
+    layer whose router reads THAT sublayer's input, with both backward
+    units, wired as ``StandardWorkflow`` wires them."""
+    prng.seed_all(5)
+    wf = DummyWorkflow()
+    src = DummyUnit(wf, output=Vector(np.asarray(x), name="x"))
+    sub = moe.GatedMLP(wf, width=F, pre_norm="rms", residual=True,
+                       act=act)
+    sub.link_attrs(src, ("input", "output"))
+    fwd = moe.MoE(wf, **{**OPTIONS, "held": held, "act": act,
+                         "norm_topk": True,
+                         "route_from": "block_input"})
+    fwd.link_attrs(sub, ("input", "output"))
+    fwd.link_attrs(sub, ("route_input", "input"))
+    sub.initialize(device=device)
+    fwd.initialize(device=device)
+    rng = np.random.default_rng(3)
+    for unit in (sub, fwd):
+        unit.gain_norm.reset(rng.uniform(0.5, 1.5, D).astype(np.float32))
+        unit.gain_norm.initialize(device)
+    for (unit, attr), arr in (params or {}).items():
+        vec = getattr((sub, fwd)[unit], attr)
+        vec.reset(np.array(arr, np.float32))
+        vec.initialize(device)
+    gds = []
+    for unit, cls in ((sub, moe.GDGatedMLP), (fwd, moe.GDMoE)):
+        gd_u = cls(wf, learning_rate=0.05, gradient_moment=0.9)
+        gd_u.forward_unit = unit
+        gd_u.link_attrs(unit, "input", "output", "weights", "bias")
+        gds.append(gd_u)
+    gds[1].err_output = Vector(np.zeros(np.shape(x), np.float32),
+                               name="err")
+    gds[1].initialize(device=device)
+    gds[0].link_attrs(gds[1], ("err_output", "err_input"))
+    gds[0].initialize(device=device)
+    fwd.route_gd = gds[0]
+    return (sub, fwd), gds
+
+
+BLOCK_PARAMS = [(0, attr) for attr in ("weights", "weights_up",
+                                       "weights_down", "gain_norm")] \
+    + [(1, attr) for attr in PARAMS]
+
+
+def block_state(units, gds) -> dict:
+    out = {}
+    for unit, attr in BLOCK_PARAMS:
+        vec = getattr(units[unit], attr)
+        vec.map_read()
+        out[unit, attr] = np.array(vec.mem, np.float32)
+    for vec, key in ((units[1].output, "output"),
+                     (gds[0].err_input, "err_input")):
+        vec.map_read()
+        out[key] = np.array(vec.mem, np.float32)
+    return out
+
+
+def block_steps(units, gds, err, n=2) -> dict:
+    for _ in range(n):
+        for unit in units:
+            unit.run()
+        gds[1].err_output.reset(err.copy())
+        gds[1].err_output.initialize(units[0].device)
+        for gd_u in reversed(gds):
+            gd_u.run()
+    return block_state(units, gds)
+
+
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["ragged_dot", "kernels_interpreted"])
+@pytest.mark.parametrize("share", ["dropless", "held_two_lengths",
+                                   "held_one_length"])
+def test_relu_experts_and_the_early_router_against_the_numpy_oracle(
+        share, kernel, monkeypatch):
+    """``act="relu"`` and ``route_from="block_input"`` — XLA path
+    against the numpy oracle after two momentum steps: the layer's
+    output, the cotangent that leaves the BLOCK (the sublayer's own
+    plus the router's share, joined after the sublayer's backward) and
+    every parameter of both units, the router's among them — dropless,
+    and a held share at both of its buffer lengths."""
+    if kernel:
+        kernels_interpreted()
+    if share == "held_one_length":
+        monkeypatch.setattr(moe, "HELD_FIT", moe.HELD_SLACK)
+    held = None if share == "dropless" else (1, 3, 4, 6)
+    rng = np.random.default_rng(2)
+    x = rng.normal(0, 1.0, (4, T, D)).astype(np.float32)
+    err = rng.normal(0, 0.1, x.shape).astype(np.float32)
+    np_units, np_gds = block(NumpyDevice(), x, held)
+    drawn = block_state(np_units, np_gds)
+    xla_units, xla_gds = block(XLADevice(), x, held, params={
+        key: drawn[key] for key in BLOCK_PARAMS})
+    assert xla_units[1]._gmm_kernel == kernel
+    if held:
+        assert (xla_units[1]._fit < xla_units[1]._capacity) \
+            == (share == "held_two_lengths")
+    want = block_steps(np_units, np_gds, err)
+    got = block_steps(xla_units, xla_gds, err)
+    for key, value in want.items():
+        np.testing.assert_allclose(got[key], value, rtol=2e-3, atol=3e-5,
+                                   err_msg=str(key))
+    for key in BLOCK_PARAMS:          # and every parameter MOVED
+        assert np.abs(want[key] - drawn[key]).max() > 0, key
+    # the count of the hidden beside moe_stats: about half is not zero
+    for units in (np_units, xla_units):
+        stats = units[1].hidden_stats
+        stats.map_read()
+        live, total = stats.mem
+        assert total > 0 and 0.25 * total < live < 0.75 * total
+        assert total % F == 0
+    assert np_units[0].act == xla_units[0].act == "relu"
+
+
+def test_the_router_s_cotangent_joins_the_sublayer_s(monkeypatch):
+    """The block's input cotangent is ``jax.grad`` of the composed
+    block — sublayer, then the expert layer with its router on the
+    block's input — and NOT the chain's alone: without the second
+    backward edge the router's share is missing."""
+    import jax
+    import jax.numpy as jnp
+    rng = np.random.default_rng(4)
+    x = rng.normal(0, 1.0, (4, T, D)).astype(np.float32)
+    err = rng.normal(0, 0.1, x.shape).astype(np.float32)
+    (sub, fwd), gds = block(XLADevice(), x, (1, 3, 4, 6))
+    fwd.weights.map_write()
+    fwd.weights.mem[...] *= 4.0       # a router that matters
+    fwd.weights.unmap()
+    got = block_steps((sub, fwd), gds, err, n=1)["err_input"]
+
+    (sub, fwd), gds = block(XLADevice(), x, (1, 3, 4, 6))
+    fwd.weights.map_write()
+    fwd.weights.mem[...] *= 4.0
+    fwd.weights.unmap()
+
+    def composed(x_, detach):
+        a = sub.xla_forward(x_, *sub.forward_args()[1:])
+        route = jax.lax.stop_gradient(x_) if detach else x_
+        (y, (lb, z)), _ = fwd.xla_forward(
+            a, *fwd.forward_args()[1:-1], x_route=route)
+        return jnp.sum(y * jnp.asarray(err)) \
+            + fwd.aux_loss_weight * lb + fwd.z_loss_weight * z
+
+    whole = np.asarray(jax.grad(composed)(jnp.asarray(x), False))
+    chain = np.asarray(jax.grad(composed)(jnp.asarray(x), True))
+    np.testing.assert_allclose(got, whole, rtol=2e-4, atol=2e-6)
+    assert np.abs(whole - chain).max() > 1e-3 * np.abs(whole).max()
+
+
+@pytest.mark.parametrize("device_cls", [NumpyDevice, XLADevice])
+def test_the_dense_gated_mlp_takes_the_gate_function_too(device_cls):
+    rng = np.random.default_rng(6)
+    x = rng.normal(0, 1.0, (B, T, D)).astype(np.float32)
+    outs = {}
+    for act in ("silu", "relu"):
+        prng.seed_all(5)
+        wf = DummyWorkflow()
+        src = DummyUnit(wf, output=Vector(x.copy(), name="x"))
+        unit = moe.GatedMLP(wf, width=F, act=act)
+        unit.link_attrs(src, ("input", "output"))
+        unit.initialize(device=device_cls())
+        unit.run()
+        unit.output.map_read()
+        for vec in (unit.weights, unit.weights_up, unit.weights_down):
+            vec.map_read()
+        gate = x.reshape(-1, D) @ unit.weights.mem
+        hidden = (gate / (1 + np.exp(-gate)) if act == "silu"
+                  else np.maximum(gate, 0)) \
+            * (x.reshape(-1, D) @ unit.weights_up.mem)
+        np.testing.assert_allclose(
+            np.asarray(unit.output.mem, np.float32).reshape(-1, D),
+            hidden @ unit.weights_down.mem, rtol=2e-4, atol=2e-5)
+        outs[act] = np.array(unit.output.mem)
+    assert np.abs(outs["silu"] - outs["relu"]).max() > 1e-2
+
+
+def test_what_the_two_options_refuse():
+    from znicz_tpu.export import refuse_unserved
+    wf = DummyWorkflow()
+    with pytest.raises(ValueError, match="act"):
+        moe.MoE(wf, **{**OPTIONS, "act": "gelu"})
+    with pytest.raises(ValueError, match="act"):
+        moe.GatedMLP(wf, width=F, act="tanh")
+    with pytest.raises(ValueError, match="route_from"):
+        moe.MoE(wf, **{**OPTIONS, "route_from": "the_embedding"})
+    # no route_input linked: the unit says what links it
+    unit = moe.MoE(wf, **{**OPTIONS, "route_from": "block_input"})
+    unit.link_attrs(DummyUnit(wf, output=Vector(
+        np.zeros((B, T, D), np.float32), name="x")), ("input", "output"))
+    with pytest.raises(AttributeError, match="route_input"):
+        unit.initialize(device=NumpyDevice())
+    # a table whose expert layer has no residual sublayer before it
+    ids = np.zeros((4, T), np.float32)
+    back = {"learning_rate": 0.1}
+    for before in ([], [{"type": "gated_mlp", "->": {"width": F},
+                         "<-": back}]):
+        with pytest.raises(ValueError, match="layer %d: route_from"
+                           % (1 + len(before))):
+            StandardWorkflow(
+                name="refused",
+                loader_factory=lambda w: ArrayLoader(
+                    w, train_data=ids,
+                    train_labels=np.zeros((4, T), np.int32),
+                    minibatch_size=2),
+                layers=[{"type": "embedding",
+                         "->": {"vocab_size": 8, "dim": D}, "<-": back},
+                        *before,
+                        {"type": "moe", "->": {
+                            **OPTIONS, "route_from": "block_input"},
+                         "<-": back}])
+    # serving refuses both by name
+    with pytest.raises(NotImplementedError, match="route_from"):
+        refuse_unserved([moe.GatedMLP(wf, width=F, residual=True), unit],
+                        "export_forward")
+    with pytest.raises(NotImplementedError, match="act=relu"):
+        refuse_unserved([moe.MoE(wf, **{**OPTIONS, "act": "relu"})],
+                        "DecodeModel")
+    with pytest.raises(NotImplementedError, match="act=relu"):
+        refuse_unserved([moe.GatedMLP(wf, width=F, act="relu")],
+                        "DecodeModel")

@@ -120,9 +120,53 @@ def test_every_member_unit_is_in_the_map(build):
         # a layer's two units are one family: the forward's class
         assert {by_unit[u.name]["family"] for u in (fwd, gd)
                 if u.name in by_unit} == {type(fwd).__name__}
-        assert phases_of(ops, fwd.name) <= {"forward"}
+        # (an expert layer's router, top k and dispatch plan run under
+        # the scope ``route``, forward and pullback: PR 50)
+        route = {"route"} if type(fwd).__name__ == "MoE" else set()
+        assert phases_of(ops, fwd.name) <= {"forward"} | route
         assert phases_of(ops, gd.name) \
-            <= {"backward", "update", "fingerprint"}
+            <= {"backward", "update", "fingerprint"} | route
+
+
+@pytest.mark.parametrize("early", [False, True],
+                         ids=["router_on_its_own_input",
+                              "router_on_the_block_input"])
+def test_route_is_a_phase_of_the_expert_layer_forward_and_pullback(early):
+    """The router's logits, top k and the dispatch's sort lie under the
+    scope ``route`` (``ops/moe.py``): whatever XLA kept of them apart
+    from their neighbours reads phase ``route``, in the forward unit
+    and — ``transpose(jvp(route))`` — in the backward unit; with
+    ``route_from`` the logits come from the block's input and the map
+    is the same."""
+    from znicz_tpu.observe import scopes
+    assert scopes._ROUTE.search("jit(step)/MoE_2/jvp(route)/dot_general")
+    assert scopes._ROUTE.search("a/GDMoE_2/transpose(jvp(route))/mul")
+    assert not scopes._ROUTE.search("a/MoE_2/jvp()/router_bias/add")
+    wf = attention_moe(f"scopes_route_{early}")
+    if early:
+        table = [dict(layer) for layer in wf.layers_config]
+        table[2] = {**table[2], "->": {
+            **table[2]["->"], "route_from": "block_input",
+            "act": "relu"}}
+        ids = np.random.default_rng(6).integers(0, 29, (8, 9))
+        prng.seed_all(21)
+        wf = StandardWorkflow(
+            name="scopes_route_early",
+            loader_factory=lambda w: ArrayLoader(
+                w, train_data=ids[:, :-1].astype(np.float32),
+                train_labels=ids[:, 1:].astype(np.int32),
+                minibatch_size=4, shuffle_limit=0),
+            layers=table, decision_config={"max_epochs": 1})
+        wf.initialize(device=XLADevice())
+    wf.run()
+    ops = only_program(f"znicz_step__{wf._region_unit.region.name}")
+    expert = next(u for u in wf.forwards if type(u).__name__ == "MoE")
+    gd = wf.gds[wf.forwards.index(expert)]
+    assert "route" in phases_of(ops, expert.name)
+    assert phases_of(ops, gd.name) & {"route", "backward"}
+    for unit in wf.forwards + wf.gds:
+        if unit not in (expert, gd):
+            assert "route" not in phases_of(ops, unit.name)
 
 
 def test_update_and_fingerprint_are_phases_of_the_backward_unit():

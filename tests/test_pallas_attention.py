@@ -406,6 +406,8 @@ def test_tile_schedule_is_derived_from_the_shapes():
     (True, 4096, 1024, None, (1024, 2)),    # a caller's (the ring's) tile
     (True, 1024, 1024, None, (1024, 1)),
     (True, 4096, 512, 512, (512, 2)),       # Laguna's sliding layers
+    (True, 16384, 512, 4096, (512, 2)),     # SmallThinker's RoPE layers
+    (True, 8192, 512, 4096, (512, 2)),
     (True, 4096, 2048, 512, (2048, 2)),
     (False, 2048, 1024, None, (1024, 2)),   # non-causal
     (False, 1024, 1024, None, (1024, 2)),
@@ -440,6 +442,9 @@ PASSES = [
     (1, True, 24, 1, 2),        # a window: a Q tile meets two K tiles
     (4, True, 24, 6, 2),
     (1, True, 64, 6, 1),        # a window that covers T is the causal call
+    (8, True, None, 7, 2),      # SmallThinker's full layer: 7 query heads
+                                # a K/V head over a deep K grid
+    (8, True, 40, 7, 2),        # … and its band, six tiles wide here
 ]
 
 
@@ -819,3 +824,82 @@ def test_unit_engages_flash_only_on_tpu(monkeypatch):
     FakeDev.device_kind = "TPU v5 lite"
     assert not pallas_kernels.is_tpu_device(D())
 
+
+
+# ----------------------------------------------------------------------
+# the shapes of smallthinker_train_1of8 in small (PR 50): seven query
+# heads a K/V head; an un-windowed causal call past WHOLE_BLOCK_K, so the
+# two-pass backward over a deep K grid at the chooser's own tiles; a band
+# NINE tiles wide
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("case", ["causal_past_the_whole_key_range",
+                                  "band_nine_tiles_wide"])
+@pytest.mark.parametrize("dh", [16, 128], ids=["head_major", "boundary"])
+def test_seven_queries_a_kv_head_at_the_long_cells_tilings(case, dh,
+                                                           monkeypatch):
+    from tests.test_laguna_reference import oracle as _gqa_oracle
+    from znicz_tpu.ops import pallas_attention as pa
+    t, h, h_kv = 192, 7, 1
+    if case == "band_nine_tiles_wide":
+        window = 128
+        monkeypatch.setattr(pa, "BAND_BLOCK", 16)
+        assert pa.band_blocks(t) == (16, 16)
+        assert pa.band_steps(t, 16, 16, window) == (9, 9)
+        kernels = ["znicz_flash_fwd_win", "znicz_flash_dq_win",
+                   "znicz_flash_dkv_win"]
+    else:
+        # the chooser's own tiles, a key range it does not take whole
+        window = None
+        monkeypatch.setattr(pa, "BLOCK_Q", 32)
+        monkeypatch.setattr(pa, "CAUSAL_BLOCK_K", 32)
+        monkeypatch.setattr(pa, "WHOLE_BLOCK_K", 64)
+        assert pa.grid_blocks(True, t, t) == (32, 32)
+        assert pa.backward_block_k(True, t, 32) == 32
+        assert pa.backward_passes(True, t, 32) == 2
+        kernels = ["znicz_flash_fwd", "znicz_flash_dq", "znicz_flash_dkv"]
+    q = _rand((1, t, h, dh), 1)
+    k = _rand((1, t, h_kv, dh), 2)
+    v = _rand((1, t, h_kv, dh), 3)
+    weight = _rand((1, t, h, dh), 4)
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=True,
+                               window=window)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * weight)
+
+    assert _kernel_names(jax.grad(loss(kernel), (0, 1, 2)), q, k, v) \
+        == kernels
+    want = _gqa_oracle(q, k, v, window)
+    np.testing.assert_allclose(kernel(q, k, v), want, atol=3e-5,
+                               rtol=3e-5)
+    g_want = jax.grad(loss(lambda *a: _gqa_oracle(*a, window)),
+                      (0, 1, 2))(q, k, v)
+    g_got = jax.grad(loss(kernel), (0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), g_got, g_want):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("t,overwork", [(16384, 1.125), (8192, 1.125)])
+def test_a_band_of_4096_under_tiles_of_512_runs_an_eighth_over(t,
+                                                                overwork):
+    """What ``flash_band_overwork`` reads in ``smallthinker_train_1of8``:
+    a row block past row 4,096 visits nine K tiles for a band of eight
+    tiles' pairs, the first eight row blocks 36 tiles for 32 tiles' worth
+    — where Laguna's band of 512 reads 2.0."""
+    from znicz_tpu.ops import pallas_attention as pa
+    assert pa.band_blocks(t) == (512, 512)
+    assert pa.band_steps(t, 512, 512, 4096) == (9, 9)
+    counts = pa.causal_tile_counts(t, t, 512, 512, 512, 512, window=4096)
+    assert counts["executed_share"] / pa.band_share(t, 4096) \
+        == pytest.approx(overwork, rel=2e-3)
+    laguna = pa.causal_tile_counts(4096, 4096, 512, 512, 512, 512,
+                                   window=512)
+    assert laguna["executed_share"] / pa.band_share(4096, 512) \
+        == pytest.approx(2.0, rel=2e-2)
+    # seven of a row block's nine tiles lie wholly inside the band: no
+    # mask code (the diagonal's and the lower edge's are masked)
+    assert counts["interior"] > 3 * (counts["crossing"]
+                                     + counts["band_edge"])

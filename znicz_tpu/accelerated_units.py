@@ -262,6 +262,13 @@ class AcceleratedUnit(Unit):
             self.numpy_run()
         else:
             self.xla_run()
+        self.join_beside()
+
+    def join_beside(self) -> None:
+        """What reaches this unit beside the chain, joined to what its
+        run made — after every run, eager or traced.  Nothing, but for
+        a backward unit whose input a unit further on read too
+        (``GradientDescentBase.join_beside``)."""
 
     def numpy_run(self) -> None:
         raise NotImplementedError(f"{type(self).__name__}.numpy_run")
@@ -612,9 +619,11 @@ class JitRegion(Logger):
         def trace(unit, pass_index: int | None = None) -> None:
             with jax.named_scope(unit.name):
                 if pass_index is None:
-                    return unit.xla_run()
-                with jax.named_scope(f"pass{pass_index}"):
                     unit.xla_run()
+                else:
+                    with jax.named_scope(f"pass{pass_index}"):
+                        unit.xla_run()
+                unit.join_beside()
 
         heads = {}
         for span in self.pass_spans:

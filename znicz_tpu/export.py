@@ -221,6 +221,16 @@ def refuse_unserved(forwards, what: str) -> None:
     residual or the experts.  Refuse such a chain by name rather than
     serve another model than was trained (ROADMAP R1, serving half)."""
     for i, unit in enumerate(forwards):
+        if getattr(unit, "route_from", None):
+            # before the sublayer it reaches past is refused for itself
+            raise NotImplementedError(
+                f"{what}: layer {i} takes its router's logits from "
+                f"the input of the sublayer before it (moe, "
+                f"route_from={unit.route_from}); serving runs a chain "
+                f"one layer's output into the next — the edge beside it "
+                f"and the expert layer exist on the training path only "
+                f"(ROADMAP R1, serving half)")
+    for i, unit in enumerate(forwards):
         kind = type(unit).__name__
         span = getattr(unit, "pass_span", None)
         if span is not None:
@@ -238,8 +248,10 @@ def refuse_unserved(forwards, what: str) -> None:
                 f"exit distribution exist on the training path only "
                 f"(ROADMAP R7, serving half)")
         if kind == "GatedMLP":
+            relu = getattr(unit, "act", "silu") != "silu"
             raise NotImplementedError(
-                f"{what}: layer {i} is a gated MLP block (gated_mlp); "
+                f"{what}: layer {i} is a gated MLP block (gated_mlp"
+                f"{', act=' + unit.act if relu else ''}); "
                 f"serving runs no feed-forward sublayer yet (ROADMAP R1, "
                 f"serving half)")
         if kind == "GatedDeltaNet":
@@ -272,9 +284,11 @@ def refuse_unserved(forwards, what: str) -> None:
             choice = [name for name, on in (
                 ("select_bias", getattr(unit, "select_bias_on", False)),
                 ("groups", getattr(unit, "groups", None))) if on]
+            said = choice + [f"act={unit.act}" for _ in range(
+                getattr(unit, "act", "silu") != "silu")]
             raise NotImplementedError(
                 f"{what}: layer {i} is a sparse-expert layer (moe"
-                f"{', ' + ', '.join(choice) if choice else ''}); "
+                f"{', ' + ', '.join(said) if said else ''}); "
                 f"serving has no expert dispatch yet — router, top-k"
                 f"{', the selection bias and the group limit' if choice else ''} "
                 f"and grouped matmul exist on the training path only "

@@ -138,6 +138,16 @@ class StandardWorkflow(AcceleratedWorkflow):
         of the chain runs R times a step on shared weights, one
         gradient summed over the passes and one update
         (:mod:`znicz_tpu.pass_span`; XLA path only).
+        Two options of the feed-forward layers (``ops/moe.py``):
+        ``act`` (``moe``, ``gated_mlp``) names the gate function,
+        ``"silu"`` or ``"relu"`` — any other name is refused by the
+        unit; ``route_from="block_input"`` (``moe``) takes the router's
+        logits from the input of the layer BEFORE the expert layer, as
+        it is — refused here, by index, unless that layer is a
+        ``residual`` sublayer outside a looped span (the edge beside
+        the chain: :meth:`_link_route`), and by ``run_pipelined`` (a
+        stage boundary would cut it).  ``export_forward`` and the
+        decode engine refuse both by name (``export.refuse_unserved``).
     loss:
         ``"softmax"`` (classification) or ``"mse"``.
     decision_config / snapshotter_config:
@@ -266,6 +276,8 @@ class StandardWorkflow(AcceleratedWorkflow):
                 unit.pass_span = member_of[index]
             if isinstance(unit, streams._Stream):
                 self._link_stream(index, unit, prev)
+            if getattr(unit, "route_from", None):
+                self._link_route(index, unit, prev)
             self.forwards.append(unit)
             prev = unit
         unwritten = [i for i, u in enumerate(self.forwards)
@@ -311,6 +323,31 @@ class StandardWorkflow(AcceleratedWorkflow):
                     f"layer {index}: a stream_write with no sublayer "
                     f"between it and its stream_read, layer {at}")
             unit.read_unit, read.write_unit = read, unit
+
+    def _link_route(self, index: int, unit, prev) -> None:
+        """An expert layer's second forward edge (``ops/moe.py``,
+        ``route_from="block_input"``): its router reads the INPUT of
+        the residual sublayer before it — the block's input, the
+        ``residual`` being inside that sublayer's unit.  The stream
+        units' edge beside the chain (:meth:`_link_stream`) is of
+        another kind: that one carries the n·D-wide stream past a
+        sublayer from one stream unit to the next; this one carries a
+        plain (B, T, D) Vector past a sublayer into a unit of the
+        chain, and its cotangent back into that sublayer's GD
+        (:meth:`link_gds`).  A table that has no such sublayer there is
+        refused here, by index."""
+        looped = any(getattr(u, "pass_span", None) is not None
+                     for u in (prev, unit))
+        if not getattr(prev, "residual", False) or looped:
+            raise ValueError(
+                f"layer {index}: route_from={unit.route_from!r} reads "
+                f"the input of the residual sublayer before the expert "
+                f"layer, outside a looped span; layer {index - 1} is "
+                + ("the loader" if prev is None else
+                   f"a {self.layers_config[index - 1]['type']} "
+                   f"(residual: {getattr(prev, 'residual', False)}, "
+                   f"looped: {looped})"))
+        unit.link_attrs(prev, ("route_input", "input"))
 
     def link_evaluator(self, **config) -> None:
         last = self.forwards[-1]
@@ -383,6 +420,13 @@ class StandardWorkflow(AcceleratedWorkflow):
                         f"layer after it is "
                         f"{'none' if next_gd is None else 'no stream_read or stream_close'}")
                 unit.stream_gd = next_gd
+            if next_gd is not None and getattr(
+                    next_gd.forward_unit, "route_from", None):
+                # the expert layer after this sublayer read this
+                # sublayer's input too: its GD parks that cotangent
+                # here, and this unit joins it to its own
+                # (GradientDescentBase.join_beside)
+                next_gd.forward_unit.route_gd = unit
             if span is not None:
                 span.gds.insert(0, unit)
             # train minibatches only (reference: decision.gd_skip)
@@ -851,6 +895,16 @@ class StandardWorkflow(AcceleratedWorkflow):
                 f"are built from one forward and one backward per "
                 f"layer, and a state that returns to an earlier stage "
                 f"has no place in their schedule")
+        routed = [i for i, unit in enumerate(self.forwards)
+                  if getattr(unit, "route_from", None)]
+        if routed:
+            raise NotImplementedError(
+                f"workflow '{self.name}': run_pipelined does not run "
+                f"layer {routed[0]} (moe, route_from="
+                f"{self.forwards[routed[0]].route_from}): its router "
+                f"reads the sublayer before it beside the chain, and "
+                f"that cotangent is handed back inside ONE program — "
+                f"a stage boundary between the two would cut the edge")
         n_micro = self._microbatches(microbatches)
         self._require_microbatchable(n_micro, "pipelined")
         executor = self._pipeline

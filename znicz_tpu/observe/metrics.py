@@ -767,6 +767,23 @@ def moe_router(unit: str, stat: str) -> Gauge:
         labels=("unit", "stat")).labels(unit=unit, stat=stat)
 
 
+def moe_hidden(unit: str, stat: str) -> Gauge:
+    """What a ``MoE`` unit of ReLU experts (``act="relu"``) found in
+    its hidden, ``relu(W_gate m) ⊙ W_up m`` over the rows of the experts
+    it holds, counted on the device beside ``moe_stats`` and summed over
+    the steps since the last epoch-end read (``stat`` = ``live``:
+    elements that are not zero; ``total``: elements there are — of a
+    held share, over the steps that ran at the fit size).  ``live`` /
+    ``total`` ≈ 0.5 at initialisation: the half a ReLU leaves is the
+    work a sparse down-projection would skip, which the layer counts
+    and does not exploit."""
+    return REGISTRY.gauge(
+        "znicz_moe_hidden",
+        "Non-zero and all elements of a ReLU expert layer's hidden "
+        "since the last epoch-end read",
+        labels=("unit", "stat")).labels(unit=unit, stat=stat)
+
+
 def attention_latent(unit: str, stat: str) -> Gauge:
     """The static sizes of a latent-K/V attention unit
     (``MultiHeadAttention`` with ``kv_latent``; ``stat`` = ``latent``:

@@ -13,7 +13,7 @@ of the COMPILED step program carries
     {program name: {HLO instruction name: {
         "unit": "GDMoE_2", "kind": "GDMoE", "family": "MoE",
         "phase": "forward" | "backward" | "update" | "fingerprint"
-                 | "pass_sum" | "router_bias"}}}
+                 | "pass_sum" | "router_bias" | "route"}}}
 
 - ``kind`` is the unit's class, ``family`` the forward class a
   backward unit is paired with (a forward unit's own pairing class):
@@ -23,7 +23,10 @@ of the COMPILED step program carries
   if EVERY scoped instruction in it lies inside that scope
   (``fingerprint`` is nested in ``update`` and reads likewise; so does
   ``pass_sum``, the sum of a looped span's partial gradients over its
-  passes), else its unit's forward / backward.  A member of a looped
+  passes; ``router_bias``, the selection bias's rule; ``route``, an
+  expert layer's logits, scores, top k and the sort that plans its
+  dispatch, forward and pullback), else its unit's forward /
+  backward.  A member of a looped
   span traces each application under ``<unit>/pass<r>/``: the pass is
   in the ``op_name`` path, the unit is still the outermost scope;
 - a fusion is attributed by ALL the instructions fused into it (the
@@ -146,6 +149,11 @@ def forget() -> None:
 # ----------------------------------------------------------------------
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([^\s=]+)\s+=\s+(.*)$")
 _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+#: the scope ``route`` of an expert layer (``ops/moe.py``) in an
+#: ``op_name``: a path element of its own, bare in what the unit traced
+#: itself, inside ``jvp(…)`` / ``transpose(jvp(…))`` in a forward traced
+#: under ``jax.vjp`` and in that forward's pullback
+_ROUTE = re.compile(r"(?:^|[/(])route(?:[/)]|$)")
 _CALLEES = re.compile(
     r"\b(?:calls|to_apply|body|condition|true_computation|"
     r"false_computation|branch_computations|called_computations)="
@@ -239,7 +247,8 @@ def attribute(text: str, units: tuple) -> dict:
         found = scope_of(op_name, names) if op_name else None
         return found and found + (
             found[1] and "/pass_sum/" in f"/{op_name}/",
-            "/router_bias/" in f"/{op_name}/")
+            "/router_bias/" in f"/{op_name}/",
+            _ROUTE.search(op_name) is not None)
 
     def scopes_in(computation: str) -> frozenset:
         """Scopes of every instruction in a computation and in what
@@ -283,14 +292,16 @@ def _entry(scopes: set, units: tuple) -> dict:
     parts = []
     for index in sorted(by_unit):
         name, kind, family, backward = units[index]
-        if all(fingerprint for _u, fingerprint, _s, _r in by_unit[index]):
+        if all(fingerprint for _u, fingerprint, *_ in by_unit[index]):
             phase = "fingerprint"
-        elif all(pass_sum for _u, _f, pass_sum, _r in by_unit[index]):
+        elif all(pass_sum for _u, _f, pass_sum, *_ in by_unit[index]):
             phase = "pass_sum"
-        elif all(update for update, _f, _s, _r in by_unit[index]):
+        elif all(update for update, *_ in by_unit[index]):
             phase = "update"
-        elif all(bias for _u, _f, _s, bias in by_unit[index]):
+        elif all(bias for *_, bias, _r in by_unit[index]):
             phase = "router_bias"
+        elif all(route for *_, route in by_unit[index]):
+            phase = "route"
         else:
             phase = "backward" if backward else "forward"
         parts.append({"unit": name, "kind": kind, "family": family,

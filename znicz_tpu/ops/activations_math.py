@@ -76,6 +76,41 @@ ACTIVATIONS: dict[str, Activation] = {
 }
 
 
+def _logistic(xp, x):
+    return 1.0 / (1.0 + xp.exp(-x))
+
+
+#: the gate functions of a gated MLP, ``W_down (gate(W_gate m) ⊙ W_up
+#: m)`` (``ops/moe.py``: the experts, the shared expert, ``GatedMLP``) —
+#: the ONE home of each function and its derivative, by the name the
+#: layers' ``act`` option takes: ``silu`` (SwiGLU) and ``relu`` (ReGLU:
+#: ``max(x, 0)``, the table's ``strict_relu`` — the reference's
+#: ``relu`` is a softplus)
+GATES: dict[str, Activation] = {
+    "silu": Activation(
+        "silu",
+        fwd=lambda xp, x: x / (1.0 + xp.exp(-x)),
+        # with σ = σ(x): d/dx x·σ = σ·(1 + x·(1 − σ))
+        derivative=lambda xp, y, x: _logistic(xp, x) * (
+            1.0 + x * (1.0 - _logistic(xp, x))),
+        needs_input=True),
+    "relu": Activation(
+        "relu",
+        fwd=lambda xp, x: xp.maximum(x, 0),
+        derivative=lambda xp, y, x: (x > 0).astype(x.dtype),
+        needs_input=True),
+}
+
+
+def gate(name: str) -> Activation:
+    """The gate function a gated MLP's ``act`` option names."""
+    try:
+        return GATES[name]
+    except KeyError:
+        raise ValueError(
+            f"act must be one of {sorted(GATES)}, got {name!r}") from None
+
+
 def get(name: str) -> Activation:
     try:
         return ACTIVATIONS[name]

@@ -134,8 +134,38 @@ and restores it; it lies OUTSIDE the SDC fingerprint, which folds what
 ``_apply_param_xla`` updates: a flipped bit in b moves a choice, not a
 value, and the next steps' rule pulls it back.
 
+``act`` and ``route_from`` (SmallThinker-21BA3B, PR 50;
+arXiv:2507.20984).  ``act`` names the gate function of every gated MLP
+of the layer — the experts', the shared expert's; ``GatedMLP`` takes it
+too: ``"silu"`` (SwiGLU, the layer above) or ``"relu"`` (ReGLU:
+``W_down (max(W_gate m, 0) ⊙ W_up m)``).  The function and its
+derivative have ONE home, ``activations_math.GATES``; the XLA path
+differentiates the function, the numpy oracle uses the derivative.
+About half of a ReLU layer's hidden is zeros, which the layer COUNTS —
+``hidden_stats``, two numbers kept on the device beside ``moe_stats``
+and read with it (``znicz_moe_hidden{stat="live"|"total"}``; of a held
+share, over the steps that ran at the fit size) — and does not exploit.
+**``route_from="block_input"``** takes the router's logits from another
+tensor than the experts read: the INPUT of the residual sublayer before
+this one (the block's input — ``residual`` is inside that sublayer's
+unit), as it is, before that sublayer's norm and before this one's:
+``r = x W_r`` where the experts read ``m = RMSNorm(a)``, ``a = x +
+Attn(RMSNorm(x))``.  The choice is then known a sublayer ahead of the
+experts, which a deployment uses to send or fetch by it while attention
+runs; here nothing overlaps, the option is the model's mathematics.
+It is a second forward edge into the unit (``route_input``, linked by
+``StandardWorkflow._link_route``, which refuses a table with no
+residual sublayer there) and a second backward edge out of it: the
+pullback's cotangent of ``x_route``, ``∂L/∂r · W_rᵀ``, does not go down
+the chain but is parked on that sublayer's GD unit
+(``GradientDescentBase.park_beside``) and joined to its ``err_input``
+after its own run, inside the one step program.  The logits, the
+scores, the top k, its weights and the sort that plans the dispatch run
+under the scope ``route`` (forward and pullback:
+``observe.op_scopes()`` gives such an operation the phase ``route``).
+
 ``GatedMLP`` is the same gated MLP with no router — a model's dense
-feed-forward block (``y = x + W_down (silu(W_gate m) ⊙ W_up m)``).
+feed-forward block (``y = x + W_down (act(W_gate m) ⊙ W_up m)``).
 """
 
 from __future__ import annotations
@@ -150,7 +180,7 @@ import jax
 import jax.numpy as jnp
 
 from znicz_tpu.memory import Vector
-from znicz_tpu.ops import pallas_gmm
+from znicz_tpu.ops import activations_math, pallas_gmm
 from znicz_tpu.ops.nn_units import Forward, GradientDescentBase
 from znicz_tpu.ops.rms_norm import (norm_gains, post_gain, rms_norm,
                                     rms_norm_backward)
@@ -315,6 +345,13 @@ def _unpermute_bwd(residual, grad):
 _unpermute.defvjp(_unpermute_fwd, _unpermute_bwd)
 
 
+def _hidden_stats(live, total):
+    """``[elements of the hidden that are not zero, elements there
+    are]`` as a ReLU layer's ``hidden_stats`` adds them up."""
+    return jax.lax.stop_gradient(
+        jnp.stack([live, total]).astype(jnp.float32))
+
+
 class _Held(typing.NamedTuple):
     """What the routed sum of a held share reads of its layer beside
     the arrays: hashable, so that the layers of a model that agree on
@@ -326,6 +363,7 @@ class _Held(typing.NamedTuple):
     interpret: bool
     fit: int                   # the buffer's two static lengths
     capacity: int
+    act: str = "silu"          # the experts' gate function
 
 
 def _cut(sizes, length):
@@ -364,7 +402,7 @@ def _held_rows(plan, length, m, top_p, w_g, w_u, w_d, taps, casts, order,
                            0.0).astype(dt))
     gate = saved(grouped_matmul(rows, w_g, sizes, *path, tap=taps[0]))
     up = saved(grouped_matmul(rows, w_u, sizes, *path, tap=taps[1]))
-    hidden = saved((_silu(jnp, gate) * up).astype(dt))
+    hidden = saved((_gate(jnp, plan.act, gate) * up).astype(dt))
     out = saved(grouped_matmul(hidden, w_d, sizes, *path, tap=taps[2]))
     weight = jnp.where(live, jnp.take(top_p.reshape(n * k), pair), 0.0)
     f = jnp.zeros((n, d), jnp.float32).at[token].add(
@@ -471,7 +509,12 @@ def _fit_or_capacity_fwd(plan, *args):
     f, sizes, kept = jax.lax.cond(
         args[-1] <= plan.fit,
         lambda *a: _held_rows(plan, plan.fit, *a), at_capacity, *args)
-    return (f, sizes), (args, kept)
+    out = (f, sizes)
+    if plan.act == "relu":
+        # … and how much of the hidden is not zero, of a step at the
+        # fit size (the capacity branch keeps none: 0 there)
+        out += (jnp.count_nonzero(kept[3]),)
+    return out, (args, kept)
 
 
 @functools.partial(jax.jit, static_argnums=0)
@@ -499,18 +542,24 @@ def _fit_or_capacity_bwd(plan, residual, grads):
 _fit_or_capacity.defvjp(_fit_or_capacity_fwd, _fit_or_capacity_bwd)
 
 
-def _silu(xp, x):
-    return x / (1.0 + xp.exp(-x))
+def _gate(xp, act: str, x):
+    """``act(x)``, ``act`` a name of ``activations_math.GATES``."""
+    return activations_math.gate(act).fwd(xp, x)
+
+
+#: (``ops/delta_net.py`` takes both from here)
+_silu = activations_math.GATES["silu"].fwd
 
 
 def _sigmoid(xp, x):
     return 1.0 / (1.0 + xp.exp(-x))
 
 
-def gated_mlp(xp, dot, m, w_gate, w_up, w_down):
-    """``W_down (silu(W_gate m) ⊙ W_up m)`` of (N, D) rows; ``dot`` is
-    the unit's matmul (bf16 inputs on the device, plain in numpy)."""
-    return dot(xp, _silu(xp, dot(xp, m, w_gate)) * dot(xp, m, w_up),
+def gated_mlp(xp, dot, m, w_gate, w_up, w_down, act: str = "silu"):
+    """``W_down (act(W_gate m) ⊙ W_up m)`` of (N, D) rows; ``dot`` is
+    the unit's matmul (bf16 inputs on the device, plain in numpy),
+    ``act`` a name of ``activations_math.GATES``."""
+    return dot(xp, _gate(xp, act, dot(xp, m, w_gate)) * dot(xp, m, w_up),
                w_down)
 
 
@@ -518,8 +567,14 @@ def _np_dot(xp, a, b):
     return a @ b
 
 
+#: the one place ``route_from`` may name: the input of the block, that
+#: is of the residual sublayer before the expert layer
+ROUTE_FROM = ("block_input",)
+
+
 class MoE(Forward):
-    """Dropless top-k mixture of SwiGLU experts (module docstring)."""
+    """Dropless top-k mixture of gated-MLP experts (module
+    docstring)."""
 
     EXPORT_PARAMS = ("weights", "weights_gate", "weights_up",
                      "weights_down", "gain_norm", "weights_shared_gate",
@@ -543,11 +598,26 @@ class MoE(Forward):
                  score: str = "softmax", routed_scale: float = 1.0,
                  shared_width: int = 0, held=None,
                  select_bias: bool = False, groups=None,
-                 bias_rate: float = 1e-3,
+                 bias_rate: float = 1e-3, act: str = "silu",
+                 route_from: str | None = None,
                  name=None, **kwargs) -> None:
         kwargs.setdefault("weights_filling", "xavier")
         kwargs["include_bias"] = False
         super().__init__(workflow, name=name, **kwargs)
+        #: the experts' (and the shared expert's) gate function
+        self.act = activations_math.gate(act).name
+        if route_from not in (None,) + ROUTE_FROM:
+            raise ValueError(f"route_from must be None or one of "
+                             f"{ROUTE_FROM}, got {route_from!r}")
+        #: where the router's logits are taken from (module docstring):
+        #: None — what the experts read; "block_input" — the input of
+        #: the residual sublayer before this one, as it is
+        self.route_from = route_from
+        #: … that tensor (``StandardWorkflow.link_forwards`` links it to
+        #: the sublayer's ``input``) and the sublayer's GD unit, which
+        #: joins the router's cotangent to its own (``link_gds``)
+        self.route_input: Vector | None = None
+        self.route_gd = None
         self.n_experts, self.top_k = int(n_experts), int(top_k)
         self.width = int(width)
         if not 1 <= self.top_k <= self.n_experts:
@@ -610,6 +680,11 @@ class MoE(Forward):
         #: visits cover, rows that are real (both 0 on the XLA path)],
         #: summed on the device
         self.moe_stats = Vector(name=f"{self.name}.moe_stats")
+        #: of a layer of ReLU experts: [elements of the hidden that are
+        #: not zero, elements there are] over the steps since the last
+        #: read, counted on the device (a held share: the steps that ran
+        #: at the fit size)
+        self.hidden_stats = Vector(name=f"{self.name}.hidden_stats")
         #: what the router did in the last step: its (N, E) logits and
         #: the (N, top_k) experts chosen — what a check against a plain
         #: reference needs, which must not re-decide near-ties
@@ -635,6 +710,19 @@ class MoE(Forward):
         b, t, d = self.input.shape
         e, f = self.n_experts, self.width
         local = self.n_local
+        if self.route_from is not None:
+            if self.route_input is None or not self.route_input:
+                raise AttributeError(
+                    f"{self}: route_from={self.route_from!r} and no "
+                    f"route_input linked (a layer table links it: "
+                    f"StandardWorkflow.link_forwards)")
+            if self.route_input.shape != self.input.shape:
+                raise ValueError(
+                    f"{self}: the router reads {self.route_input.shape}"
+                    f", the experts {self.input.shape}")
+        if self.act == "relu" and (
+                not self.hidden_stats or self.hidden_stats.shape != (2,)):
+            self.hidden_stats.reset(np.zeros(2, np.float32))
         if not self.weights:                       # the router
             self.weights.reset(self.fill_array(
                 (d, e), self.weights_filling, self.weights_stddev,
@@ -728,13 +816,17 @@ class MoE(Forward):
                      f"under scope router_bias)"
                      if self.select_bias_on else "")
                   + ("; group-limited: %d groups, %d kept" % self.groups
-                     if self.groups else ""))
+                     if self.groups else "")
+                  + (f"; {self.act} experts" if self.act != "silu" else "")
+                  + (f"; the router reads the {self.route_from}, as it is"
+                     if self.route_from else ""))
         self.init_vectors(self.input, self.output, self.weights,
                           self.weights_gate, self.weights_up,
                           self.weights_down, self.gain_norm,
                           self.moe_stats, self.router_logits,
                           self.last_choice, self.select_bias,
-                          self.select_load,
+                          self.select_load, self.hidden_stats,
+                          self.route_input,
                           *(getattr(self, attr) for attr in self.SHARED))
         self._keep_slab_copies()
 
@@ -798,6 +890,7 @@ class MoE(Forward):
                     else None)
         tail.append((jnp.zeros((), jnp.float32),) * len(self.TAPPED)
                     if getattr(self, "_gmm_kernel", False) else None)
+        tail.append(self.route_input.devmem if self.route_from else None)
         while tail and tail[-1] is None:
             tail.pop()
         return args + tuple(tail)
@@ -880,63 +973,78 @@ class MoE(Forward):
 
     def _held_experts(self, m, top_p, top_e, w_g, w_u, w_d, taps):
         """``(f, local counts, (rows here, rows over, whether the step
-        ran at the fit size))``: the routed sum of the pairs whose
-        expert lives here (module docstring)."""
+        ran at the fit size[, of ReLU experts: the hidden's elements
+        that are not zero and that there are]))``: the routed sum of
+        the pairs whose expert lives here (module docstring)."""
         n = m.shape[0]
         k, local = self.top_k, self.n_local
         plan = _Held(k, self.mxu_dtype or jnp.float32,
                      getattr(self, "_gmm_kernel", False),
                      getattr(self, "_gmm_interpret", False),
-                     self._fit, self._capacity)
-        table = np.full(self.n_experts, local, np.int32)
-        table[list(self.held)] = np.arange(local, dtype=np.int32)
-        slot = jnp.asarray(table)[top_e.reshape(n * k)]
+                     self._fit, self._capacity, self.act)
         casts = None
         if isinstance(w_g, tuple):    # (slab, its copy): forward_args
             (w_g, w_u, w_d), casts = zip(w_g, w_u, w_d)
-        # the pairs here first, by expert and inside an expert by
-        # token; the pairs of absent experts last
-        order = jnp.argsort(slot, stable=True).astype(jnp.int32)
-        sizes = (slot[:, None] == jnp.arange(local)[None, :]).sum(
-            axis=0, dtype=jnp.int32)
-        here = sizes.sum()
+        with jax.named_scope("route"):
+            table = np.full(self.n_experts, local, np.int32)
+            table[list(self.held)] = np.arange(local, dtype=np.int32)
+            slot = jnp.asarray(table)[top_e.reshape(n * k)]
+            # the pairs here first, by expert and inside an expert by
+            # token; the pairs of absent experts last
+            order = jnp.argsort(slot, stable=True).astype(jnp.int32)
+            sizes = (slot[:, None] == jnp.arange(local)[None, :]).sum(
+                axis=0, dtype=jnp.int32)
+            here = sizes.sum()
         if plan.fit == plan.capacity:
             # one length: the plain body under plain autodiff
-            f, sizes, _ = _held_rows(plan, plan.capacity, m, top_p, w_g,
-                                     w_u, w_d, taps, casts, order, sizes,
-                                     here)
+            f, sizes, kept = _held_rows(
+                plan, plan.capacity, m, top_p, w_g, w_u, w_d, taps, casts,
+                order, sizes, here)
+            live = (jnp.count_nonzero(kept[3]),) \
+                if self.act == "relu" else ()
         else:
-            f, sizes = _fit_or_capacity(plan, m, top_p, w_g, w_u, w_d,
-                                        taps, casts, order, sizes, here)
+            f, sizes, *live = _fit_or_capacity(
+                plan, m, top_p, w_g, w_u, w_d, taps, casts, order, sizes,
+                here)
         over = jnp.maximum(here - plan.capacity, 0)
         # never short: the guard refuses a step that is over
         f = f + jnp.where(over > 0, jnp.float32(jnp.nan), 0.0)
-        return f, sizes, (here, over, here <= plan.fit)
+        fits = here <= plan.fit
+        hidden = ()
+        if self.act == "relu":      # [not zero, there are], fit steps
+            hidden = (_hidden_stats(
+                live[0], jnp.where(fits, here, 0) * self.width),)
+        return f, sizes, (here, over, fits) + hidden
 
     def xla_forward(self, x, w_r, w_g, w_u, w_d, g_norm=None,
                     ws_g=None, ws_u=None, ws_d=None, select_bias=None,
-                    taps=None):
+                    taps=None, x_route=None):
         """``((y, (lb, z)), (counts, logits, top_e))``: the output and
         the two auxiliary losses (differentiable); rows per expert, the
-        router's logits and its choice (not).  ``taps``: a zero scalar
+        router's logits and its choice (not; then the loads a selection
+        bias moves by and a ReLU layer's count of its hidden, where the
+        layer has them).  ``taps``: a zero scalar
         for each slab of ``TAPPED``, which enters nothing — the
         pullback returns Σ g² of that slab's gradient in its place
         (``grouped_matmul``; the kernel path only).  A slab may be a
         pair, the slab and its kept copy in the matmuls' dtype
         (:meth:`forward_args`): the matmuls read the copy, the
-        cotangent is the slab's."""
+        cotangent is the slab's.  ``x_route`` (``route_from``): what
+        the router reads in place of ``m``, as it is."""
         taps = taps or (None,) * len(self.TAPPED)
         b, t, d = x.shape
         n, k, e = b * t, self.top_k, self.n_experts
         x32 = x.astype(jnp.float32)
         m = (x32 if g_norm is None
              else rms_norm(jnp, x32, g_norm, self.norm_eps)).reshape(n, d)
-        logits, p, top_p, top_e = self.route(
-            jnp, m, w_r,
-            None if select_bias is None
-            else jax.lax.stop_gradient(select_bias))
-        top_p = self._weights_of(top_p)
-        flat_e = top_e.reshape(n * k)
+        with jax.named_scope("route"):
+            logits, p, top_p, top_e = self.route(
+                jnp, m if x_route is None
+                else x_route.astype(jnp.float32).reshape(n, d), w_r,
+                None if select_bias is None
+                else jax.lax.stop_gradient(select_bias))
+            top_p = self._weights_of(top_p)
+            flat_e = top_e.reshape(n * k)
 
         def rows_per_expert():
             # by comparison, not by a scatter-add (which a TPU
@@ -944,44 +1052,56 @@ class MoE(Forward):
             return (flat_e[:, None] == jnp.arange(e)[None, :]).sum(
                 axis=0, dtype=jnp.int32)
 
+        hidden_stats = None
         if self.held is not None:
-            sizes = rows_per_expert()
+            with jax.named_scope("route"):
+                sizes = rows_per_expert()
             f, local, here = self._held_experts(m, top_p, top_e, w_g,
                                                 w_u, w_d, taps)
+            hidden_stats = here[3] if self.act == "relu" else None
             y = f.reshape(b, t, d)
             extra = jnp.stack([here[0], jnp.int32(n * k), here[1],
                                here[2].astype(jnp.int32)])
             counts = jax.lax.stop_gradient(
                 jnp.concatenate([local, extra]).astype(jnp.float32))
         else:
-            order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
-            inverse = jnp.argsort(order).astype(jnp.int32)
-            sizes = rows_per_expert()
+            with jax.named_scope("route"):
+                order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
+                inverse = jnp.argsort(order).astype(jnp.int32)
+                sizes = rows_per_expert()
             dt = self.mxu_dtype or jnp.float32
             path = (getattr(self, "_gmm_kernel", False),
                     getattr(self, "_gmm_interpret", False))
             rows = _dispatch(m, order, inverse, dt)
             gate = grouped_matmul(rows, w_g, sizes, *path, tap=taps[0])
             up = grouped_matmul(rows, w_u, sizes, *path, tap=taps[1])
-            hidden = (_silu(jnp, gate) * up).astype(dt)
+            hidden = (_gate(jnp, self.act, gate) * up).astype(dt)
             out = grouped_matmul(hidden, w_d, sizes, *path, tap=taps[2])
             out = _unpermute(out, inverse, order).reshape(n, k, d)
             y = (out * top_p[..., None]).sum(axis=1).reshape(b, t, d)
             counts = None
+            if self.act == "relu":
+                hidden_stats = _hidden_stats(jnp.count_nonzero(hidden),
+                                             hidden.size)
         if ws_g is not None:
-            y = y + gated_mlp(jnp, self.mxu_dot, m, ws_g, ws_u,
-                              ws_d).reshape(b, t, d)
+            y = y + gated_mlp(jnp, self.mxu_dot, m, ws_g, ws_u, ws_d,
+                              self.act).reshape(b, t, d)
         if self.residual:
             y = x32 + y
         routed = jax.lax.stop_gradient(sizes.astype(jnp.float32))
         return ((y, self.aux_losses(jnp, logits, p, routed)),
                 (routed if counts is None else counts,
                  jax.lax.stop_gradient(logits), top_e)
-                + ((routed,) if self.select_bias_on else ()))
+                + ((routed,) if self.select_bias_on else ())
+                + ((hidden_stats,) if self.act == "relu" else ()))
 
-    def _record(self, lb, z, counts, logits, top_e, load=None) -> None:
-        if load is not None:      # what GDMoE's rule reads this step
-            self.select_load.devmem = load
+    def _record(self, lb, z, counts, logits, top_e, *more) -> None:
+        more = list(more)
+        if self.select_bias_on:   # what GDMoE's rule reads this step
+            self.select_load.devmem = more.pop(0)
+        if self.act == "relu":
+            self.hidden_stats.devmem = self.hidden_stats.devmem \
+                + more.pop(0)
         self.router_logits.devmem = logits.reshape(
             self.router_logits.shape)
         self.last_choice.devmem = top_e.astype(jnp.int32).reshape(
@@ -1065,6 +1185,14 @@ class MoE(Forward):
                     extreme)
         stats.map_invalidate()
         stats.mem[...] = 0.0      # uploaded on the next region fire
+        if self.hidden_stats:
+            self.hidden_stats.map_read()
+            live, total = (float(v) for v in self.hidden_stats.mem)
+            if obs_metrics.enabled() and total:
+                for stat, value in (("live", live), ("total", total)):
+                    obs_metrics.moe_hidden(self.name, stat).set(value)
+            self.hidden_stats.map_invalidate()
+            self.hidden_stats.mem[...] = 0.0
 
     # -- numpy oracle ---------------------------------------------------
     def _forward_np(self, x):
@@ -1077,7 +1205,7 @@ class MoE(Forward):
         if self.select_bias_on:
             self.select_bias.map_read()
         logits, p, raw_p, top_e = self.route(
-            np, m, self.weights.mem,
+            np, self._routed_from_np(m), self.weights.mem,
             self.select_bias.mem if self.select_bias_on else None)
         top_p = self._weights_of(raw_p)
         f = np.zeros((n, d), np.float32)
@@ -1089,13 +1217,14 @@ class MoE(Forward):
             me = m[rows]
             gate = me @ self.weights_gate.mem[slot]
             up = me @ self.weights_up.mem[slot]
-            hidden = _silu(np, gate) * up
+            hidden = _gate(np, self.act, gate) * up
             out = hidden @ self.weights_down.mem[slot]
             np.add.at(f, rows, out * top_p[rows, slots][:, None])
             per_expert.append((rows, slots, me, gate, up, hidden, out))
         if self.shared_width:
             f = f + gated_mlp(np, _np_dot, m, *(
-                getattr(self, attr).mem for attr in self.SHARED))
+                getattr(self, attr).mem for attr in self.SHARED),
+                self.act)
         y = f.reshape(b, t, d)
         if self.residual:
             y = x + y
@@ -1104,6 +1233,14 @@ class MoE(Forward):
         routed = np.asarray([(top_e == e).sum()
                              for e in range(self.n_experts)], np.float32)
         return y, (m, logits, p, raw_p, top_p, top_e, per_expert, routed)
+
+    def _routed_from_np(self, m):
+        """The rows the router reads: the experts' own ``m``, or the
+        block's input as it is (``route_from``)."""
+        if not self.route_from:
+            return m
+        self.route_input.map_read()
+        return self.route_input.mem.astype(np.float32).reshape(m.shape)
 
     def numpy_run(self) -> None:
         for vec in (self.input, self.weights, self.weights_gate,
@@ -1114,8 +1251,9 @@ class MoE(Forward):
         y, cache = self._forward_np(self.input.mem.astype(np.float32))
         self.output.map_invalidate()
         self.output.mem[...] = y
-        logits, p, routed = cache[1], cache[2], cache[-1]
-        counts = np.asarray([len(pe[0]) for pe in cache[-2]], np.float32)
+        logits, p, per_expert, routed = cache[1], cache[2], cache[-2], \
+            cache[-1]
+        counts = np.asarray([len(pe[0]) for pe in per_expert], np.float32)
         for vec, value in ((self.router_logits, logits),
                            (self.last_choice, cache[5])):
             vec.map_invalidate()
@@ -1133,6 +1271,11 @@ class MoE(Forward):
         self.moe_stats.mem[...] += np.concatenate(
             [counts, [lb, z, 1.0, counts.max(), counts.min()], held,
              [0.0, 0.0]]).astype(np.float32)
+        if self.act == "relu":
+            self.hidden_stats.map_write()
+            self.hidden_stats.mem[...] += (
+                sum(np.count_nonzero(pe[5]) for pe in per_expert),
+                sum(pe[5].size for pe in per_expert))
 
 
 class GDMoE(GradientDescentBase):
@@ -1148,6 +1291,9 @@ class GDMoE(GradientDescentBase):
     EXTRA = ("weights_gate", "weights_up", "weights_down", "gain_norm",
              "weights_shared_gate", "weights_shared_up",
              "weights_shared_down")
+    #: … and what follows them there that is no parameter of this
+    #: rule (``MoE.xla_forward``)
+    AFTER_EXTRA = ("select_bias", "taps", "x_route")
     #: the forward returns what routing did beside its output
     HAS_AUX = True
 
@@ -1229,10 +1375,14 @@ class GDMoE(GradientDescentBase):
         # takes no cotangent)
         grads = {attr: grad[0] if isinstance(grad, tuple) else grad
                  for attr, grad in zip(self.EXTRA, g_extra)}
+        after = dict(zip(self.AFTER_EXTRA, g_extra[len(self.EXTRA):]))
         # Σ g² of a slab's gradient where the kernels made it: what the
-        # pullback returns for the forward's ``taps``, its last argument
+        # pullback returns for the forward's ``taps``
         tapped = getattr(fwd, "_gmm_kernel", False)
-        sums = dict(zip(fwd.TAPPED, g_extra[-1])) if tapped else {}
+        sums = dict(zip(fwd.TAPPED, after["taps"])) if tapped else {}
+        if getattr(fwd, "route_from", None):
+            # the router's share of the block input's cotangent
+            fwd.route_gd.park_beside(after["x_route"])
         taken = 0
         for attr, param, acc in self._extra_pairs():
             taken += self._apply_weights_xla(
@@ -1311,7 +1461,7 @@ class GDMoE(GradientDescentBase):
             grads["weights_down"][e] = hidden.T @ dout
             dgate, dup, dme = _gated_mlp_backward(
                 me, gate, up, dout, fwd.weights_gate.mem[e],
-                fwd.weights_up.mem[e], fwd.weights_down.mem[e])
+                fwd.weights_up.mem[e], fwd.weights_down.mem[e], fwd.act)
             grads["weights_gate"][e] = me.T @ dgate
             grads["weights_up"][e] = me.T @ dup
             np.add.at(dm, rows, dme)
@@ -1319,9 +1469,10 @@ class GDMoE(GradientDescentBase):
             w_g, w_u, w_d = (getattr(fwd, attr).mem
                              for attr in fwd.SHARED)
             gate, up = m @ w_g, m @ w_u
-            grads["weights_shared_down"] = (_silu(np, gate) * up).T @ dy
+            grads["weights_shared_down"] = (
+                _gate(np, fwd.act, gate) * up).T @ dy
             dgate, dup, dme = _gated_mlp_backward(m, gate, up, dy, w_g,
-                                                  w_u, w_d)
+                                                  w_u, w_d, fwd.act)
             grads["weights_shared_gate"] = m.T @ dgate
             grads["weights_shared_up"] = m.T @ dup
             dm += dme
@@ -1348,8 +1499,12 @@ class GDMoE(GradientDescentBase):
         lse = top + np.log(np.exp(logits - top[:, None]).sum(axis=-1))
         soft = np.exp(logits - lse[:, None])
         dlogits += fwd.z_loss_weight * (2.0 * lse / n)[:, None] * soft
-        grad_router = m.T @ dlogits
-        dm += dlogits @ self.weights.mem.T
+        grad_router = fwd._routed_from_np(m).T @ dlogits
+        d_routed_from = dlogits @ self.weights.mem.T
+        if fwd.route_from:     # the block input's cotangent, its share
+            fwd.route_gd.park_beside(d_routed_from.reshape(b, t, d))
+        else:
+            dm += d_routed_from
         dx = dm.reshape(b, t, d)
         if fwd.pre_norm:
             dx, grads["gain_norm"] = rms_norm_backward(
@@ -1366,13 +1521,14 @@ class GDMoE(GradientDescentBase):
             self._move_select_bias(np)
 
 
-def _gated_mlp_backward(m, gate, up, dout, w_gate, w_up, w_down):
-    """``(dgate, dup, dm)`` of ``W_down (silu(gate) ⊙ up)`` given the
+def _gated_mlp_backward(m, gate, up, dout, w_gate, w_up, w_down,
+                        act: str = "silu"):
+    """``(dgate, dup, dm)`` of ``W_down (act(gate) ⊙ up)`` given the
     cotangent ``dout`` of its output."""
     dhidden = dout @ w_down.T
-    sig = 1.0 / (1.0 + np.exp(-gate))
-    dgate = dhidden * up * sig * (1.0 + gate * (1.0 - sig))
-    dup = dhidden * gate * sig
+    dgate = dhidden * up * activations_math.gate(act).derivative(
+        np, None, gate)
+    dup = dhidden * _gate(np, act, gate)
     return dgate, dup, dgate @ w_gate.T + dup @ w_up.T
 
 
@@ -1388,11 +1544,13 @@ class GatedMLP(Forward):
 
     def __init__(self, workflow, width: int, pre_norm: str | None = None,
                  residual: bool = False, norm_eps: float = 1e-5,
-                 post_norm: str | None = None,
+                 post_norm: str | None = None, act: str = "silu",
                  name=None, **kwargs) -> None:
         kwargs.setdefault("weights_filling", "xavier")
         kwargs["include_bias"] = False
         super().__init__(workflow, name=name, **kwargs)
+        #: the gate function, as the expert layer's
+        self.act = activations_math.gate(act).name
         for option, value in (("pre_norm", pre_norm),
                               ("post_norm", post_norm)):
             if value not in (None, "rms"):
@@ -1447,7 +1605,7 @@ class GatedMLP(Forward):
         m = x32 if g_pre is None \
             else rms_norm(jnp, x32, g_pre, self.norm_eps)
         y = gated_mlp(jnp, self.mxu_dot, m.reshape(-1, x.shape[-1]),
-                      w_g, w_u, w_d).reshape(x.shape)
+                      w_g, w_u, w_d, self.act).reshape(x.shape)
         if self.post_norm:
             y = rms_norm(jnp, y, g_post, self.norm_eps)
         return x32 + y if self.residual else y
@@ -1465,8 +1623,8 @@ class GatedMLP(Forward):
         m = (rms_norm(np, x, self.gain_norm.mem, self.norm_eps)
              if self.pre_norm else x).reshape(-1, x.shape[-1])
         gate, up = m @ self.weights.mem, m @ self.weights_up.mem
-        y = ((_silu(np, gate) * up) @ self.weights_down.mem).reshape(
-            x.shape)
+        y = ((_gate(np, self.act, gate) * up)
+             @ self.weights_down.mem).reshape(x.shape)
         raw = None
         if self.post_norm:
             raw, y = y, rms_norm(np, y, post_gain(self).mem,
@@ -1511,10 +1669,10 @@ class GDGatedMLP(GDMoE):
                       else "gain_norm"] = rms_norm_backward(
                 np, raw, gain.mem, fwd.norm_eps, err)
         dy = dy.reshape(m.shape)
-        grads["weights_down"] = (_silu(np, gate) * up).T @ dy
+        grads["weights_down"] = (_gate(np, fwd.act, gate) * up).T @ dy
         dgate, dup, dm = _gated_mlp_backward(
             m, gate, up, dy, fwd.weights.mem, fwd.weights_up.mem,
-            fwd.weights_down.mem)
+            fwd.weights_down.mem, fwd.act)
         grads["weights_up"] = m.T @ dup
         dx = dm.reshape(x.shape)
         if fwd.pre_norm:

@@ -239,6 +239,14 @@ class GradientDescentBase(AcceleratedUnit, metaclass=MatchingObject):
         #: bounds the DATA term only — the regularizer stays exact.
         self.gradient_clip = gradient_clip
         self.need_err_input = need_err_input
+        #: a cotangent of this unit's INPUT that reaches it beside the
+        #: chain — made by a GD further on than the next one (an expert
+        #: layer whose router reads this block's input: ``ops/moe.py``,
+        #: ``route_from``), parked in that unit's turn
+        #: (:meth:`park_beside`) and joined to ``err_input`` after this
+        #: unit's own run (:meth:`join_beside`): an ndarray, a jax
+        #: array or a tracer, for one eager step or one trace
+        self._err_beside = None
         #: resolved at initialize (parallel.mesh.zero1_choice): True =
         #: the update runs ZeRO-1 sharded over the mesh's data axis
         self._zero1 = False
@@ -285,6 +293,29 @@ class GradientDescentBase(AcceleratedUnit, metaclass=MatchingObject):
         # LearningRateAdjust unit schedules this GD unit — a region
         # leaf, so schedule changes never recompile the step program
         self.lr_state = Vector(name=f"{self.name}.lr_state")
+
+    def park_beside(self, grad) -> None:
+        """``grad``: a further cotangent of this unit's input, from a
+        unit that read it beside the chain."""
+        self._err_beside = grad
+
+    def join_beside(self) -> None:
+        """``err_input`` plus what was parked, which is then gone.
+        The engine calls this after the unit's run, eager or traced
+        (``AcceleratedUnit.run``, ``JitRegion._trace_members``)."""
+        grad, self._err_beside = self._err_beside, None
+        if grad is None or not self.need_err_input:
+            return
+        if self.device.is_host_only:
+            self.err_input.map_write()
+            self.err_input.mem[...] += np.asarray(grad).reshape(
+                self.err_input.shape)
+        else:
+            self.err_input.devmem = self.err_input.devmem \
+                + grad.reshape(self.err_input.shape)
+
+    def forget_trace(self) -> None:
+        self._err_beside = None
 
     def initialize(self, device=None, **kwargs) -> None:
         if self.REQUIRES_FORWARD_UNIT \

@@ -67,10 +67,14 @@ Design (the standard flash decomposition, implemented TPU-first):
   - *two passes*, ``znicz_flash_dq`` (grid over K blocks innermost,
     accumulating dq tiles) and ``znicz_flash_dkv`` (grid over Q blocks
     innermost, accumulating dk/dv tiles): seven matmuls and the
-    exponentials twice.  A deeper K grid (T > 4096, a caller's shorter
-    K tiles), a window (a Q tile meets two K tiles and a K tile two Q
-    tiles: ``znicz_flash_*_win``) and non-causal calls leave dq
-    unfinished at a dk/dv step's end and keep them.
+    exponentials twice.  A deeper K grid (T > 4096 — SmallThinker's
+    NoPE full layer at T 16,384 walks eight K tiles of 2048 with seven
+    query heads a K/V head, the one cell that runs these two
+    un-windowed, PR 50 — or a caller's shorter K tiles), a window (a Q
+    tile meets two K tiles and a K tile two Q tiles under Laguna's band
+    of 512, nine and nine under SmallThinker's of 4,096:
+    ``znicz_flash_*_win``) and non-causal calls leave dq unfinished at
+    a dk/dv step's end and keep them.
 
   Both forms share one dk/dv body (:func:`_dkv_kernel`), `_p_tile`,
   the walk, the masks and the fully-masked-row guards.
@@ -410,10 +414,11 @@ def backward_passes(causal: bool, t_k: int, bk: int, window=None) -> int:
     its Q tile can see, so dq finishes inside it and the backward is
     one ``pallas_call``, ``znicz_flash_bwd`` (five matmuls and one pass
     of exponentials per visible sub-tile); else 2, ``znicz_flash_dq`` +
-    ``znicz_flash_dkv`` (seven and two): a deeper K grid, a window (a
-    Q tile meets two K tiles and a K tile two Q tiles) or a non-causal
-    call leaves dq unfinished at a dk/dv step's end.  Static per
-    program: in the plan (:func:`plan`) and its line."""
+    ``znicz_flash_dkv`` (seven and two): a deeper K grid (T_k past
+    ``WHOLE_BLOCK_K``: 8,192 and 16,384 run so), a window (a Q tile
+    meets two to nine K tiles and a K tile as many Q tiles) or a
+    non-causal call leaves dq unfinished at a dk/dv step's end.  Static
+    per program: in the plan (:func:`plan`) and its line."""
     whole = backward_block_k(causal, t_k, bk, window) == t_k
     return 1 if causal and window is None and whole else 2
 
@@ -422,7 +427,10 @@ def backward_passes(causal: bool, t_k: int, bk: int, window=None) -> int:
 # the window: a grid that visits only the tiles a band touches
 # ----------------------------------------------------------------------
 #: grid tile edge of a windowed call (one body per tile, no sub-tile
-#: walk: a band of 512 is two or three tiles wide)
+#: walk: a band of 512 is two or three tiles wide — Laguna's — and one
+#: of 4,096 nine — SmallThinker's, at T 8,192 and 16,384 —, of which
+#: seven lie wholly inside the band and run with no mask code:
+#: ``_band_visit``)
 BAND_BLOCK = 512
 
 

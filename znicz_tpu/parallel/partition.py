@@ -237,6 +237,8 @@ _REPLICATED_SLOTS = (
     # the pre-norm block's gains, the expert layer's weight slabs and
     # its routing totals (PR 25)
     r"gain_\w+", r"weights_\w+", r"moe_stats",
+    # … and, of ReLU experts, how much of their hidden is not zero
+    r"hidden_stats",
     # the stream maps' biases and scalars, and what Sinkhorn reached
     r"maps_\w+", r"stream_stats",
 )
