@@ -575,11 +575,14 @@ def test_where_there_is_one_length_the_text_is_the_parents(
         layer, monkeypatch):
     """A held layer whose fit size IS its capacity traces the text it
     traced before it had two sizes — forward and pullback, no ``cond``
-    of the two lengths in it —, and a dropless
+    of the two lengths in it; its rows go back by the scatter-add,
+    as below ``HELD_GATHER`` they do, and the pairs' inverse map is not
+    even made —, and a dropless
     layer and a dense gated MLP trace one text whatever the fit size:
     the mechanism is in no program but a held layer's with two
     lengths."""
     kernels_interpreted()
+    monkeypatch.setattr(moe, "HELD_GATHER", 0)
     x, err = _data(9)
     texts = []
     for fit in (moe.HELD_FIT, moe.HELD_SLACK):
@@ -662,6 +665,164 @@ def test_a_step_at_the_fit_size_writes_nothing_of_the_capacitys(
         assert zeros.primitive.name == "broadcast_in_dim"
         assert float(zeros.invars[0].val) == 0.0
     assert not saved[1]
+
+
+# ----------------------------------------------------------------------
+# a held share's rows go back to their tokens by gathers (PR 51)
+# ----------------------------------------------------------------------
+#: 3 of 64 held, top 2 of 32 tokens: the fit size 4, the capacity 12
+#: (three windows: a scan) of the 64 pairs — 64 > HELD_GATHER · 4, the
+#: share is small and the layer's own form the scatter-add
+FAR = dict(n_experts=64, top_k=2, held=(1, 6, 11), norm_topk=True,
+           score="sigmoid")
+#: (options, (fit, capacity), routing, the layer's own form)
+GATHERED = {
+    # 26 pairs here of the fit size's 40: dead rows; tokens with no
+    # pair here, with one and with both
+    "at_the_fit_size_with_dead_rows": (NEAR, (40, 64),
+                                       "under_the_fit_size", "gather"),
+    "at_the_capacity_in_two_windows": (NEAR, (40, 64),
+                                       "over_the_fit_size", "gather"),
+    "at_the_capacity_in_a_scan": (WIDE, (FIT, CAPACITY),
+                                  "over_the_fit_size", "gather"),
+    "over_the_capacity": (WIDE, (FIT, CAPACITY), "over_the_capacity",
+                          "gather"),
+    "a_small_share": (FAR, (4, 12), "under_the_fit_size", "scatter"),
+}
+
+
+def routed_sum_and_pullback(fwd, err):
+    """A held layer's routed sum (``MoE._held_experts``: what follows
+    the router) with its pullback, as one function of the rows, the
+    weights and the slabs — and its arguments."""
+    import jax
+    rng = np.random.default_rng(13)
+    n, k = B * 2 * T, fwd.top_k
+    m = rng.normal(0, 1, (n, D)).astype(np.float32)
+    fwd.last_choice.map_read()
+    top_e = np.array(fwd.last_choice.mem).reshape(n, k)
+    top_p = rng.uniform(0.1, 1.0, (n, k)).astype(np.float32)
+    slabs = [host(getattr(fwd, attr)) for attr in SLABS]
+
+    def both(m, top_p, *slabs):
+        f, pullback = jax.vjp(
+            lambda *args: fwd._held_experts(
+                args[0], args[1], top_e, *args[2:], (None,) * 3)[0],
+            m, top_p, *slabs)
+        return f, pullback(jax.numpy.asarray(err.reshape(n, D)))
+
+    return both, (m, top_p, *slabs)
+
+
+@pytest.mark.parametrize("rows", ["f32_rows", "bf16_rows"])
+@pytest.mark.parametrize("case", list(GATHERED))
+def test_rows_by_gathers_are_the_rows_by_a_scatter_add(case, rows,
+                                                       monkeypatch):
+    """The output, both auxiliary losses, what routing did and the
+    cotangent of every argument (the rows' ``m``, through the router
+    the weights ``top_p``, the three slabs) of a held layer whose rows
+    come and go by gathers through the pairs' inverse map are those of
+    the layer with the gather and the scatter-add under autodiff, to
+    f32 rounding — the same terms, added in another order —, on either
+    side of the rule, at the fit size with dead rows, at the capacity
+    in two windows and in a scan, with f32 rows and with bf16 rows; a
+    step over its capacity is NaN either way.  The gauge names the form
+    the module's rule picks."""
+    import jax
+    options, sizes, routing, own = GATHERED[case]
+    if rows == "bf16_rows":
+        bf16_matmuls()
+    got = []
+    for form, above in (("own", moe.HELD_GATHER), ("gather", 10 ** 9),
+                        ("scatter", 0)):
+        monkeypatch.setattr(moe, "HELD_GATHER", above)
+        fwd, gd_u, err = held_pair(routing, False, monkeypatch, HELD_FIT,
+                                   wide=options, sizes=sizes)
+        if form == "own":
+            assert {f: obs_metrics.moe_combine("MoE", f).value
+                    for f in ("gather", "scatter")} \
+                == {"gather": own == "gather", "scatter": own != "gather"}
+            continue
+        primal, pullback, aux = jax.vjp(
+            fwd.xla_forward, *fwd.forward_args(), has_aux=True)
+        grads = pullback((jax.numpy.asarray(err),
+                          (np.float32(0.01), np.float32(0.001))))
+        got.append((leaves_of(primal), leaves_of(aux), leaves_of(grads)))
+        step(fwd, gd_u, err)
+        here = np.isin(host(fwd.last_choice), fwd.held)
+        if case == "at_the_fit_size_with_dead_rows":
+            assert 0 < here.sum() < sizes[0]
+            assert set(here.sum(axis=-1).ravel()) == {0, 1, 2}
+    assert np.isfinite(got[0][0][0]).all() \
+        == (routing != "over_the_capacity")
+    # (bf16 rows: a slab's kept copy beside it, which takes no
+    # cotangent)
+    assert len(got[0][2]) == (8 if rows == "bf16_rows" else 5)
+    for mine, want in zip(*got):
+        assert len(mine) == len(want)
+        for a, b in zip(mine, want):
+            # bf16 rows: the input's cotangent is the sum of the gate
+            # and the up matmul's row gradients, a bf16 sum, which the
+            # gathers read as rounded and XLA hands the scatter-add's
+            # f32 cast unrounded (excess precision): one bf16 rounding
+            loose = rows == "bf16_rows" and a is mine[0] \
+                and mine is got[0][2]
+            np.testing.assert_allclose(
+                a, b, rtol=1e-6, atol=(2.0 ** -8 if loose else 1e-6)
+                * np.abs(np.nan_to_num(b)).max())
+
+
+@pytest.mark.parametrize("lengths", ["two_lengths", "one_length"])
+def test_no_scatter_is_left_where_the_rows_go_back_by_gathers(
+        lengths, monkeypatch):
+    """The lowered text of a held layer's routed sum and its pullback:
+    by gathers NO ``scatter`` — autodiff cannot bring one back unseen
+    through the transpose of a ``take``, the primitives' pullbacks are
+    their own —, below the rule the two of the parent where the buffer
+    has one length (the weighted rows' and, the transpose of the rows'
+    gather, their gradient's; the pairs' weights' a third, of N · k
+    numbers) and those of every window besides where it has two."""
+    import jax
+    counts = []
+    for above in (10 ** 9, 0):
+        monkeypatch.setattr(moe, "HELD_GATHER", above)
+        fwd, gd_u, err = held_pair(
+            "over_the_fit_size", False, monkeypatch,
+            HELD_FIT if lengths == "two_lengths" else None, wide=NEAR,
+            sizes=(40, 64))
+        step(fwd, gd_u, err)         # (the router's choice, kept)
+        both, args = routed_sum_and_pullback(fwd, err)
+        text = jax.jit(both).lower(*args).as_text()
+        assert "stablehlo.gather" in text
+        counts.append(text.count('"stablehlo.scatter"('))
+    assert counts[0] == 0, counts
+    assert (counts[1] == 3) if lengths == "one_length" \
+        else (counts[1] > 3), counts
+
+
+def test_a_large_buffer_is_gathered_by_column_blocks(monkeypatch):
+    """A buffer larger than ``GATHER_OPERAND_BYTES`` is gathered a block
+    of whole 128-lane tiles at a time — SmallThinker's f32 rows in four,
+    its bf16 rows and LFM2's f32 rows in two, Laguna's as they are, a
+    width of no whole tile never — and the sum over a token's slots is
+    the same numbers either way."""
+    import jax.numpy as jnp
+    blocks = moe._column_blocks
+    assert [blocks(15360, 2560, 4), blocks(15360, 2560, 2),
+            blocks(10240, 2048, 4), blocks(2048, 3072, 4),
+            blocks(1 << 20, 2560 + 64, 4), blocks(1 << 20, 128, 4)] \
+        == [4, 2, 2, 1, 1, 1]
+    rng = np.random.default_rng(17)
+    rows = jnp.asarray(rng.normal(0, 1, (40, 384)).astype(np.float32))
+    at = jnp.asarray(rng.integers(-5, 60, (32, 3)).astype(np.int32))
+    ok = (at >= 0) & (at < 40)
+    want = np.where(np.asarray(ok)[..., None],
+                    np.asarray(rows)[np.clip(at, 0, 39)], 0).sum(axis=1)
+    whole = moe._slots_sum(rows, at, ok)
+    monkeypatch.setattr(moe, "GATHER_OPERAND_BYTES", 40 * 128 * 4)
+    assert blocks(40, 384, 4) == 3
+    for got in (whole, moe._slots_sum(rows, at, ok)):
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
 
 
 def test_a_snapshot_from_before_the_fit_size_restores():

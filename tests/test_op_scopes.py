@@ -55,7 +55,9 @@ def conv_dense(name: str, epochs: int = 1, **loader) -> StandardWorkflow:
     return wf
 
 
-def attention_moe(name: str) -> StandardWorkflow:
+def attention_moe(name: str, **expert) -> StandardWorkflow:
+    """An embedding, an attention sublayer, an expert layer (with the
+    further options ``expert``) and a head."""
     vocab, seq, dim = 29, 8, 16
     ids = np.random.default_rng(6).integers(0, vocab, (8, seq + 1))
     prng.seed_all(21)
@@ -73,7 +75,8 @@ def attention_moe(name: str) -> StandardWorkflow:
                     "pre_norm": "rms", "residual": True}, "<-": GD},
             {"type": "moe",
              "->": {"n_experts": 4, "top_k": 2, "width": 16,
-                    "pre_norm": "rms", "residual": True}, "<-": GD},
+                    "pre_norm": "rms", "residual": True, **expert},
+             "<-": GD},
             {"type": "softmax",
              "->": {"output_sample_shape": vocab, "per_position": True,
                     "include_bias": False}, "<-": GD}],
@@ -122,7 +125,10 @@ def test_every_member_unit_is_in_the_map(build):
                 if u.name in by_unit} == {type(fwd).__name__}
         # (an expert layer's router, top k and dispatch plan run under
         # the scope ``route``, forward and pullback: PR 50)
-        route = {"route"} if type(fwd).__name__ == "MoE" else set()
+        # … and its experts' rows gathered and put back under the
+        # scope ``combine`` (PR 51)
+        route = {"route", "combine"} if type(fwd).__name__ == "MoE" \
+            else set()
         assert phases_of(ops, fwd.name) <= {"forward"} | route
         assert phases_of(ops, gd.name) \
             <= {"backward", "update", "fingerprint"} | route
@@ -142,22 +148,8 @@ def test_route_is_a_phase_of_the_expert_layer_forward_and_pullback(early):
     assert scopes._ROUTE.search("jit(step)/MoE_2/jvp(route)/dot_general")
     assert scopes._ROUTE.search("a/GDMoE_2/transpose(jvp(route))/mul")
     assert not scopes._ROUTE.search("a/MoE_2/jvp()/router_bias/add")
-    wf = attention_moe(f"scopes_route_{early}")
-    if early:
-        table = [dict(layer) for layer in wf.layers_config]
-        table[2] = {**table[2], "->": {
-            **table[2]["->"], "route_from": "block_input",
-            "act": "relu"}}
-        ids = np.random.default_rng(6).integers(0, 29, (8, 9))
-        prng.seed_all(21)
-        wf = StandardWorkflow(
-            name="scopes_route_early",
-            loader_factory=lambda w: ArrayLoader(
-                w, train_data=ids[:, :-1].astype(np.float32),
-                train_labels=ids[:, 1:].astype(np.int32),
-                minibatch_size=4, shuffle_limit=0),
-            layers=table, decision_config={"max_epochs": 1})
-        wf.initialize(device=XLADevice())
+    wf = attention_moe(f"scopes_route_{early}", **(
+        {"route_from": "block_input", "act": "relu"} if early else {}))
     wf.run()
     ops = only_program(f"znicz_step__{wf._region_unit.region.name}")
     expert = next(u for u in wf.forwards if type(u).__name__ == "MoE")
@@ -167,6 +159,53 @@ def test_route_is_a_phase_of_the_expert_layer_forward_and_pullback(early):
     for unit in wf.forwards + wf.gds:
         if unit not in (expert, gd):
             assert "route" not in phases_of(ops, unit.name)
+
+
+@pytest.mark.parametrize("share", ["dropless", "held_by_gathers",
+                                   "held_by_a_scatter_add"])
+def test_combine_is_a_phase_of_the_expert_layer_forward_and_pullback(
+        share, monkeypatch):
+    """The rows of a layer's experts gathered from their tokens and put
+    back, weighted and summed, lie under the scope ``combine``
+    (``ops/moe.py``) — a dropless layer's, a held share's by gathers and
+    by a scatter-add: what XLA kept of them apart from their neighbours
+    reads phase ``combine``, in the forward unit (traced under
+    ``jax.vjp``: ``jvp(combine)``) and in the backward unit
+    (``transpose(jvp(combine))``, the pullbacks of the layer's own
+    primitives too), and in no other unit."""
+    from znicz_tpu.observe import scopes
+    from znicz_tpu.ops import moe
+    assert scopes._COMBINE.search("jit(step)/MoE_2/jvp(combine)/gather")
+    assert scopes._COMBINE.search(
+        "a/GDMoE_2/jit(_fit_or_capacity_bwd)/transpose(jvp(combine))/mul")
+    assert not scopes._COMBINE.search("a/MoE_2/jvp(route)/combined/add")
+    if share == "held_by_a_scatter_add":
+        monkeypatch.setattr(moe, "HELD_GATHER", 0)
+    wf = attention_moe(f"scopes_combine_{share}", **(
+        {} if share == "dropless" else {"held": (0, 2)}))
+    wf.run()
+    ops = only_program(f"znicz_step__{wf._region_unit.region.name}")
+    expert = next(u for u in wf.forwards if type(u).__name__ == "MoE")
+    gd = wf.gds[wf.forwards.index(expert)]
+    assert obs_metrics.moe_combine(expert.name, "gather").value \
+        == (share != "held_by_a_scatter_add")
+    assert "combine" in phases_of(ops, expert.name)
+    # (at this size XLA may fuse the pullback's gathers into their
+    # neighbours: the scope is then in the text it compiled from)
+    assert phases_of(ops, gd.name) & {"combine", "backward"}
+    for unit in wf.forwards + wf.gds:
+        if unit not in (expert, gd):
+            assert "combine" not in phases_of(ops, unit.name)
+
+    def both(*args):
+        (y, aux), pullback, _ = jax.vjp(expert.xla_forward, *args,
+                                        has_aux=True)
+        return pullback((y, aux))
+
+    text = jax.jit(both).lower(*expert.forward_args()).as_text(
+        debug_info=True)
+    for scope in ("/jvp(combine)", "/transpose(jvp(combine))"):
+        assert scope in text, scope
 
 
 def test_update_and_fingerprint_are_phases_of_the_backward_unit():

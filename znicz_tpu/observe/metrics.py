@@ -784,6 +784,21 @@ def moe_hidden(unit: str, stat: str) -> Gauge:
         labels=("unit", "stat")).labels(unit=unit, stat=stat)
 
 
+def moe_combine(unit: str, form: str) -> Gauge:
+    """How a ``MoE`` layer's rows go back to their tokens (``form`` =
+    ``gather``: by gathers in both directions — every dropless layer,
+    and a held share whose N · k pairs are at most ``HELD_GATHER``
+    times its fit size; ``scatter``: a held share below that, by a
+    gather and a scatter-add over its buffer's rows): 1 for the form
+    the layer's programs hold, 0 for the other.  Set once at
+    ``initialize``."""
+    return REGISTRY.gauge(
+        "znicz_moe_combine",
+        "Whether a MoE layer's rows go back to their tokens by gathers "
+        "or by a scatter-add",
+        labels=("unit", "form")).labels(unit=unit, form=form)
+
+
 def attention_latent(unit: str, stat: str) -> Gauge:
     """The static sizes of a latent-K/V attention unit
     (``MultiHeadAttention`` with ``kv_latent``; ``stat`` = ``latent``:

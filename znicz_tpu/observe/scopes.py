@@ -13,7 +13,7 @@ of the COMPILED step program carries
     {program name: {HLO instruction name: {
         "unit": "GDMoE_2", "kind": "GDMoE", "family": "MoE",
         "phase": "forward" | "backward" | "update" | "fingerprint"
-                 | "pass_sum" | "router_bias" | "route"}}}
+                 | "pass_sum" | "router_bias" | "route" | "combine"}}}
 
 - ``kind`` is the unit's class, ``family`` the forward class a
   backward unit is paired with (a forward unit's own pairing class):
@@ -25,8 +25,9 @@ of the COMPILED step program carries
   ``pass_sum``, the sum of a looped span's partial gradients over its
   passes; ``router_bias``, the selection bias's rule; ``route``, an
   expert layer's logits, scores, top k and the sort that plans its
-  dispatch, forward and pullback), else its unit's forward /
-  backward.  A member of a looped
+  dispatch, forward and pullback; ``combine``, its experts' rows
+  gathered from their tokens and put back, weighted and summed,
+  likewise), else its unit's forward / backward.  A member of a looped
   span traces each application under ``<unit>/pass<r>/``: the pass is
   in the ``op_name`` path, the unit is still the outermost scope;
 - a fusion is attributed by ALL the instructions fused into it (the
@@ -153,7 +154,10 @@ _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 #: ``op_name``: a path element of its own, bare in what the unit traced
 #: itself, inside ``jvp(…)`` / ``transpose(jvp(…))`` in a forward traced
 #: under ``jax.vjp`` and in that forward's pullback
-_ROUTE = re.compile(r"(?:^|[/(])route(?:[/)]|$)")
+#: … and its scope ``combine``: the rows of a layer's experts gathered
+#: from their tokens and put back, weighted and summed
+_ROUTE, _COMBINE = (re.compile(rf"(?:^|[/(]){scope}(?:[/)]|$)")
+                    for scope in ("route", "combine"))
 _CALLEES = re.compile(
     r"\b(?:calls|to_apply|body|condition|true_computation|"
     r"false_computation|branch_computations|called_computations)="
@@ -248,7 +252,8 @@ def attribute(text: str, units: tuple) -> dict:
         return found and found + (
             found[1] and "/pass_sum/" in f"/{op_name}/",
             "/router_bias/" in f"/{op_name}/",
-            _ROUTE.search(op_name) is not None)
+            _ROUTE.search(op_name) is not None,
+            _COMBINE.search(op_name) is not None)
 
     def scopes_in(computation: str) -> frozenset:
         """Scopes of every instruction in a computation and in what
@@ -292,16 +297,14 @@ def _entry(scopes: set, units: tuple) -> dict:
     parts = []
     for index in sorted(by_unit):
         name, kind, family, backward = units[index]
-        if all(fingerprint for _u, fingerprint, *_ in by_unit[index]):
-            phase = "fingerprint"
-        elif all(pass_sum for _u, _f, pass_sum, *_ in by_unit[index]):
-            phase = "pass_sum"
-        elif all(update for update, *_ in by_unit[index]):
-            phase = "update"
-        elif all(bias for *_, bias, _r in by_unit[index]):
-            phase = "router_bias"
-        elif all(route for *_, route in by_unit[index]):
-            phase = "route"
+        # ``inside``: (update, fingerprint, pass_sum, router_bias,
+        # route, combine), as ``attribute``'s ``scope`` hands them out;
+        # the scopes nested in ``update`` before it
+        for phase, slot in (("fingerprint", 1), ("pass_sum", 2),
+                            ("update", 0), ("router_bias", 3),
+                            ("route", 4), ("combine", 5)):
+            if all(inside[slot] for inside in by_unit[index]):
+                break
         else:
             phase = "backward" if backward else "forward"
         parts.append({"unit": name, "kind": kind, "family": family,

@@ -84,9 +84,32 @@ stands in for the others: ``y = x + Shared(m) + Σ_{e ∈ top_k ∩ held}
 w_e · Expert_e(m)``.  The pairs here are a traced number under a static
 shape: they are sorted to the front and the first rows of that order
 go through the grouped matmuls, whose work follows the rows that are
-real, and come back by a scatter-add.  The gathers, the weighting and
-the scatter-add run over every row of the buffer, real or not
-(PERF.md §6, PR 29), so the buffer has TWO static lengths and the
+real.  The two ways between the (N, D) tokens and that buffer are each
+other's adjoint, and where the share held is a tenth of the pairs or
+more — N · k ≤ ``HELD_GATHER`` (8) times the fit size, a rule of the
+static shapes alone — BOTH are gathers in both directions, as the
+dropless layer's are (PR 51): the rows come by a gather at their
+tokens (:func:`_gather_rows`) and go back, weighted, by k gathers of N
+rows through the pairs' inverse map, added up slot by slot in f32
+(:func:`_combine`; a buffer above ``GATHER_OPERAND_BYTES`` a block of
+columns at a time, so that the gathers read their operand from the
+chip's fast memory); each one's pullback is the other's gather (the
+rows' cotangent gathered in the rows' dtype, a token's k copies summed
+in f32), so no scatter-add runs in the step — a TPU runs one serially,
+0.49 µs a row of 2,560 into 16,384 tokens, where the gathers of a
+direction take a third of that pass (PERF.md §6, PR 51).  The map is no second sort: a pair here lies at
+its expert's offset plus its rank among that expert's pairs, a
+cumulative sum over the comparison that counts the groups
+(:func:`_positions`).  The gathers read a row for every one of a
+token's k slots, here or not, so a share below the rule (8 of 256
+experts: one slot in thirty-two points at a row) keeps the form the
+layer had: a gather and a scatter-add over the buffer's rows, under
+autodiff, and no map is made.  The gauge ``znicz_moe_combine{form}``
+names the form; either way the operations lie under the scope
+``combine`` (``observe.op_scopes()``: phase ``combine``), the dropless
+layer's un-permutation and weighted sum too.  The gathers, the
+weighting and the way back run over every row of the buffer, real or
+not (PERF.md §6, PR 29), so the buffer has TWO static lengths and the
 device picks one a step, from the count it has (PR 45).  ONE rule
 sizes the longer, the **capacity**: ``HELD_SLACK`` (4) times the share
 uniform routing sends here, N · k · |held| / E, and never more than
@@ -345,6 +368,138 @@ def _unpermute_bwd(residual, grad):
 _unpermute.defvjp(_unpermute_fwd, _unpermute_bwd)
 
 
+#: a held share's rows come from their tokens and go back to them BY
+#: GATHERS where the N · k pairs are at most this many times the fit
+#: size's rows (the share held is a tenth of the pairs or more), else
+#: by a gather and a scatter-add over the buffer's rows (module
+#: docstring): the gathers read a row for every one of a token's k
+#: slots, here or not, the scatter-add walks the buffer's rows one
+#: after the other
+HELD_GATHER = 8
+
+
+def _by_gathers(n: int, k: int, fit: int) -> bool:
+    return n * k <= HELD_GATHER * fit
+
+
+def _positions(mask, sizes):
+    """Where each of the N·k flat (token, slot) pairs lies in the order
+    that sorts them by the expert held (``mask`` (N·k, |held|): which
+    pair is which expert's; ``sizes`` its column sums): a pair here at
+    its expert's offset plus its rank among that expert's pairs, a
+    pair of an absent expert past them all — the inverse of the
+    order's head without a second sort."""
+    mask = mask.astype(jnp.int32)
+    rank = jnp.cumsum(mask, axis=0) - mask
+    first = jnp.cumsum(sizes) - sizes
+    return jnp.where(mask.any(axis=1),
+                     (mask * (first[None, :] + rank)).sum(axis=1),
+                     mask.shape[0])
+
+
+#: a row gather reads its operand from the chip's fast memory where
+#: the compiler can hold it there and fetches it row by row from HBM
+#: where it cannot (PERF.md §6, PR 51: 16,384 rows of 10 KB out of a
+#: 157 MB operand take 1.1 ms, six times what they take out of one
+#: that fits), so a larger operand than this is gathered a block of
+#: columns at a time — a share of a v5e's 128 MiB that leaves room for
+#: the next block's prefetch
+GATHER_OPERAND_BYTES = 48 << 20
+
+
+def _column_blocks(rows: int, columns: int, itemsize: int) -> int:
+    """Into how many blocks of whole 128-lane tiles a (rows, columns)
+    gather operand is cut so that a block holds at most
+    ``GATHER_OPERAND_BYTES`` (1: as it is)."""
+    tiles, ragged = divmod(columns, 128)
+    least = -(-rows * columns * itemsize // GATHER_OPERAND_BYTES)
+    return 1 if ragged else next(
+        (blocks for blocks in range(least, tiles + 1)
+         if tiles % blocks == 0), 1)
+
+
+def _slots_sum(rows, at, ok, scale=None):
+    """(N, D) f32: Σ over a token's k slots of the buffer's row
+    ``at[n, s]`` where ``ok[n, s]`` — k gathers of N rows added up
+    slot by slot, never an (N·k, D) buffer; by column blocks where the
+    buffer is large (:func:`_column_blocks`).  ``scale``: a factor a
+    row of the buffer, applied where the rows lie, a block at a time
+    (one pass over the buffer with the cut)."""
+    blocks = _column_blocks(*rows.shape, rows.dtype.itemsize)
+    out = []
+    for part in jnp.split(rows, blocks, axis=1):
+        if scale is not None:
+            part = part * scale[:, None]
+        f = 0.0
+        for s in range(at.shape[1]):
+            f = f + jnp.where(
+                ok[:, s, None],
+                jnp.take(part, at[:, s], axis=0, mode="clip"),
+                0).astype(jnp.float32)
+        out.append(f)
+    return out[0] if blocks == 1 else jnp.concatenate(out, axis=1)
+
+
+def _rows_of(m, dtype, token, live):
+    """Rows of ``m`` (N, D) in the buffer's order: row j is token
+    ``token[j]`` where ``live[j]``, else zero, cast to ``dtype``."""
+    return jnp.where(live[:, None], jnp.take(m, token, axis=0),
+                     0.0).astype(dtype)
+
+
+def _weights_of_rows(top_p, pair, live):
+    """The weight of each of the buffer's rows: ``top_p`` (N, k) at its
+    flat (token, slot) pair where ``live``, else zero."""
+    return jnp.where(live, jnp.take(top_p.reshape(-1), pair), 0.0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _gather_rows(m, dtype, token, live, at, ok):
+    """:func:`_rows_of` whose pullback gathers too: ``at`` (N, k) is
+    the row of each (token, slot) pair, ``ok`` whether it has one."""
+    return _rows_of(m, dtype, token, live)
+
+
+def _gather_rows_fwd(m, dtype, token, live, at, ok):
+    return _gather_rows(m, dtype, token, live, at, ok), (at, ok)
+
+
+def _gather_rows_bwd(dtype, residual, grad):
+    # gathered in the rows' dtype; a token's k copies then sum, in f32
+    # (the values a scatter-add of the f32 cast adds up)
+    return (_slots_sum(grad, *residual),) + (None,) * 4
+
+
+_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine(out, top_p, pair, live, at, ok):
+    """(N, D) f32: each token's rows of ``out`` (the buffer's, f32)
+    times their weights ``top_p`` (N, k), summed: the rows weighted
+    where they lie — ``pair`` and ``live``, the buffer's side of the
+    same map: the products the scatter-add's form makes — and then
+    gathered (:func:`_slots_sum`)."""
+    return _slots_sum(out, at, ok, _weights_of_rows(top_p, pair, live))
+
+
+def _combine_fwd(out, top_p, pair, live, at, ok):
+    return _combine(out, top_p, pair, live, at, ok), \
+        (out, top_p, pair, live, at, ok)
+
+
+def _combine_bwd(residual, grad):
+    out, top_p, pair, live, at, ok = residual
+    back = jnp.take(grad, pair // top_p.shape[1], axis=0)
+    d_weight = jnp.where(live, (out * back).sum(axis=1), 0.0)
+    return (back * _weights_of_rows(top_p, pair, live)[:, None],
+            jnp.where(ok, jnp.take(d_weight, at, mode="clip"), 0.0),
+            None, None, None, None)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def _hidden_stats(live, total):
     """``[elements of the hidden that are not zero, elements there
     are]`` as a ReLU layer's ``hidden_stats`` adds them up."""
@@ -375,14 +530,17 @@ def _cut(sizes, length):
 
 
 def _held_rows(plan, length, m, top_p, w_g, w_u, w_d, taps, casts, order,
-               sizes, here, kept=None):
+               inverse, sizes, here, kept=None):
     """``(f, sizes, (rows, gate, up, hidden, out))``: the routed sum
     of the first ``length`` (static) pairs of ``order``, of which
     ``here`` are real; the groups cut to that buffer; and what a
-    pullback of it reads.  ``casts``: the three slabs in the rows'
-    dtype, the layer's kept copies, or None (cast at each call);
-    ``kept``: the five results from an earlier run of the same
-    function, which then stand in for the ones made here
+    pullback of it reads.  ``inverse``: each pair's position in
+    ``order`` — the rows then come and go by gathers
+    (:func:`_gather_rows`, :func:`_combine`) — or None: a gather and a
+    scatter-add under autodiff (``HELD_GATHER``).  ``casts``: the three
+    slabs in the rows' dtype, the layer's kept copies, or None (cast
+    at each call); ``kept``: the five results from an earlier run of
+    the same function, which then stand in for the ones made here
     (:func:`_kept`)."""
     n, d = m.shape
     k, dt = plan.top_k, plan.dtype
@@ -398,15 +556,25 @@ def _held_rows(plan, length, m, top_p, w_g, w_u, w_d, taps, casts, order,
     def saved(made):
         return made if kept is None else _kept(made, next(keep))
 
-    rows = saved(jnp.where(live[:, None], jnp.take(m, token, axis=0),
-                           0.0).astype(dt))
+    with jax.named_scope("combine"):
+        if inverse is None:
+            rows = _rows_of(m, dt, token, live)
+        else:
+            at = inverse.reshape(n, k)
+            ok = (at >= 0) & (at < jnp.minimum(here, length))
+            rows = _gather_rows(m, dt, token, live, at, ok)
+    rows = saved(rows)
     gate = saved(grouped_matmul(rows, w_g, sizes, *path, tap=taps[0]))
     up = saved(grouped_matmul(rows, w_u, sizes, *path, tap=taps[1]))
     hidden = saved((_gate(jnp, plan.act, gate) * up).astype(dt))
     out = saved(grouped_matmul(hidden, w_d, sizes, *path, tap=taps[2]))
-    weight = jnp.where(live, jnp.take(top_p.reshape(n * k), pair), 0.0)
-    f = jnp.zeros((n, d), jnp.float32).at[token].add(
-        out * weight[:, None])
+    with jax.named_scope("combine"):
+        if inverse is None:
+            weight = _weights_of_rows(top_p, pair, live)
+            f = jnp.zeros((n, d), jnp.float32).at[token].add(
+                out * weight[:, None])
+        else:
+            f = _combine(out, top_p, pair, live, at, ok)
     return f, sizes, (rows, gate, up, hidden, out)
 
 
@@ -416,7 +584,7 @@ UNROLLED_WINDOWS = 2
 
 
 def _held_windows(plan, m, top_p, w_g, w_u, w_d, taps, casts, order,
-                  sizes, here):
+                  inverse, sizes, here):
     """``(f, sizes)`` of a buffer of the capacity's rows, made by
     :func:`_held_rows` over ⌈capacity / fit⌉ windows of ``fit`` rows of
     the order, one after the other: each window takes what is left of
@@ -443,7 +611,8 @@ def _held_windows(plan, m, top_p, w_g, w_u, w_d, taps, casts, order,
 
     def window(f, left, start, pairs):
         part, took, _ = _held_rows(
-            plan, fit, m, top_p, w_g, w_u, w_d, taps, casts, pairs, left,
+            plan, fit, m, top_p, w_g, w_u, w_d, taps, casts, pairs,
+            None if inverse is None else inverse - start, left,
             here - start)
         return f + part, left - took
 
@@ -520,9 +689,9 @@ def _fit_or_capacity_fwd(plan, *args):
 @functools.partial(jax.jit, static_argnums=0)
 def _fit_or_capacity_bwd(plan, residual, grads):
     args, kept = residual
-    # the last four — the slabs' casts, the pairs' order, the groups'
-    # sizes, the pairs here — take no cotangent
-    diff, rest = args[:-4], args[-4:]
+    # the last five — the slabs' casts, the pairs' order and its
+    # inverse, the groups' sizes, the pairs here — take no cotangent
+    diff, rest = args[:-5], args[-5:]
 
     def at_fit(diff, kept, grad):
         return jax.vjp(lambda *diff: _held_rows(
@@ -536,7 +705,7 @@ def _fit_or_capacity_bwd(plan, residual, grads):
             for tap, slab in zip(taps, grads[2:])))
 
     return jax.lax.cond(args[-1] <= plan.fit, at_fit, at_capacity,
-                        diff, kept, grads[0]) + (None,) * 4
+                        diff, kept, grads[0]) + (None,) * 5
 
 
 _fit_or_capacity.defvjp(_fit_or_capacity_fwd, _fit_or_capacity_bwd)
@@ -787,6 +956,11 @@ class MoE(Forward):
             fit = whole_tiles(math.ceil(HELD_FIT * pairs * local / e),
                               rows)
             self._capacity, self._fit = rows, fit
+        from znicz_tpu.observe import metrics as obs_metrics
+        gathers = _by_gathers(b * t, self.top_k, fit)   # (dropless: 1)
+        for form in ("gather", "scatter"):
+            obs_metrics.moe_combine(self.name, form).set(
+                gathers == (form == "gather"))
         #: the kernels' row tile: one for the layer's nine calls (a
         #: held layer's calls all run at the fit size's length)
         self._gmm_row_tile = tile = pallas_gmm.row_tile(fit)
@@ -992,20 +1166,24 @@ class MoE(Forward):
             # the pairs here first, by expert and inside an expert by
             # token; the pairs of absent experts last
             order = jnp.argsort(slot, stable=True).astype(jnp.int32)
-            sizes = (slot[:, None] == jnp.arange(local)[None, :]).sum(
-                axis=0, dtype=jnp.int32)
+            mask = slot[:, None] == jnp.arange(local)[None, :]
+            sizes = mask.sum(axis=0, dtype=jnp.int32)
             here = sizes.sum()
+            # … and, where the rows go back by gathers, the order's
+            # inverse
+            inverse = _positions(mask, sizes) \
+                if _by_gathers(n, k, plan.fit) else None
         if plan.fit == plan.capacity:
             # one length: the plain body under plain autodiff
             f, sizes, kept = _held_rows(
                 plan, plan.capacity, m, top_p, w_g, w_u, w_d, taps, casts,
-                order, sizes, here)
+                order, inverse, sizes, here)
             live = (jnp.count_nonzero(kept[3]),) \
                 if self.act == "relu" else ()
         else:
             f, sizes, *live = _fit_or_capacity(
-                plan, m, top_p, w_g, w_u, w_d, taps, casts, order, sizes,
-                here)
+                plan, m, top_p, w_g, w_u, w_d, taps, casts, order, inverse,
+                sizes, here)
         over = jnp.maximum(here - plan.capacity, 0)
         # never short: the guard refuses a step that is over
         f = f + jnp.where(over > 0, jnp.float32(jnp.nan), 0.0)
@@ -1077,8 +1255,9 @@ class MoE(Forward):
             up = grouped_matmul(rows, w_u, sizes, *path, tap=taps[1])
             hidden = (_gate(jnp, self.act, gate) * up).astype(dt)
             out = grouped_matmul(hidden, w_d, sizes, *path, tap=taps[2])
-            out = _unpermute(out, inverse, order).reshape(n, k, d)
-            y = (out * top_p[..., None]).sum(axis=1).reshape(b, t, d)
+            with jax.named_scope("combine"):
+                out = _unpermute(out, inverse, order).reshape(n, k, d)
+                y = (out * top_p[..., None]).sum(axis=1).reshape(b, t, d)
             counts = None
             if self.act == "relu":
                 hidden_stats = _hidden_stats(jnp.count_nonzero(hidden),
