@@ -117,11 +117,16 @@ def readings(reference, layers: list, bias: dict) -> list:
              {"weights_of": from_biased}, None)]
 
 
-def bias_rate_control(args, cell_name: str = CELL) -> int:
+def bias_rate_control(args, cell_name: str = CELL,
+                      through: int | None = None) -> int:
     """The system with its bias ``args.bias_rate_times`` times as fast,
     after a run's steps, under the driver's own ``check`` (module
     docstring); ``cell_name``: another cell with such a router
-    (``benchmarks/xing_controls.py``)."""
+    (``benchmarks/xing_controls.py``); ``through``: the last layer the
+    reference's forward computes (``check`` compares the layers it is
+    given, and reads every router on the system's own input BEFORE
+    that forward: where the whole stack is minutes of the host's time,
+    ``router_gap`` does not need it)."""
     import json
     import time
 
@@ -141,7 +146,15 @@ def bias_rate_control(args, cell_name: str = CELL) -> int:
     with trained(cell_name, args, epochs, faster) as (
             ctx, driver, wf, layers, _reference, devices):
         t0 = time.perf_counter()
-        problems, notes = driver.check(ctx, wf, layers)
+        load_module = discovery.load_module
+        if through is not None:
+            short = spoiled(_reference, through, {}, {}, None)
+            discovery.load_module = lambda kind, what: short \
+                if kind == "reference" else load_module(kind, what)
+        try:
+            problems, notes = driver.check(ctx, wf, layers)
+        finally:
+            discovery.load_module = load_module
         refused = any("trails the reference's k-th" in p
                       for p in problems)
         good = refused if times != 1 else not problems

@@ -991,3 +991,70 @@ def test_query_latent_layer_compiled_for_v5e_at_published_widths(
     for kernel in ("znicz_flash_fwd_mla", "znicz_flash_bwd_mla_dq",
                    "znicz_flash_bwd_mla_dkv"):
         assert kernel in text, kernel
+
+
+def test_latent_layer_compiled_for_v5e_at_t_16384(v5e_chip, monkeypatch):
+    """kanana-2-30b-a3b's latent-K/V layer WITHOUT a query latent (2,048;
+    512 + 64; 32 heads of 128 + 64 / 128; theta 1e6) forward and
+    backward at T 16,384 in bf16, through Mosaic for the chip: a K grid
+    32 tiles deep in all three two-width kernels; the forward's
+    statistic leaves at 8 lanes a head — (1, 16, 16384, 16) f32, not a
+    128-lane block a head, which at this length was 256 MiB a layer
+    held from the forward to the backward and kept a five-block step
+    off the chip — and dq / dk / dv leave their kernels in bf16 (PR
+    52)."""
+    import jax
+    import jax.numpy as jnp
+
+    from znicz_tpu.dummy import DummyUnit, DummyWorkflow
+    from znicz_tpu.memory import Vector
+    from znicz_tpu.ops import attention, pallas_kernels
+    b, t, d = 1, 16384, 2048
+    monkeypatch.setattr(pallas_kernels, "is_tpu_device",
+                        lambda device: True)
+    root.common.precision_type = "bfloat16"
+    wf = DummyWorkflow()
+    src = DummyUnit(wf, output=Vector(np.zeros((b, t, d), np.float32),
+                                      name="x"))
+    unit = attention.MultiHeadAttention(
+        wf, n_heads=32, causal=True, include_bias=False, pre_norm="rms",
+        residual=True, kv_latent=512, qk_nope=128, qk_rope=64,
+        v_head_dim=128, norm_eps=1e-6, rope={"theta": 1000000})
+    unit.link_attrs(src, ("input", "output"))
+    unit.initialize(device=XLADevice())
+    assert unit._flash.runs and unit._flash.tile == 512
+    assert unit.weights.shape == (d, 32 * 192 + 512 + 64)
+    assert not unit.weights_q_up
+
+    def struct(a):
+        return None if a is None else jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e_chip)
+
+    def step(dy, *args):
+        out, pullback = jax.vjp(unit.xla_forward, *args)
+        return out, pullback(dy)
+
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(step).lower(
+            jax.ShapeDtypeStruct((b, t, d), jnp.float32,
+                                 sharding=v5e_chip),
+            *(struct(a) for a in unit.forward_args())) \
+            .compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    calls = {}
+    for kernel in ("znicz_flash_fwd_mla", "znicz_flash_bwd_mla_dq",
+                   "znicz_flash_bwd_mla_dkv"):
+        found = re.search(rf"%\w*{kernel}[._\d]* = (\([^=]*\)|\S+) "
+                          rf"custom-call", text)
+        assert found, kernel
+        calls[kernel] = found.group(1)
+    assert "f32[1,16,16384,16]" in calls["znicz_flash_fwd_mla"]
+    for kernel, result in calls.items():
+        # no (T, H·128) array leaves a kernel in f32
+        assert "f32[1,16384,4096]" not in result, (kernel, result)
+    assert calls["znicz_flash_bwd_mla_dq"].count("bf16[1,16384,") == 2
+    assert calls["znicz_flash_bwd_mla_dkv"].count(
+        "bf16[1,16384,4096]") == 2

@@ -1636,3 +1636,88 @@ def test_what_the_two_options_refuse():
     with pytest.raises(NotImplementedError, match="act=relu"):
         refuse_unserved([moe.GatedMLP(wf, width=F, act="relu")],
                         "DecodeModel")
+
+
+# ----------------------------------------------------------------------
+# an eighth of a wide router's experts held, chosen under a selection
+# bias with no group limit, beside a shared expert of twice the routed
+# width (PR 52: kanana-2-30b-a3b's expert layer in small)
+# ----------------------------------------------------------------------
+BIASED = dict(n_experts=128, top_k=6, width=F, shared_width=2 * F,
+              held=tuple(range(16)), norm_topk=True, score="sigmoid",
+              routed_scale=2.448, select_bias=True, bias_rate=1e-3,
+              aux_loss_weight=0.0, z_loss_weight=0.0)
+BIASED_PARAMS = PARAMS + moe.MoE.SHARED
+
+
+def biased_state(fwd, gd_u) -> dict:
+    out = {}
+    for attr in BIASED_PARAMS + ("select_bias", "output"):
+        vec = getattr(fwd, attr)
+        vec.map_read()
+        out[attr] = np.array(vec.mem, np.float32)
+    gd_u.err_input.map_read()
+    out["err_input"] = np.array(gd_u.err_input.mem, np.float32)
+    return out
+
+
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["ragged_dot", "kernels_interpreted"])
+@pytest.mark.parametrize("lengths", ["two_lengths", "one_length"])
+def test_sixteen_of_128_under_a_bias_beside_a_doubled_shared_expert(
+        lengths, kernel, monkeypatch):
+    """``select_bias`` WITHOUT ``groups``, 16 of 128 experts held, top
+    6, ``routed_scale`` 2.448, a ``shared_width`` TWICE ``width`` — the
+    XLA path (``ragged_dot`` and the interpreted kernels; the buffer at
+    its fit size and at its one length of before PR 45) against the
+    numpy oracle after two momentum steps: the output, the input's
+    cotangent, every parameter — the shared expert's three among them
+    — and the bias after its rule's two moves."""
+    if kernel:
+        kernels_interpreted()
+    if lengths == "one_length":
+        monkeypatch.setattr(moe, "HELD_FIT", moe.HELD_SLACK)
+    rng = np.random.default_rng(52)
+    x = rng.normal(0, 1.0, (4, 32, D)).astype(np.float32)
+    err = rng.normal(0, 0.1, x.shape).astype(np.float32)
+    bias = rng.uniform(-0.2, 0.2, BIASED["n_experts"]).astype(np.float32)
+    np_fwd, np_gd = build(NumpyDevice(), x, **BIASED)
+    drawn = {attr: np.array(getattr(np_fwd, attr).mem)
+             for attr in BIASED_PARAMS}
+    xla_fwd, xla_gd = build(XLADevice(), x, params=drawn, **BIASED)
+    assert xla_fwd._gmm_kernel == kernel and xla_fwd.groups is None
+    assert xla_fwd.weights_shared_gate.shape == (D, 2 * F)
+    assert xla_fwd.weights_gate.shape == (16, D, F)
+    # 128 tokens × 6 pairs × 16 / 128 = 96 pairs here under uniform
+    # routing: the fit size 1.25 ×, the capacity 4 ×
+    assert xla_fwd._capacity == 384
+    assert xla_fwd._fit == (120 if lengths == "two_lengths" else 384)
+    got = []
+    for fwd, gd_u in ((np_fwd, np_gd), (xla_fwd, xla_gd)):
+        fwd.select_bias.map_write()
+        fwd.select_bias.mem[...] = bias
+        fwd.select_bias.unmap()
+        for _ in range(2):
+            step(fwd, gd_u, err)
+        got.append(biased_state(fwd, gd_u))
+        fwd.last_choice.map_read()
+        got[-1]["chosen"] = np.sort(
+            np.array(fwd.last_choice.mem).reshape(-1, 6), axis=-1)
+    want, have = got
+    np.testing.assert_array_equal(have.pop("chosen"), want.pop("chosen"))
+    for key, value in want.items():
+        np.testing.assert_allclose(have[key], value, rtol=2e-3, atol=3e-5,
+                                   err_msg=key)
+    for attr in BIASED_PARAMS:        # every parameter MOVED
+        assert np.abs(want[attr] - drawn[attr]).max() > 0, attr
+    # the bias moved twice by its rate, every entry, and the choice
+    # under it is not the unbiased one
+    moved = np.abs(want["select_bias"] - bias)
+    assert moved.max() <= 2e-3 + 1e-7 and moved.max() > 0
+    logits = moe.rms_norm(np, x, drawn["gain_norm"],
+                          np_fwd.norm_eps).reshape(-1, D) \
+        @ drawn["weights"]
+    scores = 1.0 / (1.0 + np.exp(-logits))
+    plain = np.sort(np.argsort(-scores, axis=-1)[:, :6], axis=-1)
+    under = np.sort(np.argsort(-(scores + bias), axis=-1)[:, :6], axis=-1)
+    assert (plain != under).any()

@@ -208,6 +208,87 @@ def test_combine_is_a_phase_of_the_expert_layer_forward_and_pullback(
         assert scope in text, scope
 
 
+def latent_stack(name: str, **latent) -> StandardWorkflow:
+    """An embedding, a latent-K/V attention sublayer (with the further
+    options ``latent``), a dense gated MLP and a head."""
+    vocab, seq, dim = 29, 32, 64
+    ids = np.random.default_rng(7).integers(0, vocab, (8, seq + 1))
+    prng.seed_all(22)
+    wf = StandardWorkflow(
+        name=name,
+        loader_factory=lambda w: ArrayLoader(
+            w, train_data=ids[:, :-1].astype(np.float32),
+            train_labels=ids[:, 1:].astype(np.int32),
+            minibatch_size=4, shuffle_limit=0),
+        layers=[
+            {"type": "embedding",
+             "->": {"vocab_size": vocab, "dim": dim}, "<-": GD},
+            {"type": "latent_attention",
+             "->": {"n_heads": 2, "causal": True, "include_bias": False,
+                    "pre_norm": "rms", "residual": True, "kv_latent": 32,
+                    "qk_nope": 128, "qk_rope": 64, "v_head_dim": 128,
+                    "rope": {"theta": 1e6}, "norm_eps": 1e-6, **latent},
+             "<-": GD},
+            {"type": "gated_mlp",
+             "->": {"width": 96, "pre_norm": "rms", "residual": True},
+             "<-": GD},
+            {"type": "softmax",
+             "->": {"output_sample_shape": vocab, "per_position": True,
+                    "include_bias": False}, "<-": GD}],
+        decision_config={"max_epochs": 1})
+    wf.initialize(device=XLADevice())
+    return wf
+
+
+@pytest.mark.parametrize("latent", [{}, {"q_latent": 48},
+                                    {"head_gate": True}],
+                         ids=["fused_queries", "query_latent",
+                              "head_gate"])
+def test_project_and_rotate_norm_are_phases_of_a_latent_layer(latent):
+    """A latent-K/V layer's matmuls outside its kernels lie under the
+    scope ``project``, the element-wise passes around them under
+    ``rotate_norm`` (``ops/attention.py`` ``_latent_forward``, PR 52):
+    what XLA kept of each apart from the other reads that phase — in
+    the forward unit (``jvp(project)``) and, ``transpose(jvp(…))``, in
+    the backward unit — and no other unit has either; ``UNIT_PHASES``
+    is where a reader asks whether the program knows a phase."""
+    assert scopes.UNIT_PHASES[:2] == ("route", "combine")
+    assert {"project", "rotate_norm"} <= set(scopes.UNIT_PHASES)
+    assert scopes._PROJECT.search(
+        "jit(step)/MultiHeadAttention_2/jvp(project)/dot_general")
+    assert scopes._ROTATE_NORM.search(
+        "a/GDMultiHeadAttention_2/transpose(jvp(rotate_norm))/mul")
+    assert not scopes._PROJECT.search("a/MoE_2/jvp()/_project_out/add")
+    assert not scopes._ROTATE_NORM.search("a/b/jvp(rotate)/norm/mul")
+    wf = latent_stack(f"scopes_latent_{len(latent)}_"
+                      f"{next(iter(latent), 'plain')}", **latent)
+    wf.run()
+    ops = only_program(f"znicz_step__{wf._region_unit.region.name}")
+    mixer = wf.forwards[1]
+    assert mixer.kv_latent == 32
+    gd = wf.gds[1]
+    forward, backward = phases_of(ops, mixer.name), phases_of(ops, gd.name)
+    assert {"project", "rotate_norm"} <= forward | backward
+    assert "project" in forward and "project" in backward
+    assert forward <= {"forward", "project", "rotate_norm"}
+    assert backward <= {"backward", "update", "fingerprint", "project",
+                        "rotate_norm"}
+    for unit in wf.forwards + wf.gds:
+        if unit not in (mixer, gd):
+            assert not phases_of(ops, unit.name) \
+                & {"project", "rotate_norm"}
+
+    def both(*args):
+        y, pullback = jax.vjp(mixer.xla_forward, *args)
+        return pullback(y)
+
+    text = jax.jit(both).lower(*mixer.forward_args()).as_text(
+        debug_info=True)
+    for scope in ("/jvp(project)", "/transpose(jvp(project))",
+                  "/jvp(rotate_norm)", "/transpose(jvp(rotate_norm))"):
+        assert scope in text, scope
+
+
 def test_update_and_fingerprint_are_phases_of_the_backward_unit():
     wf = conv_dense("scopes_phases")
     wf.run()
@@ -309,6 +390,63 @@ def test_the_text_is_attributed_by_all_the_fused_instructions():
     # no scope, no entry: the scan's while, the compiler's copy, and
     # nothing from inside a fused computation
     assert set(ops) == {"fusion.7", "fusion.8", "custom-call.1"}
+
+
+PRODUCTS = """HloModule jit_znicz_step__r, is_scheduled=true
+
+%fused_computation (p: f32[8,8], w: bf16[8,8]) -> f32[8,8] {
+  %p = f32[8,8]{1,0} parameter(0)
+  %w = bf16[8,8]{1,0} parameter(1)
+  %zero.1 = f32[] constant(0), metadata={op_name="jit(znicz_step__r)/MoE/jvp(jit(_fit))"}
+  %div.1 = f32[8,8]{1,0} multiply(%p, %p), metadata={op_name="jit(znicz_step__r)/Mixer/jvp(rotate_norm)/mul"}
+  %cast.1 = bf16[8,8]{1,0} convert(%div.1), metadata={op_name="jit(znicz_step__r)/GDMixer/transpose(jvp(project))/convert_element_type"}
+  ROOT %conv.1 = f32[8,8]{1,0} convolution(%cast.1, %w), dim_labels=bf_io->bf, metadata={op_name="jit(znicz_step__r)/GDMixer/transpose(jvp(project))/dot_general"}
+}
+
+%fused_computation.1 (p: f32[8,8], w: bf16[8,8]) -> f32[8,8] {
+  %p.1 = f32[8,8]{1,0} parameter(0)
+  %w.1 = bf16[8,8]{1,0} parameter(1)
+  %cast.2 = bf16[8,8]{1,0} convert(%p.1), metadata={op_name="jit(znicz_step__r)/Mixer/jvp(project)/convert_element_type"}
+  %conv.2 = f32[8,8]{1,0} convolution(%cast.2, %w.1), dim_labels=bf_io->bf, metadata={op_name="jit(znicz_step__r)/Mixer/jvp(project)/dot_general"}
+  %cast.3 = bf16[8,8]{1,0} convert(%conv.2), metadata={op_name="jit(znicz_step__r)/Mixer/jvp()/convert_element_type"}
+  ROOT %conv.3 = f32[8,8]{1,0} convolution(%cast.3, %w.1), dim_labels=bf_io->bf, metadata={op_name="jit(znicz_step__r)/Mixer/jvp()/dot_general"}
+}
+
+%fused_computation.2 (p: f32[8,8]) -> f32[8,8] {
+  %p.2 = f32[8,8]{1,0} parameter(0)
+  %mul.2 = f32[8,8]{1,0} multiply(%p.2, %p.2), metadata={op_name="jit(znicz_step__r)/Mixer/jvp(rotate_norm)/mul"}
+  ROOT %cast.4 = bf16[8,8]{1,0} convert(%mul.2), metadata={op_name="jit(znicz_step__r)/Mixer/jvp(project)/convert_element_type"}
+}
+
+ENTRY %main.3 (x: f32[8,8], w: bf16[8,8]) -> f32[8,8] {
+  %x = f32[8,8]{1,0} parameter(0)
+  %w.2 = bf16[8,8]{1,0} parameter(1)
+  %fusion.1 = f32[8,8]{1,0} fusion(%x, %w.2), kind=kOutput, calls=%fused_computation, metadata={op_name="jit(znicz_step__r)/GDMixer/transpose(jvp(project))/dot_general"}
+  %fusion.2 = f32[8,8]{1,0} fusion(%x, %w.2), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(znicz_step__r)/Mixer/jvp()/dot_general"}
+  ROOT %fusion.3 = f32[8,8]{1,0} fusion(%x), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(znicz_step__r)/Mixer/jvp(project)/convert_element_type"}
+}
+"""
+
+
+def test_a_fusion_around_the_products_of_project_reads_project():
+    """``project`` names products (PR 52): a fusion whose every matmul
+    lies in it is ``project`` of the matmuls' unit, whatever the
+    compiler fused around them — the norm's last multiply of the
+    re-made forward, a constant another unit's trace left; one matmul
+    outside the scope, or none at all, and the fusion is read by all
+    its instructions as ever."""
+    units = (("Mixer", "MultiHeadAttention", "MultiHeadAttention", False),
+             ("GDMixer", "GDMultiHeadAttention", "MultiHeadAttention",
+              True),
+             ("MoE", "MoE", "MoE", False))
+    ops = scopes.attribute(PRODUCTS, units)
+    assert ops["fusion.1"] == {
+        "unit": "GDMixer", "kind": "GDMultiHeadAttention",
+        "family": "MultiHeadAttention", "phase": "project"}
+    assert ops["fusion.2"]["unit"] == "Mixer" \
+        and ops["fusion.2"]["phase"] == "forward"
+    assert ops["fusion.3"]["unit"] == "Mixer" \
+        and ops["fusion.3"]["phase"] == "forward"
 
 
 def test_the_outermost_scope_names_the_unit():

@@ -13,7 +13,8 @@ of the COMPILED step program carries
     {program name: {HLO instruction name: {
         "unit": "GDMoE_2", "kind": "GDMoE", "family": "MoE",
         "phase": "forward" | "backward" | "update" | "fingerprint"
-                 | "pass_sum" | "router_bias" | "route" | "combine"}}}
+                 | "pass_sum" | "router_bias" | "route" | "combine"
+                 | "project" | "rotate_norm"}}}
 
 - ``kind`` is the unit's class, ``family`` the forward class a
   backward unit is paired with (a forward unit's own pairing class):
@@ -27,7 +28,21 @@ of the COMPILED step program carries
   expert layer's logits, scores, top k and the sort that plans its
   dispatch, forward and pullback; ``combine``, its experts' rows
   gathered from their tokens and put back, weighted and summed,
-  likewise), else its unit's forward / backward.  A member of a looped
+  likewise; ``project``, a latent-K/V attention layer's matmuls outside
+  its kernels — the fused down-projection, the query's and the K/V's
+  up-projections, the out-projection — and ``rotate_norm``, the
+  element-wise passes at activation size around them — the pre-norm,
+  the latents' norms, the rotations, the scale, the casts to the
+  kernels' dtype —, both forward and pullback), else its unit's
+  forward / backward.  ``project`` names PRODUCTS, so it alone reads
+  by them: a fusion whose every matmul (``convolution`` / ``dot``) lies
+  in ``project`` is ``project`` of those matmuls' unit whatever the
+  compiler fused around them — the norm's last multiply on the way in,
+  a cast or the next norm's sum of squares on the way out, a re-made
+  forward row beside a pullback's product: they run in the product's
+  loop and their time is the product's; on a TPU hardly one of these
+  products stands in a fusion of its own scope alone.  A member of a
+  looped
   span traces each application under ``<unit>/pass<r>/``: the pass is
   in the ``op_name`` path, the unit is still the outermost scope;
 - a fusion is attributed by ALL the instructions fused into it (the
@@ -67,6 +82,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import itertools
 import logging
 import re
 import threading
@@ -155,9 +171,25 @@ _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 #: itself, inside ``jvp(…)`` / ``transpose(jvp(…))`` in a forward traced
 #: under ``jax.vjp`` and in that forward's pullback
 #: … and its scope ``combine``: the rows of a layer's experts gathered
-#: from their tokens and put back, weighted and summed
-_ROUTE, _COMBINE = (re.compile(rf"(?:^|[/(]){scope}(?:[/)]|$)")
-                    for scope in ("route", "combine"))
+#: from their tokens and put back, weighted and summed; ``project`` and
+#: ``rotate_norm`` of a latent-K/V attention layer (``ops/attention.py``
+#: ``_latent_forward``) likewise.  The phases a unit names inside its
+#: own scope, in the order ``attribute``'s ``scope`` hands their flags
+#: out (a reader asks here whether the program knows a phase)
+UNIT_PHASES = ("route", "combine", "project", "rotate_norm")
+_ROUTE, _COMBINE, _PROJECT, _ROTATE_NORM = _IN_UNIT = tuple(
+    re.compile(rf"(?:^|[/(]){scope}(?:[/)]|$)") for scope in UNIT_PHASES)
+#: ``project`` names PRODUCTS: a fusion whose every matmul lies in it
+#: is ``project`` of those matmuls' unit, whatever the compiler fused
+#: around them (the norm's last multiply on the way in, a cast or the
+#: next norm's sum of squares on the way out, a re-made forward row
+#: beside a pullback's product, a constant another unit's trace left:
+#: they run in the product's loop and their time is the product's);
+#: the flag's place in what ``attribute``'s ``scope`` hands out (the
+#: unit's index, ``update``, ``fingerprint``, ``pass_sum`` and
+#: ``router_bias`` come before the phases of ``UNIT_PHASES``)
+_MATMULS = ("convolution", "dot")
+_MATMUL_SLOT = 5 + UNIT_PHASES.index("project")
 _CALLEES = re.compile(
     r"\b(?:calls|to_apply|body|condition|true_computation|"
     r"false_computation|branch_computations|called_computations)="
@@ -252,8 +284,8 @@ def attribute(text: str, units: tuple) -> dict:
         return found and found + (
             found[1] and "/pass_sum/" in f"/{op_name}/",
             "/router_bias/" in f"/{op_name}/",
-            _ROUTE.search(op_name) is not None,
-            _COMBINE.search(op_name) is not None)
+            *(pattern.search(op_name) is not None
+              for pattern in _IN_UNIT))
 
     def scopes_in(computation: str) -> frozenset:
         """Scopes of every instruction in a computation and in what
@@ -270,6 +302,22 @@ def attribute(text: str, units: tuple) -> dict:
             nested[computation] = frozenset(found)
         return nested[computation]
 
+    products: dict = {}
+
+    def products_in(computation: str) -> frozenset:
+        """Scopes of the matmuls alone, likewise."""
+        if computation not in products:
+            products[computation] = frozenset()
+            found = set()
+            for _name, opcode, op_name, callees in \
+                    computations.get(computation, ()):
+                if opcode in _MATMULS:
+                    found.add(scope(op_name))
+                for callee in callees:
+                    found |= products_in(callee)
+            products[computation] = frozenset(found)
+        return products[computation]
+
     out: dict = {}
     seen, queue = set(), [entry] if entry else []
     while queue:
@@ -283,8 +331,15 @@ def attribute(text: str, units: tuple) -> dict:
             if opcode in _CONTROL or opcode.startswith("async"):
                 queue.extend(callees)
             else:
-                for callee in callees:
-                    scopes |= scopes_in(callee)
+                matmuls = frozenset().union(
+                    *(products_in(callee) for callee in callees))
+                if matmuls and all(found is not None
+                                   and found[_MATMUL_SLOT]
+                                   for found in matmuls):
+                    scopes = set(matmuls)      # a fusion of `project`
+                else:
+                    for callee in callees:
+                        scopes |= scopes_in(callee)
             if scopes:
                 out[name] = _entry(scopes, units)
     return out
@@ -298,11 +353,12 @@ def _entry(scopes: set, units: tuple) -> dict:
     for index in sorted(by_unit):
         name, kind, family, backward = units[index]
         # ``inside``: (update, fingerprint, pass_sum, router_bias,
-        # route, combine), as ``attribute``'s ``scope`` hands them out;
-        # the scopes nested in ``update`` before it
+        # route, combine, project, rotate_norm), as ``attribute``'s
+        # ``scope`` hands them out; the scopes nested in ``update``
+        # before it
         for phase, slot in (("fingerprint", 1), ("pass_sum", 2),
                             ("update", 0), ("router_bias", 3),
-                            ("route", 4), ("combine", 5)):
+                            *zip(UNIT_PHASES, itertools.count(4))):
             if all(inside[slot] for inside in by_unit[index]):
                 break
         else:

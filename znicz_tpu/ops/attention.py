@@ -627,14 +627,26 @@ class MultiHeadAttention(Forward):
 
     def _latent_forward(self, x, w, w_out, g_norm, w_gate, w_up,
                         g_latent, w_q_up=None, g_q_latent=None):
+        """A latent-K/V layer's forward.  Two phases are named for the
+        program's map (``observe/scopes.py``), forward and pullback:
+        ``project`` — the matmuls outside the kernels (the fused
+        down-projection, the query's and the K/V's up-projections, the
+        out-projection) — and ``rotate_norm`` — the element-wise passes
+        at activation size around them (the pre-norm, the latents'
+        norms, the rotations, the scale, the casts to the kernels'
+        dtype); the kernels are their own names.  What the compiler
+        fuses around a product of ``project`` reads ``project`` with
+        it (the map's rule for this one phase)."""
         b, t, d = x.shape
         h, nope, rope = self.n_heads, self.qk_nope, self.qk_rope
         latent, dv = self.kv_latent, self.v_head_dim
-        x32 = x.astype(jnp.float32)
-        m = x32 if g_norm is None \
-            else rms_norm(jnp, x32, g_norm, self.norm_eps)
-        rows = m.reshape(b * t, d)
-        proj = self.mxu_dot(jnp, rows, w).reshape(b, t, -1)
+        with jax.named_scope("rotate_norm"):
+            x32 = x.astype(jnp.float32)
+            m = x32 if g_norm is None \
+                else rms_norm(jnp, x32, g_norm, self.norm_eps)
+            rows = m.reshape(b * t, d)
+        with jax.named_scope("project"):
+            proj = self.mxu_dot(jnp, rows, w).reshape(b, t, -1)
         at = h * nope
         # the queries: columns of the one projection, or up-projected
         # from their own normed latent
@@ -643,34 +655,41 @@ class MultiHeadAttention(Forward):
             q, below = proj, wide
         else:
             below = w_q_up.shape[0]
-            c_q = rms_norm(jnp, proj[..., :below], g_q_latent,
-                           self.norm_eps).reshape(b * t, below)
-            q = self.mxu_dot(jnp, c_q, w_q_up).reshape(b, t, wide)
-        c = rms_norm(jnp, proj[..., below:below + latent],
-                     g_latent, self.norm_eps).reshape(b * t, latent)
-        cos, sin = rope_tables(jnp, t, rope, self.rope_theta,
-                               self.rope_yarn)
-        scale = self._latent_scale()
-        q_nope = q[..., :at] * scale
-        # the heads' rotary parts turn where they lie (PR 28's view)
-        q_rope = apply_rope_rows(
-            jnp, q[..., at:wide], cos, sin, h) * scale
-        k_rope = apply_rope(jnp, proj[..., None, -rope:], cos,
-                            sin).reshape(b, t, rope)
+            with jax.named_scope("rotate_norm"):
+                c_q = rms_norm(jnp, proj[..., :below], g_q_latent,
+                               self.norm_eps).reshape(b * t, below)
+            with jax.named_scope("project"):
+                q = self.mxu_dot(jnp, c_q, w_q_up).reshape(b, t, wide)
+        with jax.named_scope("rotate_norm"):
+            c = rms_norm(jnp, proj[..., below:below + latent],
+                         g_latent, self.norm_eps).reshape(b * t, latent)
+            cos, sin = rope_tables(jnp, t, rope, self.rope_theta,
+                                   self.rope_yarn)
+            scale = self._latent_scale()
+            q_nope = q[..., :at] * scale
+            # the heads' rotary parts turn where they lie (PR 28's view)
+            q_rope = apply_rope_rows(
+                jnp, q[..., at:wide], cos, sin, h) * scale
+            k_rope = apply_rope(jnp, proj[..., None, -rope:], cos,
+                                sin).reshape(b, t, rope)
         # two products over the up-projection's column ranges, so that
         # neither k_nope ‖ v nor its cotangent is ever cut or joined at
         # activation size
-        k_nope = self.mxu_dot(jnp, c, w_up[:, :at]).reshape(b, t, at)
-        v = self.mxu_dot(jnp, c, w_up[:, at:]).reshape(b, t, h * dv)
+        with jax.named_scope("project"):
+            k_nope = self.mxu_dot(jnp, c, w_up[:, :at]).reshape(b, t, at)
+            v = self.mxu_dot(jnp, c, w_up[:, at:]).reshape(b, t, h * dv)
         arrays = (q_nope, q_rope, k_nope, k_rope, v)
         if self.mxu_dtype is not None:
-            arrays = tuple(a.astype(self.mxu_dtype) for a in arrays)
+            with jax.named_scope("rotate_norm"):
+                arrays = tuple(a.astype(self.mxu_dtype) for a in arrays)
         if self._flash is not None and self._flash.runs:
             o = self._flash.attend(*arrays)
         else:
             o = latent_attention_plain(*arrays, h)
-        return self._project_out(x32, m, o.astype(jnp.float32), w_out,
-                                 None, w_gate, None)
+        with jax.named_scope("rotate_norm"):
+            o = o.astype(jnp.float32)
+        with jax.named_scope("project"):
+            return self._project_out(x32, m, o, w_out, None, w_gate, None)
 
     @property
     def ring_active(self) -> bool:

@@ -36,9 +36,21 @@ every accumulator are f32; the products take the operands' dtype (bf16
 in mixed precision) with f32 accumulation.
 
 The kernels are simple on purpose: one (512, 512) tile a visit, the
-diagonal's mask applied to every visited tile.  One layer in six of
-Ling-3.0-flash is of this kind; what the visit of PR 36 would save
-here is PERF.md §7's to weigh.
+diagonal's mask applied to every visited tile, a two-pass backward that
+recomputes every score tile twice.  Since PR 52 a cell runs them where
+they are most of a step: ``kanana2_train_1of8``, five such layers at
+T 16,384 — a K grid 32 tiles deep — 506 of a 929 ms step (54.5%) at
+49.6% of their roofline, where Ling's one layer in six at T 4,096 is
+7.3 of 311 ms at 43.0% (PERF.md §5; chip runs of PR 52, call Q).  What the visit of PR 36 (only
+the diagonal's tiles need the mask: 6% of the visited at 32 tiles) and
+the one pass of PR 30 would save here is PERF.md §7's and ROADMAP
+S2 (7)'s to weigh.
+
+What the forward keeps for the backward beside q, k, v and o is the
+row statistic ``lse`` at ``_STAT`` lanes a head, (B, pairs, T, 16) f32:
+at a whole 128-lane block a head it was 256 MiB a layer at T 16,384,
+held from every layer's forward to its backward.  The per-head
+cotangents leave their kernels in their operands' dtype.
 """
 
 from __future__ import annotations
@@ -54,6 +66,12 @@ from jax.experimental.pallas import tpu as pltpu
 #: positions per Q and K tile
 BLOCK = 512
 _LANES = 128
+#: lanes a head's per-row statistic takes in HBM (lse: a pair's block
+#: is (rows, 2 · _STAT), the value repeated over a head's lanes — the
+#: least a tile allows, as ``pallas_attention`` keeps its own; a whole
+#: 128-lane block a head was 268 MB a layer at T 16,384, held from the
+#: forward to the backward)
+_STAT = 8
 _NEG = -1e30
 #: grid (batch, head pair, resident tile, passing tile)
 _PARAMS = pltpu.CompilerParams(
@@ -123,7 +141,10 @@ def _fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
     @pl.when(ik == iq)
     def _leave():
         o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
-        lse_ref[...] = m_ref[...] + jnp.log(l_ref[...])
+        lse = m_ref[...] + jnp.log(l_ref[...])
+        lse_ref[...] = jnp.concatenate(
+            [lse[:, j * _LANES:j * _LANES + _STAT] for j in range(2)],
+            axis=1)
 
 
 def _p_and_ds(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, do_ref,
@@ -131,7 +152,8 @@ def _p_and_ds(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, do_ref,
     """A head's probabilities and score cotangents on one tile."""
     lanes = slice(j * _LANES, (j + 1) * _LANES)
     s = _scores(qn_ref, qr_ref, kn_ref, kr_ref, j)
-    p = jnp.where(seen, jnp.exp(s - lse_ref[:, lanes][:, :1]), 0.0)
+    p = jnp.where(seen, jnp.exp(s - lse_ref[:, j * _STAT:j * _STAT + 1]),
+                  0.0)
     do = do_ref[:, lanes]
     delta = jnp.sum(do.astype(jnp.float32)
                     * o_ref[:, lanes].astype(jnp.float32),
@@ -164,8 +186,8 @@ def _dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, do_ref,
 
     @pl.when(ik == iq)
     def _leave():
-        dqn_ref[...] = dqn_acc[...]
-        dqr_ref[...] = dqr_acc[...]
+        dqn_ref[...] = dqn_acc[...].astype(dqn_ref.dtype)
+        dqr_ref[...] = dqr_acc[...].astype(dqr_ref.dtype)
 
 
 def _dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, do_ref,
@@ -202,16 +224,18 @@ def _dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, do_ref,
 
     @pl.when(iq == pl.num_programs(3) - 1)
     def _leave():
-        dkn_ref[...] = dkn_acc[...]
-        dv_ref[...] = dv_acc[...]
+        dkn_ref[...] = dkn_acc[...].astype(dkn_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
         dkr_ref[...] = dkr_acc[...]
 
 
 def _specs(bq: int, bk: int, q_at, k_at):
     """Blocks of a pair of heads, column block ``p`` of each per-head
-    array: the two block makers, a pair's width, and the specs of the
-    operands in the kernels' order — q_nope, q_rope, k_nope, k_r twice,
-    v (the forward's five), then o, do, lse (the backward's eight)."""
+    array: the two block makers, a pair's width, the statistics' block
+    (``lse`` lies (B, pairs, T, 2 · _STAT): a pair's rows are one block
+    whatever its narrow last axis), and the specs of the operands in
+    the kernels' order — q_nope, q_rope, k_nope, k_r twice, v (the
+    forward's five), then o, do, lse (the backward's eight)."""
     pair = 2 * _LANES
 
     def q_side(width):
@@ -223,10 +247,12 @@ def _specs(bq: int, bk: int, q_at, k_at):
             (None, bk, width),
             lambda b, p, i, j: (b, k_at(i, j), 0 if shared else p))
 
+    stat = pl.BlockSpec((None, None, bq, 2 * _STAT),
+                        lambda b, p, i, j: (b, p, q_at(i, j), 0))
     operands = [q_side(pair), q_side(_LANES), k_side(pair),
                 k_side(pair, shared=True), k_side(pair), q_side(pair),
-                q_side(pair), q_side(pair)]
-    return q_side, k_side, pair, operands
+                q_side(pair), stat]
+    return q_side, k_side, pair, stat, operands
 
 
 def _twice(kr):
@@ -241,13 +267,14 @@ def _forward(qn, qr, kn, kr, v, interpret):
     pairs = wide // (2 * _LANES)
     bq = bk = min(BLOCK, t)
     steps = t // bq
-    q_side, _, pair, ins = _specs(
+    q_side, _, pair, stat, ins = _specs(
         bq, bk, lambda i, j: i, lambda i, j: jnp.minimum(i, j))
     return pl.pallas_call(
         _fwd_kernel, grid=(b, pairs, steps, steps), in_specs=ins[:5],
-        out_specs=(q_side(pair), q_side(pair)),
+        out_specs=(q_side(pair), stat),
         out_shape=(jax.ShapeDtypeStruct((b, t, wide), qn.dtype),
-                   jax.ShapeDtypeStruct((b, t, wide), jnp.float32)),
+                   jax.ShapeDtypeStruct((b, pairs, t, 2 * _STAT),
+                                        jnp.float32)),
         scratch_shapes=[pltpu.VMEM((bq, pair), jnp.float32)] * 3,
         compiler_params=_PARAMS,
         interpret=interpret, name="znicz_flash_fwd_mla",
@@ -256,6 +283,10 @@ def _forward(qn, qr, kn, kr, v, interpret):
 
 @functools.partial(jax.jit, static_argnums=(8,))
 def _backward(qn, qr, kn, kr, v, o, lse, do, interpret):
+    """dq_nope, dq_rope, dk_nope, dk_r, dv: the per-head ones leave
+    their kernel in their operand's dtype (f32 accumulators cast at the
+    one write: three (B, T, H·128) arrays are never f32 in HBM), the
+    shared key's per pair in f32 for the sum outside."""
     b, t, wide = qn.shape
     pairs = wide // (2 * _LANES)
     bq = bk = min(BLOCK, t)
@@ -263,13 +294,13 @@ def _backward(qn, qr, kn, kr, v, o, lse, do, interpret):
     f32 = jnp.float32
     kr2 = _twice(kr)
     # dq: a Q tile stays, the K tiles up to the diagonal pass
-    q_side, k_side, pair, ins = _specs(
+    q_side, k_side, pair, _, ins = _specs(
         bq, bk, lambda i, j: i, lambda i, j: jnp.minimum(i, j))
     dqn, dqr = pl.pallas_call(
         _dq_kernel, grid=(b, pairs, steps, steps), in_specs=ins,
         out_specs=(q_side(pair), q_side(_LANES)),
-        out_shape=(jax.ShapeDtypeStruct((b, t, wide), f32),
-                   jax.ShapeDtypeStruct((b, t, pairs * _LANES), f32)),
+        out_shape=(jax.ShapeDtypeStruct((b, t, wide), qn.dtype),
+                   jax.ShapeDtypeStruct((b, t, pairs * _LANES), qr.dtype)),
         scratch_shapes=[pltpu.VMEM((bq, pair), f32),
                         pltpu.VMEM((bq, _LANES), f32)],
         compiler_params=_PARAMS, interpret=interpret,
@@ -277,15 +308,15 @@ def _backward(qn, qr, kn, kr, v, o, lse, do, interpret):
     )(qn, qr, kn, kr2, v, o, do, lse)
     # dk, dv: a K tile stays (grid axis 2), the Q tiles from the
     # diagonal down pass (axis 3)
-    q_side, k_side, pair, ins = _specs(
+    q_side, k_side, pair, _, ins = _specs(
         bq, bk, lambda i, j: jnp.maximum(i, j), lambda i, j: i)
     dkn, dv, dkr = pl.pallas_call(
         _dkv_kernel, grid=(b, pairs, steps, steps), in_specs=ins,
         out_specs=(k_side(pair), k_side(pair),
                    pl.BlockSpec((None, None, bk, _LANES),
                                 lambda b_, p, i, j: (b_, p, i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((b, t, wide), f32),
-                   jax.ShapeDtypeStruct((b, t, wide), f32),
+        out_shape=(jax.ShapeDtypeStruct((b, t, wide), kn.dtype),
+                   jax.ShapeDtypeStruct((b, t, wide), v.dtype),
                    jax.ShapeDtypeStruct((b, pairs, t, _LANES), f32)),
         scratch_shapes=[pltpu.VMEM((bk, pair), f32),
                         pltpu.VMEM((bk, pair), f32),
