@@ -200,6 +200,7 @@ def compile_step(t: int, workload: str = "ling_train_1of64",
     from znicz_tpu.backends import XLADevice
     from znicz_tpu.loader.fullbatch import ArrayLoader
     from znicz_tpu.models.standard_workflow import StandardWorkflow
+    from znicz_tpu.observe import metrics as obs_metrics
     from znicz_tpu.utils import prng
     from znicz_tpu.utils.config import root
 
@@ -230,6 +231,11 @@ def compile_step(t: int, workload: str = "ling_train_1of64",
                 if getattr(unit, flag, False):
                     setattr(unit, flag, False)
         line = {"workload": workload, "t": t}
+        latent = obs_metrics.REGISTRY.get("znicz_attention_latent") or {}
+        passes = sorted({int(gauge.value) for (_, stat), gauge
+                         in latent.items() if stat == "backward_passes"})
+        if passes:      # what the rule read from the shapes, per layer
+            line["mla_backward_passes"] = passes
         try:
             line.update(compile_for_described_chip(wf, text_to),
                         loads=True)

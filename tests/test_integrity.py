@@ -988,21 +988,25 @@ def test_query_latent_layer_compiled_for_v5e_at_published_widths(
             .compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
-    for kernel in ("znicz_flash_fwd_mla", "znicz_flash_bwd_mla_dq",
-                   "znicz_flash_bwd_mla_dkv"):
+    for kernel in ("znicz_flash_fwd_mla", "znicz_flash_bwd_mla"):
         assert kernel in text, kernel
+    # T 4,096: a pair's dq is 6 MiB of VMEM, the backward ONE call
+    assert unit._flash.backward_passes == 1
+    assert "znicz_flash_bwd_mla_d" not in text
 
 
 def test_latent_layer_compiled_for_v5e_at_t_16384(v5e_chip, monkeypatch):
     """kanana-2-30b-a3b's latent-K/V layer WITHOUT a query latent (2,048;
     512 + 64; 32 heads of 128 + 64 / 128; theta 1e6) forward and
     backward at T 16,384 in bf16, through Mosaic for the chip: a K grid
-    32 tiles deep in all three two-width kernels; the forward's
+    32 tiles deep in both two-width kernels; the forward's
     statistic leaves at 8 lanes a head — (1, 16, 16384, 16) f32, not a
     128-lane block a head, which at this length was 256 MiB a layer
     held from the forward to the backward and kept a five-block step
-    off the chip — and dq / dk / dv leave their kernels in bf16 (PR
-    52)."""
+    off the chip — and dq / dk / dv leave their kernel in bf16 (PR
+    52).  The backward is ONE call, ``znicz_flash_bwd_mla`` (PR 53): a
+    pair's whole dq, 24 MiB of f32, stays in VMEM, and what the call
+    asks for — 40 MiB — compiles through Mosaic for this chip."""
     import jax
     import jax.numpy as jnp
 
@@ -1023,6 +1027,7 @@ def test_latent_layer_compiled_for_v5e_at_t_16384(v5e_chip, monkeypatch):
     unit.link_attrs(src, ("input", "output"))
     unit.initialize(device=XLADevice())
     assert unit._flash.runs and unit._flash.tile == 512
+    assert unit._flash.backward_passes == 1
     assert unit.weights.shape == (d, 32 * 192 + 512 + 64)
     assert not unit.weights_q_up
 
@@ -1045,16 +1050,32 @@ def test_latent_layer_compiled_for_v5e_at_t_16384(v5e_chip, monkeypatch):
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
     calls = {}
-    for kernel in ("znicz_flash_fwd_mla", "znicz_flash_bwd_mla_dq",
-                   "znicz_flash_bwd_mla_dkv"):
+    for kernel in ("znicz_flash_fwd_mla", "znicz_flash_bwd_mla"):
         found = re.search(rf"%\w*{kernel}[._\d]* = (\([^=]*\)|\S+) "
-                          rf"custom-call", text)
+                          rf"custom-call\(.*", text)
         assert found, kernel
         calls[kernel] = found.group(1)
+        if kernel == "znicz_flash_bwd_mla":
+            whole_call = found.group(0)
+    assert "znicz_flash_bwd_mla_d" not in text
     assert "f32[1,16,16384,16]" in calls["znicz_flash_fwd_mla"]
     for kernel, result in calls.items():
         # no (T, H·128) array leaves a kernel in f32
         assert "f32[1,16384,4096]" not in result, (kernel, result)
-    assert calls["znicz_flash_bwd_mla_dq"].count("bf16[1,16384,") == 2
-    assert calls["znicz_flash_bwd_mla_dkv"].count(
-        "bf16[1,16384,4096]") == 2
+    # dk_nope, dv, dq_nope; dq_rope at 64 a head; dk_r per pair in f32
+    backward = calls["znicz_flash_bwd_mla"]
+    assert backward.count("bf16[1,16384,4096]") == 3
+    assert backward.count("bf16[1,16384,2048]") == 1
+    assert backward.count("f32[1,16,16384,128]") == 1
+    assert backward.count("[") == 5
+    from znicz_tpu.ops import pallas_mla
+    ask = pallas_mla._resident_dq_bytes(t) + pallas_mla._STEP_VMEM
+    assert ask == 40 * 2 ** 20
+    # the call's scoped VMEM: what it asked for, and what Mosaic used
+    # of it (a pair's whole dq and a grid step's tiles)
+    asked, used = (int(re.search(
+        rf'"{key}":\[\{{"memory_space":"1","offset":"0","size":"(\d+)"',
+        whole_call).group(1)) for key in (
+            "scoped_memory_configs", "used_scoped_memory_configs"))
+    assert asked == ask
+    assert pallas_mla._resident_dq_bytes(t) < used <= asked

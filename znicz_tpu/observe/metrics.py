@@ -804,7 +804,10 @@ def attention_latent(unit: str, stat: str) -> Gauge:
     (``MultiHeadAttention`` with ``kv_latent``; ``stat`` = ``latent``:
     the compressed K/V's width; ``qk_nope`` / ``qk_rope``: a key's
     per-head part and the rotary part all heads share; ``v``: a
-    value's width).  Set once at ``initialize``.  Where such a layer's
+    value's width; ``q_latent``: the query latent's, where there is
+    one; ``backward_passes``, where the two-width kernels run: 1 —
+    ``znicz_flash_bwd_mla``, a score tile computed once — or 2,
+    ``pallas_mla.backward_passes``).  Set once at ``initialize``.  Where such a layer's
     device time goes is no counter's: its matmuls outside the kernels
     and the element-wise passes around them are the phases ``project``
     and ``rotate_norm`` of ``observe.op_scopes()`` (``observe/scopes.py``)."""

@@ -607,7 +607,9 @@ class MultiHeadAttention(Forward):
         self._flash = pallas_mla.plan(self.device, t, h, nope, rope, dv)
         for stat, value in (("latent", latent), ("qk_nope", nope),
                             ("qk_rope", rope), ("v", dv)) + (
-                (("q_latent", self.q_latent),) if self.q_latent else ()):
+                (("q_latent", self.q_latent),) if self.q_latent else ()) + (
+                (("backward_passes", self._flash.backward_passes),)
+                if self._flash.runs else ()):
             obs_metrics.attention_latent(self.name, stat).set(value)
         self.info("%s: latent K/V of %d (+ %d shared rotary)%s, %d heads, "
                   "keys %d + %d, values %d: %s", self.name, latent, rope,
