@@ -1638,6 +1638,50 @@ def test_what_the_two_options_refuse():
                         "DecodeModel")
 
 
+def test_a_unit_says_itself_why_it_is_not_served():
+    """The seam: ``refuse_unserved`` is a loop over the units' own
+    words (``Forward.unserved``) — a layer type defined HERE is refused
+    by its own sentence behind the caller's name and its index, and the
+    function names no unit class and reads no option of one."""
+    import inspect
+    from znicz_tpu import export
+    from znicz_tpu.ops.all2all import All2All
+
+    class Unserved(All2All):
+        def unserved(self):
+            return (f"is a toy of {self.neurons} neurons; serving has "
+                    f"no step for it (ROADMAP R-never, serving half)")
+
+    wf = DummyWorkflow()
+    served = All2All(wf, output_sample_shape=3)
+    assert served.unserved() is None and served.unserved_beside() is None
+    export.refuse_unserved([served, served], "export_forward")
+    with pytest.raises(NotImplementedError) as refused:
+        export.refuse_unserved(
+            [served, Unserved(wf, output_sample_shape=5)],
+            "export_forward")
+    assert str(refused.value) == (
+        "export_forward: layer 1 is a toy of 5 neurons; serving has no "
+        "step for it (ROADMAP R-never, serving half)")
+    # an edge beside the chain's speaks before a layer's own word
+    early = moe.MoE(wf, **{**OPTIONS, "route_from": "block_input"})
+    with pytest.raises(NotImplementedError, match="layer 1 takes its "
+                       "router's logits from the input of the sublayer"):
+        export.refuse_unserved(
+            [Unserved(wf, output_sample_shape=5), early], "DecodeModel")
+    source = inspect.getsource(export.refuse_unserved)
+    code = source[source.index('"""', source.index('"""') + 3):]
+    for word in ("getattr(", "isinstance(", "__name__", "moe", "MoE",
+                 "Stream", "GatedMLP", "GatedDeltaNet", "ShortConv",
+                 "All2AllExits", "Attention", "route_from"):
+        assert word not in code, word
+    assert not [line for line in inspect.getsource(export).splitlines()
+                if line.startswith(("import ", "from "))
+                and any(module in line for module in (
+                    "ops.moe", "ops.streams", "ops.delta_net",
+                    "ops.short_conv", "ops.loop_exits"))]
+
+
 # ----------------------------------------------------------------------
 # an eighth of a wide router's experts held, chosen under a selection
 # bias with no group limit, beside a shared expert of twice the routed

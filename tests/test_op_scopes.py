@@ -145,9 +145,9 @@ def test_route_is_a_phase_of_the_expert_layer_forward_and_pullback(early):
     ``route_from`` the logits come from the block's input and the map
     is the same."""
     from znicz_tpu.observe import scopes
-    assert scopes._ROUTE.search("jit(step)/MoE_2/jvp(route)/dot_general")
-    assert scopes._ROUTE.search("a/GDMoE_2/transpose(jvp(route))/mul")
-    assert not scopes._ROUTE.search("a/MoE_2/jvp()/router_bias/add")
+    assert scopes.pattern("route").search("jit(step)/MoE_2/jvp(route)/dot_general")
+    assert scopes.pattern("route").search("a/GDMoE_2/transpose(jvp(route))/mul")
+    assert not scopes.pattern("route").search("a/MoE_2/jvp()/router_bias/add")
     wf = attention_moe(f"scopes_route_{early}", **(
         {"route_from": "block_input", "act": "relu"} if early else {}))
     wf.run()
@@ -175,10 +175,10 @@ def test_combine_is_a_phase_of_the_expert_layer_forward_and_pullback(
     primitives too), and in no other unit."""
     from znicz_tpu.observe import scopes
     from znicz_tpu.ops import moe
-    assert scopes._COMBINE.search("jit(step)/MoE_2/jvp(combine)/gather")
-    assert scopes._COMBINE.search(
+    assert scopes.pattern("combine").search("jit(step)/MoE_2/jvp(combine)/gather")
+    assert scopes.pattern("combine").search(
         "a/GDMoE_2/jit(_fit_or_capacity_bwd)/transpose(jvp(combine))/mul")
-    assert not scopes._COMBINE.search("a/MoE_2/jvp(route)/combined/add")
+    assert not scopes.pattern("combine").search("a/MoE_2/jvp(route)/combined/add")
     if share == "held_by_a_scatter_add":
         monkeypatch.setattr(moe, "HELD_GATHER", 0)
     wf = attention_moe(f"scopes_combine_{share}", **(
@@ -251,15 +251,20 @@ def test_project_and_rotate_norm_are_phases_of_a_latent_layer(latent):
     what XLA kept of each apart from the other reads that phase — in
     the forward unit (``jvp(project)``) and, ``transpose(jvp(…))``, in
     the backward unit — and no other unit has either; ``UNIT_PHASES``
-    is where a reader asks whether the program knows a phase."""
-    assert scopes.UNIT_PHASES[:2] == ("route", "combine")
-    assert {"project", "rotate_norm"} <= set(scopes.UNIT_PHASES)
-    assert scopes._PROJECT.search(
+    is where a reader asks whether the program knows a phase: every
+    phase a unit class of this process declares."""
+    from znicz_tpu.ops import attention, moe
+    assert tuple(moe.MoE.PHASES) == ("router_bias", "route", "combine")
+    assert attention.MultiHeadAttention.PHASES == {
+        "project": scopes.PRODUCTS, "rotate_norm": scopes.ALL}
+    assert {*moe.MoE.PHASES, "project", "rotate_norm"} \
+        <= set(scopes.UNIT_PHASES)
+    assert scopes.pattern("project").search(
         "jit(step)/MultiHeadAttention_2/jvp(project)/dot_general")
-    assert scopes._ROTATE_NORM.search(
+    assert scopes.pattern("rotate_norm").search(
         "a/GDMultiHeadAttention_2/transpose(jvp(rotate_norm))/mul")
-    assert not scopes._PROJECT.search("a/MoE_2/jvp()/_project_out/add")
-    assert not scopes._ROTATE_NORM.search("a/b/jvp(rotate)/norm/mul")
+    assert not scopes.pattern("project").search("a/MoE_2/jvp()/_project_out/add")
+    assert not scopes.pattern("rotate_norm").search("a/b/jvp(rotate)/norm/mul")
     wf = latent_stack(f"scopes_latent_{len(latent)}_"
                       f"{next(iter(latent), 'plain')}", **latent)
     wf.run()
@@ -435,9 +440,11 @@ def test_a_fusion_around_the_products_of_project_reads_project():
     re-made forward, a constant another unit's trace left; one matmul
     outside the scope, or none at all, and the fusion is read by all
     its instructions as ever."""
-    units = (("Mixer", "MultiHeadAttention", "MultiHeadAttention", False),
+    phases = (("project", scopes.PRODUCTS), ("rotate_norm", scopes.ALL))
+    units = (("Mixer", "MultiHeadAttention", "MultiHeadAttention", False,
+              phases),
              ("GDMixer", "GDMultiHeadAttention", "MultiHeadAttention",
-              True),
+              True, phases),
              ("MoE", "MoE", "MoE", False))
     ops = scopes.attribute(PRODUCTS, units)
     assert ops["fusion.1"] == {
@@ -447,6 +454,76 @@ def test_a_fusion_around_the_products_of_project_reads_project():
         and ops["fusion.2"]["phase"] == "forward"
     assert ops["fusion.3"]["unit"] == "Mixer" \
         and ops["fusion.3"]["phase"] == "forward"
+
+
+def test_a_unit_declares_its_own_phases():
+    """The seam: a layer type lands as a unit — its class declares the
+    scopes it opens (``PHASES``), the region hands the declaration in
+    with its members, and ``observe/scopes.py`` reads them without
+    knowing the unit: one read by all an operation's instructions, one
+    by its products, in the forward unit and (the declaration holds
+    for the layer's backward unit) wherever its GD carries them."""
+    import jax.numpy as jnp
+    from znicz_tpu.models.standard_workflow import register_layer_type
+    from znicz_tpu.ops import all2all
+
+    class ToyScoped(all2all.All2AllTanh):
+        PHASES = {"toy_matmul": scopes.PRODUCTS, "toy_squash": scopes.ALL}
+
+        def xla_run(self) -> None:
+            x = self.input.devmem
+            with jax.named_scope("toy_matmul"):
+                y = self.mxu_dot(jnp, x.reshape(len(x), -1),
+                                 self.weights.devmem)
+            with jax.named_scope("toy_squash"):
+                self.output.devmem = self.activation.fwd(
+                    jnp, y + self.bias.devmem)
+
+    assert {"toy_matmul", "toy_squash"} <= set(scopes.UNIT_PHASES)
+    with pytest.raises(ValueError, match="PHASES"):
+        type("Wrong", (all2all.All2All,), {"PHASES": {"update": "all"}})
+    register_layer_type("toy_scoped", ToyScoped)
+    rng = np.random.default_rng(0)
+    prng.seed_all(3)
+    wf = StandardWorkflow(
+        name="scopes_toy",
+        loader_factory=lambda w: ArrayLoader(
+            w, train_data=rng.normal(size=(16, 12)).astype(np.float32),
+            train_labels=rng.integers(0, 4, 16).astype(np.int32),
+            minibatch_size=4),
+        layers=[{"type": "toy_scoped",
+                 "->": {"output_sample_shape": 8}, "<-": GD},
+                {"type": "softmax", "->": {"output_sample_shape": 4},
+                 "<-": GD}],
+        decision_config={"max_epochs": 1})
+    wf.initialize(device=XLADevice())
+    wf.run()
+    ops = only_program(f"znicz_step__{wf._region_unit.region.name}")
+    toy, gd = wf.forwards[0], wf.gds[0]
+    assert isinstance(toy, ToyScoped)
+    assert {"toy_matmul", "toy_squash"} <= phases_of(ops, toy.name)
+    # the classic GD writes its backward by hand: it opens neither
+    assert phases_of(ops, gd.name) <= {"backward", "update",
+                                       "fingerprint"}
+    for unit in wf.forwards[1:] + wf.gds[1:]:
+        assert not phases_of(ops, unit.name) & set(ToyScoped.PHASES)
+    # read by its products: the text of the latent layer's test under
+    # the toy's scope, and its backward unit handed the declaration
+    declared = tuple(ToyScoped.PHASES.items())
+    units = (("Mixer", "ToyScoped", "All2AllTanh", False, declared),
+             ("GDMixer", "GDTanh", "All2AllTanh", True, declared),
+             ("MoE", "MoE", "MoE", False))
+    ops = scopes.attribute(
+        PRODUCTS.replace("project", "toy_matmul"), units)
+    assert ops["fusion.1"]["phase"] == "toy_matmul" \
+        and ops["fusion.1"]["unit"] == "GDMixer"
+    assert [ops[f"fusion.{n}"]["phase"] for n in (2, 3)] \
+        == ["forward", "forward"]
+    # … and undeclared, the same text reads by unit alone
+    ops = scopes.attribute(PRODUCTS.replace("project", "toy_matmul"),
+                           tuple(unit[:4] for unit in units))
+    assert ops["fusion.1"]["unit"] is None
+    assert {ops[f"fusion.{n}"]["phase"] for n in (2, 3)} == {"forward"}
 
 
 def test_the_outermost_scope_names_the_unit():

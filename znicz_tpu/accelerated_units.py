@@ -93,6 +93,17 @@ def set_pass_phase(phase: "tuple | None") -> "tuple | None":
 class AcceleratedUnit(Unit):
     """Base class for compute units with oracle + XLA paths."""
 
+    #: the scopes a unit opens inside its own (``jax.named_scope``),
+    #: which ``observe.op_scopes()`` reads as phases of it and of its
+    #: backward unit: ``{scope: how}`` in the order they are tested,
+    #: ``how`` one of ``observe.scopes.ALL`` / ``PRODUCTS`` — declared
+    #: where the scope is opened, and nowhere else
+    PHASES: dict = {}
+
+    def __init_subclass__(cls, **kwargs) -> None:
+        super().__init_subclass__(**kwargs)
+        _scopes.declare(cls.__dict__.get("PHASES", {}))
+
     def __init__(self, workflow, name: str | None = None, **kwargs) -> None:
         super().__init__(workflow, name=name, **kwargs)
         self.device: Device | None = None
@@ -490,13 +501,14 @@ class JitRegion(Logger):
         program's compiled text, should anyone ask: the jitted function
         (a program of the persisted store has none: one is made when
         asked), the leaves' shapes, dtypes and shardings, donation, and
-        the member units by name, class and family.  Nothing is
+        the member units by name, class, family and the phases their
+        classes declare (``PHASES``).  Nothing is
         lowered here.  When asked, the lowering of the SAME jitted
         function finds JAX's own record of the running executable; a
         re-trace (JAX dropped that record, or the store's program)
         runs the body, which leaves tracers in the Vectors — they are
         put back."""
-        from znicz_tpu.ops.nn_units import family_of
+        from znicz_tpu.ops.nn_units import family_of, phases_of
         ref = weakref.ref(self)
 
         def text() -> str:
@@ -513,8 +525,8 @@ class JitRegion(Logger):
 
         _scopes.remember(
             body.__name__,
-            tuple((unit.name, type(unit).__name__) + family_of(unit)
-                  for unit in self.units),
+            tuple((unit.name, type(unit).__name__, *family_of(unit),
+                   phases_of(unit)) for unit in self.units),
             text)
 
     def run(self) -> None:

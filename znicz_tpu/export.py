@@ -209,124 +209,22 @@ def attach_decode_meta(path: str, *, page_tokens: int | None = None,
     return meta
 
 
-#: the attention options of the pre-norm residual block (ROADMAP R0)
-_BLOCK_OPTIONS = ("pre_norm", "post_norm", "qk_norm", "rope_theta",
-                  "residual", "head_dim", "window", "head_gate")
-
-
 def refuse_unserved(forwards, what: str) -> None:
-    """Serving knows the bare attention layer only: a bundle's manifest
-    carries no block option and no expert layer, the decode plan has no
-    incremental step for rotary positions, the q/k norms, the pre-norm
-    residual or the experts.  Refuse such a chain by name rather than
-    serve another model than was trained (ROADMAP R1, serving half)."""
-    for i, unit in enumerate(forwards):
-        if getattr(unit, "route_from", None):
-            # before the sublayer it reaches past is refused for itself
-            raise NotImplementedError(
-                f"{what}: layer {i} takes its router's logits from "
-                f"the input of the sublayer before it (moe, "
-                f"route_from={unit.route_from}); serving runs a chain "
-                f"one layer's output into the next — the edge beside it "
-                f"and the expert layer exist on the training path only "
-                f"(ROADMAP R1, serving half)")
-    for i, unit in enumerate(forwards):
-        kind = type(unit).__name__
-        span = getattr(unit, "pass_span", None)
-        if span is not None:
-            raise NotImplementedError(
-                f"{what}: layer {i} is a member of a looped span (table "
-                f"key 'passes': {span.passes} passes over "
-                f"{len(span.forwards)} layers on shared weights); "
-                f"serving runs a chain once — a pass has no K/V cache "
-                f"of its own and no early exit yet (ROADMAP R7, serving "
-                f"half)")
-        if kind == "All2AllExits":
-            raise NotImplementedError(
-                f"{what}: layer {i} is a multi-exit head (loop_exits); "
-                f"serving has no exit rule yet — the exit gate and the "
-                f"exit distribution exist on the training path only "
-                f"(ROADMAP R7, serving half)")
-        if kind == "GatedMLP":
-            relu = getattr(unit, "act", "silu") != "silu"
-            raise NotImplementedError(
-                f"{what}: layer {i} is a gated MLP block (gated_mlp"
-                f"{', act=' + unit.act if relu else ''}); "
-                f"serving runs no feed-forward sublayer yet (ROADMAP R1, "
-                f"serving half)")
-        if kind == "GatedDeltaNet":
-            channel = getattr(unit, "decay", "head") == "channel"
-            raise NotImplementedError(
-                f"{what}: layer {i} is a gated-delta-rule linear-"
-                f"attention layer (gated_delta_net"
-                f"{', decay=channel: a decay per key channel' if channel else ''}"
-                f"); serving has no "
-                f"state slot yet — the recurrent state and the "
-                f"convolution's tail exist on the training path only "
-                f"(ROADMAP R6, serving half)")
-        if kind == "ShortConv":
-            raise NotImplementedError(
-                f"{what}: layer {i} is a gated short-convolution mixer "
-                f"(short_conv); serving has no rolling state for the "
-                f"convolution's last taps yet — the mixer exists on the "
-                f"training path only (ROADMAP R1, serving half)")
-        if kind in ("StreamOpen", "StreamRead", "StreamWrite",
-                    "StreamClose"):
-            raise NotImplementedError(
-                f"{what}: layer {i} is a unit of a residual path of "
-                f"{getattr(unit, 'n_streams', 'n')} streams "
-                f"(stream_open / stream_read / stream_write / "
-                f"stream_close); serving carries one residual row a "
-                f"token — the n-stream state, its maps and Sinkhorn's "
-                f"iterations exist on the training path only (ROADMAP "
-                f"R1, serving half)")
-        if kind == "MoE":
-            choice = [name for name, on in (
-                ("select_bias", getattr(unit, "select_bias_on", False)),
-                ("groups", getattr(unit, "groups", None))) if on]
-            said = choice + [f"act={unit.act}" for _ in range(
-                getattr(unit, "act", "silu") != "silu")]
-            raise NotImplementedError(
-                f"{what}: layer {i} is a sparse-expert layer (moe"
-                f"{', ' + ', '.join(said) if said else ''}); "
-                f"serving has no expert dispatch yet — router, top-k"
-                f"{', the selection bias and the group limit' if choice else ''} "
-                f"and grouped matmul exist on the training path only "
-                f"(ROADMAP R1, serving half)")
-        if kind != "MultiHeadAttention":
-            continue
-        if getattr(unit, "kv_latent", None) is not None:
-            more = [name for name in ("q_latent", "score_scale")
-                    if getattr(unit, name, None) is not None]
-            raise NotImplementedError(
-                f"{what}: attention layer {i} sets kv_latent (a latent "
-                f"K/V with qk_nope, qk_rope, v_head_dim"
-                f"{'; ' + ', '.join(more) if more else ''}); serving has "
-                f"no latent page and no absorbed projections yet — the "
-                f"prefill / decode steps cache whole keys and values"
-                + (", and the manifest lacks the query latent's two "
-                   "projections, its norm's gain and the scores' factor"
-                   if more else "")
-                + " (ROADMAP R5, serving half)")
-        if getattr(unit, "qk_norm", None) == "rms_head":
-            raise NotImplementedError(
-                f"{what}: attention layer {i} sets qk_norm=rms_head (a "
-                f"norm over each head of q and k, one gain of the head's "
-                f"size); the manifest and the prefill / decode steps "
-                f"lack the gains and the norm of a cached key (ROADMAP "
-                f"R1, serving half)")
-        used = [name for name in _BLOCK_OPTIONS
-                if getattr(unit, name, None)]
-        if getattr(unit, "n_kv_heads", unit.n_heads) != unit.n_heads:
-            used.append("n_kv_heads")
-        if used:
-            raise NotImplementedError(
-                f"{what}: attention layer {i} sets "
-                f"{', '.join(n.replace('_theta', '') for n in used)}; "
-                f"serving runs the bare attention layer only — the "
-                f"manifest and the prefill / decode steps lack the "
-                f"norm gains, the rotation by cached position and the "
-                f"residual (ROADMAP R1, serving half)")
+    """Refuse, by layer, a chain that serving would run as another
+    model than was trained (ROADMAP R1, serving half).  A bundle's
+    manifest and the prefill / decode steps know the classic layers and
+    the bare attention layer; a unit they have no step for says so
+    itself (``Forward.unserved``: its own sentence, from its own
+    options), and this raises the first such sentence — of an edge
+    beside the chain's before any layer's own (``unserved_beside``).
+    A unit that says nothing is served (ROADMAP D26)."""
+    def refuse(sentences) -> None:
+        for i, why in enumerate(sentences):
+            if why:
+                raise NotImplementedError(f"{what}: layer {i} {why}")
+
+    refuse(unit.unserved_beside() for unit in forwards)
+    refuse(unit.unserved() for unit in forwards)
 
 
 def export_forward(workflow, path: str) -> str:

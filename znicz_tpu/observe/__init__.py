@@ -23,8 +23,9 @@ introspection — plotters and ``veles/web_status.py``):
   and ``op_scopes.json`` beside them.
 - :func:`op_scopes` (:mod:`znicz_tpu.observe.scopes`) — which unit,
   and which phase of it (forward, backward, ``update``,
-  ``fingerprint``), every HLO instruction of the compiled region
-  programs belongs to: the key to a profile's ``fusion.362``.
+  ``fingerprint``, a phase the unit's class declares: ``PHASES``),
+  every HLO instruction of the compiled region programs belongs to:
+  the key to a profile's ``fusion.362``.
 - :mod:`znicz_tpu.observe.recorder` (round 24) — the ops flight
   recorder: a bounded crash-safe JSONL journal of consequential ops
   events (swaps, canary verdicts, restarts, quarantines, breaker

@@ -13,38 +13,35 @@ of the COMPILED step program carries
     {program name: {HLO instruction name: {
         "unit": "GDMoE_2", "kind": "GDMoE", "family": "MoE",
         "phase": "forward" | "backward" | "update" | "fingerprint"
-                 | "pass_sum" | "router_bias" | "route" | "combine"
-                 | "project" | "rotate_norm"}}}
+                 | "pass_sum" | <a phase the unit's class declares>}}}
 
 - ``kind`` is the unit's class, ``family`` the forward class a
   backward unit is paired with (a forward unit's own pairing class):
   one family holds a layer's forward and backward units;
-- forward and backward are told apart by the unit's class, ``update``
-  and ``fingerprint`` by the scope: an operation reads ``update`` only
-  if EVERY scoped instruction in it lies inside that scope
-  (``fingerprint`` is nested in ``update`` and reads likewise; so does
-  ``pass_sum``, the sum of a looped span's partial gradients over its
-  passes; ``router_bias``, the selection bias's rule; ``route``, an
-  expert layer's logits, scores, top k and the sort that plans its
-  dispatch, forward and pullback; ``combine``, its experts' rows
-  gathered from their tokens and put back, weighted and summed,
-  likewise; ``project``, a latent-K/V attention layer's matmuls outside
-  its kernels — the fused down-projection, the query's and the K/V's
-  up-projections, the out-projection — and ``rotate_norm``, the
-  element-wise passes at activation size around them — the pre-norm,
-  the latents' norms, the rotations, the scale, the casts to the
-  kernels' dtype —, both forward and pullback), else its unit's
-  forward / backward.  ``project`` names PRODUCTS, so it alone reads
-  by them: a fusion whose every matmul (``convolution`` / ``dot``) lies
-  in ``project`` is ``project`` of those matmuls' unit whatever the
-  compiler fused around them — the norm's last multiply on the way in,
-  a cast or the next norm's sum of squares on the way out, a re-made
+- forward and backward are told apart by the unit's class, every other
+  phase by a scope inside the unit's own.  This module knows the scopes
+  the REGION opens in every unit: ``update`` and, nested in it,
+  ``fingerprint`` and ``pass_sum`` (the sum of a looped span's partial
+  gradients over its passes).  Any other is DECLARED by the unit that
+  opens it — ``PHASES`` on its class (``AcceleratedUnit.PHASES``;
+  ``nn_units.phases_of`` gives a backward unit its forward's, whose
+  scopes a pullback's operations carry as ``transpose(jvp(<scope>))``):
+  the scopes in the order they are tested, each with how an operation
+  is read into it.  :data:`ALL`: an operation reads the phase only if
+  EVERY scoped instruction in it lies inside that scope (as the
+  region's own scopes read).  :data:`PRODUCTS`: a fusion whose every
+  matmul (``convolution`` / ``dot``) lies in the scope reads the phase
+  of those matmuls' unit whatever the compiler fused around them — a
+  norm's last multiply on the way in, a cast on the way out, a re-made
   forward row beside a pullback's product: they run in the product's
-  loop and their time is the product's; on a TPU hardly one of these
-  products stands in a fusion of its own scope alone.  A member of a
-  looped
-  span traces each application under ``<unit>/pass<r>/``: the pass is
-  in the ``op_name`` path, the unit is still the outermost scope;
+  loop and their time is the product's.  What each phase of a unit
+  holds is in that unit's docstring; a region hands the declarations
+  in with the members (:func:`remember`), and this module names no
+  unit and no phase of one.  The region's nested scopes are tested
+  first, then the unit's in declared order, else the unit's forward /
+  backward.  A member of a looped span traces each application under
+  ``<unit>/pass<r>/``: the pass is in the ``op_name`` path, the unit
+  is still the outermost scope;
 - a fusion is attributed by ALL the instructions fused into it (the
   fused computation's body in the same text), not by its root alone:
   instructions of more than one unit make it mixed,
@@ -82,7 +79,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
-import itertools
 import logging
 import re
 import threading
@@ -101,7 +97,8 @@ class Program:
     #: the second program of that name on (a region's train and eval
     #: variants share ``znicz_step__<region>``)
     name: str
-    #: per member unit ``(name, kind, family, backward)``
+    #: per member unit ``(name, kind, family, backward, phases)``,
+    #: ``phases`` the ``((scope, how), …)`` its class declares
     units: tuple
     #: ``() -> compiled HLO text``; dropped once parsed
     text: object
@@ -166,30 +163,45 @@ def forget() -> None:
 # ----------------------------------------------------------------------
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([^\s=]+)\s+=\s+(.*)$")
 _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
-#: the scope ``route`` of an expert layer (``ops/moe.py``) in an
-#: ``op_name``: a path element of its own, bare in what the unit traced
-#: itself, inside ``jvp(…)`` / ``transpose(jvp(…))`` in a forward traced
-#: under ``jax.vjp`` and in that forward's pullback
-#: … and its scope ``combine``: the rows of a layer's experts gathered
-#: from their tokens and put back, weighted and summed; ``project`` and
-#: ``rotate_norm`` of a latent-K/V attention layer (``ops/attention.py``
-#: ``_latent_forward``) likewise.  The phases a unit names inside its
-#: own scope, in the order ``attribute``'s ``scope`` hands their flags
-#: out (a reader asks here whether the program knows a phase)
-UNIT_PHASES = ("route", "combine", "project", "rotate_norm")
-_ROUTE, _COMBINE, _PROJECT, _ROTATE_NORM = _IN_UNIT = tuple(
-    re.compile(rf"(?:^|[/(]){scope}(?:[/)]|$)") for scope in UNIT_PHASES)
-#: ``project`` names PRODUCTS: a fusion whose every matmul lies in it
-#: is ``project`` of those matmuls' unit, whatever the compiler fused
-#: around them (the norm's last multiply on the way in, a cast or the
-#: next norm's sum of squares on the way out, a re-made forward row
-#: beside a pullback's product, a constant another unit's trace left:
-#: they run in the product's loop and their time is the product's);
-#: the flag's place in what ``attribute``'s ``scope`` hands out (the
-#: unit's index, ``update``, ``fingerprint``, ``pass_sum`` and
-#: ``router_bias`` come before the phases of ``UNIT_PHASES``)
+#: how an operation is read into a phase a unit declares (the module's
+#: text): by all its scoped instructions, or by its matmuls
+ALL, PRODUCTS = "all", "products"
 _MATMULS = ("convolution", "dot")
-_MATMUL_SLOT = 5 + UNIT_PHASES.index("project")
+#: the scopes the region opens in every unit: ``update`` and, nested
+#: in it, the two tested before it
+_NESTED = ("fingerprint", "pass_sum")
+#: every phase a unit class of this process declares, in the order the
+#: classes were defined (:func:`declare`): a reader asks here whether
+#: the program knows a phase
+UNIT_PHASES: tuple = ()
+
+
+def declare(phases: dict) -> None:
+    """Take a unit class's ``PHASES`` (``AcceleratedUnit`` hands them
+    in as the class is defined): ``{scope: ALL | PRODUCTS}``."""
+    global UNIT_PHASES
+    for scope, how in phases.items():
+        if how not in (ALL, PRODUCTS) or scope in (
+                "forward", "backward", "update", *_NESTED):
+            raise ValueError(
+                f"PHASES: {scope!r}: {how!r} — a scope of the unit's "
+                f"own, read by {ALL!r} or by {PRODUCTS!r}")
+        if scope not in UNIT_PHASES:
+            UNIT_PHASES += (scope,)
+
+
+@functools.lru_cache(maxsize=None)
+def pattern(scope: str) -> "re.Pattern":
+    """A scope in an ``op_name``: a path element of its own, bare in
+    what the unit traced itself, inside ``jvp(…)`` /
+    ``transpose(jvp(…))`` in a forward traced under ``jax.vjp`` and in
+    that forward's pullback."""
+    return re.compile(rf"(?:^|[/(]){re.escape(scope)}(?:[/)]|$)")
+
+
+#: pinned by name in znbench/tests/test_smallthinker_cell.py:422, which
+#: only a benchmark PR may edit (ROADMAP S0): ask :func:`pattern`
+_ROUTE = pattern("route")
 _CALLEES = re.compile(
     r"\b(?:calls|to_apply|body|condition|true_computation|"
     r"false_computation|branch_computations|called_computations)="
@@ -249,9 +261,8 @@ def parse(text: str) -> tuple[dict, str | None]:
     return computations, entry
 
 
-def scope_of(op_name: str, names: list) -> tuple | None:
-    """``(unit index, in update, in fingerprint)`` of one instruction's
-    ``op_name``, or ``None`` outside every unit's scope.  The unit is
+def _locate(op_name: str, names: list) -> tuple | None:
+    """``(unit index, the path inside that unit's scope)``: the unit is
     the OUTERMOST scope that is a member's name (a backward unit's ops
     may carry ``transpose(jvp(<forward unit>))`` further in)."""
     path = f"/{op_name}/"
@@ -264,28 +275,50 @@ def scope_of(op_name: str, names: list) -> tuple | None:
             best = (at, index)
     if best is None:
         return None
-    inner = path[best[0] + len(names[best[1]]) + 1:]
+    return best[1], path[best[0] + len(names[best[1]]) + 1:]
+
+
+def _region_scopes(inner: str) -> list:
+    """The region's scopes an instruction lies in: ``update`` and
+    those of ``_NESTED`` inside it."""
     update = inner.find("/update/")
-    return (best[1], update >= 0,
-            update >= 0 and "/fingerprint/" in inner[update + 7:])
+    if update < 0:
+        return []
+    return ["update", *(scope for scope in _NESTED
+                        if f"/{scope}/" in inner[update + 7:])]
+
+
+def scope_of(op_name: str, names: list) -> tuple | None:
+    """``(unit index, in update, in fingerprint)`` of one instruction's
+    ``op_name``, or ``None`` outside every unit's scope."""
+    found = _locate(op_name, names)
+    if found is None:
+        return None
+    inside = _region_scopes(found[1])
+    return found[0], "update" in inside, "fingerprint" in inside
 
 
 def attribute(text: str, units: tuple) -> dict:
-    """The map of one program: see the module's text."""
+    """The map of one program: see the module's text.  ``units``: per
+    member ``(name, kind, family, backward[, phases])``."""
     computations, entry = parse(text)
     names = [unit[0] for unit in units]
+    declared = [dict(unit[4]) if len(unit) > 4 else {} for unit in units]
+    by_products = [frozenset(scope for scope, how in phases.items()
+                             if how == PRODUCTS) for phases in declared]
     nested: dict = {}
+
     @functools.lru_cache(maxsize=None)           # few distinct names
     def scope(op_name: str):
-        """:func:`scope_of`, and whether the instruction is one of the
-        adds of a looped span's gradient sum (``pass_sum``, a scope
-        inside ``update``)."""
-        found = scope_of(op_name, names) if op_name else None
-        return found and found + (
-            found[1] and "/pass_sum/" in f"/{op_name}/",
-            "/router_bias/" in f"/{op_name}/",
-            *(pattern.search(op_name) is not None
-              for pattern in _IN_UNIT))
+        """``(unit index, the scopes the instruction lies in)``: the
+        region's and the ones its unit declares."""
+        found = _locate(op_name, names) if op_name else None
+        if found is None:
+            return None
+        index, inner = found
+        return index, frozenset(_region_scopes(inner)) | {
+            name for name in declared[index]
+            if pattern(name).search(inner)}
 
     def scopes_in(computation: str) -> frozenset:
         """Scopes of every instruction in a computation and in what
@@ -333,33 +366,30 @@ def attribute(text: str, units: tuple) -> dict:
             else:
                 matmuls = frozenset().union(
                     *(products_in(callee) for callee in callees))
-                if matmuls and all(found is not None
-                                   and found[_MATMUL_SLOT]
-                                   for found in matmuls):
-                    scopes = set(matmuls)      # a fusion of `project`
+                if matmuls and all(
+                        found is not None
+                        and found[1] & by_products[found[0]]
+                        for found in matmuls):
+                    scopes = set(matmuls)   # read by its products
                 else:
                     for callee in callees:
                         scopes |= scopes_in(callee)
             if scopes:
-                out[name] = _entry(scopes, units)
+                out[name] = _entry(scopes, units, declared)
     return out
 
 
-def _entry(scopes: set, units: tuple) -> dict:
+def _entry(scopes: set, units: tuple, declared: list) -> dict:
     by_unit: dict = {}
-    for index, *inside in scopes:
+    for index, inside in scopes:
         by_unit.setdefault(index, []).append(inside)
     parts = []
     for index in sorted(by_unit):
-        name, kind, family, backward = units[index]
-        # ``inside``: (update, fingerprint, pass_sum, router_bias,
-        # route, combine, project, rotate_norm), as ``attribute``'s
-        # ``scope`` hands them out; the scopes nested in ``update``
-        # before it
-        for phase, slot in (("fingerprint", 1), ("pass_sum", 2),
-                            ("update", 0), ("router_bias", 3),
-                            *zip(UNIT_PHASES, itertools.count(4))):
-            if all(inside[slot] for inside in by_unit[index]):
+        name, kind, family, backward = units[index][:4]
+        # the scopes nested in ``update`` before it, then the unit's
+        # own in the order it declares them
+        for phase in (*_NESTED, "update", *declared[index]):
+            if all(phase in inside for inside in by_unit[index]):
                 break
         else:
             phase = "backward" if backward else "forward"

@@ -126,6 +126,7 @@ import jax
 import jax.numpy as jnp
 
 from znicz_tpu.memory import Vector
+from znicz_tpu.observe import scopes as _scopes
 from znicz_tpu.ops.nn_units import Forward, GradientDescentBase
 from znicz_tpu.ops.rms_norm import (norm_gains, post_gain, rms_norm,
                                     rms_norm_backward)
@@ -303,6 +304,16 @@ class MultiHeadAttention(Forward):
     #: may be a member of a looped span (``znicz_tpu.pass_span``): the
     #: backward needs the forward's input and pullback only
     PASS_SAFE = True
+    #: the scopes inside a latent-K/V layer's two units
+    #: (:meth:`_latent_forward`): its matmuls outside the kernels, read
+    #: by the products — on a TPU hardly one stands in a fusion of its
+    #: own scope alone — and the element-wise passes at activation size
+    #: around them
+    PHASES = {"project": _scopes.PRODUCTS, "rotate_norm": _scopes.ALL}
+    #: the options of the pre-norm residual block (ROADMAP R0), which
+    #: serving has no step for (:meth:`unserved`)
+    _BLOCK_OPTIONS = ("pre_norm", "post_norm", "qk_norm", "rope_theta",
+                      "residual", "head_dim", "window", "head_gate")
 
     def __init__(self, workflow, n_heads: int, causal: bool = False,
                  seq_parallel: bool = False,
@@ -430,6 +441,45 @@ class MultiHeadAttention(Forward):
         self._flash = None
         self.weights_out = Vector(name=f"{self.name}.weights_out")
         self.bias_out = Vector(name=f"{self.name}.bias_out")
+
+    def unserved(self) -> str | None:
+        """Serving knows the bare attention layer only: a bundle's
+        manifest carries no block option, the decode plan has no
+        incremental step for rotary positions, the q/k norms or the
+        pre-norm residual, no latent page."""
+        said = super().unserved()
+        if said is not None:
+            return said
+        if self.kv_latent is not None:
+            more = [name for name in ("q_latent", "score_scale")
+                    if getattr(self, name) is not None]
+            return (
+                f"sets kv_latent (a latent K/V with qk_nope, qk_rope, "
+                f"v_head_dim{'; ' + ', '.join(more) if more else ''}); "
+                f"serving has no latent page and no absorbed "
+                f"projections yet — the prefill / decode steps cache "
+                f"whole keys and values"
+                + (", and the manifest lacks the query latent's two "
+                   "projections, its norm's gain and the scores' factor"
+                   if more else "")
+                + " (ROADMAP R5, serving half)")
+        if self.qk_norm == "rms_head":
+            return ("sets qk_norm=rms_head (a norm over each head of q "
+                    "and k, one gain of the head's size); the manifest "
+                    "and the prefill / decode steps lack the gains and "
+                    "the norm of a cached key (ROADMAP R1, serving half)")
+        used = [name for name in self._BLOCK_OPTIONS
+                if getattr(self, name)]
+        if self.n_kv_heads != self.n_heads:
+            used.append("n_kv_heads")
+        if not used:
+            return None
+        return (
+            f"sets {', '.join(n.replace('_theta', '') for n in used)}; "
+            f"serving runs the bare attention layer only — the manifest "
+            f"and the prefill / decode steps lack the norm gains, the "
+            f"rotation by cached position and the residual (ROADMAP R1, "
+            f"serving half)")
 
     def initialize(self, device=None, **kwargs) -> None:
         super().initialize(device=device, **kwargs)

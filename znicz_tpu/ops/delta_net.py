@@ -203,6 +203,16 @@ class GatedDeltaNet(Forward):
         return np.log(a).astype(np.float32), \
             (dt + np.log(-np.expm1(-dt))).astype(np.float32)
 
+    def unserved(self) -> str | None:
+        channel = self.decay == "channel"
+        return super().unserved() or (
+            f"is a gated-delta-rule linear-attention layer "
+            f"(gated_delta_net"
+            f"{', decay=channel: a decay per key channel' if channel else ''}"
+            f"); serving has no state slot yet — the recurrent state and "
+            f"the convolution's tail exist on the training path only "
+            f"(ROADMAP R6, serving half)")
+
     def initialize(self, device=None, **kwargs) -> None:
         super().initialize(device=device, **kwargs)
         if self.input is None or not self.input:

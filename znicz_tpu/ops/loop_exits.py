@@ -99,6 +99,12 @@ class All2AllExits(Forward):
     def n_exits(self) -> int:
         return self.input.shape[1] if len(self.input.shape) == 4 else 1
 
+    def unserved(self) -> str | None:
+        return super().unserved() or (
+            "is a multi-exit head (loop_exits); serving has no exit "
+            "rule yet — the exit gate and the exit distribution exist "
+            "on the training path only (ROADMAP R7, serving half)")
+
     def initialize(self, device=None, **kwargs) -> None:
         super().initialize(device=device, **kwargs)
         if self.input is None or not self.input:

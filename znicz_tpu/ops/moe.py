@@ -203,6 +203,7 @@ import jax
 import jax.numpy as jnp
 
 from znicz_tpu.memory import Vector
+from znicz_tpu.observe import scopes as _scopes
 from znicz_tpu.ops import activations_math, pallas_gmm
 from znicz_tpu.ops.nn_units import Forward, GradientDescentBase
 from znicz_tpu.ops.rms_norm import (norm_gains, post_gain, rms_norm,
@@ -759,6 +760,12 @@ class MoE(Forward):
     #: (:meth:`_keep_slab_copies`): made from its slab, never saved
     SLAB_COPIES = tuple(f"{attr}_cast" for attr in TAPPED)
     SNAPSHOT_EXCLUDE = SLAB_COPIES
+    #: the scopes inside the layer's two units (module docstring): the
+    #: selection bias's rule; the logits, scores, top k and the sort
+    #: that plans the dispatch; the experts' rows gathered from their
+    #: tokens and put back, weighted and summed — forward and pullback
+    PHASES = {"router_bias": _scopes.ALL, "route": _scopes.ALL,
+              "combine": _scopes.ALL}
 
     def __init__(self, workflow, n_experts: int, top_k: int, width: int,
                  norm_topk: bool = False, pre_norm: str | None = None,
@@ -868,6 +875,27 @@ class MoE(Forward):
     def n_local(self) -> int:
         """Experts whose weights live here."""
         return self.n_experts if self.held is None else len(self.held)
+
+    def unserved(self) -> str | None:
+        choice = [name for name, on in (
+            ("select_bias", self.select_bias_on),
+            ("groups", self.groups)) if on]
+        said = choice + [f"act={self.act}"] * (self.act != "silu")
+        return super().unserved() or (
+            f"is a sparse-expert layer (moe"
+            f"{', ' + ', '.join(said) if said else ''}); "
+            f"serving has no expert dispatch yet — router, top-k"
+            f"{', the selection bias and the group limit' if choice else ''} "
+            f"and grouped matmul exist on the training path only "
+            f"(ROADMAP R1, serving half)")
+
+    def unserved_beside(self) -> str | None:
+        return self.route_from and (
+            f"takes its router's logits from the input of the sublayer "
+            f"before it (moe, route_from={self.route_from}); serving "
+            f"runs a chain one layer's output into the next — the edge "
+            f"beside it and the expert layer exist on the training path "
+            f"only (ROADMAP R1, serving half)")
 
     def initialize(self, device=None, **kwargs) -> None:
         super().initialize(device=device, **kwargs)
@@ -1748,6 +1776,13 @@ class GatedMLP(Forward):
         self.gain_norm = Vector(name=f"{self.name}.gain_norm")
         self.gain_post = Vector(name=f"{self.name}.gain_post")
         self._traced_vjp = None
+
+    def unserved(self) -> str | None:
+        return super().unserved() or (
+            f"is a gated MLP block (gated_mlp"
+            f"{', act=' + self.act if self.act != 'silu' else ''}); "
+            f"serving runs no feed-forward sublayer yet (ROADMAP R1, "
+            f"serving half)")
 
     def initialize(self, device=None, **kwargs) -> None:
         super().initialize(device=device, **kwargs)

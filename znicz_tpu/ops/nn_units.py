@@ -89,6 +89,19 @@ def family_of(unit) -> tuple[str, bool]:
     return type(unit).__name__, backward
 
 
+def phases_of(unit) -> tuple:
+    """``((scope, how), …)``: the phases inside a region member's own
+    scope, for ``observe.op_scopes()`` — its class's ``PHASES``, a
+    backward unit's those of the forward unit it walks back: the
+    pullback's operations carry the forward's scopes
+    (``transpose(jvp(<scope>))``), and what only the backward unit
+    opens is declared with its layer all the same."""
+    if isinstance(unit, GradientDescentBase) \
+            and getattr(unit, "forward_unit", None) is not None:
+        unit = unit.forward_unit
+    return tuple(type(unit).PHASES.items())
+
+
 # ----------------------------------------------------------------------
 # Forward base
 # ----------------------------------------------------------------------
@@ -103,6 +116,9 @@ class Forward(AcceleratedUnit):
     #: Vector attributes the exporter serializes; units with extra
     #: parameter pairs (attention's output projection) extend this
     EXPORT_PARAMS: tuple = ("weights", "bias")
+    #: the looped span this unit is a member of
+    #: (``StandardWorkflow.link_forwards`` sets it), else None
+    pass_span = None
 
     def __init__(self, workflow, name: str | None = None,
                  weights_filling: str = "uniform",
@@ -148,6 +164,25 @@ class Forward(AcceleratedUnit):
                                    float(np.sqrt(1.0 / max(1, fan_in))),
                                    dtype=np.float32)
         raise ValueError(f"unknown filling '{filling}'")
+
+    # -- serving ----------------------------------------------------------
+    def unserved(self) -> str | None:
+        """Why serving cannot run this unit — the rest of the sentence
+        ``<caller>: layer <i> …`` that ``export.refuse_unserved`` raises,
+        from "is a …" or "sets …" to the ROADMAP item that would serve
+        it — or ``None``: the unit is exported through the generic
+        manifest and served as it trained.  A layer type that has no
+        prefill / decode step says so HERE, reading its own options; a
+        member of a looped span answers for its span."""
+        return None if self.pass_span is None \
+            else self.pass_span.unserved()
+
+    def unserved_beside(self) -> str | None:
+        """Likewise for an edge beside the chain's that ends in this
+        unit.  ``export.refuse_unserved`` asks this of every layer
+        before it asks any for itself: such an edge reaches past the
+        layer before, which would else be refused first, for itself."""
+        return None
 
     @property
     def current_batch(self) -> int:

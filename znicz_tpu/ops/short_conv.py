@@ -93,6 +93,13 @@ class ShortConv(Forward):
         self._kernels = False
         self._interpret = False
 
+    def unserved(self) -> str | None:
+        return super().unserved() or (
+            "is a gated short-convolution mixer (short_conv); serving "
+            "has no rolling state for the convolution's last taps yet "
+            "— the mixer exists on the training path only (ROADMAP R1, "
+            "serving half)")
+
     def initialize(self, device=None, **kwargs) -> None:
         super().initialize(device=device, **kwargs)
         if self.input is None or not self.input:

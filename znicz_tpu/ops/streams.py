@@ -179,6 +179,14 @@ class _Stream(Forward):
             raise ValueError(f"{self}: n_streams {n_streams}: a residual "
                              f"path has 1 stream or more")
 
+    def unserved(self) -> str | None:
+        return super().unserved() or (
+            f"is a unit of a residual path of {self.n_streams} streams "
+            f"(stream_open / stream_read / stream_write / stream_close); "
+            f"serving carries one residual row a token — the n-stream "
+            f"state, its maps and Sinkhorn's iterations exist on the "
+            f"training path only (ROADMAP R1, serving half)")
+
     def _input_shape(self, wide: bool) -> tuple:
         """(B, T, D) of the model from this unit's input: the streams
         ((B, n·D, T), ``wide``) or a sublayer's rows (B, T, D)."""

@@ -125,6 +125,15 @@ class PassSpan(AcceleratedUnit):
         self._tape: list = []
         self.partial: dict = {}
 
+    def unserved(self) -> str:
+        """What every member says of itself to
+        ``export.refuse_unserved`` (``Forward.unserved``)."""
+        return (f"is a member of a looped span (table key '{TABLE_KEY}': "
+                f"{self.passes} passes over {len(self.forwards)} layers "
+                f"on shared weights); serving runs a chain once — a pass "
+                f"has no K/V cache of its own and no early exit yet "
+                f"(ROADMAP R7, serving half)")
+
     def initialize(self, device=None, **kwargs) -> None:
         super().initialize(device=device, **kwargs)
         first, last = self.forwards[0], self.forwards[-1]
