@@ -236,6 +236,14 @@ def compile_step(t: int, workload: str = "ling_train_1of64",
                          in latent.items() if stat == "backward_passes"})
         if passes:      # what the rule read from the shapes, per layer
             line["mla_backward_passes"] = passes
+        flash = obs_metrics.REGISTRY.get("znicz_flash_backward") or {}
+        if flash:       # the one-width kernels' rule, the same way
+            line["flash_backward_passes"] = sorted(
+                {int(gauge.value) for (_, stat), gauge in flash.items()
+                 if stat == "passes"})
+            line["flash_resident_dq_mib"] = sorted(
+                {gauge.value / 2 ** 20 for (_, stat), gauge
+                 in flash.items() if stat == "resident_dq_bytes"})
         try:
             line.update(compile_for_described_chip(wf, text_to),
                         loads=True)

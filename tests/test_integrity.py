@@ -315,13 +315,13 @@ def test_attention_layer_compiled_for_v5e_moves_no_activation(
 
 #: the two kinds of attention layer of ``smallthinker_train_1of8`` at the
 #: cell's T: (options, the kernels the program has to hold)
+#: … and the MiB of dq its one-pass backward keeps in VMEM
 _LONG_CONTEXT_LAYERS = {
     "nope_full": (dict(rope=None),
-                  ("znicz_flash_fwd", "znicz_flash_dq", "znicz_flash_dkv")),
+                  ("znicz_flash_fwd", "znicz_flash_bwd"), 56.0),
     "rope_window_4096": (
         dict(rope={"theta": 1500000.0}, window=4096),
-        ("znicz_flash_fwd_win", "znicz_flash_dq_win",
-         "znicz_flash_dkv_win")),
+        ("znicz_flash_fwd_win", "znicz_flash_bwd_win"), 15.75),
 }
 
 
@@ -331,10 +331,14 @@ def test_long_context_attention_layers_compile_for_v5e_at_published_widths(
     """SmallThinker's two attention layers at T 16,384 × 2,560, 28 query
     heads on 4 K/V heads of 128 (a group of SEVEN), bf16 operands,
     forward + backward, through Mosaic for a described v5e: the
-    un-windowed causal call past ``WHOLE_BLOCK_K`` keeps the TWO-pass
-    backward over a K grid eight tiles deep, the window of 4,096 runs
-    the banded kernels over a band nine tiles wide — shapes no other
-    cell has, which the chip's compiler has to take (PR 50)."""
+    un-windowed causal call past ``WHOLE_BLOCK_K`` walks a K grid eight
+    tiles deep, the window of 4,096 runs the banded kernels over a band
+    nine tiles wide — shapes no other cell has, which the chip's
+    compiler has to take (PR 50).  Each backward is ONE call (PR 55):
+    the seven heads' 7 × 16 dq tiles of 1024 × 128 wait in 56 MiB of
+    VMEM for their later K tiles, the band's in 7 × 9 slots of 512 ×
+    128 — which Mosaic has to grant beside a step's own tiles — and no
+    ``znicz_flash_dq`` / ``_dkv`` is left in the program."""
     import jax
     import jax.numpy as jnp
 
@@ -342,7 +346,7 @@ def test_long_context_attention_layers_compile_for_v5e_at_published_widths(
     from znicz_tpu.memory import Vector
     from znicz_tpu.ops import attention, pallas_attention, pallas_kernels
     b, t, d = 1, 16384, 2560
-    options, kernels = _LONG_CONTEXT_LAYERS[kind]
+    options, kernels, resident_mib = _LONG_CONTEXT_LAYERS[kind]
     monkeypatch.setattr(pallas_kernels, "is_tpu_device",
                         lambda device: True)
     root.common.precision_type = "bfloat16"
@@ -357,7 +361,9 @@ def test_long_context_attention_layers_compile_for_v5e_at_published_widths(
     unit.initialize(device=XLADevice())
     plan = unit._flash
     assert plan.runs and plan.layout == "boundary"
-    assert plan.n_heads // plan.n_kv_heads == 7 and plan.backward == 2
+    assert plan.n_heads // plan.n_kv_heads == 7 and plan.backward == 1
+    assert plan.resident_dq == resident_mib * 2 ** 20 \
+        <= pallas_attention.RESIDENT_DQ_VMEM
     if options.get("window"):
         assert pallas_attention.band_steps(t, 512, 512, 4096) == (9, 9)
     else:
@@ -383,8 +389,9 @@ def test_long_context_attention_layers_compile_for_v5e_at_published_widths(
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
     for kernel in kernels:
         assert re.search(rf"%\w*{kernel}[._\d]* = ", text), kernel
-    assert "znicz_flash_bwd" not in text
+    assert "znicz_flash_dq" not in text and "znicz_flash_dkv" not in text
     assert ("znicz_flash_fwd_win" in text) == bool(options.get("window"))
+    assert ("znicz_flash_bwd_win" in text) == bool(options.get("window"))
 
 
 def test_gated_delta_rule_compiled_for_v5e_keeps_a_chunk_in_vmem(v5e_chip):

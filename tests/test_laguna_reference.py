@@ -172,18 +172,22 @@ def test_a_window_is_named_and_refuses_what_it_cannot_do():
 
     banded = str(jax.make_jaxpr(jax.grad(lambda q: loss(q, 8)))(q))
     plain = str(jax.make_jaxpr(jax.grad(lambda q: loss(q, None)))(q))
-    for name in ("znicz_flash_fwd", "znicz_flash_dq", "znicz_flash_dkv"):
+    # each backward is ONE kernel (PR 55: past one K tile the dq tiles
+    # wait in VMEM), the band's named for its window
+    for name in ("znicz_flash_fwd", "znicz_flash_bwd"):
         assert name + "_win" in banded and name + "_win" not in plain
         assert name in plain
-    # under ONE K tile the causal call's backward is one kernel; a
-    # window keeps its dq and dk/dv kernels there too
+    for text in (banded, plain):
+        assert "znicz_flash_dq" not in text
+        assert "znicz_flash_dkv" not in text
+    # under ONE K tile too
     banded = str(jax.make_jaxpr(jax.grad(
         lambda q: loss(q, 8, BAND_T)))(q))
     plain = str(jax.make_jaxpr(jax.grad(
         lambda q: loss(q, None, BAND_T)))(q))
     assert "znicz_flash_bwd" in plain and "znicz_flash_dq" not in plain
-    assert "znicz_flash_bwd" not in banded
-    assert "znicz_flash_dq_win" in banded and "znicz_flash_dkv_win" in banded
+    assert "znicz_flash_bwd_win" in banded
+    assert "znicz_flash_dq_win" not in banded
     with pytest.raises(ValueError, match="window"):
         pa.flash_attention(q, q, q, causal=False, interpret=True,
                            window=8)
@@ -657,6 +661,12 @@ def test_what_the_expert_layers_report(one_step):
         == plan.tiles["executed_share"]
     assert "%d query heads to a K/V head, window 8" % (
         plan.n_heads // plan.n_kv_heads) in plan.line()
+    # the band's backward is one pass whose dq tiles wait in a ring of
+    # slots (PR 55): the gauge and the line say so
+    assert plan.backward == 1 and plan.resident_dq > 0
+    assert obs_metrics.flash_backward(
+        windowed.name, "resident_dq_bytes").value == plan.resident_dq
+    assert "backward passes 1 (" in plan.line()
 
 
 # ----------------------------------------------------------------------

@@ -93,6 +93,7 @@ def test_canonical_families_render_in_exposition():
         m.fleet_tenant_tokens("cov", "tenant").set(8.0),
         m.fleet_traffic_weight("cov", "lm", "v2").set(0.25),
         m.flash_band("cov_attention", "band_share").set(0.06),
+        m.flash_backward("cov_attention", "passes").set(1),
         m.moe_held("cov_moe", "rows_here").set(1280),
         m.moe_gmm_rows("cov_moe", "visited").set(41216),
         m.delta_scan("cov_delta", "padded_share").set(1.78),

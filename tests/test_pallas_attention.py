@@ -22,6 +22,27 @@ def _rand(shape, seed):
                        .normal(0, 1, shape).astype(np.float32))
 
 
+def _split(x, edges):
+    """The column ranges of ``x`` between consecutive ``edges``."""
+    return tuple(x[..., lo:hi] for lo, hi in zip(edges[:-1], edges[1:]))
+
+
+@pytest.fixture
+def dq_budget(monkeypatch):
+    """``dq_budget(n)`` sets ``pallas_attention.RESIDENT_DQ_VMEM`` for
+    one test — 0 sends every call past one K tile back to the two-pass
+    kernels.  The rule is asked while a backward is traced and JAX
+    keeps what it traced, so the traces are dropped with every change
+    of the constant, the one back included."""
+    from znicz_tpu.ops import pallas_attention as pa
+
+    def set_budget(n_bytes: int) -> None:
+        monkeypatch.setattr(pa, "RESIDENT_DQ_VMEM", n_bytes)
+        jax.clear_caches()
+    yield set_budget
+    jax.clear_caches()
+
+
 #: (grid tile, compute sub-tile): ``None`` lets the chooser decide
 #: (sub-tile = tile at these sizes); the explicit shapes put interior,
 #: crossing and skipped sub-tiles inside ONE 128-wide grid tile
@@ -396,32 +417,44 @@ def test_tile_schedule_is_derived_from_the_shapes():
 # ----------------------------------------------------------------------
 # the one-pass backward: dq accumulates in the dk/dv walk
 # ----------------------------------------------------------------------
-@pytest.mark.parametrize("causal,t_k,bk,window,want", [
-    (True, 2048, 2048, None, (2048, 1)),    # attn_lm_train_t2048
-    (True, 4096, 2048, None, (4096, 1)),    # olmoe_train_t4096, Laguna's
-                                            # full layers: K taken whole
-    (True, 8192, 2048, None, (2048, 2)),    # past WHOLE_BLOCK_K
-    (True, 16384, 2048, None, (2048, 2)),
-    (True, 3072, 1024, None, (1024, 2)),    # 2048 does not tile T
-    (True, 4096, 1024, None, (1024, 2)),    # a caller's (the ring's) tile
-    (True, 1024, 1024, None, (1024, 1)),
-    (True, 4096, 512, 512, (512, 2)),       # Laguna's sliding layers
-    (True, 16384, 512, 4096, (512, 2)),     # SmallThinker's RoPE layers
-    (True, 8192, 512, 4096, (512, 2)),
-    (True, 4096, 2048, 512, (2048, 2)),
-    (False, 2048, 1024, None, (1024, 2)),   # non-causal
-    (False, 1024, 1024, None, (1024, 2)),
+@pytest.mark.parametrize("causal,t_k,bk,window,group,want", [
+    (True, 2048, 2048, None, 1, (2048, 1, 0)),  # attn_lm_train_t2048
+    (True, 4096, 2048, None, 1, (4096, 1, 0)),  # olmoe_train_t4096 …
+    (True, 4096, 2048, None, 6, (4096, 1, 0)),  # … Laguna's full layers:
+                                                # K taken whole
+    # past WHOLE_BLOCK_K: every dq tile of the group waits in VMEM
+    (True, 8192, 2048, None, 1, (2048, 1, 4)),
+    (True, 8192, 2048, None, 7, (2048, 1, 28)),
+    (True, 16384, 2048, None, 7, (2048, 1, 56)),    # SmallThinker's NoPE
+    (True, 32768, 2048, None, 1, (2048, 1, 16)),
+    (True, 32768, 2048, None, 7, (2048, 2, 112)),   # past the budget
+    (True, 3072, 1024, None, 1, (1024, 1, 1.5)),    # 2048 does not tile T
+    (True, 4096, 1024, None, 1, (1024, 1, 2)),  # a caller's (the ring's)
+    (True, 1024, 1024, None, 1, (1024, 1, 0)),
+    # a window: a band's width of slots a query head
+    (True, 4096, 512, 512, 9, (512, 1, 4.5)),   # Laguna's sliding layers
+    (True, 16384, 512, 4096, 7, (512, 1, 15.75)),   # SmallThinker's RoPE
+    (True, 8192, 512, 4096, 7, (512, 1, 15.75)),
+    (True, 4096, 2048, 512, 1, (2048, 1, 1.25)),
+    (False, 2048, 1024, None, 1, (1024, 2, 0)),     # non-causal
+    (False, 1024, 1024, None, 1, (1024, 2, 0)),
 ])
 def test_backward_tile_and_passes_of_the_cells(causal, t_k, bk, window,
-                                               want):
-    """The backward's K tile and its passes at the chooser's tiles, next
-    to ``grid_blocks`` / ``sub_tile_for``: from ``causal``, T_k, the
-    forward's K tile and the window alone."""
+                                               group, want):
+    """The backward's K tile, its passes and the dq it keeps in VMEM
+    (MiB) at the chooser's tiles, next to ``grid_blocks`` /
+    ``sub_tile_for``: from ``causal``, T, the forward's K tile, the
+    window, the query heads a K/V head and the 128 lanes of a head
+    alone, against ONE constant."""
     from znicz_tpu.ops import pallas_attention as pa
+    assert pa.RESIDENT_DQ_VMEM == 64 * 2 ** 20
     if window is None:
         assert pa.grid_blocks(causal, t_k, t_k, block_k=bk)[1] == bk
+    resident = pa.resident_dq_bytes(causal, t_k, bk, window, group=group) \
+        if causal else 0
     assert (pa.backward_block_k(causal, t_k, bk, window),
-            pa.backward_passes(causal, t_k, bk, window)) == want
+            pa.backward_passes(causal, t_k, bk, window, group=group),
+            resident / 2 ** 20) == want
 
 
 def _kernel_names(fn, *args) -> list:
@@ -430,35 +463,47 @@ def _kernel_names(fn, *args) -> list:
                       str(jax.make_jaxpr(fn)(*args)))
 
 
-#: (K tiles, causal, window, query heads per K/V head) → passes over
+#: (K tiles, causal, window, query heads per K/V head, the budget in
+#: bytes of dq that may wait in VMEM — None: the module's) → passes over
 #: the score tiles in the backward, and the kernels the program holds
 PASSES = [
-    (1, True, None, 1, 1),      # the LM cell: T 2048 under a 2048 K tile
-    (1, True, None, 6, 1),      # grouped queries change nothing
-    (2, True, None, 1, 2),      # a caller's shorter K tiles
-    (2, True, None, 6, 2),
-    (4, True, None, 1, 2),
-    (1, False, None, 1, 2),     # non-causal: the two kernels
-    (1, True, 24, 1, 2),        # a window: a Q tile meets two K tiles
-    (4, True, 24, 6, 2),
-    (1, True, 64, 6, 1),        # a window that covers T is the causal call
-    (8, True, None, 7, 2),      # SmallThinker's full layer: 7 query heads
-                                # a K/V head over a deep K grid
-    (8, True, 40, 7, 2),        # … and its band, six tiles wide here
+    (1, True, None, 1, None, 1),    # the LM cell: T 2048, a 2048 K tile
+    (1, True, None, 6, None, 1),    # grouped queries change nothing
+    (1, True, None, 6, 0, 1),       # … and one K tile keeps nothing
+    (2, True, None, 1, None, 1),    # a caller's shorter K tiles: dq waits
+    (2, True, None, 6, None, 1),
+    (4, True, None, 1, None, 1),
+    (4, True, None, 1, 4 * 16 * 16 * 4, 1),     # 4 tiles of (16, 16) f32
+    (4, True, None, 1, 4 * 16 * 16 * 4 - 1, 2),  # a byte short: two calls
+    (1, False, None, 1, None, 2),   # non-causal: the two kernels
+    (1, True, 24, 1, None, 1),      # a window: a Q tile meets two K tiles
+    (4, True, 24, 6, None, 1),
+    (4, True, 24, 6, 0, 2),
+    (1, True, 64, 6, None, 1),  # a window that covers T is the causal call
+    (8, True, None, 7, None, 1),    # SmallThinker's full layer: 7 query
+                                    # heads a K/V head over a deep K grid
+    (8, True, None, 7, 0, 2),
+    (8, True, 40, 7, None, 1),      # … and its band, six tiles wide here
+    (8, True, 40, 7, 0, 2),
 ]
 
 
-@pytest.mark.parametrize("nk,causal,window,group,passes", PASSES)
+@pytest.mark.parametrize("nk,causal,window,group,budget,passes", PASSES)
 def test_backward_passes_are_read_from_the_shapes(nk, causal, window,
-                                                  group, passes):
+                                                  group, budget, passes,
+                                                  dq_budget):
     """The rule (``backward_passes``) and the program it gives: ONE
-    ``znicz_flash_bwd`` where a causal, un-windowed call's K side is one
-    grid tile, else ``znicz_flash_dq`` + ``znicz_flash_dkv`` — no
-    argument but the call's own shapes enters."""
+    ``znicz_flash_bwd`` (``_bwd_win`` under a window) where the call is
+    causal and the dq tiles that wait for a later K tile fit the
+    budget, else ``znicz_flash_dq`` + ``znicz_flash_dkv`` — no argument
+    but the call's own shapes enters."""
     from znicz_tpu.ops import pallas_attention as pa
+    if budget is not None:
+        dq_budget(budget)
     t, dh, bk = 64, 16, 64 // nk
     live = window if window is not None and window < t else None
-    assert pa.backward_passes(causal, t, bk, live) == passes
+    assert pa.backward_passes(causal, t, bk, live, t, 16, group,
+                              dh) == passes
     q = _rand((1, t, group, dh), 0)
     k = _rand((1, t, 1, dh), 1)
 
@@ -470,7 +515,7 @@ def test_backward_passes_are_read_from_the_shapes(nk, causal, window,
     names = _kernel_names(jax.grad(loss, argnums=(0, 1, 2)), q, k, k)
     win = "_win" if live is not None else ""
     assert names == [f"znicz_flash_fwd{win}"] + (
-        ["znicz_flash_bwd"] if passes == 1 else
+        [f"znicz_flash_bwd{win}"] if passes == 1 else
         [f"znicz_flash_dq{win}", f"znicz_flash_dkv{win}"])
 
 
@@ -487,12 +532,15 @@ ONE_PASS = {
 
 
 @pytest.mark.parametrize("shape", list(ONE_PASS))
-def test_one_pass_backward_matches_the_core_and_the_two_kernels(shape):
+def test_one_pass_backward_matches_the_core_and_the_two_kernels(
+        shape, dq_budget):
     """dq, dk, dv of the one-pass call (K tile = T: two Q tiles walk it
     in 32² sub-tiles, interior, crossing and skipped) against the
-    plain-XLA core, and against the two-kernel call the same operands
-    get under two K tiles — only the order of dq's f32 partial sums
-    differs between those."""
+    plain-XLA core; against the one-pass call under TWO K tiles, whose
+    first Q tile's dq leaves at K tile 0 and whose second waits for K
+    tile 1; and against the two-kernel call the same operands get there
+    once nothing may wait — only the order of dq's f32 partial sums
+    differs between the three."""
     from znicz_tpu.ops.pallas_attention import flash_attention_rows
     h, h_kv, dh, fused = ONE_PASS[shape]
     b, t, group = 2, 128, h // h_kv
@@ -501,19 +549,16 @@ def test_one_pass_backward_matches_the_core_and_the_two_kernels(shape):
     x = _rand((b, t, edges[-1]), 21)
     dy = _rand((b, t, h * dh), 22)
 
-    def split(x):
-        return tuple(x[..., lo:hi] for lo, hi in zip(edges[:-1], edges[1:]))
-
     def core(x):
-        q, k, v = (a.reshape(b, t, -1, dh) for a in split(x))
+        q, k, v = (a.reshape(b, t, -1, dh) for a in _split(x, edges))
         k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
         return local_attention(q, k, v, causal=True).reshape(b, t, -1)
 
     def kernels(block_k):
         def run(x):
             return flash_attention_rows(
-                (x,) if fused else split(x), h, causal=True, block_q=64,
-                block_k=block_k, sub_tile=(32, 32), interpret=True,
+                (x,) if fused else _split(x, edges), h, causal=True,
+                block_q=64, block_k=block_k, sub_tile=(32, 32), interpret=True,
                 n_kv_heads=h_kv)
         return run
 
@@ -521,17 +566,92 @@ def test_one_pass_backward_matches_the_core_and_the_two_kernels(shape):
         return jax.grad(lambda x: jnp.vdot(fn(x), dy))
 
     one, two = kernels(t), kernels(t // 2)
-    assert _kernel_names(grad(one), x) == ["znicz_flash_fwd",
-                                           "znicz_flash_bwd"]
-    assert "znicz_flash_dq" in _kernel_names(grad(two), x)
+    for fn in (one, two):
+        assert _kernel_names(grad(fn), x) == ["znicz_flash_fwd",
+                                              "znicz_flash_bwd"]
     np.testing.assert_allclose(one(x), core(x), atol=2e-5)
-    want, got, apart = grad(core)(x), grad(one)(x), grad(two)(x)
+    want, got, deep = grad(core)(x), grad(one)(x), grad(two)(x)
+    dq_budget(0)
+    assert _kernel_names(grad(two), x) == [
+        "znicz_flash_fwd", "znicz_flash_dq", "znicz_flash_dkv"]
+    apart = grad(two)(x)
     for name, (lo, hi) in zip(("dq", "dk", "dv"),
                               zip(edges[:-1], edges[1:])):
         np.testing.assert_allclose(got[..., lo:hi], want[..., lo:hi],
                                    atol=5e-5, err_msg=name)
+        np.testing.assert_allclose(deep[..., lo:hi], want[..., lo:hi],
+                                   atol=5e-5, err_msg=name + " / deep")
         np.testing.assert_allclose(got[..., lo:hi], apart[..., lo:hi],
                                    atol=1e-5, err_msg=name + " / two")
+        np.testing.assert_allclose(deep[..., lo:hi], apart[..., lo:hi],
+                                   atol=1e-5, err_msg=name + " / deep, two")
+    # dk and dv add their Q tiles in ONE order in both forms
+    np.testing.assert_array_equal(deep[..., edges[1]:],
+                                  apart[..., edges[1]:])
+
+
+#: case → (T, query heads, K/V heads, dh, (block_q, block_k), window,
+#: one fused array?): the shapes past one K tile in small — SmallThinker's
+#: full layer (seven query heads a K/V head over a deep K grid), its band
+#: (nine tiles wide: the ring of slots wraps), Laguna's (two tiles wide,
+#: nine query heads), each where a head is a column block and where it is
+#: not, as three arrays and as ONE fused (B, T, 3·D) result, pairs of
+#: dh-64 heads, and Q tiles longer and shorter than the K tiles
+PAST_ONE_K_TILE = {
+    "deep_gqa7_head_major": (128, 7, 1, 16, (16, 16), None, False),
+    "deep_gqa7_boundary": (128, 7, 1, 128, (16, 32), None, False),
+    "deep_gqa7_fused": (128, 7, 1, 128, (16, 32), None, True),
+    "deep_pairs_fused": (128, 4, 4, 64, (32, 16), None, True),
+    "band9_gqa7_head_major": (192, 7, 1, 16, (16, 16), 128, False),
+    "band9_gqa7_boundary": (192, 7, 1, 128, (16, 16), 128, False),
+    "band2_gqa9_boundary": (128, 9, 1, 128, (16, 16), 16, False),
+    "band3_gqa3_fused": (128, 3, 1, 128, (16, 16), 24, True),
+    "band_long_q_tiles": (128, 2, 1, 128, (32, 16), 40, False),
+    "band_long_k_tiles": (128, 2, 1, 128, (16, 32), 40, False),
+}
+
+
+@pytest.mark.parametrize("case", list(PAST_ONE_K_TILE))
+def test_one_pass_past_one_k_tile_gives_the_two_kernels_bits(case,
+                                                             dq_budget):
+    """The one-pass backward whose dq tiles wait in VMEM from K tile to
+    K tile (``dq_slots``), one body per grid tile: every cotangent
+    matches the plain oracle, and EQUALS the two-pass kernels' bit for
+    bit — K tiles add into dq ascending and Q tiles into dk and dv
+    ascending in both forms (PR 55)."""
+    from tests.test_laguna_reference import oracle as _gqa_oracle
+    from znicz_tpu.ops import pallas_attention as pa
+    t, h, h_kv, dh, (bq, bk), window, fused = PAST_ONE_K_TILE[case]
+    win = "" if window is None else "_win"
+    widths = [h * dh, h_kv * dh, h_kv * dh]
+    edges = np.cumsum([0] + widths)
+    x = _rand((1, t, edges[-1]), 51)
+    dy = _rand((1, t, h * dh), 52)
+
+    def kernels(x):
+        return pa.flash_attention_rows(
+            (x,) if fused else _split(x, edges), h, causal=True, block_q=bq,
+            block_k=bk, interpret=True, n_kv_heads=h_kv, window=window)
+
+    def plain(x):
+        q, k, v = (a.reshape(1, t, -1, dh) for a in _split(x, edges))
+        return _gqa_oracle(q, k, v, window).reshape(1, t, -1)
+
+    def grad(fn):
+        return jax.grad(lambda x: jnp.vdot(fn(x), dy))
+
+    slots = pa.dq_slots(t, t, bq, bk, window)
+    assert slots == (t // bq if window is None
+                     else pa.band_steps(t, bq, bk, window)[1]) > 1
+    assert _kernel_names(grad(kernels), x) == [
+        f"znicz_flash_fwd{win}", f"znicz_flash_bwd{win}"]
+    one = grad(kernels)(x)
+    np.testing.assert_allclose(one, grad(plain)(x), atol=2e-4, rtol=2e-4)
+    dq_budget(0)
+    assert _kernel_names(grad(kernels), x) == [
+        f"znicz_flash_fwd{win}", f"znicz_flash_dq{win}",
+        f"znicz_flash_dkv{win}"]
+    np.testing.assert_array_equal(one, grad(kernels)(x))
 
 
 @pytest.mark.parametrize("heads", [(1, 1), (2, 1)], ids=["mha", "gqa2"])
@@ -577,13 +697,16 @@ def _hop_oracle(q, k, v, q_off, k_off):
 @pytest.mark.parametrize("pack", [1, 2])
 @pytest.mark.parametrize("sub", [None, (8, 8), (16, 8)])
 def test_one_pass_hop_with_offsets_masked_rows_and_an_lse_cotangent(
-        sub, pack):
+        sub, pack, dq_budget):
     """The ring's hop through the one pass: q rows 8…71 against k
     columns 40…103 in ONE K tile — the first Q tile (rows 8…39) sees no
     key at all, the diagonal crosses the second mid-tile — with a
     cotangent on the hop's lse as the cross-hop combination sends one.
-    Every gradient matches the oracle and the two-kernel hop; the
-    masked rows' dq is exactly 0."""
+    Every gradient matches the oracle, the one-pass hop over TWO K
+    tiles (the unseen Q tile's dq leaves at K tile 0 as the zeros it
+    is, the other waits for K tile 1: both read from the offsets'
+    scalars) and the two-kernel hop; the masked rows' dq is exactly
+    0."""
     from znicz_tpu.ops.pallas_attention import ring_hop
     b, hp, t, dh = 2, 2, 64, 16
     q_off, k_off = 8, 40
@@ -611,16 +734,26 @@ def test_one_pass_hop_with_offsets_masked_rows_and_an_lse_cotangent(
                 + jnp.vdot(jnp.where(rows > 0, lse, 0.0), dl)
         return loss
 
-    assert _kernel_names(jax.grad(hop(t), (0, 1, 2)), q, k, v) \
-        == ["znicz_flash_fwd", "znicz_flash_bwd"]
+    for block_k in (t, t // 2):
+        assert _kernel_names(jax.grad(hop(block_k), (0, 1, 2)), q, k, v) \
+            == ["znicz_flash_fwd", "znicz_flash_bwd"]
     want = jax.grad(core, (0, 1, 2))(q, k, v)
     got = jax.grad(hop(t), (0, 1, 2))(q, k, v)
+    deep = jax.grad(hop(t // 2), (0, 1, 2))(q, k, v)
+    dq_budget(0)
+    assert "znicz_flash_dq" in _kernel_names(
+        jax.grad(hop(t // 2), (0, 1, 2)), q, k, v)
     apart = jax.grad(hop(t // 2), (0, 1, 2))(q, k, v)
-    for name, a, w, two in zip(("dq", "dk", "dv"), got, want, apart):
+    for name, a, d, w, two in zip(("dq", "dk", "dv"), got, deep, want,
+                                  apart):
         np.testing.assert_allclose(a, w, atol=5e-5, err_msg=name)
+        np.testing.assert_allclose(d, w, atol=5e-5, err_msg=name + " / deep")
         np.testing.assert_allclose(a, two, atol=1e-5,
                                    err_msg=name + " / two")
-    assert np.all(np.asarray(got[0])[:, :, ~vis] == 0.0)
+        np.testing.assert_allclose(d, two, atol=1e-5,
+                                   err_msg=name + " / deep, two")
+    for dq in (got[0], deep[0]):
+        assert np.all(np.asarray(dq)[:, :, ~vis] == 0.0)
 
 
 # ---- the forward's visit (PR 36): state, statistics, scale ------------
@@ -828,25 +961,33 @@ def test_unit_engages_flash_only_on_tpu(monkeypatch):
 
 # ----------------------------------------------------------------------
 # the shapes of smallthinker_train_1of8 in small (PR 50): seven query
-# heads a K/V head; an un-windowed causal call past WHOLE_BLOCK_K, so the
-# two-pass backward over a deep K grid at the chooser's own tiles; a band
-# NINE tiles wide
+# heads a K/V head; an un-windowed causal call past WHOLE_BLOCK_K, so a
+# deep K grid at the chooser's own tiles; a band NINE tiles wide.  Since
+# PR 55 both backwards are one pass whose dq tiles wait in VMEM
 # ----------------------------------------------------------------------
 @pytest.mark.parametrize("case", ["causal_past_the_whole_key_range",
                                   "band_nine_tiles_wide"])
 @pytest.mark.parametrize("dh", [16, 128], ids=["head_major", "boundary"])
 def test_seven_queries_a_kv_head_at_the_long_cells_tilings(case, dh,
-                                                           monkeypatch):
+                                                           monkeypatch,
+                                                           dq_budget):
     from tests.test_laguna_reference import oracle as _gqa_oracle
     from znicz_tpu.ops import pallas_attention as pa
     t, h, h_kv = 192, 7, 1
+    seen = dict(t_q=t, group=h, width=dh)
     if case == "band_nine_tiles_wide":
         window = 128
         monkeypatch.setattr(pa, "BAND_BLOCK", 16)
         assert pa.band_blocks(t) == (16, 16)
         assert pa.band_steps(t, 16, 16, window) == (9, 9)
-        kernels = ["znicz_flash_fwd_win", "znicz_flash_dq_win",
-                   "znicz_flash_dkv_win"]
+        # a ring of nine slots a query head, which twelve Q tiles share
+        assert pa.dq_slots(t, t, 16, 16, window) == 9
+        assert pa.resident_dq_bytes(True, t, 16, window, **seen) \
+            == 7 * 9 * 16 * dh * 4
+        assert pa.backward_passes(True, t, 16, window, **seen) == 1
+        kernels = ["znicz_flash_fwd_win", "znicz_flash_bwd_win"]
+        apart = ["znicz_flash_fwd_win", "znicz_flash_dq_win",
+                 "znicz_flash_dkv_win"]
     else:
         # the chooser's own tiles, a key range it does not take whole
         window = None
@@ -855,8 +996,13 @@ def test_seven_queries_a_kv_head_at_the_long_cells_tilings(case, dh,
         monkeypatch.setattr(pa, "WHOLE_BLOCK_K", 64)
         assert pa.grid_blocks(True, t, t) == (32, 32)
         assert pa.backward_block_k(True, t, 32) == 32
-        assert pa.backward_passes(True, t, 32) == 2
-        kernels = ["znicz_flash_fwd", "znicz_flash_dq", "znicz_flash_dkv"]
+        # every Q tile of the seven heads waits
+        assert pa.dq_slots(t, t, 32, 32) == 6
+        assert pa.resident_dq_bytes(True, t, 32, **seen) \
+            == 7 * 6 * 32 * dh * 4
+        assert pa.backward_passes(True, t, 32, **seen) == 1
+        kernels = ["znicz_flash_fwd", "znicz_flash_bwd"]
+        apart = ["znicz_flash_fwd", "znicz_flash_dq", "znicz_flash_dkv"]
     q = _rand((1, t, h, dh), 1)
     k = _rand((1, t, h_kv, dh), 2)
     v = _rand((1, t, h_kv, dh), 3)
@@ -880,6 +1026,14 @@ def test_seven_queries_a_kv_head_at_the_long_cells_tilings(case, dh,
     for name, a, b in zip(("dq", "dk", "dv"), g_got, g_want):
         np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4,
                                    err_msg=name)
+    # a byte short of what has to wait: the two kernels, the same bits
+    dq_budget(pa.resident_dq_bytes(True, t, 32 if window is None else 16,
+                                   window, **seen) - 1)
+    assert _kernel_names(jax.grad(loss(kernel), (0, 1, 2)), q, k, v) \
+        == apart
+    for name, a, b in zip(("dq", "dk", "dv"), g_got,
+                          jax.grad(loss(kernel), (0, 1, 2))(q, k, v)):
+        np.testing.assert_array_equal(a, b, err_msg=name)
 
 
 @pytest.mark.parametrize("t,overwork", [(16384, 1.125), (8192, 1.125)])

@@ -333,10 +333,15 @@ def test_head_pack_gate(monkeypatch, caplog):
     # non-causal: the backward keeps its dq and dk/dv kernels
     assert unit._flash.backward == 2
     assert "backward passes 2" in caplog.text
-    # the families the plan's line replaced are gone
-    for family in ("layout", "backward", "forward", "tiles"):
+    # the families the plan's line replaced are gone — but the
+    # backward's passes and the dq it keeps in VMEM, which a probe and
+    # an operator read as numbers (PR 55)
+    for family in ("layout", "forward", "tiles"):
         assert "znicz_flash_%s{" % family \
             not in obs_metrics.REGISTRY.to_prometheus()
+    assert obs_metrics.flash_backward(unit.name, "passes").value == 2
+    assert obs_metrics.flash_backward(
+        unit.name, "resident_dq_bytes").value == 0
     unit = _attention_unit(XLADevice(), d=256, heads=2)  # dh = 128
     assert (unit._flash.layout, unit._flash.head_pack) == ("boundary", 1)
     # an odd head count keeps one head per program, never raises
@@ -390,14 +395,26 @@ def test_causal_schedule_resolves_from_shapes_not_options(monkeypatch,
     assert unit._flash.forward.stats == "lanes"
     assert unit._flash.window is None and "window" not in unit._flash.line()
     # T 4096: the backward takes the two 2048-long K tiles whole, the
-    # forward carries its state over them; past that a dq and a dk/dv
+    # forward carries its state over them; past that the Q tiles' dq
+    # wait in VMEM for their later K tiles, and past THAT room (seven
+    # heads of 128 a K/V head at T 32,768: 112 MiB) a dq and a dk/dv
     # kernel
     assert _attention_unit(XLADevice(), t=4096,
                            causal=True)._flash.forward.state == "carried"
-    assert _attention_unit(XLADevice(), t=4096,
-                           causal=True)._flash.backward == 1
-    assert _attention_unit(XLADevice(), t=8192,
-                           causal=True)._flash.backward == 2
+    whole = _attention_unit(XLADevice(), t=4096, causal=True)
+    assert (whole._flash.backward, whole._flash.resident_dq) == (1, 0)
+    deep = _attention_unit(XLADevice(), b=1, t=8192, causal=True)
+    assert (deep._flash.backward, deep._flash.resident_dq) \
+        == (1, 8192 * 8 * 4)     # two heads of 8
+    assert "backward passes 1 (0.25 MiB of dq wait in VMEM)" \
+        in deep._flash.line()
+    assert obs_metrics.flash_backward(
+        deep.name, "resident_dq_bytes").value == 8192 * 8 * 4
+    past = _attention_unit(XLADevice(), b=1, t=32768, heads=7,
+                           n_kv_heads=1, head_dim=128, causal=True)
+    assert (past._flash.backward, past._flash.resident_dq) == (2, 0)
+    assert pa.resident_dq_bytes(True, 32768, 2048, group=7) \
+        == 112 * 2 ** 20 > pa.RESIDENT_DQ_VMEM
     # an option of that name steers nothing any more
     root.common.engine.flash_causal_block = 256
     again = _attention_unit(XLADevice(), t=2048, causal=True)

@@ -247,6 +247,17 @@ def test_what_the_expert_layers_report(one_step):
     assert "znicz_moe_hidden{" in text
     windowed = wf.forwards[3]
     assert obs_metrics.flash_band(windowed.name, "window").value == 8
+    # every attention layer's backward is one pass (PR 55), and the
+    # gauge says what its plan says
+    for unit in wf.forwards:
+        plan = getattr(unit, "_flash", None)
+        if plan is None or not plan.runs:
+            continue
+        assert obs_metrics.flash_backward(
+            unit.name, "passes").value == plan.backward == 1
+        assert obs_metrics.flash_backward(
+            unit.name, "resident_dq_bytes").value == plan.resident_dq
+    assert windowed._flash.resident_dq > 0
 
 
 # ----------------------------------------------------------------------

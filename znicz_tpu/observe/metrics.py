@@ -600,6 +600,24 @@ def flash_band(unit: str, stat: str) -> Gauge:
         labels=("unit", "stat")).labels(unit=unit, stat=stat)
 
 
+def flash_backward(unit: str, stat: str) -> Gauge:
+    """The form of a flash-attention unit's backward, as its plan holds
+    it (``pallas_attention.FlashPlan``; ``stat`` = ``passes``: 1 — ONE
+    ``znicz_flash_bwd`` / ``_bwd_win`` call, a score sub-tile computed
+    once — or 2, ``znicz_flash_dq`` + ``znicz_flash_dkv``,
+    ``pallas_attention.backward_passes``; ``resident_dq_bytes``: the f32
+    VMEM in which a one-pass backward keeps its unfinished dq tiles
+    from K tile to K tile, what the call asks for beyond a call's own —
+    0 where the K side is one tile and under two passes).  Static per
+    program, set once at ``initialize`` by every unit whose core the
+    kernels run."""
+    return REGISTRY.gauge(
+        "znicz_flash_backward",
+        "Passes over the score tiles in a flash-attention layer's "
+        "backward and the bytes of dq it keeps resident in VMEM",
+        labels=("unit", "stat")).labels(unit=unit, stat=stat)
+
+
 def moe_expert_tokens(unit: str, stat: str) -> Gauge:
     """Rows (token, expert) pairs an expert of a ``MoE`` unit computed
     per step, over the last epoch: ``stat`` = ``max`` / ``min`` (the

@@ -607,8 +607,13 @@ class MultiHeadAttention(Forward):
                       self.name, self._ring_axis, self._ring_fold)
         else:
             self.info("%s: %s", self.name, self._flash.line())
-        if self._flash.runs and self._flash.window is not None:
+        if self._flash.runs:
             from znicz_tpu.observe import metrics as obs_metrics
+            for stat, value in (
+                    ("passes", self._flash.backward),
+                    ("resident_dq_bytes", self._flash.resident_dq)):
+                obs_metrics.flash_backward(self.name, stat).set(value)
+        if self._flash.runs and self._flash.window is not None:
             for stat, value in (
                     ("window", self._flash.window),
                     ("band_share", self._flash.band_share),

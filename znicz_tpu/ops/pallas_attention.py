@@ -49,32 +49,47 @@ Design (the standard flash decomposition, implemented TPU-first):
   in VMEM — ONCE where the call's shapes allow, else twice
   (:func:`backward_passes`, never an option):
 
-  - *one pass*, ``znicz_flash_bwd`` — a causal, un-windowed call whose
-    K side is ONE grid tile of the backward: T ≤ 2048 under
-    ``CAUSAL_BLOCK_K`` (the LM cell; a ring hop whose keys are one
-    tile), and up to ``WHOLE_BLOCK_K`` 4096 because the backward takes
-    such a key range whole (:func:`backward_block_k`; OLMoE, Laguna's
-    full layers).  The grid is the dk/dv grid, (B, H_kv, 1, group ·
-    Q tiles): a step's Q tile meets every key it can see, so beside
-    ``dv += pᵀ·do`` and ``dk += dsᵀ·q`` the walk adds ``dq[rows] +=
-    ds·k`` into an f32 (bq, width) scratch that is zeroed as the step
-    starts and leaves, cast, as it ends: five matmuls and one pass of
-    exponentials per visible sub-tile, the count FlashAttention-2 gives
-    and ``znbench/flops.py`` holds the kernels to.  On a v5e (PERF.md
+  - *one pass*, ``znicz_flash_bwd`` (``znicz_flash_bwd_win`` under a
+    window) — every causal call whose unfinished dq fits
+    ``RESIDENT_DQ_VMEM``.  The grid is the dk/dv grid, (B, H_kv, K
+    tiles, group · Q tiles): beside ``dv += pᵀ·do`` and ``dk += dsᵀ·q``
+    the walk adds ``dq[rows] += ds·k`` from the ``ds`` it has: five
+    matmuls and one pass of exponentials per visible sub-tile, the
+    count FlashAttention-2 gives and ``znbench/flops.py`` holds the
+    kernels to.  Where the K side is ONE grid tile of the backward —
+    T ≤ 2048 under ``CAUSAL_BLOCK_K`` (the LM cell; a ring hop whose
+    keys are one tile), and up to ``WHOLE_BLOCK_K`` 4096 because the
+    backward takes such a key range whole (:func:`backward_block_k`;
+    OLMoE, Laguna's full layers) — a step's Q tile meets every key it
+    can see, and its dq is an f32 (bq, width) scratch that is zeroed as
+    the step starts and leaves, cast, as it ends.  On a v5e (PERF.md
     §6, PR 30): the LM cell's backward 42.9 → 30.9 ms a step; a layer
     at T 4096 × 128 lanes 2.01 → 1.40 ms (16 heads), 5.48 → 3.71 (48
-    query heads on 8).
+    query heads on 8).  Past one K tile (PR 55) the K tile is an
+    ordered axis and a Q tile's dq WAITS in VMEM, f32, from the first K
+    tile that feeds it to the last one, the diagonal's, where it is
+    cast and sent to HBM once, by DMA (:func:`dq_slots`): without a
+    window every Q tile of the K/V head's group waits — SmallThinker's
+    NoPE full layer at T 16,384, eight K tiles of 2048 under seven
+    query heads of sixteen Q tiles: 56 MiB of a v5e's 128, asked for
+    with ``vmem_limit_bytes`` —; under a window only the Q tiles one K
+    tile meets are unfinished at once, a ring of slots indexed by the
+    Q tile modulo their number (SmallThinker's band of 4,096: 7 heads ×
+    9 slots of 512 × 128 = 15.75 MiB; Laguna's of 512: 9 × 2 = 4.5).
+    Which K tile is a Q tile's first and which its last is read from
+    the offsets' scalars, so a ring hop over several K tiles takes the
+    same path.  K tiles add into dq ascending and Q tiles into dk and
+    dv ascending, as in the two-pass kernels: under one body per tile
+    (the bands) the cotangents are theirs bit for bit; where a K tile
+    is walked in column blocks, dq's partial sums inside the tile add
+    in another order (an ulp).
   - *two passes*, ``znicz_flash_dq`` (grid over K blocks innermost,
     accumulating dq tiles) and ``znicz_flash_dkv`` (grid over Q blocks
     innermost, accumulating dk/dv tiles): seven matmuls and the
-    exponentials twice.  A deeper K grid (T > 4096 — SmallThinker's
-    NoPE full layer at T 16,384 walks eight K tiles of 2048 with seven
-    query heads a K/V head, the one cell that runs these two
-    un-windowed, PR 50 — or a caller's shorter K tiles), a window (a Q
-    tile meets two K tiles and a K tile two Q tiles under Laguna's band
-    of 512, nine and nine under SmallThinker's of 4,096:
-    ``znicz_flash_*_win``) and non-causal calls leave dq unfinished at
-    a dk/dv step's end and keep them.
+    exponentials twice.  What the rule still sends there: non-causal
+    calls (every Q tile would wait for the LAST K tile) and calls past
+    the budget — T 32,768 un-windowed at a group of seven would keep
+    112 MiB.  No cell runs them since PR 55.
 
   Both forms share one dk/dv body (:func:`_dkv_kernel`), `_p_tile`,
   the walk, the masks and the fully-masked-row guards.
@@ -145,7 +160,9 @@ reshape), ``do`` is read in place, and dq, dk, dv land in column
 blocks of the cotangent: for one fused array ONE (B, T, 3·D) result —
 the one-pass call writes all of it (a blocked output is one block per
 grid step, so its finished tiles leave by DMA from VMEM: a Q tile's dq
-as its step ends, under the next step's walk; dk and dv at the last);
+as its step ends — past one K tile as its LAST K tile's step ends,
+which is how three separate cotangents get their dq too —, under the
+next steps' walk; dk and dv at a K tile's last step);
 under two passes the dq call begins the result and the dk/dv call
 takes it aliased and puts its two tiles beside.  ``lse`` and ``delta``
 stay head-major (B, Hp, T, lanes): they are the kernels' own.  Head
@@ -206,8 +223,8 @@ _SUB_TILE = 512
 #: 2048 × 512 do not)
 _ROW_VISIT_ELEMS = 512 * 2048
 _COL_VISIT_ELEMS = 1024 * 512
-#: the BACKWARD takes a causal call's key range whole up to here: one
-#: K tile makes it one pass (`backward_passes`).  At T 4096 × 128 lanes
+#: the BACKWARD takes a causal call's key range whole up to here: under
+#: one K tile no dq tile has to wait (`dq_slots`).  At T 4096 × 128 lanes
 #: that pass takes 1.40 ms where dq + dk/dv under 2048-long tiles take
 #: 2.01 (16 MHA heads), 3.71 against 5.48 (48 query heads on 8) on a
 #: v5e (PERF.md §6, PR 30); the forward keeps ``CAUSAL_BLOCK_K`` …
@@ -216,6 +233,19 @@ WHOLE_BLOCK_K = 4096
 #: f32 accumulators of 4096 × 128 overflow the 16 MB a call gets
 #: unasked by 0.4–1.4 MB (compiled for a described v5e, PR 30)
 _WHOLE_K_VMEM = 40 * 2 ** 20
+#: past one K tile the one-pass backward keeps every UNFINISHED dq tile
+#: of a K/V head's group in VMEM, f32, from the first K tile that feeds
+#: it to the diagonal's (`dq_slots`, `resident_dq_bytes`) where that is
+#: at most this much of a v5e's 128 MiB: SmallThinker's full layer at
+#: T 16,384 — seven query heads × 16 tiles of 1024 × 128 — takes 56 MiB,
+#: its band of 4,096 7 × 9 tiles of 512 = 15.75, Laguna's band of 512
+#: 9 × 2 = 4.5; T 32,768 un-windowed at a group of seven would take 112
+#: and keeps the two calls (`backward_passes`; PR 55) …
+RESIDENT_DQ_VMEM = 64 * 2 ** 20
+#: … and asks for it on top of what a call gets unasked, which holds a
+#: grid step's tiles, their second buffers, dk's and dv's accumulators
+#: and a visit's score run as it held the dk/dv call's
+_STEP_VMEM = 16 * 2 ** 20
 #: lane width of the per-row statistics that live in HBM (lse, delta):
 #: the minimum tile-legal last dim — the value is replicated across
 #: lanes (with head packing, each sub-head owns one _LANES-wide lane
@@ -397,8 +427,9 @@ def backward_block_k(causal: bool, t_k: int, bk: int, window=None) -> int:
     """K-side grid tile of the BACKWARD of a call whose forward walks
     K tiles of ``bk``: the same — except that a causal, un-windowed
     call already at the chooser's longest tile (``CAUSAL_BLOCK_K``)
-    takes a key range of up to ``WHOLE_BLOCK_K`` whole, because the
-    backward then is one pass (:func:`backward_passes`).  The
+    takes a key range of up to ``WHOLE_BLOCK_K`` whole, because a Q
+    tile's dq is then finished inside one grid step of the one-pass
+    backward and nothing waits in VMEM (:func:`dq_slots`).  The
     statistics the forward saved do not depend on its tiles."""
     if causal and window is None \
             and bk == CAUSAL_BLOCK_K < t_k <= WHOLE_BLOCK_K:
@@ -406,21 +437,63 @@ def backward_block_k(causal: bool, t_k: int, bk: int, window=None) -> int:
     return bk
 
 
-def backward_passes(causal: bool, t_k: int, bk: int, window=None) -> int:
+def dq_slots(t_q: int, t_k: int, bq: int, bk: int, window=None) -> int:
+    """dq tiles of ONE query head that are unfinished at once while the
+    one-pass backward of a causal call walks K tiles of ``bk`` (the
+    BACKWARD's: :func:`backward_block_k`): 0 where the K side is one
+    tile — a Q tile's dq is whole as its grid step ends and nothing
+    waits; every Q tile where the call has no window (tile q is fed
+    from K tile 0 to its diagonal); under a window the Q tiles ONE K
+    tile meets (``band_steps``), a ring of slots indexed by the Q tile
+    modulo their number — a tile's slot is zeroed at the first K tile
+    its band touches and leaves at the diagonal, before the tile that
+    takes the slot next starts."""
+    if window is not None:
+        return band_steps(t_q, bq, bk, window)[1]
+    return 0 if bk == t_k else t_q // bq
+
+
+def resident_dq_bytes(causal: bool, t_k: int, bk: int, window=None,
+                      t_q=None, bq=None, group: int = 1,
+                      width: int = _STAT_LANES) -> int:
+    """The f32 VMEM the one-pass backward of a causal call keeps across
+    its K tiles: :func:`dq_slots` tiles of (bq, ``width``) for each of
+    the ``group`` query heads that share a K/V head (``bk``: the
+    forward's K tile; ``t_q`` / ``bq`` left out: self-attention at the
+    chooser's Q tile).  0 where nothing outlives a grid step."""
+    t_q = t_k if t_q is None else t_q
+    if bq is None:
+        bq = band_blocks(t_q)[0] if window is not None \
+            else grid_blocks(causal, t_q, t_k)[0]
+    slots = dq_slots(t_q, t_k, bq,
+                     backward_block_k(causal, t_k, bk, window), window)
+    return group * slots * bq * width * 4
+
+
+def backward_passes(causal: bool, t_k: int, bk: int, window=None,
+                    t_q=None, bq=None, group: int = 1,
+                    width: int = _STAT_LANES) -> int:
     """How many times the backward recomputes a score sub-tile, from
-    the call's shapes alone (``bk``: the forward's K tile): 1 where a
-    causal, un-windowed call's K side is ONE grid tile of the backward
-    (:func:`backward_block_k`) — a dk/dv grid step then holds every key
-    its Q tile can see, so dq finishes inside it and the backward is
-    one ``pallas_call``, ``znicz_flash_bwd`` (five matmuls and one pass
-    of exponentials per visible sub-tile); else 2, ``znicz_flash_dq`` +
-    ``znicz_flash_dkv`` (seven and two): a deeper K grid (T_k past
-    ``WHOLE_BLOCK_K``: 8,192 and 16,384 run so), a window (a Q tile
-    meets two to nine K tiles and a K tile as many Q tiles) or a
-    non-causal call leaves dq unfinished at a dk/dv step's end.  Static
-    per program: in the plan (:func:`plan`) and its line."""
-    whole = backward_block_k(causal, t_k, bk, window) == t_k
-    return 1 if causal and window is None and whole else 2
+    the call's shapes alone (``bk``: the forward's K tile; ``t_q``,
+    ``bq``, ``group``, ``width``: the query side's length and tile, the
+    query heads a K/V head and a program's lane width — what
+    :func:`resident_dq_bytes` reads): 1 where the call is causal and
+    the dq tiles that have to wait for a later K tile fit
+    ``RESIDENT_DQ_VMEM`` — none where the backward's K side is ONE grid
+    tile (:func:`backward_block_k`: a dk/dv grid step then holds every
+    key its Q tile can see), every Q tile of a deeper un-windowed grid,
+    a band's width of them under a window.  The backward is then one
+    ``pallas_call``, ``znicz_flash_bwd`` / ``znicz_flash_bwd_win`` (five
+    matmuls and one pass of exponentials per visible sub-tile); else 2,
+    ``znicz_flash_dq`` + ``znicz_flash_dkv`` (seven and two): a
+    non-causal call, whose Q tiles all finish at the LAST K tile, and a
+    call past the budget (T 32,768 un-windowed at a group of seven).
+    Static per program: in the plan (:func:`plan`), its line and the
+    unit's gauge."""
+    if not causal:
+        return 2
+    return 1 if resident_dq_bytes(causal, t_k, bk, window, t_q, bq, group,
+                                  width) <= RESIDENT_DQ_VMEM else 2
 
 
 # ----------------------------------------------------------------------
@@ -876,10 +949,19 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
 
 
+def _when(cond, fn) -> None:
+    """``fn()`` where ``cond`` holds: a traced scalar, or True as
+    Python knows it — then no branch is made."""
+    if cond is True:
+        fn()
+    else:
+        pl.when(cond)(fn)
+
+
 def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                 lse_ref, delta_ref, *rest, scale, causal, bq, bk, sq,
                 sk, pack, shared, window=None, q_steps=None, q_tiles=None,
-                group=1, one_pass=False):
+                group=1, one_pass=False, slots=0, dq_block=None):
     """``q_steps`` (None, or the Q tiles one query head brings to the
     grid's last axis): grouped queries and a window make that axis
     something else than the Q tiles in turn — it runs over every query
@@ -890,16 +972,24 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
     of a fused projection.  A kernel's blocked output is one block per
     grid step, so the finished tiles go there by DMA from VMEM.
 
-    ``one_pass`` (the K side is ONE grid tile: :func:`backward_passes`):
-    a grid step's Q tile meets here every key it can see, so its dq is
-    complete at the step's end.  The body then also contracts the ``ds``
-    it has with k, into an f32 (bq, width) scratch that is zeroed as the
-    step starts and leaves as it ends — this kernel is the whole
-    backward (``znicz_flash_bwd``) and nothing is recomputed twice.
-    Otherwise the dq call (:func:`_dq_kernel`) has begun a fused result
-    and hands it in aliased."""
+    ``one_pass`` (:func:`backward_passes`): the body also contracts the
+    ``ds`` it has with k, into dq — this kernel is the whole backward
+    (``znicz_flash_bwd``) and nothing is recomputed twice.  ``slots`` 0
+    (the K side is ONE grid tile): a grid step's Q tile meets here every
+    key it can see, so its dq is an f32 (bq, width) scratch that is
+    zeroed as the step starts and leaves as it ends.  Else (:func:`
+    dq_slots`) the scratch holds ``slots`` such tiles for each query
+    head of the group and the step's tile has a slot of its own: zeroed
+    at the first K tile that feeds it, added to in every K tile between
+    — ascending, as :func:`_dq_kernel` adds them —, cast and sent to
+    its rows of dq (``dq_block``: their first column block, None
+    head-major) by DMA at the last, the diagonal's; both read from the
+    offsets' scalars, so a hop's rows above every key leave as the
+    zeros they are at K tile 0.  Without ``one_pass`` the dq call
+    (:func:`_dq_kernel`) has begun a fused result and hands it in
+    aliased."""
     refs = list(rest)
-    dq_ref = dq_scr = dq_tile = None
+    dq_ref = dq_scr = dq_tile = pending = None
     if shared is None:
         if one_pass:
             dq_ref = refs.pop(0)
@@ -907,14 +997,19 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
     else:
         if not one_pass:
             refs.pop(0)     # the aliased operand itself is never read
-        out_ref = refs.pop(0)
+        out_ref = dq_ref = refs.pop(0)
+        dq_block = shared[0]
     dk_scr, dv_scr = refs.pop(0), refs.pop(0)
     if one_pass:
         dq_scr = refs.pop(0)
-        if shared is not None:
+        if shared is not None or slots:
             dq_tile = refs.pop(0)
+    if slots:
+        pending = refs.pop()    # whether a dq tile is on its way out
     if shared is not None:
         dk_tile, dv_tile, sems = refs
+    elif slots:
+        sems, = refs
     batch, head = pl.program_id(0), pl.program_id(1)
     ik, step = pl.program_id(2), pl.program_id(3)
     nq = pl.num_programs(3)
@@ -930,9 +1025,24 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
+        if slots:
+            pending[0] = 0
 
+    # the step's dq tile: the scratch itself, begun and finished here
+    # — or its slot, between the first K tile of its rows and the last
+    slot, begins, leaves = (), True, True
+    if slots:
+        slot = ((step // q_steps) * slots + iq % slots,)
+        first_k = 0 if window is None \
+            else _band_k_first(row0, window, bk)
+        last_k = jnp.clip(row0 + bq - 1 - koff_ref[0, 0], 0,
+                          pl.num_programs(2) * bk - 1) // bk
+        begins, leaves = (ik == first_k) & live, (ik == last_k) & live
     if one_pass:
-        dq_scr[...] = jnp.zeros_like(dq_scr)
+        def _begin_dq():
+            dq_scr[slot] = jnp.zeros(dq_scr.shape[len(slot):],
+                                     dq_scr.dtype)
+        _when(begins, _begin_dq)
 
     def body(c, parts):
         """dk, dv of columns [c, c+sk) from every row run in
@@ -970,7 +1080,8 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         dv_scr[cs, :] += jnp.concatenate(dv_out, axis=1)
         if one_pass:
             for (r, n, _), dq_run in zip(parts, dq_out):
-                dq_scr[r:r + n, :] += jnp.concatenate(dq_run, axis=1)
+                dq_scr[(*slot, slice(r, r + n), slice(None))] \
+                    += jnp.concatenate(dq_run, axis=1)
 
     _fold_rows(body, causal, row0, col0, bq, bk, sq, sk, window,
                cols_outer=True, live=live)
@@ -978,28 +1089,40 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
     d = dk_scr.shape[1]
     last = step == nq - 1
 
-    def to_column_block(tile, rows, block, sem):
+    def to_column_block(tile, out, rows, block, sem):
         """The DMA of a finished tile to ``rows`` of column block
-        ``block`` of the one result."""
+        ``block`` of ``out``."""
         lanes = pl.ds(pl.multiple_of(block * d, d), d)
         return pltpu.make_async_copy(
-            tile, out_ref.at[batch, rows, lanes], sems.at[sem])
+            tile, out.at[batch, rows, lanes], sems.at[sem])
 
-    if one_pass and shared is None:
+    if one_pass and dq_tile is None:
         dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
     elif one_pass:
-        # the step's own Q tile: the copy runs under the next step's
+        # the step's own Q tile: the copy runs under the next steps'
         # walk and is waited for where its tile is written again
         q_head = head if q_steps is None else head * group + step // q_steps
-        dq_copy = to_column_block(
-            dq_tile, pl.ds(pl.multiple_of(iq * bq, bq), bq),
-            shared[0] + q_head, 2)
-        pl.when(step > 0)(dq_copy.wait)
-        dq_tile[...] = dq_scr[...].astype(dq_tile.dtype)
-        dq_copy.start()
+        rows = pl.ds(pl.multiple_of(iq * bq, bq), bq)
+        if dq_block is None:    # head-major: the query head's rows
+            dq_copy = pltpu.make_async_copy(
+                dq_tile, dq_ref.at[batch, q_head, rows, :], sems.at[2])
+        else:
+            dq_copy = to_column_block(dq_tile, dq_ref, rows,
+                                      dq_block + q_head, 2)
+
+        def _leave_dq():
+            # every step sends a tile where none waits; else the flag says
+            pl.when(pending[0] == 1 if slots else step > 0)(dq_copy.wait)
+            dq_tile[...] = dq_scr[slot].astype(dq_tile.dtype)
+            dq_copy.start()
+            if slots:
+                pending[0] = 1
+        _when(leaves, _leave_dq)
 
     @pl.when(last)
     def _finish():
+        if slots:   # the flag lives one K tile: its last copy ends here
+            pl.when(pending[0] == 1)(dq_copy.wait)
         if shared is None:
             dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
             dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
@@ -1009,9 +1132,10 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         for acc, tile, first, sem in ((dk_scr, dk_tile, shared[1], 0),
                                       (dv_scr, dv_tile, shared[2], 1)):
             tile[...] = acc[...].astype(tile.dtype)
-            copies.append(to_column_block(tile, rows, first + head, sem))
+            copies.append(to_column_block(tile, out_ref, rows,
+                                          first + head, sem))
             copies[-1].start()
-        if one_pass:
+        if one_pass and not slots:
             copies.append(dq_copy)
         for copy in copies:
             copy.wait()
@@ -1159,12 +1283,15 @@ def _fwd_call(arrays, q_off, k_off, causal, bq, bk, interpret, pack,
     )(q_off, k_off, q, k, v)
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12, 13))
+@functools.partial(jax.jit,
+                   static_argnums=(6, 7, 8, 9, 10, 11, 12, 13, 14))
 def _bwd_call(arrays, lse, do, delta4, q_off, k_off, causal, bq, bk,
-              interpret, pack, sub=None, cols=None, window=None):
+              interpret, pack, sub, cols, window, passes):
     """Cotangents of ``arrays`` (see :func:`_fwd_call`), in their
     layout: (dq, dk, dv), or for ONE fused array its one cotangent —
-    written whole by the one-pass call (:func:`backward_passes`); under
+    written whole by the one-pass call (``passes``:
+    :func:`backward_passes` of the shapes, asked by the caller so that
+    the call is traced per answer); under
     two passes the dq call writes q's column blocks of a (B, T, C)
     result and the dk/dv call takes that result aliased and puts its
     tiles beside them — so no concatenate of activation size stands
@@ -1180,7 +1307,7 @@ def _bwd_call(arrays, lse, do, delta4, q_off, k_off, causal, bq, bk,
         _operands(arrays, cols)
     shared = len(arrays) == 1
     group = h // h_kv
-    one_pass = backward_passes(causal, tk, bk, window) == 1
+    one_pass = passes == 1
     bk = backward_block_k(causal, tk, bk, window)
     nq, nk = t // bq, tk // bk
     sq, sk = sub or sub_tile_for(causal, bq, bk)
@@ -1195,9 +1322,8 @@ def _bwd_call(arrays, lse, do, delta4, q_off, k_off, causal, bq, bk,
         static.update(sq=bq, sk=bk, window=window)
         suffix = "_win"
     off_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel",
-                             "arbitrary"))
+    semantics = ("parallel", "parallel", "parallel", "arbitrary")
+    params = pltpu.CompilerParams(dimension_semantics=semantics)
     c_do = None if cols is None else 0
 
     def like(a):
@@ -1227,7 +1353,8 @@ def _bwd_call(arrays, lse, do, delta4, q_off, k_off, causal, bq, bk,
     # grid dim now, the k-side by dim 2
     q_at = _q_side(group, window, q_steps, t, bq, bk)
     in_specs = specs(q_at, _first)
-    if group > 1 or window is not None:
+    slots = dq_slots(t, tk, bq, bk, window) if one_pass else 0
+    if group > 1 or window is not None or slots:
         static["q_steps"] = q_steps
     if window is not None:
         static["q_tiles"] = nq
@@ -1235,33 +1362,48 @@ def _bwd_call(arrays, lse, do, delta4, q_off, k_off, causal, bq, bk,
     outs = [(k, ck, bk, _first), (v, cv, bk, _first)]
     scratch = [pltpu.VMEM((bk, d), jnp.float32),
                pltpu.VMEM((bk, d), jnp.float32)]
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
     if one_pass:        # the whole backward: dq leaves with dk and dv
         static.update(one_pass=True, group=group)
-        name = "znicz_flash_bwd"
+        name = "znicz_flash_bwd" + suffix
         outs.insert(0, (q, cq, bq, q_at))
-        scratch.append(pltpu.VMEM((bq, d), jnp.float32))
-        if bk > CAUSAL_BLOCK_K:
+        scratch.append(pltpu.VMEM(
+            (group * slots, bq, d) if slots else (bq, d), jnp.float32))
+        limit = _WHOLE_K_VMEM if bk > CAUSAL_BLOCK_K else None
+        if slots:
+            # dq tiles wait in their slots from K tile to K tile: that
+            # axis runs in turn, and the call asks for the room
+            static.update(slots=slots)
+            semantics = ("parallel", "parallel", "arbitrary", "arbitrary")
+            limit = group * slots * bq * d * 4 + (limit or _STEP_VMEM)
+        if limit:
             params = pltpu.CompilerParams(
-                dimension_semantics=params.dimension_semantics,
-                vmem_limit_bytes=_WHOLE_K_VMEM)
+                dimension_semantics=semantics, vmem_limit_bytes=limit)
     call = functools.partial(
         pl.pallas_call, grid=(b, h_kv, nk, group * q_steps),
         compiler_params=params, interpret=interpret, name=name)
+    # the tiles that leave by DMA from a VMEM copy in the cotangent's
+    # dtype: all of ONE fused result's, and a dq tile that waited
+    sent = outs if shared else outs[:1] if slots else []
+    scratch += [pltpu.VMEM((rows, d), a.dtype) for a, _, rows, _ in sent]
+    if sent:    # dk's, dv's and (the kernel's index 2) dq's
+        scratch.append(pltpu.SemaphoreType.DMA((len(outs),)))
+    if slots:   # whether a dq tile is on its way out
+        scratch.append(pltpu.SMEM((1,), jnp.int32))
     if not shared:
+        if slots:
+            static.update(dq_block=cq)
         grads = call(
             functools.partial(_dkv_kernel, shared=None, **static),
             in_specs=in_specs,
-            out_specs=tuple(_tile(rows, d, col, at)
-                            for _, col, rows, at in outs),
+            out_specs=tuple(anywhere if slots and i == 0
+                            else _tile(rows, d, col, at)
+                            for i, (_, col, rows, at) in enumerate(outs)),
             out_shape=tuple(like(a) for a, *_ in outs),
             scratch_shapes=scratch,
         )(*operands)
         return tuple(grads) if one_pass else (dq, *grads)
-    # ONE result: every finished tile leaves by DMA from a VMEM copy in
-    # the cotangent's dtype
-    anywhere = pl.BlockSpec(memory_space=pl.ANY)
-    scratch += [pltpu.VMEM((rows, d), a.dtype) for a, _, rows, _ in outs]
-    scratch.append(pltpu.SemaphoreType.DMA((len(outs),)))
+    # ONE result
     kernel = functools.partial(_dkv_kernel, shared=(cq, ck, cv), **static)
     if one_pass:
         return (call(kernel, in_specs=in_specs, out_specs=anywhere,
@@ -1333,8 +1475,10 @@ def _pass_bwd(causal, bq, bk, interpret, pack, sub, cols, window, res,
     do, dlse = cts
     do = do.astype(out.dtype)
     delta4 = _delta(do, out, dlse, pack, cols is not None)
+    _, h, h_kv, t, tk, d = _operands(arrays, cols)[1]
+    passes = backward_passes(causal, tk, bk, window, t, bq, h // h_kv, d)
     grads = _bwd_call(arrays, lse, do, delta4, q_off, k_off, causal,
-                      bq, bk, interpret, pack, sub, cols, window)
+                      bq, bk, interpret, pack, sub, cols, window, passes)
     zero = np.zeros((1, 1), jax.dtypes.float0)
     return tuple(grads), zero, zero
 
@@ -1538,6 +1682,9 @@ class FlashPlan(NamedTuple):
     window the causal half) are :func:`sub_tile_for`,
     :func:`head_layout`, :func:`forward_form`, :func:`backward_passes`,
     :func:`causal_tile_counts` and :func:`band_share` of the call;
+    ``resident_dq``: the bytes of unfinished dq a one-pass backward
+    keeps in VMEM from K tile to K tile (:func:`resident_dq_bytes`; 0
+    under two passes and where the K side is one tile);
     ``mesh`` / ``spec``: per shard under ``shard_map``."""
     refused: str | None
     interpret: bool
@@ -1552,6 +1699,7 @@ class FlashPlan(NamedTuple):
     head_pack: int | None = None
     forward: ForwardForm | None = None
     backward: int | None = None
+    resident_dq: int | None = None
     tiles: dict | None = None
     band_share: float | None = None
     mesh: object = None
@@ -1593,6 +1741,9 @@ class FlashPlan(NamedTuple):
                     if cls != "executed_share"),
                 tiles["executed_share"], self.layout, self.head_pack,
                 *self.forward, self.backward))
+        if self.resident_dq:
+            text += " (%.2f MiB of dq wait in VMEM)" % (
+                self.resident_dq / 2 ** 20)
         if group != 1 or self.window is not None:
             text += (", %d query heads to a K/V head, window %s (band "
                      "%.4f of T×T)" % (group, self.window,
@@ -1654,12 +1805,18 @@ def plan(device, batch: int, t: int, n_heads: int, n_kv_heads: int,
                      window)
     if refused is not None:
         return said
-    layout, head_pack = head_layout(n_heads, dh, n_heads // n_kv_heads)
+    group = n_heads // n_kv_heads
+    layout, head_pack = head_layout(n_heads, dh, group)
     sq, sk = sub_tile_for(causal, bq, bk) if window is None else (bq, bk)
+    # what the backward's rule reads: per shard the same — a mesh
+    # splits the batch, and the heads of a group stay together
+    seen = (causal, t, bk, window, t, bq, group, head_pack * dh)
+    passes = backward_passes(*seen)
     return said._replace(
         block_q=bq, block_k=bk, sub_tile=(sq, sk), layout=layout,
         head_pack=head_pack, forward=forward_form(t, bq, bk, dh, window),
-        backward=backward_passes(causal, t, bk, window),
+        backward=passes,
+        resident_dq=resident_dq_bytes(*seen) if passes == 1 else 0,
         tiles=causal_tile_counts(t, t, bq, bk, sq, sk, causal=causal,
                                  window=window),
         band_share=band_share(t, window),
